@@ -13,6 +13,12 @@ and node labels (slice name / accelerator type / worker id) that the GCS
 placement-group manager uses to keep a TPU gang on a SINGLE slice (one ICI
 domain) — see gcs/pg_manager.py. On hosts with no TPU markers this is a
 no-op, so CPU nodes are unaffected.
+
+The chip count comes from the host's device nodes when it has any
+(``count_local_chips``): environment variables describe the slice type,
+not what this machine holds (the one-chip v5e host exports
+``TPU_CHIPS_PER_HOST_BOUNDS=2,2,1``). None of this imports jax: the raylet
+must not open the chips its workers will need.
 """
 
 from __future__ import annotations
@@ -20,7 +26,9 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
-from typing import Dict, Mapping, Optional
+import re
+import threading
+from typing import Dict, Mapping, Optional, Sequence
 
 logger = logging.getLogger(__name__)
 
@@ -37,6 +45,7 @@ _GKE_HOSTNAMES = "TPU_WORKER_HOSTNAMES"
 _GKE_NAME = "TPU_NAME"
 _CHIP_BOUNDS = "TPU_CHIPS_PER_HOST_BOUNDS"  # e.g. "2,2,1" -> 4 chips
 _VISIBLE_CHIPS = "TPU_VISIBLE_CHIPS"        # e.g. "0,1,2,3"
+_HOST_BOUNDS = "TPU_HOST_BOUNDS"
 
 _GCE_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1"
 
@@ -60,8 +69,11 @@ def tpu_head_resource_name(accelerator_type: str) -> str:
     return f"TPU-{accelerator_type}-head"
 
 
-# Per-chip bf16 peak FLOP/s by jax device_kind, for MFU math (published
-# figures: v2/v3 per-chip = 2 cores; v5e has no matmul-rate doubling).
+# Published per-chip peaks by jax ``device_kind`` (Google Cloud TPU
+# documentation, system-architecture page of each generation; v2/v3
+# per-chip = 2 cores). The tables every MFU and roofline figure in the
+# repo divides by: a device that is not listed is an error, never a
+# default. HBM bandwidth is listed only where the repo has measured.
 _BF16_PEAK_FLOPS = {
     "TPU v2": 46e12,
     "TPU v3": 123e12,
@@ -72,14 +84,75 @@ _BF16_PEAK_FLOPS = {
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
 }
+_HBM_PEAK_BYTES_PER_SEC = {
+    "TPU v5 lite": 819e9,
+    "TPU v5e": 819e9,
+}
+
+
+def _published_peak(table: Dict[str, float], device_kind: str) -> float:
+    try:
+        return table[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for device_kind {device_kind!r} (listed: "
+            f"{sorted(table)}); utilization against a guessed peak is not "
+            "a measurement — add the device, with its source, to "
+            "ray_tpu/_private/accelerators/tpu.py") from None
 
 
 def bf16_peak_flops_per_chip(device_kind: str) -> float:
-    """Per-chip bf16 peak for the given jax ``device_kind``. Unknown
-    generations fall back to the v5e figure (this repo's reference chip) —
-    MFU against the wrong generation's peak is off by the peak ratio, so
-    keep the table current as new device kinds appear."""
-    return _BF16_PEAK_FLOPS.get(device_kind, 197e12)
+    """Per-chip bf16 peak FLOP/s for the given jax ``device_kind``; raises
+    ValueError for a device the table does not list."""
+    return _published_peak(_BF16_PEAK_FLOPS, device_kind)
+
+
+def hbm_peak_bytes_per_sec(device_kind: str) -> float:
+    """Per-chip HBM bandwidth for the given jax ``device_kind``; raises
+    ValueError for a device the table does not list."""
+    return _published_peak(_HBM_PEAK_BYTES_PER_SEC, device_kind)
+
+
+def count_local_chips(dev_root: str = "/dev") -> int:
+    """TPU chips this host holds, read from its device nodes without
+    opening them: ``accel<N>`` entries, else numbered VFIO groups
+    (``vfio/<N>``; ``vfio/vfio`` is the container node, not a chip). The
+    v5e hosts this repo runs on expose the latter."""
+    try:
+        accel = [n for n in os.listdir(dev_root)
+                 if re.fullmatch(r"accel\d+", n)]
+    except OSError:
+        return 0
+    if accel:
+        return len(accel)
+    try:
+        return sum(n.isdigit()
+                   for n in os.listdir(os.path.join(dev_root, "vfio")))
+    except OSError:
+        return 0
+
+
+# libtpu reads these when the process first opens the backend. A worker
+# granted one or two chips of a larger host must describe a one-process
+# "host" of that size, or libtpu waits for the rest of the advertised
+# topology (reference: tpu.py set_current_process_visible_accelerator_ids,
+# which likewise leaves other counts to TPU_VISIBLE_CHIPS alone).
+_SUBHOST_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+
+def visible_chips_env(chip_ids: Sequence[int],
+                      chips_on_host: int) -> Dict[str, str]:
+    """Environment that confines a worker process to ``chip_ids`` (indices
+    into this host's chips). Empty when the worker gets the whole host:
+    libtpu's own defaults are right there."""
+    if not chip_ids or len(chip_ids) >= chips_on_host:
+        return {}
+    env = {_VISIBLE_CHIPS: ",".join(str(c) for c in chip_ids)}
+    bounds = _SUBHOST_BOUNDS.get(len(chip_ids))
+    if bounds:
+        env[_CHIP_BOUNDS] = bounds
+        env[_HOST_BOUNDS] = "1,1,1"
+    return env
 
 
 def chips_per_host(accelerator_type: str,
@@ -121,17 +194,28 @@ def _chips_per_host(env: Mapping[str, str], accelerator_type: str) -> int:
 
 
 def _gce_metadata(path: str, timeout: float = 0.5) -> Optional[str]:
-    """Best-effort GCE metadata read (absent off-GCP; never raises)."""
-    try:
-        import urllib.request
+    """Best-effort GCE metadata read (absent off-GCP; never raises). The
+    socket timeout does not cover name resolution, which can hang on a
+    host with no network, so the request runs on a daemon thread that is
+    abandoned after ``2 * timeout``: node start-up never waits longer."""
+    result = []
 
-        req = urllib.request.Request(
-            f"{_GCE_METADATA_URL}/{path}",
-            headers={"Metadata-Flavor": "Google"})
-        with urllib.request.urlopen(req, timeout=timeout) as resp:
-            return resp.read().decode()
-    except Exception:  # noqa: BLE001 — any failure means "not on GCE"
-        return None
+    def fetch():
+        try:
+            import urllib.request
+
+            req = urllib.request.Request(
+                f"{_GCE_METADATA_URL}/{path}",
+                headers={"Metadata-Flavor": "Google"})
+            with urllib.request.urlopen(req, timeout=timeout) as resp:
+                result.append(resp.read().decode())
+        except Exception:  # noqa: BLE001 — any failure means "not on GCE"
+            pass
+
+    t = threading.Thread(target=fetch, daemon=True, name="gce-metadata")
+    t.start()
+    t.join(2 * timeout)
+    return result[0] if result else None
 
 
 def detect_tpu(env: Optional[Mapping[str, str]] = None,
@@ -215,15 +299,30 @@ def apply_tpu_detection(
     labels: Dict[str, str],
     env: Optional[Mapping[str, str]] = None,
     probe_gce: bool = False,
+    dev_root: Optional[str] = None,
 ) -> Optional[TpuSliceInfo]:
     """Merge detected TPU resources/labels into a node's advertisement.
+
+    ``dev_root`` (``"/dev"`` for a real node; None for in-process test
+    nodes that model other hosts) makes the host's device nodes the chip
+    count: they override what the slice type implies, and a plain host
+    with chips but no slice identity still advertises ``TPU``. Without
+    device nodes there is nothing to schedule, so the metadata server is
+    not asked either.
 
     Explicit user-set values win (a node started with ``resources={"TPU": 8}``
     keeps 8). Mutates both dicts in place; returns the detection result.
     """
-    info = detect_tpu(env, probe_gce=probe_gce)
+    chips = count_local_chips(dev_root) if dev_root else 0
+    info = detect_tpu(
+        env, probe_gce=probe_gce and (chips > 0 or not dev_root))
     if info is None:
+        if chips:
+            resources.setdefault("TPU", float(chips))
+            logger.info("TPU host without slice identity: %d chips", chips)
         return None
+    if chips:
+        info = dataclasses.replace(info, num_chips=chips)
     resources.setdefault("TPU", float(info.num_chips))
     # Typed per-chip resource alongside the generic one: gangs that pin a
     # topology (ScalingConfig(topology="v5e-8")) demand `TPU-v5e-8` per

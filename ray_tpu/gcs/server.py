@@ -312,8 +312,19 @@ class GcsNodeManager:
         period = CONFIG.health_check_period_ms / 1000.0
         threshold = CONFIG.health_check_failure_threshold
         while True:
+            slept_from = time.monotonic()
             await asyncio.sleep(period)
             now = time.monotonic()
+            if now - slept_from > 2 * period:
+                # This loop was itself held up: the process, or the whole
+                # host, stalled (libtpu opening four chips freezes the
+                # v5e sandbox for several seconds). Whoever it would
+                # judge was most likely held up with it — a node gets a
+                # full period to report before its silence counts.
+                logger.warning(
+                    "health loop stalled %.1fs; no liveness verdicts "
+                    "this round", now - slept_from - period)
+                continue
             for node_id, info in list(self._nodes.items()):
                 if not info.alive:
                     continue

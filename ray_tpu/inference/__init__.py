@@ -6,5 +6,19 @@ compiled-shape management (bucketing) in Serve replicas" as a required
 hard part — this package supplies it.
 """
 
-from ray_tpu.inference.engine import GenerationConfig, InferenceEngine  # noqa: F401
-from ray_tpu.inference.sampling import sample_token  # noqa: F401
+_NAMES = {
+    "GenerationConfig": "ray_tpu.inference.engine",
+    "InferenceEngine": "ray_tpu.inference.engine",
+    "sample_token": "ray_tpu.inference.sampling",
+}
+
+
+def __getattr__(name):
+    # the engines import jax at module level; resolving on first use keeps
+    # `import ray_tpu.inference` (and serve.llm, which imports it) jax-free
+    # for drivers that only bind a deployment.
+    if name in _NAMES:
+        import importlib
+
+        return getattr(importlib.import_module(_NAMES[name]), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -435,9 +435,36 @@ class PagedInferenceEngine:
             "prefix_stats": dict(self.prefix_stats),
         }
 
+    def device_report(self) -> Dict[str, Any]:
+        """The device the weights live on, as jax reports it, with its
+        memory counters. What a benchmark or smoke run must print instead
+        of its driver's platform: the replica is the process that
+        computes. `visible_chips` is the worker's chip assignment
+        (TPU_VISIBLE_CHIPS; None = the whole host) — processes confined
+        to different chips each see their own as device 0."""
+        import os
+
+        from ray_tpu._private.device_profiler import hbm_stats
+
+        dev = min(jax.tree.leaves(self.params)[0].devices(),
+                  key=lambda d: d.id)
+        (memory,) = hbm_stats([dev], export=False).values()
+        return {"platform": dev.platform, "device_kind": dev.device_kind,
+                "id": dev.id,
+                "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+                **memory}
+
     def stats(self) -> Dict[str, Any]:
         """Host-side engine occupancy snapshot (serving observability)."""
+        from ray_tpu._private.device_profiler import compile_stats
+
         return {
+            "device": self.device_report(),
+            "param_bytes": sum(int(x.nbytes)
+                               for x in jax.tree.leaves(self.params)),
+            # XLA compiles this process has paid for (count, seconds);
+            # near zero when the persistent cache was warm
+            "compile": compile_stats(),
             "max_batch": self.max_batch,
             "active_slots": self.max_batch - len(self.free_slots),
             "free_blocks": len(self.free_blocks),

@@ -24,7 +24,7 @@ NEG_INF = -1e30
 
 
 # --------------------------------------------------------------------------
-# Reference (oracle / CPU fallback)
+# Reference (the oracle tests compare against; the path on non-TPU backends)
 # --------------------------------------------------------------------------
 
 def _reference_attention(q, k, v, causal: bool, scale: float):
@@ -353,9 +353,9 @@ def flash_attention(
     q, k, v,
     causal: bool = True,
     scale: Optional[float] = None,
-    # Measured on v5e at B8/H16/D128 seq 2048 (fwd+bwd): 128x128 ~2x slower
-    # than 512x512 (14.2ms); 512x1024 is best (12.3ms; 1024x512 12.5ms,
-    # 1024x1024 and k=1536+ exceed VMEM). Clamped to seq below.
+    # Block sizes were chosen on a v5e at B8/H16/D128 seq 2048 in an
+    # earlier round (512x1024 fastest; 1024x1024 and k>=1536 exceed VMEM);
+    # not re-measured on the current installation. Clamped to seq below.
     block_q: int = 512,
     block_k: int = 1024,
     use_pallas: Optional[bool] = None,
@@ -363,9 +363,22 @@ def flash_attention(
 ):
     """Exact attention over [B, S, H, D] inputs (GQA: fewer KV heads OK).
 
-    On TPU lowers to the Pallas kernels above; elsewhere (or with
-    use_pallas=False) runs the JAX oracle so the same model code runs on the
-    CPU test mesh.
+    Which path runs is read off the platform, never off a failure: with
+    `use_pallas=None`, `jax.default_backend() == "tpu"` lowers to the
+    Pallas kernels above and any other backend runs the jax.numpy oracle
+    (what the CPU test mesh uses; `interpret=True` runs the kernels in
+    the Pallas interpreter instead). Nothing falls back from one to the
+    other at run time — chip_smoke.py fails unless `tpu_custom_call` is
+    in the lowered train step.
+
+    Sequence limit (v5e, libtpu 0.0.34, pinned by
+    tests/test_tpu_aot_compile.py): each kernel instance keeps the whole
+    sequence's K and V (forward, dq) or q, dO, lse and delta (dk/dv) in
+    VMEM, so the backward pass compiles at S 4096 and is refused at
+    S 8192 ("Scoped allocation with size 18.98M and limit 16.00M exceeded
+    scoped vmem limit"; the forward pass alone still compiles there) —
+    at `LlamaConfig.max_seq_len`'s default. Longer sequences need the
+    backward pass tiled over the sequence, or `ring_attention` over `sp`.
     """
     b, s_q, h, d = q.shape
     h_kv = k.shape[2]
@@ -410,7 +423,6 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True,
     runs the kernel on its local [B/dp·fsdp, S, H/tp, D] block. KV heads are
     repeated to match q heads first so the tp shard is uniform under GQA.
     """
-    from ray_tpu._private.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     h_kv = k.shape[2]
@@ -434,7 +446,7 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True,
     spec = P(batch_axes or None, None, head_axis, None)
 
     fn = functools.partial(flash_attention, causal=causal, scale=scale, **kw)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

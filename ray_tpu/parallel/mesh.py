@@ -60,7 +60,14 @@ class MeshConfig:
 
 
 def build_mesh(config: MeshConfig = MeshConfig(), devices=None):
-    """Build a jax.sharding.Mesh over the (global) device set."""
+    """Build a jax.sharding.Mesh over the (global) device set.
+
+    Devices are reshaped in list order, tp innermost. On a v5e 2x2 host
+    `jax.devices()` is id 0-3 at coords (0,0) (1,0) (0,1) (1,1), so every
+    tp pair and every fsdp pair of the (fsdp=2, tp=2) mesh is a pair of
+    physical neighbours; chip_smoke.py asserts that on the chip. Larger
+    or wrapped topologies should go through
+    `jax.experimental.mesh_utils.create_device_mesh` instead."""
     import jax
     from jax.sharding import Mesh
 
@@ -159,27 +166,27 @@ def initialize_distributed(
     """
     import jax
 
-    try:  # jax 0.4.x: no public is_initialized — inspect the global client
-        from jax._src import distributed as _dist
+    # jax has no public accessor for the coordinator a process is bound
+    # to (jax.distributed.is_initialized() only says whether): read the
+    # global client state.
+    from jax._src import distributed as _dist
 
-        state = _dist.global_state
-        if getattr(state, "client", None) is not None:
-            if (state.coordinator_address == coordinator_address
-                    and state.num_processes == num_processes
-                    and state.process_id == process_id):
-                logger.info(
-                    "jax.distributed already initialized for this gang; "
-                    "skipping")
-                return
-            logger.warning(
-                "jax.distributed bound to %s (world=%s rank=%s); "
-                "re-initializing for %s (world=%s rank=%s)",
-                state.coordinator_address, state.num_processes,
-                state.process_id, coordinator_address, num_processes,
-                process_id)
-            state.shutdown()
-    except ImportError:  # pragma: no cover — future jax moves the module
-        pass
+    state = _dist.global_state
+    if state.client is not None:
+        if (state.coordinator_address == coordinator_address
+                and state.num_processes == num_processes
+                and state.process_id == process_id):
+            logger.info(
+                "jax.distributed already initialized for this gang; "
+                "skipping")
+            return
+        logger.warning(
+            "jax.distributed bound to %s (world=%s rank=%s); "
+            "re-initializing for %s (world=%s rank=%s)",
+            state.coordinator_address, state.num_processes,
+            state.process_id, coordinator_address, num_processes,
+            process_id)
+        state.shutdown()
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

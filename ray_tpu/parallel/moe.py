@@ -78,7 +78,6 @@ def moe_shard_map(x, gate_w, expert_fn, expert_params, mesh,
     buffers exchanged with lax.all_to_all."""
     import jax
     import jax.numpy as jnp
-    from ray_tpu._private.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_exp_total = gate_w.shape[1]
@@ -108,7 +107,7 @@ def moe_shard_map(x, gate_w, expert_fn, expert_params, mesh,
         return y.astype(x_loc.dtype), jax.lax.pmean(aux, axis_name)
 
     pspec = jax.tree.map(lambda _: P(axis_name), expert_params)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(axis_name), P(), pspec),
         out_specs=(P(axis_name), P()),

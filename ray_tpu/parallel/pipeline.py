@@ -76,13 +76,12 @@ def pipeline_sharded(stage_fn, mesh, axis_name: str = "pp"):
     """shard_map wrapper: params lead with a [pp, ...] stage axis, inputs are
     replicated microbatches; returns final outputs replicated."""
     import jax
-    from ray_tpu._private.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     def wrapped(stacked_params, microbatches):
         fn = functools.partial(pipeline_apply, stage_fn, axis_name=axis_name)
         param_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(),

@@ -112,7 +112,6 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "sp",
     """
     import jax
     from jax.sharding import PartitionSpec as P
-    from ray_tpu._private.jax_compat import shard_map
 
     # Shard batch over every data-parallel axis (incl. the inter-slice dcn
     # axis of multi-slice meshes) and heads over tp — replicating those
@@ -129,7 +128,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "sp",
                  and q.shape[2] % mesh.shape["tp"] == 0 else None)
     spec = P(batch_axes or None, axis_name, head_axis, None)
     fn = functools.partial(ring_attention, axis_name=axis_name, causal=causal)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -90,29 +90,11 @@ def with_logical_constraint(x, logical_axes: Sequence[Optional[str]],
     import jax
 
     if mesh is None:
-        # Ambient mesh: prefer the new jax.set_mesh context, fall back to the
-        # legacy `with mesh:` context (thread_resources — deprecated but the
-        # only way to see `with mesh:` users; warning suppressed).
-        mesh = None
-        try:
-            from jax.sharding import get_abstract_mesh
-
-            am = get_abstract_mesh()
-            if am is not None and not am.empty:
-                mesh = am
-        except Exception:  # noqa: BLE001
-            pass
-        if mesh is None:
-            import warnings
-
-            with warnings.catch_warnings():
-                warnings.simplefilter("ignore", DeprecationWarning)
-                from jax.interpreters import pxla
-
-                legacy = pxla.thread_resources.env.physical_mesh
-            if legacy.empty:
-                return x
-            mesh = legacy
+        # Ambient mesh from a `jax.set_mesh(...)` scope; none means
+        # the caller is running unsharded.
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            return x
     rules = rules or LogicalAxisRules()
     return jax.lax.with_sharding_constraint(
         x, logical_sharding(mesh, logical_axes, rules)

@@ -94,6 +94,9 @@ class _Lease:
     retriable: bool = False
     owner_id: str = ""
     start_time: float = field(default_factory=time.monotonic)
+    # host chip indices the leased worker was confined to; they return to
+    # the node's free list with the lease
+    chip_ids: Tuple[int, ...] = ()
 
 
 @dataclass
@@ -137,18 +140,24 @@ class Raylet:
         resources.setdefault("CPU", float(os.cpu_count() or 1))
         resources.setdefault("memory", 4.0 * 1024**3)
         self.labels = dict(labels or {})
-        # TPU slice detection (reference: _private/accelerators/tpu.py:75):
-        # GKE/GCE markers become TPU + TPU-<type>-head resources and slice
-        # labels used for single-slice gang placement. `accelerator_env`
-        # lets in-process test clusters model multiple slices on one host;
-        # the GCE metadata probe (non-GKE TPU VMs) only runs for real nodes
-        # reading the ambient environment.
+        # TPU detection (reference: _private/accelerators/tpu.py:75): the
+        # host's device nodes give the chip count; GKE/GCE markers add the
+        # TPU-<type>-head resource and the slice labels used for
+        # single-slice gang placement. `accelerator_env` lets in-process
+        # test clusters model multiple slices on one host; the device
+        # nodes and the (hard-bounded) GCE metadata probe are only looked
+        # at by real nodes reading the ambient environment.
         from ray_tpu._private.accelerators import apply_tpu_detection
 
         apply_tpu_detection(
             resources, self.labels, env=accelerator_env,
             probe_gce=(accelerator_env is None
-                       and CONFIG.tpu_probe_gce_metadata))
+                       and CONFIG.tpu_probe_gce_metadata),
+            dev_root="/dev" if accelerator_env is None else None)
+        # Which chips are whose: a worker granted `TPU: k` is started with
+        # k of these indices visible and no others (worker_pool._spawn).
+        self._free_chips: List[int] = list(
+            range(int(resources.get("TPU", 0))))
         # On k8s, the autoscaler joins provider pods to GCS nodes via this
         # label (downward-API env; see autoscaler.update's label join).
         pod_name = os.environ.get("RT_POD_NAME") or os.environ.get("POD_NAME")
@@ -240,6 +249,7 @@ class Raylet:
             log_dir=self._log_dir,
             on_worker_death=self._on_worker_death,
             env=self._worker_env,
+            chips_on_host=int(self.total.get("TPU", 0)),
         )
         # spawned workers learn the socket from their env, which lets them
         # register one-way (no reply round trip on the ctor path)
@@ -926,6 +936,14 @@ class Raylet:
         """Lease released by the submitter (direct_task_transport returns)."""
         addr: Address = payload["worker_address"]
         worker_id = addr.worker_id
+        handle = self.worker_pool.get_by_worker_id(worker_id)
+        if (handle is not None and handle.needs_accelerator
+                and self.worker_pool.retire_worker(handle)):
+            # The process may have its chips open, and a chip belongs to
+            # one process at a time: it is never pooled, and its lease
+            # (resources and chip ids) is released by _on_worker_death
+            # once it is gone, not while it can still hold the device.
+            return True
         lease = self._leases.pop(worker_id, None)
         if lease is not None:
             self._release_lease_resources(lease)
@@ -1135,6 +1153,7 @@ class Raylet:
                          tags={"stage": "queue"})
         resources, pg_id, bundle_index = alloc
         needs_accel = q.spec.resources.get("TPU", 0) > 0
+        chip_ids: Tuple[int, ...] = ()
         env_key = ""
         image_uri = None
         if q.spec.runtime_env:
@@ -1155,15 +1174,21 @@ class Raylet:
                     "or docker on the node's PATH (or RT_CONTAINER_RUNTIME)",
             })
             return
+        if needs_accel:
+            # _try_allocate reserved the count; whole chips get identities
+            # (a fractional demand shares a chip it cannot be confined to)
+            n = int(q.spec.resources["TPU"])
+            chip_ids = tuple(self._free_chips[:n])
+            del self._free_chips[:n]
         worker = await self.worker_pool.pop_worker(
             CONFIG.worker_register_timeout_s, needs_accelerator=needs_accel,
-            env_hash=env_key, image_uri=image_uri,
+            env_hash=env_key, image_uri=image_uri, chip_ids=chip_ids,
         )
         if hist is not None:
             hist.observe(max(0.0, time.monotonic() - granted_at),
                          tags={"stage": "dispatch"})
         if worker is None or q.future.done():
-            self._release_alloc(resources, pg_id, bundle_index)
+            self._release_alloc(resources, pg_id, bundle_index, chip_ids)
             if worker is not None:
                 self.worker_pool.return_worker(worker.worker_id)
             if not q.future.done():
@@ -1186,6 +1211,7 @@ class Raylet:
                        else q.spec.max_retries != 0),
             owner_id=(owner.worker_id.hex()
                       if owner is not None and owner.worker_id else ""),
+            chip_ids=chip_ids,
         )
         if is_actor:
             self.worker_pool.mark_actor_worker(
@@ -1213,7 +1239,10 @@ class Raylet:
                        "worker_id": worker.worker_id.hex()[:12]})
         q.future.set_result({"worker_address": addr})
 
-    def _release_alloc(self, resources: Resources, pg_id, bundle_index):
+    def _release_alloc(self, resources: Resources, pg_id, bundle_index,
+                       chip_ids: Tuple[int, ...] = ()):
+        if chip_ids:
+            self._free_chips = sorted({*self._free_chips, *chip_ids})
         if pg_id is not None:
             bundles = self._bundles.get(pg_id)
             if bundles is not None and bundle_index in bundles:
@@ -1230,7 +1259,8 @@ class Raylet:
         self._kick()
 
     def _release_lease_resources(self, lease: _Lease):
-        self._release_alloc(lease.resources, lease.pg_id, lease.bundle_index)
+        self._release_alloc(lease.resources, lease.pg_id, lease.bundle_index,
+                            lease.chip_ids)
 
     # ----------------------------------------------------------- RPC: PG 2PC
     async def handle_prepare_bundles(self, payload):

@@ -14,7 +14,9 @@ ISSUE 6 adds the numbers the serving gate is judged on:
     clients hides exactly that collapse.
   * PREFIX TTFT — client-observed TTFT on a shared-system-prompt
     serve.llm workload, prefix-cache hit vs cold, plus the engine's
-    hit/evict counters.
+    hit/evict counters. A device benchmark: its replica demands a TPU
+    chip and builds its weights there; it raises without one. (The other
+    modes are host-plane and touch no jax.)
 
 Run: python -m ray_tpu.serve.benchmarks             # all of the above
      python -m ray_tpu.serve.benchmarks classic     # the r01 trio only
@@ -314,39 +316,36 @@ def run_prefix_ttft_benchmark(n_requests: int = 6,
     requests, so the delta is prefill compute, not queueing."""
     import random
 
-    import jax
-
     import ray_tpu
     from ray_tpu import serve
-    from ray_tpu.inference.paged_engine import PagedInferenceEngine
-    from ray_tpu.models import llama
+    from ray_tpu.inference.benchmarks import advertised_chips, replica_stats
     from ray_tpu.serve.llm import build_llm_app
 
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu:
-        config = llama.LlamaConfig.small_1b()
-    else:
-        # wider than tiny(): the benchmark separates prefill COMPUTE
-        # from fixed routing/RPC overhead, so the shared-prefix prefill
-        # must be the dominant term even on CPU
-        config = llama.LlamaConfig(
-            vocab_size=512, d_model=256, n_layers=4, n_heads=8,
-            n_kv_heads=4, d_head=32, d_ff=512, max_seq_len=1024)
-    params = llama.init(config, jax.random.PRNGKey(0))
     max_len = 2 * shared_prefix_len
     block = 16
 
     def build():
-        return PagedInferenceEngine(params, config, max_batch=4,
-                                    max_len=max_len, block_size=block,
-                                    n_blocks=4 * (max_len // block),
-                                    decode_chunk=4)
+        # runs inside the replica: weights are made on the replica's chip
+        import jax
+
+        from ray_tpu.inference.paged_engine import PagedInferenceEngine
+        from ray_tpu.models import llama
+
+        config = llama.LlamaConfig.small_1b()
+        return PagedInferenceEngine(
+            llama.init(config, jax.random.PRNGKey(0)), config, max_batch=4,
+            max_len=max_len, block_size=block,
+            n_blocks=4 * (max_len // block), decode_chunk=4)
 
     ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
+    advertised_chips()
     app = build_llm_app(build, name="llm_prefix", num_replicas=1,
                         default_config={"max_new_tokens": 4},
-                        shed_queue_depth=10_000)
+                        shed_queue_depth=10_000,
+                        engine_actor_options={"resources": {"TPU": 1}})
     handle = serve.run(app, name="llm_prefix")
+    # fails here, before any timing, unless the replica is on a TPU
+    replica_stats("llm_prefix", "llm_prefix_engine")
     stream = handle.options(method_name="stream_tokens", stream=True)
     rng = random.Random(0)
 
@@ -376,12 +375,9 @@ def run_prefix_ttft_benchmark(n_requests: int = 6,
         # shared prefix: tail-only prefill after the warmup request
         hits.append(ttft(warm_prefix + rand_tokens(tail_len)))
 
-    controller = ray_tpu.get_actor("SERVE_CONTROLLER")
-    replicas = ray_tpu.get(controller.get_replica_handles.remote(
-        "llm_prefix", "llm_prefix_engine"))
-    stats = ray_tpu.get(replicas[0].handle_request.remote(
-        "get_stats", (), {}), timeout=30)
+    (stats,) = replica_stats("llm_prefix", "llm_prefix_engine")
     pc = stats["engine"]["prefix_cache"]
+    device = stats["engine"]["device"]
     serve.shutdown()
 
     def p50(xs):
@@ -393,6 +389,8 @@ def run_prefix_ttft_benchmark(n_requests: int = 6,
         "hit_over_cold": round(p50(hits) / max(p50(cold), 1e-9), 3),
         "shared_prefix_len": shared_prefix_len,
         "n_requests": n_requests,
+        "platform": device["platform"],
+        "device_kind": device["device_kind"],
         "cache": {k: pc.get(k) for k in
                   ("hit_requests", "miss_requests", "hit_tokens",
                    "evictions", "bytes_saved")},
@@ -402,10 +400,8 @@ def run_prefix_ttft_benchmark(n_requests: int = 6,
 
 
 if __name__ == "__main__":
-    import os
     import sys
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     modes = set(sys.argv[1:]) or {"classic", "sustained", "prefix"}
     out: Dict[str, dict] = {}
     if "classic" in modes:

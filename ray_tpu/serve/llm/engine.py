@@ -39,7 +39,6 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import CONFIG
-from ray_tpu.inference import GenerationConfig
 from ray_tpu.serve.llm import metrics as llm_metrics
 
 logger = logging.getLogger(__name__)
@@ -100,6 +99,12 @@ class LLMEngineReplica:
         `max_queue_depth` bounds requests waiting for engine admission;
         beyond it submissions fail with LLMOverloadedError (the router
         sheds earlier — this is the per-replica backstop)."""
+        # jax enters with the replica, never with the module: the driver
+        # that binds this class must stay off the device its replicas own
+        from ray_tpu._private import compile_cache
+        from ray_tpu.inference import GenerationConfig
+
+        compile_cache.enable()
         self.engine = build_engine()
         self.default = GenerationConfig(**(default_config or {}))
         self._continuous = hasattr(self.engine, "serve_stream")

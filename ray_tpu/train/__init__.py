@@ -43,11 +43,6 @@ from ray_tpu.train.spmd import (  # noqa: F401
     get_mesh,
     shard_local_batch,
 )
-from ray_tpu.train.step import (  # noqa: F401
-    TrainState,
-    init_train_state,
-    make_train_step,
-)
 from ray_tpu.train.gbdt import (  # noqa: F401
     LightGBMTrainer,
     XGBoostTrainer,
@@ -58,6 +53,21 @@ from ray_tpu.train.trainer import (  # noqa: F401
     JaxTrainer,
     TorchTrainer,
 )
+
+_STEP_NAMES = ("TrainState", "init_train_state", "make_train_step")
+
+
+def __getattr__(name):
+    # train.step imports jax at module level; resolving its names on first
+    # use keeps `import ray_tpu.train` jax-free, so a driver that only
+    # launches a JaxTrainer never loads — let alone opens — the backend
+    # its workers own.
+    if name in _STEP_NAMES:
+        from ray_tpu.train import step
+
+        return getattr(step, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 __all__ = [
     "Backend",

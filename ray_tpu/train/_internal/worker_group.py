@@ -142,6 +142,10 @@ class WorkerGroup:
         self.workers: List[Any] = []
         self._pg = None
 
+    @property
+    def demands_tpu(self) -> bool:
+        return any(b.get("TPU", 0) > 0 for b in self._bundles)
+
     def start(self) -> None:
         bundles = [dict(b) for b in self._bundles]
         self._pg = placement_group(bundles, strategy=self._strategy)

@@ -79,14 +79,19 @@ def _find_free_port() -> int:
 
 def _init_jax_worker(platform: Optional[str], coordinator: Optional[str],
                      world_size: int, rank: int,
-                     env_vars: Optional[dict] = None,
-                     probe_backend: bool = True) -> str:
+                     env_vars: Optional[dict] = None) -> None:
+    """First thing a gang worker runs, before anything touches the
+    backend: jax.distributed.initialize (here, or later from the mesh
+    rendezvous) refuses to run after any jax computation."""
     import os
+
+    from ray_tpu._private import compile_cache
 
     for k, v in (env_vars or {}).items():
         os.environ[k] = v
     if platform:
         os.environ["JAX_PLATFORMS"] = platform
+    compile_cache.enable()
     if coordinator is not None:
         import jax
 
@@ -95,11 +100,9 @@ def _init_jax_worker(platform: Optional[str], coordinator: Optional[str],
             num_processes=world_size,
             process_id=rank,
         )
-    if not probe_backend:
-        # Mesh-native gangs must not touch the backend yet:
-        # jax.distributed.initialize (run later, fed by the collective
-        # rendezvous) refuses to run after any jax computation.
-        return platform or "deferred"
+
+
+def _worker_platform() -> str:
     import jax
 
     return jax.devices()[0].platform
@@ -129,16 +132,14 @@ class JaxBackend(Backend):
                 0, _find_free_port)
             coordinator = f"{meta[0]['hostname']}:{port}"
             logger.info("jax.distributed coordinator at %s", coordinator)
-        platforms = [
-            worker_group.workers[rank].execute.remote(
-                _init_jax_worker, backend_config.platform, coordinator,
-                world, rank, backend_config.env_vars,
-                probe_backend=not mesh_mode)
-            for rank in range(world)
-        ]
         import ray_tpu
 
-        ray_tpu.get(platforms)
+        ray_tpu.get([
+            worker_group.workers[rank].execute.remote(
+                _init_jax_worker, backend_config.platform, coordinator,
+                world, rank, backend_config.env_vars)
+            for rank in range(world)
+        ])
         if mesh_mode:
             import uuid
 
@@ -159,6 +160,20 @@ class JaxBackend(Backend):
                 raise RuntimeError(
                     f"gang workers disagree on mesh shape: {shapes}")
             logger.info("gang mesh established: %s", shapes[0])
+        # A gang that was given chips must be computing on them. Checked
+        # once the backend is up (after the mesh rendezvous, which must
+        # precede it): a worker that came up on another platform would
+        # otherwise train on, at a fraction of the speed, under the
+        # device's name.
+        expected = backend_config.platform or (
+            "tpu" if worker_group.demands_tpu else None)
+        platforms = worker_group.execute(_worker_platform)
+        if expected and any(p != expected for p in platforms):
+            raise RuntimeError(
+                f"gang asked for platform {expected!r} but its workers "
+                f"report {platforms}: check JAX_PLATFORMS in the workers' "
+                "environment and that the chips are not held by another "
+                "process")
 
     def on_shutdown(self, worker_group, backend_config: JaxConfig) -> None:
         if backend_config.mesh_config is None:

@@ -90,22 +90,12 @@ def init_train_state(
         lambda k: _init(k),
         out_shardings=_as_dict(state_shardings),
     )
-    # Sharding-invariant initialization: with non-partitionable threefry
-    # (the jax 0.4.x default), jax.random draws inside a jit depend on the
-    # OUTPUT sharding — the same seed yields different params on different
-    # meshes, breaking 1<->n-device loss parity and cross-mesh checkpoint
-    # resume. Scoped to the init program so the ambient stream is untouched.
-    try:
-        from jax._src.config import threefry_partitionable as _tfp
-
-        _ctx = _tfp(True)
-    except ImportError:  # future jax: partitionable is the default
-        import contextlib
-
-        _ctx = contextlib.nullcontext()
     # jit out_shardings wants a matching pytree structure; use dict form.
-    with _ctx:
-        state_dict = init_jit(key)
+    # (Partitionable threefry, the jax default, makes these draws
+    # independent of the output sharding: one seed gives the same params
+    # on every mesh — what 1<->n-device loss parity and cross-mesh
+    # checkpoint resume rest on.)
+    state_dict = init_jit(key)
     state = TrainState(**state_dict)
     return state, state_shardings
 

@@ -1,0 +1,114 @@
+"""What the installed libtpu's compiler accepts, checked with no chip.
+
+`jax.experimental.topologies` describes a v5e 2x2 host to the compiler, so
+the Pallas kernels and the serving decode program are compiled for
+`TPU v5 lite` here, on the CPU sandbox — a reading of the compiler, not of
+a run (chip_smoke.py is the run). One subprocess does all of it: libtpu is
+loaded there, not into the test process.
+
+It also writes down a limit where the next kernel PR will trip over it:
+the flash BACKWARD pass is refused at S 8192 (the kernels keep whole-
+sequence K/V, or q/dO, blocks in VMEM; see ops/flash_attention.py).
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+pytest.importorskip("libtpu")
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_SCRIPT = r"""
+import json
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ray_tpu.inference.paged_engine import PagedInferenceEngine
+from ray_tpu.models import llama
+from ray_tpu.ops.flash_attention import flash_attention
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
+
+
+def flash_grads(q, k, v):
+    # use_pallas=True: the default follows jax.default_backend(), cpu here
+    return jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, use_pallas=True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+out = {"device_kind": topo.devices[0].device_kind}
+bf16 = jnp.bfloat16
+lowered = jax.jit(flash_grads).lower(
+    spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
+    spec((4, 2048, 8, 128), bf16))
+out["flash_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+lowered.compile()
+out["flash_s2048"] = "compiled"
+
+try:
+    jax.jit(flash_grads).lower(*[spec((1, 8192, 8, 128), bf16)] * 3).compile()
+    out["flash_s8192"] = "compiled"
+except Exception as e:  # noqa: BLE001 - the refusal is the finding
+    out["flash_s8192"] = str(e)[:300]
+
+cfg = llama.LlamaConfig.small_1b()
+params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
+eng = PagedInferenceEngine(params, cfg, max_batch=8, max_len=1024,
+                           block_size=64)
+b, i32 = eng.max_batch, jnp.int32
+eng._decode.lower(
+    on_chip(params), on_chip(eng.pool), spec((b, 1), i32),
+    spec((b, eng.max_blocks_per_seq), i32), spec((b,), i32),
+    spec((b,), i32), spec((b,), jnp.bool_), spec((2,), jnp.uint32),
+    spec((), i32), spec((), i32), max_steps=16).compile()
+out["paged_decode"] = "compiled"
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=REPO_ROOT + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    (line,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("RESULT ")]
+    return json.loads(line[len("RESULT "):])
+
+
+def test_flash_fwd_bwd_compiles_for_v5e(compiled):
+    assert compiled["device_kind"] == "TPU v5 lite"
+    # forward, dq and dk/dv kernels
+    assert compiled["flash_custom_calls"] >= 3
+    assert compiled["flash_s2048"] == "compiled"
+
+
+def test_paged_decode_compiles_for_v5e(compiled):
+    assert compiled["paged_decode"] == "compiled"
+
+
+def test_flash_backward_refused_at_s8192(compiled):
+    """The limit, pinned: when a kernel PR tiles the backward pass over
+    the sequence this flips to "compiled" — update the docstring of
+    ops.flash_attention.flash_attention with it."""
+    assert "vmem" in compiled["flash_s8192"].lower(), compiled["flash_s8192"]

@@ -6,6 +6,8 @@ placement itself is TPU-first design — a STRICT_PACK TPU gang maps onto
 one slice (one ICI domain) and never straddles slices.
 """
 
+import pytest
+
 import ray_tpu
 from ray_tpu._private.accelerators import (
     apply_tpu_detection,
@@ -105,6 +107,153 @@ def test_detect_tpu_gce_metadata_probe(monkeypatch):
     monkeypatch.setattr(tpu_mod, "_gce_metadata",
                         lambda path, timeout=0.5: 1 / 0)
     assert detect_tpu({}, probe_gce=True).slice_name == "my-tpu-vm"
+
+
+def test_chip_count_comes_from_device_nodes(tmp_path):
+    """A plain TPU host (no GKE variables, no network) still advertises
+    its chips; and where variables describe the slice TYPE, the device
+    nodes say what THIS host holds (the one-chip v5e machine exports
+    2,2,1 bounds)."""
+    from ray_tpu._private.accelerators.tpu import count_local_chips
+
+    dev = tmp_path / "dev"
+    (dev / "vfio").mkdir(parents=True)
+    assert count_local_chips(str(dev)) == 0
+    assert count_local_chips(str(tmp_path / "missing")) == 0
+    for name in ("vfio", "0", "1", "2", "3"):  # vfio/vfio: container node
+        (dev / "vfio" / name).touch()
+    assert count_local_chips(str(dev)) == 4
+    (dev / "accel0").touch()  # accel nodes win where the host has them
+    (dev / "accelerometer").touch()
+    assert count_local_chips(str(dev)) == 1
+    (dev / "accel0").unlink()
+
+    resources, labels = {}, {}
+    assert apply_tpu_detection(resources, labels, env={},
+                               dev_root=str(dev)) is None
+    assert resources == {"TPU": 4.0} and labels == {}
+
+    one = tmp_path / "one"
+    (one / "vfio").mkdir(parents=True)
+    (one / "vfio" / "1").touch()
+    resources = {}
+    info = apply_tpu_detection(
+        resources, {}, dev_root=str(one),
+        env={"TPU_ACCELERATOR_TYPE": "v5litepod-4",
+             "TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1", "TPU_WORKER_ID": "0"})
+    assert info.num_chips == 1
+    assert resources["TPU"] == 1.0 and resources["TPU-v5litepod-4"] == 1.0
+
+
+def test_metadata_probe_skipped_without_chips_and_bounded(monkeypatch,
+                                                           tmp_path):
+    import time
+
+    from ray_tpu._private.accelerators import tpu as tpu_mod
+
+    asked = []
+    monkeypatch.setattr(tpu_mod, "_GCE_PROBE_RESULT", ...)
+    monkeypatch.setattr(tpu_mod, "_gce_metadata",
+                        lambda path, timeout=0.5: asked.append(path))
+    apply_tpu_detection({}, {}, env={}, probe_gce=True,
+                        dev_root=str(tmp_path))
+    assert asked == []  # no device nodes: nothing to ask about
+    monkeypatch.undo()
+
+    # a lookup that never returns (no network: resolution can hang past
+    # any socket timeout) costs start-up a bounded wait
+    import urllib.request
+
+    monkeypatch.setattr(urllib.request, "urlopen",
+                        lambda *a, **kw: time.sleep(30))
+    t0 = time.monotonic()
+    assert tpu_mod._gce_metadata("instance/name", timeout=0.1) is None
+    assert time.monotonic() - t0 < 2.0
+
+
+def test_unknown_device_kind_has_no_peak():
+    from ray_tpu._private.accelerators.tpu import (
+        bf16_peak_flops_per_chip,
+        hbm_peak_bytes_per_sec,
+    )
+
+    assert bf16_peak_flops_per_chip("TPU v5 lite") == 197e12
+    assert hbm_peak_bytes_per_sec("TPU v5 lite") == 819e9
+    for kind in ("cpu", "TPU v9", ""):
+        with pytest.raises(ValueError, match="no published peak"):
+            bf16_peak_flops_per_chip(kind)
+        with pytest.raises(ValueError, match="no published peak"):
+            hbm_peak_bytes_per_sec(kind)
+
+
+def test_visible_chips_env():
+    from ray_tpu._private.accelerators.tpu import visible_chips_env
+
+    assert visible_chips_env((2,), 4) == {
+        "TPU_VISIBLE_CHIPS": "2", "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_HOST_BOUNDS": "1,1,1"}
+    assert visible_chips_env((2, 3), 4)["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,2,1"
+    # the whole host: libtpu's defaults stand
+    assert visible_chips_env((0, 1, 2, 3), 4) == {}
+    assert visible_chips_env((0,), 1) == {}
+    # other sub-host counts: the ids alone, as the reference does
+    assert visible_chips_env((0, 1, 2, 3), 8) == {
+        "TPU_VISIBLE_CHIPS": "0,1,2,3"}
+
+
+def test_chip_ids_handed_out_and_returned_with_leases(ray_start_cluster):
+    """One process per chip: workers granted `TPU: 1` each see a different
+    chip; the id comes back only when the process is gone; and a worker
+    that ran with a chip is never pooled."""
+    import os
+    import time
+
+    raylet = ray_start_cluster.add_node(num_cpus=4, resources={"TPU": 4})
+    ray_start_cluster.connect()
+
+    def wait_free(want):
+        deadline = time.monotonic() + 10
+        while raylet._free_chips != want and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert raylet._free_chips == want
+
+    def who():
+        return (os.getpid(), os.environ.get("TPU_VISIBLE_CHIPS"),
+                os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS"))
+
+    @ray_tpu.remote(resources={"TPU": 1})
+    class Holder:
+        def who(self):
+            return who()
+
+    holders = [Holder.remote() for _ in range(4)]
+    seen = ray_tpu.get([h.who.remote() for h in holders])
+    assert sorted(chip for _, chip, _ in seen) == ["0", "1", "2", "3"]
+    assert {bounds for _, _, bounds in seen} == {"1,1,1"}
+    wait_free([])
+
+    ray_tpu.kill(holders[2])
+    freed = int(seen[2][1])
+    wait_free([freed])
+
+    task = ray_tpu.remote(resources={"TPU": 1})(who)
+    pid1, chip1, _ = ray_tpu.get(task.remote())
+    assert chip1 == str(freed)
+    wait_free([freed])  # released on the worker's death, not before
+    pid2, chip2, _ = ray_tpu.get(task.remote())
+    assert chip2 == str(freed)
+    assert pid2 != pid1, "a worker that held a chip went back to the pool"
+
+    for h in holders:
+        ray_tpu.kill(h)
+    wait_free([0, 1, 2, 3])
+    # the whole host: no confinement; plain workers: held to the CPU
+    whole = ray_tpu.remote(resources={"TPU": 4})(who)
+    assert ray_tpu.get(whole.remote())[1] is None
+    plain = ray_tpu.remote(lambda: os.environ.get("JAX_PLATFORMS"))
+    assert ray_tpu.get(plain.remote()) == "cpu"
+    wait_free([0, 1, 2, 3])
+    ray_tpu.shutdown()
 
 
 def test_garbled_worker_id_degrades_not_crashes():

@@ -35,13 +35,7 @@
 #                        rule (compressed windows) and RESOLVE after
 #                        the burst, with alert.firing/alert.resolved
 #                        in the cluster event log and a live scorecard
-#  10. perf gate       — tools/perf_gate.py --smoke: the newest bench
-#                        trajectory row vs its history, per-metric
-#                        noise-banded thresholds (loose smoke bands on
-#                        this shared CI host; run WITHOUT --smoke on a
-#                        quiet dedicated host for the strict bands that
-#                        catch r05-class drifts)
-#  11. tier-1 tests    — the full `not slow` suite
+#  10. tier-1 tests    — the full `not slow` suite
 #
 # Usage: tools/ci.sh [--skip-tests]
 set -euo pipefail
@@ -83,9 +77,6 @@ JAX_PLATFORMS=cpu python -m tools.memory_smoke --budget 120
 
 echo "== health smoke (bounded) =="
 JAX_PLATFORMS=cpu python -m tools.health_smoke --budget 120
-
-echo "== perf-regression gate (smoke bands) =="
-python -m tools.perf_gate --smoke
 
 if [[ "${1:-}" != "--skip-tests" ]]; then
     echo "== tier-1 tests =="

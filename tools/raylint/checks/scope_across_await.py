@@ -1,11 +1,10 @@
 """RTL011 scope-across-await.
 
 Invariant (PR 11's rule, now mechanized): loop-thread ambient scopes
-must not leak across awaits. ``trace_scope(ctx)``,
-``ambient_deadline(d)`` and ``forced_host_device_count(n)`` install
-THREAD-scoped state (threading.local / env mutation) — on an event
-loop, every task interleaved at an ``await`` inside the ``with`` body
-runs with this request's context: its task specs get stamped with the
+must not leak across awaits. ``trace_scope(ctx)`` and
+``ambient_deadline(d)`` install THREAD-scoped state (threading.local)
+— on an event loop, every task interleaved at an ``await`` inside the
+``with`` body runs with this request's context: its task specs get stamped with the
 wrong trace parent and the wrong deadline, the exact leak class PR 11
 documented in the serve proxy (which now deliberately wraps only the
 synchronous submission window).
@@ -43,7 +42,6 @@ DEFAULT_SCOPE_PATHS = ["ray_tpu/"]
 DEFAULT_AMBIENT_SCOPES = [
     "trace_scope",
     "ambient_deadline",
-    "forced_host_device_count",
 ]
 
 
