@@ -133,6 +133,7 @@ def train_headline() -> dict:
     from ray_tpu._private.device_profiler import (
         get_profiler,
         install_compile_listener,
+        span,
     )
     from ray_tpu.models import llama
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -204,28 +205,28 @@ def train_headline() -> dict:
     flops_tok = llama.flops_per_token(cfg, seq)
     mfu = flops_tok * tokens_per_sec_per_chip / peak_flops
 
-    # Phase attribution of the train step (ISSUE 15): a short PROFILED
-    # segment after the headline timing — fenced per phase, so the detail
-    # says whether the step is input-starved (input_wait/h2d) or
+    # Phase attribution of the train step: a short segment after the
+    # headline timing, each phase a span that ends at its fence, so the
+    # detail says whether the step is input-starved (input_wait/h2d) or
     # device-bound (device_execute), and how much of this process's wall
-    # went to XLA compiles. The headline loop above stays unprofiled.
-    prof = get_profiler(
-        "train", flops_per_step=flops_tok * tokens_per_step,
-        peak_flops_per_chip=peak_flops, n_devices=n_devices)
+    # went to XLA compiles. The headline loop above stays as it is.
+    prof = get_profiler("train")
     host_inputs = np.asarray(toks[:, :-1])
     host_targets = np.asarray(toks[:, 1:])
     for _ in range(5):
-        with prof.step(tokens=tokens_per_step) as sp:
-            with sp.phase("input_wait"):
-                # host-side batch production (the input pipeline's share)
-                hb = {"inputs": np.array(host_inputs),
-                      "targets": np.array(host_targets)}
-            with sp.phase("h2d") as ph:
-                b2 = {k: jax.device_put(v, bs) for k, v in hb.items()}
-                ph.fence(b2)
-            with sp.phase("device_execute"):
-                state, m2 = step(state, b2)
-                float(m2["loss"])  # the fence
+        with span("bench.input") as s_in:
+            # host-side batch production (the input pipeline's share)
+            hb = {"inputs": np.array(host_inputs),
+                  "targets": np.array(host_targets)}
+        with span("bench.h2d") as s_h2d:
+            b2 = {k: jax.device_put(v, bs) for k, v in hb.items()}
+            jax.block_until_ready(b2)
+        with span("bench.step") as s_dev:
+            state, m2 = step(state, b2)
+            float(m2["loss"])  # the fence
+        prof.record_step({"input_wait": s_in.seconds, "h2d": s_h2d.seconds,
+                          "device_execute": s_dev.seconds},
+                         tokens=tokens_per_step)
     phase_rep = prof.report(emit_event=False)
 
     detail = {

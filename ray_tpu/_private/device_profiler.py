@@ -1,56 +1,279 @@
-"""Device-plane step-phase profiler: attribute every training step and
-engine decode wave into fenced phases.
+"""The one way to time a region in a process that owns a chip, or in the
+driver that starts it: `span`, `count`, `record`, `snapshot`.
 
-The control plane has had stage breakdowns since PR 1 — the DEVICE plane
-(where the two flat ROADMAP curves live: single-chip MFU at 0.656 since
-BENCH_r02, decode at 85% of the HBM roofline) had none: nothing said
-whether a step was input-starved, recompiling, or compute-bound. Podracer
-(PAPERS.md) frames TPU efficiency as exactly this attribution problem —
-keep the chip busy by measuring what it waits on.
+    with span("engine.decode_chunk", steps=16, rows=8) as sp:
+        ...                      # the region
+    sp.seconds                   # what it took, once it has ended
 
-One step decomposes into phases:
+A span does two things, and has no switch:
 
-  input_wait       host: blocked on the input pipeline (iterator next)
-  h2d              host->device transfer of the batch (device_put, fenced)
-  compile          XLA compilation observed DURING the step (via the
-                   jax.monitoring backend_compile listener; subtracted
-                   from the phase it fired inside of)
-  device_execute   the fenced device program (dispatch -> buffers ready)
-  reply            result delivery (host transfer of metrics / token
-                   chunks pushed to consumers)
+  * If jax is ALREADY imported in the process it is a
+    `jax.profiler.TraceAnnotation("rt." + name)`: under a running
+    `jax.profiler` trace the region lies on its thread's line of the
+    xplane's `/host:CPU`, on the same clock as the chip's `XLA Ops`, nested
+    as entered. With no trace running that is a no-op of the profiler's.
+    This module never imports jax to find out (a driver, and the
+    benchmark's parent, stay off jax: the chip belongs to the process they
+    start); it looks in `sys.modules`.
+  * It adds to a per-process aggregate `{name: count, total_s, max_s,
+    self_s}` from `time.perf_counter_ns` (self time is the duration less
+    what child spans on the same thread cover) and appends `(name, start,
+    end, parent, attrs)` to a ring of the last few thousand records.
 
-FENCING is the load-bearing part: jax dispatch is async, so a bare
-``perf_counter()`` delta around a jitted call measures dispatch (~µs) and
-silently attributes the real device time to whatever host code happens to
-block next. Every phase context fences with ``jax.block_until_ready`` on
-the value registered via ``fence()`` before stopping its clock (raylint
-RTL009 `unfenced-device-timing` enforces the same invariant tree-wide).
+No fence, no `block_until_ready`, no histogram, no registry lookup; each
+thread adds to a table of its own, so the only lock on the way is the
+ring's (a `deque`'s own). A span does not wait for the device: around a
+jitted call it times the dispatch, and a region that must cover device work
+ends at the host transfer that fetches its result (raylint RTL009 holds the
+tree to that).
 
-Exports, per profiler (train step / decode wave):
+`snapshot()` is what readers read (the benchmark's `span_readers.py`, after
+`ray_tpu.shutdown()`: nothing here is reset by it); `delta` subtracts two
+snapshots; `merge` grafts a snapshot taken in another process (rank 0 of a
+gang hands its start-up spans back on a return value the driver waits for
+anyway) under the span that is open on the calling thread.
 
-  ray_tpu_step_phase_seconds{phase,profiler}   histogram
-  ray_tpu_device_mfu{profiler}                 gauge (needs flops_per_step)
-  ray_tpu_hbm_bytes_in_use{device}             gauge (device.memory_stats)
-  ray_tpu_hbm_bytes_peak{device}               gauge
+Beside the spans, for a repeated device program (`DeviceStepProfiler`: the
+engine's decode wave) the per-step phase records behind `ray-tpu profile
+--device`, and the process's telemetry:
 
-plus ``compile.start`` / ``compile.end`` events into the event log so
-recompile storms show up in ``ray-tpu debug postmortem``, and per-step
-records behind ``report()`` — the payload `ray-tpu profile --device`
-fans out and merges with PR 1's task-stage spans into one chrome trace.
-
-Zero overhead when off: a disabled profiler's ``step()``/``phase()``
-return shared no-op contexts (one attribute check per call).
+  install_compile_listener / compile_stats   XLA backend compiles, counted
+      through `jax.monitoring`, with `compile.start` / `compile.end` in the
+      event log so that a recompile storm shows in `ray-tpu debug
+      postmortem`
+  hbm_stats   `device.memory_stats()` per device, exported as the gauges
+      ray_tpu_hbm_bytes_{in_use,peak}{device} when it is asked (by
+      `report()`, `snapshot_all()`, `engine.stats()`: never from a service
+      loop)
+  observe_phase   ray_tpu_step_phase_seconds{phase,profiler}, which the
+      input pipeline (data/dataset.py) feeds from its consumer
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
 PHASES = ("input_wait", "h2d", "compile", "device_execute", "reply")
+
+# -- spans -------------------------------------------------------------------
+
+RING_RECORDS = 8192
+TRACE_PREFIX = "rt."
+# perf_counter_ns -> ns since the epoch, for the records `snapshot` hands out
+_EPOCH_NS = time.time_ns() - time.perf_counter_ns()
+
+_span_lock = threading.Lock()   # the list of per-thread tables, not the path
+# (thread, spans, counters) of every live thread that has timed something
+_tables: List[tuple] = []
+# what threads that have ended left behind: [{spans}, {counters}]
+_retired: List[dict] = [{}, {}]
+_ring: deque = deque(maxlen=RING_RECORDS)
+_TraceAnnotation = None
+
+
+def _fold(spans: dict, counters: dict, into_spans: dict,
+          into_counters: dict) -> None:
+    for name, (n, total, longest, own) in list(spans.items()):
+        a = into_spans.get(name)
+        if a is None:
+            into_spans[name] = [n, total, longest, own]
+        else:
+            a[0] += n
+            a[1] += total
+            a[2] = max(a[2], longest)
+            a[3] += own
+    for name, n in list(counters.items()):
+        into_counters[name] = into_counters.get(name, 0) + n
+
+
+class _Table(threading.local):
+    """One thread's aggregate: nothing on the path of a span is shared."""
+
+    def __init__(self):
+        self.spans: Dict[str, list] = {}   # name -> [count, ns, max, self]
+        self.counters: Dict[str, int] = {}
+        self.top: Optional[_Span] = None
+        thread = threading.current_thread()
+        self.thread = thread.name
+        with _span_lock:
+            for entry in [e for e in _tables if not e[0].is_alive()]:
+                _tables.remove(entry)
+                _fold(entry[1], entry[2], *_retired)
+            _tables.append((thread, self.spans, self.counters))
+
+
+_local = _Table()
+
+
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation` once jax is in the process; never
+    the import that would put it there."""
+    global _TraceAnnotation
+    jax = sys.modules.get("jax")
+    cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if cls is not None:
+        _TraceAnnotation = cls
+    return cls
+
+
+def _add(table: _Table, name: str, ns: int, own: int) -> None:
+    a = table.spans.get(name)
+    if a is None:
+        table.spans[name] = [1, ns, ns, own]
+    else:
+        a[0] += 1
+        a[1] += ns
+        if ns > a[2]:
+            a[2] = ns
+        a[3] += own
+
+
+class _Span:
+    __slots__ = ("name", "attrs", "ns", "_t0", "_parent", "_child_ns",
+                 "_annotation")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.attrs = attrs
+        self.ns = 0
+
+    @property
+    def seconds(self) -> float:
+        return self.ns * 1e-9
+
+    def __enter__(self):
+        table = _local
+        self._parent = table.top
+        table.top = self
+        self._child_ns = 0
+        cls = _TraceAnnotation or _annotation_class()
+        if cls is None:
+            self._annotation = None
+        else:
+            self._annotation = cls(TRACE_PREFIX + self.name, **self.attrs)
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        self.ns = ns = t1 - self._t0
+        table, parent, name = _local, self._parent, self.name
+        # a generator closed on another thread, or out of order: the
+        # region still counts, the other thread's stack is left alone
+        if table.top is self:
+            table.top = parent
+            if parent is not None:
+                parent._child_ns += ns
+        _add(table, name, ns, max(0, ns - self._child_ns))
+        _ring.append((name, self._t0, t1,
+                      None if parent is None else parent.name,
+                      self.attrs, table.thread))
+        return False
+
+
+def span(name: str, **attrs) -> _Span:
+    """Context manager over one region; see the module's docstring."""
+    return _Span(name, attrs)
+
+
+now = time.perf_counter_ns   # the clock `record` takes its two ends from
+
+
+def record(name: str, start_ns: int, end_ns: int, ring: bool = True,
+           **attrs) -> None:
+    """A region whose ends were read with `now()` where they happened (a
+    request's life in the engine, from its caller's enqueue to its last
+    token): aggregate and ring, no annotation (the profiler takes none
+    after the fact). `ring=False` for a part of a region that leaves a
+    record of its own anyway (the request's queue wait)."""
+    table = _local
+    ns = max(0, end_ns - start_ns)
+    _add(table, name, ns, ns)
+    if ring:
+        _ring.append((name, start_ns, end_ns, None, attrs, table.thread))
+
+
+def count(name: str, n: int = 1) -> None:
+    counters = _local.counters
+    counters[name] = counters.get(name, 0) + n
+
+
+def snapshot(recent: int = 0) -> Dict[str, Any]:
+    """This process's aggregate, over every thread that timed something:
+    `spans` {name: {count, total_s, max_s, self_s}}, `counters` {name: n}
+    and, with `recent`, the ring's newest records oldest first (`start` and
+    `end` in seconds since the epoch)."""
+    spans: Dict[str, list] = {}
+    counters: Dict[str, int] = {}
+    with _span_lock:
+        _fold(*_retired, spans, counters)
+        tables = list(_tables)
+    for _thread, s, c in tables:
+        _fold(s, c, spans, counters)  # copies each table in one C call
+    out: Dict[str, Any] = {
+        "pid": os.getpid(),
+        "spans": {name: {"count": n, "total_s": total * 1e-9,
+                         "max_s": longest * 1e-9, "self_s": own * 1e-9}
+                  for name, (n, total, longest, own) in spans.items()},
+        "counters": counters,
+    }
+    if recent > 0:
+        out["recent"] = [
+            {"name": name, "start": (t0 + _EPOCH_NS) * 1e-9,
+             "end": (t1 + _EPOCH_NS) * 1e-9, "parent": parent,
+             "attrs": attrs, "thread": thread}
+            for name, t0, t1, parent, attrs, thread in list(_ring)[-recent:]]
+    return out
+
+
+def delta(after: Dict[str, Any], before: Dict[str, Any]) -> Dict[str, Any]:
+    """What happened between two snapshots of ONE process (`max_s` is the
+    later snapshot's: a maximum cannot be subtracted)."""
+    spans = {}
+    for name, a in after["spans"].items():
+        b = before["spans"].get(name)
+        if b is None:
+            spans[name] = dict(a)
+        elif a["count"] > b["count"]:
+            spans[name] = {"count": a["count"] - b["count"],
+                           "total_s": a["total_s"] - b["total_s"],
+                           "max_s": a["max_s"],
+                           "self_s": a["self_s"] - b["self_s"]}
+    counters = {name: n - before["counters"].get(name, 0)
+                for name, n in after["counters"].items()
+                if n != before["counters"].get(name, 0)}
+    return {"pid": after["pid"], "spans": spans, "counters": counters}
+
+
+def merge(other: Dict[str, Any]) -> None:
+    """Graft another process's snapshot (or delta) into this one under the
+    same names, as children of the span open on the calling thread: that
+    span's self time then leaves out what the other process accounted for
+    (the sum of its self times, which is what its spans cover)."""
+    table = _local
+    spans = {name: [a["count"], int(a["total_s"] * 1e9),
+                    int(a["max_s"] * 1e9), int(a["self_s"] * 1e9)]
+             for name, a in other.get("spans", {}).items()}
+    _fold(spans, other.get("counters", {}), table.spans, table.counters)
+    if table.top is not None:
+        table.top._child_ns += sum(a[3] for a in spans.values())
+
+
+# -- compile telemetry (jax.monitoring backend_compile listener) ------------
+
+_lock = threading.Lock()           # profiler registry
+_metrics_lock = threading.Lock()   # lazy metric creation
+_phase_hist = None
+_hbm_gauges = None
+_registry: Dict[str, "DeviceStepProfiler"] = {}
 
 # Device phases span ~100µs (one decode chunk) to minutes (a compile
 # storm); reuse the control-plane stage layout which covers that range.
@@ -59,15 +282,6 @@ _PHASE_BOUNDARIES = [
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0, 300.0,
 ]
 
-_lock = threading.Lock()           # profiler registry
-_metrics_lock = threading.Lock()   # lazy metric creation
-_phase_hist = None
-_mfu_gauge = None
-_hbm_gauges = None
-_registry: Dict[str, "DeviceStepProfiler"] = {}
-
-# -- compile telemetry (jax.monitoring backend_compile listener) ------------
-
 _compile_lock = threading.Lock()
 _compile_listener_installed = False
 _compile_seconds = 0.0
@@ -75,47 +289,6 @@ _compile_count = 0
 # jax.monitoring fires this once per XLA backend compilation (cache
 # misses only — cache hits never reach the backend).
 _COMPILE_EVENT_SUFFIX = "backend_compile_duration"
-
-
-def _metrics():
-    """Lazy per-process metric objects (importing this module must not
-    register metrics in processes that never profile). Locked: the data
-    feed thread (observe_phase) can race a profiler construction here."""
-    global _phase_hist, _mfu_gauge, _hbm_gauges
-    with _metrics_lock:
-        return _metrics_locked()
-
-
-def _metrics_locked():
-    global _phase_hist, _mfu_gauge, _hbm_gauges
-    if _phase_hist is None:
-        from ray_tpu.util.metrics import Gauge, get_metric, \
-            get_or_create_histogram
-
-        _phase_hist = get_or_create_histogram(
-            "ray_tpu_step_phase_seconds",
-            "Per-phase device-step latency (input_wait/h2d/compile/"
-            "device_execute/reply)",
-            boundaries=_PHASE_BOUNDARIES,
-            tag_keys=("phase", "profiler"),
-        )
-
-        def _gauge(name, desc, tags):
-            m = get_metric(name)
-            return m if m is not None else Gauge(name, desc, tag_keys=tags)
-
-        _mfu_gauge = _gauge(
-            "ray_tpu_device_mfu",
-            "Model FLOPs utilization of the profiled step (device_execute "
-            "time vs the per-chip peak-flops table)", ("profiler",))
-        _hbm_gauges = (
-            _gauge("ray_tpu_hbm_bytes_in_use",
-                   "Device memory in use (device.memory_stats)", ("device",)),
-            _gauge("ray_tpu_hbm_bytes_peak",
-                   "Peak device memory in use (device.memory_stats)",
-                   ("device",)),
-        )
-    return _phase_hist, _mfu_gauge, _hbm_gauges
 
 
 def _on_event_duration(event: str, duration: float, **attrs) -> None:
@@ -168,6 +341,28 @@ def compile_stats() -> Dict[str, float]:
 
 # -- HBM telemetry ----------------------------------------------------------
 
+def _gauges():
+    """Lazy per-process gauges (importing this module must not register
+    metrics in processes that never ask)."""
+    global _hbm_gauges
+    with _metrics_lock:
+        if _hbm_gauges is None:
+            from ray_tpu.util.metrics import Gauge, get_metric
+
+            def gauge(name, desc):
+                m = get_metric(name)
+                return m if m is not None else Gauge(
+                    name, desc, tag_keys=("device",))
+
+            _hbm_gauges = (
+                gauge("ray_tpu_hbm_bytes_in_use",
+                      "Device memory in use (device.memory_stats)"),
+                gauge("ray_tpu_hbm_bytes_peak",
+                      "Peak device memory in use (device.memory_stats)"),
+            )
+        return _hbm_gauges
+
+
 def hbm_stats(devices: Optional[List[Any]] = None,
               export: bool = True) -> Dict[str, Dict[str, int]]:
     """Per-device HBM occupancy from ``device.memory_stats()``, exported
@@ -183,7 +378,7 @@ def hbm_stats(devices: Optional[List[Any]] = None,
         except Exception:  # noqa: BLE001 — no backend reachable
             return {}
     out: Dict[str, Dict[str, int]] = {}
-    gauges = _metrics()[2] if export else None
+    gauges = _gauges() if export else None
     for d in devices:
         label = f"{getattr(d, 'platform', '?')}:{getattr(d, 'id', '?')}"
         try:
@@ -211,235 +406,86 @@ def hbm_stats(devices: Optional[List[Any]] = None,
 
 
 def observe_phase(phase: str, seconds: float, profiler: str = "data") -> None:
-    """Record one phase sample into the cluster-wide histogram without a
-    step scope — how the input pipeline (data/dataset.py) contributes
-    input_wait/h2d from its producer thread."""
-    _metrics()[0].observe(max(0.0, seconds),
-                          tags={"phase": phase, "profiler": profiler})
+    """Record one phase sample into the cluster-wide histogram
+    ray_tpu_step_phase_seconds — how the input pipeline (data/dataset.py)
+    contributes input_wait from its consumer."""
+    global _phase_hist
+    with _metrics_lock:
+        if _phase_hist is None:
+            from ray_tpu.util.metrics import get_or_create_histogram
+
+            _phase_hist = get_or_create_histogram(
+                "ray_tpu_step_phase_seconds",
+                "Per-phase device-step latency (the input pipeline's "
+                "input_wait)",
+                boundaries=_PHASE_BOUNDARIES,
+                tag_keys=("phase", "profiler"),
+            )
+    _phase_hist.observe(max(0.0, seconds),
+                        tags={"phase": phase, "profiler": profiler})
 
 
-def _block(value: Any) -> None:
-    """Fence: wait until every jax array in `value` is ready. Non-array
-    leaves pass through untouched (jax.block_until_ready's contract), so
-    host values are free to fence."""
-    import jax
-
-    jax.block_until_ready(value)
-
-
-# -- no-op fast path --------------------------------------------------------
-
-class _NoopPhase:
-    __slots__ = ()
-
-    def fence(self, value):
-        return value
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-class _NoopStep(_NoopPhase):
-    __slots__ = ()
-
-    def phase(self, name):  # noqa: ARG002 — signature parity
-        return _NOOP_PHASE
-
-    def external(self, name, seconds):
-        pass
-
-
-_NOOP_PHASE = _NoopPhase()
-_NOOP_STEP = _NoopStep()
-
-
-# -- the profiler -----------------------------------------------------------
-
-class _Phase:
-    """One timed, fenced phase inside a step scope."""
-
-    __slots__ = ("_scope", "_name", "_t0", "_fence")
-
-    def __init__(self, scope: "_StepScope", name: str):
-        self._scope = scope
-        self._name = name
-        self._fence = None
-
-    def fence(self, value):
-        """Register the value whose readiness ends this phase (pytrees
-        fine; non-array leaves ignored). Returns it for inline use."""
-        self._fence = value
-        return value
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        if exc_type is None and self._fence is not None:
-            _block(self._fence)
-        self._scope._record_phase(
-            self._name, time.perf_counter() - self._t0)
-        self._fence = None
-        return False
-
-
-class _StepScope:
-    """One step's phase accounting; created by DeviceStepProfiler.step()."""
-
-    __slots__ = ("_prof", "_phases", "_t0", "_wall0", "_compile0",
-                 "_tokens", "_lock")
-
-    def __init__(self, prof: "DeviceStepProfiler", tokens: Optional[int]):
-        self._prof = prof
-        self._phases: Dict[str, float] = {}
-        self._tokens = tokens
-        self._lock = threading.Lock()
-
-    def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
-
-    def external(self, name: str, seconds: float) -> None:
-        """Attribute host-measured seconds (e.g. the input pipeline's
-        consumer wait, measured by the iterator) to a phase of this step."""
-        self._record_phase(name, seconds)
-
-    def _record_phase(self, name: str, dur: float) -> None:
-        with self._lock:
-            self._phases[name] = self._phases.get(name, 0.0) + max(0.0, dur)
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        self._wall0 = time.time()
-        with _compile_lock:
-            self._compile0 = _compile_seconds
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        total = time.perf_counter() - self._t0
-        with _compile_lock:
-            compile_d = _compile_seconds - self._compile0
-        if exc_type is None:
-            self._prof._finish_step(
-                self._wall0, total, dict(self._phases), compile_d,
-                self._tokens)
-        return False
-
+# -- per-step phase records --------------------------------------------------
 
 class DeviceStepProfiler:
-    """Phase attribution for a repeated device program (train step /
-    decode wave). Thread-safe; one instance per logical step stream.
+    """Phase records of a repeated device program (the engine's decode
+    wave), one per step, from durations the caller read off its spans.
+    Thread-safe; one instance per logical step stream. Keeps totals and
+    the recent steps for `report()`; exports nothing per step."""
 
-    flops_per_step + peak_flops_per_chip make every profiled step export
-    a live MFU (the PR 7 per-chip flops tables feed peak_flops_per_chip:
-    accelerators.tpu.bf16_peak_flops_per_chip(device_kind))."""
-
-    def __init__(self, name: str, *,
-                 flops_per_step: Optional[float] = None,
-                 peak_flops_per_chip: Optional[float] = None,
-                 n_devices: int = 1,
-                 enabled: bool = True,
-                 max_steps: int = 1024,
-                 hbm_every: int = 0):
+    def __init__(self, name: str, *, max_steps: int = 1024):
         self.name = name
-        self.flops_per_step = flops_per_step
-        self.peak_flops_per_chip = peak_flops_per_chip
-        self.n_devices = max(1, n_devices)
-        self.enabled = enabled
-        self.hbm_every = hbm_every  # export HBM gauges every N steps (0=off)
         self._steps: deque = deque(maxlen=max_steps)
         self._totals: Dict[str, float] = {}
         self._n = 0
-        self._mfu_last: Optional[float] = None
         self._lock = threading.Lock()
-        # record_step compile attribution: compiles since this mark belong
-        # to the next recorded step (the scope path snapshots per step)
+        # compile attribution: compiles since this mark belong to the
+        # next recorded step
         with _compile_lock:
             self._compile_mark = _compile_seconds
-        if enabled:
-            install_compile_listener()
-            _metrics()
-
-    # the one per-step overhead when disabled: this attribute check
-    def step(self, tokens: Optional[int] = None):
-        if not self.enabled:
-            return _NOOP_STEP
-        return _StepScope(self, tokens)
+        install_compile_listener()
 
     def record_step(self, phases: Dict[str, float],
                     tokens: Optional[int] = None,
                     wall0: Optional[float] = None) -> None:
-        """Record one already-timed step (generator-shaped loops — the
-        engine's decode wave — can't wrap their body in a scope without
-        attributing consumer suspension time to a phase). The caller
-        fenced its own device phases (device_get / block_until_ready);
-        compile seconds since the previous record are carved out exactly
-        like the scoped path."""
-        if not self.enabled:
-            return
-        with _compile_lock:
-            now_c = _compile_seconds
-        with self._lock:
-            mark = self._compile_mark
-            self._compile_mark = now_c
-        compile_d = max(0.0, now_c - mark)
+        """Record one step whose phases the caller timed (`span(...)`'s
+        `.seconds`; a device phase ends at the host transfer that fetched
+        its result). Compile seconds since the previous record fired
+        inside one of those phases (almost always device_execute's first
+        call): they are carved out of it into a `compile` phase, so that
+        the steady-state phase does not wear the compile storm."""
+        phases = {k: max(0.0, v) for k, v in phases.items()}
         total = sum(phases.values())
-        self._finish_step(
-            wall0 if wall0 is not None else time.time() - total,
-            total, dict(phases), compile_d, tokens)
-
-    def _finish_step(self, wall0: float, total: float,
-                     phases: Dict[str, float], compile_d: float,
-                     tokens: Optional[int]) -> None:
-        hist, mfu_gauge, _ = _metrics()
-        if compile_d > 0:
-            # compile fired inside one of the fenced phases (almost
-            # always device_execute's first call); carve it out so the
-            # steady-state phase doesn't wear the compile storm
-            for carve in ("device_execute", "h2d"):
-                if phases.get(carve, 0.0) > 0:
-                    phases[carve] = max(0.0, phases[carve] - compile_d)
-                    break
-            phases["compile"] = phases.get("compile", 0.0) + compile_d
-        mfu = None
-        dev = phases.get("device_execute", 0.0)
-        if (self.flops_per_step and self.peak_flops_per_chip and dev > 0):
-            mfu = (self.flops_per_step / dev
-                   / (self.peak_flops_per_chip * self.n_devices))
-            mfu_gauge.set(mfu, tags={"profiler": self.name})
-        for ph, dur in phases.items():
-            hist.observe(dur, tags={"phase": ph, "profiler": self.name})
-        rec = {"time": wall0, "total": total, "phases": phases,
-               "mfu": mfu, "tokens": tokens}
+        now_c = _compile_seconds   # one read of a float: no lock needed
+        rec = {"time": wall0 if wall0 is not None else time.time() - total,
+               "total": total, "phases": phases, "tokens": tokens}
         with self._lock:
+            compile_d = now_c - self._compile_mark
+            self._compile_mark = now_c
+            if compile_d > 0:
+                for carve in ("device_execute", "h2d"):
+                    if phases.get(carve, 0.0) > 0:
+                        phases[carve] = max(0.0, phases[carve] - compile_d)
+                        break
+                phases["compile"] = phases.get("compile", 0.0) + compile_d
             self._steps.append(rec)
             self._n += 1
-            self._mfu_last = mfu if mfu is not None else self._mfu_last
             for ph, dur in phases.items():
                 self._totals[ph] = self._totals.get(ph, 0.0) + dur
-            n = self._n
-        if self.hbm_every and n % self.hbm_every == 0:
-            try:
-                hbm_stats()
-            except Exception:  # noqa: BLE001 — telemetry only
-                pass
 
     def report(self, recent: int = 64, emit_event: bool = True,
                include_hbm: bool = True) -> Dict[str, Any]:
         """Aggregate phase report: totals, fractions of accounted time
-        (input_wait_frac / device_frac / ...), compile seconds, MFU, HBM
-        occupancy, and the recent per-step records `ray-tpu profile
-        --device` renders into chrome-trace lanes. recent=0 means NO
-        per-step records; include_hbm=False skips the device sweep
-        (snapshot_all does ONE sweep for all profilers)."""
+        (input_wait_frac / device_frac / ...), compile seconds, HBM
+        occupancy (which sets the HBM gauges), and the recent per-step
+        records `ray-tpu profile --device` renders into chrome-trace
+        lanes. recent=0 means NO per-step records; include_hbm=False
+        skips the device sweep (snapshot_all does ONE sweep for all
+        profilers)."""
         with self._lock:
             totals = dict(self._totals)
             steps = self._n
             recent_steps = list(self._steps)[-recent:] if recent > 0 else []
-            mfu = self._mfu_last
         accounted = sum(totals.values()) or 1.0
         fracs = {f"{ph}_frac": round(totals.get(ph, 0.0) / accounted, 4)
                  for ph in PHASES}
@@ -451,7 +497,6 @@ class DeviceStepProfiler:
             "phase_seconds": {k: round(v, 6) for k, v in totals.items()},
             "accounted_s": round(accounted if totals else 0.0, 6),
             "compile_s": round(totals.get("compile", 0.0), 6),
-            "mfu": mfu,
             **fracs,
             "compile_process": compile_stats(),
             "hbm": hbm_stats() if include_hbm else {},
@@ -472,13 +517,12 @@ class DeviceStepProfiler:
             self._steps.clear()
             self._totals.clear()
             self._n = 0
-            self._mfu_last = None
 
 
 def get_profiler(name: str, **kwargs) -> DeviceStepProfiler:
     """Process-wide registry: the engine/train loop creates, the
     profile_device RPC snapshots. Construction kwargs only apply on first
-    creation; flops/peak updates go through the returned object."""
+    creation."""
     with _lock:
         prof = _registry.get(name)
         if prof is None:
@@ -487,15 +531,19 @@ def get_profiler(name: str, **kwargs) -> DeviceStepProfiler:
 
 
 def snapshot_all(recent: int = 64) -> Dict[str, Any]:
-    """Every registered profiler's report — the profile_device RPC body."""
+    """Every registered profiler's report and this process's spans — the
+    profile_device RPC body."""
     with _lock:
         profs = list(_registry.values())
+    spans = snapshot()
     return {
         "pid": os.getpid(),
         "compile": compile_stats(),
         # ONE device sweep for the whole snapshot (per-profiler reports
         # skip theirs — identical data K+1 times otherwise)
         "hbm": hbm_stats(),
+        "spans": spans["spans"],
+        "counters": spans["counters"],
         "profilers": {p.name: p.report(recent=recent, emit_event=False,
                                        include_hbm=False)
                       for p in profs},
@@ -517,7 +565,7 @@ def steps_to_spans(report: Dict[str, Any], proc: str) -> List[Dict[str, Any]]:
             "trace_id": None, "name": f"{name}.step",
             "proc": proc, "thread": f"device:{name}",
             "start": t0, "end": t0 + rec.get("total", 0.0),
-            "attrs": {"mfu": rec.get("mfu"), "tokens": rec.get("tokens")},
+            "attrs": {"tokens": rec.get("tokens")},
         })
         phases = rec.get("phases", {})
         # canonical phases first for stable ordering, then any custom

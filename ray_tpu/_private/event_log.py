@@ -56,6 +56,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ray_tpu._private.device_profiler import span
+
 # ---------------------------------------------------------------- schemas
 
 # type -> required data-field names. The contract the golden corpus pins:
@@ -381,11 +383,13 @@ def _flush_loop() -> None:
     while True:
         _flush_wake.wait(timeout=_config().event_log_flush_interval_s)
         _flush_wake.clear()
-        try:
-            _flush_once()
-        except Exception:  # noqa: BLE001 — the flusher must never die
-            pass
-        _update_gauges()
+        # on the trace, what this thread did beside a stalled step
+        with span("bg.event_flush"):
+            try:
+                _flush_once()
+            except Exception:  # noqa: BLE001 — the flusher must never die
+                pass
+            _update_gauges()
 
 
 def _flush_once(batch_size: int = 2000) -> None:

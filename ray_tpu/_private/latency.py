@@ -35,6 +35,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from ray_tpu._private.device_profiler import span
+
 STAGES = ("submit", "queue", "rpc", "dispatch", "execute", "reply")
 
 # Sub-millisecond buckets matter here: the whole control-plane budget is
@@ -145,10 +147,11 @@ def _drain_loop() -> None:
     while True:
         _drain_wake.wait(timeout=_DRAIN_INTERVAL_S)
         _drain_wake.clear()
-        try:
-            drain_pending()
-        except Exception:  # noqa: BLE001 — the drainer must never die
-            pass
+        with span("bg.latency_drain"):
+            try:
+                drain_pending()
+            except Exception:  # noqa: BLE001 — the drainer must never die
+                pass
 
 
 def drain_pending() -> None:

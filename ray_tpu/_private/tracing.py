@@ -49,6 +49,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu._private.device_profiler import span
+
 _W3C_VERSION = "00"
 
 # ------------------------------------------------------------ trace context
@@ -403,10 +405,11 @@ def _flush_loop() -> None:
     while True:
         _flush_wake.wait(timeout=_config().trace_flush_interval_s)
         _flush_wake.clear()
-        try:
-            _flush_once()
-        except Exception:  # noqa: BLE001 — the flusher must never die
-            pass
+        with span("bg.span_flush"):
+            try:
+                _flush_once()
+            except Exception:  # noqa: BLE001 — the flusher must never die
+                pass
 
 
 def _flush_once(batch_size: int = 2000) -> None:

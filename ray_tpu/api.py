@@ -18,6 +18,7 @@ from typing import Any, List, Optional, Sequence, Union
 
 from ray_tpu import exceptions as exc
 from ray_tpu._private.config import CONFIG
+from ray_tpu._private.device_profiler import span
 from ray_tpu._private.ids import ActorID
 from ray_tpu._raylet import ObjectRef, ObjectRefGenerator, get_core_worker, global_state
 from ray_tpu.actor import ActorClass, ActorHandle
@@ -105,7 +106,7 @@ def init(
     **_kwargs,
 ) -> RayContext:
     global _global_node
-    with _init_lock:
+    with _init_lock, span("cluster.init"):
         if global_state.core_worker is not None:
             if ignore_reinit_error:
                 cw = global_state.core_worker

@@ -596,24 +596,19 @@ class DashboardHead:
                         add(f"{label}_{tags.get('replica', '')[:24]}", v)
         except Exception:  # noqa: BLE001 — serving stack not up
             pass
-        # 1.55) device-plane performance (ISSUE 15): step-phase p50/p99
-        # per phase (input_wait/h2d/compile/device_execute/reply), live
-        # MFU, and HBM occupancy — the series that say whether the chip
-        # is input-starved, recompiling, or compute-bound.
+        # 1.55) device-plane performance: the input pipeline's
+        # input_wait p50/p99 and HBM occupancy.
         hist = get_metric("ray_tpu_step_phase_seconds")
         if hist is not None and hasattr(hist, "quantiles_by"):
             for phase, qs in hist.quantiles_by("phase").items():
                 for q, label in ((0.5, "p50"), (0.99, "p99")):
                     add(f"device_phase_{phase}_{label}", qs.get(q, 0.0))
-        for metric, label in (("ray_tpu_device_mfu", "device_mfu"),
-                              ("ray_tpu_hbm_bytes_in_use", "hbm_in_use"),
+        for metric, label in (("ray_tpu_hbm_bytes_in_use", "hbm_in_use"),
                               ("ray_tpu_hbm_bytes_peak", "hbm_peak")):
             g = get_metric(metric)
             if g is not None:
                 for _, tags, v in g._samples():
-                    tag = (tags.get("profiler") or tags.get("device")
-                           or "")[:24]
-                    add(f"{label}_{tag}", v)
+                    add(f"{label}_{(tags.get('device') or '')[:24]}", v)
         # 1.6) overload protection (ISSUE 9): cluster-wide shed and
         # doomed-work totals from the GCS event manager's per-type
         # counts (covers every process, not just this one's registry),

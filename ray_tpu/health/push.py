@@ -26,6 +26,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, Optional
 
+from ray_tpu._private.device_profiler import span
 from ray_tpu.util import metrics as um
 
 # sink(payload: dict) — ships one push_metrics payload (direct call for
@@ -147,10 +148,11 @@ def _push_loop() -> None:
     while True:
         _wake.wait(timeout=_config().health_push_interval_s)
         _wake.clear()
-        try:
-            _push_once()
-        except Exception:  # noqa: BLE001 — the pusher must never die
-            pass
+        with span("bg.metrics_push"):
+            try:
+                _push_once()
+            except Exception:  # noqa: BLE001 — the pusher must never die
+                pass
 
 
 def _push_once() -> None:

@@ -34,7 +34,6 @@ max_blocks_per_seq] operand.
 from __future__ import annotations
 
 import hashlib
-import time
 from collections import OrderedDict
 from functools import partial
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
@@ -43,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private.device_profiler import count, now, record, span
 from ray_tpu.inference.engine import GenerationConfig, _default_buckets
 from ray_tpu.inference.sampling import sample_token
 
@@ -122,14 +122,13 @@ class PagedInferenceEngine:
         }
         self._key = jax.random.PRNGKey(0)
         self.decode_chunk = max(1, decode_chunk)
-        # Device-plane phase attribution (ISSUE 15): every decode wave
-        # records input_wait / prefill / device_execute / reply into the
-        # shared "decode" profiler — `ray-tpu profile --device` fans these
-        # out, engine.stats() carries the aggregate, and HBM occupancy
-        # gauges refresh every few waves (memory_stats is a no-op on CPU).
+        # Every decode wave leaves one record of its phases (input_wait /
+        # prefill / device_execute / reply, read off the service loop's
+        # spans) in the shared "decode" profiler: `ray-tpu profile
+        # --device` fans these out, engine.stats() carries the aggregate.
         from ray_tpu._private.device_profiler import get_profiler
 
-        self.profiler = get_profiler("decode", hbm_every=8)
+        self.profiler = get_profiler("decode")
         self.preemptions = 0  # observability: recompute-preemption count
         self.peak_active = 0  # high-water mark of concurrently-decoding
         # requests — the ground-truth continuous-batching signal
@@ -435,7 +434,7 @@ class PagedInferenceEngine:
             "prefix_stats": dict(self.prefix_stats),
         }
 
-    def device_report(self) -> Dict[str, Any]:
+    def device_report(self, export: bool = False) -> Dict[str, Any]:
         """The device the weights live on, as jax reports it, with its
         memory counters. What a benchmark or smoke run must print instead
         of its driver's platform: the replica is the process that
@@ -448,7 +447,7 @@ class PagedInferenceEngine:
 
         dev = min(jax.tree.leaves(self.params)[0].devices(),
                   key=lambda d: d.id)
-        (memory,) = hbm_stats([dev], export=False).values()
+        (memory,) = hbm_stats([dev], export=export).values()
         return {"platform": dev.platform, "device_kind": dev.device_kind,
                 "id": dev.id,
                 "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
@@ -456,10 +455,12 @@ class PagedInferenceEngine:
 
     def stats(self) -> Dict[str, Any]:
         """Host-side engine occupancy snapshot (serving observability)."""
-        from ray_tpu._private.device_profiler import compile_stats
+        from ray_tpu._private.device_profiler import compile_stats, snapshot
 
+        timed = snapshot()
         return {
-            "device": self.device_report(),
+            # asking is what sets the HBM gauges: the service loop never does
+            "device": self.device_report(export=True),
             "param_bytes": sum(int(x.nbytes)
                                for x in jax.tree.leaves(self.params)),
             # XLA compiles this process has paid for (count, seconds);
@@ -478,7 +479,11 @@ class PagedInferenceEngine:
                 "cached_blocks": len(self.cached_lru),
                 "indexed_blocks": len(self.hash_index),
             },
-            # decode-wave phase attribution (ISSUE 15): is the engine
+            # this process's spans (engine.feed / admit > admit_wave / decode_chunk
+            # / fanout / request / queue_wait) and counters (decode.*)
+            "spans": timed["spans"],
+            "counters": timed["counters"],
+            # decode-wave phases, summed from those spans: is the engine
             # input-starved, recompiling, or device-bound?
             "device_phases": {
                 k: v for k, v in self.profiler.report(
@@ -502,7 +507,10 @@ class PagedInferenceEngine:
 
           * new: list of (req_id, prompt_tokens, max_new_tokens|None) —
             max_new defaults to gen.max_new_tokens. Admission order is
-            FIFO (preempted requests re-admit ahead of new arrivals).
+            FIFO (preempted requests re-admit ahead of new arrivals). A
+            fourth element, if given, is `device_profiler.now()` as the
+            caller read it when the request reached IT: the request's
+            queue wait then counts from there, not from this poll.
           * cancelled: req_ids to abort (consumer went away): their slots
             and blocks free immediately, nothing further is yielded.
           * stop: no more requests will ever arrive; the loop drains and
@@ -542,15 +550,43 @@ class PagedInferenceEngine:
         pending: List[Tuple[Any, List[int], List[int], int]] = []
         failed: List[Any] = []  # rejected at admission; yielded as aborts
         stopped = False
+        # req_id -> [enqueued, admitted, first token, preemptions] on
+        # `now()`'s clock: one `engine.request` record when it leaves
+        reqs: Dict[Any, list] = {}
+        # Seconds of the wave being built, read off the spans below:
+        # input_wait = blocked on feed, prefill = admission (waves, their
+        # bookkeeping and the first tokens' hand-off), device_execute =
+        # the decode dispatch up to the host transfer of its tokens,
+        # reply = the decoded tokens' fan-out to the consumer. One
+        # profiler record per decode dispatch, which carves compile
+        # seconds out of the phase they fell in: the span aggregate
+        # cannot, so `phase_seconds` is kept here and not derived from it.
+        phases = {"input_wait": 0.0, "prefill": 0.0, "reply": 0.0}
+
+        def leave(req_id, tokens: int, outcome: str = "ok") -> None:
+            r = reqs.pop(req_id, None)
+            if r is None:
+                return
+            enq, admitted, first, preempted = r
+            record("engine.request", enq, now(), req_id=req_id,
+                   admitted_s=None if admitted is None
+                   else (admitted - enq) * 1e-9,
+                   first_token_s=None if first is None
+                   else (first - enq) * 1e-9,
+                   tokens=tokens, preemptions=preempted, outcome=outcome)
 
         def poll(block: bool) -> None:
             nonlocal stopped
             if stopped:
                 return
-            new, cancelled, stop = feed(block)
+            with span("engine.feed", block=block) as sp:
+                new, cancelled, stop = feed(block)
+            if block:
+                phases["input_wait"] += sp.seconds
             stopped = bool(stop)
             for item in new or ():
-                req_id, prompt, max_new = item
+                req_id, prompt, max_new, *at = item
+                reqs[req_id] = [at[0] if at else now(), None, None, 0]
                 max_new = gen.max_new_tokens if max_new is None else max_new
                 prompt = list(prompt)
                 if not prompt:
@@ -569,13 +605,26 @@ class PagedInferenceEngine:
                 for i, item in enumerate(pending):
                     if item[0] == req_id:
                         del pending[i]
+                        leave(req_id, len(item[2]), "cancelled")
                         break
                 for slot, st in list(active.items()):
                     if st["req"] == req_id:
                         del active[slot]
                         self._release(slot)
+                        leave(req_id, len(st["emitted"]), "cancelled")
 
         def admit_all():
+            """`prefill` is ALL of admission, as it always was: prefix
+            matching, reservation, the waves, bookkeeping and the first
+            tokens' hand-off (`engine.admit`, with `engine.admit_wave`
+            and the first `engine.fanout` as its children)."""
+            if not (pending and self.free_slots):
+                return
+            with span("engine.admit") as sp:
+                yield from admit_waves()
+            phases["prefill"] += sp.seconds
+
+        def admit_waves():
             """Admit pending requests in tail-bucket-grouped waves:
             match each prompt against the prefix cache, reserve
             slot+blocks host-side for as many as fit, run the batched
@@ -611,43 +660,56 @@ class PagedInferenceEngine:
                 if not wave:
                     return
                 n = len(wave)
-                toks = np.zeros((n, bucket), np.int32)
-                true_lens = np.zeros((n,), np.int32)
-                offsets = np.zeros((n,), np.int32)
-                rows = np.zeros((n, self.max_blocks_per_seq), np.int32)
-                for i, (_, _, _, _, slot, prefix, m) in enumerate(wave):
-                    tail = prefix[m:]
-                    toks[i, :len(tail)] = tail
-                    true_lens[i] = len(tail)
-                    offsets[i] = m
-                    rows[i] = self.block_table[slot]
-                self._key, sub = jax.random.split(self._key)
-                try:
-                    if cow_pairs:
-                        # pad the pair list to a power of two so the copy
-                        # program compiles O(log) variants, not one per
-                        # count; scratch->scratch pads are no-ops
-                        n_cow = 1
-                        while n_cow < len(cow_pairs):
-                            n_cow *= 2
-                        src = [s for s, _ in cow_pairs]
-                        dst = [d for _, d in cow_pairs]
-                        src += [0] * (n_cow - len(cow_pairs))
-                        dst += [0] * (n_cow - len(cow_pairs))
-                        self.pool = self._copy_blocks(
-                            self.pool, jnp.asarray(src, jnp.int32),
-                            jnp.asarray(dst, jnp.int32))
-                    self.pool, firsts = self._prefill_batch(
-                        self.params, self.pool, jnp.asarray(toks),
-                        jnp.asarray(rows), jnp.asarray(true_lens),
-                        jnp.asarray(offsets), sub,
-                        temperature=gen.temperature, top_k=gen.top_k,
-                        top_p=gen.top_p)
-                    firsts = np.asarray(firsts)
-                except Exception:
-                    for _, _, _, _, slot, _, _ in wave:
-                        self._release(slot)
-                    raise
+                t_admit = now()
+                for req_id, *_ in wave:
+                    r = reqs.get(req_id)
+                    if r is not None and r[1] is None:  # not a re-admission
+                        r[1] = t_admit
+                        # the aggregate's mean and max; the interval
+                        # itself is `engine.request`'s `admitted_s`
+                        record("engine.queue_wait", r[0], t_admit,
+                               ring=False)
+                with span(
+                        "engine.admit_wave", rows=n, bucket=int(bucket),
+                        prompt_tokens=sum(len(w[5]) for w in wave),
+                        cached_tokens=int(sum(w[6] for w in wave))):
+                    toks = np.zeros((n, bucket), np.int32)
+                    true_lens = np.zeros((n,), np.int32)
+                    offsets = np.zeros((n,), np.int32)
+                    rows = np.zeros((n, self.max_blocks_per_seq), np.int32)
+                    for i, (_, _, _, _, slot, prefix, m) in enumerate(wave):
+                        tail = prefix[m:]
+                        toks[i, :len(tail)] = tail
+                        true_lens[i] = len(tail)
+                        offsets[i] = m
+                        rows[i] = self.block_table[slot]
+                    self._key, sub = jax.random.split(self._key)
+                    try:
+                        if cow_pairs:
+                            # pad the pair list to a power of two so the copy
+                            # program compiles O(log) variants, not one per
+                            # count; scratch->scratch pads are no-ops
+                            n_cow = 1
+                            while n_cow < len(cow_pairs):
+                                n_cow *= 2
+                            src = [s for s, _ in cow_pairs]
+                            dst = [d for _, d in cow_pairs]
+                            src += [0] * (n_cow - len(cow_pairs))
+                            dst += [0] * (n_cow - len(cow_pairs))
+                            self.pool = self._copy_blocks(
+                                self.pool, jnp.asarray(src, jnp.int32),
+                                jnp.asarray(dst, jnp.int32))
+                        self.pool, firsts = self._prefill_batch(
+                            self.params, self.pool, jnp.asarray(toks),
+                            jnp.asarray(rows), jnp.asarray(true_lens),
+                            jnp.asarray(offsets), sub,
+                            temperature=gen.temperature, top_k=gen.top_k,
+                            top_p=gen.top_p)
+                        firsts = np.asarray(firsts)
+                    except Exception:
+                        for _, _, _, _, slot, _, _ in wave:
+                            self._release(slot)
+                        raise
                 # Bookkeep the WHOLE wave (register/release every slot)
                 # before yielding anything: a consumer closing the
                 # generator at a yield must find each reserved slot
@@ -655,6 +717,7 @@ class PagedInferenceEngine:
                 # finally releases) — yielding mid-bookkeeping would
                 # leak the not-yet-registered slots forever.
                 first_tokens = []
+                t_first = now()
                 for (req_id, prompt, emitted, max_new, slot,
                      prefix, _m), first in zip(wave, firsts):
                     self.lengths[slot] = len(prefix)
@@ -674,30 +737,26 @@ class PagedInferenceEngine:
                             or self.lengths[slot] + 1 >= self.max_len)
                     if fresh:
                         first_tokens.append((req_id, tok, done))
+                        if req_id in reqs:
+                            reqs[req_id][2] = t_first
                     if done:
                         self._release(slot)
+                        leave(req_id, len(emitted))
                         continue
                     active[slot] = {"req": req_id, "prompt": prompt,
                                     "emitted": emitted, "current": tok,
                                     "max_new": max_new}
-                yield from first_tokens
+                with span("engine.fanout", tokens=len(first_tokens),
+                          first=True):
+                    yield from first_tokens
 
-        # per-wave phase accounting (ISSUE 15): input_wait = blocked on
-        # feed, prefill = admission waves (batched prefill + first-token
-        # handoff), device_execute = the fenced decode dispatch, reply =
-        # token fan-out to the consumer. Accumulates across the host-side
-        # bookkeeping of one wave, records one profiler step per dispatch.
-        phase_acc = {"input_wait": 0.0, "prefill": 0.0}
-
-        _t = time.perf_counter()
         poll(block=True)
-        phase_acc["input_wait"] += time.perf_counter() - _t
         while True:
             while failed:
-                yield failed.pop(), None, True
-            _t = time.perf_counter()
+                req_id = failed.pop()
+                leave(req_id, 0, "rejected")
+                yield req_id, None, True
             yield from admit_all()
-            phase_acc["prefill"] += time.perf_counter() - _t
             self.peak_active = max(self.peak_active, len(active))
             if not active:
                 if pending:
@@ -709,13 +768,12 @@ class PagedInferenceEngine:
                         f"paged pool too small for a {len(prompt)}-token "
                         f"prompt (n_blocks={self.n_blocks}); increase "
                         "n_blocks")
+                    leave(req_id, len(emitted), "aborted")
                     yield req_id, None, True
                     continue
                 if stopped:
                     return
-                _t = time.perf_counter()
                 poll(block=True)
-                phase_acc["input_wait"] += time.perf_counter() - _t
                 continue
             # grow every active slot to cover the next chunk; preempt the
             # youngest request (fewest emitted tokens) until it fits.
@@ -762,11 +820,14 @@ class PagedInferenceEngine:
                     self.abort_reasons[st["req"]] = (
                         "paged pool exhausted by a single request; "
                         "increase n_blocks or lower max_new_tokens")
+                    leave(st["req"], len(st["emitted"]), "aborted")
                     yield st["req"], None, True
                     break
                 victim = min(active, key=lambda s: len(active[s]["emitted"]))
                 st = active.pop(victim)
                 self.preemptions += 1
+                if st["req"] in reqs:
+                    reqs[st["req"]][3] += 1
                 pending.append((st["req"], st["prompt"], st["emitted"],
                                 st["max_new"]))
                 self._release(victim)
@@ -788,57 +849,63 @@ class PagedInferenceEngine:
                    if gen.eos_token_id is not None else -1)
             # n_steps is capped by the block capacity the host actually
             # reserved (`steps`), not just the remaining budget
-            _t = time.perf_counter()
-            self.pool, chunk, executed = self._decode(
-                self.params, self.pool, jnp.asarray(tokens), table,
-                lengths, jnp.asarray(budget), jnp.asarray(act), sub,
-                jnp.int32(steps), jnp.int32(eos), max_steps=steps,
-                temperature=gen.temperature,
-                top_k=gen.top_k, top_p=gen.top_p)
-            # the device_get IS the fence: the wave's device time ends
-            # when its tokens reach the host (RTL009's invariant)
-            chunk, executed = jax.device_get((chunk, executed))
-            phase_acc["device_execute"] = time.perf_counter() - _t
-            n_emitted = 0
-            _t = time.perf_counter()
+            with span("engine.decode_chunk", steps=steps,
+                      rows=len(active)) as sp:
+                self.pool, chunk, executed = self._decode(
+                    self.params, self.pool, jnp.asarray(tokens), table,
+                    lengths, jnp.asarray(budget), jnp.asarray(act), sub,
+                    jnp.int32(steps), jnp.int32(eos), max_steps=steps,
+                    temperature=gen.temperature,
+                    top_k=gen.top_k, top_p=gen.top_p)
+                # the device_get IS the fence: the wave's device time ends
+                # when its tokens reach the host (RTL009's invariant)
+                chunk, executed = jax.device_get((chunk, executed))
+            phases["device_execute"] = sp.seconds
+            executed = int(executed)
+            n_emitted = kv_attended = 0
             finished = []
-            for step in range(int(executed)):
-                if not active:
-                    break
-                for slot in list(active):
-                    st = active[slot]
-                    self.lengths[slot] += 1
-                    # the KV just written belongs to the step's INPUT
-                    # token (the previous current) — track it so release
-                    # can promote full blocks into the prefix cache
-                    self.slot_tokens[slot].append(st["current"])
-                    token = int(chunk[step, slot])
-                    st["emitted"].append(token)
-                    st["current"] = token
-                    done = ((gen.eos_token_id is not None
-                             and token == gen.eos_token_id)
-                            or len(st["emitted"]) >= st["max_new"]
-                            or self.lengths[slot] + 1 >= self.max_len)
-                    n_emitted += 1
-                    yield st["req"], token, done
-                    if done:
-                        del active[slot]
-                        finished.append(slot)
-            for slot in finished:
-                self._release(slot)
-            # reply covers token fan-out INCLUDING consumer handoff (the
-            # generator suspends at each yield): a slow consumer shows up
-            # here, not hidden inside device time
-            phase_acc["reply"] = time.perf_counter() - _t
+            # fan-out INCLUDES the consumer's handoff (the generator
+            # suspends at each yield): a slow consumer shows up here,
+            # not hidden inside device time
+            with span("engine.fanout", first=False) as sp:
+                for step in range(executed):
+                    if not active:
+                        break
+                    for slot in list(active):
+                        st = active[slot]
+                        self.lengths[slot] += 1
+                        # the row attended to every token it now holds
+                        kv_attended += int(self.lengths[slot])
+                        # the KV just written belongs to the step's INPUT
+                        # token (the previous current) — track it so release
+                        # can promote full blocks into the prefix cache
+                        self.slot_tokens[slot].append(st["current"])
+                        token = int(chunk[step, slot])
+                        st["emitted"].append(token)
+                        st["current"] = token
+                        done = ((gen.eos_token_id is not None
+                                 and token == gen.eos_token_id)
+                                or len(st["emitted"]) >= st["max_new"]
+                                or self.lengths[slot] + 1 >= self.max_len)
+                        n_emitted += 1
+                        yield st["req"], token, done
+                        if done:
+                            del active[slot]
+                            finished.append(slot)
+                            leave(st["req"], len(st["emitted"]))
+                for slot in finished:
+                    self._release(slot)
+            phases["reply"] += sp.seconds
+            count("decode.row_steps_active", n_emitted)
+            count("decode.row_steps_capacity", executed * self.max_batch)
+            count("decode.kv_tokens_attended", kv_attended)
             self.profiler.record_step(
-                {k: v for k, v in phase_acc.items() if v > 0},
+                {k: v for k, v in phases.items() if v > 0},
                 tokens=n_emitted)
-            phase_acc = {"input_wait": 0.0, "prefill": 0.0}
+            phases.update(input_wait=0.0, prefill=0.0, reply=0.0)
             poll(block=False)
             if finished or (pending and self.free_slots):
-                _t = time.perf_counter()
                 yield from admit_all()
-                phase_acc["prefill"] += time.perf_counter() - _t
 
     def generate_stream(
         self,

@@ -1119,20 +1119,17 @@ def _cmd_profile_device(args) -> int:
     else:
         hdr = (f"{'proc':<16} {'profiler':<12} {'steps':>6} "
                f"{'input_wait':>10} {'h2d':>7} {'compile_s':>9} "
-               f"{'device':>7} {'reply':>7} {'mfu':>7}")
+               f"{'device':>7} {'reply':>7}")
         print(hdr)
         print("-" * len(hdr))
         for proc, rep in reports:
-            mfu = rep.get("mfu")
-            mfu_s = "-" if mfu is None else f"{mfu:.4f}"
             print(f"{proc:<16} {rep.get('profiler', '?'):<12} "
                   f"{rep.get('steps', 0):>6} "
                   f"{rep.get('input_wait_frac', 0.0):>10.3f} "
                   f"{rep.get('h2d_frac', 0.0):>7.3f} "
                   f"{rep.get('compile_s', 0.0):>9.3f} "
                   f"{rep.get('device_execute_frac', 0.0):>7.3f} "
-                  f"{rep.get('reply_frac', 0.0):>7.3f} "
-                  f"{mfu_s:>7}")
+                  f"{rep.get('reply_frac', 0.0):>7.3f}")
     if args.chrome:
         from ray_tpu._private import tracing as _tracing
         from ray_tpu.util.state.api import list_tasks

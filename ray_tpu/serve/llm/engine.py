@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import CONFIG
+from ray_tpu._private.device_profiler import now as _now_ns
 from ray_tpu.serve.llm import metrics as llm_metrics
 
 logger = logging.getLogger(__name__)
@@ -70,8 +71,8 @@ class _Abort:
 
 class _Request:
     __slots__ = ("req_id", "prompt", "max_new", "gen_override", "out",
-                 "enqueued_at", "first_at", "last_at", "n_tokens",
-                 "cancelled")
+                 "enqueued_at", "enqueued_ns", "first_at", "last_at",
+                 "n_tokens", "cancelled")
 
     def __init__(self, req_id: int, prompt: List[int], max_new: int,
                  gen_override: Optional[GenerationConfig] = None):
@@ -83,6 +84,9 @@ class _Request:
         # per generated token, consumer-drained)
         self.out: "queue.SimpleQueue" = queue.SimpleQueue()  # raylint: disable=unbounded-queue
         self.enqueued_at = time.monotonic()
+        # the same instant on the spans' clock: the engine counts the
+        # request's queue wait from here, not from the poll that found it
+        self.enqueued_ns = _now_ns()
         self.first_at: Optional[float] = None
         self.last_at: Optional[float] = None
         self.n_tokens = 0
@@ -129,6 +133,24 @@ class LLMEngineReplica:
                           "replica": ctx.replica_tag}
         except RuntimeError:  # constructed outside serve (tests, bench)
             self._tags = {"deployment": "llm", "replica": "local"}
+        # Metric handles and tag dicts, taken once: the service loop makes
+        # no registry lookup and builds no tag dict per token or per poll.
+        self._m_requests = llm_metrics.requests_counter()
+        self._m_tokens = llm_metrics.tokens_counter()
+        self._m_ttft = llm_metrics.ttft_histogram()
+        self._m_tpot = llm_metrics.tpot_histogram()
+        self._m_queue_depth = llm_metrics.queue_depth_gauge()
+        self._m_occupancy = llm_metrics.occupancy_gauge()
+        self._m_preemptions = llm_metrics.preemptions_counter()
+        self._m_prefix = [
+            (llm_metrics.prefix_cache_counter(name), key)
+            for name, (_d, key) in llm_metrics.PREFIX_CACHE_COUNTERS.items()]
+        self._outcome_tags = {
+            o: {**self._tags, "outcome": o}
+            for o in ("ok", "error", "shed", "cancelled")}
+        # tokens delivered since the token counter was last bumped: once
+        # per chunk (at the next feed) and at a request's end, not per token
+        self._tokens_uncounted = 0
         self._thread = threading.Thread(
             target=self._run, name="llm-batcher", daemon=True)
         self._thread.start()
@@ -150,8 +172,7 @@ class LLMEngineReplica:
         if self._shutdown.is_set():
             raise RuntimeError("replica is shutting down")
         if self._backlog() >= self._max_queue_depth:
-            llm_metrics.requests_counter().inc(
-                tags={**self._tags, "outcome": "shed"})
+            self._m_requests.inc(tags=self._outcome_tags["shed"])
             ambient = _tracing.current_trace()
             if ambient is not None:
                 _tracing.force_trace(ambient.trace_id, "llm_shed:engine")
@@ -175,8 +196,7 @@ class LLMEngineReplica:
                     # wave path checks rq.cancelled directly (adding here
                     # would grow the set forever)
                     self._cancels.add(rq.req_id)
-                llm_metrics.requests_counter().inc(
-                    tags={**self._tags, "outcome": "cancelled"})
+                self._m_requests.inc(tags=self._outcome_tags["cancelled"])
 
     def generate_stream(self, prompt: List[int],
                         max_new_tokens: Optional[int] = None):
@@ -352,28 +372,31 @@ class LLMEngineReplica:
                 self._requests.pop(rq.req_id, None)
             rq.out.put(e)
 
+    def _count_tokens(self) -> None:
+        if self._tokens_uncounted:
+            self._m_tokens.inc(self._tokens_uncounted, tags=self._tags)
+            self._tokens_uncounted = 0
+
     def _update_gauges(self) -> None:
-        llm_metrics.queue_depth_gauge().set(
-            self._backlog(), tags=self._tags)
+        self._count_tokens()
+        self._m_queue_depth.set(self._backlog(), tags=self._tags)
         eng = self.engine
-        llm_metrics.occupancy_gauge().set(
+        self._m_occupancy.set(
             (eng.max_batch - len(eng.free_slots)) / max(1, eng.max_batch),
             tags=self._tags)
         preempt = getattr(eng, "preemptions", 0)
         if preempt > self._seen_preemptions:
-            llm_metrics.preemptions_counter().inc(
+            self._m_preemptions.inc(
                 preempt - self._seen_preemptions, tags=self._tags)
             self._seen_preemptions = preempt
         prefix = getattr(eng, "prefix_stats", None)
         if prefix:
             # engine counters are cumulative; export only the delta
-            for name, (_d, key) in \
-                    llm_metrics.PREFIX_CACHE_COUNTERS.items():
+            for counter, key in self._m_prefix:
                 cur = prefix.get(key, 0)
                 seen = self._seen_prefix.get(key, 0)
                 if cur > seen:
-                    llm_metrics.prefix_cache_counter(name).inc(
-                        cur - seen, tags=self._tags)
+                    counter.inc(cur - seen, tags=self._tags)
                     self._seen_prefix[key] = cur
 
     def _feed(self, block: bool):
@@ -388,7 +411,7 @@ class LLMEngineReplica:
         with self._lock:
             cancelled, self._cancels = self._cancels, set()
         self._update_gauges()
-        return ([(rq.req_id, rq.prompt, rq.max_new)
+        return ([(rq.req_id, rq.prompt, rq.max_new, rq.enqueued_ns)
                  for rq in new if not rq.cancelled],
                 cancelled, self._shutdown.is_set())
 
@@ -406,8 +429,7 @@ class LLMEngineReplica:
             if rq is None or rq.cancelled:
                 return
             rq.out.put(_Abort(reason))
-            llm_metrics.requests_counter().inc(
-                tags={**self._tags, "outcome": "error"})
+            self._m_requests.inc(tags=self._outcome_tags["error"])
             with self._lock:
                 self._requests.pop(req_id, None)
             return
@@ -416,19 +438,19 @@ class LLMEngineReplica:
         now = time.monotonic()
         if rq.first_at is None:
             rq.first_at = now
-            llm_metrics.ttft_histogram().observe(
-                now - rq.enqueued_at, tags=self._tags)
+            self._m_ttft.observe(now - rq.enqueued_at, tags=self._tags)
         rq.n_tokens += 1
         rq.last_at = now
-        llm_metrics.tokens_counter().inc(tags=self._tags)
+        self._tokens_uncounted += 1
         rq.out.put(token)
         if done:
+            # a caller that saw its last token must find it counted
+            self._count_tokens()
             if rq.n_tokens >= 2:
-                llm_metrics.tpot_histogram().observe(
+                self._m_tpot.observe(
                     (rq.last_at - rq.first_at) / (rq.n_tokens - 1),
                     tags=self._tags)
-            llm_metrics.requests_counter().inc(
-                tags={**self._tags, "outcome": "ok"})
+            self._m_requests.inc(tags=self._outcome_tags["ok"])
             rq.out.put(_DONE)
             with self._lock:
                 self._requests.pop(req_id, None)
@@ -474,15 +496,15 @@ class LLMEngineReplica:
                         self._requests.pop(rq.req_id, None)
                 continue
             # stream exhausted: everything this wave produced is out
+            self._count_tokens()
             for rq in items:
                 with self._lock:
                     alive = self._requests.pop(rq.req_id, None)
                 if alive is not None and not rq.cancelled:
                     if rq.n_tokens >= 2:
-                        llm_metrics.tpot_histogram().observe(
+                        self._m_tpot.observe(
                             (rq.last_at - rq.first_at) / (rq.n_tokens - 1),
                             tags=self._tags)
-                    llm_metrics.requests_counter().inc(
-                        tags={**self._tags, "outcome": "ok"})
+                    self._m_requests.inc(tags=self._outcome_tags["ok"])
                     rq.out.put(_DONE)
                     self._n_finished += 1

@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import ray_tpu
 from ray_tpu import exceptions as exc
 from ray_tpu._private import tracing as _tracing
+from ray_tpu._private.device_profiler import count, snapshot
 from ray_tpu._private.rpc import ConnectionLost
 from ray_tpu.serve.llm import metrics as llm_metrics
 from ray_tpu.serve.llm.engine import (
@@ -221,6 +222,10 @@ class LLMRouter:
                 # an amortized size bound); each use slides the TTL
                 if hit is not None and hit[0] in by_id and hit[1] > now:
                     choice = (hit[0], by_id[hit[0]])
+                    # sent to the replica that served this session before,
+                    # so to the one whose prefix cache holds its turns
+                    count("router.prefix_hits")
+            count("router.choices")
             if choice is None:
                 if len(replicas) == 1:
                     choice = replicas[0]
@@ -424,6 +429,8 @@ class LLMRouter:
                 "sessions": len(self._sessions),
                 "shed_total": self._shed_total,
                 "shed_queue_depth": self._shed_queue_depth,
+                # router.choices / router.prefix_hits of this process
+                "counters": snapshot()["counters"],
             }
 
     def llm_metrics_snapshot(self) -> List[Dict]:

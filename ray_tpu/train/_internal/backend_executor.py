@@ -19,6 +19,7 @@ from collections import defaultdict
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private.device_profiler import span
 from ray_tpu._private.event_watch import EventCursor
 from ray_tpu.train._internal.storage import StorageContext
 from ray_tpu.train._internal.worker_group import WorkerGroup
@@ -125,7 +126,8 @@ class BackendExecutor:
         self.worker_group = WorkerGroup(
             self._num_workers, self._resources, self._strategy,
             bundles=self._bundles)
-        self.worker_group.start()
+        with span("train.gang.place", world=self._num_workers):
+            self.worker_group.start()
         try:
             self._backend.on_start(self.worker_group, self._backend_config)
         except Exception:
@@ -143,6 +145,22 @@ class BackendExecutor:
     ) -> None:
         wg = self.worker_group
         assert wg is not None, "start() must run first"
+        with span("train.gang.session", world=self._num_workers):
+            meta = self._init_sessions(
+                wg, storage, latest_checkpoint, experiment_name, trial_id)
+        with span("train.gang.launch", world=self._num_workers):
+            self._backend.on_training_start(wg, self._backend_config)
+            ray_tpu.get([
+                w.start_training.remote(train_fn, config)
+                for w in wg.workers
+            ])
+        self._preempt_watcher = _PreemptWatcher(
+            wg, [m["node_id"] for m in meta],
+            since=self._placement_started_at)
+        self._preempt_watcher.start()
+
+    def _init_sessions(self, wg, storage, latest_checkpoint,
+                       experiment_name, trial_id) -> List[dict]:
         # node_rank / local_rank derived from gang metadata, like the
         # reference's _create_rank_world_size_mappings.
         meta = wg.group_metadata()
@@ -173,14 +191,7 @@ class BackendExecutor:
                     ctx_kwargs, latest_checkpoint,
                     storage.next_checkpoint_index()))
         ray_tpu.get(init_refs)
-        self._backend.on_training_start(wg, self._backend_config)
-        ray_tpu.get([
-            w.start_training.remote(train_fn, config) for w in wg.workers
-        ])
-        self._preempt_watcher = _PreemptWatcher(
-            wg, [m["node_id"] for m in meta],
-            since=self._placement_started_at)
-        self._preempt_watcher.start()
+        return meta
 
     def get_next_results(self, timeout: float = 3600.0) -> Optional[List[dict]]:
         """One result per worker, or None when training completed everywhere.

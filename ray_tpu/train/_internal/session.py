@@ -18,6 +18,7 @@ import queue
 import threading
 from typing import Any, Dict, Optional
 
+from ray_tpu._private.device_profiler import span
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.context import TrainContext
 
@@ -145,7 +146,10 @@ def report(metrics: Dict[str, Any],
     if s is None:
         raise RuntimeError(
             "ray_tpu.train.report() called outside a training session")
-    s.report(metrics, checkpoint)
+    # the checkpoint's copy to trial storage and the wait for the driver
+    # to take the previous result both fall in here
+    with span("train.report", checkpoint=checkpoint is not None):
+        s.report(metrics, checkpoint)
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

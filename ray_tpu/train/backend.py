@@ -16,6 +16,8 @@ import dataclasses
 import logging
 from typing import Any, Dict, Optional
 
+from ray_tpu._private.device_profiler import delta, merge, snapshot, span
+
 logger = logging.getLogger(__name__)
 
 
@@ -95,17 +97,47 @@ def _init_jax_worker(platform: Optional[str], coordinator: Optional[str],
     if coordinator is not None:
         import jax
 
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=world_size,
-            process_id=rank,
-        )
+        with span("train.worker.distributed_init"):
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=world_size,
+                process_id=rank,
+            )
 
 
 def _worker_platform() -> str:
     import jax
 
-    return jax.devices()[0].platform
+    # Outside mesh mode this is the first call that brings the backend
+    # up (seconds on a chip); after a mesh rendezvous it is microseconds.
+    with span("train.worker.open_chip"):
+        return jax.devices()[0].platform
+
+
+def _spanned(fn, *args, **kwargs):
+    """In a gang worker: one round's call, and beside its result the
+    `train.worker.*` spans it left (not what the process's background
+    threads did meanwhile). The driver waits for the result anyway, so
+    the spans reach it with no round trip of their own."""
+    before = snapshot()
+    out = fn(*args, **kwargs)
+    left = delta(snapshot(), before)["spans"]
+    return out, {"spans": {name: agg for name, agg in left.items()
+                           if name.startswith("train.worker.")}}
+
+
+def _round(worker_group, name: str, fn, kwargs_of=lambda rank: {}) -> list:
+    """One round of remote calls, `fn(**kwargs_of(rank))` on every rank,
+    under the driver's span `name`; rank 0's worker-side spans are merged
+    under it, so its self time is what the workers did not account for."""
+    import ray_tpu
+
+    with span(name, world=worker_group.num_workers):
+        outs = ray_tpu.get([
+            worker.execute.remote(_spanned, fn, **kwargs_of(rank))
+            for rank, worker in enumerate(worker_group.workers)])
+        merge(outs[0][1])
+    return [out for out, _ in outs]
 
 
 class JaxBackend(Backend):
@@ -132,14 +164,11 @@ class JaxBackend(Backend):
                 0, _find_free_port)
             coordinator = f"{meta[0]['hostname']}:{port}"
             logger.info("jax.distributed coordinator at %s", coordinator)
-        import ray_tpu
-
-        ray_tpu.get([
-            worker_group.workers[rank].execute.remote(
-                _init_jax_worker, backend_config.platform, coordinator,
-                world, rank, backend_config.env_vars)
-            for rank in range(world)
-        ])
+        _round(worker_group, "train.gang.backend_init", _init_jax_worker,
+               lambda rank: dict(
+                   platform=backend_config.platform, coordinator=coordinator,
+                   world_size=world, rank=rank,
+                   env_vars=backend_config.env_vars))
         if mesh_mode:
             import uuid
 
@@ -147,15 +176,14 @@ class JaxBackend(Backend):
 
             group = f"rt_train_mesh:{uuid.uuid4().hex[:8]}"
             self._mesh_group = group
-            shapes = ray_tpu.get([
-                worker_group.workers[rank].execute.remote(
-                    setup_worker_mesh, backend_config.mesh_config,
+            shapes = _round(
+                worker_group, "train.gang.mesh", setup_worker_mesh,
+                lambda rank: dict(
+                    mesh_config=backend_config.mesh_config,
                     group_name=group, world_size=world, rank=rank,
                     distributed=backend_config.distributed,
                     num_slices=backend_config.num_slices,
-                    coordinator_port=backend_config.coordinator_port)
-                for rank in range(world)
-            ])
+                    coordinator_port=backend_config.coordinator_port))
             if len(set(map(str, shapes))) != 1:
                 raise RuntimeError(
                     f"gang workers disagree on mesh shape: {shapes}")
@@ -167,7 +195,8 @@ class JaxBackend(Backend):
         # device's name.
         expected = backend_config.platform or (
             "tpu" if worker_group.demands_tpu else None)
-        platforms = worker_group.execute(_worker_platform)
+        platforms = _round(worker_group, "train.gang.platform_check",
+                           _worker_platform)
         if expected and any(p != expected for p in platforms):
             raise RuntimeError(
                 f"gang asked for platform {expected!r} but its workers "

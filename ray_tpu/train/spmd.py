@@ -24,6 +24,8 @@ import logging
 import threading
 from typing import Any, Dict, Optional
 
+from ray_tpu._private.device_profiler import span
+
 logger = logging.getLogger(__name__)
 
 _state_lock = threading.Lock()
@@ -59,9 +61,24 @@ def setup_worker_mesh(mesh_config, *, group_name: str, world_size: int,
     if not col.is_group_initialized(group_name):
         col.init_collective_group(ws, rk, backend="mesh",
                                   group_name=group_name, mesh_axes=mesh_axes)
-    mesh = col.bootstrap_mesh(mesh_config, group_name=group_name,
-                              num_slices=num_slices,
-                              coordinator_port=coordinator_port)
+    if ws == 1:
+        import jax
+
+        # In a process that has computed nothing yet this is the call that
+        # brings the backend up: 8-14 s where that opens a chip.
+        with span("train.worker.open_chip"):
+            devices = jax.devices()
+        with span("train.worker.mesh_build", devices=len(devices)):
+            mesh = col.bootstrap_mesh(mesh_config, group_name=group_name,
+                                      devices=devices, num_slices=num_slices)
+    else:
+        # jax.distributed.initialize has to precede the backend, and
+        # bootstrap_mesh does both behind its rendezvous: seen from here
+        # the backend comes up inside this one call
+        with span("train.worker.open_chip", world=ws):
+            mesh = col.bootstrap_mesh(mesh_config, group_name=group_name,
+                                      num_slices=num_slices,
+                                      coordinator_port=coordinator_port)
     with _state_lock:
         _state["mesh"] = mesh
         _state["group"] = group_name

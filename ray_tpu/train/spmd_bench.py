@@ -24,7 +24,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-import time
 from functools import partial
 from typing import Dict, List, Tuple
 
@@ -48,23 +47,22 @@ def _timed_steps(step, state, batch, steps: int,
                  profiler=None) -> Tuple[float, List[float]]:
     """Wall time per step + the loss trajectory. The fence is the host
     transfer of each step's loss (float()). With a DeviceStepProfiler each
-    step's device_execute phase (and any compile it triggers) is
-    attributed (ISSUE 15)."""
+    step leaves a record of its device_execute phase (and of any compile
+    it triggered)."""
+    from ray_tpu._private.device_profiler import span
+
     losses = []
     state, m = step(state, batch)  # warmup/compile
     losses.append(float(m["loss"]))
-    t0 = time.perf_counter()
-    for _ in range(steps):
-        if profiler is None:
-            state, m = step(state, batch)
-            losses.append(float(m["loss"]))
-        else:
-            with profiler.step() as sp:
-                with sp.phase("device_execute"):
-                    state, m = step(state, batch)
-                    # the float() host transfer is the fence
-                    losses.append(float(m["loss"]))
-    dt = (time.perf_counter() - t0) / steps
+    with span("spmd_bench.steps", steps=steps) as timed:
+        for _ in range(steps):
+            with span("spmd_bench.step") as sp:
+                state, m = step(state, batch)
+                # the float() host transfer is the fence
+                losses.append(float(m["loss"]))
+            if profiler is not None:
+                profiler.record_step({"device_execute": sp.seconds})
+    dt = timed.seconds / steps
     del state
     return dt, losses
 
@@ -120,18 +118,13 @@ def run(n_devices: int, steps: int = 8) -> dict:
              "targets": jax.device_put(toks[:, 1:], bs)}
         return _timed_steps(step, state, b, steps, profiler=profiler)
 
-    # Device-plane attribution of the MESH program (ISSUE 15): live MFU
-    # from the per-chip flops tables + compile seconds for the n-device
-    # compile, reported in detail and visible to `ray-tpu profile
-    # --device` via the registry.
+    # Phase records of the MESH program's steps, visible to `ray-tpu
+    # profile --device` via the registry.
     from ray_tpu._private.device_profiler import get_profiler
 
     flops_tok = llama.flops_per_token(cfg, seq)
     tokens_per_step = per_chip_batch * n_devices * seq
     prof_n = get_profiler("train_spmd")
-    prof_n.flops_per_step = flops_tok * tokens_per_step
-    prof_n.peak_flops_per_chip = peak_flops
-    prof_n.n_devices = n_devices
     prof_n.reset()
 
     # The same per-chip batch through both programs: first the single-chip
@@ -144,8 +137,6 @@ def run(n_devices: int, steps: int = 8) -> dict:
                              profiler=prof_n)
     compile_after = compile_stats()
 
-    # (tokens_per_step / flops_tok computed once above, shared with the
-    # profiler's flops_per_step so MFU and tokens/s can't desynchronize)
     per_chip_1 = per_chip_batch * seq / dt_1  # 1 device
     per_chip_n = tokens_per_step / dt_n / n_devices
 
@@ -169,18 +160,16 @@ def run(n_devices: int, steps: int = 8) -> dict:
         "loss_1dev": [round(x, 6) for x in losses_1],
         "loss_ndev": [round(x, 6) for x in losses_n],
     }
-    # fenced phase attribution of the mesh program (ISSUE 15): device
-    # fraction + live MFU from the profiled steady-state steps; compile
-    # seconds as a compile_stats() DELTA around the n-device measure —
-    # the big XLA compile fires in the unprofiled warmup call, so the
-    # per-step carve-out (steady-state recompiles) is ~0 by design
+    # phases of the mesh program's steady-state steps; compile seconds
+    # as a compile_stats() DELTA around the n-device measure — the big
+    # XLA compile fires in the unprofiled warmup call, so the per-step
+    # carve-out (steady-state recompiles) is ~0 by design
     rep = prof_n.report(emit_event=False)
     detail["step_phases_ndev"] = {
         "device_execute_frac": rep.get("device_execute_frac", 0.0),
         "compile_frac": rep.get("compile_frac", 0.0),
         "compile_s": round(
             compile_after["compile_s"] - compile_before["compile_s"], 3),
-        "mfu_live": rep.get("mfu"),
     }
     return {
         "metric": "train_multichip_tokens_per_sec_per_chip",

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private.device_profiler import span
 from ray_tpu.parallel.sharding import (
     LogicalAxisRules,
     logical_sharding,
@@ -146,7 +147,9 @@ def make_train_step(
     )
 
     def wrapped(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
-        out, metrics = jitted(_as_dict(state), batch)
+        # host time of the dispatch (jit returns before the device ends)
+        with span("train.step.dispatch"):
+            out, metrics = jitted(_as_dict(state), batch)
         return TrainState(**out), metrics
 
     wrapped.lower = lambda state, batch: jitted.lower(_as_dict(state), batch)

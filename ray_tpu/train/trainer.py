@@ -19,6 +19,7 @@ import time
 import uuid
 from typing import Any, Callable, Dict, Optional
 
+from ray_tpu._private.device_profiler import span
 from ray_tpu.air import Result, RunConfig, ScalingConfig
 from ray_tpu.train._internal.backend_executor import (
     BackendExecutor,
@@ -106,8 +107,9 @@ class DataParallelTrainer(BaseTrainer):
         preemptions = 0
         while True:
             try:
-                return self._run_attempt(storage, latest_checkpoint,
-                                         name, trial_id)
+                with span("train.fit", attempt=attempts + preemptions):
+                    return self._run_attempt(storage, latest_checkpoint,
+                                             name, trial_id)
             except TrainingWorkerError as e:
                 if getattr(e, "preempted", False):
                     # announced node loss: the gang checkpoint-drained on

@@ -53,11 +53,16 @@ def test_jax_backend_refuses_a_cpu_gang_that_asked_for_tpus(monkeypatch):
     """A use_tpu gang whose workers came up on another platform must not
     train on: on_start raises with what the workers reported."""
     import ray_tpu
-    from ray_tpu.train.backend import JaxBackend, JaxConfig
+    from ray_tpu.train.backend import (
+        JaxBackend,
+        JaxConfig,
+        _worker_platform,
+    )
 
     class _Method:
-        def remote(self, *a, **kw):
-            return None
+        def remote(self, _spanned, fn, **kw):
+            # what a round hands back: the call's result, the spans it left
+            return ("cpu" if fn is _worker_platform else None), {}
 
     class _Worker:
         execute = _Method()
@@ -66,9 +71,6 @@ def test_jax_backend_refuses_a_cpu_gang_that_asked_for_tpus(monkeypatch):
         num_workers = 1
         workers = [_Worker()]
         demands_tpu = True
-
-        def execute(self, fn):
-            return ["cpu"]
 
     monkeypatch.setattr(ray_tpu, "get", lambda refs, **kw: refs)
     with pytest.raises(RuntimeError, match=r"asked for platform 'tpu'.*cpu"):

@@ -180,6 +180,11 @@ def test_session_affinity_sticks_to_one_replica(llm_handle):
     hit = [rid for rid, c in delta.items() if c > 0]
     assert len(hit) == 1, f"session requests spread across {delta}"
     assert after["sessions"] >= 1
+    # the router's own counters: four choices, the last three of them sent
+    # to the replica that already holds the session's turns
+    moved = {k: after["counters"].get(k, 0) - before["counters"].get(k, 0)
+             for k in ("router.choices", "router.prefix_hits")}
+    assert moved == {"router.choices": 4, "router.prefix_hits": 3}
 
 
 def test_http_sse_stream(llm_handle):

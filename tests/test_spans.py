@@ -1,0 +1,403 @@
+"""The span layer (`_private/device_profiler.span`): the aggregate's
+arithmetic, the xplane under a running `jax.profiler` trace, and the
+places the spans go (trainer start-up, the engine's service loop, the
+replica's hot path)."""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import pytest
+
+from ray_tpu._private import device_profiler as dp
+from ray_tpu._private.device_profiler import count, record, span
+
+pytestmark = pytest.mark.profiling
+
+
+# ------------------------------------------------------------ the aggregate
+
+def test_aggregate_count_total_self_and_parent_under_nesting():
+    before = dp.snapshot()
+    for _ in range(2):
+        with span("t.outer", k=1) as outer:
+            with span("t.inner"):
+                time.sleep(0.01)
+            with span("t.inner") as inner:
+                time.sleep(0.01)
+            time.sleep(0.005)
+    got = dp.delta(dp.snapshot(), before)["spans"]
+    assert got["t.outer"]["count"] == 2 and got["t.inner"]["count"] == 4
+    # a parent's self time is its duration less what its children cover
+    assert got["t.outer"]["self_s"] == pytest.approx(
+        got["t.outer"]["total_s"] - got["t.inner"]["total_s"], abs=1e-9)
+    assert 0.008 <= got["t.outer"]["self_s"] <= got["t.outer"]["total_s"]
+    assert got["t.inner"]["self_s"] == got["t.inner"]["total_s"] >= 0.04
+    assert got["t.outer"]["max_s"] >= got["t.outer"]["total_s"] / 2
+    assert outer.seconds > inner.seconds >= 0.01
+    recent = dp.snapshot(recent=3)["recent"]
+    assert [(r["name"], r["parent"]) for r in recent] == [
+        ("t.inner", "t.outer"), ("t.inner", "t.outer"), ("t.outer", None)]
+    assert recent[-1]["attrs"] == {"k": 1}
+    assert recent[0]["start"] >= recent[-1]["start"]
+    assert recent[0]["end"] <= recent[-1]["end"]
+
+
+def test_aggregate_across_threads_and_after_they_end():
+    before = dp.snapshot()
+    seen = []
+
+    def work():
+        with span("t.threaded"):
+            with span("t.threaded.child"):
+                time.sleep(0.002)
+        count("t.events", 2)
+        seen.append(threading.current_thread().name)
+
+    with span("t.main"):
+        threads = [threading.Thread(target=work, name=f"t-span-{i}")
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    got = dp.delta(dp.snapshot(), before)
+    assert got["spans"]["t.threaded"]["count"] == 4
+    assert got["counters"]["t.events"] == 8
+    # another thread's span is no child of this thread's open span
+    assert got["spans"]["t.main"]["self_s"] == got["spans"]["t.main"]["total_s"]
+    by_thread = {r["thread"]: r["parent"]
+                 for r in dp.snapshot(recent=64)["recent"]
+                 if r["name"] == "t.threaded"}
+    assert set(seen) <= set(by_thread) and set(by_thread.values()) == {None}
+    # a thread that starts later retires the ended ones' tables: the
+    # totals stay
+    t = threading.Thread(target=work, name="t-span-late")
+    t.start()
+    t.join()
+    assert dp.delta(dp.snapshot(), before)["spans"]["t.threaded"][
+        "count"] == 5
+
+
+def test_record_counts_a_region_timed_elsewhere():
+    before = dp.snapshot()
+    t0 = dp.now()
+    record("t.request", t0, t0 + 3_000_000, req_id=7, tokens=5)
+    record("t.request", t0, t0 + 1_000_000, req_id=8, tokens=1)
+    got = dp.delta(dp.snapshot(), before)["spans"]["t.request"]
+    assert got["count"] == 2
+    assert got["total_s"] == pytest.approx(0.004)
+    assert got["max_s"] == pytest.approx(0.003)
+    last = dp.snapshot(recent=1)["recent"][0]
+    assert last["attrs"] == {"req_id": 8, "tokens": 1}
+    assert last["end"] - last["start"] == pytest.approx(0.001, abs=1e-6)
+
+
+def test_merge_grafts_another_process_under_the_open_span():
+    """What rank 0 of a gang hands back: merged under the same names, and
+    left out of the self time of the round the driver waited in."""
+    worker = {"pid": 1, "counters": {"t.w.events": 3}, "spans": {
+        "t.w.open": {"count": 1, "total_s": 0.03, "max_s": 0.03,
+                     "self_s": 0.03},
+        "t.w.build": {"count": 1, "total_s": 0.05, "max_s": 0.05,
+                      "self_s": 0.02}}}   # 0.03 of it was t.w.open
+    before = dp.snapshot()
+    with span("t.round"):
+        time.sleep(0.06)
+        dp.merge(worker)
+    got = dp.delta(dp.snapshot(), before)
+    assert got["spans"]["t.w.open"]["total_s"] == pytest.approx(0.03)
+    assert got["spans"]["t.w.build"]["self_s"] == pytest.approx(0.02)
+    assert got["counters"]["t.w.events"] == 3
+    rnd = got["spans"]["t.round"]
+    assert rnd["total_s"] >= 0.06
+    assert rnd["self_s"] == pytest.approx(rnd["total_s"] - 0.05, abs=1e-6)
+
+
+def test_delta_of_two_snapshots():
+    with span("t.delta"):
+        pass
+    before = dp.snapshot()
+    with span("t.delta"):
+        time.sleep(0.002)
+    count("t.delta.n")
+    got = dp.delta(dp.snapshot(), before)
+    assert got["spans"]["t.delta"]["count"] == 1
+    assert got["spans"]["t.delta"]["total_s"] >= 0.002
+    assert got["counters"] == {"t.delta.n": 1}
+    assert dp.delta(dp.snapshot(), dp.snapshot())["spans"] == {}
+
+
+def test_span_survives_an_exception_and_leaves_the_stack_clean():
+    before = dp.snapshot()
+    with pytest.raises(ValueError):
+        with span("t.raises"):
+            with span("t.raises.inner"):
+                raise ValueError("x")
+    with span("t.after"):
+        pass
+    got = dp.delta(dp.snapshot(), before)["spans"]
+    assert got["t.raises"]["count"] == got["t.raises.inner"]["count"] == 1
+    assert dp.snapshot(recent=1)["recent"][0]["parent"] is None
+
+
+def test_span_in_a_fresh_interpreter_leaves_jax_out():
+    code = (
+        "import sys\n"
+        "from ray_tpu._private.device_profiler import span, count, snapshot\n"
+        "with span('a', n=1):\n"
+        "    count('c')\n"
+        "s = snapshot(recent=1)\n"
+        "assert s['spans']['a']['count'] == 1 and s['counters'] == {'c': 1}\n"
+        "assert 'jax' not in sys.modules, 'span imported jax'\n"
+        "import ray_tpu.train, ray_tpu.serve.llm\n"
+        "assert 'jax' not in sys.modules, 'a driver-side import took jax'\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root)
+    done = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+def test_span_cost_with_no_trace_running():
+    """Enter + exit: microseconds, with jax in the process and no trace
+    (the annotation is then a no-op of the profiler's)."""
+    import jax  # noqa: F401 — the annotation path is the one measured
+
+    n = 20_000
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter_ns()
+        for _ in range(n):
+            with span("t.cost"):
+                pass
+        best = min(best, (time.perf_counter_ns() - t0) / n)
+    assert best < 20_000, f"{best:.0f} ns a span"
+
+
+# ------------------------------------------------- on the profiler's clock
+
+def test_xplane_holds_rt_events_nested_on_the_calling_thread(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    f = jax.jit(lambda x: x @ x)
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("x.outer", rows=3):
+            with span("x.inner"):
+                f(x).block_until_ready()
+
+        def other():
+            with span("x.other"):
+                time.sleep(0.001)
+
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    (host,) = [p for p in ProfileData.from_file(path).planes
+               if p.name == "/host:CPU"]
+    lines = {}
+    for i, line in enumerate(host.lines):
+        for e in line.events:
+            if e.name.startswith("rt.x."):
+                lines.setdefault(i, {})[e.name] = (
+                    e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+    by_name = {name: i for i, evs in lines.items() for name in evs}
+    assert set(by_name) == {"rt.x.outer", "rt.x.inner", "rt.x.other"}
+    # the calling thread's line holds both, nested as entered; the other
+    # thread's span lies on a line of its own
+    assert by_name["rt.x.outer"] == by_name["rt.x.inner"]
+    assert by_name["rt.x.other"] != by_name["rt.x.outer"]
+    o0, o1, stats = lines[by_name["rt.x.outer"]]["rt.x.outer"]
+    i0, i1, _ = lines[by_name["rt.x.inner"]]["rt.x.inner"]
+    assert o0 <= i0 and i1 <= o1
+    assert stats.get("rows") == 3
+
+
+# ------------------------------------------------------- where the spans go
+
+def test_fit_leaves_gang_spans_that_cover_the_trainer_start(
+        ray_start_regular, tmp_path):
+    from ray_tpu.parallel.mesh import MeshConfig
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    from ray_tpu.train.backend import JaxConfig
+
+    def _train_fn(config):  # a closure: shipped by value
+        t_enter = time.time()
+        from ray_tpu import train
+
+        train.report({"t_enter": t_enter,
+                      "mesh": dict(train.get_mesh().shape)})
+
+    before = dp.snapshot()
+    t_fit = time.time()
+    result = JaxTrainer(
+        _train_fn, train_loop_config={},
+        jax_config=JaxConfig(
+            platform="cpu", mesh_config=MeshConfig(fsdp=2), env_vars={
+                "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}),
+        scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="spans", storage_path=str(tmp_path)),
+    ).fit()
+    assert result.error is None, result.error
+    trainer_start_s = result.metrics["t_enter"] - t_fit
+    got = dp.delta(dp.snapshot(), before)["spans"]
+    gang = {k: v for k, v in got.items() if k.startswith("train.gang.")}
+    assert set(gang) == {
+        "train.gang.place", "train.gang.backend_init", "train.gang.mesh",
+        "train.gang.platform_check", "train.gang.session",
+        "train.gang.launch"}
+    assert all(v["count"] == 1 for v in gang.values())
+    covered = sum(v["total_s"] for v in gang.values())
+    assert covered >= 0.9 * trainer_start_s, (covered, trainer_start_s)
+    assert got["train.fit"]["total_s"] >= covered
+    # rank 0's spans rode back on the rounds' return values
+    assert got["train.worker.open_chip"]["count"] >= 1
+    assert got["train.worker.mesh_build"]["count"] == 1
+    # ... and the mesh round's self time leaves them out
+    mesh = gang["train.gang.mesh"]
+    assert mesh["self_s"] <= mesh["total_s"] - got[
+        "train.worker.mesh_build"]["total_s"] + 1e-6
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    import jax
+
+    from ray_tpu.inference.paged_engine import PagedInferenceEngine
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    return lambda: PagedInferenceEngine(
+        params, cfg, max_batch=2, max_len=128, decode_chunk=4)
+
+
+def test_serve_stream_request_records_occupancy_and_phases(tiny_engine):
+    from ray_tpu.inference.engine import GenerationConfig
+
+    eng = tiny_engine()
+
+    def run(names):
+        t_sent = dp.now()
+        batch = [(name, [1 + i, 2, 3], 6, t_sent)
+                 for i, name in enumerate(names)]
+
+        def feed(_block):
+            out, batch[:] = list(batch), []
+            return out, (), True
+
+        tokens = {}
+        for req, tok, _done in eng.serve_stream(feed, GenerationConfig()):
+            tokens.setdefault(req, []).append(tok)
+        return {k: len(v) for k, v in tokens.items()}
+
+    run(["w0", "w1", "w2"])   # compiles every shape the second run uses
+    eng.profiler.reset()
+    before = eng.stats()
+    t_mark = (dp.now() + dp._EPOCH_NS) * 1e-9   # the ring's clock
+    assert run(["r0", "r1", "r2"]) == {"r0": 6, "r1": 6, "r2": 6}
+    after = eng.stats()
+    spans = dp.delta({"pid": 0, "spans": after["spans"],
+                      "counters": after["counters"]},
+                     {"pid": 0, "spans": before["spans"],
+                      "counters": before["counters"]})
+    got, counters = spans["spans"], spans["counters"]
+    # one record a request, and one queue wait each (max_batch 2: the
+    # third waited for a slot)
+    assert got["engine.request"]["count"] == 3
+    assert got["engine.queue_wait"]["count"] == 3
+    assert got["engine.admit_wave"]["count"] == 2
+    assert 1 <= got["engine.admit"]["count"] <= 2
+    assert got["engine.decode_chunk"]["count"] >= 2
+    assert got["engine.fanout"]["count"] >= got["engine.decode_chunk"]["count"]
+    assert got["engine.feed"]["count"] >= 1
+    # every token but each request's first (the prefill samples that one)
+    # is one active row of one decode step
+    assert counters["decode.row_steps_active"] == 3 * 6 - 3
+    assert counters["decode.row_steps_capacity"] >= counters[
+        "decode.row_steps_active"]
+    assert counters["decode.row_steps_capacity"] % eng.max_batch == 0
+    # a row of length L attends to L tokens: prompts of 3, five steps each
+    assert counters["decode.kv_tokens_attended"] == 3 * sum(range(4, 9))
+    ring = [r for r in dp.snapshot(recent=256)["recent"]
+            if r["start"] >= t_mark]
+    # the wait is part of the request's one record, not a second one
+    assert not [r for r in ring if r["name"] == "engine.queue_wait"]
+    records = {r["attrs"]["req_id"]: r for r in ring
+               if r["name"] == "engine.request"}
+    assert set(records) >= {"r0", "r1", "r2"}
+    for rec in (records[k] for k in ("r0", "r1", "r2")):
+        a = rec["attrs"]
+        assert a["tokens"] == 6 and a["outcome"] == "ok"
+        assert a["preemptions"] == 0
+        assert 0 <= a["admitted_s"] <= a["first_token_s"] <= (
+            rec["end"] - rec["start"]) + 1e-6
+    # the third request's wait is the first two's whole decode
+    assert records["r2"]["attrs"]["admitted_s"] > records["r0"]["attrs"][
+        "first_token_s"]
+    # the phases the benchmark reads keep their keys, summed off the spans
+    phases = after["device_phases"]["phase_seconds"]
+    assert {"input_wait", "prefill", "device_execute", "reply"} <= set(phases)
+    # prefill is all of admission, the first tokens' hand-off with it;
+    # reply is the decoded tokens' fan-out alone
+    assert phases["prefill"] == pytest.approx(
+        got["engine.admit"]["total_s"], abs=1e-4)
+    assert got["engine.admit"]["total_s"] >= got[
+        "engine.admit_wave"]["total_s"]
+    assert phases["device_execute"] == pytest.approx(
+        got["engine.decode_chunk"]["total_s"], abs=1e-4)
+    assert phases["reply"] == pytest.approx(
+        sum(r["end"] - r["start"] for r in ring
+            if r["name"] == "engine.fanout" and not r["attrs"]["first"]),
+        abs=1e-4)
+    first = [r for r in ring
+             if r["name"] == "engine.fanout" and r["attrs"]["first"]]
+    assert first and all(r["parent"] == "engine.admit" for r in first)
+
+
+def test_replica_hot_path_makes_no_registry_lookup(tiny_engine, monkeypatch):
+    """Handles are taken at construction; the token counter moves once a
+    chunk, by the tokens delivered, and its total is what it was."""
+    from ray_tpu.serve.llm import metrics as llm_metrics
+    from ray_tpu.serve.llm.engine import LLMEngineReplica
+    from ray_tpu.util import metrics as um
+
+    replica = LLMEngineReplica(tiny_engine, {"max_new_tokens": 12})
+    try:
+        assert replica.generate([1, 2, 3], max_new_tokens=2)  # warm
+        lookups = []
+        real = um.get_metric
+        monkeypatch.setattr(
+            um, "get_metric", lambda name: lookups.append(name) or real(name))
+        tokens = um.get_metric(llm_metrics.TOKENS_NAME)
+        incs = []
+        real_inc = tokens.inc
+        monkeypatch.setattr(
+            tokens, "inc",
+            lambda value=1.0, tags=None: incs.append(value) or real_inc(
+                value, tags=tags))
+        lookups.clear()
+        total_before = sum(v for _, _, v in tokens._samples())
+        out = replica.generate([4, 5, 6], max_new_tokens=12)
+        assert len(out) == 12
+        assert lookups == [], f"registry lookups on the hot path: {lookups}"
+        assert sum(v for _, _, v in tokens._samples()) - total_before == 12
+        # 1 token from the prefill, then chunks of up to 4: not 12 bumps
+        assert sum(incs) == 12 and len(incs) <= 5, incs
+        stats = replica.get_stats()["engine"]
+        assert stats["spans"]["engine.request"]["count"] >= 2
+        assert "decode.row_steps_active" in stats["counters"]
+    finally:
+        replica.shutdown()

@@ -66,9 +66,6 @@ JAX_PLATFORMS=cpu python -m ray_tpu drill run \
     --scenario rl_rollout_storm --budget 240s --seed 0 \
     --report "${TMPDIR:-/tmp}/ci_rl_storm_report.json" --gate
 
-echo "== tracing smoke (bounded) =="
-JAX_PLATFORMS=cpu python -m tools.tracing_smoke --budget 120
-
 echo "== dataplane smoke (bounded) =="
 JAX_PLATFORMS=cpu python -m tools.dataplane_smoke --budget 120
 
