@@ -15,10 +15,12 @@ GQA is handled by repeating KV heads in the wrapper.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu._private import device_profiler
 
 NEG_INF = -1e30
 
@@ -39,63 +41,274 @@ def _reference_attention(q, k, v, causal: bool, scale: float):
 
 
 # --------------------------------------------------------------------------
+# Block schedule: which steps each grid row runs, and which of them need a mask
+# --------------------------------------------------------------------------
+
+def _cdiv(a, b):
+    return (a + b - 1) // b
+
+
+def _clamp_block(block, seq):
+    # Clamp to the sequence, then round DOWN to a lane-aligned multiple of
+    # 128 (Mosaic tiling): min(512, 300) = 300 would otherwise make an
+    # unaligned BlockSpec. Sequences <=128 keep block == seq, the
+    # long-standing short-seq path.
+    b = min(block, seq)
+    return (b // 128) * 128 if b > 128 else b
+
+
+def _step_width(own, other, causal):
+    """Width of a loop step along the axis a grid row walks. Causal: no more
+    than the block the row owns, so a step the diagonal cuts is at most
+    square and is never executed at twice its visible size (`other` when
+    the owned block does not divide it). Non-causal: the other block."""
+    return own if causal and other % own == 0 else other
+
+
+def _key_steps(qi, *, block_q, width, seq_k, pad_k, causal, offset,
+               mx=max, mn=min):
+    """Forward and dq: a grid row owns `block_q` queries and walks the keys
+    in `width` steps -> (lo, mid, hi): steps [lo, mid) run unmasked, steps
+    [mid, hi) masked. `qi` may be traced (`mx`/`mn` are then jnp.maximum /
+    jnp.minimum)."""
+    if not causal:
+        return 0, seq_k // width, pad_k // width
+    # Keys [0, end) are seen by the row block's last query, so nothing
+    # beyond them by any. In a loop the steps below the diagonal run masked
+    # too: on the v5e the mask costs 2% of the kernel (it is not bound by
+    # the vector ALUs) and a second loop 5-8% (PERF.md §6, PR 26). An
+    # unrolled plan masks step by step instead (`block_schedule`).
+    end = mn(mx((qi + 1) * block_q + offset, 0), pad_k)
+    return 0, 0, _cdiv(end, width)
+
+
+def _query_steps(kj, *, block_k, width, seq_q, pad_q, causal, offset,
+                 mx=max, mn=min):
+    """dk/dv: a grid row owns `block_k` keys and walks the queries in
+    `width` steps -> (lo, mid, hi) as `_key_steps`. Causal: from the first
+    query that sees the block's first key."""
+    if not causal:
+        return 0, seq_q // width, pad_q // width
+    lo = mn(mx(kj * block_k - offset, 0), pad_q) // width
+    return lo, lo, pad_q // width
+
+
+class KernelSchedule(NamedTuple):
+    """One kernel's loop plan over one (batch, head): `tiles` are
+    (q_start, q_rows, k_start, k_cols, masked), one per loop step, and
+    `rows` the same steps by grid row, as (step index, masked). `static`:
+    every grid row's steps run as straight-line code (`_run_row`)."""
+    width: int
+    static: bool
+    tiles: tuple
+    rows: tuple
+    steps_unmasked: int
+    steps_masked: int
+    executed_over_needed: float
+
+
+# The most steps a grid row may have for its kernel to be unrolled. Measured
+# on the v5e at 512 x 512 (PERF.md §6, PR 26): 4 (S 2048) is the gain this
+# exists for; 8 (S 4096) takes the forward from 5.80 to 4.03 ms and dk/dv
+# from 8.7 to 29.5 ms, and the code grows with the square of S.
+_MAX_STATIC_STEPS = 4
+
+
+def block_schedule(s_q, s_k, block_q, block_k, causal):
+    """The steps the three kernels execute at these (already clamped) block
+    sizes -> {"fwd": KernelSchedule, "dq": ..., "dkv": ...}; jax-free, all
+    static, shared by the wrappers below and by tests/test_ops.py.
+
+    `executed_over_needed` is scores executed over scores the mask keeps.
+    Starting point (before PR 26): steps of block_q x block_k whatever the
+    diagonal left of them, 512 x 1024 at S 2048: 6 steps of 512 x 1,024 a
+    head where the causal half is 2.10 M scores, 1.5 in all three kernels.
+    Causal steps are now at most square (`_step_width`), so an owned block
+    of B rows gives 1 + B / S: 1.25 at 512 and S 2048, 1.125 at S 4096.
+
+    A step runs unmasked (no iota, compare or `where`) where the schedule
+    can tell that every score in it is valid: step by step in a static
+    plan, and loop by loop (`_key_steps`) in one too long to unroll.
+    """
+    offset = s_k - s_q
+    if causal:
+        needed = sum(min(max(r + offset + 1, 0), s_k) for r in range(s_q))
+    else:
+        needed = s_q * s_k
+
+    def visible(q0, nq, k0, nk):
+        return not causal or k0 + nk - 1 <= q0 + offset
+
+    def plan(width, rows, tile):
+        """rows: per grid row its (lo, mid, hi); tile(row, step) -> (q0,
+        nq, k0, nk, every score inside seq_q x seq_k and visible)."""
+        static = max(hi - lo for lo, _, hi in rows) <= _MAX_STATIC_STEPS
+        by_row = tuple(
+            tuple((j, not tile(i, j)[4] if static else j >= mid)
+                  for j in range(lo, hi))
+            for i, (lo, mid, hi) in enumerate(rows))
+        tiles = tuple(tile(i, j)[:4] + (masked,)
+                      for i, steps in enumerate(by_row) for j, masked in steps)
+        masked = sum(t[4] for t in tiles)
+        executed = sum(t[1] * t[3] for t in tiles)
+        return KernelSchedule(
+            width, static, tiles, by_row, len(tiles) - masked, masked,
+            executed / needed if needed else float("inf"))
+
+    width = _step_width(block_q, block_k, causal)
+    pad_q, pad_k = _cdiv(s_q, block_q) * block_q, _cdiv(s_k, width) * width
+    keys = plan(
+        width,
+        [_key_steps(qi, block_q=block_q, width=width, seq_k=s_k, pad_k=pad_k,
+                    causal=causal, offset=offset)
+         for qi in range(pad_q // block_q)],
+        lambda qi, j, w=width: (
+            qi * block_q, block_q, j * w, w,
+            (j + 1) * w <= s_k and visible(qi * block_q, block_q, j * w, w)))
+
+    width = _step_width(block_k, block_q, causal)
+    pad_q, pad_k = _cdiv(s_q, width) * width, _cdiv(s_k, block_k) * block_k
+    queries = plan(
+        width,
+        [_query_steps(kj, block_k=block_k, width=width, seq_q=s_q,
+                      pad_q=pad_q, causal=causal, offset=offset)
+         for kj in range(pad_k // block_k)],
+        lambda kj, i, w=width: (
+            i * w, w, kj * block_k, block_k,
+            (i + 1) * w <= s_q and visible(i * w, w, kj * block_k, block_k)))
+    return {"fwd": keys, "dq": keys, "dkv": queries}
+
+
+def _count_steps(*plans):
+    # Per lowering (each time a kernel is built), not per run: the share of
+    # loop steps on the unmasked path is unmasked / (unmasked + masked).
+    device_profiler.count("flash.steps_unmasked",
+                          sum(p.steps_unmasked for p in plans))
+    device_profiler.count("flash.steps_masked",
+                          sum(p.steps_masked for p in plans))
+
+
+def _run_row(plan, row, steps, body, carry, finish):
+    """Run this grid row's steps from `carry`, then `finish(carry)`.
+
+    The trip counts depend on the grid row, and Mosaic schedules nothing
+    across the iterations of a loop: the MXU then waits out every step's
+    vector work (the forward ran 1.87 ms at S 2048 so, PERF.md §6, PR 26).
+    A static plan has one branch a grid row instead, its steps straight-
+    line code in which one step's matmuls run under its neighbours'
+    softmax (1.3 ms), and each step masked only if it needs it. Otherwise
+    `steps(row, mx=, mn=)` gives the traced (lo, mid, hi): the unmasked
+    steps [lo, mid) and the masked ones [mid, hi) each in a loop, built
+    only if the plan has such a step."""
+    from jax.experimental import pallas as pl
+
+    if not plan.static:
+        lo, mid, hi = steps(row, mx=jnp.maximum, mn=jnp.minimum)
+        if plan.steps_unmasked:
+            carry = jax.lax.fori_loop(lo, mid, body(False), carry)
+        if plan.steps_masked:
+            carry = jax.lax.fori_loop(mid, hi, body(True), carry)
+        finish(carry)
+        return
+
+    def branch(mine):
+        def run():
+            c = carry
+            for j, masked in mine:
+                c = body(masked)(j, c)
+            finish(c)
+        return run
+
+    for i, mine in enumerate(plan.rows):
+        pl.when(row == i)(branch(mine))
+
+
+def _aligned(start, width):
+    from jax.experimental import pallas as pl
+
+    return start if isinstance(start, int) else pl.multiple_of(start, width)
+
+
+def _lane_chunks(x, op):
+    """Combine the 128-lane column chunks of `x` [rows, n * 128] with `op`
+    -> [rows, 128] (narrower `x`: unchanged): elementwise work only; what
+    is left to reduce across lanes is one vreg a row group."""
+    if x.shape[1] % 128:
+        return x
+    return functools.reduce(
+        op, [x[:, c:c + 128] for c in range(0, x.shape[1], 128)])
+
+
+# --------------------------------------------------------------------------
 # Pallas forward
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, block_k, seq_q, seq_k):
+                block_q, plan, seq_q, seq_k):
     from jax.experimental import pallas as pl
 
+    width = plan.width
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)  # [block_q, D]
     # Causal with s_q != s_k (decode-style): query i corresponds to key
     # position i + (seq_k - seq_q), matching the oracle's tril(k=s_k-s_q).
     causal_offset = seq_k - seq_q
     q_pos = (qi * block_q + causal_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
+             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
 
-    num_kv = pl.cdiv(seq_k, block_k)
-    if causal:
-        # Only blocks up to (and including) the diagonal contribute.
-        num_kv = jnp.minimum(
-            num_kv, pl.cdiv((qi + 1) * block_q + causal_offset, block_k)
-        )
+    def step(masked):
+        def body(j, carry):
+            o, m, l = carry
+            start = _aligned(j * width, width)
+            k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+            v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [block_q, width]
+            if masked:
+                k_pos = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, width), 1
+                )
+                # Mask padding rows of a partial final K block (manual
+                # dslice reads clamp, duplicating real rows) and, when
+                # causal, future positions.
+                valid = k_pos < seq_k
+                if causal:
+                    valid = valid & (q_pos >= k_pos)
+                s = jnp.where(valid, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+            corr = jnp.exp(m - m_new)
+            # l stays one partial sum a lane: summed across lanes once,
+            # after the loops, not in every step
+            l_new = l * corr + _lane_chunks(p, jnp.add)
+            o_new = o * corr + jax.lax.dot_general(
+                p, v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return o_new, m_new, l_new
+        return body
 
-    def body(j, carry):
-        o, m, l = carry
-        k_blk = k_ref[0, 0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_k]
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        # Mask padding rows of a partial final K block (manual dslice reads
-        # clamp, duplicating real rows) and, when causal, future positions.
-        valid = k_pos < seq_k
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        o_new = o * corr[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return o_new, m_new, l_new
-
+    steps = functools.partial(
+        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
+        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
     o0 = jnp.zeros_like(q)
-    m0 = jnp.full((block_q,), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((block_q,), dtype=jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, num_kv, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-20)
-    o_ref[0, 0] = (o / l[:, None]).astype(o_ref.dtype)
-    lse_ref[0, 0] = (m + jnp.log(l))[:, None]
+    # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
+    # they broadcast along lanes with no relayout
+    m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
+    l0 = jnp.zeros((block_q, width if width % 128 else 128),
+                   dtype=jnp.float32)
+
+    def finish(carry):
+        o, m, l = carry
+        l = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
+        o_ref[0, 0] = (o / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m + jnp.log(l)
+
+    _run_row(plan, qi, steps, step, (o0, m0, l0), finish)
 
 
 def _pad_seq(x, block):
@@ -111,17 +324,19 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
 
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
+    plan = block_schedule(s_q, s_k, block_q, block_k, causal)["fwd"]
+    _count_steps(plan)
     # Pad to block multiples: dynamic_slice CLAMPS out-of-range starts, which
     # would silently shift the last partial block. The kernels mask padded
     # positions via the true seq_q/seq_k.
     q = _pad_seq(q, block_q)
-    k = _pad_seq(k, block_k)
-    v = _pad_seq(v, block_k)
+    k = _pad_seq(k, plan.width)
+    v = _pad_seq(v, plan.width)
     s_q_pad, s_k_pad = q.shape[2], k.shape[2]
     grid = (b, h, s_q_pad // block_q)
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_q=s_q, seq_k=s_k,
+        _fwd_kernel, scale=scale, causal=causal, block_q=block_q, plan=plan,
+        seq_q=s_q, seq_k=s_k,
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -149,9 +364,10 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
 # --------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   scale, causal, block_q, block_k, seq_q, seq_k):
+                   scale, causal, block_q, plan, seq_q, seq_k):
     from jax.experimental import pallas as pl
 
+    width = plan.width
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
@@ -159,128 +375,138 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     delta = delta_ref[0, 0]  # [block_q, 1]
     causal_offset = seq_k - seq_q
     q_pos = (qi * block_q + causal_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
+             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
 
-    num_kv = pl.cdiv(seq_k, block_k)
-    if causal:
-        num_kv = jnp.minimum(
-            num_kv, pl.cdiv((qi + 1) * block_q + causal_offset, block_k)
-        )
+    def step(masked):
+        def body(j, dq):
+            start = _aligned(j * width, width)
+            k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+            v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            if masked:
+                k_pos = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, width), 1
+                )
+                valid = k_pos < seq_k
+                if causal:
+                    valid = valid & (q_pos >= k_pos)
+                s = jnp.where(valid, s, NEG_INF)
+            p = jnp.exp(s - lse)
+            if masked:
+                p = jnp.where(valid, p, 0.0)
+            dp = jax.lax.dot_general(
+                do, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = p * (dp - delta) * scale
+            return dq + jax.lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        return body
 
-    def body(j, dq):
-        k_blk = k_ref[0, 0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        valid = k_pos < seq_k
-        if causal:
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        p = jnp.where(valid, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dq = dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dq
+    steps = functools.partial(
+        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
+        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
 
-    dq = jax.lax.fori_loop(0, num_kv, body, jnp.zeros_like(q))
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+    def finish(dq):
+        dq_ref[0, 0] = dq.astype(dq_ref.dtype)
+
+    _run_row(plan, qi, steps, step, jnp.zeros_like(q), finish)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, block_k,
+                    dk_ref, dv_ref, *, scale, causal, block_k, plan,
                     seq_q, seq_k):
+    """Works on the TRANSPOSED score tile, s^T = k q^T [block_k, width]:
+    every product is then a plain or a last-dims-contracted matmul (dv +=
+    p^T do, dp^T = v do^T, dk += ds^T q), where p^T do taken from an
+    untransposed p has Mosaic transpose the whole tile first. lse and delta
+    come as rows ([steps, width]) to broadcast down the tile."""
     from jax.experimental import pallas as pl
 
+    width = plan.width
     kj = pl.program_id(2)
     k_blk = k_ref[0, 0].astype(jnp.float32)  # [block_k, D]
     v_blk = v_ref[0, 0].astype(jnp.float32)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    k_pos = kj * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, width), 0)
     causal_offset = seq_k - seq_q
 
-    num_q = pl.cdiv(seq_q, block_q)
-    start_q = jnp.int32(0)
-    if causal:
-        # First q block whose max key position reaches this k block.
-        start_q = jnp.maximum(kj * block_k - causal_offset, 0) // block_q
+    def step(masked):
+        def body(i, carry):
+            dk, dv = carry
+            start = _aligned(i * width, width)
+            q = q_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+            do = do_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+            lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
+            delta = delta_ref[0, 0, pl.dslice(i, 1), :]
+            s = jax.lax.dot_general(
+                k_blk, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [block_k, width]
+            if masked:
+                q_row = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, width), 1
+                )
+                # Mask padding rows of a partial final Q block; when causal,
+                # also mask future keys relative to the offset-shifted query
+                # positions.
+                valid = q_row < seq_q
+                if causal:
+                    valid = valid & ((q_row + causal_offset) >= k_pos)
+                s = jnp.where(valid, s, NEG_INF)
+            p = jnp.exp(s - lse)
+            if masked:
+                p = jnp.where(valid, p, 0.0)
+            dv = dv + jax.lax.dot_general(
+                p, do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dp = jax.lax.dot_general(
+                v_blk, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = p * (dp - delta) * scale
+            dk = dk + jax.lax.dot_general(
+                ds, q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return dk, dv
+        return body
 
-    def body(i, carry):
+    steps = functools.partial(
+        _query_steps, block_k=block_k, width=width, seq_q=seq_q,
+        pad_q=q_ref.shape[2], causal=causal, offset=causal_offset)
+
+    def finish(carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.dslice(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.dslice(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.dslice(i * block_q, block_q), :]
-        delta = delta_ref[0, 0, pl.dslice(i * block_q, block_q), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        q_row = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        # Mask padding rows of a partial final Q block; when causal, also
-        # mask future keys relative to the offset-shifted query positions.
-        valid = q_row < seq_q
-        if causal:
-            valid = valid & ((q_row + causal_offset) >= k_pos)
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [block_q, block_k]
-        p = jnp.where(valid, p, 0.0)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk, dv
+        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
-    dk0 = jnp.zeros_like(k_blk)
-    dv0 = jnp.zeros_like(v_blk)
-    dk, dv = jax.lax.fori_loop(start_q, num_q, body, (dk0, dv0))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    _run_row(plan, kj, steps, step,
+             (jnp.zeros_like(k_blk), jnp.zeros_like(v_blk)), finish)
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-                      interpret):
+def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
+                   interpret):
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
     # Same padding rationale as the forward (dynamic_slice clamping).
-    q = _pad_seq(q, block_q)
-    do = _pad_seq(do, block_q)
-    lse = _pad_seq(lse, block_q)
-    delta = _pad_seq(delta, block_q)
-    k = _pad_seq(k, block_k)
-    v = _pad_seq(v, block_k)
+    q, do, lse, delta = (_pad_seq(x, block_q) for x in (q, do, lse, delta))
+    k, v = _pad_seq(k, plan.width), _pad_seq(v, plan.width)
     s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_q=s_q, seq_k=s_k,
+    kernel = functools.partial(
+        _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
+        plan=plan, seq_q=s_q, seq_k=s_k,
     )
     dq = pl.pallas_call(
-        dq_kernel,
+        kernel,
         grid=(b, h, s_q_pad // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
@@ -294,21 +520,37 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
+    return dq[:, :, :s_q]
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_q=s_q, seq_k=s_k,
+
+def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
+                    interpret):
+    from jax.experimental import pallas as pl
+
+    b, h, s_q, d = q.shape
+    s_k = k.shape[2]
+    width = plan.width
+    q, do, lse, delta = (_pad_seq(x, width) for x in (q, do, lse, delta))
+    k, v = _pad_seq(k, block_k), _pad_seq(v, block_k)
+    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
+    # one row a loop step, for the transposed tile (see the kernel)
+    n_steps = s_q_pad // width
+    lse = lse.reshape(b, h, n_steps, width)
+    delta = delta.reshape(b, h, n_steps, width)
+    kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, block_k=block_k,
+        plan=plan, seq_q=s_q, seq_k=s_k,
     )
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        kernel,
         grid=(b, h, s_k_pad // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, s_q_pad, d), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, s_q_pad, d), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, s_q_pad, 1), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, s_q_pad, 1), lambda b_, h_, j: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
@@ -320,7 +562,20 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         ],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-    return dq[:, :, :s_q], dk[:, :, :s_k], dv[:, :, :s_k]
+    return dk[:, :, :s_k], dv[:, :, :s_k]
+
+
+def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+                      interpret):
+    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, causal)
+    _count_steps(plans["dq"], plans["dkv"])
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    dq = _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q,
+                        plans["dq"], interpret)
+    dk, dv = _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k,
+                             plans["dkv"], interpret)
+    return dq, dk, dv
 
 
 # --------------------------------------------------------------------------
@@ -353,11 +608,14 @@ def flash_attention(
     q, k, v,
     causal: bool = True,
     scale: Optional[float] = None,
-    # Block sizes were chosen on a v5e at B8/H16/D128 seq 2048 in an
-    # earlier round (512x1024 fastest; 1024x1024 and k>=1536 exceed VMEM);
-    # not re-measured on the current installation. Clamped to seq below.
+    # Measured on a v5e at [4, 32, 2048, 128], causal, ms a call forward /
+    # backward (PERF.md §6, PR 26; the schedule before it, 512x1024: 2.22 /
+    # 4.98): 512x512 1.34 / 3.81, 512x1024 1.30 / 4.02 (the same forward:
+    # causal steps are cut to block_q), 256x512 1.65 / 3.92, 1024x512 1.52 /
+    # 3.95, 256x256 1.64 / 4.06; 1024x1024 exceeds VMEM in dk/dv. Clamped
+    # to seq below.
     block_q: int = 512,
-    block_k: int = 1024,
+    block_k: int = 512,
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
 ):
@@ -373,12 +631,15 @@ def flash_attention(
 
     Sequence limit (v5e, libtpu 0.0.34, pinned by
     tests/test_tpu_aot_compile.py): each kernel instance keeps the whole
-    sequence's K and V (forward, dq) or q, dO, lse and delta (dk/dv) in
-    VMEM, so the backward pass compiles at S 4096 and is refused at
-    S 8192 ("Scoped allocation with size 18.98M and limit 16.00M exceeded
-    scoped vmem limit"; the forward pass alone still compiles there) —
-    at `LlamaConfig.max_seq_len`'s default. Longer sequences need the
-    backward pass tiled over the sequence, or `ring_attention` over `sp`.
+    sequence's K and V (forward, dq) or q and dO (dk/dv) in VMEM. Forward
+    and backward compile at S 2048, 4096 and 8192
+    (`LlamaConfig.max_seq_len`'s default) since dk/dv takes lse and delta
+    as rows, [steps, width]: as [S, 1] columns, a lane each, they were
+    4 MiB apiece at S 8192 and the backward pass was refused there
+    ("Scoped allocation with size 18.98M and limit 16.00M exceeded scoped
+    vmem limit"). Only S 2048 has run on the chip in a cell; longer
+    sequences still want the residency tiled, or `ring_attention` over
+    `sp`.
     """
     b, s_q, h, d = q.shape
     h_kv = k.shape[2]
@@ -397,16 +658,8 @@ def flash_attention(
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     if use_pallas or interpret:
-        # Clamp to the sequence, then round DOWN to a lane-aligned multiple
-        # of 128 (Mosaic tiling): min(512, 300) = 300 would otherwise make
-        # an unaligned BlockSpec. Sequences <=128 keep block == seq, the
-        # long-standing short-seq path.
-        def _aligned(block, seq):
-            b = min(block, seq)
-            return (b // 128) * 128 if b > 128 else b
-
-        block_q = _aligned(block_q, s_q)
-        block_k = _aligned(block_k, k.shape[1])
+        block_q = _clamp_block(block_q, s_q)
+        block_k = _clamp_block(block_k, k.shape[1])
         o = _flash_bhsd(qt, kt, vt, causal, scale, block_q, block_k, interpret)
     else:
         o = _reference_attention(qt, kt, vt, causal, scale)
