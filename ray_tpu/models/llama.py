@@ -48,6 +48,9 @@ class LlamaConfig:
     # full [B,S,V] fp32 logits tensor never materializes (chunked_ce).
     loss_chunk_size: int = 0
     use_ring_attention: bool = False  # set when mesh sp-axis > 1
+    # RMSNorm with a learned scale over ALL channels of the q projection and
+    # of the k projection, before the split into heads and RoPE (OLMoE).
+    qk_norm: bool = False
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
@@ -77,6 +80,7 @@ class LlamaConfig:
             + self.n_heads * self.d_head * self.d_model    # wo
             + 3 * self.d_model * self.d_ff                 # gate, up, down
             + 2 * self.d_model                             # norms
+            + self.qk_norm * (self.n_heads + self.n_kv_heads) * self.d_head
         )
         return (
             self.vocab_size * self.d_model                 # embed
@@ -89,9 +93,12 @@ class LlamaConfig:
 def param_logical_axes(config: LlamaConfig) -> Dict[str, Any]:
     """Logical axis names per parameter (layers stacked on 'layers')."""
     L = ("layers",)
+    qk_norm = {"q_norm": L + ("heads", "kv"), "k_norm": L + ("heads", "kv")} \
+        if config.qk_norm else {}
     return {
         "embed": ("vocab", "embed"),
         "layers": {
+            **qk_norm,
             "attn_norm": L + (None,),
             "wq": L + ("embed", "heads", "kv"),
             "wk": L + ("embed", "heads", "kv"),
@@ -117,7 +124,12 @@ def init(config: LlamaConfig, key) -> Dict[str, Any]:
 
     def layer_params(key):
         ks = jax.random.split(key, 7)
+        qk_norm = {
+            "q_norm": jnp.ones((c.n_heads, c.d_head), dtype=c.dtype),
+            "k_norm": jnp.ones((c.n_kv_heads, c.d_head), dtype=c.dtype),
+        } if c.qk_norm else {}
         return {
+            **qk_norm,
             "attn_norm": jnp.ones((c.d_model,), dtype=c.dtype),
             "wq": dense(ks[0], (c.d_model, c.n_heads, c.d_head), c.d_model),
             "wk": dense(ks[1], (c.d_model, c.n_kv_heads, c.d_head), c.d_model),
@@ -162,6 +174,21 @@ def _rms_norm(x, weight, eps):
     x = x.astype(jnp.float32)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(dtype) * weight
+
+
+def _qk_norm(q, k, params, config):
+    """With `config.qk_norm`: one RMSNorm over ALL channels of the q
+    projection [B, S, H, D] (scale `q_norm` [H, D]) and one over all of the
+    k projection, not per head, before RoPE. Otherwise q and k as given."""
+    if not config.qk_norm:
+        return q, k
+
+    def norm(x, weight):
+        b, s, h, d = x.shape
+        return _rms_norm(x.reshape(b, s, h * d), weight.reshape(h * d),
+                         config.norm_eps).reshape(b, s, h, d)
+
+    return norm(q, params["q_norm"]), norm(k, params["k_norm"])
 
 
 def _rope(x, positions, theta):
@@ -209,6 +236,7 @@ def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
     q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
+    q, k = _qk_norm(q, k, params, c)
     q = lc(q, ("batch", "seq", "act_heads", "act_kv"))
     k = lc(k, ("batch", "seq", "act_heads", "act_kv"))
     q = _rope(q, positions, c.rope_theta)
@@ -403,6 +431,7 @@ def _attn_sublayer_decode(x, params, positions, config: LlamaConfig,
     q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
+    q, k = _qk_norm(q, k, params, c)
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
     lengths = positions[:, 0]
@@ -441,6 +470,7 @@ def _attn_sublayer_paged(x, params, positions, config: LlamaConfig,
     q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
+    q, k = _qk_norm(q, k, params, c)
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
     n_blocks, bs, kvh, d = k_pool.shape
@@ -476,6 +506,7 @@ def _attn_sublayer_paged_decode(x, params, positions, config: LlamaConfig,
     q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
+    q, k = _qk_norm(q, k, params, c)
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
     n_blocks, bs, kvh, d = k_pool.shape

@@ -1,13 +1,17 @@
-"""Mixtral-family sparse-MoE decoder, TPU-first.
+"""Llama stack + routed experts: the Mixtral and OLMoE families, TPU-first.
 
-Reference gap: KantiCodes/ray has no model zoo — its RLlib/Train run user
-models; SURVEY §5 ("Long-context / sequence parallelism... the TPU framework
-must supply its own model-parallel layer natively") and §7 name sharded MoE
-dispatch a required native capability. This model composes the Llama-family
-attention stack (models/llama.py) with top-k routed experts
-(parallel/moe.py): dense gating per token, k experts, capacity-bounded
-dispatch; with an `ep` mesh axis the experts shard across chips and tokens
-travel via all_to_all on ICI.
+The attention sublayer is `models/llama.py`'s (GQA or MHA, RoPE, optional
+QK-norm); the MLP is top-k routed SwiGLU experts through `parallel/moe.py`:
+one dropless sorted dispatch over grouped matmuls, or, on a mesh with an
+`ep` axis, experts sharded across chips with a capacity-bounded
+`all_to_all` exchange. The two families differ in static config only:
+
+- Mixtral: GQA, top-2 of 8, the k weights renormalised (`norm_topk_prob`).
+- OLMoE: MHA, `qk_norm`, top-8 of 64, weights not renormalised, and a
+  router z-loss beside the load-balancing loss (arXiv:2409.02060).
+
+Training loss: CE + aux_loss_coef * mean_l LB_l + router_z_loss_coef *
+mean_l RZ_l with the per-layer terms of `parallel/moe.router_losses`.
 """
 
 from __future__ import annotations
@@ -20,18 +24,20 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama
-from ray_tpu.models.llama import (LlamaConfig, _remat_policy, _rms_norm,
-                                  _rope)
-from ray_tpu.parallel.moe import moe_layer, moe_shard_map
-from ray_tpu.parallel.sharding import LogicalAxisRules
+from ray_tpu.models.llama import LlamaConfig, _remat_policy, _rms_norm
+from ray_tpu.parallel.moe import MoEAux, moe_layer, moe_shard_map
+from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
 
 @dataclasses.dataclass(frozen=True)
 class MixtralConfig(LlamaConfig):
+    """`d_ff` is the width of ONE expert."""
     n_experts: int = 8
     experts_per_token: int = 2
-    capacity_factor: float = 1.25
-    aux_loss_coef: float = 0.01
+    norm_topk_prob: bool = True
+    aux_loss_coef: float = 0.01        # load balancing
+    router_z_loss_coef: float = 0.0
+    capacity_factor: float = 1.25      # read by the `ep` exchange only
 
     @staticmethod
     def tiny(vocab_size: int = 512) -> "MixtralConfig":
@@ -100,56 +106,68 @@ def _expert_ffn(p, x):
 
 
 def _moe_block(h, layer_p, config: MixtralConfig, mesh):
-    """h: [B,S,D] -> (out [B,S,D], aux_loss scalar)."""
+    """h: [B,S,D] -> (out [B,S,D], MoEAux of this layer)."""
     c = config
     b, s, d = h.shape
     flat = h.reshape(b * s, d)
-    expert_params = {
-        "w_gate": layer_p["experts"]["w_gate"],
-        "w_up": layer_p["experts"]["w_up"],
-        "w_down": layer_p["experts"]["w_down"],
-    }
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         out, aux = moe_shard_map(
-            flat, layer_p["moe_gate"], _expert_ffn, expert_params, mesh,
-            k=c.experts_per_token, capacity_factor=c.capacity_factor)
+            flat, layer_p["moe_gate"], _expert_ffn, layer_p["experts"], mesh,
+            k=c.experts_per_token, capacity_factor=c.capacity_factor,
+            norm_topk_prob=c.norm_topk_prob)
     else:
         out, aux = moe_layer(
-            flat, layer_p["moe_gate"], _expert_ffn, expert_params,
-            k=c.experts_per_token, capacity_factor=c.capacity_factor)
+            flat, layer_p["moe_gate"], layer_p["experts"],
+            k=c.experts_per_token, norm_topk_prob=c.norm_topk_prob)
     return out.reshape(b, s, d), aux
 
 
-def forward(params, tokens, config: MixtralConfig, mesh=None,
-            rules: Optional[LogicalAxisRules] = None):
-    """tokens [B,S] -> (logits [B,S,V] fp32, aux_loss scalar fp32)."""
+def forward_hidden(params, tokens, config: MixtralConfig, mesh=None,
+                   rules: Optional[LogicalAxisRules] = None):
+    """tokens [B,S] -> (final-norm hidden states [B,S,D], MoEAux whose
+    fields lead with the layer dim: experts [L, B*S, k], the loss terms
+    [L])."""
     c = config
+    lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    x = params["embed"][tokens].astype(c.dtype)
+    # the table's embed dim in the activation layout: llama.forward_hidden
+    table = lc(params["embed"], ("vocab", "act_embed"))
+    x = lc(table[tokens].astype(c.dtype), ("batch", "seq", "act_embed"))
 
     def layer_fn(x, layer_p):
         x, _ = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules)
         h2 = _rms_norm(x, layer_p["mlp_norm"], c.norm_eps)
         moe_out, aux = _moe_block(h2, layer_p, c, mesh)
-        return x + moe_out, aux
+        return lc(x + moe_out, ("batch", "seq", "act_embed")), aux
 
     if c.remat:
         layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
 
-    def scan_body(x, layer_p):
-        x, aux = layer_fn(x, layer_p)
-        return x, aux
+    x, aux = jax.lax.scan(layer_fn, x, params["layers"])
+    return _rms_norm(x, params["final_norm"], c.norm_eps), aux
 
-    x, aux_per_layer = jax.lax.scan(scan_body, x, params["layers"])
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
+
+def forward(params, tokens, config: MixtralConfig, mesh=None,
+            rules: Optional[LogicalAxisRules] = None):
+    """tokens [B,S] -> (logits [B,S,V] fp32, MoEAux per layer)."""
+    x, aux = forward_hidden(params, tokens, config, mesh, rules)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    return logits.astype(jnp.float32), jnp.mean(aux_per_layer)
+    return logits.astype(jnp.float32), aux
+
+
+def aux_loss(aux: MoEAux, config: MixtralConfig):
+    """The router's part of the training loss, float32 scalar."""
+    return (config.aux_loss_coef * jnp.mean(aux.load_balance)
+            + config.router_z_loss_coef * jnp.mean(aux.router_z))
 
 
 def loss_fn(params, batch, config: MixtralConfig, mesh=None,
             rules: Optional[LogicalAxisRules] = None):
-    """Next-token CE + load-balancing aux loss (Switch/Mixtral style).
+    """Next-token CE (masked by batch["mask"] when given; in sequence chunks
+    of `loss_chunk_size`, or one chunk, through `llama.chunked_ce`) +
+    `aux_loss`, whose terms are statistics of EVERY token of the batch,
+    masked or not.
     Scalar return (make_train_step contract, train/step.py:100)."""
     if "inputs" in batch:
         inputs, targets = batch["inputs"], batch["targets"]
@@ -158,24 +176,7 @@ def loss_fn(params, batch, config: MixtralConfig, mesh=None,
         tokens = batch["tokens"]
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         mask = None
-    logits, aux = forward(params, inputs, config, mesh, rules)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ce = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    if mask is not None:
-        ce_mean = jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    else:
-        ce_mean = jnp.mean(ce)
-    return ce_mean + config.aux_loss_coef * aux
-
-
-def flops_per_token(config: MixtralConfig, seq_len: int) -> float:
-    """6·N_active + attention term: a token only multiplies through its
-    k routed experts, so the (n_experts - k) inactive expert FFNs per layer
-    are excluded from the 6N parameter-flops count."""
-    c = config
-    inactive_ffn_params = (
-        c.n_layers * (c.n_experts - c.experts_per_token)
-        * 3 * c.d_model * c.d_ff)
-    param_flops = 6.0 * (c.num_params() - inactive_ffn_params)
-    attn_flops = 6.0 * c.n_layers * c.n_heads * c.d_head * seq_len
-    return param_flops + attn_flops
+    hidden, aux = forward_hidden(params, inputs, config, mesh, rules)
+    ce = llama.chunked_ce(hidden, params["lm_head"], targets, mask,
+                          chunk=config.loss_chunk_size or hidden.shape[1])
+    return ce + aux_loss(aux, config)

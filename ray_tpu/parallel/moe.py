@@ -1,93 +1,204 @@
-"""Mixture-of-Experts dispatch with expert parallelism.
+"""Mixture-of-experts routing and dispatch.
 
-EP capability absent from the reference (SURVEY.md §5): top-k routing with
-capacity, dispatch/combine as einsums against an expert-sharded weight stack.
-Under pjit, annotating the expert dim with the `ep` mesh axis makes XLA emit
-the all-to-alls; `moe_shard_map` offers the explicit `lax.all_to_all` form
-for when manual control wins.
+One router (`route`) and one set of per-layer loss terms (`router_losses`)
+serve both entry points:
+
+- `moe_layer`: the single-program dispatch, dropless. The T x k (token,
+  slot) pairs are sorted by expert, the rows gathered in that order, and the
+  experts' SwiGLU runs as three grouped matmuls over the ragged groups
+  (`ops/grouped_matmul.py`); the inverse permutation brings the k results of
+  a token back together for the weighted sum. No capacity, no dropped
+  token, no [T, E, C] tensor.
+- `moe_shard_map`: experts sharded over the `ep` mesh axis, token buffers
+  exchanged with `lax.all_to_all`. The exchange needs a static buffer, so
+  this path alone is capacity-bounded ([T, E, C] dispatch and combine
+  tensors; pairs past an expert's capacity are dropped).
 """
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from functools import partial
+from typing import Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu._private import device_profiler
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 
 
-def top_k_gating(logits, k: int, capacity: int):
-    """Compute dispatch/combine tensors for top-k routing with capacity.
+class MoEAux(NamedTuple):
+    """What a layer's dispatch hands the losses (and the tests)."""
+    experts: jax.Array       # [T, k] int32 the chosen experts
+    load_balance: jax.Array  # scalar, `router_losses`
+    router_z: jax.Array      # scalar, `router_losses`
 
-    logits: [T, E]. Returns (dispatch [T, E, C] one-hot-ish, combine
-    [T, E, C] weights, aux_loss scalar).
-    """
-    import jax
-    import jax.numpy as jnp
 
-    t, e = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)            # [T, k]
-    # Load-balancing auxiliary loss (Switch-style).
-    me = jnp.mean(probs, axis=0)                             # [E]
-    top1 = jax.nn.one_hot(gate_idx[:, 0], e)
-    ce = jnp.mean(top1, axis=0)
-    aux_loss = e * jnp.sum(me * ce)
+class Routing(NamedTuple):
+    logits: jax.Array   # [T, E] float32 router logits
+    probs: jax.Array    # [T, E] float32 softmax over all experts
+    weights: jax.Array  # [T, k] float32 combine weights of the chosen k
+    experts: jax.Array  # [T, k] int32 the chosen experts, best first
 
-    # Position of each token within its expert's buffer. Slots are assigned
-    # in priority order (all slot-0 choices first, then slot-1, ...) with a
-    # running per-expert offset so a token picking expert E as 1st choice and
-    # another picking E as 2nd choice never collide in the same capacity slot.
+
+def route(x, router_w, k: int, norm_topk_prob: bool = False) -> Routing:
+    """x [T, D], router_w [D, E] -> the top-k choice per token. Logits,
+    softmax and weights are float32 whatever the model dtype: a bf16 logit
+    would flip choices between near-equal experts. `norm_topk_prob` divides
+    the k weights by their sum (Mixtral; OLMoE leaves them as they are)."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(x, router_w, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return Routing(logits, probs, weights, experts.astype(jnp.int32))
+
+
+def router_losses(routing: Routing, axis_name=None):
+    """-> (load_balance, router_z), one layer's terms, both float32 scalars.
+
+    load_balance = E * sum_i f_i P_i with f_i the share of the T x k
+    (token, slot) pairs sent to expert i (a count: no gradient) and P_i the
+    mean router probability of expert i; 1 when routing is even. router_z =
+    mean_t logsumexp_i(logits)^2. With `axis_name` (inside a shard_map over
+    tokens) the statistics are taken over every shard's tokens."""
+    t, e = routing.probs.shape
+    counts = jnp.bincount(routing.experts.reshape(-1),
+                          length=e).astype(jnp.float32)
+    p_mean = jnp.mean(routing.probs, axis=0)
+    z = jnp.mean(jax.nn.logsumexp(routing.logits, axis=-1) ** 2)
+    if axis_name is not None:
+        counts = jax.lax.pmean(counts, axis_name)
+        p_mean = jax.lax.pmean(p_mean, axis_name)
+        z = jax.lax.pmean(z, axis_name)
+    f = jax.lax.stop_gradient(counts) / (t * routing.experts.shape[1])
+    return e * jnp.sum(f * p_mean), z
+
+
+def sort_by_expert(experts, n_experts: int):
+    """experts [T, k] -> (order, inverse, group_sizes). Pair p = t * k + j is
+    token t's j-th choice. `order[s]` is the pair at sorted position s
+    (stable: within an expert, pairs keep their order), `inverse[p]` the
+    sorted position of pair p, `group_sizes[i]` the number of pairs sent to
+    expert i; they sum to T x k, so nothing is dropped."""
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    group_sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    return order, inverse, group_sizes
+
+
+# Rows move by a permutation of the T x k pairs, so the transpose of either
+# gather is the other permutation's gather. Autodiff of a gather would emit
+# a scatter-add, which knows nothing of that and serialises on the TPU.
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _permute(x, order, inverse, k):
+    """x [T, D] -> rows [T * k, D] in sorted order."""
+    return x[order // k]
+
+
+def _permute_fwd(x, order, inverse, k):
+    return _permute(x, order, inverse, k), inverse
+
+
+def _permute_bwd(k, inverse, g):
+    t = inverse.shape[0] // k
+    dx = jnp.sum(g[inverse].reshape(t, k, -1).astype(jnp.float32), axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def _unpermute(rows, order, inverse):
+    """rows [T * k, D] in sorted order -> [T * k, D] in pair order."""
+    return rows[inverse]
+
+
+def _unpermute_fwd(rows, order, inverse):
+    return rows[inverse], order
+
+
+def _unpermute_bwd(order, g):
+    return g[order], None, None
+
+
+_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+
+
+def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False):
+    """Dropless top-k SwiGLU experts. x [T, D]; router_w [D, E]; `experts`
+    holds w_gate, w_up [E, D, F] and w_down [E, F, D]. -> (y [T, D] in
+    x.dtype, MoEAux): y_t = sum_j w_tj * down_e(silu(gate_e x_t) * up_e x_t)
+    over token t's k experts e, accumulated in float32."""
+    t, d = x.shape
+    e = router_w.shape[1]
+    routing = route(x, router_w, k, norm_topk_prob)
+    with jax.named_scope("moe.permute"):
+        order, inverse, group_sizes = sort_by_expert(routing.experts, e)
+        rows = _permute(x, order, inverse, k)
+    with jax.named_scope("moe.experts"):
+        gate = grouped_matmul(rows, experts["w_gate"], group_sizes)
+        up = grouped_matmul(rows, experts["w_up"], group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up, experts["w_down"],
+                             group_sizes)
+    with jax.named_scope("moe.combine"):
+        per_pair = _unpermute(out, order, inverse).reshape(t, k, d)
+        y = jnp.einsum("tkd,tk->td", per_pair.astype(jnp.float32),
+                       routing.weights)
+    # per lowering, as `flash.steps_*` are
+    device_profiler.count("moe.rows_routed", t * k)
+    device_profiler.count("moe.experts", e)
+    device_profiler.count("moe.gmm_calls", 3)
+    return y.astype(x.dtype), MoEAux(routing.experts, *router_losses(routing))
+
+
+def _capacity_dispatch(routing: Routing, capacity: int):
+    """-> (dispatch, combine), both float32 [T, E, C], for the `ep`
+    exchange's static buffers. Slots are assigned in priority order (all
+    first choices, then all second choices, ...) with a running per-expert
+    offset, so two tokens never share a capacity slot; a pair whose
+    position is past `capacity` is dropped."""
+    t, e = routing.probs.shape
     dispatch = jnp.zeros((t, e, capacity), dtype=jnp.float32)
     combine = jnp.zeros((t, e, capacity), dtype=jnp.float32)
     expert_counts = jnp.zeros((e,), dtype=jnp.float32)
-    for slot in range(k):
-        idx = gate_idx[:, slot]                              # [T]
-        onehot = jax.nn.one_hot(idx, e)                      # [T, E]
+    for slot in range(routing.experts.shape[1]):
+        onehot = jax.nn.one_hot(routing.experts[:, slot], e)     # [T, E]
         pos = (jnp.cumsum(onehot, axis=0) - onehot + expert_counts) * onehot
         pos_in_expert = jnp.sum(pos, axis=-1).astype(jnp.int32)  # [T]
         expert_counts = expert_counts + jnp.sum(onehot, axis=0)
         keep = pos_in_expert < capacity
-        cap_onehot = jax.nn.one_hot(pos_in_expert, capacity)  # [T, C]
+        cap_onehot = jax.nn.one_hot(pos_in_expert, capacity)     # [T, C]
         d = onehot[:, :, None] * cap_onehot[:, None, :] * keep[:, None, None]
         dispatch = dispatch + d
-        combine = combine + d * gate_vals[:, slot][:, None, None]
-    return dispatch, combine, aux_loss
+        combine = combine + d * routing.weights[:, slot][:, None, None]
+    return dispatch, combine
 
 
-def moe_layer(x, gate_w, expert_fn: Callable, expert_params,
-              k: int = 2, capacity_factor: float = 1.25):
-    """Apply an MoE layer. x: [T, D]; gate_w: [D, E]; expert_params leaves
-    lead with the expert dim E (annotate it with the `expert` logical axis so
-    pjit shards it over `ep`). Returns ([T, D], aux_loss)."""
-    import jax.numpy as jnp
-    import jax
-
-    t, d = x.shape
-    e = gate_w.shape[1]
-    capacity = max(1, int(capacity_factor * t * max(k, 1) / e))
-    logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    dispatch, combine, aux = top_k_gating(logits, k, capacity)
-    # [E, C, D]: per-expert token buffers.
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))
-    expert_out = jax.vmap(expert_fn)(expert_params, expert_in.astype(x.dtype))
-    out = jnp.einsum("tec,ecd->td", combine, expert_out.astype(jnp.float32))
-    return out.astype(x.dtype), aux
-
-
-def moe_shard_map(x, gate_w, expert_fn, expert_params, mesh,
+def moe_shard_map(x, router_w, expert_fn: Callable, expert_params, mesh,
                   axis_name: str = "ep", k: int = 2,
-                  capacity_factor: float = 1.25):
-    """Explicit-collective variant: experts sharded over `axis_name`, token
-    buffers exchanged with lax.all_to_all."""
-    import jax
-    import jax.numpy as jnp
+                  capacity_factor: float = 1.25,
+                  norm_topk_prob: bool = False):
+    """Expert-parallel variant: tokens and experts sharded over `axis_name`,
+    capacity-bounded buffers exchanged with `lax.all_to_all`.
+    `expert_fn(params_of_one_expert, rows [C', D]) -> [C', D]`; the leaves
+    of `expert_params` lead with the expert dim. -> (y [T, D], MoEAux) with
+    the loss terms taken over every shard's tokens (`router_losses`)."""
     from jax.sharding import PartitionSpec as P
 
-    n_exp_total = gate_w.shape[1]
+    n_exp_total = router_w.shape[1]
 
-    def local_fn(x_loc, gate_w_full, params_loc):
+    def local_fn(x_loc, router_w_full, params_loc):
         t, d = x_loc.shape
         n_shards = jax.lax.psum(1, axis_name)
         capacity = max(1, int(capacity_factor * t * max(k, 1) / n_exp_total))
-        logits = x_loc.astype(jnp.float32) @ gate_w_full.astype(jnp.float32)
-        dispatch, combine, aux = top_k_gating(logits, k, capacity)
+        routing = route(x_loc, router_w_full, k, norm_topk_prob)
+        dispatch, combine = _capacity_dispatch(routing, capacity)
         buf = jnp.einsum("tec,td->ecd", dispatch, x_loc.astype(jnp.float32))
         # [E, C, D] -> exchange so each shard holds its experts' tokens from
         # every shard: split E across shards.
@@ -101,15 +212,13 @@ def moe_shard_map(x, gate_w, expert_fn, expert_params, mesh,
         out = jax.lax.all_to_all(out, axis_name, 0, 0, tiled=False)
         out = out.reshape(n_exp_total, capacity, d)
         y = jnp.einsum("tec,ecd->td", combine, out.astype(jnp.float32))
-        # aux is computed from this shard's tokens only; the result is
-        # declared replicated (out_specs=P()), so it must actually BE the
-        # global mean, not one shard's local value.
-        return y.astype(x_loc.dtype), jax.lax.pmean(aux, axis_name)
+        return y.astype(x_loc.dtype), MoEAux(
+            routing.experts, *router_losses(routing, axis_name))
 
     pspec = jax.tree.map(lambda _: P(axis_name), expert_params)
     return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(axis_name), P(), pspec),
-        out_specs=(P(axis_name), P()),
+        out_specs=(P(axis_name), MoEAux(P(axis_name), P(), P())),
         check_vma=False,
-    )(x, gate_w, expert_params)
+    )(x, router_w, expert_params)

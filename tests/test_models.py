@@ -39,7 +39,9 @@ def test_mixtral_forward_shapes(mx):
     logits, aux = mixtral.forward(params, toks, cfg)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert logits.dtype == jnp.float32
-    assert np.isfinite(float(aux)) and float(aux) > 0
+    assert aux.experts.shape == (cfg.n_layers, 2 * 16, cfg.experts_per_token)
+    aux_loss = float(mixtral.aux_loss(aux, cfg))
+    assert np.isfinite(aux_loss) and aux_loss > 0
 
 
 def test_mixtral_loss_decreases(mx):
@@ -68,9 +70,9 @@ def test_mixtral_loss_decreases(mx):
 def test_mixtral_ep_sharded_matches_dense(mx):
     """Expert-parallel execution must agree with single-device routing.
 
-    Capacity is computed over LOCAL tokens in the sharded path vs global in
-    the dense path, so token-dropping can legitimately differ at tight
-    capacity — parity is asserted at ample capacity where nothing drops."""
+    The single-device dispatch is dropless; the `ep` exchange is bounded by
+    a capacity over its LOCAL tokens, so parity is asserted at ample
+    capacity, where it drops nothing either."""
     cfg, params = mx
     cfg = mixtral.MixtralConfig(**{**cfg.__dict__, "capacity_factor": 8.0})
     toks = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0,

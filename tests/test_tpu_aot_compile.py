@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
 from ray_tpu.models import llama
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.flash_attention import flash_attention
 
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -70,6 +71,22 @@ for name, shape in (("flash_s4096", (2, 4096, 32, 128)),
         out[name] = "compiled"
     except Exception as e:  # noqa: BLE001 - a refusal is the finding
         out[name] = str(e)[:300]
+
+
+def gmm_grads(lhs, rhs, sizes):
+    # _gmm_tpu: `grouped_matmul` follows jax.default_backend(), cpu here
+    return jax.value_and_grad(
+        lambda lhs, rhs: grouped_matmul._gmm_tpu(lhs, rhs, sizes)
+        .astype(jnp.float32).sum(), argnums=(0, 1))(lhs, rhs)
+
+
+# train-olmoe-1chip's shapes: 8,192 tokens x top-8 rows, 64 experts
+for name, (k, n) in (("gmm_up", (2048, 1024)), ("gmm_down", (1024, 2048))):
+    lowered = jax.jit(gmm_grads).lower(
+        spec((65536, k), bf16), spec((64, k, n), bf16), spec((64,), jnp.int32))
+    out[name + "_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+    lowered.compile()
+    out[name] = "compiled"
 
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
@@ -116,3 +133,12 @@ def test_flash_backward_compiles_at_long_sequences(compiled, seq):
     PR 26): if this flips back, update the docstring of
     ops.flash_attention.flash_attention with it."""
     assert compiled[seq] == "compiled", compiled[seq]
+
+
+@pytest.mark.parametrize("shape", ["gmm_up", "gmm_down"])
+def test_grouped_matmul_fwd_bwd_compiles_for_v5e(compiled, shape):
+    """The MoE dispatch's Pallas grouped matmuls at train-olmoe-1chip's
+    shapes with `ops/grouped_matmul.py`'s tilings: forward, the rows'
+    gradient and the weights' gradient."""
+    assert compiled[shape + "_custom_calls"] == 3
+    assert compiled[shape] == "compiled"
