@@ -6,9 +6,12 @@ serve both entry points:
 - `moe_layer`: the single-program dispatch, dropless. The T x k (token,
   slot) pairs are sorted by expert, the rows gathered in that order, and the
   experts' SwiGLU runs as three grouped matmuls over the ragged groups
-  (`ops/grouped_matmul.py`); the inverse permutation brings the k results of
-  a token back together for the weighted sum. No capacity, no dropped
-  token, no [T, E, C] tensor.
+  (`ops/grouped_matmul.py`). The k combine weights are brought to sorted
+  order too and applied BEFORE the down projection (it is linear), so the
+  inverse permutation only brings the k results of a token back together
+  for a plain sum: the backward pass needs none of the down projection's
+  output, and under remat reruns neither it nor the un-permute. No
+  capacity, no dropped token, no [T, E, C] tensor.
 - `moe_shard_map`: experts sharded over the `ep` mesh axis, token buffers
   exchanged with `lax.all_to_all`. The exchange needs a static buffer, so
   this path alone is capacity-bounded ([T, E, C] dispatch and combine
@@ -90,71 +93,105 @@ def sort_by_expert(experts, n_experts: int):
     return order, inverse, group_sizes
 
 
-# Rows move by a permutation of the T x k pairs, so the transpose of either
-# gather is the other permutation's gather. Autodiff of a gather would emit
-# a scatter-add, which knows nothing of that and serialises on the TPU.
+# The three functions below move rows (or scalars) by a permutation of the
+# T x k pairs, so the transpose of each is such a move too: `_permute` and
+# `_combine` are each other's, `_reorder`'s is itself under the inverse
+# permutation. Autodiff of a gather would emit a scatter-add, which knows
+# nothing of that and serialises on the TPU.
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _permute(x, order, inverse, k):
-    """x [T, D] -> rows [T * k, D] in sorted order."""
+    """x [T, D] -> rows [T * k, D] in sorted order: row s is the token of
+    pair `order[s]`."""
     return x[order // k]
 
 
 def _permute_fwd(x, order, inverse, k):
-    return _permute(x, order, inverse, k), inverse
+    return _permute(x, order, inverse, k), (order, inverse)
 
 
-def _permute_bwd(k, inverse, g):
-    t = inverse.shape[0] // k
-    dx = jnp.sum(g[inverse].reshape(t, k, -1).astype(jnp.float32), axis=1)
-    return dx.astype(g.dtype), None, None
+def _permute_bwd(k, res, g):
+    return _combine(g, *res, k), None, None
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, order, inverse, k):
+    """rows [T * k, D] in sorted order -> [T, D]: the sum of each token's k
+    rows, accumulated in float32, in rows.dtype. No operand but the indices
+    is needed to transpose it."""
+    t = inverse.shape[0] // k
+    per_pair = rows[inverse].reshape(t, k, -1)
+    return jnp.sum(per_pair.astype(jnp.float32), axis=1).astype(rows.dtype)
+
+
+def _combine_fwd(rows, order, inverse, k):
+    return _combine(rows, order, inverse, k), (order, inverse)
+
+
+def _combine_bwd(k, res, g):
+    return _permute(g, *res, k), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 @jax.custom_vjp
-def _unpermute(rows, order, inverse):
-    """rows [T * k, D] in sorted order -> [T * k, D] in pair order."""
-    return rows[inverse]
+def _reorder(v, index, index_inverse):
+    """v [N] -> v[index] for a permutation `index` of N scalars, as a sort
+    by the inverse permutation: a gather moves scalars one index at a time
+    (0.58 ms for 65,536 on the v5e), the sort takes 0.20 ms (PERF.md §6,
+    PR 28)."""
+    return jax.lax.sort_key_val(index_inverse, v)[1]
 
 
-def _unpermute_fwd(rows, order, inverse):
-    return rows[inverse], order
+def _reorder_fwd(v, index, index_inverse):
+    return _reorder(v, index, index_inverse), (index, index_inverse)
 
 
-def _unpermute_bwd(order, g):
-    return g[order], None, None
+def _reorder_bwd(res, g):
+    index, index_inverse = res
+    return _reorder(g, index_inverse, index), None, None
 
 
-_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+_reorder.defvjp(_reorder_fwd, _reorder_bwd)
 
 
 def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False):
     """Dropless top-k SwiGLU experts. x [T, D]; router_w [D, E]; `experts`
     holds w_gate, w_up [E, D, F] and w_down [E, F, D]. -> (y [T, D] in
     x.dtype, MoEAux): y_t = sum_j w_tj * down_e(silu(gate_e x_t) * up_e x_t)
-    over token t's k experts e, accumulated in float32."""
-    t, d = x.shape
+    over token t's k experts e, accumulated in float32.
+
+    The down projection is linear, so w_tj multiplies its INPUT, on the
+    sorted side: silu(gate) * up * w is formed in float32 and rounded to
+    x.dtype once. What is left of the combine is then a gather and a sum
+    over k with nothing but indices to keep for its transpose. Were the
+    weights applied after the down projection, their gradient would need its
+    output, and a backward pass that keeps no Pallas call's result (remat
+    "dots") would rerun the down matmul and the un-permute for it alone."""
+    t = x.shape[0]
     e = router_w.shape[1]
     routing = route(x, router_w, k, norm_topk_prob)
     with jax.named_scope("moe.permute"):
         order, inverse, group_sizes = sort_by_expert(routing.experts, e)
         rows = _permute(x, order, inverse, k)
+        w_sorted = _reorder(routing.weights.reshape(-1), order, inverse)
     with jax.named_scope("moe.experts"):
         gate = grouped_matmul(rows, experts["w_gate"], group_sizes)
         up = grouped_matmul(rows, experts["w_up"], group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up, experts["w_down"],
-                             group_sizes)
+        h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+             * w_sorted[:, None]).astype(x.dtype)
+        out = grouped_matmul(h, experts["w_down"], group_sizes)
     with jax.named_scope("moe.combine"):
-        per_pair = _unpermute(out, order, inverse).reshape(t, k, d)
-        y = jnp.einsum("tkd,tk->td", per_pair.astype(jnp.float32),
-                       routing.weights)
+        y = _combine(out, order, inverse, k)
     # per lowering, as `flash.steps_*` are
     device_profiler.count("moe.rows_routed", t * k)
     device_profiler.count("moe.experts", e)
     device_profiler.count("moe.gmm_calls", 3)
-    return y.astype(x.dtype), MoEAux(routing.experts, *router_losses(routing))
+    return y, MoEAux(routing.experts, *router_losses(routing))
 
 
 def _capacity_dispatch(routing: Routing, capacity: int):

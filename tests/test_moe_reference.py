@@ -151,25 +151,77 @@ def test_dispatch_is_a_permutation(n_experts, k):
         assert np.all(np.diff(np.asarray(order)[flat[order] == e]) > 0)
 
 
-def test_permutation_gradients_are_the_inverse_gathers():
-    """The hand-written transposes of the two row gathers against autodiff
-    of the plain gathers (a scatter-add)."""
-    t, k, d, e = 24, 2, 8, 4
-    _, experts = jax.lax.top_k(
-        jax.random.normal(jax.random.PRNGKey(0), (t, e)), k)
-    order, inverse, _ = moe.sort_by_expert(experts, e)
-    x = jax.random.normal(jax.random.PRNGKey(1), (t, d))
-    w = jax.random.normal(jax.random.PRNGKey(2), (t * k, d))
+@pytest.mark.parametrize("n_experts,k", [(4, 1), (4, 2), (8, 4), (64, 8)])
+def test_permutation_gradients_are_the_inverse_gathers(n_experts, k):
+    """The hand-written transposes of the layer's three moves (tokens to
+    sorted rows, the k weights to sorted order, sorted rows back to a sum
+    per token) against autodiff of the plain gathers (a scatter-add), with
+    respect to the rows and to the weights."""
+    t, d = 24, 8
+    ks = jax.random.split(jax.random.PRNGKey(n_experts + k), 4)
+    _, experts = jax.lax.top_k(jax.random.normal(ks[0], (t, n_experts)), k)
+    order, inverse, _ = moe.sort_by_expert(experts, n_experts)
+    x = jax.random.normal(ks[1], (t, d))
+    w = jax.random.normal(ks[2], (t, k))
+    m = jax.random.normal(ks[3], (t * k, d))   # stands for the experts
 
-    def ours(x):
+    def ours(x, w):
         rows = moe._permute(x, order, inverse, k)
-        return jnp.sum(moe._unpermute(rows * w, order, inverse) ** 2)
+        w_sorted = moe._reorder(w.reshape(-1), order, inverse)
+        return jnp.sum(moe._combine(rows * w_sorted[:, None] * m,
+                                    order, inverse, k) ** 2)
 
-    def plain(x):
-        return jnp.sum(((x[order // k]) * w)[inverse] ** 2)
+    def plain(x, w):
+        out = x[order // k] * w.reshape(-1)[order][:, None] * m
+        return jnp.sum(jnp.sum(out[inverse].reshape(t, k, d), axis=1) ** 2)
 
-    np.testing.assert_allclose(jax.grad(ours)(x), jax.grad(plain)(x),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(ours(x, w), plain(x, w), rtol=1e-6)
+    grads = jax.grad(ours, argnums=(0, 1))
+    for g, want in zip(grads(x, w), jax.grad(plain, argnums=(0, 1))(x, w)):
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-5)
+    assert "scatter" not in str(jax.make_jaxpr(grads)(x, w))
+    assert "scatter" in str(jax.make_jaxpr(
+        jax.grad(plain, argnums=(0, 1)))(x, w))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (scan
+    and remat bodies, custom_vjp calls, pjit)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("switches", [OLMOE, MIXTRAL], ids=["olmoe", "mixtral"])
+def test_backward_reruns_neither_down_projection_nor_unpermute(switches):
+    """The model's gradient as the cell takes it (layers scanned, each under
+    `jax.checkpoint` with the "dots" policy, which saves no grouped matmul's
+    result). A layer's body appears once in the forward scan and once in
+    the backward scan, so the counts below are per layer. Grouped matmuls:
+    3 forward, gate and up recomputed, 6 backward = 11; with the top-k
+    weights applied after the down projection their gradient needed its
+    output, and the recomputation reran it: 12. Gathers of [T * k, D] rows:
+    tokens to sorted rows (forward and recomputed), the k-sum's un-permute,
+    and the transposes of both = 5; 6 with the recomputed un-permute."""
+    cfg, params, _ = _model(switches)
+    cfg = dataclasses.replace(cfg, remat=True, remat_policy="dots")
+    inputs, targets = _tokens(6, rows=3)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: mixtral.loss_fn(p, {"inputs": inputs, "targets": targets},
+                                  cfg)))(params)
+    pair_rows = (inputs.size * cfg.experts_per_token, cfg.d_model)
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert sum(n.startswith("ragged_dot") for n in names) == 11
+    assert sum(e.primitive.name == "gather"
+               and e.outvars[0].aval.shape == pair_rows for e in eqns) == 5
+    # no gather of rows or of the sorted weights is transposed by autodiff
+    for e in eqns:
+        if e.primitive.name.startswith("scatter"):
+            assert pair_rows not in [v.aval.shape for v in e.invars], e
+            assert e.outvars[0].aval.shape != pair_rows[:1], e
 
 
 @pytest.mark.parametrize("m,k,n,sizes", [

@@ -25,6 +25,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = r"""
 import json
+import re
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,6 +36,7 @@ from ray_tpu.inference.paged_engine import PagedInferenceEngine
 from ray_tpu.models import llama
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.parallel import moe
 
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
@@ -87,6 +89,33 @@ for name, (k, n) in (("gmm_up", (2048, 1024)), ("gmm_down", (1024, 2048))):
     out[name + "_custom_calls"] = lowered.as_text().count("tpu_custom_call")
     lowered.compile()
     out[name] = "compiled"
+
+
+def moe_layer_grads(x, router_w, experts):
+    # one layer as the cell's scan body runs it: under remat "dots"
+    layer = jax.checkpoint(
+        lambda x, router_w, experts: moe.moe_layer(x, router_w, experts, 8)[0],
+        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    # the value too, or the forward pass is dead code
+    return jax.value_and_grad(
+        lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(
+            x, router_w, experts)
+
+
+# `moe_layer` too follows jax.default_backend() through `grouped_matmul`
+moe.grouped_matmul = grouped_matmul._gmm_tpu
+hlo = jax.jit(moe_layer_grads).lower(
+    spec((8192, 2048), bf16), spec((2048, 64), bf16),
+    {"w_gate": spec((64, 2048, 1024), bf16),
+     "w_up": spec((64, 2048, 1024), bf16),
+     "w_down": spec((64, 1024, 2048), bf16)}).compile().as_text()
+# the OPTIMIZED program: after the compiler's own dead-code removal
+out["moe_layer_custom_calls"] = hlo.count('custom_call_target="tpu_custom_call"')
+out["moe_layer_row_gathers"] = len(re.findall(
+    r"= bf16\[65536,2048\]\S* gather\(", hlo))
+out["moe_layer_pair_scatters"] = [
+    ln.strip()[:200] for ln in hlo.splitlines()
+    if re.search(r" scatter\(", ln) and "[65536" in ln]
 
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
@@ -142,3 +171,15 @@ def test_grouped_matmul_fwd_bwd_compiles_for_v5e(compiled, shape):
     gradient and the weights' gradient."""
     assert compiled[shape + "_custom_calls"] == 3
     assert compiled[shape] == "compiled"
+
+
+def test_moe_layer_backward_as_compiled_for_v5e(compiled):
+    """The gradient of one `moe_layer` under remat "dots" at
+    train-olmoe-1chip's shapes, as the v5e's compiler leaves it: 11 Pallas
+    calls (3 forward, gate and up recomputed, 6 backward; 12 when the top-k
+    weights were applied after the down projection and the recomputation
+    reran it), 5 gathers of [65536, 2048] rows (6 then), and no scatter
+    over the 65,536 pairs: no gather is left to autodiff to transpose."""
+    assert compiled["moe_layer_custom_calls"] == 11
+    assert compiled["moe_layer_row_gathers"] == 5
+    assert compiled["moe_layer_pair_scatters"] == []
