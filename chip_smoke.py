@@ -216,9 +216,8 @@ def train_fn(cfg):
 
 
 def train_phase(chips: int) -> dict:
-    from ray_tpu.parallel.mesh import MeshConfig
+    from ray_tpu.parallel.mesh import MeshConfig, axis_plan
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
-    from ray_tpu.train.spmd_bench import axis_plan
 
     cfg = SETTINGS["train"]
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as storage:

@@ -487,7 +487,7 @@ class Dataset:
         synchronous path (bit-identical batch stream, no extra thread).
         ``stats``, when a dict, is filled with produce_s / wait_s /
         batches / overlap_frac on exhaustion — the measured
-        input-pipeline-overlap fraction ``bench.py`` reports.
+        input-pipeline-overlap fraction.
         """
         import jax  # hoisted: ONE import for the whole iteration
 

@@ -8,13 +8,12 @@ hard part — this package supplies it.
 
 _NAMES = {
     "GenerationConfig": "ray_tpu.inference.engine",
-    "InferenceEngine": "ray_tpu.inference.engine",
     "sample_token": "ray_tpu.inference.sampling",
 }
 
 
 def __getattr__(name):
-    # the engines import jax at module level; resolving on first use keeps
+    # the sampler imports jax at module level; resolving on first use keeps
     # `import ray_tpu.inference` (and serve.llm, which imports it) jax-free
     # for drivers that only bind a deployment.
     if name in _NAMES:

@@ -1,10 +1,10 @@
 """PagedInferenceEngine: continuous batching over a block-pool KV cache.
 
-The dense engine (engine.py) reserves [max_batch, max_len] KV rows — a
-64-slot x 8k-token config pins worst-case HBM whether or not anyone sends
-long prompts. This engine implements the PagedAttention scheme TPU-style
-(reference capability: the serving stacks ray defers to, e.g. vLLM's
-block tables; ray itself ships no engine):
+A dense [max_batch, max_len] KV cache pins worst-case HBM whether or not
+anyone sends long prompts (64 slots x 8k tokens, reserved up front). This
+engine implements the PagedAttention scheme TPU-style (reference
+capability: the serving stacks ray defers to, e.g. vLLM's block tables;
+ray itself ships no engine):
 
   * KV lives in a BLOCK POOL ([L, n_blocks, block, kv, d], llama.py
     init_paged_kv_cache); a host-side allocator hands blocks to slots.
@@ -912,9 +912,10 @@ class PagedInferenceEngine:
         prompts: List[List[int]],
         gen: Optional[GenerationConfig] = None,
     ) -> Iterator[Tuple[int, int]]:
-        """Yields (request_index, token_id) as tokens are produced
-        (block-at-a-time: see InferenceEngine.generate_stream). One-shot
-        wrapper over serve_stream with the whole batch fed up front."""
+        """Yields (request_index, token_id) as tokens are produced, a
+        decode chunk at a time: per-token streaming would put a host round
+        trip back into the decode loop. One-shot wrapper over serve_stream
+        with the whole batch fed up front."""
         gen = gen or GenerationConfig()
         for p in prompts:
             if not p:

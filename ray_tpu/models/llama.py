@@ -41,8 +41,7 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     remat: bool = True
     # "full" recomputes everything; "dots" saves matmul outputs and
-    # recomputes only cheap elementwise ops (~6% faster at 500M/1-chip,
-    # still fits long-seq activations in HBM).
+    # recomputes only cheap elementwise ops.
     remat_policy: str = "dots"
     # >0: compute the training CE over sequence chunks of this size so the
     # full [B,S,V] fp32 logits tensor never materializes (chunked_ce).
@@ -51,13 +50,6 @@ class LlamaConfig:
     # RMSNorm with a learned scale over ALL channels of the q projection and
     # of the k projection, before the split into heads and RoPE (OLMoE).
     qk_norm: bool = False
-
-    @staticmethod
-    def llama3_8b() -> "LlamaConfig":
-        return LlamaConfig(
-            vocab_size=128_256, d_model=4096, n_layers=32, n_heads=32,
-            n_kv_heads=8, d_head=128, d_ff=14_336,
-        )
 
     @staticmethod
     def tiny(vocab_size: int = 512) -> "LlamaConfig":
@@ -223,42 +215,35 @@ def _attention(q, k, v, config: LlamaConfig, mesh=None):
     return flash_attention(q, k, v, causal=True)
 
 
-def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
-                   rules: Optional[LogicalAxisRules] = None,
-                   kv_cache=None, lengths=None):
-    """Pre-norm attention block shared by the training layer, the KV-cache
-    decode path and mixtral. With kv_cache=(k_cache, v_cache) it scatters
-    the new K/V at `positions` and attends over the cache, returning
-    (x, (new_k_cache, new_v_cache)); otherwise returns (x, None)."""
+def _qkv(x, params, positions, config: LlamaConfig, lc=None):
+    """The attention prologue every sublayer shares: pre-norm, the q/k/v
+    projections, QK-norm, RoPE on q and k. `lc` (training only) constrains
+    q and k to their logical layout between the norm and RoPE.
+    -> q [B,S,H,K], k and v [B,S,kv,K]."""
     c = config
-    lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     h = _rms_norm(x, params["attn_norm"], c.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
     q, k = _qk_norm(q, k, params, c)
-    q = lc(q, ("batch", "seq", "act_heads", "act_kv"))
-    k = lc(k, ("batch", "seq", "act_heads", "act_kv"))
+    if lc is not None:
+        q = lc(q, ("batch", "seq", "act_heads", "act_kv"))
+        k = lc(k, ("batch", "seq", "act_heads", "act_kv"))
     q = _rope(q, positions, c.rope_theta)
     k = _rope(k, positions, c.rope_theta)
-    new_cache = None
-    if kv_cache is not None:
-        # Prefill path (decode S=1 goes through _attn_sublayer_decode):
-        # additive one-hot scatter at each row's offset (target slots are
-        # still zero in append-only generation) — a single MXU matmul
-        # over the padded block.
-        k_cache, v_cache = kv_cache
-        t = k_cache.shape[1]
-        onehot = jax.nn.one_hot(positions, t, dtype=k.dtype)  # [B,S,T]
-        k_cache = k_cache + jnp.einsum("bst,bshk->bthk", onehot, k)
-        v_cache = v_cache + jnp.einsum("bst,bshk->bthk", onehot, v)
-        attn = _cached_attention(q, k_cache, v_cache, lengths, c)
-        new_cache = (k_cache, v_cache)
-    else:
-        attn = _attention(q, k, v, c, mesh)
-        attn = _checkpoint_name(attn, "attn_out")
+    return q, k, v
+
+
+def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
+                   rules: Optional[LogicalAxisRules] = None):
+    """Pre-norm causal attention block of the training layer (and of
+    mixtral's)."""
+    lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
+    q, k, v = _qkv(x, params, positions, config, lc)
+    attn = _attention(q, k, v, config, mesh)
+    attn = _checkpoint_name(attn, "attn_out")
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
-    return lc(x, ("batch", "seq", "act_embed")), new_cache
+    return lc(x, ("batch", "seq", "act_embed"))
 
 
 def _mlp_sublayer(x, params, config: LlamaConfig, mesh=None,
@@ -277,7 +262,7 @@ def _mlp_sublayer(x, params, config: LlamaConfig, mesh=None,
 
 def _layer(x, params, positions, config: LlamaConfig, mesh=None,
            rules: Optional[LogicalAxisRules] = None):
-    x, _ = _attn_sublayer(x, params, positions, config, mesh, rules)
+    x = _attn_sublayer(x, params, positions, config, mesh, rules)
     return _mlp_sublayer(x, params, config, mesh, rules)
 
 
@@ -323,8 +308,7 @@ def forward(params, tokens, config: LlamaConfig, mesh=None,
 def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256):
     """Cross-entropy without materializing full [B,S,V] fp32 logits: the
     sequence is scanned in chunks and each chunk's logits are rematerialized
-    in the backward pass. At V=32k, S=2048 this cuts peak HBM by ~4 GB per
-    8 rows — the difference between batch 8 and 16+ on one v5e chip."""
+    in the backward pass."""
     b, s, d = hidden.shape
     n = s // chunk
     rem = s - n * chunk
@@ -349,17 +333,6 @@ def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256):
     return total / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def init_kv_cache(config: LlamaConfig, batch: int, max_len: int,
-                  dtype=None) -> Dict[str, Any]:
-    """Per-layer KV cache for incremental decoding: arrays shaped
-    [n_layers, batch, max_len, n_kv_heads, d_head] (layer-major so the same
-    lax.scan over params['layers'] carries the matching cache slice)."""
-    c = config
-    dtype = dtype or c.dtype
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.d_head)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
 def _cached_attention(q, k_cache, v_cache, lengths, config: LlamaConfig):
     """q: [B,S,H,K] new queries at positions lengths..lengths+S;
     k/v_cache: [B,T,kv,K] full cache (already containing the new keys).
@@ -369,8 +342,7 @@ def _cached_attention(q, k_cache, v_cache, lengths, config: LlamaConfig):
     Decode is HBM-bound on the cache read, so the einsums are grouped-query
     aware: q is reshaped to [B,S,kv,rep,K] and contracted against the bf16
     cache directly (fp32 accumulation via preferred_element_type) — no
-    jnp.repeat head broadcast, no materialized fp32 cache copy. At bench
-    shapes that cuts per-step cache traffic ~4x."""
+    jnp.repeat head broadcast, no materialized fp32 cache copy."""
     c = config
     b, s, h, d = q.shape
     t = k_cache.shape[1]
@@ -421,25 +393,6 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, lengths,
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
-def _attn_sublayer_decode(x, params, positions, config: LlamaConfig,
-                          k_cache, v_cache):
-    """Decode-step (S=1) attention block: attends over the cache plus the
-    new token's own K/V, returning the new K/V for a deferred top-level
-    cache scatter (see forward_with_cache)."""
-    c = config
-    h = _rms_norm(x, params["attn_norm"], c.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
-    q, k = _qk_norm(q, k, params, c)
-    q = _rope(q, positions, c.rope_theta)
-    k = _rope(k, positions, c.rope_theta)
-    lengths = positions[:, 0]
-    attn = _decode_attention(q, k, v, k_cache, v_cache, lengths, c)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
-    return x, (k.astype(k_cache.dtype), v.astype(v_cache.dtype))
-
-
 def init_paged_kv_cache(config: LlamaConfig, n_blocks: int,
                         block_size: int, dtype=None) -> Dict[str, Any]:
     """Block-pool KV cache (PagedAttention layout, TPU-shaped): arrays
@@ -462,33 +415,24 @@ def _attn_sublayer_paged(x, params, positions, config: LlamaConfig,
     positions: [B, S] logical positions of the new tokens; valid: [B, S]
     bool (False rows scatter into the reserved scratch block 0).
     The per-layer gather materializes [B, max_blocks*bs, kv, d]
-    transiently — 1/n_layers of a dense cache's resident footprint — and
-    logical position t lands at gathered index t, so _cached_attention's
-    length masking applies unchanged."""
+    transiently, and logical position t lands at gathered index t, so
+    _cached_attention's length masking applies as over a dense cache."""
     c = config
-    h = _rms_norm(x, params["attn_norm"], c.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
-    q, k = _qk_norm(q, k, params, c)
-    q = _rope(q, positions, c.rope_theta)
-    k = _rope(k, positions, c.rope_theta)
+    q, k, v = _qkv(x, params, positions, c)
     n_blocks, bs, kvh, d = k_pool.shape
     b, s = positions.shape
     blk = jnp.take_along_axis(block_table, positions // bs, axis=1)
     flat = jnp.where(valid, blk * bs + positions % bs, 0)  # 0 = scratch
-    kf = k_pool.reshape(n_blocks * bs, kvh, d)
-    vf = v_pool.reshape(n_blocks * bs, kvh, d)
-    kf = kf.at[flat.reshape(-1)].set(
-        k.reshape(b * s, kvh, d).astype(kf.dtype))
-    vf = vf.at[flat.reshape(-1)].set(
-        v.reshape(b * s, kvh, d).astype(vf.dtype))
-    k_pool = kf.reshape(n_blocks, bs, kvh, d)
-    v_pool = vf.reshape(n_blocks, bs, kvh, d)
-    k_all = jnp.take(k_pool, block_table, axis=0).reshape(
-        b, -1, kvh, d)
-    v_all = jnp.take(v_pool, block_table, axis=0).reshape(
-        b, -1, kvh, d)
+
+    def write_then_gather(pool, new):
+        rows = pool.reshape(n_blocks * bs, kvh, d).at[flat.reshape(-1)].set(
+            new.reshape(b * s, kvh, d).astype(pool.dtype))
+        pool = rows.reshape(pool.shape)
+        return pool, jnp.take(pool, block_table, axis=0).reshape(
+            b, -1, kvh, d)
+
+    k_pool, k_all = write_then_gather(k_pool, k)
+    v_pool, v_all = write_then_gather(v_pool, v)
     attn = _cached_attention(q, k_all, v_all, lengths, c)
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
     return x, (k_pool, v_pool)
@@ -499,144 +443,72 @@ def _attn_sublayer_paged_decode(x, params, positions, config: LlamaConfig,
     """Decode-step (S=1) paged attention: gathers each row's KV from the
     pool (positions < lengths only — the pool is READ-ONLY here), adds
     the new token's self-attention term directly, and returns the new
-    K/V for a single deferred top-level pool scatter (mirrors
-    _attn_sublayer_decode for the dense cache)."""
+    K/V for a single deferred top-level pool scatter (see
+    forward_with_paged_cache)."""
     c = config
-    h = _rms_norm(x, params["attn_norm"], c.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, params["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, params["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, params["wv"])
-    q, k = _qk_norm(q, k, params, c)
-    q = _rope(q, positions, c.rope_theta)
-    k = _rope(k, positions, c.rope_theta)
+    q, k, v = _qkv(x, params, positions, c)
     n_blocks, bs, kvh, d = k_pool.shape
     b = positions.shape[0]
     # gathered index t == logical position t, so length masking applies
     k_all = jnp.take(k_pool, block_table, axis=0).reshape(b, -1, kvh, d)
     v_all = jnp.take(v_pool, block_table, axis=0).reshape(b, -1, kvh, d)
-    lengths = positions[:, 0]
-    attn = _decode_attention(q, k.astype(k_pool.dtype),
-                             v.astype(v_pool.dtype), k_all, v_all,
-                             lengths, c)
+    k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
+    attn = _decode_attention(q, k, v, k_all, v_all, positions[:, 0], c)
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
-    return x, (k.astype(k_pool.dtype), v.astype(v_pool.dtype))
+    return x, (k, v)
 
 
 def forward_with_paged_cache(params, tokens, pool, block_table, lengths,
                              config: LlamaConfig, valid=None):
-    """forward_with_cache over a paged pool (see init_paged_kv_cache).
+    """Incremental forward for generation over a paged pool (see
+    init_paged_kv_cache): prefill when S > 1, decode at S = 1.
 
-    tokens: [B, S] new tokens at positions lengths..lengths+S; valid:
-    optional [B, S] bool for padded prefill tails (invalid positions write
-    to the scratch block and are masked from attention by `lengths`).
+    tokens: [B, S] the NEW tokens, logically at positions
+    lengths..lengths+S; lengths: [B] int32, tokens already in the pool per
+    row; valid: optional [B, S] bool for padded prefill tails (invalid
+    positions write to the scratch block and are masked from attention by
+    `lengths`).
     -> (logits [B, S, vocab] fp32, new_pool)"""
     c = config
     b, s = tokens.shape
     positions = lengths[:, None] + jnp.arange(s)[None, :]
     if valid is None:
         valid = jnp.ones((b, s), bool)
-    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"))
-    x = table[tokens].astype(c.dtype)
-
-    if s == 1:
-        # Decode fast path (see forward_with_cache): layers only READ
-        # the pool; the new K/V comes out as [L,B,1,kv,K] ys and lands
-        # in the (donated) pool with one in-place scatter instead of a
-        # per-layer full-pool rewrite.
-        def decode_body(x, layer_in):
-            layer_p, kp, vp = layer_in
-            x, (k1, v1) = _attn_sublayer_paged_decode(
-                x, layer_p, positions, c, kp, vp, block_table)
-            x = _mlp_sublayer(x, layer_p, c)
-            return x, (k1, v1)
-
-        x, (k_new, v_new) = jax.lax.scan(
-            decode_body, x, (params["layers"], pool["k"], pool["v"]))
-        n_blocks, bs = pool["k"].shape[1], pool["k"].shape[2]
-        pos = positions[:, 0]
-        blk = jnp.take_along_axis(block_table, (pos // bs)[:, None],
-                                  axis=1)[:, 0]
-        flat = jnp.where(valid[:, 0], blk * bs + pos % bs, 0)  # 0 = scratch
-        new_pool = {}
-        for name, new_rows in (("k", k_new), ("v", v_new)):
-            flat_pool = pool[name].reshape(
-                pool[name].shape[0], n_blocks * bs, *pool[name].shape[3:])
-            flat_pool = flat_pool.at[:, flat].set(new_rows[:, :, 0])
-            new_pool[name] = flat_pool.reshape(pool[name].shape)
-        x = _rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-        return logits.astype(jnp.float32), new_pool
-
-    def scan_body(x, layer_in):
-        layer_p, kp, vp = layer_in
-        x, (kp, vp) = _attn_sublayer_paged(
-            x, layer_p, positions, c, kp, vp, block_table, lengths, valid)
-        x = _mlp_sublayer(x, layer_p, c)
-        return x, (kp, vp)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        scan_body, x, (params["layers"], pool["k"], pool["v"]))
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
-
-
-def forward_with_cache(params, tokens, cache, lengths, config: LlamaConfig):
-    """Incremental forward for generation (prefill when S>1, decode at S=1).
-
-    tokens: [B, S] the NEW tokens, logically at positions lengths..lengths+S.
-    cache:  dict from init_kv_cache (functionally updated and returned).
-    lengths: [B] int32 — number of tokens already in the cache per row.
-    -> (logits [B, S, vocab] fp32, new_cache)
-
-    Reference parity note: ray has no inference engine (serving delegates to
-    user code / vLLM); this is the TPU-native decode path that
-    ray_tpu.inference builds continuous batching on.
-    """
-    c = config
-    b, s = tokens.shape
-    positions = lengths[:, None] + jnp.arange(s)[None, :]
     # Same embed-dim constraint as forward_hidden: under an ambient sharded
     # mesh a gather from an fsdp-sharded table forces a full-remat reshard.
     table = with_logical_constraint(params["embed"], ("vocab", "act_embed"))
     x = table[tokens].astype(c.dtype)
 
+    # Decode fast path (S = 1): layers only READ the pool; the new K/V comes
+    # out as [L,B,1,kv,K] ys and lands in the (donated) pool with one
+    # in-place scatter instead of a per-layer full-pool rewrite. Prefill
+    # layers write their rows and hand the layer's pool back as ys.
+    attn = _attn_sublayer_paged_decode if s == 1 else partial(
+        _attn_sublayer_paged, lengths=lengths, valid=valid)
+
+    def scan_body(x, layer_in):
+        layer_p, kp, vp = layer_in
+        x, kv = attn(x, layer_p, positions, c, kp, vp, block_table)
+        return _mlp_sublayer(x, layer_p, c), kv
+
+    x, (k_out, v_out) = jax.lax.scan(
+        scan_body, x, (params["layers"], pool["k"], pool["v"]))
+    new_pool = {"k": k_out, "v": v_out}
     if s == 1:
-        # Decode fast path: layers only READ the cache; each layer's new
-        # K/V comes out as a tiny [L,B,1,kv,K] ys and is scattered into
-        # the (donated) cache once, in place — the per-layer in-scan
-        # rewrite would cost a full cache read+write per token.
-        def decode_body(x, layer_in):
-            layer_p, k_cache, v_cache = layer_in
-            x, (k1, v1) = _attn_sublayer_decode(
-                x, layer_p, positions, c, k_cache, v_cache)
-            x = _mlp_sublayer(x, layer_p, c)
-            return x, (k1, v1)
+        n_blocks, bs = pool["k"].shape[1], pool["k"].shape[2]
+        # the one new row a sequence, addressed as _attn_sublayer_paged does
+        blk = jnp.take_along_axis(block_table, positions // bs, axis=1)
+        flat = jnp.where(valid, blk * bs + positions % bs, 0)[:, 0]
 
-        x, (k_new, v_new) = jax.lax.scan(
-            decode_body, x, (params["layers"], cache["k"], cache["v"]))
-        b_idx = jnp.arange(b)
-        new_cache = {
-            "k": cache["k"].at[:, b_idx, lengths].set(
-                k_new[:, :, 0], mode="drop"),
-            "v": cache["v"].at[:, b_idx, lengths].set(
-                v_new[:, :, 0], mode="drop"),
-        }
-    else:
-        def scan_body(x, layer_in):
-            layer_p, k_cache, v_cache = layer_in
-            x, (k_cache, v_cache) = _attn_sublayer(
-                x, layer_p, positions, c, kv_cache=(k_cache, v_cache),
-                lengths=lengths)
-            x = _mlp_sublayer(x, layer_p, c)
-            return x, (k_cache, v_cache)
+        def place(old, new_rows):
+            rows = old.reshape(old.shape[0], n_blocks * bs, *old.shape[3:])
+            return rows.at[:, flat].set(new_rows[:, :, 0]).reshape(old.shape)
 
-        x, (new_k, new_v) = jax.lax.scan(
-            scan_body, x, (params["layers"], cache["k"], cache["v"]))
-        new_cache = {"k": new_k, "v": new_v}
+        new_pool = {name: place(pool[name], new_rows)
+                    for name, new_rows in new_pool.items()}
     x = _rms_norm(x, params["final_norm"], c.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    return logits.astype(jnp.float32), new_cache
+    return logits.astype(jnp.float32), new_pool
 
 
 def loss_fn(params, batch, config: LlamaConfig, mesh=None,
@@ -665,13 +537,3 @@ def loss_fn(params, batch, config: LlamaConfig, mesh=None,
     else:
         denom = nll.size
     return jnp.sum(nll) / denom
-
-
-def flops_per_token(config: LlamaConfig, seq_len: int) -> float:
-    """Approx training FLOPs/token (fwd+bwd ≈ 6N + attention term)."""
-    c = config
-    param_flops = 6.0 * c.num_params()
-    # Causal attention: QK^T + PV = 2 matmuls × 2 flops × H·D × S/2 (causal
-    # average) × 3 (fwd+bwd) = 6·H·D·S per layer per token.
-    attn_flops = 6.0 * c.n_layers * c.n_heads * c.d_head * seq_len
-    return param_flops + attn_flops

@@ -136,7 +136,7 @@ def forward_hidden(params, tokens, config: MixtralConfig, mesh=None,
     x = lc(table[tokens].astype(c.dtype), ("batch", "seq", "act_embed"))
 
     def layer_fn(x, layer_p):
-        x, _ = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules)
+        x = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules)
         h2 = _rms_norm(x, layer_p["mlp_norm"], c.norm_eps)
         moe_out, aux = _moe_block(h2, layer_p, c, mesh)
         return lc(x + moe_out, ("batch", "seq", "act_embed")), aux

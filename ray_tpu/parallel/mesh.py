@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import math
 from typing import Dict, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
@@ -194,7 +193,16 @@ def initialize_distributed(
     )
 
 
-def best_mesh_for(n_devices: int, model_axis_max: int = 8) -> MeshConfig:
-    """Heuristic default: TP within a chip-group bound, rest data parallel."""
-    tp = math.gcd(n_devices, model_axis_max)
-    return MeshConfig(dp=n_devices // tp, tp=tp)
+def axis_plan(n_devices: int) -> Dict[str, int]:
+    """Split n devices over the (dp, fsdp, tp) named mesh, model axes
+    first (tp rides the fastest links, then fsdp shards params, remainder
+    is pure data parallel): 8 -> dp=2, fsdp=2, tp=2; 4 -> fsdp=2, tp=2;
+    2 -> tp=2; odd prime counts fall back to pure dp."""
+    plan = {"dp": 1, "fsdp": 1, "tp": 1}
+    rest = n_devices
+    for axis in ("tp", "fsdp"):
+        if rest % 2 == 0:
+            plan[axis] = 2
+            rest //= 2
+    plan["dp"] = rest
+    return plan

@@ -1115,7 +1115,7 @@ def _cmd_profile_device(args) -> int:
     elif not reports:
         print("no device-step profilers registered anywhere (a profiler "
               "appears with the first profiled train step / decode wave; "
-              "bench.py and the paged engine register them)")
+              "the paged engine registers one)")
     else:
         hdr = (f"{'proc':<16} {'profiler':<12} {'steps':>6} "
                f"{'input_wait':>10} {'h2d':>7} {'compile_s':>9} "

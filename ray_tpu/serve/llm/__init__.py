@@ -49,9 +49,8 @@ def build_llm_app(build_engine, *, name: str = "llm",
     """-> a bindable application: LLMRouter ingress over `num_replicas`
     LLMEngineReplica deployments.
 
-    build_engine() -> PagedInferenceEngine (continuous batching) or
-    InferenceEngine (wave batching); constructed inside each replica so
-    params land on the replica's device. `shed_queue_depth` is the
+    build_engine() -> PagedInferenceEngine, constructed inside each
+    replica so params land on the replica's device. `shed_queue_depth` is the
     aggregate outstanding-request bound past which the router sheds with
     429; `max_queue_depth` is the per-replica admission backstop."""
     from ray_tpu.serve.api import Deployment
@@ -93,7 +92,7 @@ def llm_deployment(build_engine, *, name: str = "llm",
     """Single-deployment engine app (no router): the original serve.llm
     surface, kept for handle-first users.
 
-        app = llm_deployment(lambda: InferenceEngine(params, cfg)).bind()
+        app = llm_deployment(lambda: PagedInferenceEngine(params, cfg)).bind()
         handle = serve.run(app)
         tokens = handle.generate.remote([1,2,3]).result()
     """

@@ -1,17 +1,11 @@
 """LLM engine replica: admission queue + continuous-batching loop.
 
-One replica hosts one inference engine. Requests stream in from the
-router as actor calls; a single batcher thread drains the admission
-queue through the engine:
-
-  * With a `PagedInferenceEngine` the batcher runs ONE long-lived
-    `serve_stream` service loop — requests are admitted between decode
-    chunks, so a request arriving mid-generation joins the running batch
-    instead of waiting behind it (true continuous batching).
-  * With the dense `InferenceEngine` (no dynamic admission) the batcher
-    falls back to wave mode: it coalesces whatever is queued into one
-    `generate_stream` run per wave — concurrency within a wave, queueing
-    between waves.
+One replica hosts one inference engine (`PagedInferenceEngine`). Requests
+stream in from the router as actor calls; a single batcher thread drains
+the admission queue through ONE long-lived `engine.serve_stream` service
+loop — requests are admitted between decode chunks, so a request arriving
+mid-generation joins the running batch instead of waiting behind it
+(continuous batching).
 
 Tokens flow back per-request through a hand-off queue; the replica's
 `generate_stream` method is a plain generator, which the Serve layer
@@ -29,7 +23,6 @@ batch-occupancy gauges the batcher refreshes every poll.
 
 from __future__ import annotations
 
-import dataclasses
 import itertools
 import logging
 import queue
@@ -70,16 +63,14 @@ class _Abort:
 
 
 class _Request:
-    __slots__ = ("req_id", "prompt", "max_new", "gen_override", "out",
-                 "enqueued_at", "enqueued_ns", "first_at", "last_at",
-                 "n_tokens", "cancelled")
+    __slots__ = ("req_id", "prompt", "max_new", "out", "enqueued_at",
+                 "enqueued_ns", "first_at", "last_at", "n_tokens",
+                 "cancelled")
 
-    def __init__(self, req_id: int, prompt: List[int], max_new: int,
-                 gen_override: Optional[GenerationConfig] = None):
+    def __init__(self, req_id: int, prompt: List[int], max_new: int):
         self.req_id = req_id
         self.prompt = prompt
         self.max_new = max_new
-        self.gen_override = gen_override
         # bounded by the request's own max_new token budget (one entry
         # per generated token, consumer-drained)
         self.out: "queue.SimpleQueue" = queue.SimpleQueue()  # raylint: disable=unbounded-queue
@@ -98,8 +89,12 @@ class LLMEngineReplica:
 
     def __init__(self, build_engine, default_config: Optional[dict] = None,
                  max_queue_depth: int = 64):
-        """build_engine() -> PagedInferenceEngine | InferenceEngine
-        (constructed in the replica so params land on its device).
+        """build_engine() -> the engine, constructed in the replica so
+        params land on its device. What the replica asks of it:
+        `serve_stream(feed, gen)` with the contract
+        `PagedInferenceEngine.serve_stream` documents, and `max_batch` and
+        `free_slots` for the backlog; `stats()`, `abort_reasons`,
+        `preemptions` and `prefix_stats` are read where present.
         `max_queue_depth` bounds requests waiting for engine admission;
         beyond it submissions fail with LLMOverloadedError (the router
         sheds earlier — this is the per-replica backstop)."""
@@ -110,8 +105,13 @@ class LLMEngineReplica:
 
         compile_cache.enable()
         self.engine = build_engine()
+        if not callable(getattr(self.engine, "serve_stream", None)):
+            raise TypeError(
+                f"build_engine() returned {type(self.engine).__name__}, "
+                "which has no serve_stream(feed, gen): the replica drives "
+                "an engine through that service loop and nothing else "
+                "(see PagedInferenceEngine.serve_stream)")
         self.default = GenerationConfig(**(default_config or {}))
-        self._continuous = hasattr(self.engine, "serve_stream")
         self._max_queue_depth = max_queue_depth
         # bounded by max_queue_depth at submit (LLMOverloadedError 429
         # past it) — the 429-shed half of the overload-protection story
@@ -167,8 +167,8 @@ class LLMEngineReplica:
         with self._lock:
             return max(0, len(self._requests) - decoding)
 
-    def _submit(self, prompt: List[int], max_new_tokens: Optional[int],
-                gen_override: Optional[GenerationConfig]) -> _Request:
+    def _submit(self, prompt: List[int],
+                max_new_tokens: Optional[int]) -> _Request:
         if self._shutdown.is_set():
             raise RuntimeError("replica is shutting down")
         if self._backlog() >= self._max_queue_depth:
@@ -181,7 +181,7 @@ class LLMEngineReplica:
                 f"({self._max_queue_depth} requests waiting)")
         rq = _Request(next(self._next_id), list(prompt),
                       max_new_tokens if max_new_tokens is not None
-                      else self.default.max_new_tokens, gen_override)
+                      else self.default.max_new_tokens)
         with self._lock:
             self._requests[rq.req_id] = rq
         self._queue.put(rq)
@@ -191,11 +191,7 @@ class LLMEngineReplica:
         rq.cancelled = True
         with self._lock:
             if self._requests.pop(rq.req_id, None) is not None:
-                if self._continuous:
-                    # only the serve_stream feed consumes cancel ids; the
-                    # wave path checks rq.cancelled directly (adding here
-                    # would grow the set forever)
-                    self._cancels.add(rq.req_id)
+                self._cancels.add(rq.req_id)  # consumed by the next _feed
                 self._m_requests.inc(tags=self._outcome_tags["cancelled"])
 
     def generate_stream(self, prompt: List[int],
@@ -209,7 +205,7 @@ class LLMEngineReplica:
         first_token = trace_ctx is not None
         span_cap = (CONFIG.trace_max_stream_spans
                     if trace_ctx is not None else 0)
-        rq = self._submit(prompt, max_new_tokens, None)
+        rq = self._submit(prompt, max_new_tokens)
         finished = False
         produced = 0
         try:
@@ -268,23 +264,15 @@ class LLMEngineReplica:
                  temperature: Optional[float] = None,
                  eos_token_id: Optional[int] = None) -> List[int]:
         """Unary path (and the llm_deployment compatibility surface).
-        Sampling overrides ride only on the dense-engine wave path; the
-        continuous loop compiles one sampling config per replica."""
-        override = None
+        The service loop compiles one sampling config per replica, so a
+        per-request `temperature` / `eos_token_id` is refused."""
         if temperature is not None or eos_token_id is not None:
-            override = dataclasses.replace(
-                self.default,
-                temperature=(self.default.temperature if temperature is None
-                             else temperature),
-                eos_token_id=(self.default.eos_token_id if eos_token_id
-                              is None else eos_token_id))
-            if self._continuous:
-                raise ValueError(
-                    "per-request sampling overrides are not supported by "
-                    "the continuous-batching engine (sampling params are "
-                    "compile-time constants); configure them per replica "
-                    "via default_config")
-        rq = self._submit(prompt, max_new_tokens, override)
+            raise ValueError(
+                "per-request sampling overrides are not supported by "
+                "the continuous-batching engine (sampling params are "
+                "compile-time constants); configure them per replica "
+                "via default_config")
+        rq = self._submit(prompt, max_new_tokens)
         out: List[int] = []
         while True:
             try:
@@ -312,7 +300,6 @@ class LLMEngineReplica:
             "queue_depth": self._backlog(),
             "outstanding_requests": len(self._requests),
             "finished_requests": self._n_finished,
-            "continuous_batching": self._continuous,
             "max_queue_depth": self._max_queue_depth,
         }
         eng_stats = getattr(self.engine, "stats", None)
@@ -346,11 +333,13 @@ class LLMEngineReplica:
     # -- batcher -------------------------------------------------------------
 
     def _run(self) -> None:
-        run = (self._run_continuous if self._continuous
-               else self._run_waves)
+        """One serve_stream service loop for the replica's lifetime,
+        restarted (its waiters failed) if it raises."""
         while not self._shutdown.is_set():
             try:
-                run()
+                for req_id, token, done in self.engine.serve_stream(
+                        self._feed, self.default):
+                    self._deliver(req_id, token, done)
             except Exception as e:  # noqa: BLE001 — fail waiters, recover
                 logger.exception("llm batcher loop failed; restarting")
                 self._fail_outstanding(e)
@@ -455,56 +444,3 @@ class LLMEngineReplica:
             with self._lock:
                 self._requests.pop(req_id, None)
                 self._n_finished += 1
-
-    def _run_continuous(self) -> None:
-        """One serve_stream service loop for the replica's lifetime."""
-        for req_id, token, done in self.engine.serve_stream(
-                self._feed, self.default):
-            self._deliver(req_id, token, done)
-
-    def _run_waves(self) -> None:
-        """Dense-engine fallback: coalesce queued requests into
-        generate_stream waves (concurrency within a wave)."""
-        try:
-            first = self._queue.get(timeout=0.2)
-        except queue.Empty:
-            return
-        wave = [first]
-        while len(wave) < self.engine.max_batch * 4:
-            try:
-                wave.append(self._queue.get_nowait())
-            except queue.Empty:
-                break
-        self._update_gauges()
-        # group by generation config: the engine streams one config per run
-        groups: Dict[Any, List[_Request]] = {}
-        for rq in wave:
-            if rq.cancelled:
-                continue
-            gen = dataclasses.replace(rq.gen_override or self.default,
-                                      max_new_tokens=rq.max_new)
-            groups.setdefault(gen, []).append(rq)
-        for gen, items in groups.items():
-            try:
-                for idx, token in self.engine.generate_stream(
-                        [rq.prompt for rq in items], gen):
-                    self._deliver(items[idx].req_id, token, done=False)
-            except Exception as e:  # noqa: BLE001 — report to this wave
-                for rq in items:
-                    rq.out.put(e)
-                    with self._lock:
-                        self._requests.pop(rq.req_id, None)
-                continue
-            # stream exhausted: everything this wave produced is out
-            self._count_tokens()
-            for rq in items:
-                with self._lock:
-                    alive = self._requests.pop(rq.req_id, None)
-                if alive is not None and not rq.cancelled:
-                    if rq.n_tokens >= 2:
-                        self._m_tpot.observe(
-                            (rq.last_at - rq.first_at) / (rq.n_tokens - 1),
-                            tags=self._tags)
-                    self._m_requests.inc(tags=self._outcome_tags["ok"])
-                    rq.out.put(_DONE)
-                    self._n_finished += 1

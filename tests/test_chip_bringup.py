@@ -2,8 +2,6 @@
 the cheap, chip-free half of chip_smoke.py's contract."""
 
 import os
-import subprocess
-import sys
 
 import pytest
 
@@ -29,24 +27,6 @@ def test_compile_cache_honours_the_variable_else_fixed_path(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == compile_cache.FIXED_DIR
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_bench_exits_nonzero_without_a_tpu():
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=REPO_ROOT,
-                          capture_output=True, text=True, timeout=60)
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""  # no result line
-    assert "no TPU chips" in proc.stderr
-
-
-def test_device_benchmarks_refuse_other_platforms():
-    from ray_tpu.inference.benchmarks import benchmark_engine
-    from ray_tpu.train import spmd_bench
-
-    with pytest.raises(RuntimeError, match="need a TPU"):
-        spmd_bench.run(1)
-    with pytest.raises(RuntimeError, match="need a TPU"):
-        benchmark_engine()
 
 
 def test_jax_backend_refuses_a_cpu_gang_that_asked_for_tpus(monkeypatch):

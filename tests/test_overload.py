@@ -14,6 +14,7 @@ amplification (the anti-retry-storm property the overload_storm drill
 exercises at the cluster level).
 """
 
+import os
 import random
 import time
 
@@ -372,7 +373,7 @@ def test_gcs_creation_queue_bound(overload_cluster):
         CONFIG.set("gcs_actor_creation_queue_max", prev)
 
 
-def test_serve_proxy_maps_deadline_header(overload_cluster):
+def test_serve_proxy_maps_deadline_header(overload_cluster, tmp_path):
     """X-Request-Timeout-S becomes a task deadline: a request whose
     budget expires is refused typed (504 = shed), not hung or lost."""
     import http.client
@@ -380,13 +381,29 @@ def test_serve_proxy_maps_deadline_header(overload_cluster):
     from ray_tpu import serve
     from ray_tpu._private.rpc import find_free_port
 
+    budget_s = 0.2
+    # The handler outlasts the tight request's budget by 0.8 s counted from
+    # its OWN entry, which the test observes before it sends that request:
+    # nothing here assumes how fast this machine gets a request into the
+    # replica. (The proxy stops waiting 1.0 s past the budget.)
+    hold_s = budget_s + 0.8
+    entered = str(tmp_path / "entered")
+
     @serve.deployment(max_ongoing_requests=1)
     def slow_echo(body=None):
-        time.sleep(0.5)
+        with open(entered, "a") as f:
+            f.write(".")
+        time.sleep(hold_s)
         return {"ok": True}
 
     port = find_free_port()
-    serve.run(slow_echo.bind(), name="overload_app", http_port=port)
+    # ONE proxy shard: the replica starts one caller's calls in order, and
+    # each shard is a caller of its own. Calls from two shards run side by
+    # side in a max_ongoing_requests=1 replica (ROADMAP D10), so the tight
+    # request would be served, not queued, whenever the kernel handed its
+    # connection to another shard than the blocker's.
+    serve.run(slow_echo.bind(), name="overload_app", http_port=port,
+              http_shards=1)
     try:
         def req(headers):
             conn = http.client.HTTPConnection(f"127.0.0.1:{port}",
@@ -408,10 +425,14 @@ def test_serve_proxy_maps_deadline_header(overload_cluster):
         # fill the single-ongoing replica, then send a tight request
         import threading
 
+        calls_before = os.path.getsize(entered)
         t = threading.Thread(target=req, args=({},), daemon=True)
         t.start()
-        time.sleep(0.1)
-        status = req({"X-Request-Timeout-S": "0.2"})
+        give_up = time.monotonic() + 30
+        while os.path.getsize(entered) == calls_before:
+            assert time.monotonic() < give_up, "request never reached replica"
+            time.sleep(0.005)
+        status = req({"X-Request-Timeout-S": str(budget_s)})
         assert status == 504, status
         t.join(timeout=10)
     finally:

@@ -1,14 +1,15 @@
 """Paged KV cache engine (PagedAttention layout; see
-ray_tpu/inference/paged_engine.py): parity with the dense engine, block
-accounting, many concurrent ragged streams on a small pool, and
-recompute-preemption when the pool runs dry."""
+ray_tpu/inference/paged_engine.py): the paged forward's logits against the
+plain forward, block accounting, many concurrent ragged streams on a small
+pool, and recompute-preemption when the pool runs dry. (Greedy engine
+output against naive full-forward decode: tests/test_inference.py.)"""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.inference import GenerationConfig, InferenceEngine
+from ray_tpu.inference import GenerationConfig
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
 from ray_tpu.models import llama
 
@@ -22,43 +23,57 @@ def tiny():
     return cfg, params
 
 
-def test_paged_forward_matches_dense_cache(tiny):
-    """Prefill+decode logits through the paged pool must match the dense
-    cache path position for position."""
-    cfg, params = tiny
+_PARITY_CASES = [
+    pytest.param(kv, qk, bs, None, id=f"kv{kv}-qknorm{int(qk)}-block{bs}")
+    for kv in (4, 2) for qk in (False, True) for bs in (4, 16)
+] + [pytest.param(2, True, 4, 5, id="kv2-qknorm1-block4-padded-tail")]
+
+
+@pytest.mark.parametrize("n_kv_heads,qk_norm,block_size,short_len",
+                         _PARITY_CASES)
+def test_paged_cache_matches_full_forward(n_kv_heads, qk_norm, block_size,
+                                          short_len):
+    """Prefill 8 tokens then decode 4, one at a time, through the pool:
+    every position's logits must match `llama.forward` over the whole
+    sequence (no cache at all). With `short_len`, row 1's prompt is that
+    many tokens inside the same padded [2, 8] prefill (its tail invalid),
+    and it decodes from there."""
+    cfg = llama.LlamaConfig.tiny(vocab_size=128)
+    cfg = cfg.__class__(**{**cfg.__dict__, "dtype": jnp.float32,
+                           "remat": False, "n_kv_heads": n_kv_heads,
+                           "qk_norm": qk_norm})
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    if qk_norm:  # init makes the scales 1: move them, or they test nothing
+        for name, seed in (("q_norm", 3), ("k_norm", 4)):
+            w = params["layers"][name]
+            params["layers"][name] = w + 0.2 * jax.random.normal(
+                jax.random.PRNGKey(seed), w.shape, w.dtype)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
                               cfg.vocab_size)
-    dense = llama.init_kv_cache(cfg, 2, 32)
-    d_logits, dense = llama.forward_with_cache(
-        params, toks, dense, jnp.zeros((2,), jnp.int32), cfg)
+    full = np.asarray(llama.forward(params, toks, cfg))  # [2, 12, V]
 
-    pool = llama.init_paged_kv_cache(cfg, n_blocks=9, block_size=8)
-    table = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
-    p_logits, pool = llama.forward_with_paged_cache(
-        params, toks, pool, table, jnp.zeros((2,), jnp.int32), cfg)
-    np.testing.assert_allclose(np.asarray(d_logits), np.asarray(p_logits),
-                               rtol=2e-4, atol=2e-4)
+    def close(got, want):
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   rtol=2e-4, atol=2e-4)
 
-    # one decode step on top
-    nxt = jnp.argmax(p_logits[:, -1], -1)[:, None].astype(jnp.int32)
-    d2, _ = llama.forward_with_cache(
-        params, nxt, dense, jnp.full((2,), 12, jnp.int32), cfg)
-    p2, _ = llama.forward_with_paged_cache(
-        params, nxt, pool, table, jnp.full((2,), 12, jnp.int32), cfg)
-    np.testing.assert_allclose(np.asarray(d2), np.asarray(p2),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_paged_engine_greedy_matches_dense_engine(tiny):
-    cfg, params = tiny
-    prompts = [[1, 5, 9, 2], [3, 3, 7], [11, 4, 8, 2, 6]]
-    gen = GenerationConfig(max_new_tokens=12)
-    dense = InferenceEngine(params, cfg, max_batch=2, max_len=64)
-    expected = dense.generate(prompts, gen)
-    paged = PagedInferenceEngine(params, cfg, max_batch=2, max_len=64,
-                                 block_size=8)
-    got = paged.generate(prompts, gen)
-    assert got == expected
+    n_pre = 8
+    lens = np.array([n_pre, short_len or n_pre], np.int32)
+    pool = llama.init_paged_kv_cache(cfg, n_blocks=9, block_size=block_size)
+    # each row's blocks out of order and interleaved with the other's
+    table = jnp.asarray([[3, 1, 5, 7], [2, 8, 4, 6]], jnp.int32)
+    valid = jnp.arange(n_pre)[None, :] < lens[:, None]
+    prefill = jnp.where(valid, toks[:, :n_pre], 0)
+    logits, pool = llama.forward_with_paged_cache(
+        params, prefill, pool, table, jnp.zeros(2, jnp.int32), cfg,
+        valid=valid)
+    for row in range(2):
+        close(logits[row, :lens[row]], full[row, :lens[row]])
+    for _ in range(4):
+        step, pool = llama.forward_with_paged_cache(
+            params, toks[np.arange(2), lens][:, None], pool, table,
+            jnp.asarray(lens), cfg)
+        close(step[:, 0], full[np.arange(2), lens])
+        lens = lens + 1
 
 
 def test_eight_concurrent_streams_small_pool(tiny):

@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+from ray_tpu.parallel.mesh import MeshConfig, axis_plan, build_mesh
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_sharding
 from ray_tpu.parallel.ring_attention import ring_attention_sharded
 from ray_tpu.parallel.pipeline import pipeline_sharded
@@ -31,6 +31,21 @@ def test_mesh_config_resolution():
     assert cfg.dp == 4
     with pytest.raises(ValueError):
         MeshConfig(dp=3, tp=3).resolved(8)
+
+
+@pytest.mark.parametrize("n,plan", [
+    (1, {"dp": 1, "fsdp": 1, "tp": 1}),
+    (2, {"dp": 1, "fsdp": 1, "tp": 2}),
+    (4, {"dp": 1, "fsdp": 2, "tp": 2}),
+    (8, {"dp": 2, "fsdp": 2, "tp": 2}),
+    (3, {"dp": 3, "fsdp": 1, "tp": 1}),
+])
+def test_axis_plan_fills_model_axes_first(n, plan):
+    """What chip_smoke.py's train phase and the four-chip cell's mesh
+    (fsdp 2 x tp 2) rest on: tp, then fsdp, take a factor of two each
+    while the count allows; the rest is data parallel."""
+    assert axis_plan(n) == plan
+    assert MeshConfig(**plan).resolved(n).dp == plan["dp"]
 
 
 def test_build_mesh_axes():

@@ -658,15 +658,19 @@ def test_llm_trace_spans_proxy_router_replica_engine(ray_start_regular,
 
     def build():
         class StubEngine:
-            """Dense-engine stub: yields 4 tokens per prompt, no JAX."""
+            """serve_stream stub: 4 tokens per request, no JAX."""
 
             max_batch = 4
             free_slots = list(range(4))
 
-            def generate_stream(self, prompts, gen):
-                for _ in range(4):
-                    for idx in range(len(prompts)):
-                        yield idx, 7
+            def serve_stream(self, feed, gen):
+                while True:
+                    new, _cancelled, stop = feed(True)
+                    for req_id, *_ in new:
+                        for i in range(4):
+                            yield req_id, 7, i == 3
+                    if stop:
+                        return
 
         return StubEngine()
 

@@ -581,3 +581,57 @@ def test_paged_engine_serve_stream_dynamic_admission(tiny):
     # dynamic path matches the one-shot batch path token for token
     assert eng.generate([[1, 2, 3]],
                         GenerationConfig(max_new_tokens=8))[0] == out["A"]
+
+
+class _StubEngine:
+    """serve_stream stub: `max_new` tokens per request, no JAX."""
+
+    max_batch = 2
+
+    def __init__(self):
+        self.free_slots = [0, 1]
+
+    def serve_stream(self, feed, gen):
+        while True:
+            new, _cancelled, stop = feed(True)
+            for req_id, _prompt, max_new, *_ in new:
+                for i in range(max_new):
+                    yield req_id, 7, i == max_new - 1
+            if stop:
+                return
+
+
+def test_replica_refuses_an_engine_without_serve_stream():
+    """The replica drives an engine through serve_stream and nothing
+    else: an object that only has generate_stream (what the removed
+    wave path took) is refused where it is built, by name."""
+    from ray_tpu.serve.llm.engine import LLMEngineReplica
+
+    class WaveOnly:
+        max_batch = 2
+        free_slots = [0, 1]
+
+        def generate_stream(self, prompts, gen):
+            yield 0, 7
+
+    with pytest.raises(TypeError, match=r"WaveOnly.*serve_stream\(feed, gen\)"):
+        LLMEngineReplica(WaveOnly)
+
+
+def test_replica_generate_refuses_per_request_sampling():
+    """One sampling config per replica (it is compiled into the decode
+    program): generate() keeps `temperature=` / `eos_token_id=` in its
+    signature and refuses a value for either, before anything is queued."""
+    from ray_tpu.serve.llm.engine import LLMEngineReplica
+
+    replica = LLMEngineReplica(_StubEngine, {"max_new_tokens": 3})
+    try:
+        for override in ({"temperature": 0.7}, {"eos_token_id": 2},
+                         {"temperature": 0.0, "eos_token_id": 2}):
+            with pytest.raises(ValueError, match="per-request sampling"):
+                replica.generate([1, 2, 3], **override)
+        assert replica.get_stats()["outstanding_requests"] == 0
+        assert replica.generate([1, 2, 3]) == [7, 7, 7]
+        assert replica.generate([1, 2, 3], max_new_tokens=2) == [7, 7]
+    finally:
+        replica.shutdown()

@@ -3,10 +3,15 @@
 The r05 HTTP p99 regression (3.39 -> 4.69 ms) shipped because nothing
 read the bench trajectory — a reviewer had to notice a number in a JSON
 artifact. This gate makes the machine notice: it loads every historical
-bench row (BENCH_r*.json artifacts + the BENCH_HISTORY.jsonl lines
-bench.py now appends), treats the newest row (or --current) as the run
-under test, and fails CI when a gated metric falls past its per-metric
-noise band versus the median of its history.
+bench row (BENCH_r*.json artifacts + BENCH_HISTORY.jsonl lines), treats
+the newest row (or --current) as the run under test, and fails CI when a
+gated metric falls past its per-metric noise band versus the median of
+its history.
+
+It has NO PRODUCER any more: the root bench script that wrote those rows
+was deleted (PR 29) and no tool runs this gate. The repo's benchmark is
+`BENCHMARK.json` + `benchmarks/`, its record `PERF_LEDGER.jsonl`; this
+file and tests/test_perf_gate.py are named debt (ROADMAP, D3 remainder).
 
 Two calibrations, because shared CI hosts are loud:
 
@@ -29,7 +34,7 @@ without declaring its regression policy.
 
 Usage:
     python -m tools.perf_gate                 # gate newest row, strict
-    python -m tools.perf_gate --smoke         # CI mode (tools/ci.sh)
+    python -m tools.perf_gate --smoke         # loose bands, shared hosts
     python -m tools.perf_gate --current f.json  # gate an explicit run
     python -m tools.perf_gate --list-metrics  # show policies + trajectory
 """
@@ -151,8 +156,8 @@ UNTRACKED: Tuple[str, ...] = (
 
 
 def flatten_result(result: Dict[str, Any]) -> Dict[str, Any]:
-    """One bench result (bench.py's printed object, or a BENCH_r*.json
-    'parsed' field) -> a flat metric->value row. The headline rides under
+    """One bench result (a {"metric", "value", "detail"} object, or a
+    BENCH_r*.json 'parsed' field) -> a flat metric->value row. The headline rides under
     its metric name; detail keys flatten with dotted paths; context keys
     get an underscore prefix so the gate never mistakes them for perf."""
     row: Dict[str, Any] = {}
@@ -221,8 +226,8 @@ def load_trajectory(root: str = REPO_ROOT,
                     history_file: Optional[str] = None,
                     bench_glob: Optional[str] = None) -> List[Dict[str, Any]]:
     """All known bench rows, oldest first: BENCH_r*.json artifacts, then
-    BENCH_HISTORY.jsonl lines (the machine-readable trajectory bench.py
-    appends — already flattened)."""
+    BENCH_HISTORY.jsonl lines (the machine-readable trajectory,
+    already flattened)."""
     rows: List[Dict[str, Any]] = []
     for path in sorted(_glob.glob(
             os.path.join(root, bench_glob or BENCH_GLOB))):
@@ -247,9 +252,9 @@ def load_trajectory(root: str = REPO_ROOT,
 
 def append_history(result: Dict[str, Any],
                    path: Optional[str] = None) -> Dict[str, Any]:
-    """Append one flattened metric->value JSON line for this bench run —
-    called by bench.py so the gate reads a machine-readable trajectory
-    instead of parsing BENCH_r*.json tails."""
+    """Append one flattened metric->value JSON line for this bench run,
+    so the gate reads a machine-readable trajectory instead of parsing
+    BENCH_r*.json tails."""
     row = flatten_result(result)
     row["_ts"] = round(time.time(), 3)
     path = path or os.path.join(REPO_ROOT, HISTORY_FILE)
@@ -355,8 +360,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--history", help=f"history file (default "
                                       f"<root>/{HISTORY_FILE})")
     ap.add_argument("--current",
-                    help="bench result JSON to gate (bench.py output "
-                         "object or a BENCH_r*.json artifact); default: "
+                    help="bench result JSON to gate (a result object "
+                         "or a BENCH_r*.json artifact); default: "
                          "the newest trajectory row")
     ap.add_argument("--smoke", action="store_true",
                     help="loose noise bands for shared CI hosts (strict "
