@@ -22,8 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
+from ray_tpu._private import device_profiler
 from ray_tpu.ops.flash_attention import flash_attention
-from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
+from ray_tpu.parallel.sharding import (
+    LogicalAxisRules,
+    logical_sharding,
+    with_logical_constraint,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -234,16 +239,95 @@ def _qkv(x, params, positions, config: LlamaConfig, lc=None):
     return q, k, v
 
 
+# The residual stream [B, S, D] BETWEEN sublayers: the sequence dim over
+# `sp` and `tp` ("res_seq"). On a tp mesh the sum over tp that ends a
+# row-parallel matmul (wo, w_down) then lands as a reduce-scatter, norms and
+# residual adds run on S / tp rows a chip, and the next column-parallel
+# matmul (q/k/v, gate/up) gathers the rows it needs. Where tp does not
+# divide S (decode, S = 1) or is 1 this is `("batch", "seq", "act_embed")`.
+_RESIDUAL = ("batch", "res_seq", "act_embed")
+
+
+def _residual_seq_axes(x, mesh, rules) -> tuple:
+    """The mesh axes that x's sequence dim is scattered over between
+    sublayers, on a mesh handed in by the caller (the training step); ()
+    without one (the paged forward's ambient mesh is the compiler's)."""
+    if mesh is None:
+        return ()
+    axes = logical_sharding(mesh, _RESIDUAL, rules, x.shape).spec[1]
+    return axes if isinstance(axes, tuple) else (axes,) if axes else ()
+
+
+def _residual(x, mesh=None, rules: Optional[LogicalAxisRules] = None):
+    """Hold the residual stream to its layout between sublayers."""
+    if "tp" in _residual_seq_axes(x, mesh, rules):
+        # per LOWERING of a boundary, not per run (the scanned layer body
+        # lowers once for all layers)
+        device_profiler.count("tp.seq_sharded_boundaries")
+    return with_logical_constraint(x, _RESIDUAL, mesh=mesh, rules=rules)
+
+
+def _mlp_ring(h, params, mesh):
+    """silu(h w_gate) * (h w_up) w_down over tp with every transfer under a
+    matmul. h [B, S, D] arrives with S scattered over tp and the result
+    leaves so; gate/up are column-parallel, w_down row-parallel. Left to the
+    compiler, the all-gather of h and the reduce-scatter of the output stand
+    alone on the device's op line, 1.44 and 1.81 ms a layer each way at
+    train-4chip's shapes (PERF.md §6, PR 30). Here the chips pass their row
+    chunks round a ring (`ppermute`, an async collective-permute) while they
+    multiply the chunk they hold, then pass the partial sums of w_down's
+    output round it while they multiply the next chunk: position t of `ffs`
+    holds chunk (i + t) % n on chip i, so no index depends on the chip.
+    Manual over tp only; batch and fsdp stay the compiler's."""
+    from jax.sharding import PartitionSpec as P
+
+    n = mesh.shape["tp"]
+    to_previous = [(i, (i - 1) % n) for i in range(n)]
+
+    def ring(h, w_gate, w_up, w_down):
+        ffs = []
+        for t in range(n):
+            arriving = (jax.lax.ppermute(h, "tp", to_previous)
+                        if t < n - 1 else None)
+            ffs.append(jax.nn.silu(jnp.einsum("bsd,df->bsf", h, w_gate))
+                       * jnp.einsum("bsd,df->bsf", h, w_up))
+            h = arriving
+        # chip i sums chunk (i + 1 + t) % n at step t, its own one last
+        out = None
+        for t in range(n):
+            part = jnp.einsum("bsf,fd->bsd", ffs[(t + 1) % n], w_down)
+            if out is not None:
+                # the barrier keeps the sum out of the matmul's fusion,
+                # where it would make the matmul wait for the transfer
+                part, arrived = jax.lax.optimization_barrier(
+                    (part, jax.lax.ppermute(out, "tp", to_previous)))
+                part = part + arrived
+            out = part
+        return out
+
+    return jax.shard_map(
+        ring, mesh=mesh, axis_names={"tp"},
+        in_specs=(P(None, "tp", None), P(None, "tp"), P(None, "tp"),
+                  P("tp", None)),
+        out_specs=P(None, "tp", None),
+    )(h, params["w_gate"], params["w_up"], params["w_down"])
+
+
 def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
                    rules: Optional[LogicalAxisRules] = None):
     """Pre-norm causal attention block of the training layer (and of
     mixtral's)."""
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     q, k, v = _qkv(x, params, positions, config, lc)
+    if "tp" in _residual_seq_axes(x, mesh, rules):
+        # v too leaves its projection with heads over tp, from the rows
+        # gathered for q and k: left unsaid, the compiler projects the local
+        # rows onto every head and turns v round with an all-to-all
+        v = lc(v, ("batch", "seq", "act_heads", "act_kv"))
     attn = _attention(q, k, v, config, mesh)
     attn = _checkpoint_name(attn, "attn_out")
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
-    return lc(x, ("batch", "seq", "act_embed"))
+    return _residual(x, mesh, rules)
 
 
 def _mlp_sublayer(x, params, config: LlamaConfig, mesh=None,
@@ -252,12 +336,15 @@ def _mlp_sublayer(x, params, config: LlamaConfig, mesh=None,
     c = config
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     h = _rms_norm(x, params["mlp_norm"], c.norm_eps)
+    if _residual_seq_axes(x, mesh, rules) == ("tp",) \
+            and c.d_ff % mesh.shape["tp"] == 0:
+        return _residual(x + _mlp_ring(h, params, mesh), mesh, rules)
     gate = jnp.einsum("bsd,df->bsf", h, params["w_gate"])
     up = jnp.einsum("bsd,df->bsf", h, params["w_up"])
     gate = lc(gate, ("batch", "seq", "act_mlp"))
     ff = jax.nn.silu(gate) * up
     x = x + jnp.einsum("bsf,fd->bsd", ff, params["w_down"])
-    return lc(x, ("batch", "seq", "act_embed"))
+    return _residual(x, mesh, rules)
 
 
 def _layer(x, params, positions, config: LlamaConfig, mesh=None,
@@ -280,8 +367,7 @@ def forward_hidden(params, tokens, config: LlamaConfig, mesh=None,
     # (replicate-then-repartition). With embed replicated at the gather the
     # reshard to the activation spec is a local slice.
     table = lc(params["embed"], ("vocab", "act_embed"))
-    x = table[tokens].astype(c.dtype)
-    x = lc(x, ("batch", "seq", "act_embed"))
+    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
 
     layer_fn = partial(_layer, positions=positions, config=c, mesh=mesh,
                        rules=rules)

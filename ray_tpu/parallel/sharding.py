@@ -27,6 +27,13 @@ DEFAULT_RULES: List[Tuple[str, PhysicalAxes]] = [
     # DCN all-reduce -> ICI all-gather); model axes stay on ICI.
     ("batch", ("dcn", "dp", "fsdp")),
     ("seq", "sp"),               # sequence/context parallel
+    # The residual stream BETWEEN sublayers: its sequence dim also takes
+    # `tp` (Megatron's sequence parallelism). A row-parallel matmul (wo,
+    # w_down) then ends in a reduce-scatter and a column-parallel one
+    # (q/k/v, gate/up) begins with an all-gather the compiler can run
+    # beside it, where a whole-sequence residual needs an all-reduce that
+    # nothing overlaps (PERF.md §6, PR 30).
+    ("res_seq", ("sp", "tp")),
     ("act_embed", None),         # activations: embed replicated
     ("act_heads", "tp"),         # attention activations: heads over TP
     ("act_kv", None),
@@ -48,14 +55,17 @@ class LogicalAxisRules:
     def __init__(self, rules: Optional[Sequence[Tuple[str, PhysicalAxes]]] = None):
         self._rules: Dict[str, PhysicalAxes] = dict(rules if rules is not None else DEFAULT_RULES)
 
-    def to_physical(self, logical_axes: Sequence[Optional[str]], mesh=None):
+    def to_physical(self, logical_axes: Sequence[Optional[str]], mesh=None,
+                    shape: Optional[Sequence[int]] = None):
         """Map logical axis names to a PartitionSpec, dropping mesh axes of
-        size 1 (so the same model code runs on any mesh shape)."""
+        size 1 (so the same model code runs on any mesh shape) and, given
+        the array's `shape`, mesh axes that would not divide their dim
+        evenly (a decode step's S = 1, an odd test length)."""
         from jax.sharding import PartitionSpec
 
         sizes = dict(mesh.shape) if mesh is not None else None
 
-        def resolve(name: Optional[str]):
+        def resolve(i: int, name: Optional[str]):
             if name is None:
                 return None
             phys = self._rules.get(name)
@@ -64,11 +74,19 @@ class LogicalAxisRules:
             axes = (phys,) if isinstance(phys, str) else tuple(phys)
             if sizes is not None:
                 axes = tuple(a for a in axes if sizes.get(a, 1) > 1)
+                if shape is not None:
+                    kept, shards = [], 1
+                    for a in axes:
+                        if shape[i] % (shards * sizes[a]) == 0:
+                            kept.append(a)
+                            shards *= sizes[a]
+                    axes = tuple(kept)
             if not axes:
                 return None
             return axes if len(axes) > 1 else axes[0]
 
-        return PartitionSpec(*[resolve(n) for n in logical_axes])
+        return PartitionSpec(
+            *[resolve(i, n) for i, n in enumerate(logical_axes)])
 
     def replace(self, **kwargs: PhysicalAxes) -> "LogicalAxisRules":
         new = LogicalAxisRules(list(self._rules.items()))
@@ -77,16 +95,18 @@ class LogicalAxisRules:
 
 
 def logical_sharding(mesh, logical_axes: Sequence[Optional[str]],
-                     rules: Optional[LogicalAxisRules] = None):
+                     rules: Optional[LogicalAxisRules] = None,
+                     shape: Optional[Sequence[int]] = None):
     from jax.sharding import NamedSharding
 
     rules = rules or LogicalAxisRules()
-    return NamedSharding(mesh, rules.to_physical(logical_axes, mesh))
+    return NamedSharding(mesh, rules.to_physical(logical_axes, mesh, shape))
 
 
 def with_logical_constraint(x, logical_axes: Sequence[Optional[str]],
                             mesh=None, rules: Optional[LogicalAxisRules] = None):
-    """Annotate an intermediate value inside jit with a logical sharding."""
+    """Annotate an intermediate value inside jit with a logical sharding;
+    each dim keeps only the mesh axes that divide it."""
     import jax
 
     if mesh is None:
@@ -97,7 +117,7 @@ def with_logical_constraint(x, logical_axes: Sequence[Optional[str]],
             return x
     rules = rules or LogicalAxisRules()
     return jax.lax.with_sharding_constraint(
-        x, logical_sharding(mesh, logical_axes, rules)
+        x, logical_sharding(mesh, logical_axes, rules, x.shape)
     )
 
 

@@ -77,7 +77,10 @@ def test_no_model_collective_crosses_dcn():
     violations = []
     for line in hlo.splitlines():
         if re.search(r"\b(all-gather|reduce-scatter|all-to-all)\b", line):
-            for group in re.findall(r"\{([0-9,]+)\}", line):
+            # the groups only: a layout such as {5,4,3,2,1,0} is not one
+            groups = re.search(r"replica_groups=\{(\{[0-9,{}]*\})\}", line)
+            for group in re.findall(r"\{([0-9,]+)\}",
+                                    groups[1] if groups else ""):
                 ids = [int(x) for x in group.split(",") if x != ""]
                 if len({_slice_of(i) for i in ids}) > 1:
                     violations.append(line.strip()[:160])

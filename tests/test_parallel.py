@@ -238,6 +238,143 @@ def test_collective_report_per_mesh_config():
     assert tp["total"]["bytes"] > 0
 
 
+def _llama_loss_and_grads(cfg, toks, mesh_cfg=None, rules=None):
+    """Loss and gradients of `llama.loss_fn` on one seeded batch: on a
+    single device, or with parameters and batch placed on `mesh_cfg`'s
+    mesh (over the first devices it needs)."""
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.sharding import shard_params
+
+    params = llama.init(cfg, jax.random.PRNGKey(0))
+    batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
+    if mesh_cfg is None:
+        fn = jax.jit(jax.value_and_grad(partial(llama.loss_fn, config=cfg)))
+        return fn, params, batch
+    n = int(np.prod(list(mesh_cfg.axis_sizes().values())))
+    mesh = build_mesh(mesh_cfg, devices=jax.devices()[:n])
+    rules = rules or LogicalAxisRules()
+    params = shard_params(params, llama.param_logical_axes(cfg), mesh, rules)
+    bs = logical_sharding(mesh, ("batch", "seq"), rules,
+                          batch["inputs"].shape)
+    batch = jax.device_put(batch, bs)
+    fn = jax.jit(jax.value_and_grad(
+        partial(llama.loss_fn, config=cfg, mesh=mesh, rules=rules)))
+    return fn, params, batch
+
+
+def _seq_sharded_boundaries():
+    from ray_tpu._private import device_profiler
+
+    return device_profiler.snapshot()["counters"].get(
+        "tp.seq_sharded_boundaries", 0)
+
+
+def _assert_matches_single_device(cfg, toks, mesh_cfg):
+    fn, params, batch = _llama_loss_and_grads(cfg, toks)
+    want_loss, want = fn(params, batch)
+    fn, params, batch = _llama_loss_and_grads(cfg, toks, mesh_cfg)
+    loss, grads = fn(params, batch)
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=2e-5,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("mesh_cfg,ring", [
+    (MeshConfig(dp=1, fsdp=2, tp=2), False),
+    (MeshConfig(dp=2, sp=2, tp=2), True),
+    (MeshConfig(dp=2, tp=4), False),
+], ids=["fsdp2_tp2", "dp2_sp2_tp2", "dp2_tp4"])
+def test_llama_seq_sharded_residual_matches_single_device(mesh_cfg, ring):
+    """The residual stream between sublayers is sequence-sharded over tp
+    (and sp): the loss and EVERY gradient leaf are the single-device
+    program's, and the boundaries were lowered in that layout. With tp
+    alone on the sequence the MLP runs as `llama._mlp_ring` (two chips,
+    and four: every chunk of the ring in its place); with sp beside it,
+    as the compiler lays it out."""
+    from ray_tpu.models import llama
+
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(), dtype=jnp.float32, use_ring_attention=ring,
+        # the parameters' heads dim is placed over tp: 2 kv heads cannot be
+        n_kv_heads=max(2, mesh_cfg.tp))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (8, 33), 0,
+                              cfg.vocab_size)
+    before = _seq_sharded_boundaries()
+    _assert_matches_single_device(cfg, toks, mesh_cfg)
+    assert _seq_sharded_boundaries() > before
+
+
+@pytest.mark.parametrize("seq", [33, 1])
+def test_llama_residual_layout_adapts_to_the_sequence(seq):
+    """tp 2 divides neither S = 33 nor S = 1 (a decode step): the mesh
+    axis is dropped from that dim, the step lowers in the whole-sequence
+    layout and matches the single-device program."""
+    from ray_tpu.models import llama
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (8, seq + 1), 0,
+                              cfg.vocab_size)
+    before = _seq_sharded_boundaries()
+    _assert_matches_single_device(cfg, toks, MeshConfig(dp=1, fsdp=2, tp=2))
+    assert _seq_sharded_boundaries() == before
+
+
+def test_seq_sharded_boundaries_counts_lowered_boundaries():
+    """`tp.seq_sharded_boundaries`: one a sublayer boundary LOWERED with
+    the sequence over tp (the embedding's output and the scanned layer
+    body's two: the body lowers once for all layers), none without a tp
+    mesh."""
+    from ray_tpu.models import llama
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (8, 33), 0,
+                              cfg.vocab_size)
+    before = _seq_sharded_boundaries()
+    for mesh_cfg in (None, MeshConfig(dp=4)):
+        fn, params, batch = _llama_loss_and_grads(cfg, toks, mesh_cfg)
+        fn.lower(params, batch)
+    assert _seq_sharded_boundaries() == before
+    fn, params, batch = _llama_loss_and_grads(
+        cfg, toks, MeshConfig(dp=1, fsdp=2, tp=2))
+    fn.lower(params, batch)
+    assert _seq_sharded_boundaries() - before >= 3
+
+
+def test_collective_report_seq_sharded_residual():
+    """On a tp 2 mesh the tp boundary is a reduce-scatter and an
+    all-gather, against the whole-sequence layout (the same rules with
+    "res_seq" over sp alone, which is what the residual had before): the
+    activations now reach attention's column-parallel matmuls through
+    all-gathers (the MLP's go round `llama._mlp_ring` as
+    collective-permutes), and where the backend forms reduce-scatters the
+    all-reduced bytes fall. (XLA's CPU pipeline keeps each as an all-reduce
+    whose result it slices; the v5e's compiler fuses them,
+    tests/test_tpu_aot_compile.py.)"""
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.hlo_report import collective_report
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (8, 33), 0,
+                              cfg.vocab_size)
+    mesh_cfg = MeshConfig(dp=1, fsdp=2, tp=2)
+    whole = collective_report(*_llama_loss_and_grads(
+        cfg, toks, mesh_cfg, LogicalAxisRules().replace(res_seq="sp")))
+    sharded = collective_report(*_llama_loss_and_grads(cfg, toks, mesh_cfg))
+    # one chip's block of the residual stream, whole sequence
+    activation = (8 // 2) * 32 * cfg.d_model * 4
+    scattered = sharded["reduce-scatter"]["bytes"]
+    assert (sharded["all-gather"]["bytes"] - whole["all-gather"]["bytes"]
+            >= 4 * activation)
+    assert sharded["collective-permute"]["bytes"] >= 4 * activation // 2
+    assert (sharded["all-reduce"]["bytes"] + scattered
+            <= whole["all-reduce"]["bytes"] + activation)
+    if scattered:
+        assert sharded["all-reduce"]["bytes"] < whole["all-reduce"]["bytes"]
+
+
 def test_llama_ring_attention_mesh():
     import optax
     from ray_tpu.models import llama
