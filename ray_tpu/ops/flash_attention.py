@@ -295,7 +295,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     steps = functools.partial(
         _key_steps, block_q=block_q, width=width, seq_k=seq_k,
         pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
-    o0 = jnp.zeros_like(q)
+    o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
     # they broadcast along lanes with no relayout
     m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
@@ -323,7 +323,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     plan = block_schedule(s_q, s_k, block_q, block_k, causal)["fwd"]
     _count_steps(plan)
     # Pad to block multiples: dynamic_slice CLAMPS out-of-range starts, which
@@ -344,14 +344,14 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, s_k_pad, d_v), lambda b_, h_, i: (b_, h_, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, d_v), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(q.shape[:3] + (d_v,), q.dtype),
             jax.ShapeDtypeStruct((b, h, s_q_pad, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -496,7 +496,7 @@ def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     # Same padding rationale as the forward (dynamic_slice clamping).
     q, do, lse, delta = (_pad_seq(x, block_q) for x in (q, do, lse, delta))
     k, v = _pad_seq(k, plan.width), _pad_seq(v, plan.width)
@@ -511,8 +511,8 @@ def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, s_k_pad, d_v), lambda b_, h_, i: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, block_q, d_v), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
         ],
@@ -528,7 +528,7 @@ def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     width = plan.width
     q, do, lse, delta = (_pad_seq(x, width) for x in (q, do, lse, delta))
     k, v = _pad_seq(k, block_k), _pad_seq(v, block_k)
@@ -547,14 +547,14 @@ def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
         in_specs=[
             pl.BlockSpec((1, 1, s_q_pad, d), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, s_q_pad, d), lambda b_, h_, j: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, d_v), lambda b_, h_, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, s_q_pad, d_v), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, block_k, d_v), lambda b_, h_, j: (b_, h_, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),

@@ -12,6 +12,12 @@ serve both entry points:
   for a plain sum: the backward pass needs none of the down projection's
   output, and under remat reruns neither it nor the un-permute. No
   capacity, no dropped token, no [T, E, C] tensor.
+  Told which experts it holds (`held=(first_expert, n_held)`: one chip's
+  share of an expert-parallel deployment, run without its exchange), it
+  routes over ALL the router's experts and computes only the (token, slot)
+  pairs whose expert is held (`_held_experts`): what the absent experts
+  would add is left out, nothing that lands here is dropped, and device
+  time follows the rows that landed.
 - `moe_shard_map`: experts sharded over the `ep` mesh axis, token buffers
   exchanged with `lax.all_to_all`. The exchange needs a static buffer, so
   this path alone is capacity-bounded ([T, E, C] dispatch and combine
@@ -39,23 +45,48 @@ class MoEAux(NamedTuple):
 
 class Routing(NamedTuple):
     logits: jax.Array   # [T, E] float32 router logits
-    probs: jax.Array    # [T, E] float32 softmax over all experts
+    probs: jax.Array    # [T, E] float32 scores of all experts (`score`)
     weights: jax.Array  # [T, k] float32 combine weights of the chosen k
     experts: jax.Array  # [T, k] int32 the chosen experts, best first
 
 
-def route(x, router_w, k: int, norm_topk_prob: bool = False) -> Routing:
-    """x [T, D], router_w [D, E] -> the top-k choice per token. Logits,
-    softmax and weights are float32 whatever the model dtype: a bf16 logit
-    would flip choices between near-equal experts. `norm_topk_prob` divides
-    the k weights by their sum (Mixtral; OLMoE leaves them as they are)."""
+def route(x, router_w, k: int, norm_topk_prob: bool = False, *,
+          score: str = "softmax", bias=None, scale: float = 1.0) -> Routing:
+    """x [T, D], router_w [D, E] -> the top-k choice per token, over ALL E
+    experts whether or not they are held here. Logits, scores and weights
+    are float32 whatever the model dtype: a bf16 logit would flip choices
+    between near-equal experts.
+
+    `score` is the scoring function: "softmax" over the experts (Mixtral,
+    OLMoE) or an independent "sigmoid" per expert (DeepSeek-V3's form).
+    `bias` [E] is added to the scores for the CHOICE only (`noaux_tc`): it
+    gets no gradient and the weights are the unbiased scores of the chosen.
+    `norm_topk_prob` divides the k weights by their sum (over all k chosen,
+    held or not; Mixtral and DeepSeek-V3; OLMoE leaves them as they are);
+    `scale` then multiplies them (`routed_scaling_factor`)."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown scoring function {score!r}")
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x, router_w, precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
+        probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if bias is None:
+            weights, experts = jax.lax.top_k(probs, k)
+        else:
+            _, experts = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+            # the chosen experts' own scores, as a masked sum over the
+            # experts: a gather of T x k scalars out of [T, E] and the
+            # scatter that transposes it take 0.67 and 0.57 ms on the v5e
+            # at 8,192 x 256, three times a layer (PERF.md section 6, PR 32)
+            chosen = experts[..., None] == jnp.arange(probs.shape[-1])
+            weights = jnp.sum(
+                jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
         if norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
         return Routing(logits, probs, weights, experts.astype(jnp.int32))
 
 
@@ -159,11 +190,203 @@ def _reorder_bwd(res, g):
 _reorder.defvjp(_reorder_fwd, _reorder_bwd)
 
 
-def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False):
+def share_capacities(t: int, k: int, n_held: int, n_experts: int) -> tuple:
+    """Static row capacities of a share's buffers, smallest first: twice the
+    T x k x n_held / n_experts rows that land here when routing is even
+    (rounded up to the grouped matmul's row tile), doubling up to T x k, the
+    most that can land (every choice of every token held): nothing is
+    dropped at any imbalance. Twice: at seeded weights the live rows of a
+    routed block scatter widely around the even share (0.16x to 2.2x over
+    ~4,000 blocks of sixteen seeds on the v5e); the smallest capacity's
+    [rows, D] buffers are then the 64 MiB the chip's gathers stage in fast
+    memory, and 1-4% of a seed's blocks run the next one, 7.3 ms dearer
+    (PERF.md section 6, PR 32, on why not 1.25x or 2.5x). Holding every
+    expert gives (T x k,)."""
+    rows = t * k
+    cap = -(-2 * (rows * n_held // n_experts) // 256) * 256
+    caps = []
+    while 0 < cap < rows:
+        caps.append(cap)
+        cap *= 2
+    return tuple(caps) + (rows,)
+
+
+def sort_held(experts, first_expert: int, n_held: int):
+    """experts [T, k] -> (order, inverse, group_sizes): the pairs whose
+    expert is in [first_expert, first_expert + n_held) FIRST, by expert
+    (stable), then the absent ones. `order[s]` is the pair at sorted
+    position s, `inverse[p]` the sorted position of pair p = t * k + j,
+    `group_sizes[i]` the pairs of held expert i. Their sum is the LIVE rows:
+    the sorted positions below it hold every pair that landed here, so a
+    buffer of at least that many rows drops nothing."""
+    flat = experts.reshape(-1) - first_expert
+    local = jnp.where((flat >= 0) & (flat < n_held), flat, n_held)
+    pairs = jnp.arange(local.shape[0], dtype=jnp.int32)
+    keys, order = jax.lax.sort_key_val(local, pairs)
+    inverse = jax.lax.sort_key_val(order, pairs)[1]
+    # counts without a scatter (a `bincount` of 65,536 pairs serialises on
+    # the TPU, PERF.md section 7): where each expert's run starts in the keys
+    starts = jnp.sum(keys[None, :] < jnp.arange(n_held + 1)[:, None], axis=1)
+    return order, inverse, jnp.diff(starts).astype(jnp.int32)
+
+
+# A share's two moves of rows, each the other's transpose (as `_permute` and
+# `_combine` are for the whole dispatch): `token[s]` is the token of sorted
+# row s, or T for a dead row (past the live ones); `slot[t, j]` the row of
+# token t's j-th pair, or a dead row for a pair whose expert is absent.
+# Summing a token's rows is a gather of T x k rows whatever landed here; a
+# scatter-add of the live rows into their tokens takes twice as long on the
+# v5e (1.83 against 0.96 ms for 16,384 rows of 2,048; PERF.md section 6, PR 32).
+
+@jax.custom_vjp
+def _take_rows(x, token, slot):
+    """x [T, D] -> [cap, D]: row s is token `token[s]`, zeros if dead."""
+    return x.at[token].get(mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(x, token, slot):
+    return _take_rows(x, token, slot), (token, slot)
+
+
+def _take_rows_bwd(res, g):
+    token, slot = res
+    # the grouped matmuls' backward leaves dead rows unwritten
+    g = jnp.where((token < slot.shape[0])[:, None], g, jnp.zeros((), g.dtype))
+    return _sum_rows(g, token, slot), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _sum_rows(rows, token, slot):
+    """rows [cap, D], zero where dead -> [T, D]: the sum of each token's k
+    rows, accumulated in float32, in rows.dtype."""
+    return jnp.sum(rows[slot].astype(jnp.float32), axis=1).astype(rows.dtype)
+
+
+def _sum_rows_fwd(rows, token, slot):
+    return _sum_rows(rows, token, slot), (token, slot)
+
+
+def _sum_rows_bwd(res, g):
+    return _take_rows(g, *res), None, None
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
+               cap: int):
+    """One capacity's program: the first `cap` sorted pairs (all the live
+    ones, and at least one dead row unless every pair is live, or
+    `_capacity_switch` would not have chosen it) through the held experts'
+    SwiGLU, summed back into their tokens -> [T, D] in x.dtype. Plain ops,
+    differentiable in x and the experts' weights: rows past the live ones
+    read no token and are masked out of every grouped matmul's result (the
+    kernels visit live tiles only and leave the rest unwritten), so they
+    are zero where a token's absent pairs point."""
+    t = x.shape[0]
+    live = jnp.sum(group_sizes)
+    valid = jnp.arange(cap) < live
+    pair = order[:cap]
+    token = jnp.where(valid, pair // k, t)
+    slot = jnp.where(inverse < live, inverse, cap - 1).reshape(t, k)
+
+    def gmm(lhs, w):
+        return jnp.where(valid[:, None], grouped_matmul(lhs, w, group_sizes),
+                         jnp.zeros((), lhs.dtype))
+
+    with jax.named_scope("moe.permute"):
+        rows = _take_rows(x, token, slot)
+        w_sorted = weights.reshape(-1)[pair]  # dead rows: gate, up are 0
+    with jax.named_scope("moe.experts"):
+        gate = gmm(rows, experts["w_gate"])
+        up = gmm(rows, experts["w_up"])
+        h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+             * w_sorted[:, None]).astype(x.dtype)
+        out = gmm(h, experts["w_down"])
+    with jax.named_scope("moe.combine"):
+        return _sum_rows(out, token, slot)
+
+
+def _capacity_switch(caps, group_sizes, branch, *operands):
+    """Run `branch(cap)(*operands)` at the smallest capacity that is MORE
+    than the live rows (so a dead row is there for absent pairs to point
+    at), or at the last, T x k, which holds everything. One capacity: no
+    switch in the program."""
+    if len(caps) == 1:
+        return branch(caps[0])(*operands)
+    live = jnp.sum(group_sizes)
+    index = sum((live >= c).astype(jnp.int32) for c in caps[:-1])
+    return jax.lax.switch(index, [branch(c) for c in caps], *operands)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _held_experts(x, experts, weights, order, inverse, group_sizes, k, caps):
+    """The routed part of a share: `_held_rows` at the smallest of the
+    static capacities `caps` that holds this step's live rows, so buffers,
+    gathers and elementwise work are sized by what landed here (to a factor
+    of two) and the grouped matmuls visit live tiles only.
+
+    A `custom_vjp` because of the switch: differentiated by jax, every
+    branch would write zeros for every other branch's residuals on each
+    step. Here the residuals are the operands, and the backward pass picks
+    its capacity as the forward did and differentiates that one program
+    (gate and up are recomputed, as remat "dots" recomputes them anyway).
+    The combine weights get no gradient (`moe_layer` says why)."""
+    return _capacity_switch(
+        caps, group_sizes, lambda cap: partial(_held_rows, k=k, cap=cap),
+        x, experts, weights, order, inverse, group_sizes)
+
+
+def _held_experts_fwd(x, experts, weights, order, inverse, group_sizes, k,
+                      caps):
+    return (_held_experts(x, experts, weights, order, inverse, group_sizes,
+                          k, caps),
+            (x, experts, weights, order, inverse, group_sizes))
+
+
+def _held_experts_bwd(k, caps, res, g):
+    def branch(cap):
+        def grads(x, experts, weights, order, inverse, group_sizes, g):
+            _, vjp = jax.vjp(
+                lambda x, e: _held_rows(x, e, weights, order, inverse,
+                                        group_sizes, k=k, cap=cap), x, experts)
+            return vjp(g)
+        return grads
+
+    return _capacity_switch(caps, res[5], branch, *res, g) \
+        + (None, None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
+              score: str = "softmax", router_bias=None,
+              weight_scale: float = 1.0, held=None):
     """Dropless top-k SwiGLU experts. x [T, D]; router_w [D, E]; `experts`
-    holds w_gate, w_up [E, D, F] and w_down [E, F, D]. -> (y [T, D] in
+    holds w_gate, w_up [E', D, F] and w_down [E', F, D]. -> (y [T, D] in
     x.dtype, MoEAux): y_t = sum_j w_tj * down_e(silu(gate_e x_t) * up_e x_t)
-    over token t's k experts e, accumulated in float32.
+    over token t's k experts e, accumulated in float32. The choice and the
+    weights w_tj are `route`'s (`score`: softmax or sigmoid scores,
+    `router_bias`, `norm_topk_prob`, `weight_scale`), over all E experts.
+
+    The share: `held=None` means every expert is here (E' = E). With
+    `held=(first_expert, n_held)` the E' = n_held experts `first_expert ..`
+    are (one chip of an expert-parallel deployment): the sum runs over the
+    pairs (t, j) whose expert is held, with the weights they have among all
+    k chosen; the other chips' part is left out and no exchange is made.
+    Nothing that lands here is dropped (`share_capacities`). On a share the
+    weights w_tj are CONSTANTS of the backward pass: what reaches them here
+    is the held experts' term of a gradient whose other terms (the absent
+    experts') this program cannot form, and that term alone says "only held
+    experts answer": applied by itself it moved every token of the first
+    expert layer onto the held experts within ~15 AdamW steps on the v5e
+    (PERF.md section 6, PR 32), a trajectory no deployment has. So a share
+    trains its experts and the layers around them, not its router (whose
+    `router_losses`, where a model uses them, still do).
 
     The down projection is linear, so w_tj multiplies its INPUT, on the
     sorted side: silu(gate) * up * w is formed in float32 and rounded to
@@ -174,19 +397,37 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False):
     "dots") would rerun the down matmul and the un-permute for it alone."""
     t = x.shape[0]
     e = router_w.shape[1]
-    routing = route(x, router_w, k, norm_topk_prob)
-    with jax.named_scope("moe.permute"):
-        order, inverse, group_sizes = sort_by_expert(routing.experts, e)
-        rows = _permute(x, order, inverse, k)
-        w_sorted = _reorder(routing.weights.reshape(-1), order, inverse)
-    with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(rows, experts["w_gate"], group_sizes)
-        up = grouped_matmul(rows, experts["w_up"], group_sizes)
-        h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-             * w_sorted[:, None]).astype(x.dtype)
-        out = grouped_matmul(h, experts["w_down"], group_sizes)
-    with jax.named_scope("moe.combine"):
-        y = _combine(out, order, inverse, k)
+    routing = route(x, router_w, k, norm_topk_prob, score=score,
+                    bias=router_bias, scale=weight_scale)
+    if held is not None:
+        first, n_held = held
+        if not (0 <= first and first + n_held <= e) \
+                or experts["w_gate"].shape[0] != n_held:
+            raise ValueError(
+                f"held experts {held} of {e}, weights for "
+                f"{experts['w_gate'].shape[0]}")
+        caps = share_capacities(t, k, n_held, e)
+        with jax.named_scope("moe.permute"):
+            order, inverse, group_sizes = sort_held(
+                routing.experts, first, n_held)
+        y = _held_experts(x, experts, routing.weights, order, inverse,
+                          group_sizes, k, caps)
+        device_profiler.count("moe.experts_held", n_held)
+        device_profiler.count("moe.rows_capacity", caps[-1])
+    else:
+        with jax.named_scope("moe.permute"):
+            order, inverse, group_sizes = sort_by_expert(routing.experts, e)
+            rows = _permute(x, order, inverse, k)
+            w_sorted = _reorder(routing.weights.reshape(-1), order, inverse)
+        with jax.named_scope("moe.experts"):
+            gate = grouped_matmul(rows, experts["w_gate"], group_sizes)
+            up = grouped_matmul(rows, experts["w_up"], group_sizes)
+            h = (jax.nn.silu(gate.astype(jnp.float32))
+                 * up.astype(jnp.float32)
+                 * w_sorted[:, None]).astype(x.dtype)
+            out = grouped_matmul(h, experts["w_down"], group_sizes)
+        with jax.named_scope("moe.combine"):
+            y = _combine(out, order, inverse, k)
     # per lowering, as `flash.steps_*` are
     device_profiler.count("moe.rows_routed", t * k)
     device_profiler.count("moe.experts", e)

@@ -1,0 +1,396 @@
+"""The latent-attention, routed-experts model (`models/mla_moe.py` over
+`parallel/moe.py` and `ops/flash_attention.py`) against the plain reference
+`benchmarks/reference_joyai.py`, at tiny sizes on the CPU, seeded weights.
+The program runs in float32 here, so that routing cannot flip between the
+two: every difference is then summation order.
+"""
+
+import dataclasses
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import reference_joyai as ref
+from ray_tpu._private import device_profiler
+from ray_tpu.models import mixtral, mla_moe
+from ray_tpu.ops.flash_attention import _reference_attention, flash_attention
+from ray_tpu.parallel import moe
+
+# float32 against float32-"highest": ~1e2 additions per output of O(1)
+# terms, each rounded to 6e-8 (1e-6 to 4e-6 measured below). 2e-5 is 5x
+# that; a bfloat16 matmul anywhere (4e-3 a product) is 200x over it.
+RTOL = ATOL = 2e-5
+
+SHARE = dict(n_experts_held=4, first_expert=4)   # experts 4-7 of 16
+WHOLE = dict()                                   # all 16
+
+
+def _model(over=WHOLE, seed=0, **kw):
+    # 1 dense + 1 expert layer + the MTP block: every kind once, cheaply
+    cfg = mla_moe.MlaMoeConfig.tiny(
+        vocab_size=256, n_layers=2, dtype=jnp.float32, remat=False,
+        loss_chunk_size=16, **over, **kw)
+    params = mla_moe.init(cfg, jax.random.PRNGKey(seed))
+    # norm scales that are not 1, so that a scale applied in the wrong
+    # place shows; a router bias large enough to move many choices
+    key = jax.random.PRNGKey(seed + 100)
+
+    def rescale(path, w):
+        name = path[-1].key
+        # crc32, not hash(): the same weights in every process
+        sub = jax.random.fold_in(
+            key, zlib.crc32(jax.tree_util.keystr(path).encode()) % 2**31)
+        if name.endswith("norm"):
+            return (1.0 + 0.3 * jax.random.normal(sub, w.shape)).astype(w.dtype)
+        if name == "router_bias":
+            return 0.2 * jax.random.normal(sub, w.shape)
+        return w
+
+    params = jax.tree_util.tree_map_with_path(rescale, params)
+    return cfg, params, dataclasses.asdict(cfg)
+
+
+def _tokens(seed, rows=2, seq=24):
+    return jax.random.randint(jax.random.PRNGKey(seed), (rows, seq + 1), 0, 256)
+
+
+@pytest.mark.parametrize("over", [SHARE, WHOLE], ids=["share", "whole"])
+def test_loss_and_gradients_match_reference(over):
+    """Share on (or every expert held), MTP on: the loss and every leaf of
+    its gradient."""
+    cfg, params, model = _model(over)
+    assert sum(a.size for a in jax.tree.leaves(params)) == cfg.num_params()
+    toks = _tokens(1)
+    with jax.default_matmul_precision("highest"):
+        got, g_got = jax.value_and_grad(
+            lambda p: mla_moe.loss_fn(p, {"tokens": toks}, cfg))(params)
+    want, g_want = jax.value_and_grad(
+        lambda p: ref.loss_value(p, toks[:, :-1], toks[:, 1:], model))(params)
+    np.testing.assert_allclose(got, want, rtol=RTOL)
+    flat_got = jax.tree_util.tree_leaves_with_path(g_got)
+    for (path, a), b in zip(flat_got, jax.tree.leaves(g_want)):
+        scale = float(jnp.abs(b).max()) + 1e-30
+        np.testing.assert_allclose(
+            a / scale, b / scale, atol=ATOL,
+            err_msg=jax.tree_util.keystr(path))
+    # the bias steers the choice and is not trained; a share's router is
+    # not trained either (its combine weights are constants), the whole
+    # model's is
+    assert not np.any(g_got["layers"]["router_bias"])
+    assert not np.any(g_got["mtp"]["block"]["router_bias"])
+    assert bool(np.any(g_got["layers"]["router"])) == (over is WHOLE)
+
+
+@pytest.mark.parametrize("mtp_depth", [0, 1])
+def test_logits_match_reference(mtp_depth):
+    cfg, params, model = _model(SHARE, mtp_depth=mtp_depth)
+    toks = _tokens(2)[:, :-1]
+    with jax.default_matmul_precision("highest"):
+        got = mla_moe.forward(params, toks, cfg)
+    for row_got, row in zip(got, toks):
+        np.testing.assert_allclose(row_got, ref.logits(params, row, model),
+                                   rtol=RTOL, atol=ATOL)
+
+
+def test_eight_shares_and_the_shared_expert_once_make_the_whole_layer():
+    """The guide's share test: the routed parts that all the shares give,
+    plus what every chip computes alike (the shared expert) counted once,
+    equal the uncut reference's expert sublayer."""
+    cfg, params, model = _model(WHOLE)
+    p = jax.tree.map(lambda a: a[0], params["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(3), (64, cfg.d_model))
+    want_routed, want_shared, chosen = ref.experts(h, p, model)
+    n_shares, per = 8, cfg.n_experts // 8
+    total = jnp.zeros_like(h)
+    live = 0
+    with jax.default_matmul_precision("highest"):
+        for i in range(n_shares):
+            held = jax.tree.map(lambda a: a[i * per:(i + 1) * per],
+                                p["experts"])
+            y, aux = moe.moe_layer(
+                h, p["router"], held, cfg.experts_per_token,
+                cfg.norm_topk_prob, score="sigmoid",
+                router_bias=p["router_bias"],
+                weight_scale=cfg.routed_scaling_factor, held=(i * per, per))
+            np.testing.assert_array_equal(aux.experts, chosen)
+            total = total + y
+            live += int(np.sum((chosen >= i * per) & (chosen < (i + 1) * per)))
+    assert live == chosen.size          # every pair is some share's
+    np.testing.assert_allclose(total, want_routed, rtol=RTOL, atol=ATOL)
+    # and the whole layer through the program with every expert held
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 64, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        got, _ = mla_moe._expert_sublayer(x, p, cfg)
+    hn = ref._rms(x[0], p["mlp_norm"], cfg.norm_eps)
+    routed, shared, _ = ref.experts(hn, p, model)
+    np.testing.assert_allclose(got[0], x[0] + routed + shared,
+                               rtol=RTOL, atol=ATOL)
+
+
+def test_sigmoid_routing_bias_and_normalisation():
+    x = jax.random.normal(jax.random.PRNGKey(5), (32, 16))
+    w = jax.random.normal(jax.random.PRNGKey(6), (16, 8))
+    plain = moe.route(x, w, 3, True, score="sigmoid", scale=2.5)
+    s = np.asarray(jax.nn.sigmoid(jnp.dot(x, w, precision="highest")))
+    top = np.argsort(-s, axis=-1)[:, :3]
+    np.testing.assert_array_equal(plain.experts, top)
+    picked = np.take_along_axis(s, top, -1)
+    # normalised over ALL the chosen (held here or not), then x 2.5
+    np.testing.assert_allclose(
+        plain.weights, 2.5 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    np.testing.assert_allclose(plain.weights.sum(-1), 2.5, rtol=1e-5)
+    # a bias moves the choice, not the weights' values; it gets no gradient
+    bias = jnp.zeros((8,)).at[7].set(10.0)
+    biased = moe.route(x, w, 3, True, score="sigmoid", bias=bias, scale=2.5)
+    assert np.all(np.asarray(biased.experts)[:, 0] == 7)
+    assert np.any(np.asarray(plain.experts)[:, 0] != 7)
+    chosen = np.asarray(biased.experts)
+    picked = np.take_along_axis(s, chosen, -1)
+    np.testing.assert_allclose(
+        biased.weights, 2.5 * picked / picked.sum(-1, keepdims=True), rtol=1e-5)
+    g_bias, g_w = jax.grad(
+        lambda b, w: jnp.sum(moe.route(x, w, 3, True, score="sigmoid",
+                                       bias=b).weights ** 2),
+        argnums=(0, 1))(bias, w)
+    assert not np.any(g_bias) and np.any(g_w)
+    with pytest.raises(ValueError):
+        moe.route(x, w, 3, score="tanh")
+
+
+@pytest.mark.parametrize("where", ["all_held", "none_held", "even", "heavy"])
+def test_nothing_is_dropped_at_either_extreme(where):
+    """Every pair on held experts (the buffers' worst case), none, the even
+    share and three times it: the live rows are exactly the pairs whose
+    expert is held, each lands in the capacity that holds it, and the
+    layer's output and gradients are the reference's with exactly those
+    pairs."""
+    cfg, params, model = _model(SHARE)
+    p = jax.tree.map(lambda a: a[0], params["layers"])
+    push = {"all_held": 10.0, "none_held": -10.0, "even": 0.0,
+            "heavy": 0.2}[where]
+    bias = p["router_bias"].at[4:8].add(push)
+    p = dict(p, router_bias=bias)
+    t, k = 512, cfg.experts_per_token
+    h = jax.random.normal(jax.random.PRNGKey(7), (t, cfg.d_model))
+    want, _, chosen = ref.experts(h, p, model)
+    held = np.asarray((chosen >= 4) & (chosen < 8))
+    live = int(held.sum())
+    caps = moe.share_capacities(t, k, 4, cfg.n_experts)
+    assert caps == (1024, 2048) and caps[-1] == t * k
+    lo, hi = {"all_held": (t * k, t * k), "none_held": (0, 0),
+              "even": (1, 1023), "heavy": (1024, t * k - 1)}[where]
+    assert lo <= live <= hi, live   # so both capacities are exercised
+    order, inverse, group_sizes = moe.sort_held(chosen, 4, 4)
+    np.testing.assert_array_equal(np.asarray(order)[np.asarray(inverse)],
+                                  np.arange(t * k))
+    assert int(group_sizes.sum()) == live
+    np.testing.assert_array_equal(
+        group_sizes, [np.sum(np.asarray(chosen) == e) for e in range(4, 8)])
+    # the first `live` sorted positions are exactly the held pairs
+    np.testing.assert_array_equal(np.sort(np.asarray(order)[:live]),
+                                  np.flatnonzero(held.reshape(-1)))
+
+    def program(h, experts):
+        return moe.moe_layer(
+            h, p["router"], experts, k, cfg.norm_topk_prob, score="sigmoid",
+            router_bias=bias, weight_scale=cfg.routed_scaling_factor,
+            held=(4, 4))[0]
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(program)(h, p["experts"])
+        g_got = jax.grad(lambda h, e: jnp.sum(program(h, e) ** 2),
+                         argnums=(0, 1))(h, p["experts"])
+    np.testing.assert_allclose(got, want, rtol=RTOL, atol=ATOL)
+    g_want = jax.grad(
+        lambda h, e: jnp.sum(ref.experts(h, dict(p, experts=e), model)[0] ** 2),
+        argnums=(0, 1))(h, p["experts"])
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-4)
+
+
+def test_routing_stats_counts_the_held_pairs_of_every_layer():
+    cfg, params, model = _model(SHARE)
+    toks = _tokens(8)
+    chosen = np.asarray(ref.routing(params, toks[:, :-1], toks[:, 1:], model))
+    want = ((chosen >= 4) & (chosen < 8)).sum(axis=(1, 2))
+    assert len(want) == cfg.n_layers - cfg.n_dense_layers + cfg.mtp_depth
+    np.testing.assert_array_equal(
+        mla_moe.routing_stats(params, toks, cfg), want)
+
+
+def test_seeded_weights_have_the_scales_the_cell_counts_on():
+    cfg = mla_moe.MlaMoeConfig.tiny(dtype=jnp.float32)
+    p = mla_moe.init(cfg, jax.random.PRNGKey(0))
+    rms = lambda a: float(jnp.sqrt(jnp.mean(jnp.square(a))))
+    # rows of unit RMS, whatever the width; the matmuls fan-in scaled
+    assert abs(rms(p["embed"]) - 1.0) < 0.02
+    assert abs(rms(p["lm_head"]) - cfg.d_model ** -0.5) < 0.01
+    assert abs(rms(p["layers"]["wo"])
+               - (cfg.n_heads * cfg.v_head_dim) ** -0.5) < 0.01
+    assert abs(rms(p["layers"]["router"]) - 0.02) < 0.002
+    assert abs(rms(p["layers"]["router_bias"]) - 0.01) < 0.004
+    assert all(rms(p["layers"][k]) == 1.0 for k in ("attn_norm", "mlp_norm"))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_unit_embedding_rows_keep_the_tokens_apart_at_the_router(seed):
+    """What evens the experts' loads at seeded weights (`init`'s
+    docstring): the part of the first router's logits that all tokens
+    share, over the part that tells them apart, grows when the embedding
+    is scaled down to fan-in and the stream is left to the sublayers."""
+    cfg = mla_moe.MlaMoeConfig.tiny(
+        vocab_size=256, n_layers=2, dtype=jnp.float32, remat=False)
+    key = jax.random.PRNGKey(seed)
+    params = mla_moe.init(cfg, key)
+    toks = jax.random.randint(jax.random.fold_in(key, 1), (4, 128), 0, 256)
+    positions = jnp.broadcast_to(jnp.arange(128), (4, 128))
+
+    def shared_over_own(embed):
+        x = mla_moe._dense_layer(
+            embed[toks], jax.tree.map(lambda a: a[0], params["dense"]),
+            positions, cfg, None, None)
+        p = jax.tree.map(lambda a: a[0], params["layers"])
+        h = mla_moe._rms_norm(mla_moe._mla_sublayer(x, p, positions, cfg),
+                              p["mlp_norm"], cfg.norm_eps)
+        logits = np.asarray(h.reshape(4 * 128, -1) @ p["router"])
+        return logits.mean(axis=0).std() / logits.std(axis=0).mean()
+
+    unit = shared_over_own(params["embed"])
+    scaled_down = shared_over_own(params["embed"] * cfg.d_model ** -0.5)
+    # 0.11-0.15 against 0.19-0.56 over seeds 0-5; sampling alone gives 0.044
+    assert unit < 0.75 * scaled_down
+    assert unit < 0.17
+
+
+def test_mtp_shift_and_mask():
+    targets = jnp.arange(1, 11).reshape(2, 5)
+    shifted, mask = mla_moe.mtp_targets(targets)
+    np.testing.assert_array_equal(shifted[:, :-1], targets[:, 1:])
+    np.testing.assert_array_equal(mask, [[1, 1, 1, 1, 0]] * 2)
+    rows = jnp.array([[1.0] * 5, [0.0] * 5])
+    np.testing.assert_array_equal(mla_moe.mtp_targets(targets, rows)[1],
+                                  [[1, 1, 1, 1, 0], [0] * 5])
+    # the MTP loss does not see the last position's (padded) target, and
+    # its weight is `mtp_loss_coef`
+    cfg, params, model = _model(SHARE)
+    toks = _tokens(9)
+    with jax.default_matmul_precision("highest"):
+        hidden, _ = mla_moe.forward_hidden(params, toks[:, :-1], cfg)
+        h_mtp, _ = mla_moe.mtp_hidden(params, hidden, toks[:, 1:], cfg)
+        lg = (h_mtp @ params["lm_head"])[:, :-1]
+        ce_mtp = -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(lg, -1), toks[:, 2:, None], -1))
+        total = mla_moe.loss_fn(params, {"tokens": toks}, cfg)
+        main = mla_moe.loss_fn(
+            params, {"tokens": toks}, dataclasses.replace(cfg, mtp_depth=0))
+    np.testing.assert_allclose(total - main, cfg.mtp_loss_coef * ce_mtp,
+                               rtol=1e-4)
+    np.testing.assert_allclose(
+        ce_mtp, ref.loss_terms(params, toks[:, :-1], toks[:, 1:], model)[1],
+        rtol=RTOL)
+
+
+def test_rope_interleave_is_a_common_permutation():
+    """The program's even-then-odd order against the reference's in-place
+    pairs: q.k products are equal."""
+    cfg = mla_moe.MlaMoeConfig.tiny()
+    q, k = (jax.random.normal(jax.random.PRNGKey(i), (1, 12, 2, 8))
+            for i in (0, 1))
+    pos = jnp.arange(12)[None]
+    got = jnp.einsum("bshr,bthr->bhst", mla_moe._rope_pairs(q, pos, cfg),
+                     mla_moe._rope_pairs(k, pos, cfg))
+    want = jnp.einsum("shr,thr->hst", ref._rope(q[0], cfg.rope_theta, True),
+                      ref._rope(k[0], cfg.rope_theta, True))
+    np.testing.assert_allclose(got[0], want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", [(2, 256, 2, 192, 128), (1, 200, 3, 24, 16)],
+                         ids=["192_128", "24_16_partial_blocks"])
+def test_flash_attention_key_width_differs_from_value_width(shape):
+    """q, k [B, S, H, 192], v [B, S, H, 128] through the Pallas kernels in
+    the interpreter against the jax.numpy oracle: forward, dq, dk, dv."""
+    b, s, h, d_qk, d_v = shape
+    q, k = (jax.random.normal(jax.random.PRNGKey(i), (b, s, h, d_qk))
+            for i in (0, 1))
+    v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d_v))
+    do = jax.random.normal(jax.random.PRNGKey(3), (b, s, h, d_v))
+
+    def oracle(q, k, v):
+        t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
+        return t(_reference_attention(t(q), t(k), t(v), True, d_qk ** -0.5))
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True,
+                               block_q=128, block_k=128)
+
+    with jax.default_matmul_precision("highest"):
+        got, vjp = jax.vjp(kernels, q, k, v)
+        want, vjp_want = jax.vjp(oracle, q, k, v)
+        assert got.shape == (b, s, h, d_v)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        for a, w in zip(vjp(do), vjp_want(do)):
+            assert a.shape == w.shape
+            np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4)
+
+
+def test_mixtral_through_the_changed_moe_layer():
+    """`mixtral.py` passes no share: `held=None` is the dispatch as it was
+    (tests/test_moe_reference.py holds it to its reference). Holding ALL the
+    experts as a share gives the same layer and the same experts' gradients;
+    `mla_moe` on an `ep` mesh raises."""
+    cfg = mixtral.MixtralConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_head=16, d_ff=32, max_seq_len=64, dtype=jnp.float32, remat=False,
+        n_experts=4, experts_per_token=2)
+    params = mixtral.init(cfg, jax.random.PRNGKey(0))
+    assert np.isfinite(mixtral.loss_fn(params, {"tokens": _tokens(10)}, cfg))
+    p0 = jax.tree.map(lambda a: a[0], params["layers"])
+    h = jax.random.normal(jax.random.PRNGKey(12), (48, 64))
+
+    def layer(experts, held):
+        return moe.moe_layer(h, p0["moe_gate"], experts, 2, True,
+                             held=held)[0]
+
+    def energy(experts, held):
+        return jnp.sum(layer(experts, held) ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(layer(p0["experts"], (0, 4)),
+                                   layer(p0["experts"], None),
+                                   rtol=1e-5, atol=1e-6)
+        # a share's combine weights are constants, so x and the router hear
+        # less; the experts' gradients are the dispatch's as it was
+        g_want = jax.grad(energy)(p0["experts"], None)
+        g_got = jax.grad(energy)(p0["experts"], (0, 4))
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    with pytest.raises(ValueError, match="held experts"):
+        layer(p0["experts"], (2, 2))   # weights for 4, told it holds 2
+
+    class EpMesh:
+        shape = {"ep": 2}
+
+    with pytest.raises(NotImplementedError):
+        c2, p2, _ = _model(SHARE)
+        mla_moe._expert_sublayer(
+            jnp.zeros((1, 8, 64)),
+            jax.tree.map(lambda a: a[0], p2["layers"]), c2, EpMesh())
+
+
+def test_counters_of_a_lowering():
+    cfg, params, _ = _model(SHARE)
+    toks = _tokens(11)
+    before = dict(device_profiler.snapshot()["counters"])
+    jax.jit(lambda p: mla_moe.loss_fn(p, {"tokens": toks}, cfg)).lower(params)
+    after = device_profiler.snapshot()["counters"]
+    delta = {k: after.get(k, 0) - before.get(k, 0) for k in after}
+    # the scanned expert layers lower once, the dense layer and the MTP
+    # block once each
+    assert delta["mla.layers"] == 3
+    assert delta["mtp.depth"] == 1
+    assert delta["moe.experts_held"] == 2 * 4
+    assert delta["moe.rows_capacity"] == 2 * toks[:, :-1].size * cfg.experts_per_token
+    assert delta["moe.experts"] == 2 * cfg.n_experts

@@ -68,6 +68,14 @@ out["flash_custom_calls"] = lowered.as_text().count("tpu_custom_call")
 lowered.compile()
 out["flash_s2048"] = "compiled"
 
+# MLA's call (train-joyai-1chip): keys 192 wide, values 128
+lowered = jax.jit(flash_grads).lower(
+    spec((4, 2048, 32, 192), bf16), spec((4, 2048, 32, 192), bf16),
+    spec((4, 2048, 32, 128), bf16))
+out["flash_mla_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+lowered.compile()
+out["flash_mla"] = "compiled"
+
 for name, shape in (("flash_s4096", (2, 4096, 32, 128)),
                     ("flash_s8192", (1, 8192, 8, 128))):
     try:
@@ -185,7 +193,7 @@ def compiled():
                PYTHONPATH=REPO_ROOT + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=300)
+                          capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     (line,) = [ln for ln in proc.stdout.splitlines()
                if ln.startswith("RESULT ")]
@@ -209,6 +217,14 @@ def test_flash_backward_compiles_at_long_sequences(compiled, seq):
     PR 26): if this flips back, update the docstring of
     ops.flash_attention.flash_attention with it."""
     assert compiled[seq] == "compiled", compiled[seq]
+
+
+def test_flash_with_keys_wider_than_values_compiles_for_v5e(compiled):
+    """MLA's call at train-joyai-1chip's shape, q and k [4, 2048, 32, 192],
+    v [4, 2048, 32, 128]: forward, dq and dk/dv. Mosaic takes the 192-wide
+    blocks as they are (a block's last dim may be the array's)."""
+    assert compiled["flash_mla_custom_calls"] >= 3
+    assert compiled["flash_mla"] == "compiled"
 
 
 @pytest.mark.parametrize("shape", ["gmm_up", "gmm_down"])
