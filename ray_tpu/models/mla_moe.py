@@ -8,10 +8,12 @@ RMSNorm, RoPE, SwiGLU sublayer, remat policy and chunked cross-entropy:
   `kv_lora_rank` latent (each RMS-normed), per head a 128-wide "nope" part
   and a 64-wide rotary part; ONE rotary key shared by all heads. Scores run
   over 128 + 64 = 192 channels, values over `v_head_dim` 128: one
-  `flash_attention` call with q, k [B, S, H, 192] and v [B, S, H, 128].
-  RoPE pairs channels (2i, 2i + 1) with `rope_interleave` (a fixed
-  permutation of the 64 against `llama._rope`'s halves, applied to q and k
-  alike, so the scores are the interleaved ones).
+  `flash_attention` call IN PARTS, q and k [B, S, H, 128], the rotary q
+  [B, S, H, 64], the rotary key [B, S, 1, 64] and v [B, S, H, 128], each a
+  projection's own output (`_mla_sublayer`). RoPE pairs channels
+  (2i, 2i + 1) with `rope_interleave` (a fixed permutation of the 64
+  against `llama._rope`'s halves, applied to the WEIGHTS that make the
+  rotary q and k, alike, so the scores are the interleaved ones).
 - `n_dense_layers` leading layers with a dense SwiGLU of `d_ff`; then expert
   layers: sigmoid scores over `n_experts`, top-k of score + a per-expert
   bias that gets no gradient (`noaux_tc`), weights normalised over the k
@@ -41,7 +43,7 @@ import jax.numpy as jnp
 from ray_tpu._private import device_profiler
 from ray_tpu.models import llama
 from ray_tpu.models.llama import _remat_policy, _residual, _rms_norm, _rope
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import RESIDUAL_NAMES, flash_attention
 from ray_tpu.parallel.moe import moe_layer
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
@@ -257,51 +259,65 @@ def init(config: MlaMoeConfig, key) -> Dict[str, Any]:
 # blocks
 # --------------------------------------------------------------------------
 
-def _rope_pairs(x, positions, config: MlaMoeConfig):
-    """RoPE over the last dim of x [B, S, H, R]. With `rope_interleave`
-    channel 2i turns with 2i + 1: the even channels are brought in front of
-    the odd ones and `llama._rope` turns (i, i + R/2). The result stays in
-    that order; q and k get the same treatment, so their products are the
-    interleaved form's."""
-    if config.rope_interleave:
-        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    return _rope(x, positions, config.rope_theta)
+def _interleaved(w, config: MlaMoeConfig):
+    """With `rope_interleave` channel 2i turns with 2i + 1: the even
+    channels of the last dim are brought in front of the odd ones, so that
+    `llama._rope` turns (i, i + R/2). A permutation of a linear map's
+    output channels, so it is applied to the weights that make the rotary
+    parts (6 MiB) and not to their [B, S, H, R] outputs. The rotary parts
+    stay in that order; q and k get the same treatment, so their products
+    are the interleaved form's."""
+    if not config.rope_interleave:
+        return w
+    return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
 
 
-def _attention(q, k, v, mesh):
+def _attention(q, k, v, q_rope, k_rope, mesh):
+    kw = dict(causal=True, q_rope=q_rope, k_rope=k_rope)
     if mesh is not None and any(
             mesh.shape.get(a, 1) > 1 for a in ("dp", "fsdp", "tp")):
         from ray_tpu.ops.flash_attention import flash_attention_sharded
 
-        return flash_attention_sharded(q, k, v, mesh, causal=True)
-    return flash_attention(q, k, v, causal=True)
+        return flash_attention_sharded(q, k, v, mesh, **kw)
+    return flash_attention(q, k, v, **kw)
 
 
 def _mla_sublayer(x, p, positions, config: MlaMoeConfig, mesh=None,
                   rules: Optional[LogicalAxisRules] = None):
-    """x [B, S, D] -> x + MLA(RMSNorm(x))."""
+    """x [B, S, D] -> x + MLA(RMSNorm(x)).
+
+    The flash call takes its operands in the parts the projections make:
+    `wq_b` [r, H, 128 + 64] and `wkv_b` [r, H, 128 + 128] stay the published
+    parameters and are used by slices of the WEIGHT, so q, the rotary q, k
+    and v are each a dot's own output: no [B, S, H, 192] q or k is built,
+    nothing is cut out of a [B, S, H, 256] k|v, the rotary key is not
+    copied to H heads (`flash_attention` in parts; it sums that key's
+    gradient over heads itself) and the interleave is a permutation of
+    weight columns (`_interleaved`). Under remat "dots" the saved residuals
+    are then the call's operands themselves (the rotary q before RoPE),
+    and `_checkpointed` saves its result beside them."""
     c = config
-    n_nope, n_rope = c.qk_nope_head_dim, c.qk_rope_head_dim
+    n_nope, n_lat = c.qk_nope_head_dim, c.kv_lora_rank
     h = _rms_norm(x, p["attn_norm"], c.norm_eps)
     with jax.named_scope("mla.latents"):
+        up = partial(jnp.einsum, "bsr,rhk->bshk")
         c_q = _rms_norm(h @ p["wq_a"], p["q_norm"], c.norm_eps)
-        q = jnp.einsum("bsr,rhk->bshk", c_q, p["wq_b"])
-        kv_a = h @ p["wkv_a"]
-        c_kv = _rms_norm(kv_a[..., :c.kv_lora_rank], p["kv_norm"], c.norm_eps)
-        kv = jnp.einsum("bsr,rhk->bshk", c_kv, p["wkv_b"])
-        q_rope = _rope_pairs(q[..., n_nope:], positions, c)
-        # one rotary key, the same for every head
-        k_rope = _rope_pairs(kv_a[..., None, c.kv_lora_rank:], positions, c)
-        q = jnp.concatenate([q[..., :n_nope], q_rope], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :n_nope],
-             jnp.broadcast_to(k_rope, k_rope.shape[:2] + (c.n_heads, n_rope))],
+        q = up(c_q, p["wq_b"][..., :n_nope])
+        q_rope = _rope(up(c_q, _interleaved(p["wq_b"][..., n_nope:], c)),
+                       positions, c.rope_theta)
+        kv_a = h @ jnp.concatenate(
+            [p["wkv_a"][:, :n_lat], _interleaved(p["wkv_a"][:, n_lat:], c)],
             axis=-1)
-        v = kv[..., n_nope:]
+        c_kv = _rms_norm(kv_a[..., :n_lat], p["kv_norm"], c.norm_eps)
+        k = up(c_kv, p["wkv_b"][..., :n_nope])
+        v = up(c_kv, p["wkv_b"][..., n_nope:])
+        # one rotary key, the same for every head
+        k_rope = _rope(kv_a[..., None, n_lat:], positions, c.rope_theta)
     with jax.named_scope("mla.attend"):
         # scores over n_nope + n_rope channels, scaled by their root
-        attn = _attention(q, k, v, mesh)
+        attn = _attention(q, k, v, q_rope, k_rope, mesh)
     device_profiler.count("mla.layers", 1)  # per lowering
+    device_profiler.count("mla.attend_parts", 1)
     x = x + jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
     return _residual(x, mesh, rules)
 
@@ -340,8 +356,18 @@ def _dense_layer(x, p, positions, config, mesh, rules):
 
 
 def _checkpointed(fn, config):
-    return jax.checkpoint(fn, policy=_remat_policy(config)) \
-        if config.remat else fn
+    """`fn` under `config.remat_policy` (llama's names) and, whatever that
+    saves, the flash call's own residuals beside it (its output and lse,
+    named by `ops/flash_attention.py`: 65 MiB a layer at B 4 x S 2048), so
+    the backward pass runs no second forward kernel. "full" saves nothing."""
+    if not config.remat:
+        return fn
+    policy = _remat_policy(config)
+    if policy is not None:
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            policy,
+            jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
+    return jax.checkpoint(fn, policy=policy)
 
 
 def forward_hidden(params, tokens, config: MlaMoeConfig, mesh=None,

@@ -617,7 +617,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     use_pallas: Optional[bool] = None,
-    interpret: bool = False,
+    interpret: bool = False, q_rope=None, k_rope=None,
 ):
     """Exact attention over [B, S, H, D] inputs (GQA: fewer KV heads OK).
 
@@ -626,20 +626,20 @@ def flash_attention(
     Pallas kernels above and any other backend runs the jax.numpy oracle
     (what the CPU test mesh uses; `interpret=True` runs the kernels in
     the Pallas interpreter instead). Nothing falls back from one to the
-    other at run time — chip_smoke.py fails unless `tpu_custom_call` is
+    other at run time: chip_smoke.py fails unless `tpu_custom_call` is
     in the lowered train step.
 
-    Sequence limit (v5e, libtpu 0.0.34, pinned by
-    tests/test_tpu_aot_compile.py): each kernel instance keeps the whole
-    sequence's K and V (forward, dq) or q and dO (dk/dv) in VMEM. Forward
-    and backward compile at S 2048, 4096 and 8192
-    (`LlamaConfig.max_seq_len`'s default) since dk/dv takes lse and delta
-    as rows, [steps, width]: as [S, 1] columns, a lane each, they were
-    4 MiB apiece at S 8192 and the backward pass was refused there
-    ("Scoped allocation with size 18.98M and limit 16.00M exceeded scoped
-    vmem limit"). Only S 2048 has run on the chip in a cell; longer
-    sequences still want the residency tiled, or `ring_attention` over
-    `sp`.
+    In parts (latent attention): with `q_rope` [B, S, H, R] and `k_rope`
+    [B, S, 1, R], ONE rotary key a (batch, position) for all heads, a score
+    is q . k + q_rope . k_rope over D + R channels (default scale
+    (D + R) ** -0.5). The rotary key's gradient, [B, S, 1, R], is summed
+    over heads by the backward rule, not by the caller: `_flash_in_parts`.
+
+    Sequence limit (v5e, libtpu 0.0.34, tests/test_tpu_aot_compile.py):
+    each kernel instance keeps the whole sequence's K and V (forward, dq)
+    or q and dO (dk/dv) in VMEM. Forward and backward compile at S 2048,
+    4096 and 8192 since dk/dv takes lse and delta as rows, [steps, width]
+    (as [S, 1] columns the backward pass was refused at S 8192).
     """
     b, s_q, h, d = q.shape
     h_kv = k.shape[2]
@@ -650,13 +650,13 @@ def flash_attention(
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     if scale is None:
-        scale = d ** -0.5
+        scale = (d + (0 if q_rope is None else q_rope.shape[-1])) ** -0.5
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu" and not interpret
-
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    if q_rope is not None:
+        return _flash_in_parts(q, q_rope, k, k_rope, v, causal, scale,
+                               block_q, block_k, use_pallas, interpret)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     if use_pallas or interpret:
         block_q = _clamp_block(block_q, s_q)
         block_k = _clamp_block(block_k, k.shape[1])
@@ -666,25 +666,23 @@ def flash_attention(
     return o.transpose(0, 2, 1, 3)
 
 
-def flash_attention_sharded(q, k, v, mesh, causal: bool = True,
-                            scale: Optional[float] = None, **kw):
-    """shard_map-wrapped flash attention for use inside a pjit-sharded model.
-
-    GSPMD has no partitioning rule for a Pallas custom call, so without this
-    wrapper XLA all-gathers q/k/v to every device and replicates the kernel.
-    Here batch rides ('dp','fsdp') and heads ride 'tp' explicitly; each shard
-    runs the kernel on its local [B/dp·fsdp, S, H/tp, D] block. KV heads are
-    repeated to match q heads first so the tp shard is uniform under GQA.
+def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
+                            q_rope=None, k_rope=None, **kw):
+    """shard_map-wrapped flash attention for use inside a pjit-sharded model:
+    GSPMD has no partitioning rule for a Pallas custom call, so without it
+    XLA all-gathers q/k/v to every device and replicates the kernel. Batch
+    rides ('dp','fsdp') and heads ride 'tp' explicitly; each shard runs the
+    kernel on its local [B/dp·fsdp, S, H/tp, D] block. KV heads are repeated
+    to match q heads first so the tp shard is uniform under GQA. In parts
+    (`flash_attention`): `_sharded_in_parts` below.
     """
     from jax.sharding import PartitionSpec as P
-
     h_kv = k.shape[2]
     h = q.shape[2]
     if h_kv != h:
         rep = h // h_kv
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-
     # incl. the inter-slice dcn axis: a replicated batch dim would
     # all-gather q/k/v across DCN before every attention call
     batch_axes = tuple(a for a in ("dcn", "dp", "fsdp")
@@ -697,9 +695,400 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True,
     head_axis = "tp" if (mesh.shape.get("tp", 1) > 1
                          and h % mesh.shape["tp"] == 0) else None
     spec = P(batch_axes or None, None, head_axis, None)
-
     fn = functools.partial(flash_attention, causal=causal, scale=scale, **kw)
+    if q_rope is not None:
+        return _sharded_in_parts(fn, mesh, spec, P(batch_axes or None),
+                                 q, k, v, q_rope, k_rope)
     return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
+
+
+# --------------------------------------------------------------------------
+# In parts: q and k as a D-wide part and a rotary part, ONE rotary key
+# --------------------------------------------------------------------------
+# Latent attention's projections make q as (q [H, D], q_rope [H, R]) and k
+# as (k [H, D], ONE k_rope [R] for all heads). These kernels take them so:
+# no [.., D + R] q and k are built in HBM and the rotary key is never
+# copied to H heads (its BlockSpec ignores the head). They are the three
+# kernels above (same schedule, `_run_row`, masks, float32 arithmetic) with
+# each score the SUM of two contractions, which on the 128-wide MXU are the
+# same two passes as one contraction over D + R (PERF.md §6, PR 32). They
+# stand below, not folded into, the kernels above because a Mosaic payload
+# carries the file locations of its kernel's lines: moving those recompiles
+# every other caller (PERF.md §6, PR 29; §7 for the fold).
+
+# Names of the forward rule's residuals (`jax.ad_checkpoint.checkpoint_name`):
+# a remat policy that saves them runs no second forward kernel in its
+# backward pass. lse is kept as [B, H, S]: as [B, H, S, 1] its last dim pads
+# to 128 lanes in the TPU's tiled layout, 128x the bytes. Only the in-parts
+# rule names them: the rules above stand as they are.
+RESIDUAL_NAMES = ("flash.o", "flash.lse")
+
+
+def _mm(a, b, dim_a, dim_b):
+    """2-D a, b: contract a's `dim_a` with b's `dim_b`, float32 out."""
+    return jax.lax.dot_general(a, b, (((dim_a,), (dim_b,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _scores(a, a_rope, b, b_rope, scale):
+    """[rows of a, rows of b]: a . b over D plus a_rope . b_rope over R."""
+    return (_mm(a, b, 1, 1) + _mm(a_rope, b_rope, 1, 1)) * scale
+
+
+def _fwd_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref, lse_ref, *,
+                      scale, causal, block_q, plan, seq_q, seq_k):
+    from jax.experimental import pallas as pl
+
+    width = plan.width
+    qi = pl.program_id(2)
+    q = q_ref[0, 0].astype(jnp.float32)    # [block_q, D]
+    qr = qr_ref[0, 0].astype(jnp.float32)  # [block_q, R]
+    causal_offset = seq_k - seq_q
+    q_pos = (qi * block_q + causal_offset
+             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
+
+    def step(masked):
+        def body(j, carry):
+            o, m, l = carry
+            start = _aligned(j * width, width)
+            rows = pl.dslice(start, width)
+            s = _scores(q, qr, k_ref[0, 0, rows, :].astype(jnp.float32),
+                        kr_ref[0, 0, rows, :].astype(jnp.float32), scale)
+            if masked:
+                k_pos = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, width), 1)
+                valid = k_pos < seq_k
+                if causal:
+                    valid = valid & (q_pos >= k_pos)
+                s = jnp.where(valid, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + _lane_chunks(p, jnp.add)
+            o_new = o * corr + _mm(
+                p, v_ref[0, 0, rows, :].astype(jnp.float32), 1, 0)
+            return o_new, m_new, l_new
+        return body
+
+    steps = functools.partial(
+        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
+        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
+    o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
+    m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
+    l0 = jnp.zeros((block_q, width if width % 128 else 128),
+                   dtype=jnp.float32)
+
+    def finish(carry):
+        o, m, l = carry
+        l = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
+        o_ref[0, 0] = (o / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m + jnp.log(l)
+
+    _run_row(plan, qi, steps, step, (o0, m0, l0), finish)
+
+
+def _bwd_dq_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
+                         delta_ref, dq_ref, *, scale, causal, block_q, plan,
+                         seq_q, seq_k):
+    """dq_ref [block_q, D + R]: both parts' gradients side by side."""
+    from jax.experimental import pallas as pl
+
+    width = plan.width
+    qi = pl.program_id(2)
+    q = q_ref[0, 0].astype(jnp.float32)
+    qr = qr_ref[0, 0].astype(jnp.float32)
+    do = do_ref[0, 0].astype(jnp.float32)
+    lse = lse_ref[0, 0]      # [block_q, 1]
+    delta = delta_ref[0, 0]  # [block_q, 1]
+    causal_offset = seq_k - seq_q
+    q_pos = (qi * block_q + causal_offset
+             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
+
+    def step(masked):
+        def body(j, carry):
+            dq, dqr = carry
+            start = _aligned(j * width, width)
+            rows = pl.dslice(start, width)
+            k_blk = k_ref[0, 0, rows, :].astype(jnp.float32)
+            kr_blk = kr_ref[0, 0, rows, :].astype(jnp.float32)
+            s = _scores(q, qr, k_blk, kr_blk, scale)
+            if masked:
+                k_pos = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, width), 1)
+                valid = k_pos < seq_k
+                if causal:
+                    valid = valid & (q_pos >= k_pos)
+                s = jnp.where(valid, s, NEG_INF)
+            p = jnp.exp(s - lse)
+            if masked:
+                p = jnp.where(valid, p, 0.0)
+            dp = _mm(do, v_ref[0, 0, rows, :].astype(jnp.float32), 1, 1)
+            ds = p * (dp - delta) * scale
+            return dq + _mm(ds, k_blk, 1, 0), dqr + _mm(ds, kr_blk, 1, 0)
+        return body
+
+    steps = functools.partial(
+        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
+        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
+    d = q.shape[-1]
+
+    def finish(carry):
+        dq, dqr = carry
+        dq_ref[0, 0, :, :d] = dq.astype(dq_ref.dtype)
+        dq_ref[0, 0, :, d:] = dqr.astype(dq_ref.dtype)
+
+    _run_row(plan, qi, steps, step, (jnp.zeros_like(q), jnp.zeros_like(qr)),
+             finish)
+
+
+def _bwd_dkv_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dk_ref, dv_ref, *, scale,
+                          causal, block_k, plan, seq_q, seq_k):
+    """On the transposed tile, as `_bwd_dkv_kernel`. dk_ref [block_k, D + R]:
+    dk beside THIS head's share of the rotary key's gradient (the caller
+    sums the heads')."""
+    from jax.experimental import pallas as pl
+
+    width = plan.width
+    kj = pl.program_id(2)
+    k_blk = k_ref[0, 0].astype(jnp.float32)    # [block_k, D]
+    kr_blk = kr_ref[0, 0].astype(jnp.float32)  # [block_k, R]
+    v_blk = v_ref[0, 0].astype(jnp.float32)
+    k_pos = kj * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, width), 0)
+    causal_offset = seq_k - seq_q
+
+    def step(masked):
+        def body(i, carry):
+            dk, dkr, dv = carry
+            start = _aligned(i * width, width)
+            rows = pl.dslice(start, width)
+            q = q_ref[0, 0, rows, :].astype(jnp.float32)
+            qr = qr_ref[0, 0, rows, :].astype(jnp.float32)
+            do = do_ref[0, 0, rows, :].astype(jnp.float32)
+            lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
+            delta = delta_ref[0, 0, pl.dslice(i, 1), :]
+            s = _scores(k_blk, kr_blk, q, qr, scale)  # [block_k, width]
+            if masked:
+                q_row = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, width), 1)
+                valid = q_row < seq_q
+                if causal:
+                    valid = valid & ((q_row + causal_offset) >= k_pos)
+                s = jnp.where(valid, s, NEG_INF)
+            p = jnp.exp(s - lse)
+            if masked:
+                p = jnp.where(valid, p, 0.0)
+            dv = dv + _mm(p, do, 1, 0)
+            ds = p * (_mm(v_blk, do, 1, 1) - delta) * scale
+            return dk + _mm(ds, q, 1, 0), dkr + _mm(ds, qr, 1, 0), dv
+        return body
+
+    steps = functools.partial(
+        _query_steps, block_k=block_k, width=width, seq_q=seq_q,
+        pad_q=q_ref.shape[2], causal=causal, offset=causal_offset)
+    d = k_blk.shape[-1]
+
+    def finish(carry):
+        dk, dkr, dv = carry
+        dk_ref[0, 0, :, :d] = dk.astype(dk_ref.dtype)
+        dk_ref[0, 0, :, d:] = dkr.astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+    _run_row(plan, kj, steps, step,
+             (jnp.zeros_like(k_blk), jnp.zeros_like(kr_blk),
+              jnp.zeros_like(v_blk)), finish)
+
+
+def _block(rows, width, walked, head=True):
+    """BlockSpec of [1, 1, rows, width] on a grid (batch, head, i): block i
+    of the sequence where the grid walks this operand (`walked`), else all
+    of it (rows = the padded sequence). `head=False`: head 0 whatever the
+    grid's, the one rotary key, not fetched again while the batch stands."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (1, 1, rows, width),
+        lambda b_, h_, i: (b_, h_ if head else 0, i if walked else 0, 0))
+
+
+def _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q, block_k,
+                            interpret):
+    from jax.experimental import pallas as pl
+
+    b, h, s_q, d = q.shape
+    s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
+    plan = block_schedule(s_q, s_k, block_q, block_k, causal)["fwd"]
+    _count_steps(plan)
+    q, qr = _pad_seq(q, block_q), _pad_seq(qr, block_q)
+    k, kr, v = (_pad_seq(x, plan.width) for x in (k, kr, v))
+    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
+    o, lse = pl.pallas_call(
+        functools.partial(
+            _fwd_parts_kernel, scale=scale, causal=causal, block_q=block_q,
+            plan=plan, seq_q=s_q, seq_k=s_k),
+        grid=(b, h, s_q_pad // block_q),
+        in_specs=[
+            _block(block_q, d, True), _block(block_q, r, True),
+            _block(s_k_pad, d, False), _block(s_k_pad, r, False, head=False),
+            _block(s_k_pad, d_v, False),
+        ],
+        out_specs=[_block(block_q, d_v, True), _block(block_q, 1, True)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, s_q_pad, d_v), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s_q_pad, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(q, qr, k, kr, v)
+    return o[:, :, :s_q], lse[:, :, :s_q]
+
+
+def _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
+                         block_q, plan, interpret):
+    from jax.experimental import pallas as pl
+
+    b, h, s_q, d = q.shape
+    s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
+    q, qr, do, lse, delta = (_pad_seq(x, block_q)
+                             for x in (q, qr, do, lse, delta))
+    k, kr, v = (_pad_seq(x, plan.width) for x in (k, kr, v))
+    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
+    dq = pl.pallas_call(
+        functools.partial(
+            _bwd_dq_parts_kernel, scale=scale, causal=causal, block_q=block_q,
+            plan=plan, seq_q=s_q, seq_k=s_k),
+        grid=(b, h, s_q_pad // block_q),
+        in_specs=[
+            _block(block_q, d, True), _block(block_q, r, True),
+            _block(s_k_pad, d, False), _block(s_k_pad, r, False, head=False),
+            _block(s_k_pad, d_v, False), _block(block_q, d_v, True),
+            _block(block_q, 1, True), _block(block_q, 1, True),
+        ],
+        out_specs=_block(block_q, d + r, True),
+        out_shape=jax.ShapeDtypeStruct((b, h, s_q_pad, d + r), q.dtype),
+        interpret=interpret,
+    )(q, qr, k, kr, v, do, lse, delta)
+    return dq[:, :, :s_q]
+
+
+def _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
+                          block_k, plan, interpret):
+    from jax.experimental import pallas as pl
+
+    b, h, s_q, d = q.shape
+    s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
+    width = plan.width
+    q, qr, do, lse, delta = (_pad_seq(x, width)
+                             for x in (q, qr, do, lse, delta))
+    k, kr, v = (_pad_seq(x, block_k) for x in (k, kr, v))
+    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
+    # one row a loop step, for the transposed tile (see `_bwd_dkv_kernel`)
+    n_steps = s_q_pad // width
+    lse = lse.reshape(b, h, n_steps, width)
+    delta = delta.reshape(b, h, n_steps, width)
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_parts_kernel, scale=scale, causal=causal,
+            block_k=block_k, plan=plan, seq_q=s_q, seq_k=s_k),
+        grid=(b, h, s_k_pad // block_k),
+        in_specs=[
+            _block(s_q_pad, d, False), _block(s_q_pad, r, False),
+            _block(block_k, d, True), _block(block_k, r, True, head=False),
+            _block(block_k, d_v, True), _block(s_q_pad, d_v, False),
+            _block(n_steps, width, False), _block(n_steps, width, False),
+        ],
+        out_specs=[_block(block_k, d + r, True), _block(block_k, d_v, True)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, s_k_pad, d + r), k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+    )(q, qr, k, kr, v, do, lse, delta)
+    return dk[:, :, :s_k], dv[:, :, :s_k]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_parts_bhsd(q, qr, k, kr, v, causal, scale, block_q, block_k,
+                      interpret):
+    """q, k [B, H, S, D], qr [B, H, S, R], kr [B, 1, S, R], v [B, H, S, Dv]
+    -> o [B, H, S, Dv]."""
+    return _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q,
+                                   block_k, interpret)[0]
+
+
+def _flash_parts_fwd_rule(q, qr, k, kr, v, causal, scale, block_q, block_k,
+                          interpret):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q,
+                                     block_k, interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return o, (q, qr, k, kr, v, o, lse)
+
+
+def _flash_parts_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
+    q, qr, k, kr, v, o, lse = res
+    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, causal)
+    _count_steps(plans["dq"], plans["dkv"])
+    lse = lse[..., None]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    dq = _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
+                              block_q, plans["dq"], interpret)
+    dk, dv = _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal,
+                                   scale, block_k, plans["dkv"], interpret)
+    d = q.shape[-1]
+    # every head used the one rotary key: its gradient is the heads' sum
+    dkr = jnp.sum(dk[..., d:], axis=1, keepdims=True, dtype=jnp.float32)
+    return dq[..., :d], dq[..., d:], dk[..., :d], dkr.astype(kr.dtype), dv
+
+
+_flash_parts_bhsd.defvjp(_flash_parts_fwd_rule, _flash_parts_bwd_rule)
+
+
+def _flash_in_parts(q, q_rope, k, k_rope, v, causal, scale, block_q, block_k,
+                    use_pallas, interpret):
+    """`flash_attention` with q and k in the parts latent attention's
+    projections make: q, k [B, S, H, D], q_rope [B, S, H, R], k_rope
+    [B, S, 1, R] (ONE rotary key a (batch, position), used by every head),
+    v [B, S, H, Dv] -> o [B, S, H, Dv]. A score is q . k + q_rope . k_rope
+    times `scale`: attention over [q | q_rope] and [k | k_rope copied to H
+    heads], which is what the oracle path (off a TPU) builds and the kernels
+    never do. The backward rule returns the gradient of each operand as
+    given; the rotary key's, [B, S, 1, R], is the sum over heads and is
+    summed HERE (`_flash_parts_bwd_rule`), not by the caller."""
+    if k_rope is None or k_rope.shape[2] != 1:
+        raise ValueError("q_rope comes with ONE rotary key, [B, S, 1, R]")
+
+    def t(x):
+        return x.transpose(0, 2, 1, 3)
+
+    if use_pallas or interpret:
+        o = _flash_parts_bhsd(
+            t(q), t(q_rope), t(k), t(k_rope), t(v), causal, scale,
+            _clamp_block(block_q, q.shape[1]),
+            _clamp_block(block_k, k.shape[1]), interpret)
+    else:
+        k_rope = jnp.broadcast_to(k_rope, q_rope.shape)
+        o = _reference_attention(
+            t(jnp.concatenate([q, q_rope], axis=-1)),
+            t(jnp.concatenate([k, k_rope], axis=-1)), t(v), causal, scale)
+    return t(o)
+
+
+def _sharded_in_parts(fn, mesh, spec, rope_spec, q, k, v, q_rope, k_rope):
+    """`flash_attention_sharded`'s call with the rotary parts carried through
+    the shard_map: `q_rope` sharded like q; the one rotary key has no head
+    axis to put on tp, so every tp shard reads all of it (`rope_spec`, the
+    batch axes alone) and shard_map's transpose sums its gradient over tp."""
+    return jax.shard_map(
+        lambda q, k, v, qr, kr: fn(q, k, v, q_rope=qr, k_rope=kr), mesh=mesh,
+        in_specs=(spec, spec, spec, spec, rope_spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v, q_rope, k_rope)

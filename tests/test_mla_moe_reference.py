@@ -7,6 +7,7 @@ two: every difference is then summation order.
 
 import dataclasses
 import zlib
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -295,45 +296,124 @@ def test_mtp_shift_and_mask():
 
 def test_rope_interleave_is_a_common_permutation():
     """The program's even-then-odd order against the reference's in-place
-    pairs: q.k products are equal."""
+    pairs: q.k products are equal. And the order is made on the WEIGHTS:
+    the permuted weight's outputs are the plain weight's outputs permuted,
+    to the last bit (a permutation of a linear map's output channels), for
+    the per-head up-projection and for the shared rotary key's columns."""
     cfg = mla_moe.MlaMoeConfig.tiny()
+    pairs = partial(mla_moe._interleaved, config=cfg)
     q, k = (jax.random.normal(jax.random.PRNGKey(i), (1, 12, 2, 8))
             for i in (0, 1))
     pos = jnp.arange(12)[None]
-    got = jnp.einsum("bshr,bthr->bhst", mla_moe._rope_pairs(q, pos, cfg),
-                     mla_moe._rope_pairs(k, pos, cfg))
+    rope = partial(mla_moe._rope, positions=pos, theta=cfg.rope_theta)
+    got = jnp.einsum("bshr,bthr->bhst", rope(pairs(q)), rope(pairs(k)))
     want = jnp.einsum("shr,thr->hst", ref._rope(q[0], cfg.rope_theta, True),
                       ref._rope(k[0], cfg.rope_theta, True))
     np.testing.assert_allclose(got[0], want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(pairs(jnp.arange(8)), [0, 2, 4, 6, 1, 3, 5, 7])
+    plain = dataclasses.replace(cfg, rope_interleave=False)
+    np.testing.assert_array_equal(
+        mla_moe._interleaved(jnp.arange(8), plain), jnp.arange(8))
+    c_q = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 48))
+    w_up = jax.random.normal(jax.random.PRNGKey(3), (48, 2, 8))
+    w_key = jax.random.normal(jax.random.PRNGKey(4), (48, 8))
+    np.testing.assert_array_equal(
+        jnp.einsum("bsr,rhk->bshk", c_q, pairs(w_up)),
+        pairs(jnp.einsum("bsr,rhk->bshk", c_q, w_up)))
+    np.testing.assert_array_equal(c_q @ pairs(w_key), pairs(c_q @ w_key))
 
 
+@pytest.mark.parametrize("parts", [False, True], ids=["whole", "in_parts"])
 @pytest.mark.parametrize("shape", [(2, 256, 2, 192, 128), (1, 200, 3, 24, 16)],
                          ids=["192_128", "24_16_partial_blocks"])
-def test_flash_attention_key_width_differs_from_value_width(shape):
+def test_flash_attention_key_width_differs_from_value_width(shape, parts):
     """q, k [B, S, H, 192], v [B, S, H, 128] through the Pallas kernels in
-    the interpreter against the jax.numpy oracle: forward, dq, dk, dv."""
+    the interpreter against the jax.numpy oracle: forward, dq, dk, dv. In
+    parts: q as (q, q_rope), k as (k, ONE rotary key for all heads): the
+    values and all five gradients, the rotary key's summed over heads,
+    against the oracle on the concatenated q and k."""
     b, s, h, d_qk, d_v = shape
     q, k = (jax.random.normal(jax.random.PRNGKey(i), (b, s, h, d_qk))
             for i in (0, 1))
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d_v))
     do = jax.random.normal(jax.random.PRNGKey(3), (b, s, h, d_v))
+    args = (q, k, v)
+    if parts:
+        args = (q[..., :d_v], q[..., d_v:], k[..., :d_v], k[:, :, :1, d_v:], v)
 
-    def oracle(q, k, v):
+    def whole(*a):
+        if not parts:
+            return a
+        q, q_rope, k, k_rope, v = a
+        k_rope = jnp.broadcast_to(k_rope, q_rope.shape)
+        return (jnp.concatenate([q, q_rope], -1),
+                jnp.concatenate([k, k_rope], -1), v)
+
+    def oracle(*a):
+        q, k, v = whole(*a)
         t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
         return t(_reference_attention(t(q), t(k), t(v), True, d_qk ** -0.5))
 
-    def kernels(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=True,
-                               block_q=128, block_k=128)
+    def kernels(*a, **kw):
+        if parts:
+            q, q_rope, k, k_rope, v = a
+            a, kw = (q, k, v), dict(kw, q_rope=q_rope, k_rope=k_rope)
+        return flash_attention(*a, causal=True, block_q=128, block_k=128, **kw)
 
     with jax.default_matmul_precision("highest"):
-        got, vjp = jax.vjp(kernels, q, k, v)
-        want, vjp_want = jax.vjp(oracle, q, k, v)
+        got, vjp = jax.vjp(partial(kernels, interpret=True), *args)
+        want, vjp_want = jax.vjp(oracle, *args)
         assert got.shape == (b, s, h, d_v)
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        grads = vjp(do)
+        assert len(grads) == len(args)
+        for a, w in zip(grads, vjp_want(do)):
+            assert a.shape == w.shape
+            np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4)
+        # off a TPU the call is the oracle on the concatenated q and k
+        np.testing.assert_allclose(kernels(*args), want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["oracle", "kernels"])
+def test_flash_attention_in_parts_on_a_mesh(interpret):
+    """`flash_attention_sharded` carries the parts through its shard_map
+    (batch over dp x fsdp, heads over tp; the one rotary key has no head
+    axis and is read whole by every tp shard): values and all five
+    gradients equal the unsharded call's, the rotary key's summed over the
+    tp shards' heads; and a whole model on that mesh gives the loss of the
+    model on one device."""
+    from ray_tpu.ops.flash_attention import flash_attention_sharded
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
+    b, s, h, d, r = 4, 128, 4, 16, 8
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (b, s, h, d))
+               for i in (0, 1, 2))
+    q_rope = jax.random.normal(jax.random.PRNGKey(3), (b, s, h, r))
+    k_rope = jax.random.normal(jax.random.PRNGKey(4), (b, s, 1, r))
+    do = jax.random.normal(jax.random.PRNGKey(5), (b, s, h, d))
+
+    def one_device(q, k, v, q_rope, k_rope):
+        return flash_attention(q, k, v, q_rope=q_rope, k_rope=k_rope)
+
+    def sharded(q, k, v, q_rope, k_rope):
+        return flash_attention_sharded(
+            q, k, v, mesh, q_rope=q_rope, k_rope=k_rope, interpret=interpret)
+
+    with jax.default_matmul_precision("highest"):
+        want, vjp_want = jax.vjp(one_device, q, k, v, q_rope, k_rope)
+        got, vjp = jax.vjp(jax.jit(sharded), q, k, v, q_rope, k_rope)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
         for a, w in zip(vjp(do), vjp_want(do)):
             assert a.shape == w.shape
             np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4)
+        if not interpret:
+            cfg, params, _ = _model(SHARE)
+            toks = _tokens(12, rows=4)
+            np.testing.assert_allclose(
+                jax.jit(lambda p: mla_moe.loss_fn(
+                    p, {"tokens": toks}, cfg, mesh))(params),
+                mla_moe.loss_fn(params, {"tokens": toks}, cfg), rtol=RTOL)
 
 
 def test_mixtral_through_the_changed_moe_layer():
@@ -390,6 +470,8 @@ def test_counters_of_a_lowering():
     # the scanned expert layers lower once, the dense layer and the MTP
     # block once each
     assert delta["mla.layers"] == 3
+    # each of them hands its flash call the parts its projections make
+    assert delta["mla.attend_parts"] == 3
     assert delta["mtp.depth"] == 1
     assert delta["moe.experts_held"] == 2 * 4
     assert delta["moe.rows_capacity"] == 2 * toks[:, :-1].size * cfg.experts_per_token

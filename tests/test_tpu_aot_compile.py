@@ -14,6 +14,7 @@ and compiles there now, as at S 4096.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -24,7 +25,10 @@ pytest.importorskip("libtpu")
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = r"""
+import dataclasses
+import functools
 import json
+import os
 import re
 import jax
 import jax.numpy as jnp
@@ -33,7 +37,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mla_moe
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel import moe
@@ -75,6 +79,38 @@ lowered = jax.jit(flash_grads).lower(
 out["flash_mla_custom_calls"] = lowered.as_text().count("tpu_custom_call")
 lowered.compile()
 out["flash_mla"] = "compiled"
+
+# the same call as `mla_moe` makes it, IN PARTS (q, rotary q, k, ONE rotary
+# key, v): one checkpointed `_mla_sublayer` at train-joyai-1chip's widths
+# and batch, value and gradient, as the v5e's compiler leaves it
+cfg = mla_moe.MlaMoeConfig(max_seq_len=2048)
+layer_p = jax.eval_shape(lambda: mla_moe.init(
+    dataclasses.replace(cfg, vocab_size=8, n_layers=1, d_ff=8),
+    jax.random.PRNGKey(0)))["dense"]
+layer = mla_moe._checkpointed(functools.partial(
+    mla_moe._mla_sublayer, config=cfg,
+    positions=jnp.broadcast_to(jnp.arange(2048), (4, 2048))), cfg)
+# use_pallas=True: `mla_moe` too follows jax.default_backend()
+mla_moe.flash_attention = functools.partial(flash_attention, use_pallas=True)
+hlo = jax.jit(jax.value_and_grad(
+    lambda x, p: layer(x, p).astype(jnp.float32).sum(), argnums=(0, 1))).lower(
+        spec((4, 2048, cfg.d_model), bf16),
+        on_chip(jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), layer_p))
+    ).compile().as_text()
+ops = [ln.strip() for ln in hlo.splitlines()]
+out["mla_parts_calls"] = [
+    re.match(r"%[\w.\-]+ = (.*?) custom-call\(", ln)[1] for ln in ops
+    if 'custom_call_target="tpu_custom_call"' in ln]
+for name in ("mla_flash_fwd_roofline", "mla_flash_bwd_roofline"):
+    with open(os.path.join(os.environ["REPO_ROOT"], "benchmarks", "metrics",
+                           name + ".json")) as f:
+        query = re.compile(json.load(f)["trace_query"]["op"])
+    out[name + "_events"] = sum(1 for ln in ops if query.search(ln))
+out["mla_parts_wide_ops"] = [
+    ln[:160] for ln in ops
+    if re.match(r"(ROOT )?%[\w.\-]+ = bf16\[4,32,2048,192\]", ln)
+    and not re.search(r" (custom-call|parameter|get-tuple-element)\(", ln)]
 
 for name, shape in (("flash_s4096", (2, 4096, 32, 128)),
                     ("flash_s8192", (1, 8192, 8, 128))):
@@ -189,7 +225,7 @@ print("RESULT " + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def compiled():
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu", REPO_ROOT=REPO_ROOT,
                PYTHONPATH=REPO_ROOT + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
@@ -225,6 +261,27 @@ def test_flash_with_keys_wider_than_values_compiles_for_v5e(compiled):
     blocks as they are (a block's last dim may be the array's)."""
     assert compiled["flash_mla_custom_calls"] >= 3
     assert compiled["flash_mla"] == "compiled"
+
+
+def test_latent_attention_in_parts_as_compiled_for_v5e(compiled):
+    """One checkpointed `mla_moe._mla_sublayer`, value and gradient, at
+    train-joyai-1chip's widths: the flash call takes q `[4, 32, 2048, 128]`,
+    the rotary q `[.., 64]`, k, ONE rotary key `[4, 1, 2048, 64]` and v, and
+    its three kernels keep the output signatures that
+    `benchmarks/metrics/mla_flash_{fwd,bwd}_roofline.json` match in the
+    trace (the forward once; dq and dk/dv, two events a call). THREE Pallas
+    calls, not four: the layer's policy saves the forward's `o` and `lse`,
+    so the backward pass does not run it again. And nothing but the dq and
+    dk kernels writes a `[4, 32, 2048, 192]` array: no q or k is
+    concatenated to 192 channels, the rotary key is not copied to 32 heads,
+    in the forward pass, its recomputation or the backward pass."""
+    wide, narrow = "bf16[4,32,2048,192]", "bf16[4,32,2048,128]"
+    calls = [re.sub(r"\{[^}]*\}", "", c) for c in compiled["mla_parts_calls"]]
+    assert sorted(calls) == sorted([
+        f"({narrow}, f32[4,32,2048,1])", wide, f"({wide}, {narrow})"])
+    assert compiled["mla_flash_fwd_roofline_events"] == 1
+    assert compiled["mla_flash_bwd_roofline_events"] == 2
+    assert compiled["mla_parts_wide_ops"] == []
 
 
 @pytest.mark.parametrize("shape", ["gmm_up", "gmm_down"])
