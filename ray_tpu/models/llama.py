@@ -174,13 +174,17 @@ def _rms_norm(x, weight, eps):
 
 
 def _qk_norm(q, k, params, config):
-    """With `config.qk_norm`: one RMSNorm over ALL channels of the q
-    projection [B, S, H, D] (scale `q_norm` [H, D]) and one over all of the
-    k projection, not per head, before RoPE. Otherwise q and k as given."""
+    """With `config.qk_norm`, before RoPE, by the SHAPE of the scale: `q_norm`
+    [H, D] is one RMSNorm over ALL channels of the q projection [B, S, H, D]
+    and one over all of the k projection, not per head (OLMoE); `q_norm` [D]
+    is an RMSNorm of every head over its own D channels, one scale for all q
+    heads and one for all kv heads (Qwen3). Otherwise q and k as given."""
     if not config.qk_norm:
         return q, k
 
     def norm(x, weight):
+        if weight.ndim == 1:
+            return _rms_norm(x, weight, config.norm_eps)
         b, s, h, d = x.shape
         return _rms_norm(x.reshape(b, s, h * d), weight.reshape(h * d),
                          config.norm_eps).reshape(b, s, h, d)
@@ -202,7 +206,14 @@ def _rope(x, positions, theta):
     ).astype(x.dtype)
 
 
-def _attention(q, k, v, config: LlamaConfig, mesh=None):
+def _attention(q, k, v, config: LlamaConfig, mesh=None, mask=None):
+    """Causal flash attention, or under `mask` (a static rule of
+    `ops/flash_attention.py`) in its scope, which names the Pallas events."""
+    if mask is not None:
+        if config.use_ring_attention:
+            raise NotImplementedError("ring attention is causal only")
+        with jax.named_scope(mask.scope):
+            return _flash(q, k, v, mesh, mask=mask)
     if config.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention_sharded
 
@@ -211,13 +222,17 @@ def _attention(q, k, v, config: LlamaConfig, mesh=None):
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         return ring_attention_sharded(q, k, v, mesh, causal=True)
+    return _flash(q, k, v, mesh, causal=True)
+
+
+def _flash(q, k, v, mesh, **rule):
     if mesh is not None and any(
         mesh.shape.get(a, 1) > 1 for a in ("dp", "fsdp", "tp")
     ):
         from ray_tpu.ops.flash_attention import flash_attention_sharded
 
-        return flash_attention_sharded(q, k, v, mesh, causal=True)
-    return flash_attention(q, k, v, causal=True)
+        return flash_attention_sharded(q, k, v, mesh, **rule)
+    return flash_attention(q, k, v, **rule)
 
 
 def _qkv(x, params, positions, config: LlamaConfig, lc=None):
@@ -314,9 +329,9 @@ def _mlp_ring(h, params, mesh):
 
 
 def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
-                   rules: Optional[LogicalAxisRules] = None):
-    """Pre-norm causal attention block of the training layer (and of
-    mixtral's)."""
+                   rules: Optional[LogicalAxisRules] = None, mask=None):
+    """Pre-norm attention block of the training layer (and of mixtral's):
+    causal, or under the static rule `mask` (`_attention`)."""
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     q, k, v = _qkv(x, params, positions, config, lc)
     if "tp" in _residual_seq_axes(x, mesh, rules):
@@ -324,7 +339,7 @@ def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
         # gathered for q and k: left unsaid, the compiler projects the local
         # rows onto every head and turns v round with an all-to-all
         v = lc(v, ("batch", "seq", "act_heads", "act_kv"))
-    attn = _attention(q, k, v, config, mesh)
+    attn = _attention(q, k, v, config, mesh, mask)
     attn = _checkpoint_name(attn, "attn_out")
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
     return _residual(x, mesh, rules)
@@ -391,10 +406,13 @@ def forward(params, tokens, config: LlamaConfig, mesh=None,
     return logits.astype(jnp.float32)
 
 
-def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256):
+def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256,
+               denominator=None):
     """Cross-entropy without materializing full [B,S,V] fp32 logits: the
     sequence is scanned in chunks and each chunk's logits are rematerialized
-    in the backward pass."""
+    in the backward pass. `mask` [B,S] weights each position's term (0/1, or
+    any float32 weight); the sum is divided by the mask's sum, or by
+    `denominator` when the weights are no count."""
     b, s, d = hidden.shape
     n = s // chunk
     rem = s - n * chunk
@@ -416,6 +434,8 @@ def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256):
     if rem:
         total, _ = body(total, (hidden[:, n * chunk:], targets[:, n * chunk:],
                                 mask[:, n * chunk:]))
+    if denominator is not None:
+        return total / denominator
     return total / jnp.maximum(jnp.sum(mask), 1.0)
 
 

@@ -31,8 +31,14 @@ from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
 @dataclasses.dataclass(frozen=True)
 class MixtralConfig(LlamaConfig):
-    """`d_ff` is the width of ONE expert."""
+    """`d_ff` is the width of ONE expert. `n_experts` is the router's
+    outputs; with `n_experts_held` fewer than that this program is one chip's
+    share of an expert-parallel deployment run without its exchange: it
+    holds experts [`first_expert`, `first_expert + n_experts_held`) and
+    leaves out what the absent ones would add (`moe_layer`'s `held`)."""
     n_experts: int = 8
+    n_experts_held: Optional[int] = None   # None: all of them
+    first_expert: int = 0
     experts_per_token: int = 2
     norm_topk_prob: bool = True
     aux_loss_coef: float = 0.01        # load balancing
@@ -47,12 +53,20 @@ class MixtralConfig(LlamaConfig):
             n_experts=4, experts_per_token=2,
         )
 
+    @property
+    def held(self):
+        """`moe_layer`'s `held`: None where every expert is here."""
+        if self.n_experts_held in (None, self.n_experts):
+            return None
+        return self.first_expert, self.n_experts_held
+
     def num_params(self) -> int:
         base = super().num_params()
-        # replace the dense FFN count with n_experts routed FFNs + gate
+        # replace the dense FFN count with the held routed FFNs + gate
         dense_ffn = self.n_layers * 3 * self.d_model * self.d_ff
+        n_held = self.held[1] if self.held else self.n_experts
         moe_ffn = self.n_layers * (
-            self.n_experts * 3 * self.d_model * self.d_ff
+            n_held * 3 * self.d_model * self.d_ff
             + self.d_model * self.n_experts)
         return base - dense_ffn + moe_ffn
 
@@ -63,11 +77,13 @@ def param_logical_axes(config: MixtralConfig) -> Dict[str, Any]:
     for k in ("w_gate", "w_up", "w_down"):
         layer_axes.pop(k, None)
     L = ("layers",)
-    layer_axes["moe_gate"] = L + ("embed", "expert")
+    # a share's experts are not the `ep` axis's: it has no exchange
+    expert = "expert" if config.held is None else None
+    layer_axes["moe_gate"] = L + ("embed", expert)
     layer_axes["experts"] = {
-        "w_gate": L + ("expert", "embed", "mlp"),
-        "w_up": L + ("expert", "embed", "mlp"),
-        "w_down": L + ("expert", "mlp", "embed"),
+        "w_gate": L + (expert, "embed", "mlp"),
+        "w_up": L + (expert, "embed", "mlp"),
+        "w_down": L + (expert, "mlp", "embed"),
     }
     return axes
 
@@ -80,19 +96,21 @@ def init(config: MixtralConfig, key) -> Dict[str, Any]:
         layers.pop(k, None)
     k1, k2, k3, k4 = jax.random.split(jax.random.fold_in(key, 0xE), 4)
     scale_in = (2.0 / (c.d_model + c.d_ff)) ** 0.5
-    # Leading axis n_layers (scanned), then n_experts (sharded on `ep`).
+    n_held = c.held[1] if c.held else c.n_experts
+    # Leading axis n_layers (scanned), then the experts held (all of them:
+    # sharded on `ep`).
     layers["moe_gate"] = (
         jax.random.normal(k1, (c.n_layers, c.d_model, c.n_experts)) * 0.02
     ).astype(c.dtype)
     layers["experts"] = {
         "w_gate": (jax.random.normal(
-            k2, (c.n_layers, c.n_experts, c.d_model, c.d_ff)) * scale_in
+            k2, (c.n_layers, n_held, c.d_model, c.d_ff)) * scale_in
         ).astype(c.dtype),
         "w_up": (jax.random.normal(
-            k3, (c.n_layers, c.n_experts, c.d_model, c.d_ff)) * scale_in
+            k3, (c.n_layers, n_held, c.d_model, c.d_ff)) * scale_in
         ).astype(c.dtype),
         "w_down": (jax.random.normal(
-            k4, (c.n_layers, c.n_experts, c.d_ff, c.d_model)) * scale_in
+            k4, (c.n_layers, n_held, c.d_ff, c.d_model)) * scale_in
         ).astype(c.dtype),
     }
     return params
@@ -111,6 +129,9 @@ def _moe_block(h, layer_p, config: MixtralConfig, mesh):
     b, s, d = h.shape
     flat = h.reshape(b * s, d)
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        if c.held is not None:
+            raise NotImplementedError(
+                "a share runs without the exchange: no `ep` mesh axis")
         out, aux = moe_shard_map(
             flat, layer_p["moe_gate"], _expert_ffn, layer_p["experts"], mesh,
             k=c.experts_per_token, capacity_factor=c.capacity_factor,
@@ -118,25 +139,29 @@ def _moe_block(h, layer_p, config: MixtralConfig, mesh):
     else:
         out, aux = moe_layer(
             flat, layer_p["moe_gate"], layer_p["experts"],
-            k=c.experts_per_token, norm_topk_prob=c.norm_topk_prob)
+            k=c.experts_per_token, norm_topk_prob=c.norm_topk_prob,
+            held=c.held)
     return out.reshape(b, s, d), aux
 
 
-def forward_hidden(params, tokens, config: MixtralConfig, mesh=None,
-                   rules: Optional[LogicalAxisRules] = None):
-    """tokens [B,S] -> (final-norm hidden states [B,S,D], MoEAux whose
-    fields lead with the layer dim: experts [L, B*S, k], the loss terms
-    [L])."""
+def hidden_states(params, tokens, config: MixtralConfig, mesh=None,
+                  rules: Optional[LogicalAxisRules] = None, positions=None,
+                  mask=None):
+    """tokens [B,S] -> (the last layer's output [B,S,D], BEFORE the final
+    norm, MoEAux whose fields lead with the layer dim: experts [L, B*S, k],
+    the loss terms [L]). `positions` [B,S] (default 0..S-1) turn RoPE;
+    `mask` is attention's static rule (default causal)."""
     c = config
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     # the table's embed dim in the activation layout: llama.forward_hidden
     table = lc(params["embed"], ("vocab", "act_embed"))
     x = lc(table[tokens].astype(c.dtype), ("batch", "seq", "act_embed"))
 
     def layer_fn(x, layer_p):
-        x = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules)
+        x = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules, mask)
         h2 = _rms_norm(x, layer_p["mlp_norm"], c.norm_eps)
         moe_out, aux = _moe_block(h2, layer_p, c, mesh)
         return lc(x + moe_out, ("batch", "seq", "act_embed")), aux
@@ -144,8 +169,15 @@ def forward_hidden(params, tokens, config: MixtralConfig, mesh=None,
     if c.remat:
         layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
 
-    x, aux = jax.lax.scan(layer_fn, x, params["layers"])
-    return _rms_norm(x, params["final_norm"], c.norm_eps), aux
+    return jax.lax.scan(layer_fn, x, params["layers"])
+
+
+def forward_hidden(params, tokens, config: MixtralConfig, mesh=None,
+                   rules: Optional[LogicalAxisRules] = None):
+    """tokens [B,S] -> (final-norm hidden states [B,S,D], MoEAux per
+    layer, as `hidden_states`)."""
+    x, aux = hidden_states(params, tokens, config, mesh, rules)
+    return _rms_norm(x, params["final_norm"], config.norm_eps), aux
 
 
 def forward(params, tokens, config: MixtralConfig, mesh=None,

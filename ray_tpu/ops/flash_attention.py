@@ -29,15 +29,107 @@ NEG_INF = -1e30
 # Reference (the oracle tests compare against; the path on non-TPU backends)
 # --------------------------------------------------------------------------
 
-def _reference_attention(q, k, v, causal: bool, scale: float):
-    # q,k,v: [B,H,S,D]
+def _reference_attention(q, k, v, causal, scale: float):
+    # q,k,v: [B,H,S,D]; `causal` a bool or a mask rule (below): the DENSE mask
+    mask = _rule(causal)
     s_q, s_k = q.shape[2], k.shape[2]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
-        scores = jnp.where(mask[None, None], scores, NEG_INF)
+    if mask is not None:
+        kept = mask.keep(jnp.arange(s_q)[:, None] + (s_k - s_q),
+                         jnp.arange(s_k)[None, :])
+        scores = jnp.where(kept[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+
+
+# --------------------------------------------------------------------------
+# Mask rules: which (query, key) pairs a call keeps, known statically
+# --------------------------------------------------------------------------
+# A rule is a hashable value with
+#   keep(q_pos, k_pos) -> bool, elementwise over int arrays of positions
+#       (numpy in the schedule, traced int32 tiles in the kernels and the
+#       oracle; operators and `.astype` only, so one body serves all three);
+#   tile(q0, nq, k0, nk) -> (any score of the tile kept, every one kept);
+#   needed(s_q, s_k) -> the scores it keeps;
+#   scope: a `jax.named_scope` for the call (names its Pallas events), or None.
+# Query positions are aligned to the keys' END (row r of s_q stands at
+# r + s_k - s_q, the oracle's tril(k=s_k - s_q)). `causal=True` is the rule
+# `CAUSAL`, `causal=False` no rule: every score kept.
+
+
+class Causal(NamedTuple):
+    """Query i sees the keys up to its own position: what `causal=True`
+    stands for."""
+    scope = None
+
+    def keep(self, q_pos, k_pos):
+        return q_pos >= k_pos
+
+    def tile(self, q0, nq, k0, nk):
+        return k0 <= q0 + nq - 1, k0 + nk - 1 <= q0
+
+    def needed(self, s_q, s_k):
+        return sum(min(max(r + s_k - s_q + 1, 0), s_k) for r in range(s_q))
+
+
+CAUSAL = Causal()
+
+
+class BlockDiffusion(NamedTuple):
+    """Block-diffusion training over the concatenation [x_t ; x_0] of a
+    noised and a clean copy of `length` tokens (BD3-LM, arXiv:2503.09573):
+    position i < length is noised, i >= length clean, and its block is
+    (i mod length) // block. Query i sees key j iff they are in the same
+    half and block (block-diagonal inside each half), or j is clean and in
+    an EARLIER block (block-causal inside x_0, offset block-causal from x_t
+    to x_0). Neither causal nor full: x_0 rows never see x_t columns, x_t
+    rows see their own block both ways. Keeps length^2 + length x block of
+    the (2 length)^2 scores."""
+    length: int
+    block: int
+    scope = "bd.attend"
+
+    def _block_of(self, pos):
+        clean = pos >= self.length
+        inside = pos - clean.astype(pos.dtype) * self.length
+        if self.block & (self.block - 1) == 0:
+            return clean, inside >> (self.block.bit_length() - 1)
+        return clean, inside // self.block
+
+    def keep(self, q_pos, k_pos):
+        q_clean, q_blk = self._block_of(q_pos)
+        k_clean, k_blk = self._block_of(k_pos)
+        return ((q_clean == k_clean) & (q_blk == k_blk)) \
+            | (k_clean & (k_blk < q_blk))
+
+    def tile(self, q0, nq, k0, nk):
+        import numpy as np
+
+        end = 2 * self.length
+        q = np.arange(q0, min(q0 + nq, end), dtype=np.int32)[:, None]
+        k = np.arange(k0, min(k0 + nk, end), dtype=np.int32)[None, :]
+        if not q.size or not k.size:
+            return False, False
+        kept = self.keep(q, k)
+        return bool(kept.any()), bool(kept.all())
+
+    def needed(self, s_q, s_k):
+        if s_q != s_k or s_q != 2 * self.length \
+                or self.length % self.block:
+            raise ValueError(
+                f"{self} is a rule over 2 x length positions, queries and "
+                f"keys alike, in whole blocks; got {s_q} x {s_k}")
+        return self.length * self.length + self.length * self.block
+
+
+def _rule(causal, mask=None):
+    """The rule a call runs under: `mask` if given, else what `causal`
+    (a bool, or a rule already) stands for."""
+    if mask is not None:
+        return mask
+    if causal is True:
+        return CAUSAL
+    return None if causal is False else causal
 
 
 # --------------------------------------------------------------------------
@@ -57,126 +149,122 @@ def _clamp_block(block, seq):
     return (b // 128) * 128 if b > 128 else b
 
 
-def _step_width(own, other, causal):
-    """Width of a loop step along the axis a grid row walks. Causal: no more
-    than the block the row owns, so a step the diagonal cuts is at most
+def _step_width(own, other, rule):
+    """Width of a loop step along the axis a grid row walks. Under a rule:
+    no more than the block the row owns, so a step the rule cuts is at most
     square and is never executed at twice its visible size (`other` when
-    the owned block does not divide it). Non-causal: the other block."""
-    return own if causal and other % own == 0 else other
-
-
-def _key_steps(qi, *, block_q, width, seq_k, pad_k, causal, offset,
-               mx=max, mn=min):
-    """Forward and dq: a grid row owns `block_q` queries and walks the keys
-    in `width` steps -> (lo, mid, hi): steps [lo, mid) run unmasked, steps
-    [mid, hi) masked. `qi` may be traced (`mx`/`mn` are then jnp.maximum /
-    jnp.minimum)."""
-    if not causal:
-        return 0, seq_k // width, pad_k // width
-    # Keys [0, end) are seen by the row block's last query, so nothing
-    # beyond them by any. In a loop the steps below the diagonal run masked
-    # too: on the v5e the mask costs 2% of the kernel (it is not bound by
-    # the vector ALUs) and a second loop 5-8% (PERF.md §6, PR 26). An
-    # unrolled plan masks step by step instead (`block_schedule`).
-    end = mn(mx((qi + 1) * block_q + offset, 0), pad_k)
-    return 0, 0, _cdiv(end, width)
-
-
-def _query_steps(kj, *, block_k, width, seq_q, pad_q, causal, offset,
-                 mx=max, mn=min):
-    """dk/dv: a grid row owns `block_k` keys and walks the queries in
-    `width` steps -> (lo, mid, hi) as `_key_steps`. Causal: from the first
-    query that sees the block's first key."""
-    if not causal:
-        return 0, seq_q // width, pad_q // width
-    lo = mn(mx(kj * block_k - offset, 0), pad_q) // width
-    return lo, lo, pad_q // width
+    the owned block does not divide it). No rule: the other block."""
+    return own if rule is not None and other % own == 0 else other
 
 
 class KernelSchedule(NamedTuple):
     """One kernel's loop plan over one (batch, head): `tiles` are
     (q_start, q_rows, k_start, k_cols, masked), one per loop step, and
-    `rows` the same steps by grid row, as (step index, masked). `static`:
-    every grid row's steps run as straight-line code (`_run_row`)."""
+    `rows` the same steps by grid row, as (step index, masked); a tile the
+    rule keeps nothing of is no step at all (`steps_skipped` counts them).
+    `static`: every grid row's steps run as straight-line code, each masked
+    only if it needs it; otherwise ONE loop a grid row over `table`, masked
+    throughout if any step is (`_run_row`)."""
     width: int
     static: bool
     tiles: tuple
     rows: tuple
     steps_unmasked: int
     steps_masked: int
+    steps_skipped: int
     executed_over_needed: float
+
+    @property
+    def table(self):
+        """int32 [grid rows, 1 + most steps a row]: a row's number of steps,
+        then their indices; what a loop plan's kernel reads from SMEM."""
+        import numpy as np
+
+        out = np.zeros((len(self.rows), 1 + max(map(len, self.rows))),
+                       np.int32)
+        for i, steps in enumerate(self.rows):
+            out[i, 0] = len(steps)
+            out[i, 1:1 + len(steps)] = [j for j, _ in steps]
+        return out
 
 
 # The most steps a grid row may have for its kernel to be unrolled. Measured
 # on the v5e at 512 x 512 (PERF.md §6, PR 26): 4 (S 2048) is the gain this
-# exists for; 8 (S 4096) takes the forward from 5.80 to 4.03 ms and dk/dv
-# from 8.7 to 29.5 ms, and the code grows with the square of S.
-_MAX_STATIC_STEPS = 4
+# exists for; 8 (S 4096) takes the forward from 5.80 to 4.03 ms (dq gains
+# too) and dk/dv from 8.7 to 29.5 ms, and the code grows with the square
+# of S. So a cap per plan (dq runs the forward's): the block-diffusion call
+# at 2 x 2048 has rows of 5 steps in the forward and dq, of 8 in dk/dv.
+_MAX_STATIC_STEPS = {"fwd": 8, "dkv": 4}
 
 
-def block_schedule(s_q, s_k, block_q, block_k, causal):
-    """The steps the three kernels execute at these (already clamped) block
-    sizes -> {"fwd": KernelSchedule, "dq": ..., "dkv": ...}; jax-free, all
-    static, shared by the wrappers below and by tests/test_ops.py.
-
-    `executed_over_needed` is scores executed over scores the mask keeps.
-    Starting point (before PR 26): steps of block_q x block_k whatever the
-    diagonal left of them, 512 x 1024 at S 2048: 6 steps of 512 x 1,024 a
-    head where the causal half is 2.10 M scores, 1.5 in all three kernels.
-    Causal steps are now at most square (`_step_width`), so an owned block
-    of B rows gives 1 + B / S: 1.25 at 512 and S 2048, 1.125 at S 4096.
-
-    A step runs unmasked (no iota, compare or `where`) where the schedule
-    can tell that every score in it is valid: step by step in a static
-    plan, and loop by loop (`_key_steps`) in one too long to unroll.
-    """
+@functools.lru_cache(maxsize=256)
+def _block_schedule(s_q, s_k, block_q, block_k, rule):
     offset = s_k - s_q
-    if causal:
-        needed = sum(min(max(r + offset + 1, 0), s_k) for r in range(s_q))
-    else:
-        needed = s_q * s_k
+    needed = s_q * s_k if rule is None else rule.needed(s_q, s_k)
 
-    def visible(q0, nq, k0, nk):
-        return not causal or k0 + nk - 1 <= q0 + offset
-
-    def plan(width, rows, tile):
-        """rows: per grid row its (lo, mid, hi); tile(row, step) -> (q0,
-        nq, k0, nk, every score inside seq_q x seq_k and visible)."""
-        static = max(hi - lo for lo, _, hi in rows) <= _MAX_STATIC_STEPS
-        by_row = tuple(
-            tuple((j, not tile(i, j)[4] if static else j >= mid)
-                  for j in range(lo, hi))
-            for i, (lo, mid, hi) in enumerate(rows))
-        tiles = tuple(tile(i, j)[:4] + (masked,)
+    def plan(kernel, width, n_rows, n_steps, tile, inside):
+        """tile(row, step) -> (q0, nq, k0, nk); inside(step): the step lies
+        within the true length of the axis the row walks."""
+        kept = []
+        for i in range(n_rows):
+            steps = []
+            for j in range(n_steps):
+                q0, nq, k0, nk = tile(i, j)
+                some, every = (True, True) if rule is None \
+                    else rule.tile(q0 + offset, nq, k0, nk)
+                if some:
+                    steps.append((j, not (every and inside(j))))
+            kept.append(steps)
+        static = max(map(len, kept)) <= _MAX_STATIC_STEPS[kernel]
+        if not static:
+            any_masked = any(m for steps in kept for _, m in steps)
+            kept = [[(j, any_masked) for j, _ in steps] for steps in kept]
+        by_row = tuple(map(tuple, kept))
+        tiles = tuple(tile(i, j) + (masked,)
                       for i, steps in enumerate(by_row) for j, masked in steps)
         masked = sum(t[4] for t in tiles)
         executed = sum(t[1] * t[3] for t in tiles)
         return KernelSchedule(
             width, static, tiles, by_row, len(tiles) - masked, masked,
+            n_rows * n_steps - len(tiles),
             executed / needed if needed else float("inf"))
 
-    width = _step_width(block_q, block_k, causal)
-    pad_q, pad_k = _cdiv(s_q, block_q) * block_q, _cdiv(s_k, width) * width
+    w = _step_width(block_q, block_k, rule)
     keys = plan(
-        width,
-        [_key_steps(qi, block_q=block_q, width=width, seq_k=s_k, pad_k=pad_k,
-                    causal=causal, offset=offset)
-         for qi in range(pad_q // block_q)],
-        lambda qi, j, w=width: (
-            qi * block_q, block_q, j * w, w,
-            (j + 1) * w <= s_k and visible(qi * block_q, block_q, j * w, w)))
-
-    width = _step_width(block_k, block_q, causal)
-    pad_q, pad_k = _cdiv(s_q, width) * width, _cdiv(s_k, block_k) * block_k
+        "fwd", w, _cdiv(s_q, block_q), _cdiv(s_k, w),
+        lambda qi, j: (qi * block_q, block_q, j * w, w),
+        lambda j: (j + 1) * w <= s_k)
+    wq = _step_width(block_k, block_q, rule)
     queries = plan(
-        width,
-        [_query_steps(kj, block_k=block_k, width=width, seq_q=s_q,
-                      pad_q=pad_q, causal=causal, offset=offset)
-         for kj in range(pad_k // block_k)],
-        lambda kj, i, w=width: (
-            i * w, w, kj * block_k, block_k,
-            (i + 1) * w <= s_q and visible(i * w, w, kj * block_k, block_k)))
+        "dkv", wq, _cdiv(s_k, block_k), _cdiv(s_q, wq),
+        lambda kj, i: (i * wq, wq, kj * block_k, block_k),
+        lambda i: (i + 1) * wq <= s_q)
     return {"fwd": keys, "dq": keys, "dkv": queries}
+
+
+def block_schedule(s_q, s_k, block_q, block_k, causal):
+    """The steps the three kernels execute at these (already clamped) block
+    sizes under `causal` (True, False, or a mask rule) -> {"fwd":
+    KernelSchedule, "dq": ..., "dkv": ...}; all static, shared by the
+    wrappers below and by tests/test_ops.py.
+
+    A grid row (a block of queries in the forward and dq, of keys in dk/dv)
+    walks the other axis in steps; each step's tile is asked of the rule:
+    nothing kept -> no step (skipped, not masked), everything kept and
+    inside the sequence -> a step with no mask (no iota, compare or
+    `where`), else a masked step. A row's steps need not be one contiguous
+    range (a block-diffusion x_t row visits x_0 tiles 0..i, then x_t tile i).
+
+    `executed_over_needed` is scores executed over scores the rule keeps.
+    Starting point (before PR 26): steps of block_q x block_k whatever the
+    diagonal left of them, 512 x 1024 at S 2048: 6 steps of 512 x 1,024 a
+    head where the causal half is 2.10 M scores, 1.5 in all three kernels.
+    Steps under a rule are now at most square (`_step_width`), so causal
+    with an owned block of B rows gives 1 + B / S: 1.25 at 512 and S 2048,
+    1.125 at S 4096; block diffusion at length 2,048, block 4 runs 24 of
+    the 64 tiles of 512 x 512 for 4,202,496 kept scores: 1.497.
+    """
+    return _block_schedule(s_q, s_k, block_q, block_k, _rule(causal))
 
 
 def _count_steps(*plans):
@@ -186,9 +274,11 @@ def _count_steps(*plans):
                           sum(p.steps_unmasked for p in plans))
     device_profiler.count("flash.steps_masked",
                           sum(p.steps_masked for p in plans))
+    device_profiler.count("flash.tiles_skipped",
+                          sum(p.steps_skipped for p in plans))
 
 
-def _run_row(plan, row, steps, body, carry, finish):
+def _run_row(plan, row, steps_ref, body, carry, finish):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
 
     The trip counts depend on the grid row, and Mosaic schedules nothing
@@ -197,18 +287,17 @@ def _run_row(plan, row, steps, body, carry, finish):
     A static plan has one branch a grid row instead, its steps straight-
     line code in which one step's matmuls run under its neighbours'
     softmax (1.3 ms), and each step masked only if it needs it. Otherwise
-    `steps(row, mx=, mn=)` gives the traced (lo, mid, hi): the unmasked
-    steps [lo, mid) and the masked ones [mid, hi) each in a loop, built
-    only if the plan has such a step."""
+    the row's steps come from `steps_ref` (`KernelSchedule.table`, in SMEM)
+    and run in ONE loop, masked if any step of the plan is: on the v5e the
+    mask costs 2% of the kernel (it is not bound by the vector ALUs) and a
+    second loop 5-8% (PERF.md §6, PR 26)."""
     from jax.experimental import pallas as pl
 
     if not plan.static:
-        lo, mid, hi = steps(row, mx=jnp.maximum, mn=jnp.minimum)
-        if plan.steps_unmasked:
-            carry = jax.lax.fori_loop(lo, mid, body(False), carry)
-        if plan.steps_masked:
-            carry = jax.lax.fori_loop(mid, hi, body(True), carry)
-        finish(carry)
+        step = body(plan.steps_masked > 0)
+        finish(jax.lax.fori_loop(
+            0, steps_ref[row, 0],
+            lambda t, c: step(steps_ref[row, t + 1], c), carry))
         return
 
     def branch(mine):
@@ -221,6 +310,26 @@ def _run_row(plan, row, steps, body, carry, finish):
 
     for i, mine in enumerate(plan.rows):
         pl.when(row == i)(branch(mine))
+
+
+def _pallas_call(kernel, plan, in_specs, **kw):
+    """`pl.pallas_call(kernel, in_specs=in_specs, **kw)`; a plan too long to
+    unroll hands the kernel its table of steps first, whole, in SMEM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if plan.static:
+        return pl.pallas_call(kernel, in_specs=in_specs, **kw)
+
+    @functools.wraps(kernel.func)
+    def with_steps(steps_ref, *refs):
+        return kernel(*refs, steps_ref=steps_ref)
+
+    return functools.partial(
+        pl.pallas_call(
+            with_steps, in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+            + list(in_specs), **kw),
+        jnp.asarray(plan.table))
 
 
 def _aligned(start, width):
@@ -243,8 +352,8 @@ def _lane_chunks(x, op):
 # Pallas forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, plan, seq_q, seq_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
+                block_q, plan, seq_q, seq_k, steps_ref=None):
     from jax.experimental import pallas as pl
 
     width = plan.width
@@ -274,8 +383,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 # dslice reads clamp, duplicating real rows) and, when
                 # causal, future positions.
                 valid = k_pos < seq_k
-                if causal:
-                    valid = valid & (q_pos >= k_pos)
+                if mask is not None:
+                    valid = valid & mask.keep(q_pos, k_pos)
                 s = jnp.where(valid, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -292,9 +401,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
             return o_new, m_new, l_new
         return body
 
-    steps = functools.partial(
-        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
-        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
     o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
     # they broadcast along lanes with no relayout
@@ -308,7 +414,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         o_ref[0, 0] = (o / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m + jnp.log(l)
 
-    _run_row(plan, qi, steps, step, (o0, m0, l0), finish)
+    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish)
 
 
 def _pad_seq(x, block):
@@ -319,12 +425,12 @@ def _pad_seq(x, block):
     return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
 
-def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret):
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
     s_k, d_v = k.shape[2], v.shape[3]
-    plan = block_schedule(s_q, s_k, block_q, block_k, causal)["fwd"]
+    plan = block_schedule(s_q, s_k, block_q, block_k, mask)["fwd"]
     _count_steps(plan)
     # Pad to block multiples: dynamic_slice CLAMPS out-of-range starts, which
     # would silently shift the last partial block. The kernels mask padded
@@ -335,17 +441,17 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
     s_q_pad, s_k_pad = q.shape[2], k.shape[2]
     grid = (b, h, s_q_pad // block_q)
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q, plan=plan,
+        _fwd_kernel, scale=scale, mask=mask, block_q=block_q, plan=plan,
         seq_q=s_q, seq_k=s_k,
     )
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
+    o, lse = _pallas_call(
+        kernel, plan,
+        [
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, s_k_pad, d_v), lambda b_, h_, i: (b_, h_, 0, 0)),
         ],
+        grid=grid,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d_v), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
@@ -364,7 +470,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret):
 # --------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   scale, causal, block_q, plan, seq_q, seq_k):
+                   scale, mask, block_q, plan, seq_q, seq_k, steps_ref=None):
     from jax.experimental import pallas as pl
 
     width = plan.width
@@ -391,8 +497,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                     jnp.int32, (block_q, width), 1
                 )
                 valid = k_pos < seq_k
-                if causal:
-                    valid = valid & (q_pos >= k_pos)
+                if mask is not None:
+                    valid = valid & mask.keep(q_pos, k_pos)
                 s = jnp.where(valid, s, NEG_INF)
             p = jnp.exp(s - lse)
             if masked:
@@ -408,19 +514,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
             )
         return body
 
-    steps = functools.partial(
-        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
-        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
 
     def finish(dq):
         dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
-    _run_row(plan, qi, steps, step, jnp.zeros_like(q), finish)
+    _run_row(plan, qi, steps_ref, step, jnp.zeros_like(q), finish)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_k, plan,
-                    seq_q, seq_k):
+                    dk_ref, dv_ref, *, scale, mask, block_k, plan,
+                    seq_q, seq_k, steps_ref=None):
     """Works on the TRANSPOSED score tile, s^T = k q^T [block_k, width]:
     every product is then a plain or a last-dims-contracted matmul (dv +=
     p^T do, dp^T = v do^T, dk += ds^T q), where p^T do taken from an
@@ -456,8 +559,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 # also mask future keys relative to the offset-shifted query
                 # positions.
                 valid = q_row < seq_q
-                if causal:
-                    valid = valid & ((q_row + causal_offset) >= k_pos)
+                if mask is not None:
+                    valid = valid & mask.keep(q_row + causal_offset, k_pos)
                 s = jnp.where(valid, s, NEG_INF)
             p = jnp.exp(s - lse)
             if masked:
@@ -478,20 +581,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             return dk, dv
         return body
 
-    steps = functools.partial(
-        _query_steps, block_k=block_k, width=width, seq_q=seq_q,
-        pad_q=q_ref.shape[2], causal=causal, offset=causal_offset)
 
     def finish(carry):
         dk, dv = carry
         dk_ref[0, 0] = dk.astype(dk_ref.dtype)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
-    _run_row(plan, kj, steps, step,
+    _run_row(plan, kj, steps_ref, step,
              (jnp.zeros_like(k_blk), jnp.zeros_like(v_blk)), finish)
 
 
-def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
+def _bwd_dq_pallas(q, k, v, do, lse, delta, mask, scale, block_q, plan,
                    interpret):
     from jax.experimental import pallas as pl
 
@@ -502,13 +602,12 @@ def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
     k, v = _pad_seq(k, plan.width), _pad_seq(v, plan.width)
     s_q_pad, s_k_pad = q.shape[2], k.shape[2]
     kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
+        _bwd_dq_kernel, scale=scale, mask=mask, block_q=block_q,
         plan=plan, seq_q=s_q, seq_k=s_k,
     )
-    dq = pl.pallas_call(
-        kernel,
-        grid=(b, h, s_q_pad // block_q),
-        in_specs=[
+    dq = _pallas_call(
+        kernel, plan,
+        [
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, s_k_pad, d_v), lambda b_, h_, i: (b_, h_, 0, 0)),
@@ -516,6 +615,7 @@ def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
         ],
+        grid=(b, h, s_q_pad // block_q),
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
@@ -523,7 +623,7 @@ def _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q, plan,
     return dq[:, :, :s_q]
 
 
-def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
+def _bwd_dkv_pallas(q, k, v, do, lse, delta, mask, scale, block_k, plan,
                     interpret):
     from jax.experimental import pallas as pl
 
@@ -538,13 +638,12 @@ def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
     lse = lse.reshape(b, h, n_steps, width)
     delta = delta.reshape(b, h, n_steps, width)
     kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, block_k=block_k,
+        _bwd_dkv_kernel, scale=scale, mask=mask, block_k=block_k,
         plan=plan, seq_q=s_q, seq_k=s_k,
     )
-    dk, dv = pl.pallas_call(
-        kernel,
-        grid=(b, h, s_k_pad // block_k),
-        in_specs=[
+    dk, dv = _pallas_call(
+        kernel, plan,
+        [
             pl.BlockSpec((1, 1, s_q_pad, d), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k, d_v), lambda b_, h_, j: (b_, h_, j, 0)),
@@ -552,6 +651,7 @@ def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
             pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
         ],
+        grid=(b, h, s_k_pad // block_k),
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k, d_v), lambda b_, h_, j: (b_, h_, j, 0)),
@@ -565,15 +665,15 @@ def _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k, plan,
     return dk[:, :, :s_k], dv[:, :, :s_k]
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
+def _flash_bwd_pallas(q, k, v, o, lse, do, mask, scale, block_q, block_k,
                       interpret):
-    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, causal)
+    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, mask)
     _count_steps(plans["dq"], plans["dkv"])
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
-    dq = _bwd_dq_pallas(q, k, v, do, lse, delta, causal, scale, block_q,
+    dq = _bwd_dq_pallas(q, k, v, do, lse, delta, mask, scale, block_q,
                         plans["dq"], interpret)
-    dk, dv = _bwd_dkv_pallas(q, k, v, do, lse, delta, causal, scale, block_k,
+    dk, dv = _bwd_dkv_pallas(q, k, v, do, lse, delta, mask, scale, block_k,
                              plans["dkv"], interpret)
     return dq, dk, dv
 
@@ -583,20 +683,20 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, _ = _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_bhsd(q, k, v, mask, scale, block_q, block_k, interpret):
+    o, _ = _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret)
     return o
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_fwd_rule(q, k, v, mask, scale, block_q, block_k, interpret):
+    o, lse = _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_bwd_rule(mask, scale, block_q, block_k, interpret, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _flash_bwd_pallas(
-        q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret
+        q, k, v, o, lse, do, mask, scale, block_q, block_k, interpret
     )
     return dq, dk, dv
 
@@ -617,7 +717,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     use_pallas: Optional[bool] = None,
-    interpret: bool = False, q_rope=None, k_rope=None,
+    interpret: bool = False, q_rope=None, k_rope=None, mask=None,
 ):
     """Exact attention over [B, S, H, D] inputs (GQA: fewer KV heads OK).
 
@@ -628,6 +728,12 @@ def flash_attention(
     the Pallas interpreter instead). Nothing falls back from one to the
     other at run time: chip_smoke.py fails unless `tpu_custom_call` is
     in the lowered train step.
+
+    `mask`: a static rule for which (query, key) pairs are kept, in place
+    of `causal` (which is the rule `CAUSAL`): e.g. `BlockDiffusion(length,
+    block)`. The kernels skip the tiles it keeps nothing of, run those it
+    keeps whole with no mask, and apply its predicate in the others
+    (`block_schedule`); no dense mask is built on the TPU path.
 
     In parts (latent attention): with `q_rope` [B, S, H, R] and `k_rope`
     [B, S, 1, R], ONE rotary key a (batch, position) for all heads, a score
@@ -653,28 +759,30 @@ def flash_attention(
         scale = (d + (0 if q_rope is None else q_rope.shape[-1])) ** -0.5
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu" and not interpret
+    mask = _rule(causal, mask)
     if q_rope is not None:
-        return _flash_in_parts(q, q_rope, k, k_rope, v, causal, scale,
+        return _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale,
                                block_q, block_k, use_pallas, interpret)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     if use_pallas or interpret:
         block_q = _clamp_block(block_q, s_q)
         block_k = _clamp_block(block_k, k.shape[1])
-        o = _flash_bhsd(qt, kt, vt, causal, scale, block_q, block_k, interpret)
+        o = _flash_bhsd(qt, kt, vt, mask, scale, block_q, block_k, interpret)
     else:
-        o = _reference_attention(qt, kt, vt, causal, scale)
+        o = _reference_attention(qt, kt, vt, mask, scale)
     return o.transpose(0, 2, 1, 3)
 
 
 def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
-                            q_rope=None, k_rope=None, **kw):
+                            q_rope=None, k_rope=None, mask=None, **kw):
     """shard_map-wrapped flash attention for use inside a pjit-sharded model:
     GSPMD has no partitioning rule for a Pallas custom call, so without it
     XLA all-gathers q/k/v to every device and replicates the kernel. Batch
     rides ('dp','fsdp') and heads ride 'tp' explicitly; each shard runs the
     kernel on its local [B/dp·fsdp, S, H/tp, D] block. KV heads are repeated
-    to match q heads first so the tp shard is uniform under GQA. In parts
-    (`flash_attention`): `_sharded_in_parts` below.
+    to match q heads first so the tp shard is uniform under GQA. A `mask`
+    rule speaks of positions only, so every shard runs under it as it is.
+    In parts (`flash_attention`): `_sharded_in_parts` below.
     """
     from jax.sharding import PartitionSpec as P
     h_kv = k.shape[2]
@@ -695,7 +803,8 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
     head_axis = "tp" if (mesh.shape.get("tp", 1) > 1
                          and h % mesh.shape["tp"] == 0) else None
     spec = P(batch_axes or None, None, head_axis, None)
-    fn = functools.partial(flash_attention, causal=causal, scale=scale, **kw)
+    fn = functools.partial(flash_attention, causal=causal, scale=scale,
+                           mask=mask, **kw)
     if q_rope is not None:
         return _sharded_in_parts(fn, mesh, spec, P(batch_axes or None),
                                  q, k, v, q_rope, k_rope)
@@ -739,7 +848,7 @@ def _scores(a, a_rope, b, b_rope, scale):
 
 
 def _fwd_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref, lse_ref, *,
-                      scale, causal, block_q, plan, seq_q, seq_k):
+                      scale, mask, block_q, plan, seq_q, seq_k, steps_ref=None):
     from jax.experimental import pallas as pl
 
     width = plan.width
@@ -761,8 +870,8 @@ def _fwd_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref, lse_ref, *,
                 k_pos = start + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, width), 1)
                 valid = k_pos < seq_k
-                if causal:
-                    valid = valid & (q_pos >= k_pos)
+                if mask is not None:
+                    valid = valid & mask.keep(q_pos, k_pos)
                 s = jnp.where(valid, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -775,9 +884,6 @@ def _fwd_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref, lse_ref, *,
             return o_new, m_new, l_new
         return body
 
-    steps = functools.partial(
-        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
-        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
     o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((block_q, width if width % 128 else 128),
@@ -789,12 +895,12 @@ def _fwd_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref, lse_ref, *,
         o_ref[0, 0] = (o / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m + jnp.log(l)
 
-    _run_row(plan, qi, steps, step, (o0, m0, l0), finish)
+    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish)
 
 
 def _bwd_dq_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dq_ref, *, scale, causal, block_q, plan,
-                         seq_q, seq_k):
+                         delta_ref, dq_ref, *, scale, mask, block_q, plan,
+                         seq_q, seq_k, steps_ref=None):
     """dq_ref [block_q, D + R]: both parts' gradients side by side."""
     from jax.experimental import pallas as pl
 
@@ -821,8 +927,8 @@ def _bwd_dq_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
                 k_pos = start + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, width), 1)
                 valid = k_pos < seq_k
-                if causal:
-                    valid = valid & (q_pos >= k_pos)
+                if mask is not None:
+                    valid = valid & mask.keep(q_pos, k_pos)
                 s = jnp.where(valid, s, NEG_INF)
             p = jnp.exp(s - lse)
             if masked:
@@ -832,9 +938,6 @@ def _bwd_dq_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
             return dq + _mm(ds, k_blk, 1, 0), dqr + _mm(ds, kr_blk, 1, 0)
         return body
 
-    steps = functools.partial(
-        _key_steps, block_q=block_q, width=width, seq_k=seq_k,
-        pad_k=k_ref.shape[2], causal=causal, offset=causal_offset)
     d = q.shape[-1]
 
     def finish(carry):
@@ -842,13 +945,13 @@ def _bwd_dq_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
         dq_ref[0, 0, :, :d] = dq.astype(dq_ref.dtype)
         dq_ref[0, 0, :, d:] = dqr.astype(dq_ref.dtype)
 
-    _run_row(plan, qi, steps, step, (jnp.zeros_like(q), jnp.zeros_like(qr)),
+    _run_row(plan, qi, steps_ref, step, (jnp.zeros_like(q), jnp.zeros_like(qr)),
              finish)
 
 
 def _bwd_dkv_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
                           lse_ref, delta_ref, dk_ref, dv_ref, *, scale,
-                          causal, block_k, plan, seq_q, seq_k):
+                          mask, block_k, plan, seq_q, seq_k, steps_ref=None):
     """On the transposed tile, as `_bwd_dkv_kernel`. dk_ref [block_k, D + R]:
     dk beside THIS head's share of the rotary key's gradient (the caller
     sums the heads')."""
@@ -878,8 +981,8 @@ def _bwd_dkv_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
                 q_row = start + jax.lax.broadcasted_iota(
                     jnp.int32, (block_k, width), 1)
                 valid = q_row < seq_q
-                if causal:
-                    valid = valid & ((q_row + causal_offset) >= k_pos)
+                if mask is not None:
+                    valid = valid & mask.keep(q_row + causal_offset, k_pos)
                 s = jnp.where(valid, s, NEG_INF)
             p = jnp.exp(s - lse)
             if masked:
@@ -889,9 +992,6 @@ def _bwd_dkv_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
             return dk + _mm(ds, q, 1, 0), dkr + _mm(ds, qr, 1, 0), dv
         return body
 
-    steps = functools.partial(
-        _query_steps, block_k=block_k, width=width, seq_q=seq_q,
-        pad_q=q_ref.shape[2], causal=causal, offset=causal_offset)
     d = k_blk.shape[-1]
 
     def finish(carry):
@@ -900,7 +1000,7 @@ def _bwd_dkv_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
         dk_ref[0, 0, :, d:] = dkr.astype(dk_ref.dtype)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
-    _run_row(plan, kj, steps, step,
+    _run_row(plan, kj, steps_ref, step,
              (jnp.zeros_like(k_blk), jnp.zeros_like(kr_blk),
               jnp.zeros_like(v_blk)), finish)
 
@@ -917,27 +1017,28 @@ def _block(rows, width, walked, head=True):
         lambda b_, h_, i: (b_, h_ if head else 0, i if walked else 0, 0))
 
 
-def _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q, block_k,
+def _flash_fwd_parts_pallas(q, qr, k, kr, v, mask, scale, block_q, block_k,
                             interpret):
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
     s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
-    plan = block_schedule(s_q, s_k, block_q, block_k, causal)["fwd"]
+    plan = block_schedule(s_q, s_k, block_q, block_k, mask)["fwd"]
     _count_steps(plan)
     q, qr = _pad_seq(q, block_q), _pad_seq(qr, block_q)
     k, kr, v = (_pad_seq(x, plan.width) for x in (k, kr, v))
     s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-    o, lse = pl.pallas_call(
+    o, lse = _pallas_call(
         functools.partial(
-            _fwd_parts_kernel, scale=scale, causal=causal, block_q=block_q,
+            _fwd_parts_kernel, scale=scale, mask=mask, block_q=block_q,
             plan=plan, seq_q=s_q, seq_k=s_k),
-        grid=(b, h, s_q_pad // block_q),
-        in_specs=[
+        plan,
+        [
             _block(block_q, d, True), _block(block_q, r, True),
             _block(s_k_pad, d, False), _block(s_k_pad, r, False, head=False),
             _block(s_k_pad, d_v, False),
         ],
+        grid=(b, h, s_q_pad // block_q),
         out_specs=[_block(block_q, d_v, True), _block(block_q, 1, True)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s_q_pad, d_v), q.dtype),
@@ -948,7 +1049,7 @@ def _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q, block_k,
     return o[:, :, :s_q], lse[:, :, :s_q]
 
 
-def _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
+def _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask, scale,
                          block_q, plan, interpret):
     from jax.experimental import pallas as pl
 
@@ -958,17 +1059,18 @@ def _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
                              for x in (q, qr, do, lse, delta))
     k, kr, v = (_pad_seq(x, plan.width) for x in (k, kr, v))
     s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(
-            _bwd_dq_parts_kernel, scale=scale, causal=causal, block_q=block_q,
+            _bwd_dq_parts_kernel, scale=scale, mask=mask, block_q=block_q,
             plan=plan, seq_q=s_q, seq_k=s_k),
-        grid=(b, h, s_q_pad // block_q),
-        in_specs=[
+        plan,
+        [
             _block(block_q, d, True), _block(block_q, r, True),
             _block(s_k_pad, d, False), _block(s_k_pad, r, False, head=False),
             _block(s_k_pad, d_v, False), _block(block_q, d_v, True),
             _block(block_q, 1, True), _block(block_q, 1, True),
         ],
+        grid=(b, h, s_q_pad // block_q),
         out_specs=_block(block_q, d + r, True),
         out_shape=jax.ShapeDtypeStruct((b, h, s_q_pad, d + r), q.dtype),
         interpret=interpret,
@@ -976,7 +1078,7 @@ def _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
     return dq[:, :, :s_q]
 
 
-def _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
+def _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask, scale,
                           block_k, plan, interpret):
     from jax.experimental import pallas as pl
 
@@ -991,17 +1093,18 @@ def _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
     n_steps = s_q_pad // width
     lse = lse.reshape(b, h, n_steps, width)
     delta = delta.reshape(b, h, n_steps, width)
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(
-            _bwd_dkv_parts_kernel, scale=scale, causal=causal,
+            _bwd_dkv_parts_kernel, scale=scale, mask=mask,
             block_k=block_k, plan=plan, seq_q=s_q, seq_k=s_k),
-        grid=(b, h, s_k_pad // block_k),
-        in_specs=[
+        plan,
+        [
             _block(s_q_pad, d, False), _block(s_q_pad, r, False),
             _block(block_k, d, True), _block(block_k, r, True, head=False),
             _block(block_k, d_v, True), _block(s_q_pad, d_v, False),
             _block(n_steps, width, False), _block(n_steps, width, False),
         ],
+        grid=(b, h, s_k_pad // block_k),
         out_specs=[_block(block_k, d + r, True), _block(block_k, d_v, True)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s_k_pad, d + r), k.dtype),
@@ -1013,35 +1116,35 @@ def _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_parts_bhsd(q, qr, k, kr, v, causal, scale, block_q, block_k,
+def _flash_parts_bhsd(q, qr, k, kr, v, mask, scale, block_q, block_k,
                       interpret):
     """q, k [B, H, S, D], qr [B, H, S, R], kr [B, 1, S, R], v [B, H, S, Dv]
     -> o [B, H, S, Dv]."""
-    return _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q,
+    return _flash_fwd_parts_pallas(q, qr, k, kr, v, mask, scale, block_q,
                                    block_k, interpret)[0]
 
 
-def _flash_parts_fwd_rule(q, qr, k, kr, v, causal, scale, block_q, block_k,
+def _flash_parts_fwd_rule(q, qr, k, kr, v, mask, scale, block_q, block_k,
                           interpret):
     from jax.ad_checkpoint import checkpoint_name
 
-    o, lse = _flash_fwd_parts_pallas(q, qr, k, kr, v, causal, scale, block_q,
+    o, lse = _flash_fwd_parts_pallas(q, qr, k, kr, v, mask, scale, block_q,
                                      block_k, interpret)
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
     return o, (q, qr, k, kr, v, o, lse)
 
 
-def _flash_parts_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_parts_bwd_rule(mask, scale, block_q, block_k, interpret, res, do):
     q, qr, k, kr, v, o, lse = res
-    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, causal)
+    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, mask)
     _count_steps(plans["dq"], plans["dkv"])
     lse = lse[..., None]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
-    dq = _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal, scale,
+    dq = _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask, scale,
                               block_q, plans["dq"], interpret)
-    dk, dv = _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, causal,
+    dk, dv = _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask,
                                    scale, block_k, plans["dkv"], interpret)
     d = q.shape[-1]
     # every head used the one rotary key: its gradient is the heads' sum
@@ -1052,7 +1155,7 @@ def _flash_parts_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
 _flash_parts_bhsd.defvjp(_flash_parts_fwd_rule, _flash_parts_bwd_rule)
 
 
-def _flash_in_parts(q, q_rope, k, k_rope, v, causal, scale, block_q, block_k,
+def _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale, block_q, block_k,
                     use_pallas, interpret):
     """`flash_attention` with q and k in the parts latent attention's
     projections make: q, k [B, S, H, D], q_rope [B, S, H, R], k_rope
@@ -1071,14 +1174,14 @@ def _flash_in_parts(q, q_rope, k, k_rope, v, causal, scale, block_q, block_k,
 
     if use_pallas or interpret:
         o = _flash_parts_bhsd(
-            t(q), t(q_rope), t(k), t(k_rope), t(v), causal, scale,
+            t(q), t(q_rope), t(k), t(k_rope), t(v), mask, scale,
             _clamp_block(block_q, q.shape[1]),
             _clamp_block(block_k, k.shape[1]), interpret)
     else:
         k_rope = jnp.broadcast_to(k_rope, q_rope.shape)
         o = _reference_attention(
             t(jnp.concatenate([q, q_rope], axis=-1)),
-            t(jnp.concatenate([k, k_rope], axis=-1)), t(v), causal, scale)
+            t(jnp.concatenate([k, k_rope], axis=-1)), t(v), mask, scale)
     return t(o)
 
 

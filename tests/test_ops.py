@@ -7,7 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _clamp_block, block_schedule, flash_attention)
+    _MAX_STATIC_STEPS, BlockDiffusion, _clamp_block, block_schedule,
+    flash_attention)
 
 
 def _make_qkv(B=1, S=128, H=2, D=64, kv_heads=None, seed=0):
@@ -103,10 +104,25 @@ def _default_blocks(s_q, s_k):
             _clamp_block(params["block_k"].default, s_k))
 
 
+def _dense_block_diffusion(length, block):
+    """bool [2L, 2L], written out from the rule's words: same half and
+    block, or a clean key of an earlier block."""
+    pos = np.arange(2 * length)
+    clean, blk = pos >= length, (pos % length) // block
+    return ((clean[:, None] == clean[None]) & (blk[:, None] == blk[None])) \
+        | (clean[None] & (blk[None] < blk[:, None]))
+
+
 def _mask(s_q, s_k, causal, rows, cols):
+    """The dense mask of `causal` (True, False or a block-diffusion rule)
+    over a rows x cols padded area."""
     r = np.arange(rows)[:, None]
     c = np.arange(cols)[None, :]
     m = (r < s_q) & (c < s_k)
+    if isinstance(causal, BlockDiffusion):
+        rule = np.zeros((rows, cols), bool)
+        rule[:s_q, :s_k] = _dense_block_diffusion(causal.length, causal.block)
+        return m & rule
     return m & (c <= r + (s_k - s_q)) if causal else m
 
 
@@ -129,6 +145,18 @@ _SCHEDULES = {
     "s192-on-128": ((192, 192, 128, 128, True), 2.66, 2.66),
     "s100-one-block": ((100, 100, None, None, True), 2.0, 2.0),
     "s128-one-block": ((128, 128, None, None, False), 1.0, 1.0),
+    # block diffusion over [x_t ; x_0]: 24 of the 64 tiles of 512 x 512
+    "bd-l2048-b4": ((4096, 4096, None, None, BlockDiffusion(2048, 4)),
+                    1.4971, 1.4971),
+    "bd-l512-b4-128": ((1024, 1024, 128, 128, BlockDiffusion(512, 4)),
+                       1.49, 1.49),
+    # a block that does not divide the tile, a length that is no tile multiple
+    "bd-l192-b3-128": ((384, 384, 128, 128, BlockDiffusion(192, 3)),
+                       3.51, 3.51),
+    "bd-l100-b4-padded": ((200, 200, None, None, BlockDiffusion(100, 4)),
+                          6.31, 6.31),
+    "bd-l1280-b4-loops": ((2560, 2560, 128, 128, BlockDiffusion(1280, 4)),
+                          1.2, 1.2),
 }
 
 
@@ -157,6 +185,9 @@ def test_block_schedule_against_the_mask(case):
                 # at most square: the diagonal never cuts a step twice
                 # the size of what it leaves visible
                 assert plan.width <= max(block_q, block_k)
+                if plan.static:
+                    # a tile the rule keeps nothing of is no step
+                    assert mask[q0:q0 + nq, k0:k0 + nk].any(), (q0, k0)
             if masked:
                 continue
             # an unmasked step: every score of the owned block's real rows
@@ -170,7 +201,11 @@ def test_block_schedule_against_the_mask(case):
                 assert mask[q0:q0 + nq, k0:min(k0 + nk, s_k)].all(), (q0, k0)
         assert painted.max() == 1
         assert (painted[mask] == 1).all()
-        assert plan.static == (max(len(r) for r in plan.rows) <= 4)
+        # the cap on unrolling is per plan: dq runs the forward's
+        assert plan.static == (max(len(r) for r in plan.rows)
+                               <= _MAX_STATIC_STEPS[name])
+        assert plan.steps_skipped == len(plan.rows) * (
+            (cols if name == "fwd" else rows) // plan.width) - len(plan.tiles)
         assert [t[4] for t in plan.tiles] == [
             masked for row in plan.rows for _, masked in row]
         if plan.static:
@@ -202,9 +237,10 @@ def test_block_schedule_starting_point():
 
 
 # Shapes where a grid row runs several steps, some unmasked and some masked:
-# plans short enough to unroll (`static`, at most 4 steps a row: each step
-# masked or not by itself) and longer ones (loops: unmasked only where a
-# whole loop is, so never in a causal call).
+# plans short enough to unroll (`static`: at most 8 steps a row in the
+# forward and dq, 4 in dk/dv, each step masked or not by itself) and longer
+# ones (ONE loop a grid row over the plan's table: masked throughout if any
+# step is).
 _MIXED = {
     "s512-128x128": dict(s_q=512, s_k=512, block_q=128, block_k=128),
     "s512-128x256": dict(s_q=512, s_k=512, block_q=128, block_k=256),
@@ -215,11 +251,18 @@ _MIXED = {
                                    block_k=256, causal=False),
     "s448-128x256-noncausal": dict(s_q=448, s_k=448, block_q=128,
                                    block_k=256, causal=False),
+    # 6 steps a row: the forward and dq unrolled, dk/dv in a loop
     "s768-128x128-loops": dict(s_q=768, s_k=768, block_q=128, block_k=128,
-                               static=False),
+                               static=("fwd", "dq")),
     "s704-128x128-noncausal-loops": dict(s_q=704, s_k=704, block_q=128,
                                          block_k=128, causal=False,
-                                         static=False),
+                                         static=("fwd", "dq")),
+    # 9 and 10 steps a row: all three kernels in loops
+    "s1152-128x128-loops": dict(s_q=1152, s_k=1152, block_q=128,
+                                block_k=128, static=()),
+    "s1216-128x128-noncausal-loops": dict(s_q=1216, s_k=1216, block_q=128,
+                                          block_k=128, causal=False,
+                                          static=()),
 }
 
 
@@ -230,12 +273,12 @@ def test_flash_attention_interior_and_edge_steps(case):
     spec = dict(_MIXED[case])
     s_q, s_k = spec.pop("s_q"), spec.pop("s_k")
     causal = spec.pop("causal", True)
-    static = spec.pop("static", True)
+    static = spec.pop("static", ("fwd", "dq", "dkv"))
     plans = block_schedule(s_q, s_k, spec["block_q"], spec["block_k"], causal)
-    for plan in plans.values():
-        assert plan.static == static
+    for name, plan in plans.items():
+        assert plan.static == (name in static)
         assert plan.steps_masked
-        assert bool(plan.steps_unmasked) == (static or not causal)
+        assert bool(plan.steps_unmasked) == plan.static
     assert max(len(steps) for steps in plans["fwd"].rows) > 1
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(keys[0], (1, s_q, 2, 64), dtype=jnp.float32)
@@ -267,6 +310,129 @@ def test_flash_attention_counts_its_steps():
     jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **how)))(q)
     after = device_profiler.snapshot()["counters"]
     for name, field in (("flash.steps_unmasked", "steps_unmasked"),
-                        ("flash.steps_masked", "steps_masked")):
+                        ("flash.steps_masked", "steps_masked"),
+                        ("flash.tiles_skipped", "steps_skipped")):
         assert after[name] - before.get(name, 0) == sum(
             getattr(plans[kernel], field) for kernel in ("fwd", "dq", "dkv"))
+
+
+def test_block_diffusion_schedule_at_the_cell_shape():
+    """L 2,048, block 4, tiles of 512 over the 4,096-long concatenation: 24
+    of 64 tiles run (10 block-causal x_0 tiles, 10 x_t -> x_0 tiles, 4 x_t
+    diagonal tiles that keep 2,048 of 262,144 scores each), half of them
+    with no mask; the rule keeps L^2 + L x block scores, HALF of what a
+    causal call at S 4,096 keeps; an x_t row tile visits x_0 tiles 0..i and
+    then its own x_t tile, which is no contiguous range."""
+    length, block, tile = 2048, 4, 512
+    rule = BlockDiffusion(length, block)
+    plans = block_schedule(2 * length, 2 * length, tile, tile, rule)
+    assert rule.needed(2 * length, 2 * length) \
+        == length * length + length * block == 4_202_496
+    causal = block_schedule(2 * length, 2 * length, tile, tile, True)["fwd"]
+    assert sum(t[1] * t[3] for t in causal.tiles) \
+        / causal.executed_over_needed == pytest.approx(2 * 4_202_496, rel=2e-3)
+    dense = _dense_block_diffusion(length, block)
+    assert dense.sum() == 4_202_496
+    for name, plan in plans.items():
+        assert len(plan.tiles) == 24 and plan.steps_skipped == 40
+        assert plan.executed_over_needed == pytest.approx(
+            24 * tile * tile / 4_202_496)
+        by_kind = {"x0": 0, "xt_to_x0": 0, "xt_diagonal": 0}
+        for q0, nq, k0, nk, _ in plan.tiles:
+            assert dense[q0:q0 + nq, k0:k0 + nk].any()
+            if q0 >= length:
+                assert k0 >= length            # x_0 never sees x_t
+                by_kind["x0"] += 1
+            elif k0 >= length:
+                by_kind["xt_to_x0"] += 1
+            else:
+                assert q0 == k0                # x_t sees its own block only
+                assert dense[q0:q0 + nq, k0:k0 + nk].sum() == length
+                by_kind["xt_diagonal"] += 1
+        assert by_kind == {"x0": 10, "xt_to_x0": 10, "xt_diagonal": 4}
+    fwd, dkv = plans["fwd"], plans["dkv"]
+    assert [len(r) for r in fwd.rows] == [2, 3, 4, 5, 1, 2, 3, 4]
+    assert [j for j, _ in fwd.rows[2]] == [2, 4, 5, 6]   # own tile, x_0 0..2
+    assert fwd.static and (fwd.steps_unmasked, fwd.steps_masked) == (12, 12)
+    # dk/dv: an x_0 key tile is walked by up to 8 query tiles: a loop
+    assert [len(r) for r in dkv.rows] == [1, 1, 1, 1, 8, 6, 4, 2]
+    assert not dkv.static and dkv.steps_unmasked == 0
+    np.testing.assert_array_equal(dkv.table[4], [8, 0, 1, 2, 3, 4, 5, 6, 7])
+    np.testing.assert_array_equal(dkv.table[0, :2], [1, 0])
+
+
+# (length, block, tile): the three kernels under the rule, in the Pallas
+# interpreter, against the dense-mask oracle
+_BLOCK_DIFFUSION = {
+    "l512-b4-unrolled-and-loops": (512, 4, 128),
+    "l192-b3-block-cuts-the-tile": (192, 3, 128),
+    "l100-b4-padded": (100, 4, 128),
+    "l320-b5-length-no-tile-multiple": (320, 5, 128),
+    "l1280-b4-all-loops": (1280, 4, 128),
+    "l64-b1": (64, 1, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_DIFFUSION))
+def test_flash_attention_under_the_block_diffusion_rule(case):
+    """Forward and the three gradients of the Pallas kernels (interpret
+    mode) under `mask=BlockDiffusion(L, block)` against the oracle, which
+    builds the DENSE [2L, 2L] mask; GQA; and the oracle's dense mask is the
+    rule's words written out."""
+    length, block, tile = _BLOCK_DIFFUSION[case]
+    rule, s = BlockDiffusion(length, block), 2 * length
+    np.testing.assert_array_equal(
+        rule.keep(np.arange(s)[:, None], np.arange(s)[None, :]),
+        _dense_block_diffusion(length, block))
+    q, k, v = _make_qkv(S=s, H=4, kv_heads=2, D=32, seed=length)
+
+    def loss(q, k, v, **how):
+        out = flash_attention(q, k, v, mask=rule, **how)
+        return jnp.sum(out ** 2), out
+
+    (_, out), g1 = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, interpret=True, block_q=tile, block_k=tile)
+    (_, ref), g2 = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, use_pallas=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+    # the oracle against attention written out with the dense mask
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2)) \
+        / 32 ** 0.5
+    scores = jnp.where(_dense_block_diffusion(length, block), scores, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
+                      jnp.repeat(v, 2, axis=2))
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)
+
+
+def test_block_diffusion_rule_wants_whole_blocks_over_both_halves():
+    with pytest.raises(ValueError):
+        block_schedule(512, 512, 128, 128, BlockDiffusion(200, 4))
+    with pytest.raises(ValueError):
+        block_schedule(400, 400, 128, 128, BlockDiffusion(200, 3))
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["oracle", "kernels"])
+def test_flash_attention_rule_on_a_mesh(interpret):
+    """`flash_attention_sharded` carries the rule through its shard_map
+    (batch over fsdp, heads over tp; a rule speaks of positions only, so
+    every shard runs under it as it is): values and gradients equal the
+    unsharded call's."""
+    from ray_tpu.ops.flash_attention import flash_attention_sharded
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2),
+                      devices=jax.devices()[:4])
+    rule = BlockDiffusion(128, 4)
+    q, k, v = _make_qkv(B=2, S=256, H=4, kv_heads=2, D=32, seed=9)
+    do = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+    want, vjp_want = jax.vjp(
+        lambda q, k, v: flash_attention(q, k, v, mask=rule), q, k, v)
+    got, vjp = jax.vjp(jax.jit(lambda q, k, v: flash_attention_sharded(
+        q, k, v, mesh, mask=rule, interpret=interpret, block_q=128,
+        block_k=128)), q, k, v)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    for a, w in zip(vjp(do), vjp_want(do)):
+        assert a.shape == w.shape
+        np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4)

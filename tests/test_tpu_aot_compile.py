@@ -37,9 +37,9 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
-from ray_tpu.models import llama, mla_moe
+from ray_tpu.models import llama, mla_moe, sdar
 from ray_tpu.ops import grouped_matmul
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.flash_attention import BlockDiffusion, flash_attention
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import logical_sharding, param_shardings
@@ -208,6 +208,48 @@ out["tp_permutes_in_loops"] = sum(
         r"= \(bf16\[8,1024,4096\]\S*, .* collective-permute-start\(", ln))
 out["tp_all_to_alls"] = len(re.findall(r" all-to-all\(", hlo))
 
+# the flash call under the block-diffusion rule (train-sdar-1chip): q
+# [4, 4096, 32, 128] over the concatenation [x_t ; x_0] of 2 x 2,048, 4 kv
+# heads, forward and backward
+rule = BlockDiffusion(2048, 4)
+lowered = jax.jit(lambda q, k, v: jax.grad(
+    lambda q, k, v: flash_attention(q, k, v, use_pallas=True, mask=rule)
+    .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)).lower(
+        spec((4, 4096, 32, 128), bf16), spec((4, 4096, 4, 128), bf16),
+        spec((4, 4096, 4, 128), bf16))
+out["flash_bd_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+dense = re.compile(r"\[(\d+,)*4096,4096\]")
+out["flash_bd_dense"] = [ln.strip()[:160] for ln in
+                         lowered.compile().as_text().splitlines()
+                         if dense.search(ln)]
+out["flash_bd"] = "compiled"
+
+# ONE layer of the cell's model at its widths and batch (the share: 16 of
+# 128 experts), the whole objective, value and gradient, as the v5e's
+# compiler leaves it. `llama._flash` too follows jax.default_backend()
+llama.flash_attention = functools.partial(flash_attention, use_pallas=True)
+cfg = sdar.SdarConfig(
+    vocab_size=18992, d_model=2048, n_layers=1, n_heads=32, n_kv_heads=4,
+    d_head=128, d_ff=768, n_experts=128, n_experts_held=16,
+    experts_per_token=8, rope_theta=1e6, norm_eps=1e-6, max_seq_len=4096,
+    loss_chunk_size=1024)
+tokens = spec((4, 2048), jnp.int32)
+hlo = jax.jit(jax.value_and_grad(lambda p, b: sdar.loss_fn(p, b, cfg))).lower(
+    on_chip(jax.eval_shape(lambda: sdar.init(cfg, jax.random.PRNGKey(0)))),
+    {"inputs": tokens, "targets": tokens}).compile().as_text()
+ops = [ln.strip() for ln in hlo.splitlines()]
+out["sdar_custom_calls"] = sum(
+    1 for ln in ops if 'custom_call_target="tpu_custom_call"' in ln)
+out["sdar_dense"] = [ln[:160] for ln in ops if dense.search(ln)]
+for name in ("bd_flash_fwd_roofline", "bd_flash_bwd_roofline",
+             "bd_attention_time_share"):
+    with open(os.path.join(os.environ["REPO_ROOT"], "benchmarks", "metrics",
+                           name + ".json")) as f:
+        query = re.compile(json.load(f)["trace_query"]["op"])
+    hits = [ln for ln in ops if query.search(ln)]
+    out[name + "_events"] = len(hits)
+    out[name + "_named"] = all(ln.startswith("%bd.attend") for ln in hits)
+
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
 eng = PagedInferenceEngine(params, cfg, max_batch=8, max_len=1024,
@@ -321,3 +363,28 @@ def test_moe_layer_backward_as_compiled_for_v5e(compiled):
     assert compiled["moe_layer_custom_calls"] == 11
     assert compiled["moe_layer_row_gathers"] == 5
     assert compiled["moe_layer_pair_scatters"] == []
+
+
+def test_flash_under_the_block_diffusion_rule_compiles_for_v5e(compiled):
+    """train-sdar-1chip's call, q `[4, 4096, 32, 128]` over [x_t ; x_0] with
+    4 kv heads under `BlockDiffusion(2048, 4)`: forward, dq (unrolled) and
+    dk/dv (a loop over its table of steps, in SMEM) compile for the v5e, and
+    no [4096, 4096] score or mask array is in the compiled program."""
+    assert compiled["flash_bd_custom_calls"] >= 3
+    assert compiled["flash_bd"] == "compiled"
+    assert compiled["flash_bd_dense"] == []
+
+
+def test_sdar_layer_as_compiled_for_v5e(compiled):
+    """One layer of the cell's model and the whole objective at its widths
+    and batch, value and gradient: the Pallas kernels are in it (the flash
+    forward twice, the layer's remat reruns it; dq; dk/dv; the share's
+    grouped matmuls), no dense [2L, 2L] array is, and the flash events
+    carry the scope `bd.attend` and match the new metrics' queries."""
+    assert compiled["sdar_custom_calls"] >= 4 + 3
+    assert compiled["sdar_dense"] == []
+    assert compiled["bd_flash_fwd_roofline_events"] == 2
+    assert compiled["bd_flash_bwd_roofline_events"] == 2
+    assert compiled["bd_attention_time_share_events"] == 4
+    for name in ("bd_flash_fwd_roofline", "bd_flash_bwd_roofline"):
+        assert compiled[name + "_named"], name
