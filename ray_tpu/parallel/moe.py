@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.row_sums import sum_rows_by_token
 
 
 class MoEAux(NamedTuple):
@@ -197,11 +198,11 @@ def share_capacities(t: int, k: int, n_held: int, n_experts: int) -> tuple:
     most that can land (every choice of every token held): nothing is
     dropped at any imbalance. Twice: at seeded weights the live rows of a
     routed block scatter widely around the even share (0.16x to 2.2x over
-    ~4,000 blocks of sixteen seeds on the v5e); the smallest capacity's
-    [rows, D] buffers are then the 64 MiB the chip's gathers stage in fast
-    memory, and 1-4% of a seed's blocks run the next one, 7.3 ms dearer
-    (PERF.md section 6, PR 32, on why not 1.25x or 2.5x). Holding every
-    expert gives (T x k,)."""
+    ~4,000 blocks of sixteen seeds on the v5e), and 1-4% of a seed's blocks
+    run the next capacity (PERF.md section 6, PR 32, on why not 1.25x or
+    2.5x, measured while the combine still gathered T x k rows out of the
+    buffer; whether 1.5x pays now is open, section 7). Holding every expert
+    gives (T x k,)."""
     rows = t * k
     cap = -(-2 * (rows * n_held // n_experts) // 256) * 256
     caps = []
@@ -234,9 +235,9 @@ def sort_held(experts, first_expert: int, n_held: int):
 # `_combine` are for the whole dispatch): `token[s]` is the token of sorted
 # row s, or T for a dead row (past the live ones); `slot[t, j]` the row of
 # token t's j-th pair, or a dead row for a pair whose expert is absent.
-# Summing a token's rows is a gather of T x k rows whatever landed here; a
-# scatter-add of the live rows into their tokens takes twice as long on the
-# v5e (1.83 against 0.96 ms for 16,384 rows of 2,048; PERF.md section 6, PR 32).
+# Both follow the buffer's rows, not the T x k slots, and neither reads a
+# dead row (`ops/row_sums.py`; a scatter-add of the live rows into their
+# tokens: 1.83 ms for 16,384 rows of 2,048, PERF.md section 6, PR 32).
 
 @jax.custom_vjp
 def _take_rows(x, token, slot):
@@ -249,10 +250,8 @@ def _take_rows_fwd(x, token, slot):
 
 
 def _take_rows_bwd(res, g):
-    token, slot = res
-    # the grouped matmuls' backward leaves dead rows unwritten
-    g = jnp.where((token < slot.shape[0])[:, None], g, jnp.zeros((), g.dtype))
-    return _sum_rows(g, token, slot), None, None
+    # the grouped matmuls' backward leaves g's dead rows unwritten
+    return _sum_rows(g, *res), None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -260,9 +259,9 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 @jax.custom_vjp
 def _sum_rows(rows, token, slot):
-    """rows [cap, D], zero where dead -> [T, D]: the sum of each token's k
-    rows, accumulated in float32, in rows.dtype."""
-    return jnp.sum(rows[slot].astype(jnp.float32), axis=1).astype(rows.dtype)
+    """rows [cap, D], anything where dead -> [T, D]: the sum of each token's
+    live rows, accumulated in float32, in rows.dtype."""
+    return sum_rows_by_token(rows, token, slot)
 
 
 def _sum_rows_fwd(rows, token, slot):
@@ -283,9 +282,10 @@ def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
     `_capacity_switch` would not have chosen it) through the held experts'
     SwiGLU, summed back into their tokens -> [T, D] in x.dtype. Plain ops,
     differentiable in x and the experts' weights: rows past the live ones
-    read no token and are masked out of every grouped matmul's result (the
-    kernels visit live tiles only and leave the rest unwritten), so they
-    are zero where a token's absent pairs point."""
+    read no token, and the kernels visit live tiles only and leave the rest
+    unwritten. gate and up are masked there, so that h and, on the way
+    back, their gradients are zero where dead (the weights' gradients read
+    them); the down projection's result is read by `_sum_rows` alone."""
     t = x.shape[0]
     live = jnp.sum(group_sizes)
     valid = jnp.arange(cap) < live
@@ -305,7 +305,7 @@ def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
         up = gmm(rows, experts["w_up"])
         h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
              * w_sorted[:, None]).astype(x.dtype)
-        out = gmm(h, experts["w_down"])
+        out = grouped_matmul(h, experts["w_down"], group_sizes)
     with jax.named_scope("moe.combine"):
         return _sum_rows(out, token, slot)
 
@@ -390,11 +390,10 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
 
     The down projection is linear, so w_tj multiplies its INPUT, on the
     sorted side: silu(gate) * up * w is formed in float32 and rounded to
-    x.dtype once. What is left of the combine is then a gather and a sum
-    over k with nothing but indices to keep for its transpose. Were the
-    weights applied after the down projection, their gradient would need its
-    output, and a backward pass that keeps no Pallas call's result (remat
-    "dots") would rerun the down matmul and the un-permute for it alone."""
+    x.dtype once. What is left of the combine is then a sum of each token's
+    rows with nothing but indices to keep for its transpose. Applied after
+    the down projection, the weights' gradient would need its output: remat
+    "dots" would rerun the down matmul and the un-permute for it alone."""
     t = x.shape[0]
     e = router_w.shape[1]
     routing = route(x, router_w, k, norm_topk_prob, score=score,
@@ -403,9 +402,8 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
         first, n_held = held
         if not (0 <= first and first + n_held <= e) \
                 or experts["w_gate"].shape[0] != n_held:
-            raise ValueError(
-                f"held experts {held} of {e}, weights for "
-                f"{experts['w_gate'].shape[0]}")
+            raise ValueError(f"held experts {held} of {e}, weights for "
+                             f"{experts['w_gate'].shape[0]}")
         caps = share_capacities(t, k, n_held, e)
         with jax.named_scope("moe.permute"):
             order, inverse, group_sizes = sort_held(
@@ -414,6 +412,8 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
                           group_sizes, k, caps)
         device_profiler.count("moe.experts_held", n_held)
         device_profiler.count("moe.rows_capacity", caps[-1])
+        device_profiler.count("moe.combine_slots", t * k * len(caps))
+        device_profiler.count("moe.combine_rows", sum(caps))
     else:
         with jax.named_scope("moe.permute"):
             order, inverse, group_sizes = sort_by_expert(routing.experts, e)

@@ -17,6 +17,7 @@ import pytest
 from benchmarks import reference_joyai as ref
 from ray_tpu._private import device_profiler
 from ray_tpu.models import mixtral, mla_moe
+from ray_tpu.ops import row_sums
 from ray_tpu.ops.flash_attention import _reference_attention, flash_attention
 from ray_tpu.parallel import moe
 
@@ -210,6 +211,108 @@ def test_nothing_is_dropped_at_either_extreme(where):
         argnums=(0, 1))(h, p["experts"])
     for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-4)
+
+
+def _in_token_order(rows, token, slot):
+    """`ops/row_sums.py`'s TPU form, in the Pallas interpreter."""
+    return row_sums._sum_in_token_order(rows, token, slot.shape[0],
+                                       interpret=True)
+
+
+def _share_rows(t, k, e, first, n_held, held_pairs, seed):
+    """A share's buffer as `_held_rows` builds it, every capacity that
+    holds the live rows: token i chooses `held_pairs[i]` held experts and
+    k - held_pairs[i] absent ones. -> [(cap, live, token, slot)]."""
+    rng = np.random.default_rng(seed)
+    held = np.arange(first, first + n_held)
+    absent = np.setdiff1d(np.arange(e), held)
+    chosen = np.stack([rng.permutation(np.concatenate([
+        rng.choice(held, n, replace=False),
+        rng.choice(absent, k - n, replace=False)])) for n in held_pairs])
+    order, inverse, group_sizes = moe.sort_held(
+        jnp.asarray(chosen, jnp.int32), first, n_held)
+    live = int(group_sizes.sum())
+    assert live == int(np.sum(held_pairs))
+    out = []
+    for cap in moe.share_capacities(t, k, n_held, e):
+        if cap > live or cap == t * k:
+            token = jnp.where(jnp.arange(cap) < live, order[:cap] // k, t)
+            slot = jnp.where(inverse < live, inverse, cap - 1).reshape(t, k)
+            out.append((cap, live, token, slot))
+    return out
+
+
+# (T, k, E, first, n_held, D): two token tiles and eight row tiles of 256;
+# and a share smaller than any tile, so that every tile is clamped
+_TILED = (512, 4, 16, 4, 4, 384)
+_CLAMPED = (48, 2, 8, 2, 2, 128)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", [_TILED, _CLAMPED], ids=["tiled", "clamped"])
+@pytest.mark.parametrize("where", ["all_held", "none_held", "even", "heavy"])
+def test_sum_rows_in_token_order_is_the_gather_and_sum(where, shape, dtype):
+    """The TPU's form of a share's combine (sort the buffer's token ids,
+    one gather of `cap` rows, one-hot^T x rows a token tile through megablox
+    `tgmm`), in the Pallas interpreter, against `rows[slot]` summed: at
+    every pair / no pair / the even share / three times it held, at every
+    capacity that holds the live rows, with a token that has no held pair,
+    one that has all k, a token tile no row belongs to, and NaN in the dead
+    rows, which neither form may read."""
+    t, k, e, first, n_held, d = shape
+    assert n_held * 4 == e and k <= n_held     # the even share: k / 4 a token
+    held_pairs = {
+        "all_held": np.full(t, k),
+        "none_held": np.zeros(t, np.int64),
+        # 0, 1, .. k held pairs by turns in the FIRST half of the tokens
+        # (token 0 none, token k all k), none in the second half: its token
+        # tiles have no row
+        "even": np.where(np.arange(t) < t // 2, np.arange(t) % (k + 1), 0),
+        "heavy": np.full(t, -(-3 * k // 4)),
+    }[where]
+    cases = _share_rows(t, k, e, first, n_held, held_pairs, seed=3)
+    # both capacities where the shape has two and the live rows fit both
+    both = where in ("even", "none_held") and shape == _TILED
+    assert len(cases) == (2 if both else 1), [c[:2] for c in cases]
+    if where == "even":
+        assert held_pairs[0] == 0 and held_pairs[k] == k
+    for cap, live, token, slot in cases:
+        rows = np.array(jax.random.normal(jax.random.PRNGKey(cap), (cap, d)))
+        want = jnp.sum(jnp.asarray(
+            np.where(np.arange(cap)[:, None] < live, rows, 0.0),
+            dtype)[slot].astype(jnp.float32), axis=1).astype(dtype)
+        rows[live:] = np.nan
+        rows = jnp.asarray(rows, dtype)
+        got = _in_token_order(rows, token, slot)
+        assert got.shape == (t, d) and got.dtype == dtype
+        assert not np.isnan(np.asarray(got, np.float32)).any()
+        # float32 sums in another order; bf16: the same sum rounded once
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=2e-6 if dtype == jnp.float32 else 2.0 ** -7, atol=1e-6)
+        # and the form every other backend runs
+        np.testing.assert_array_equal(
+            np.asarray(row_sums.sum_rows_by_token(rows, token, slot),
+                       np.float32), np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("where", ["all_held", "none_held", "even", "heavy"])
+def test_nothing_is_dropped_through_the_token_order_combine(where, monkeypatch):
+    """`test_nothing_is_dropped_at_either_extreme`'s four cases, output and
+    gradients to x and the experts against the reference, with the TPU's
+    form of `_sum_rows` in the layer (forward, and backward as
+    `_take_rows`' transpose)."""
+    calls = []
+
+    def combine(rows, token, slot):
+        calls.append(rows.shape)
+        return _in_token_order(rows, token, slot)
+
+    monkeypatch.setattr(moe, "sum_rows_by_token", combine)
+    test_nothing_is_dropped_at_either_extreme(where)
+    # both capacities' branches, forward and backward
+    assert {shape[0] for shape in calls} == {1024, 2048} and len(calls) >= 4
 
 
 def test_routing_stats_counts_the_held_pairs_of_every_layer():
@@ -475,4 +578,11 @@ def test_counters_of_a_lowering():
     assert delta["mtp.depth"] == 1
     assert delta["moe.experts_held"] == 2 * 4
     assert delta["moe.rows_capacity"] == 2 * toks[:, :-1].size * cfg.experts_per_token
+    # a share's combine, per lowered capacity: the slots a token has (what
+    # a gather of `rows[slot]` moves) and the buffer's rows (what the sum in
+    # token order reads)
+    t, k = toks[:, :-1].size, cfg.experts_per_token
+    caps = moe.share_capacities(t, k, 4, cfg.n_experts)
+    assert delta["moe.combine_slots"] == 2 * len(caps) * t * k
+    assert delta["moe.combine_rows"] == 2 * sum(caps)
     assert delta["moe.experts"] == 2 * cfg.n_experts

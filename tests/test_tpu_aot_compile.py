@@ -38,7 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
 from ray_tpu.models import llama, mla_moe, sdar
-from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops import grouped_matmul, row_sums
 from ray_tpu.ops.flash_attention import BlockDiffusion, flash_attention
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -226,8 +226,11 @@ out["flash_bd"] = "compiled"
 
 # ONE layer of the cell's model at its widths and batch (the share: 16 of
 # 128 experts), the whole objective, value and gradient, as the v5e's
-# compiler leaves it. `llama._flash` too follows jax.default_backend()
+# compiler leaves it. `llama._flash` too follows jax.default_backend(), and
+# so does a share's combine (`ops/row_sums.py`)
 llama.flash_attention = functools.partial(flash_attention, use_pallas=True)
+moe.sum_rows_by_token = lambda rows, token, slot: (
+    row_sums._sum_in_token_order(rows, token, slot.shape[0]))
 cfg = sdar.SdarConfig(
     vocab_size=18992, d_model=2048, n_layers=1, n_heads=32, n_kv_heads=4,
     d_head=128, d_ff=768, n_experts=128, n_experts_held=16,
@@ -249,6 +252,23 @@ for name in ("bd_flash_fwd_roofline", "bd_flash_bwd_roofline",
     hits = [ln for ln in ops if query.search(ln)]
     out[name + "_events"] = len(hits)
     out[name + "_named"] = all(ln.startswith("%bd.attend") for ln in hits)
+# a share's combine below the last capacity (T x k = 131,072 rows, whose
+# own buffers are that long): nothing there is as long as the slots
+out["sdar_slot_rows"] = [
+    ln[:160] for ln in ops if re.search(r"branch_[01]_fun", ln)
+    and re.search(r"= \(?bf16\[131072,2048\]", ln)]
+out["sdar_branches"] = sorted(set(re.findall(r"branch_\d_fun", hlo)))
+with open(os.path.join(os.environ["REPO_ROOT"], "benchmarks", "metrics",
+                       "moe_combine_time_share.json")) as f:
+    query = re.compile(json.load(f)["trace_query"]["op"])
+hits = [ln for ln in ops if query.search(ln)]
+kernels = [ln for ln in hits if "tpu_custom_call" in ln]
+out["moe_combine_kernels"] = len(kernels)
+out["moe_combine_kernels_are_the_sums"] = all(
+    re.match(r"%tgmm[\w.]* = bf16\[64,256,2048\]", ln) for ln in kernels)
+out["moe_combine_other_hits_below_the_last_capacity"] = [
+    ln[:160] for ln in hits if "tpu_custom_call" not in ln
+    and re.search(r"branch_0_fun", ln)]
 
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
@@ -388,3 +408,22 @@ def test_sdar_layer_as_compiled_for_v5e(compiled):
     assert compiled["bd_attention_time_share_events"] == 4
     for name in ("bd_flash_fwd_roofline", "bd_flash_bwd_roofline"):
         assert compiled[name + "_named"], name
+
+
+def test_a_share_combines_from_the_buffers_rows_as_compiled_for_v5e(compiled):
+    """The same compiled layer: below the last capacity (T x k rows, whose
+    own buffers are that long) no array has the 131,072 rows that a gather
+    of every token's 8 slots writes, and `moe_combine_time_share`'s query
+    finds the combine's six `tgmm` calls (forward and `_take_rows`'
+    transpose in each of three capacities; the layer's remat reruns no
+    combine, its output is needed by nothing on the way back), each
+    one-hot^T x rows over 64 tiles of 256 tokens, and none of the experts'
+    grouped matmuls."""
+    assert compiled["sdar_branches"] == [
+        "branch_0_fun", "branch_1_fun", "branch_2_fun"]
+    assert compiled["sdar_slot_rows"] == []
+    assert compiled["moe_combine_kernels"] == 6
+    assert compiled["moe_combine_kernels_are_the_sums"]
+    assert compiled["moe_combine_other_hits_below_the_last_capacity"] == []
+    # the experts' own kernels are there, and matched nothing
+    assert compiled["sdar_custom_calls"] >= 4 + 9 + 6
