@@ -24,10 +24,12 @@ must not open the chips its workers will need.
 from __future__ import annotations
 
 import dataclasses
+import errno
 import logging
 import os
 import re
 import threading
+import time
 from typing import Dict, Mapping, Optional, Sequence
 
 logger = logging.getLogger(__name__)
@@ -130,6 +132,47 @@ def count_local_chips(dev_root: str = "/dev") -> int:
                    for n in os.listdir(os.path.join(dev_root, "vfio")))
     except OSError:
         return 0
+
+
+def wait_until_chips_free(timeout_s: float = 90.0, dev_root: str = "/dev",
+                          poll_s: float = 0.25) -> None:
+    """Before this process first opens the backend: wait while another
+    process still holds a VFIO group of the chips it may use (its
+    ``TPU_VISIBLE_CHIPS``, else every numbered group), at most
+    ``timeout_s``. A group has one opener at a time; the one before us is
+    as a rule a killed worker whose threads are still letting go of the
+    device, seconds after its main thread reads as gone (on four chips the
+    next process found ``/dev/vfio/<n>`` busy and libtpu gave up at once).
+    Never imports jax, never raises, and returns at once where there is
+    nothing to wait for: a process told to stay on the CPU, no VFIO nodes,
+    a node it may not open, or one it holds itself."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return
+    try:
+        vfio = os.path.join(dev_root, "vfio")
+        groups = sorted((n for n in os.listdir(vfio) if n.isdigit()), key=int)
+        visible = os.environ.get(_VISIBLE_CHIPS)
+        if visible:
+            groups = [groups[int(c)] for c in visible.split(",")]
+        paths = [os.path.join(vfio, n) for n in groups]
+        mine = {os.readlink(f"/proc/self/fd/{fd}")
+                for fd in os.listdir("/proc/self/fd")
+                if os.path.exists(f"/proc/self/fd/{fd}")}
+    except (OSError, ValueError, IndexError):
+        return
+    if mine.intersection(paths):
+        return   # the backend is up: the holder is this process
+
+    def busy(path: str) -> bool:
+        try:
+            os.close(os.open(path, os.O_RDWR))
+        except OSError as e:
+            return e.errno == errno.EBUSY   # any other: not ours to judge
+        return False
+
+    deadline = time.monotonic() + timeout_s
+    while any(busy(p) for p in paths) and time.monotonic() < deadline:
+        time.sleep(poll_s)   # past the deadline libtpu says what is wrong
 
 
 # libtpu reads these when the process first opens the backend. A worker

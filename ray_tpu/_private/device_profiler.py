@@ -31,16 +31,20 @@ tree to that).
 `ray_tpu.shutdown()`: nothing here is reset by it); `delta` subtracts two
 snapshots; `merge` grafts a snapshot taken in another process (rank 0 of a
 gang hands its start-up spans back on a return value the driver waits for
-anyway) under the span that is open on the calling thread.
+anyway, and on `finish()` what it did since its session began: spans and
+counters, never the ring) under the span that is open on the calling thread.
 
 Beside the spans, for a repeated device program (`DeviceStepProfiler`: the
 engine's decode wave) the per-step phase records behind `ray-tpu profile
 --device`, and the process's telemetry:
 
-  install_compile_listener / compile_stats   XLA backend compiles, counted
-      through `jax.monitoring`, with `compile.start` / `compile.end` in the
-      event log so that a recompile storm shows in `ray-tpu debug
-      postmortem`
+  install_compile_listener / compile_stats   what jax reports of its own
+      jit pipeline through `jax.monitoring`, recorded as the spans
+      `jit.trace`, `jit.lower`, `jit.compile`, `jit.cache_load` (self time:
+      less the jit events that lie inside), the programs that reached the
+      backend counted, with `compile.start` / `compile.end` in the event
+      log so that a recompile storm shows in `ray-tpu debug postmortem`;
+      and `host.gc`, one record a full collection of the process
   hbm_stats   `device.memory_stats()` per device, exported as the gauges
       ray_tpu_hbm_bytes_{in_use,peak}{device} when it is asked (by
       `report()`, `snapshot_all()`, `engine.stats()`: never from a service
@@ -51,6 +55,8 @@ engine's decode wave) the per-step phase records behind `ray-tpu profile
 
 from __future__ import annotations
 
+import bisect
+import gc
 import os
 import sys
 import threading
@@ -98,6 +104,9 @@ class _Table(threading.local):
         self.spans: Dict[str, list] = {}   # name -> [count, ns, max, self]
         self.counters: Dict[str, int] = {}
         self.top: Optional[_Span] = None
+        # ended jit events, for `_jit_span`: [end ns], [self ns summed up]
+        self.jit_ends: List[int] = [0]
+        self.jit_owns: List[int] = [0]
         thread = threading.current_thread()
         self.thread = thread.name
         with _span_lock:
@@ -121,10 +130,10 @@ def _annotation_class():
     return cls
 
 
-def _add(table: _Table, name: str, ns: int, own: int) -> None:
-    a = table.spans.get(name)
+def _add(spans: Dict[str, list], name: str, ns: int, own: int) -> None:
+    a = spans.get(name)
     if a is None:
-        table.spans[name] = [1, ns, ns, own]
+        spans[name] = [1, ns, ns, own]
     else:
         a[0] += 1
         a[1] += ns
@@ -172,7 +181,7 @@ class _Span:
             table.top = parent
             if parent is not None:
                 parent._child_ns += ns
-        _add(table, name, ns, max(0, ns - self._child_ns))
+        _add(table.spans, name, ns, max(0, ns - self._child_ns))
         _ring.append((name, self._t0, t1,
                       None if parent is None else parent.name,
                       self.attrs, table.thread))
@@ -196,7 +205,7 @@ def record(name: str, start_ns: int, end_ns: int, ring: bool = True,
     record of its own anyway (the request's queue wait)."""
     table = _local
     ns = max(0, end_ns - start_ns)
-    _add(table, name, ns, ns)
+    _add(table.spans, name, ns, ns)
     if ring:
         _ring.append((name, start_ns, end_ns, None, attrs, table.thread))
 
@@ -218,6 +227,7 @@ def snapshot(recent: int = 0) -> Dict[str, Any]:
         tables = list(_tables)
     for _thread, s, c in tables:
         _fold(s, c, spans, counters)  # copies each table in one C call
+    _fold(_listened, {}, spans, counters)
     out: Dict[str, Any] = {
         "pid": os.getpid(),
         "spans": {name: {"count": n, "total_s": total * 1e-9,
@@ -253,16 +263,23 @@ def delta(after: Dict[str, Any], before: Dict[str, Any]) -> Dict[str, Any]:
     return {"pid": after["pid"], "spans": spans, "counters": counters}
 
 
-def merge(other: Dict[str, Any]) -> None:
+def merge(other: Optional[Dict[str, Any]]) -> None:
     """Graft another process's snapshot (or delta) into this one under the
     same names, as children of the span open on the calling thread: that
     span's self time then leaves out what the other process accounted for
-    (the sum of its self times, which is what its spans cover)."""
+    (the sum of its self times, which is what its spans cover). What came
+    over the wire may be None (the other side failed to build it) or of
+    another shape: then nothing is merged, and nothing raised."""
+    try:
+        spans = {str(name): [int(a["count"]), int(a["total_s"] * 1e9),
+                             int(a["max_s"] * 1e9), int(a["self_s"] * 1e9)]
+                 for name, a in (other.get("spans") or {}).items()}
+        counters = {str(name): n + 0
+                    for name, n in (other.get("counters") or {}).items()}
+    except Exception:  # noqa: BLE001 — not a snapshot: all or nothing
+        return
     table = _local
-    spans = {name: [a["count"], int(a["total_s"] * 1e9),
-                    int(a["max_s"] * 1e9), int(a["self_s"] * 1e9)]
-             for name, a in other.get("spans", {}).items()}
-    _fold(spans, other.get("counters", {}), table.spans, table.counters)
+    _fold(spans, counters, table.spans, table.counters)
     if table.top is not None:
         table.top._child_ns += sum(a[3] for a in spans.values())
 
@@ -284,59 +301,169 @@ _PHASE_BOUNDARIES = [
 
 _compile_lock = threading.Lock()
 _compile_listener_installed = False
+_time_span_listener = False   # this jax hands its events' two ends over
 _compile_seconds = 0.0
 _compile_count = 0
-# jax.monitoring fires this once per XLA backend compilation (cache
-# misses only — cache hits never reach the backend).
-_COMPILE_EVENT_SUFFIX = "backend_compile_duration"
+_cache_loads = 0
+# jax's names for the stages of a jit (jax/_src/dispatch.py), each fired on
+# the calling thread with `fun_name=`. A trace fires once per NESTED trace,
+# inner first. `backend_compile_duration` wraps `compile_or_get_cached`: it
+# fires for every program that reaches it, on a hit of the persistent cache
+# too, and then holds the load, which `cache_retrieval_time_sec` (a
+# duration only) has just reported from inside it.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    _COMPILE_EVENT: "jit.compile",
+    _CACHE_LOAD_EVENT: "jit.cache_load",
+}
+_JIT_KEPT = 4096   # ended jit events a thread remembers, to nest later ones
+
+
+def _jit_span(name: str, start_s: float, end_s: float) -> None:
+    """One stage of a jit in the aggregate, its ends in `time.time()` seconds
+    (not in the ring: a cell's 2.5k-13k nested traces would push everything
+    else out of it). Its self time leaves out the jit events of this thread that lie inside
+    it: they ended before it did, so they are recorded already, and the
+    ones that began after its start are a suffix of the thread's list. So
+    the self times of all `jit.*` add up to the time some jit was open,
+    and the span open around them leaves that out of its own. A negative
+    or non-finite duration records nothing."""
+    if not (end_s >= start_s and end_s - start_s < float("inf")):
+        return
+    t0 = int(start_s * 1e9) - _EPOCH_NS
+    t1 = int(end_s * 1e9) - _EPOCH_NS
+    table = _local
+    ends, owns = table.jit_ends, table.jit_owns   # owns: running sums
+    first = max(1, bisect.bisect_right(ends, t0))
+    own = max(0, t1 - t0 - (owns[-1] - owns[first - 1]))
+    ends.append(t1)
+    owns.append(owns[-1] + own)
+    if len(ends) > 2 * _JIT_KEPT:
+        del ends[:_JIT_KEPT], owns[:_JIT_KEPT]
+    top = table.top
+    if top is not None and top._t0 <= t0:   # a child, by time, of the
+        top._child_ns += own                # span open around the jit
+    _add(table.spans, name, t1 - t0, own)
+
+
+def _on_event_time_span(event: str, start_time: float, end_time: float,
+                        **attrs) -> None:
+    """jax.monitoring listener: trace, lowering and backend compile (or
+    load) of every jit, as jax timed them."""
+    name = _JIT_SPANS.get(event)
+    if name is None:
+        return
+    try:
+        _jit_span(name, start_time, end_time)
+    except Exception:  # noqa: BLE001 — telemetry must never break compiles
+        pass
 
 
 def _on_event_duration(event: str, duration: float, **attrs) -> None:
-    """jax.monitoring listener: accumulate backend compile seconds and
+    """jax.monitoring listener: count the programs that reach the backend
+    (a load from the persistent cache is one too) and their seconds, and
     emit compile.start/compile.end so recompile storms are visible in the
-    postmortem timeline. May fire on any thread — emit() is non-blocking
-    by contract."""
-    if not event.endswith(_COMPILE_EVENT_SUFFIX):
+    postmortem timeline; record what has no time span of its own as one
+    that ends now. Every other event (`compile_time_saved_sec`, which is
+    negative when a load was slower than the compile had been) is ignored.
+    May fire on any thread — emit() is non-blocking by contract."""
+    name = _JIT_SPANS.get(event)
+    if name is None:
         return
-    global _compile_seconds, _compile_count
-    now = time.time()
-    with _compile_lock:
-        _compile_seconds += float(duration)
-        _compile_count += 1
+    global _compile_seconds, _compile_count, _cache_loads
     try:
+        end = time.time()
+        duration = float(duration)
+        if event == _CACHE_LOAD_EVENT or not _time_span_listener:
+            _jit_span(name, end - duration, end)
+        if event == _CACHE_LOAD_EVENT:
+            with _compile_lock:
+                _cache_loads += 1
+        if event != _COMPILE_EVENT:
+            return
+        with _compile_lock:
+            _compile_seconds += duration
+            _compile_count += 1
         from ray_tpu._private.event_log import emit
 
         # The listener fires at compile END; compile.start carries the
         # true wall start in its data (t_start) — its envelope time is
         # necessarily the emit instant, one compile later than reality.
-        emit("compile.start", source=event, t_start=now - float(duration))
-        emit("compile.end", source=event, duration_s=float(duration))
+        emit("compile.start", source=event, t_start=end - duration)
+        emit("compile.end", source=event, duration_s=duration)
     except Exception:  # noqa: BLE001 — telemetry must never break compiles
         pass
 
 
+# No thread's table, `snapshot` adds it: the names the listeners feed, from
+# their installation on (a count of 0 says "listened, saw none": a process
+# whose every program missed the cache loaded for 0 s), and `host.gc`.
+_listened: Dict[str, list] = {}
+_gc_t0 = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` entry: each full collection (generation 2: every
+    container the process holds is walked, a model's pytrees among them) as
+    a `host.gc` record. The younger generations cost microseconds and run
+    all the time: nothing is read or written for them but `info`."""
+    global _gc_t0
+    try:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            _gc_t0 = now()
+        elif _gc_t0:
+            t0, t1, _gc_t0 = _gc_t0, now(), 0
+            # not through `_local`: a collection can start under
+            # `_span_lock`, which a thread's first table takes
+            # and not into the ring: a collection comes when it will, in
+            # the middle of whatever sequence a reader of the ring expects
+            _add(_listened, "host.gc", t1 - t0, t1 - t0)
+    except Exception:  # noqa: BLE001 — telemetry must never break the host
+        pass
+
+
 def install_compile_listener() -> None:
-    """Install the compile-duration listener (idempotent, process-wide).
-    jax.monitoring has no deregistration, so this is once-per-process by
-    design; profilers install it on construction."""
-    global _compile_listener_installed
+    """Install the listeners of jax's jit pipeline and of the process's
+    full collections (idempotent, process-wide). jax.monitoring has no
+    deregistration, so this is once-per-process by design; profilers
+    install it on construction, a gang worker before `train_fn` starts."""
+    global _compile_listener_installed, _time_span_listener
     with _compile_lock:
         if _compile_listener_installed:
             return
         _compile_listener_installed = True
+    heard = ["host.gc"]
+    gc.callbacks.append(_on_gc)
     try:
         import jax.monitoring
 
         jax.monitoring.register_event_duration_secs_listener(
             _on_event_duration)
+        register = getattr(
+            jax.monitoring, "register_event_time_span_listener", None)
+        if register is not None:
+            register(_on_event_time_span)
+            _time_span_listener = True
+        heard += _JIT_SPANS.values()
     except Exception:  # noqa: BLE001 — profiling degrades without jax
         pass
+    for name in heard:
+        _listened.setdefault(name, [0, 0, 0, 0])
 
 
 def compile_stats() -> Dict[str, float]:
-    """Cumulative backend-compile telemetry for this process."""
+    """Cumulative telemetry of this process's backend: `compiles` the
+    programs that reached `compile_or_get_cached` and `compile_s` their
+    seconds there, loads from the persistent cache among them
+    (`cache_loads` of them; the rest were compiled)."""
     with _compile_lock:
-        return {"compiles": _compile_count, "compile_s": _compile_seconds}
+        return {"compiles": _compile_count, "compile_s": _compile_seconds,
+                "cache_loads": _cache_loads}
 
 
 # -- HBM telemetry ----------------------------------------------------------

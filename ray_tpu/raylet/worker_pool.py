@@ -90,6 +90,11 @@ class _ForkedProc:
         return self.returncode
 
 
+# `shutdown()` waits this long, at most, for the killed workers that held
+# chips to be reaped (12-20 s were seen on four chips, under 4 s on one).
+_CHIP_RELEASE_WAIT_S = 60.0
+
+
 @dataclass
 class WorkerHandle:
     worker_id: Optional[WorkerID] = None
@@ -933,6 +938,20 @@ class WorkerPool:
                     except Exception:  # noqa: BLE001 — exited post-timeout
                         logger.debug("kill on shutdown failed",
                                      exc_info=True)
+        # A worker that was started for chips holds them until ALL of its
+        # threads are gone, seconds after the kill on a four-chip host, and
+        # whoever starts next on this host finds /dev/vfio/<n> busy: the
+        # cluster is down only once such a worker is reaped. One deadline
+        # for all of them; workers without chips are not waited for.
+        deadline = time.monotonic() + _CHIP_RELEASE_WAIT_S
+        for handle in handles:
+            if handle.needs_accelerator and handle.proc is not None:
+                try:
+                    handle.proc.wait(
+                        timeout=max(0.05, deadline - time.monotonic()))
+                except Exception:  # noqa: BLE001 — it outlived the wait
+                    logger.warning("worker %s still holds chips %s",
+                                   handle.pid, handle.chip_ids)
         if self._zygote is not None:
             try:
                 self._zygote.stdin.close()  # EOF = clean zygote exit

@@ -19,7 +19,7 @@ from collections import defaultdict
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
-from ray_tpu._private.device_profiler import span
+from ray_tpu._private.device_profiler import merge, span
 from ray_tpu._private.event_watch import EventCursor
 from ray_tpu.train._internal.storage import StorageContext
 from ray_tpu.train._internal.worker_group import WorkerGroup
@@ -223,11 +223,17 @@ class BackendExecutor:
             w.request_stop.remote()
 
     def finish(self) -> None:
+        """End every worker's session. Each hands back what its process
+        timed and counted since the session began; rank 0's is merged into
+        this process's aggregate (under the `train.fit` span open here), as
+        `backend._round` does with the start-up spans: every rank's would
+        multiply each total by the world."""
         if self.worker_group is not None:
             try:
-                ray_tpu.get([
+                left = ray_tpu.get([
                     w.finish.remote() for w in self.worker_group.workers
                 ], timeout=30)
+                merge(left[0])
             except Exception:  # noqa: BLE001 — teardown best-effort
                 pass
 

@@ -16,6 +16,7 @@ import threading
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private import device_profiler
 from ray_tpu.util.placement_group import placement_group, remove_placement_group
 from ray_tpu.util.scheduling_strategies import PlacementGroupSchedulingStrategy
 
@@ -28,6 +29,7 @@ class TrainWorker:
     def __init__(self):
         self._train_thread: Optional[threading.Thread] = None
         self._session = None
+        self._spans_at_session: Optional[dict] = None
 
     def get_metadata(self) -> Dict[str, Any]:
         ctx = ray_tpu.get_runtime_context()
@@ -46,6 +48,9 @@ class TrainWorker:
         self._session = session_mod.init_session(
             TrainContext(**context_kwargs), latest_checkpoint,
             checkpoint_index_start)
+        # what `finish()` hands the driver is what happened since: the
+        # start-up spans before this went back on their rounds' results
+        self._spans_at_session = device_profiler.snapshot()
 
     def run_backend_hook(self, hook: Callable, *args, **kwargs) -> Any:
         return hook(*args, **kwargs)
@@ -107,12 +112,21 @@ class TrainWorker:
         self._session.request_preempt(reason)
         return True
 
-    def finish(self, timeout: float = 30.0) -> None:
+    def finish(self, timeout: float = 30.0) -> Optional[dict]:
+        """End the session; returns what this process timed and counted
+        since `init_session` (`device_profiler.delta`: `spans` and
+        `counters`, plain dicts of numbers, no ring), or None where that
+        could not be had."""
         if self._train_thread is not None:
             self._train_thread.join(timeout)
         from ray_tpu.train._internal import session as session_mod
 
         session_mod.shutdown_session()
+        try:
+            return device_profiler.delta(
+                device_profiler.snapshot(), self._spans_at_session)
+        except Exception:  # noqa: BLE001 — the record is no part of the run
+            return None
 
     def execute(self, fn: Callable, *args, **kwargs) -> Any:
         return fn(*args, **kwargs)

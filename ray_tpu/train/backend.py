@@ -16,7 +16,8 @@ import dataclasses
 import logging
 from typing import Any, Dict, Optional
 
-from ray_tpu._private.device_profiler import delta, merge, snapshot, span
+from ray_tpu._private.device_profiler import (
+    delta, install_compile_listener, merge, snapshot, span)
 
 logger = logging.getLogger(__name__)
 
@@ -108,6 +109,9 @@ def _init_jax_worker(platform: Optional[str], coordinator: Optional[str],
 def _worker_platform() -> str:
     import jax
 
+    from ray_tpu.train.spmd import wait_for_chips
+
+    wait_for_chips()   # returns at once where the backend is up
     # Outside mesh mode this is the first call that brings the backend
     # up (seconds on a chip); after a mesh rendezvous it is microseconds.
     with span("train.worker.open_chip"):
@@ -203,6 +207,16 @@ class JaxBackend(Backend):
                 f"report {platforms}: check JAX_PLATFORMS in the workers' "
                 "environment and that the chips are not held by another "
                 "process")
+
+    def on_training_start(self, worker_group,
+                          backend_config: JaxConfig) -> None:
+        # after `init_session`: the gang's jits and its hosts' full
+        # collections are timed whatever `train_fn` does about it, and are
+        # part of what `finish()` hands back
+        try:
+            worker_group.execute(install_compile_listener)
+        except Exception:  # noqa: BLE001 — the record is no part of the run
+            logger.debug("no jit listener in the gang", exc_info=True)
 
     def on_shutdown(self, worker_group, backend_config: JaxConfig) -> None:
         if backend_config.mesh_config is None:

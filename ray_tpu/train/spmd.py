@@ -32,6 +32,17 @@ _state_lock = threading.Lock()
 _state: Dict[str, Any] = {"mesh": None, "group": None}
 
 
+def wait_for_chips() -> None:
+    """Before the call that opens the backend, and before its span
+    `train.worker.open_chip`: a gang that starts seconds after another
+    process held these chips waits for them here, under a span of its own,
+    instead of failing the run."""
+    from ray_tpu._private.accelerators.tpu import wait_until_chips_free
+
+    with span("train.worker.chip_wait"):
+        wait_until_chips_free()
+
+
 def get_mesh():
     """The gang mesh bootstrapped for this worker (None outside mesh mode).
 
@@ -66,6 +77,7 @@ def setup_worker_mesh(mesh_config, *, group_name: str, world_size: int,
 
         # In a process that has computed nothing yet this is the call that
         # brings the backend up: 8-14 s where that opens a chip.
+        wait_for_chips()
         with span("train.worker.open_chip"):
             devices = jax.devices()
         with span("train.worker.mesh_build", devices=len(devices)):
@@ -75,6 +87,7 @@ def setup_worker_mesh(mesh_config, *, group_name: str, world_size: int,
         # jax.distributed.initialize has to precede the backend, and
         # bootstrap_mesh does both behind its rendezvous: seen from here
         # the backend comes up inside this one call
+        wait_for_chips()
         with span("train.worker.open_chip", world=ws):
             mesh = col.bootstrap_mesh(mesh_config, group_name=group_name,
                                       num_slices=num_slices,

@@ -50,55 +50,55 @@ def init_train_state(
 
     Returns (state, state_shardings) — the latter for use as jit shardings.
     """
-    rules = rules or LogicalAxisRules()
-    p_shardings = param_shardings(param_logical_axes, mesh, rules)
+    with span("train.init_state"):  # its jits: `jit.*` records of their own
+        rules = rules or LogicalAxisRules()
+        p_shardings = param_shardings(param_logical_axes, mesh, rules)
+        params_shape = jax.eval_shape(init_fn, key)
+        # Optimizer state shardings: optax states embed params-shaped
+        # subtrees (mu/nu/trace...); match them STRUCTURALLY — any subtree
+        # with the params' treedef takes the params' shardings wholesale.
+        # (Matching by leaf shape/dtype would silently collide when two params
+        # share a shape but different shardings.) All else is replicated.
+        opt_shape = jax.eval_shape(lambda p: optimizer.init(p), params_shape)
+        replicated = logical_sharding(mesh, (), rules)
+        p_treedef = jax.tree.structure(params_shape)
 
-    params_shape = jax.eval_shape(init_fn, key)
-    # Optimizer state shardings: optax states embed params-shaped subtrees
-    # (mu/nu/trace...); match them STRUCTURALLY — any subtree with the params'
-    # treedef takes the params' shardings wholesale. (Matching by leaf
-    # shape/dtype would silently collide when two params share a shape but
-    # different shardings.) Everything else is replicated.
-    opt_shape = jax.eval_shape(lambda p: optimizer.init(p), params_shape)
-    replicated = logical_sharding(mesh, (), rules)
-    p_treedef = jax.tree.structure(params_shape)
+        def map_opt(node):
+            if jax.tree.structure(node) == p_treedef:
+                return p_shardings
+            one_level = jax.tree_util.default_registry.flatten_one_level(node)
+            if one_level is None:  # leaf
+                return replicated
+            children, _aux = one_level
+            # One-level treedef: every child is a leaf from this vantage point.
+            treedef = jax.tree.structure(node, is_leaf=lambda x: x is not node)
+            return jax.tree.unflatten(treedef, [map_opt(c) for c in children])
 
-    def map_opt(node):
-        if jax.tree.structure(node) == p_treedef:
-            return p_shardings
-        one_level = jax.tree_util.default_registry.flatten_one_level(node)
-        if one_level is None:  # leaf
-            return replicated
-        children, _aux = one_level
-        # One-level treedef: every child is a leaf from this vantage point.
-        treedef = jax.tree.structure(node, is_leaf=lambda x: x is not node)
-        return jax.tree.unflatten(treedef, [map_opt(c) for c in children])
+        o_shardings = map_opt(opt_shape)
+        state_shardings = TrainState(
+            params=p_shardings, opt_state=o_shardings, step=replicated
+        )
 
-    o_shardings = map_opt(opt_shape)
-    state_shardings = TrainState(
-        params=p_shardings, opt_state=o_shardings, step=replicated
-    )
+        def _init(key):
+            params = init_fn(key)
+            return {
+                "params": params,
+                "opt_state": optimizer.init(params),
+                "step": jnp.zeros((), dtype=jnp.int32),
+            }
 
-    def _init(key):
-        params = init_fn(key)
-        return {
-            "params": params,
-            "opt_state": optimizer.init(params),
-            "step": jnp.zeros((), dtype=jnp.int32),
-        }
-
-    init_jit = jax.jit(
-        lambda k: _init(k),
-        out_shardings=_as_dict(state_shardings),
-    )
-    # jit out_shardings wants a matching pytree structure; use dict form.
-    # (Partitionable threefry, the jax default, makes these draws
-    # independent of the output sharding: one seed gives the same params
-    # on every mesh — what 1<->n-device loss parity and cross-mesh
-    # checkpoint resume rest on.)
-    state_dict = init_jit(key)
-    state = TrainState(**state_dict)
-    return state, state_shardings
+        init_jit = jax.jit(
+            lambda k: _init(k),
+            out_shardings=_as_dict(state_shardings),
+        )
+        # jit out_shardings wants a matching pytree structure; use dict form.
+        # (Partitionable threefry, the jax default, makes these draws
+        # independent of the output sharding: one seed gives the same params
+        # on every mesh — what 1<->n-device loss parity and cross-mesh
+        # checkpoint resume rest on.)
+        state_dict = init_jit(key)
+        state = TrainState(**state_dict)
+        return state, state_shardings
 
 
 def make_train_step(

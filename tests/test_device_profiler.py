@@ -394,3 +394,194 @@ def test_snapshot_all_includes_registry_and_compile():
     assert "snap-reg" in snap["profilers"]
     assert "compile_s" in snap["compile"]
     assert isinstance(snap["hbm"], dict)
+
+
+# ------------------------------------- jax's jit events as spans, host.gc
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+
+
+def _jit_spans_of(feed):
+    """The `jit.*` aggregate that `feed()` leaves when run on a thread of
+    its own (a thread nests its jit events by time: a fresh one has none)."""
+    import threading
+
+    before = dp.snapshot()
+    t = threading.Thread(target=feed)
+    t.start()
+    t.join()
+    return {k: v for k, v in dp.delta(dp.snapshot(), before)["spans"].items()
+            if k.startswith("jit.")}
+
+
+def test_nested_trace_events_give_self_times_that_sum_to_the_outer():
+    t = time.time()
+
+    def feed():  # inner first, as jax fires them
+        dp._on_event_time_span(_TRACE, t + 1.0, t + 2.0, fun_name="inner")
+        dp._on_event_time_span(_TRACE, t + 3.5, t + 4.0, fun_name="leaf")
+        dp._on_event_time_span(_TRACE, t + 3.0, t + 5.0, fun_name="inner2")
+        dp._on_event_time_span(_TRACE, t, t + 10.0, fun_name="outer")
+        dp._on_event_time_span(_LOWER, t + 10.0, t + 12.0, fun_name="outer")
+
+    got = _jit_spans_of(feed)
+    trace = got["jit.trace"]
+    assert trace["count"] == 4
+    assert trace["total_s"] == pytest.approx(1.0 + 0.5 + 2.0 + 10.0, abs=1e-3)
+    assert trace["max_s"] == pytest.approx(10.0, abs=1e-3)
+    # 1 + 0.5 + (2 - 0.5) + (10 - 1 - 2): the time some trace was open
+    assert trace["self_s"] == pytest.approx(10.0, abs=1e-3)
+    # a sibling that began after the outer trace ended is no child of it
+    assert got["jit.lower"]["self_s"] == pytest.approx(2.0, abs=1e-3)
+
+
+def test_a_cache_hits_compile_leaves_the_load_out_of_its_self_time():
+    loads = dp.compile_stats()["cache_loads"]
+
+    def feed():
+        t0 = time.time() - 0.050
+        dp._on_event_duration(_SAVED, -0.0019)       # slower than compiling
+        dp._on_event_duration(_CACHE_LOAD, 0.030)    # ends now
+        dp._on_event_time_span(_COMPILE, t0, time.time() + 0.001,
+                               fun_name="jit(f)")
+
+    got = _jit_spans_of(feed)
+    assert set(got) == {"jit.cache_load", "jit.compile"}
+    assert got["jit.cache_load"]["total_s"] == pytest.approx(0.030, abs=1e-4)
+    compile_ = got["jit.compile"]
+    assert compile_["total_s"] >= 0.050
+    assert compile_["self_s"] == pytest.approx(
+        compile_["total_s"] - 0.030, abs=1e-4)
+    assert dp.compile_stats()["cache_loads"] == loads + 1
+
+
+@pytest.mark.parametrize("call", [
+    lambda t: dp._on_event_time_span(_TRACE, t, t - 1.0),          # negative
+    lambda t: dp._on_event_time_span(_TRACE, t, float("nan")),
+    lambda t: dp._on_event_time_span(_TRACE, float("nan"), t),
+    lambda t: dp._on_event_time_span(_TRACE, t, float("inf")),
+    lambda t: dp._on_event_time_span(_TRACE, "then", None),
+    lambda t: dp._on_event_time_span("/jax/some/other_duration", t, t + 1),
+    lambda t: dp._on_event_time_span(_SAVED, t, t + 1.0),
+    lambda t: dp._on_event_duration(_CACHE_LOAD, -0.0019),
+    lambda t: dp._on_event_duration(_CACHE_LOAD, float("nan")),
+    lambda t: dp._on_event_duration(_CACHE_LOAD, "soon"),
+    lambda t: dp._on_event_duration(_SAVED, 3.0),
+    lambda t: dp._on_event_duration("/jax/some/other_sec", 1.0, extra=1),
+], ids=["negative", "nan_end", "nan_start", "inf", "no_numbers",
+        "unknown_event", "saved_as_span", "negative_load", "nan_load",
+        "load_no_number", "saved", "unknown_duration"])
+def test_jit_listeners_record_nothing_and_raise_nothing(call):
+    assert _jit_spans_of(lambda: call(time.time())) == {}
+
+
+def test_jit_listeners_take_any_keyword_and_stay_out_of_the_ring():
+    t = time.time()
+    got = _jit_spans_of(lambda: dp._on_event_time_span(
+        _LOWER, t, t + 0.25, fun_name="f", something_new=7))
+    assert got["jit.lower"]["count"] == 1
+    # thousands of nested traces a model would evict every other record
+    assert all(not r["name"].startswith("jit.")
+               for r in dp.snapshot(recent=dp.RING_RECORDS)["recent"])
+
+
+def test_a_jax_without_time_spans_is_heard_through_its_durations(
+        monkeypatch):
+    """Where `jax.monitoring` hands over durations only, every stage is a
+    record that ends when it is reported; where it hands over both ends,
+    a duration is recorded only for the event that has no time span."""
+    def feed():
+        dp._on_event_duration(_TRACE, 0.5, fun_name="f")
+        dp._on_event_duration(_LOWER, 0.25)
+        dp._on_event_duration(_CACHE_LOAD, 0.125)
+
+    monkeypatch.setattr(dp, "_time_span_listener", True)
+    assert set(_jit_spans_of(feed)) == {"jit.cache_load"}
+    monkeypatch.setattr(dp, "_time_span_listener", False)
+    got = _jit_spans_of(feed)
+    assert {k: round(v["total_s"], 4) for k, v in got.items()} == {
+        "jit.trace": 0.5, "jit.lower": 0.25, "jit.cache_load": 0.125}
+
+
+def test_a_jit_inside_a_span_is_left_out_of_its_self_time():
+    def feed():
+        with span("test.around_jit"):
+            t = time.time()
+            time.sleep(0.02)
+            dp._on_event_time_span(_TRACE, t, time.time(), fun_name="f")
+
+    before = dp.snapshot()
+    _jit_spans_of(feed)
+    got = dp.delta(dp.snapshot(), before)["spans"]
+    around, trace = got["test.around_jit"], got["jit.trace"]
+    assert trace["total_s"] >= 0.02
+    assert around["self_s"] == pytest.approx(
+        around["total_s"] - trace["total_s"], abs=2e-3)
+
+
+def test_host_gc_records_full_collections_only():
+    import gc
+
+    dp.install_compile_listener()
+    assert dp._on_gc in gc.callbacks
+    was = gc.isenabled()
+    gc.disable()   # no collection but the two asked for
+    try:
+        count = lambda: dp.snapshot()["spans"].get(  # noqa: E731
+            "host.gc", {"count": 0})["count"]
+        n = count()
+        gc.collect(0)
+        gc.collect(1)
+        assert count() == n
+        gc.collect(2)
+        assert count() == n + 1
+    finally:
+        if was:
+            gc.enable()
+    assert dp.snapshot()["spans"]["host.gc"]["max_s"] > 0
+    assert all(r["name"] != "host.gc"   # the aggregate only, as `jit.*`
+               for r in dp.snapshot(recent=dp.RING_RECORDS)["recent"])
+    # a callback that is handed nonsense records nothing, raises nothing
+    dp._on_gc("stop", {})
+    dp._on_gc("start", None)
+    assert count() == n + 1
+
+
+@pytest.mark.parametrize("other", [
+    None, {}, {"spans": None, "counters": None}, [], "spans", 7,
+    {"spans": {"x": {"count": 1}}},                  # fields missing
+    {"spans": {"x": {"count": 1, "total_s": "a", "max_s": 0, "self_s": 0}}},
+    {"spans": {"x": 3}, "counters": {"n": 1}},
+    {"spans": {}, "counters": {"n": "many"}},
+], ids=["none", "empty", "nones", "list", "str", "int", "fields_missing",
+        "not_numbers", "not_an_aggregate", "counter_no_number"])
+def test_merge_of_what_is_no_snapshot_is_a_no_op(other):
+    before = dp.snapshot()
+    with span("test.merge_noop"):
+        dp.merge(other)
+    got = dp.delta(dp.snapshot(), before)
+    assert "x" not in got["spans"] and "n" not in got["counters"]
+    noop = got["spans"]["test.merge_noop"]   # nothing grafted under it
+    assert noop["self_s"] == pytest.approx(noop["total_s"], abs=1e-9)
+
+
+def test_a_snapshot_names_what_the_listeners_feed_from_installation_on():
+    """`count` 0 says "listened, saw none" (a process whose every program
+    missed the cache loaded for 0 s; one that never collected paused for
+    0 s); a process that never installed the listeners lacks the names."""
+    import subprocess
+    import sys
+
+    dp.install_compile_listener()
+    spans = dp.snapshot()["spans"]
+    for name in ("jit.trace", "jit.lower", "jit.compile", "jit.cache_load",
+                 "host.gc"):
+        assert spans[name]["count"] >= 0 and spans[name]["max_s"] >= 0.0
+    code = ("from ray_tpu._private import device_profiler as dp\n"
+            "with dp.span('x'): pass\n"
+            "assert set(dp.snapshot()['spans']) == {'x'}")
+    subprocess.run([sys.executable, "-c", code], check=True)

@@ -38,7 +38,9 @@ def test_aggregate_count_total_self_and_parent_under_nesting():
     assert got["t.inner"]["self_s"] == got["t.inner"]["total_s"] >= 0.04
     assert got["t.outer"]["max_s"] >= got["t.outer"]["total_s"] / 2
     assert outer.seconds > inner.seconds >= 0.01
-    recent = dp.snapshot(recent=3)["recent"]
+    # (a background thread of an earlier test's cluster may record between)
+    recent = [r for r in dp.snapshot(recent=64)["recent"]
+              if r["name"].startswith("t.")][-3:]
     assert [(r["name"], r["parent"]) for r in recent] == [
         ("t.inner", "t.outer"), ("t.inner", "t.outer"), ("t.outer", None)]
     assert recent[-1]["attrs"] == {"k": 1}
@@ -269,6 +271,134 @@ def test_fit_leaves_gang_spans_that_cover_the_trainer_start(
     mesh = gang["train.gang.mesh"]
     assert mesh["self_s"] <= mesh["total_s"] - got[
         "train.worker.mesh_build"]["total_s"] + 1e-6
+
+
+def _fit_on_cpu(train_fn, tmp_path, name):
+    from ray_tpu.parallel.mesh import MeshConfig
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    from ray_tpu.train.backend import JaxConfig
+
+    return JaxTrainer(
+        train_fn, train_loop_config={},
+        jax_config=JaxConfig(platform="cpu", mesh_config=MeshConfig(fsdp=1)),
+        scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name=name, storage_path=str(tmp_path)),
+    ).fit()
+
+
+def test_fit_brings_the_gang_workers_record_home(
+        ray_start_regular, tmp_path, monkeypatch):
+    """(These would stand in tests/test_train.py, which is marked slow as a
+    whole: tier-1 would never run them.) What the worker timed and counted
+    after its session began is in the DRIVER's aggregate once `fit()`
+    returns; what it had handed back before is not counted again."""
+    from ray_tpu.train._internal.backend_executor import BackendExecutor
+
+    def _train_fn(config):  # a closure: shipped by value
+        import jax
+        import jax.numpy as jnp
+        import optax
+
+        from ray_tpu import train
+        from ray_tpu._private.device_profiler import count
+
+        opt = optax.sgd(0.1)
+        state, shardings = train.init_train_state(
+            lambda key: {"w": jnp.zeros((4,))}, opt, {"w": (None,)},
+            train.get_mesh(), jax.random.PRNGKey(0))
+        step = train.make_train_step(
+            lambda params, batch: jnp.sum((params["w"] - batch) ** 2),
+            opt, shardings)
+        for _ in range(2):
+            state, m = step(state, jnp.ones((4,)))
+        count("test.bumped_in_train_fn", 3)
+        train.report({"loss": float(m["loss"])})
+
+    at_finish = {}
+    finish = BackendExecutor.finish
+
+    def _finish(self):
+        at_finish.update(dp.snapshot())
+        finish(self)
+
+    monkeypatch.setattr(BackendExecutor, "finish", _finish)
+    before = dp.snapshot()
+    result = _fit_on_cpu(_train_fn, tmp_path, "home")
+    assert result.error is None, result.error
+    assert result.metrics == {"loss": pytest.approx(2.56)}
+    after = dp.snapshot()
+    got = dp.delta(after, before)
+    spans = got["spans"]
+    assert spans["train.step.dispatch"]["count"] == 2
+    assert spans["train.init_state"]["count"] == 1
+    assert spans["train.report"]["count"] == 1
+    # the first call traces, lowers and compiles the step: jax said so
+    for name in ("jit.trace", "jit.lower", "jit.compile"):
+        assert spans[name]["count"] >= 2, name   # the init and the step
+        assert 0 < spans[name]["self_s"] <= spans[name]["total_s"] + 1e-9
+    first = spans["train.step.dispatch"]["max_s"]
+    # ... and the spans open around those jits leave them out of their own
+    # time: what they do not count as theirs is jit self time (an identity
+    # of the accounting, whatever the host's load does to the first call)
+    inside = sum(spans[n]["total_s"] - spans[n]["self_s"]
+                 for n in ("train.step.dispatch", "train.init_state"))
+    assert 0 < inside <= sum(spans[n]["self_s"] for n in spans
+                             if n.startswith("jit.")) + 1e-6
+    assert got["counters"]["test.bumped_in_train_fn"] == 3
+    # none of this was in the driver before `finish()` ...
+    assert "train.step.dispatch" not in dp.delta(at_finish, before)["spans"]
+    # ... and the start-up spans, handed back on their rounds, not twice
+    for name in ("train.worker.open_chip", "train.worker.mesh_build",
+                 "train.worker.chip_wait"):
+        assert after["spans"][name]["count"] == \
+            at_finish["spans"][name]["count"], name
+    # the wait for a predecessor's chips is a span of its own, one a call
+    # that opens the backend, beside `open_chip` and not inside it
+    started = dp.delta(at_finish, before)["spans"]
+    assert started["train.worker.chip_wait"]["count"] == \
+        started["train.worker.open_chip"]["count"] == 2
+    assert started["train.gang.mesh"]["self_s"] <= \
+        started["train.gang.mesh"]["total_s"] - \
+        started["train.worker.chip_wait"]["total_s"] + 1e-6
+    # `train.fit`'s self time is the driver's own
+    fit = spans["train.fit"]
+    assert fit["self_s"] <= fit["total_s"] - first + 1e-6
+    # nothing but names and numbers came over, no ring
+    assert all(r["name"] != "train.step.dispatch"
+               for r in dp.snapshot(recent=dp.RING_RECORDS)["recent"])
+
+
+@pytest.mark.parametrize("how", ["raises", "a_string", "wrong_shapes"])
+def test_fit_is_the_same_when_finish_brings_nothing_home(
+        ray_start_regular, tmp_path, monkeypatch, how):
+    from ray_tpu.train._internal import worker_group
+
+    class _Worker(worker_group.TrainWorker):   # shipped by value
+        def finish(self, timeout: float = 30.0):
+            super().finish(timeout)
+            if how == "raises":
+                raise RuntimeError("no record")
+            return "garbage" if how == "a_string" else {
+                "spans": {"train.step.dispatch": 3}, "counters": [1]}
+
+    monkeypatch.setattr(worker_group, "TrainWorker", _Worker)
+
+    def _train_fn(config):
+        from ray_tpu import train
+
+        train.report({"step": 1})
+        train.report({"step": 2})
+
+    before = dp.snapshot()
+    result = _fit_on_cpu(_train_fn, tmp_path, how)
+    assert result.error is None, result.error
+    assert result.metrics == {"step": 2}
+    assert result.checkpoint is None
+    assert result.path and os.path.isdir(result.path)
+    got = dp.delta(dp.snapshot(), before)
+    assert got["spans"]["train.fit"]["count"] == 1
+    assert "train.step.dispatch" not in got["spans"]
+    assert "train.report" not in got["spans"]
 
 
 @pytest.fixture(scope="module")

@@ -6,6 +6,8 @@ placement itself is TPU-first design — a STRICT_PACK TPU gang maps onto
 one slice (one ICI domain) and never straddles slices.
 """
 
+import time
+
 import pytest
 
 import ray_tpu
@@ -398,3 +400,145 @@ def test_tpu_head_resource_schedules_gang_entry(ray_start_cluster):
     labels = {n["NodeID"]: n["Labels"] for n in ray_tpu.nodes()}
     assert labels[node_id].get(SLICE_NAME_LABEL) == "slice-a"
     assert labels[node_id].get("ray.io/tpu-worker-id") == "0"
+
+
+# ------------------------------------ a chip another process has not let go
+
+def _vfio_root(tmp_path, groups=("0", "1", "2", "3")):
+    (tmp_path / "vfio").mkdir()
+    for name in (*groups, "vfio"):
+        (tmp_path / "vfio" / name).write_bytes(b"")
+    return str(tmp_path)
+
+
+def test_wait_until_chips_free_waits_while_a_group_is_busy(
+        tmp_path, monkeypatch):
+    import errno
+    import os
+    import time
+
+    from ray_tpu._private.accelerators import tpu
+
+    root = _vfio_root(tmp_path)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    opened, real_open = [], os.open
+
+    def _open(path, flags, *a, **kw):
+        if str(path).startswith(root):
+            opened.append(os.path.basename(path))
+            if path.endswith("/2") and opened.count("2") <= 3:
+                raise OSError(errno.EBUSY, "Device or resource busy")
+        return real_open(path, flags, *a, **kw)
+
+    monkeypatch.setattr(os, "open", _open)
+    t0 = time.monotonic()
+    tpu.wait_until_chips_free(dev_root=root, poll_s=0.01)
+    assert opened.count("2") == 4 and 0.03 <= time.monotonic() - t0 < 5.0
+    assert "vfio" not in opened   # the container node is no chip
+    # confined to one chip: only that chip's group is asked
+    del opened[:]
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "1")
+    tpu.wait_until_chips_free(dev_root=root, poll_s=0.01)
+    assert set(opened) == {"1"}
+
+
+@pytest.mark.parametrize("case", ["told_cpu", "no_nodes", "not_allowed",
+                                  "held_by_me", "never_freed"])
+def test_wait_until_chips_free_returns_where_waiting_cannot_help(
+        tmp_path, monkeypatch, case):
+    import errno
+    import os
+    import time
+
+    from ray_tpu._private.accelerators import tpu
+
+    root = _vfio_root(tmp_path) if case != "no_nodes" else str(tmp_path)
+    monkeypatch.setenv("JAX_PLATFORMS",
+                       "cpu" if case == "told_cpu" else "tpu,cpu")
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    real_open, held = os.open, None
+    if case == "held_by_me":
+        held = real_open(os.path.join(root, "vfio", "3"), os.O_RDWR)
+    code = errno.EACCES if case == "not_allowed" else errno.EBUSY
+
+    def _open(path, flags, *a, **kw):
+        if str(path).startswith(root):
+            raise OSError(code, os.strerror(code))
+        return real_open(path, flags, *a, **kw)
+
+    monkeypatch.setattr(os, "open", _open)
+    # (a wait that cannot help would last its whole 2 s: the limit below
+    # leaves a loaded host a second to return "at once")
+    timeout_s = 0.2 if case == "never_freed" else 2.0
+    t0 = time.monotonic()
+    try:
+        tpu.wait_until_chips_free(timeout_s=timeout_s, dev_root=root,
+                                  poll_s=0.01)
+    finally:
+        if held is not None:
+            os.close(held)
+    if case == "never_freed":   # gives up at the timeout, raises nothing
+        assert 0.2 <= time.monotonic() - t0 < 2.0
+    else:
+        assert time.monotonic() - t0 < 1.0
+
+
+class _Proc:
+    """A worker's process as `WorkerPool.shutdown` sees it: gone from
+    `poll()` at once or after the kill, reaped `reap_s` after that."""
+
+    def __init__(self, reap_s: float):
+        self.reap_s, self.calls = reap_s, []
+        self._t0 = time.monotonic()
+
+    def poll(self):
+        return None
+
+    def terminate(self):
+        self.calls.append("terminate")
+
+    def kill(self):
+        self.calls.append("kill")
+
+    def wait(self, timeout=None):
+        self.calls.append("wait")
+        left = self._t0 + self.reap_s - time.monotonic()
+        if left > (timeout or 0):
+            time.sleep(timeout or 0)
+            raise TimeoutError("still there")
+        time.sleep(max(0.0, left))
+        return -9
+
+
+@pytest.mark.parametrize("case", ["reaped_in_time", "outlives_the_wait"])
+def test_pool_shutdown_waits_for_workers_that_held_chips_and_no_longer(
+        monkeypatch, case):
+    """`ray_tpu.shutdown()` returns with the chips free: a worker started
+    for chips is waited for until it is reaped, within ONE bound for all of
+    them; a worker without chips gets the 2 s it always got and a kill."""
+    from ray_tpu.raylet import worker_pool
+
+    monkeypatch.setattr(worker_pool, "_CHIP_RELEASE_WAIT_S", 1.5)
+    slow = 0.3 if case == "reaped_in_time" else 30.0
+    chips = [worker_pool.WorkerHandle(
+        pid=100 + i, proc=_Proc(2.0 + slow), needs_accelerator=True,
+        chip_ids=(i,)) for i in range(2)]
+    plain = worker_pool.WorkerHandle(pid=7, proc=_Proc(30.0))
+    pool = worker_pool.WorkerPool.__new__(worker_pool.WorkerPool)
+    pool._closed, pool._monitor_task, pool._zygote = False, None, None
+    pool._workers = {h.pid: h for h in (*chips, plain)}
+    t0 = time.monotonic()
+    pool.shutdown()
+    took = time.monotonic() - t0
+    # 2 s for everyone's terminate, then the chips' bound, once (the upper
+    # limits leave a loaded host a second: tier-1 runs six files at a time)
+    if case == "reaped_in_time":
+        assert 2.2 <= took < 3.4   # reaped at 2.3 s: before the bound ends
+    else:
+        assert 3.4 <= took < 4.8   # not 1.5 s a worker (5.0), not 30 s
+    for h in chips:
+        assert h.proc.calls[:2] == ["terminate", "wait"]
+        assert h.proc.calls.count("wait") == 2 and "kill" in h.proc.calls
+    # killed after its 2 s, and not waited for again
+    assert plain.proc.calls == ["terminate", "wait", "kill"]

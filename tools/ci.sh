@@ -17,25 +17,21 @@
 #                        training; learner cadence, zero stale batches
 #                        trained, zero lost progress, slot-keyed
 #                        respawn MTTR
-#   6. tracing smoke   — one traced serve request must produce a span
-#                        tree spanning >=6 spans across >=3 processes in
-#                        the GCS span store (trace context on the wire,
-#                        cluster-wide collection, header attribution)
-#   7. dataplane smoke — one >2x-chunk-size jax.Array put/get across a
+#   6. dataplane smoke — one >2x-chunk-size jax.Array put/get across a
 #                        2-node in-process cluster: value integrity, a
 #                        conservative bandwidth floor, and ZERO
 #                        whole-payload copies (serialization.COPY_STATS)
-#   8. memory smoke    — put/transfer/free churn across a 2-node
+#   7. memory smoke    — put/transfer/free churn across a 2-node
 #                        in-process cluster: every node+worker answers
 #                        the memory fan-out, the leak sweep stays at
 #                        ZERO suspects, no object.leak_suspect events,
 #                        arena bytes back to the pre-churn baseline
-#   9. health smoke    — a typed-shed burst on a 2-node cluster must
+#   8. health smoke    — a typed-shed burst on a 2-node cluster must
 #                        fire the production overload_shed_burst SLO
 #                        rule (compressed windows) and RESOLVE after
 #                        the burst, with alert.firing/alert.resolved
 #                        in the cluster event log and a live scorecard
-#  10. tier-1 tests    — the full `not slow` suite
+#   9. tier-1 tests    — the full `not slow` suite
 #
 # Usage: tools/ci.sh [--skip-tests]
 set -euo pipefail
