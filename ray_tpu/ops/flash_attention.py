@@ -157,20 +157,31 @@ def _step_width(own, other, rule):
     return own if rule is not None and other % own == 0 else other
 
 
+# A step's kind, where `masked` stands: False, no mask at all; True, the
+# rule's predicate over the whole tile; DIAGONAL (truthy: a masked step too),
+# the predicate over the tile's aligned `_SUB` x `_SUB` diagonal sub-tiles
+# alone, which hold every score the rule keeps of the tile.
+DIAGONAL = "diagonal"
+_SUB = 128
+
+
 class KernelSchedule(NamedTuple):
     """One kernel's loop plan over one (batch, head): `tiles` are
     (q_start, q_rows, k_start, k_cols, masked), one per loop step, and
     `rows` the same steps by grid row, as (step index, masked); a tile the
     rule keeps nothing of is no step at all (`steps_skipped` counts them).
     `static`: every grid row's steps run as straight-line code, each masked
-    only if it needs it; otherwise ONE loop a grid row over `table`, masked
-    throughout if any step is (`_run_row`)."""
+    only if it needs it, and as `DIAGONAL` (`steps_diagonal` of the
+    `steps_masked`) where the rule keeps nothing off the tile's diagonal
+    sub-tiles; otherwise ONE loop a grid row over `table`, every step
+    masked whole if any step is (`_run_row`)."""
     width: int
     static: bool
     tiles: tuple
     rows: tuple
     steps_unmasked: int
     steps_masked: int
+    steps_diagonal: int
     steps_skipped: int
     executed_over_needed: float
 
@@ -188,19 +199,31 @@ class KernelSchedule(NamedTuple):
         return out
 
 
-# The most steps a grid row may have for its kernel to be unrolled. Measured
-# on the v5e at 512 x 512 (PERF.md §6, PR 26): 4 (S 2048) is the gain this
-# exists for; 8 (S 4096) takes the forward from 5.80 to 4.03 ms (dq gains
-# too) and dk/dv from 8.7 to 29.5 ms, and the code grows with the square
-# of S. So a cap per plan (dq runs the forward's): the block-diffusion call
-# at 2 x 2048 has rows of 5 steps in the forward and dq, of 8 in dk/dv.
-_MAX_STATIC_STEPS = {"fwd": 8, "dkv": 4}
+# What of a plan may be how large for its kernel to be unrolled, measured on
+# the v5e at 512 x 512 (PERF.md §6, PR 26, 34, 38). Forward and dq (dq runs
+# the forward's plan): the LONGEST grid row, 8 steps: 4 (causal S 2048) is
+# the gain this exists for, 8 (S 4096, 36 steps in all) takes the forward
+# from 5.80 to 4.03 ms and dq gains too. dk/dv: the plan's steps IN ALL, 28:
+# its code grows faster, and at the 36 of causal S 4096 it collapsed (8.7 ->
+# 29.5 ms) where the block-diffusion call at 2 x 2048 (24: rows of 1, 1, 1,
+# 1, 8, 6, 4, 2) goes from 7.50 to 5.20 ms, causal S 3072 (21) from 5.21 to
+# 4.31 and S 3584 (28) from 6.83 to 5.65: a row of 8 is not what collapsed.
+_STATIC_BUDGET = {"fwd": (max, 8), "dkv": (sum, 28)}
 
 
 @functools.lru_cache(maxsize=256)
 def _block_schedule(s_q, s_k, block_q, block_k, rule):
     offset = s_k - s_q
     needed = s_q * s_k if rule is None else rule.needed(s_q, s_k)
+
+    def diagonal_only(q0, nq, k0, nk):
+        """Of this (masked) tile the rule keeps nothing off the aligned
+        diagonal sub-tiles: asked of the rule as the tile itself was."""
+        if rule is None or nq != nk or nq % _SUB or nq == _SUB:
+            return False
+        subs = range(0, nq, _SUB)
+        return not any(rule.tile(q0 + offset + a, _SUB, k0 + b, _SUB)[0]
+                       for a in subs for b in subs if a != b)
 
     def plan(kernel, width, n_rows, n_steps, tile, inside):
         """tile(row, step) -> (q0, nq, k0, nk); inside(step): the step lies
@@ -212,20 +235,28 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
                 q0, nq, k0, nk = tile(i, j)
                 some, every = (True, True) if rule is None \
                     else rule.tile(q0 + offset, nq, k0, nk)
-                if some:
-                    steps.append((j, not (every and inside(j))))
+                if not some:
+                    continue
+                masked = not (every and inside(j))
+                if masked and diagonal_only(q0, nq, k0, nk):
+                    masked = DIAGONAL
+                steps.append((j, masked))
             kept.append(steps)
-        static = max(map(len, kept)) <= _MAX_STATIC_STEPS[kernel]
+        measure, budget = _STATIC_BUDGET[kernel]
+        static = measure(map(len, kept)) <= budget
         if not static:
             any_masked = any(m for steps in kept for _, m in steps)
             kept = [[(j, any_masked) for j, _ in steps] for steps in kept]
         by_row = tuple(map(tuple, kept))
         tiles = tuple(tile(i, j) + (masked,)
                       for i, steps in enumerate(by_row) for j, masked in steps)
-        masked = sum(t[4] for t in tiles)
-        executed = sum(t[1] * t[3] for t in tiles)
+        masked = sum(bool(t[4]) for t in tiles)
+        # a diagonal step executes its sub-tiles alone
+        executed = sum(t[1] * (_SUB if t[4] == DIAGONAL else t[3])
+                       for t in tiles)
         return KernelSchedule(
             width, static, tiles, by_row, len(tiles) - masked, masked,
+            sum(t[4] == DIAGONAL for t in tiles),
             n_rows * n_steps - len(tiles),
             executed / needed if needed else float("inf"))
 
@@ -254,6 +285,15 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     inside the sequence -> a step with no mask (no iota, compare or
     `where`), else a masked step. A row's steps need not be one contiguous
     range (a block-diffusion x_t row visits x_0 tiles 0..i, then x_t tile i).
+    A masked square tile of n x n sub-tiles of 128 is asked of the rule
+    once more, sub-tile by sub-tile: where nothing off the n diagonal ones
+    is kept, an unrolled plan runs it as a DIAGONAL step, each 128-row
+    group against its own 128 of the walked axis under the same predicate
+    (1 / n of the tile's matmuls and exponentials; every score left out is
+    one the mask sets to exactly 0). What decides is the rule's answer, not
+    its type: `CAUSAL`'s diagonal tiles keep 10 of their 16 sub-tiles, so
+    no causal plan has such a step, and the in-parts kernels have no body
+    for one (`_run_row` refuses).
 
     `executed_over_needed` is scores executed over scores the rule keeps.
     Starting point (before PR 26): steps of block_q x block_k whatever the
@@ -262,7 +302,9 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     Steps under a rule are now at most square (`_step_width`), so causal
     with an owned block of B rows gives 1 + B / S: 1.25 at 512 and S 2048,
     1.125 at S 4096; block diffusion at length 2,048, block 4 runs 24 of
-    the 64 tiles of 512 x 512 for 4,202,496 kept scores: 1.497.
+    the 64 tiles of 512 x 512 for 4,202,496 kept scores, 1.497 as whole
+    tiles, and its 4 x_t diagonal tiles (2,048 kept scores each) as
+    diagonal steps: 21 tiles' area, 1.310.
     """
     return _block_schedule(s_q, s_k, block_q, block_k, _rule(causal))
 
@@ -274,25 +316,37 @@ def _count_steps(*plans):
                           sum(p.steps_unmasked for p in plans))
     device_profiler.count("flash.steps_masked",
                           sum(p.steps_masked for p in plans))
+    # of the masked ones: run on the tile's diagonal sub-tiles alone
+    device_profiler.count("flash.steps_diagonal",
+                          sum(p.steps_diagonal for p in plans))
     device_profiler.count("flash.tiles_skipped",
                           sum(p.steps_skipped for p in plans))
 
 
-def _run_row(plan, row, steps_ref, body, carry, finish):
+def _run_row(plan, row, steps_ref, body, carry, finish, diagonal=None):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
+    `body(masked)` -> a step (index, carry) -> carry; `diagonal` such a
+    step for the plan's `DIAGONAL` ones.
 
     The trip counts depend on the grid row, and Mosaic schedules nothing
     across the iterations of a loop: the MXU then waits out every step's
     vector work (the forward ran 1.87 ms at S 2048 so, PERF.md §6, PR 26).
     A static plan has one branch a grid row instead, its steps straight-
     line code in which one step's matmuls run under its neighbours'
-    softmax (1.3 ms), and each step masked only if it needs it. Otherwise
-    the row's steps come from `steps_ref` (`KernelSchedule.table`, in SMEM)
-    and run in ONE loop, masked if any step of the plan is: on the v5e the
-    mask costs 2% of the kernel (it is not bound by the vector ALUs) and a
-    second loop 5-8% (PERF.md §6, PR 26)."""
+    softmax (1.3 ms), each step masked only if it needs it, and on its
+    diagonal sub-tiles alone where they hold all the rule keeps of it (a
+    quarter of the work at 512). Otherwise the row's steps come from
+    `steps_ref` (`KernelSchedule.table`, in SMEM) and run in ONE loop, each
+    masked whole if any step of the plan is: on the v5e the mask costs 2%
+    of the kernel (it is not bound by the vector ALUs) and a second loop
+    5-8% (PERF.md §6, PR 26)."""
     from jax.experimental import pallas as pl
 
+    if plan.steps_diagonal and diagonal is None:
+        raise NotImplementedError(
+            "this kernel has no body for a diagonal step, and the plan has "
+            f"{plan.steps_diagonal}: only the whole-q kernels run a rule "
+            "that keeps a tile's diagonal sub-tiles alone")
     if not plan.static:
         step = body(plan.steps_masked > 0)
         finish(jax.lax.fori_loop(
@@ -304,12 +358,47 @@ def _run_row(plan, row, steps_ref, body, carry, finish):
         def run():
             c = carry
             for j, masked in mine:
-                c = body(masked)(j, c)
+                c = (diagonal if masked == DIAGONAL else body(masked))(j, c)
             finish(c)
         return run
 
     for i, mine in enumerate(plan.rows):
         pl.when(row == i)(branch(mine))
+
+
+def _grouped(x):
+    """[n * _SUB, ...] -> [n, _SUB, ...]: the rows of a square tile as the n
+    groups a diagonal step runs, each against its OWN `_SUB` positions of
+    the walked axis (the kernels' matmuls then take the groups as a batch).
+    On the chip the batch beats n sub-steps on slices of the carry: the
+    forward 3.14 ms for 3.97, with no diagonal step 3.39 (PERF.md §6,
+    PR 38)."""
+    return x.reshape(-1, _SUB, *x.shape[1:])
+
+
+def _flat(x):
+    """`_grouped`'s inverse."""
+    return x.reshape(-1, *x.shape[2:])
+
+
+def _walked_pos(start, shape):
+    """int32 `shape`: each score's position along the axis a step walks (the
+    last), for a step that starts at `start`; a diagonal step's [n, _SUB,
+    _SUB]: group g's positions start at `start + g * _SUB`."""
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    if len(shape) == 3:
+        pos = pos + _SUB * jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return pos
+
+
+def _mm(a, b, dim_a, dim_b):
+    """Contract dim `dim_a` of a's last two with `dim_b` of b's, float32
+    out; a leading dim (a diagonal step's groups) is a batch."""
+    lead = a.ndim - 2
+    batch = tuple(range(lead))
+    return jax.lax.dot_general(
+        a, b, (((lead + dim_a,), (lead + dim_b,)), (batch, batch)),
+        preferred_element_type=jnp.float32)
 
 
 def _pallas_call(kernel, plan, in_specs, **kw):
@@ -339,13 +428,13 @@ def _aligned(start, width):
 
 
 def _lane_chunks(x, op):
-    """Combine the 128-lane column chunks of `x` [rows, n * 128] with `op`
-    -> [rows, 128] (narrower `x`: unchanged): elementwise work only; what
-    is left to reduce across lanes is one vreg a row group."""
-    if x.shape[1] % 128:
+    """Combine the 128-lane column chunks of `x` [..., rows, n * 128] with
+    `op` -> [..., rows, 128] (narrower `x`: unchanged): elementwise work
+    only; what is left to reduce across lanes is one vreg a row group."""
+    if x.shape[-1] % 128:
         return x
     return functools.reduce(
-        op, [x[:, c:c + 128] for c in range(0, x.shape[1], 128)])
+        op, [x[..., c:c + 128] for c in range(0, x.shape[-1], 128)])
 
 
 # --------------------------------------------------------------------------
@@ -365,41 +454,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
     q_pos = (qi * block_q + causal_offset
              + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
 
+    def attend(q, q_pos, start, masked, carry):
+        """(o, m, l) of the queries `q` at `q_pos` after the keys [start,
+        start + width); `_grouped` queries: each group after its own
+        `_SUB` of them."""
+        o, m, l = carry
+        k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        if q.ndim == 3:
+            k_blk, v_blk = _grouped(k_blk), _grouped(v_blk)
+        s = _mm(q, k_blk, 1, 1) * scale  # [queries, width]
+        if masked:
+            k_pos = _walked_pos(start, q_pos.shape)
+            # Mask padding rows of a partial final K block (manual
+            # dslice reads clamp, duplicating real rows) and, when
+            # causal, future positions.
+            valid = k_pos < seq_k
+            if mask is not None:
+                valid = valid & mask.keep(q_pos, k_pos)
+            s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+        corr = jnp.exp(m - m_new)
+        # l stays one partial sum a lane: summed across lanes once,
+        # after the loops, not in every step
+        l_new = l * corr + _lane_chunks(p, jnp.add)
+        o_new = o * corr + _mm(p, v_blk, 1, 0)
+        return o_new, m_new, l_new
+
     def step(masked):
-        def body(j, carry):
-            o, m, l = carry
-            start = _aligned(j * width, width)
-            k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-            v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [block_q, width]
-            if masked:
-                k_pos = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, width), 1
-                )
-                # Mask padding rows of a partial final K block (manual
-                # dslice reads clamp, duplicating real rows) and, when
-                # causal, future positions.
-                valid = k_pos < seq_k
-                if mask is not None:
-                    valid = valid & mask.keep(q_pos, k_pos)
-                s = jnp.where(valid, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            if masked:
-                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-            corr = jnp.exp(m - m_new)
-            # l stays one partial sum a lane: summed across lanes once,
-            # after the loops, not in every step
-            l_new = l * corr + _lane_chunks(p, jnp.add)
-            o_new = o * corr + jax.lax.dot_general(
-                p, v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return o_new, m_new, l_new
-        return body
+        return lambda j, carry: attend(
+            q, q_pos, _aligned(j * width, width), masked, carry)
+
+    def diagonal(j, carry):
+        return tuple(map(_flat, attend(
+            _grouped(q), _grouped(q_pos[:, :_SUB]), j * width, True,
+            tuple(map(_grouped, carry)))))
 
     o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
@@ -414,7 +506,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
         o_ref[0, 0] = (o / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m + jnp.log(l)
 
-    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish)
+    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish, diagonal)
 
 
 def _pad_seq(x, block):
@@ -483,42 +575,40 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     q_pos = (qi * block_q + causal_offset
              + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
 
-    def step(masked):
-        def body(j, dq):
-            start = _aligned(j * width, width)
-            k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-            v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            if masked:
-                k_pos = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, width), 1
-                )
-                valid = k_pos < seq_k
-                if mask is not None:
-                    valid = valid & mask.keep(q_pos, k_pos)
-                s = jnp.where(valid, s, NEG_INF)
-            p = jnp.exp(s - lse)
-            if masked:
-                p = jnp.where(valid, p, 0.0)
-            dp = jax.lax.dot_general(
-                do, v_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ds = p * (dp - delta) * scale
-            return dq + jax.lax.dot_general(
-                ds, k_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        return body
+    def attend(q, do, lse, delta, q_pos, start, masked, dq):
+        """dq of the queries `q` plus what the keys [start, start + width)
+        give it; `_grouped` operands: each group's own `_SUB` of them."""
+        k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        if q.ndim == 3:
+            k_blk, v_blk = _grouped(k_blk), _grouped(v_blk)
+        s = _mm(q, k_blk, 1, 1) * scale
+        if masked:
+            k_pos = _walked_pos(start, q_pos.shape)
+            valid = k_pos < seq_k
+            if mask is not None:
+                valid = valid & mask.keep(q_pos, k_pos)
+            s = jnp.where(valid, s, NEG_INF)
+        p = jnp.exp(s - lse)
+        if masked:
+            p = jnp.where(valid, p, 0.0)
+        dp = _mm(do, v_blk, 1, 1)
+        ds = p * (dp - delta) * scale
+        return dq + _mm(ds, k_blk, 1, 0)
 
+    def step(masked):
+        return lambda j, dq: attend(
+            q, do, lse, delta, q_pos, _aligned(j * width, width), masked, dq)
+
+    def diagonal(j, dq):
+        return _flat(attend(
+            *map(_grouped, (q, do, lse, delta, q_pos[:, :_SUB])), j * width,
+            True, _grouped(dq)))
 
     def finish(dq):
         dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
-    _run_row(plan, qi, steps_ref, step, jnp.zeros_like(q), finish)
+    _run_row(plan, qi, steps_ref, step, jnp.zeros_like(q), finish, diagonal)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -539,48 +629,48 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         jnp.int32, (block_k, width), 0)
     causal_offset = seq_k - seq_q
 
-    def step(masked):
-        def body(i, carry):
-            dk, dv = carry
-            start = _aligned(i * width, width)
-            q = q_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-            do = do_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-            lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
-            delta = delta_ref[0, 0, pl.dslice(i, 1), :]
-            s = jax.lax.dot_general(
-                k_blk, q, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [block_k, width]
-            if masked:
-                q_row = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, width), 1
-                )
-                # Mask padding rows of a partial final Q block; when causal,
-                # also mask future keys relative to the offset-shifted query
-                # positions.
-                valid = q_row < seq_q
-                if mask is not None:
-                    valid = valid & mask.keep(q_row + causal_offset, k_pos)
-                s = jnp.where(valid, s, NEG_INF)
-            p = jnp.exp(s - lse)
-            if masked:
-                p = jnp.where(valid, p, 0.0)
-            dv = dv + jax.lax.dot_general(
-                p, do, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dp = jax.lax.dot_general(
-                v_blk, do, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            ds = p * (dp - delta) * scale
-            dk = dk + jax.lax.dot_general(
-                ds, q, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return dk, dv
-        return body
+    def attend(k_blk, v_blk, k_pos, i, masked, carry):
+        """(dk, dv) of the keys `k_blk` at `k_pos` plus what the queries of
+        step i give them; `_grouped` keys: each group's own `_SUB` of
+        them."""
+        dk, dv = carry
+        start = _aligned(i * width, width)
+        q = q_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        do = do_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
+        delta = delta_ref[0, 0, pl.dslice(i, 1), :]
+        if k_blk.ndim == 3:
+            q, do = _grouped(q), _grouped(do)
+            # a group's lanes of the row: [n, 1, _SUB]
+            lse, delta = (jnp.stack(
+                [x[:, a:a + _SUB] for a in range(0, width, _SUB)])
+                for x in (lse, delta))
+        s = _mm(k_blk, q, 1, 1) * scale  # [keys, width]
+        if masked:
+            q_row = _walked_pos(start, k_pos.shape)
+            # Mask padding rows of a partial final Q block; when causal,
+            # also mask future keys relative to the offset-shifted query
+            # positions.
+            valid = q_row < seq_q
+            if mask is not None:
+                valid = valid & mask.keep(q_row + causal_offset, k_pos)
+            s = jnp.where(valid, s, NEG_INF)
+        p = jnp.exp(s - lse)
+        if masked:
+            p = jnp.where(valid, p, 0.0)
+        dv = dv + _mm(p, do, 1, 0)
+        dp = _mm(v_blk, do, 1, 1)
+        ds = p * (dp - delta) * scale
+        dk = dk + _mm(ds, q, 1, 0)
+        return dk, dv
 
+    def step(masked):
+        return lambda i, carry: attend(k_blk, v_blk, k_pos, i, masked, carry)
+
+    def diagonal(i, carry):
+        return tuple(map(_flat, attend(
+            *map(_grouped, (k_blk, v_blk, k_pos[:, :_SUB])), i, True,
+            tuple(map(_grouped, carry)))))
 
     def finish(carry):
         dk, dv = carry
@@ -588,7 +678,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
     _run_row(plan, kj, steps_ref, step,
-             (jnp.zeros_like(k_blk), jnp.zeros_like(v_blk)), finish)
+             (jnp.zeros_like(k_blk), jnp.zeros_like(v_blk)), finish, diagonal)
 
 
 def _bwd_dq_pallas(q, k, v, do, lse, delta, mask, scale, block_q, plan,
@@ -732,7 +822,8 @@ def flash_attention(
     `mask`: a static rule for which (query, key) pairs are kept, in place
     of `causal` (which is the rule `CAUSAL`): e.g. `BlockDiffusion(length,
     block)`. The kernels skip the tiles it keeps nothing of, run those it
-    keeps whole with no mask, and apply its predicate in the others
+    keeps whole with no mask, and apply its predicate in the others, on a
+    tile's diagonal sub-tiles alone where they hold all it keeps
     (`block_schedule`); no dense mask is built on the TPU path.
 
     In parts (latent attention): with `q_rope` [B, S, H, R] and `k_rope`
@@ -823,10 +914,12 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
 # copied to H heads (its BlockSpec ignores the head). They are the three
 # kernels above (same schedule, `_run_row`, masks, float32 arithmetic) with
 # each score the SUM of two contractions, which on the 128-wide MXU are the
-# same two passes as one contraction over D + R (PERF.md §6, PR 32). They
-# stand below, not folded into, the kernels above because a Mosaic payload
-# carries the file locations of its kernel's lines: moving those recompiles
-# every other caller (PERF.md §6, PR 29; §7 for the fold).
+# same two passes as one contraction over D + R (PERF.md §6, PR 32), and
+# with no body for a diagonal step: their one caller is causal, whose plans
+# have none, and `_run_row` refuses a plan that does. They stand below, not
+# folded into, the kernels above because a Mosaic payload carries the file
+# locations of its kernel's lines: moving those recompiles every other
+# caller (PERF.md §6, PR 29; §7 for the fold).
 
 # Names of the forward rule's residuals (`jax.ad_checkpoint.checkpoint_name`):
 # a remat policy that saves them runs no second forward kernel in its
@@ -834,12 +927,6 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
 # to 128 lanes in the TPU's tiled layout, 128x the bytes. Only the in-parts
 # rule names them: the rules above stand as they are.
 RESIDUAL_NAMES = ("flash.o", "flash.lse")
-
-
-def _mm(a, b, dim_a, dim_b):
-    """2-D a, b: contract a's `dim_a` with b's `dim_b`, float32 out."""
-    return jax.lax.dot_general(a, b, (((dim_a,), (dim_b,)), ((), ())),
-                               preferred_element_type=jnp.float32)
 
 
 def _scores(a, a_rope, b, b_rope, scale):

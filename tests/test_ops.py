@@ -7,8 +7,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _MAX_STATIC_STEPS, BlockDiffusion, _clamp_block, block_schedule,
-    flash_attention)
+    _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, BlockDiffusion,
+    _bwd_dkv_parts_pallas, _bwd_dq_parts_pallas, _clamp_block,
+    _flash_fwd_parts_pallas, block_schedule, flash_attention)
 
 
 def _make_qkv(B=1, S=128, H=2, D=64, kv_heads=None, seed=0):
@@ -145,9 +146,22 @@ _SCHEDULES = {
     "s192-on-128": ((192, 192, 128, 128, True), 2.66, 2.66),
     "s100-one-block": ((100, 100, None, None, True), 2.0, 2.0),
     "s128-one-block": ((128, 128, None, None, False), 1.0, 1.0),
-    # block diffusion over [x_t ; x_0]: 24 of the 64 tiles of 512 x 512
+    # block diffusion over [x_t ; x_0]: 24 of the 64 tiles of 512 x 512,
+    # the 4 x_t diagonal ones on their four 128-wide sub-tiles: 21 / 24 of
+    # 1.4971
     "bd-l2048-b4": ((4096, 4096, None, None, BlockDiffusion(2048, 4)),
-                    1.4971, 1.4971),
+                    1.31, 1.31),
+    # a block as wide as the sub-tile, and one wider (no diagonal step)
+    "bd-l2048-b128": ((4096, 4096, None, None, BlockDiffusion(2048, 128)),
+                      1.3, 1.3),
+    "bd-l2048-b256": ((4096, 4096, None, None, BlockDiffusion(2048, 256)),
+                      1.4, 1.4),
+    "bd-l1024-b32-256": ((2048, 2048, 256, 256, BlockDiffusion(1024, 32)),
+                         1.34, 1.34),
+    # x_t ends inside a tile: that tile holds x_0 keys too, so is no
+    # diagonal step (the first x_t tile is the only one)
+    "bd-l640-b4-tile-cuts-the-halves": (
+        (1280, 1280, None, None, BlockDiffusion(640, 4)), 3.98, 3.98),
     "bd-l512-b4-128": ((1024, 1024, 128, 128, BlockDiffusion(512, 4)),
                        1.49, 1.49),
     # a block that does not divide the tile, a length that is no tile multiple
@@ -177,10 +191,24 @@ def test_block_schedule_against_the_mask(case):
         mask = _mask(s_q, s_k, causal, rows, cols)
         painted = np.zeros((rows, cols), dtype=np.int32)
         for q0, nq, k0, nk, masked in plan.tiles:
-            painted[q0:q0 + nq, k0:k0 + nk] += 1
             # the owned block, and a step of the plan's width along the other
             assert (nq, nk) == ((block_q, plan.width) if name == "fwd"
                                 else (plan.width, block_k))
+            sub_tiled = nq == nk and nq % _SUB == 0 and nq > _SUB
+            if masked == DIAGONAL:
+                # runs its aligned diagonal sub-tiles, unrolled only
+                assert plan.static and sub_tiled
+                for a in range(0, nq, _SUB):
+                    painted[q0 + a:q0 + a + _SUB, k0 + a:k0 + a + _SUB] += 1
+            else:
+                painted[q0:q0 + nq, k0:k0 + nk] += 1
+                if masked and plan.static and sub_tiled and causal:
+                    # a masked step that is not diagonal keeps a score off
+                    # its diagonal sub-tiles
+                    off = mask[q0:q0 + nq, k0:k0 + nk].copy()
+                    for a in range(0, nq, _SUB):
+                        off[a:a + _SUB, a:a + _SUB] = False
+                    assert off.any(), (q0, k0)
             if causal:
                 # at most square: the diagonal never cuts a step twice
                 # the size of what it leaves visible
@@ -201,9 +229,10 @@ def test_block_schedule_against_the_mask(case):
                 assert mask[q0:q0 + nq, k0:min(k0 + nk, s_k)].all(), (q0, k0)
         assert painted.max() == 1
         assert (painted[mask] == 1).all()
-        # the cap on unrolling is per plan: dq runs the forward's
-        assert plan.static == (max(len(r) for r in plan.rows)
-                               <= _MAX_STATIC_STEPS[name])
+        # unrolled by the longest row (forward; dq runs its plan) or by the
+        # plan's steps in all (dk/dv)
+        measure, budget = _STATIC_BUDGET[name]
+        assert plan.static == (measure(len(r) for r in plan.rows) <= budget)
         assert plan.steps_skipped == len(plan.rows) * (
             (cols if name == "fwd" else rows) // plan.width) - len(plan.tiles)
         assert [t[4] for t in plan.tiles] == [
@@ -213,10 +242,16 @@ def test_block_schedule_against_the_mask(case):
             for q0, nq, k0, nk, masked in plan.tiles:
                 real = (mask[q0:q0 + nq, k0:k0 + nk] if name == "fwd" else
                         mask[q0:q0 + nq, k0:min(k0 + nk, s_k)])
-                assert masked == (not real[:s_q - q0].all() if name == "fwd"
-                                  else not real.all()), (q0, k0)
+                assert bool(masked) == (
+                    not real[:s_q - q0].all() if name == "fwd"
+                    else not real.all()), (q0, k0)
         assert plan.steps_unmasked == sum(not t[4] for t in plan.tiles)
-        assert plan.steps_masked == sum(t[4] for t in plan.tiles)
+        assert plan.steps_masked == sum(bool(t[4]) for t in plan.tiles)
+        assert plan.steps_diagonal == sum(
+            t[4] == DIAGONAL for t in plan.tiles)
+        if not isinstance(causal, BlockDiffusion):
+            # CAUSAL's diagonal tiles keep 10 of their 16 sub-tiles
+            assert plan.steps_diagonal == 0
         np.testing.assert_allclose(plan.executed_over_needed,
                                    painted.sum() / mask.sum())
         assert plan.executed_over_needed <= bound + 1e-9, name
@@ -238,9 +273,9 @@ def test_block_schedule_starting_point():
 
 # Shapes where a grid row runs several steps, some unmasked and some masked:
 # plans short enough to unroll (`static`: at most 8 steps a row in the
-# forward and dq, 4 in dk/dv, each step masked or not by itself) and longer
-# ones (ONE loop a grid row over the plan's table: masked throughout if any
-# step is).
+# forward and dq, 28 steps in all in dk/dv, each step masked or not by
+# itself) and longer ones (ONE loop a grid row over the plan's table: masked
+# throughout if any step is).
 _MIXED = {
     "s512-128x128": dict(s_q=512, s_k=512, block_q=128, block_k=128),
     "s512-128x256": dict(s_q=512, s_k=512, block_q=128, block_k=256),
@@ -251,9 +286,11 @@ _MIXED = {
                                    block_k=256, causal=False),
     "s448-128x256-noncausal": dict(s_q=448, s_k=448, block_q=128,
                                    block_k=256, causal=False),
-    # 6 steps a row: the forward and dq unrolled, dk/dv in a loop
-    "s768-128x128-loops": dict(s_q=768, s_k=768, block_q=128, block_k=128,
-                               static=("fwd", "dq")),
+    # 6 steps a row, 21 in all: dk/dv unrolled too
+    "s768-128x128": dict(s_q=768, s_k=768, block_q=128, block_k=128),
+    # 8 steps a row, 36 in all: the forward and dq unrolled, dk/dv in a loop
+    "s1024-128x128-loops": dict(s_q=1024, s_k=1024, block_q=128, block_k=128,
+                                static=("fwd", "dq")),
     "s704-128x128-noncausal-loops": dict(s_q=704, s_k=704, block_q=128,
                                          block_k=128, causal=False,
                                          static=("fwd", "dq")),
@@ -298,19 +335,26 @@ def test_flash_attention_interior_and_edge_steps(case):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_flash_attention_counts_its_steps():
+@pytest.mark.parametrize("rule", [True, BlockDiffusion(256, 4)],
+                         ids=["causal", "block-diffusion"])
+def test_flash_attention_counts_its_steps(rule):
     """Building the kernels adds the schedule's step counts to the
-    process's counters (per lowering, not per run)."""
+    process's counters (per lowering, not per run); a diagonal step is a
+    masked one too."""
     from ray_tpu._private import device_profiler
 
     q, k, v = _make_qkv(S=512)
-    how = dict(causal=True, interpret=True, block_q=128, block_k=256)
-    plans = block_schedule(512, 512, 128, 256, True)
+    how = dict(causal=rule, interpret=True, block_q=256, block_k=256)
+    plans = block_schedule(512, 512, 256, 256, rule)
+    # under the rule: the one x_t diagonal tile, in each of three kernels
+    assert sum(p.steps_diagonal for p in plans.values()) == (
+        0 if rule is True else 3)
     before = device_profiler.snapshot()["counters"]
     jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **how)))(q)
     after = device_profiler.snapshot()["counters"]
     for name, field in (("flash.steps_unmasked", "steps_unmasked"),
                         ("flash.steps_masked", "steps_masked"),
+                        ("flash.steps_diagonal", "steps_diagonal"),
                         ("flash.tiles_skipped", "steps_skipped")):
         assert after[name] - before.get(name, 0) == sum(
             getattr(plans[kernel], field) for kernel in ("fwd", "dq", "dkv"))
@@ -335,10 +379,12 @@ def test_block_diffusion_schedule_at_the_cell_shape():
     assert dense.sum() == 4_202_496
     for name, plan in plans.items():
         assert len(plan.tiles) == 24 and plan.steps_skipped == 40
+        # the x_t diagonal tiles run their four 128-wide sub-tiles: 21 / 24
+        # of the 1.497 that 24 whole tiles are
         assert plan.executed_over_needed == pytest.approx(
-            24 * tile * tile / 4_202_496)
+            21 * tile * tile / 4_202_496)
         by_kind = {"x0": 0, "xt_to_x0": 0, "xt_diagonal": 0}
-        for q0, nq, k0, nk, _ in plan.tiles:
+        for q0, nq, k0, nk, masked in plan.tiles:
             assert dense[q0:q0 + nq, k0:k0 + nk].any()
             if q0 >= length:
                 assert k0 >= length            # x_0 never sees x_t
@@ -349,20 +395,59 @@ def test_block_diffusion_schedule_at_the_cell_shape():
                 assert q0 == k0                # x_t sees its own block only
                 assert dense[q0:q0 + nq, k0:k0 + nk].sum() == length
                 by_kind["xt_diagonal"] += 1
+            assert (masked == DIAGONAL) == (k0 < length)
         assert by_kind == {"x0": 10, "xt_to_x0": 10, "xt_diagonal": 4}
+        # all three unrolled, each step masked only if it needs it
+        assert plan.static and plan.steps_diagonal == 4
+        assert (plan.steps_unmasked, plan.steps_masked) == (12, 12)
     fwd, dkv = plans["fwd"], plans["dkv"]
     assert [len(r) for r in fwd.rows] == [2, 3, 4, 5, 1, 2, 3, 4]
     assert [j for j, _ in fwd.rows[2]] == [2, 4, 5, 6]   # own tile, x_0 0..2
-    assert fwd.static and (fwd.steps_unmasked, fwd.steps_masked) == (12, 12)
-    # dk/dv: an x_0 key tile is walked by up to 8 query tiles: a loop
+    assert fwd.rows[2][0] == (2, DIAGONAL)
+    # dk/dv: an x_0 key tile is walked by up to 8 query tiles, an x_t key
+    # tile by its own query tile alone; 24 steps in all, so unrolled
     assert [len(r) for r in dkv.rows] == [1, 1, 1, 1, 8, 6, 4, 2]
-    assert not dkv.static and dkv.steps_unmasked == 0
-    np.testing.assert_array_equal(dkv.table[4], [8, 0, 1, 2, 3, 4, 5, 6, 7])
-    np.testing.assert_array_equal(dkv.table[0, :2], [1, 0])
+    assert dkv.rows[0] == ((0, DIAGONAL),)
+    assert [j for j, _ in dkv.rows[4]] == list(range(8))
+    assert dkv.rows[5] == ((1, True), (2, False), (3, False),
+                           (5, True), (6, False), (7, False))
 
 
-# (length, block, tile): the three kernels under the rule, in the Pallas
-# interpreter, against the dense-mask oracle
+# what dk/dv's budget is for: the plan's steps in all (a head's, at 512 x
+# 512), not its longest row
+_DKV_PLANS = {
+    "causal-s2048": (2048, True, 10, True),
+    "causal-s3072": (3072, True, 21, True),
+    "block-diffusion-l2048": (4096, BlockDiffusion(2048, 4), 24, True),
+    "causal-s3584": (3584, True, 28, True),
+    "causal-s4096": (4096, True, 36, False),     # collapsed unrolled, PR 26
+    "block-diffusion-l3072": (6144, BlockDiffusion(3072, 4), 48, False),
+    "causal-s8192": (8192, True, 136, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DKV_PLANS))
+def test_dkv_is_unrolled_under_a_budget_of_the_plans_total_steps(case):
+    s, rule, steps, static = _DKV_PLANS[case]
+    plans = block_schedule(s, s, *_default_blocks(s, s), rule)
+    dkv = plans["dkv"]
+    assert len(dkv.tiles) == steps
+    assert dkv.static == static == (steps <= _STATIC_BUDGET["dkv"][1])
+    # the longest row says nothing: 8 in a plan that is unrolled and in one
+    # that is not
+    if case in ("block-diffusion-l2048", "causal-s4096"):
+        assert max(map(len, dkv.rows)) == 8
+    if not static:
+        # ONE loop a grid row, masked throughout, on whole tiles
+        assert dkv.steps_unmasked == 0 == dkv.steps_diagonal
+        assert dkv.table[-1, 0] == len(dkv.rows[-1])
+    # forward and dq keep their cap on the longest row
+    assert plans["fwd"].static == (max(map(len, plans["fwd"].rows)) <= 8)
+
+
+# (length, block, tile[, q heads, kv heads, diagonal steps a plan]): the three
+# kernels under the rule, in the Pallas interpreter, against the dense-mask
+# oracle
 _BLOCK_DIFFUSION = {
     "l512-b4-unrolled-and-loops": (512, 4, 128),
     "l192-b3-block-cuts-the-tile": (192, 3, 128),
@@ -370,6 +455,19 @@ _BLOCK_DIFFUSION = {
     "l320-b5-length-no-tile-multiple": (320, 5, 128),
     "l1280-b4-all-loops": (1280, 4, 128),
     "l64-b1": (64, 1, 64),
+    # tiles wider than 128: an x_t diagonal tile whose blocks stay inside
+    # its 128-wide diagonal sub-tiles is a DIAGONAL step, next to x_0's
+    # diagonal tiles (block-causal: masked, and not diagonal-only)
+    "diagonal-b4-tile512": (512, 4, 512, 2, 1, 1),
+    "diagonal-b32-tile256": (512, 32, 256, 2, 1, 2),
+    "diagonal-b128-as-wide-as-the-sub-tile": (512, 128, 256, 2, 1, 2),
+    "diagonal-none-b256-wider-than-the-sub-tile": (512, 256, 512, 2, 1, 0),
+    # tiles that hold both halves, and padding: the first x_t tile, and two
+    # whose second sub-tile row or column is x_0 rows that see none of these
+    # keys, or lies past the end
+    "diagonal-l320-length-no-tile-multiple": (320, 4, 256, 2, 1, 3),
+    "diagonal-gqa-8-to-1": (512, 4, 256, 8, 1, 2),
+    "diagonal-b3-block-cuts-the-sub-tile": (384, 3, 256, 2, 1, 0),
 }
 
 
@@ -379,12 +477,16 @@ def test_flash_attention_under_the_block_diffusion_rule(case):
     mode) under `mask=BlockDiffusion(L, block)` against the oracle, which
     builds the DENSE [2L, 2L] mask; GQA; and the oracle's dense mask is the
     rule's words written out."""
-    length, block, tile = _BLOCK_DIFFUSION[case]
+    length, block, tile, heads, kv_heads, diagonal = (
+        _BLOCK_DIFFUSION[case] + (4, 2, 0))[:6]
     rule, s = BlockDiffusion(length, block), 2 * length
+    for plan in block_schedule(s, s, tile, tile, rule).values():
+        assert plan.steps_diagonal == diagonal
+        assert plan.static or not diagonal
     np.testing.assert_array_equal(
         rule.keep(np.arange(s)[:, None], np.arange(s)[None, :]),
         _dense_block_diffusion(length, block))
-    q, k, v = _make_qkv(S=s, H=4, kv_heads=2, D=32, seed=length)
+    q, k, v = _make_qkv(S=s, H=heads, kv_heads=kv_heads, D=32, seed=length)
 
     def loss(q, k, v, **how):
         out = flash_attention(q, k, v, mask=rule, **how)
@@ -398,12 +500,40 @@ def test_flash_attention_under_the_block_diffusion_rule(case):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
     # the oracle against attention written out with the dense mask
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2)) \
+    rep = heads // kv_heads
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, axis=2)) \
         / 32 ** 0.5
     scores = jnp.where(_dense_block_diffusion(length, block), scores, -jnp.inf)
     want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
-                      jnp.repeat(v, 2, axis=2))
+                      jnp.repeat(v, rep, axis=2))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_in_parts_kernels_refuse_a_diagonal_step(kernel):
+    """The in-parts kernels (latent attention, causal only) have no body for
+    a diagonal step: a plan that holds one is refused when the kernel is
+    built, not run as something else."""
+    rule, s, tile = BlockDiffusion(256, 4), 512, 256
+    plans = block_schedule(s, s, tile, tile, rule)
+    assert plans[kernel].steps_diagonal == 1
+    x = jnp.zeros((1, 2, s, 32), jnp.float32)
+    xr, kr = x[..., :16], x[:, :1, :, :16]
+    col = x[..., :1]
+    with pytest.raises(NotImplementedError, match="diagonal"):
+        if kernel == "fwd":
+            _flash_fwd_parts_pallas(x, xr, x, kr, x, rule, 1.0, tile, tile,
+                                    True)
+        elif kernel == "dq":
+            _bwd_dq_parts_pallas(x, xr, x, kr, x, x, col, col, rule, 1.0,
+                                 tile, plans["dq"], True)
+        else:
+            _bwd_dkv_parts_pallas(x, xr, x, kr, x, x, col, col, rule, 1.0,
+                                  tile, plans["dkv"], True)
+    # under CAUSAL, the rule they run, the same shapes build
+    o, _ = _flash_fwd_parts_pallas(x, xr, x, kr, x, CAUSAL, 1.0, tile, tile,
+                                   True)
+    assert o.shape == x.shape
 
 
 def test_block_diffusion_rule_wants_whole_blocks_over_both_halves():

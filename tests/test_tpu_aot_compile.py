@@ -39,7 +39,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
 from ray_tpu.models import llama, mla_moe, sdar
 from ray_tpu.ops import grouped_matmul, row_sums
-from ray_tpu.ops.flash_attention import BlockDiffusion, flash_attention
+from ray_tpu.ops.flash_attention import (
+    BlockDiffusion, block_schedule, flash_attention)
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import logical_sharding, param_shardings
@@ -112,7 +113,8 @@ out["mla_parts_wide_ops"] = [
     if re.match(r"(ROOT )?%[\w.\-]+ = bf16\[4,32,2048,192\]", ln)
     and not re.search(r" (custom-call|parameter|get-tuple-element)\(", ln)]
 
-for name, shape in (("flash_s4096", (2, 4096, 32, 128)),
+for name, shape in (("flash_s3584", (2, 3584, 32, 128)),
+                    ("flash_s4096", (2, 4096, 32, 128)),
                     ("flash_s8192", (1, 8192, 8, 128))):
     try:
         jax.jit(flash_grads).lower(*[spec(shape, bf16)] * 3).compile()
@@ -218,6 +220,13 @@ lowered = jax.jit(lambda q, k, v: jax.grad(
         spec((4, 4096, 32, 128), bf16), spec((4, 4096, 4, 128), bf16),
         spec((4, 4096, 4, 128), bf16))
 out["flash_bd_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+out["flash_bd_plans"] = {
+    name: [plan.static, plan.steps_unmasked, plan.steps_masked,
+           plan.steps_diagonal]
+    for name, plan in block_schedule(4096, 4096, 512, 512, rule).items()}
+out["flash_causal_dkv_static"] = {
+    str(s): block_schedule(s, s, 512, 512, True)["dkv"].static
+    for s in (2048, 3584, 4096, 8192)}
 dense = re.compile(r"\[(\d+,)*4096,4096\]")
 out["flash_bd_dense"] = [ln.strip()[:160] for ln in
                          lowered.compile().as_text().splitlines()
@@ -309,12 +318,16 @@ def test_paged_decode_compiles_for_v5e(compiled):
     assert compiled["paged_decode"] == "compiled"
 
 
-@pytest.mark.parametrize("seq", ["flash_s4096", "flash_s8192"])
+@pytest.mark.parametrize("seq", ["flash_s3584", "flash_s4096", "flash_s8192"])
 def test_flash_backward_compiles_at_long_sequences(compiled, seq):
     """The limit, pinned (it was "refused at S 8192", for VMEM, until
     PR 26): if this flips back, update the docstring of
-    ops.flash_attention.flash_attention with it."""
+    ops.flash_attention.flash_attention with it. dk/dv is unrolled at
+    S 3,584 (28 steps a head: its budget) and a loop over its table at
+    S 4,096 (36) and S 8,192 (136), as before PR 38."""
     assert compiled[seq] == "compiled", compiled[seq]
+    assert compiled["flash_causal_dkv_static"] == {
+        "2048": True, "3584": True, "4096": False, "8192": False}
 
 
 def test_flash_with_keys_wider_than_values_compiles_for_v5e(compiled):
@@ -387,10 +400,14 @@ def test_moe_layer_backward_as_compiled_for_v5e(compiled):
 
 def test_flash_under_the_block_diffusion_rule_compiles_for_v5e(compiled):
     """train-sdar-1chip's call, q `[4, 4096, 32, 128]` over [x_t ; x_0] with
-    4 kv heads under `BlockDiffusion(2048, 4)`: forward, dq (unrolled) and
-    dk/dv (a loop over its table of steps, in SMEM) compile for the v5e, and
-    no [4096, 4096] score or mask array is in the compiled program."""
-    assert compiled["flash_bd_custom_calls"] >= 3
+    4 kv heads under `BlockDiffusion(2048, 4)`: forward, dq and dk/dv (24
+    steps a head in all: unrolled too since PR 38), each with its four x_t
+    diagonal tiles as diagonal steps, compile for the v5e as the three
+    Pallas calls they were, and no [4096, 4096] score or mask array is in
+    the compiled program."""
+    assert compiled["flash_bd_custom_calls"] == 3
+    assert compiled["flash_bd_plans"] == {
+        name: [True, 12, 12, 4] for name in ("fwd", "dq", "dkv")}
     assert compiled["flash_bd"] == "compiled"
     assert compiled["flash_bd_dense"] == []
 
