@@ -1,0 +1,470 @@
+"""A decoder whose layers are of TWO KINDS in a repeating pattern: KDA
+linear-attention layers and gated latent-attention (MLA) layers, `period`
+layers to a period, over sigmoid-routed experts chosen within groups
+(Ling-3.0-flash's language model by config), TPU-first, training only.
+
+Layer i (published index) mixes with MLA when (i + 1) % `period` == 0 and
+with KDA otherwise; its second sublayer is a dense SwiGLU (`d_ff`) when
+i < `n_dense_layers` and routed + shared experts otherwise. All pre-norm,
+sharing `models/llama.py`'s RMSNorm, RoPE, SwiGLU sublayer, remat policy and
+chunked cross-entropy, and `models/mla_moe.py`'s MLA and expert sublayers.
+With h = RMSNorm(x), per head (H heads, d = `kda_head_dim` 128):
+
+- *KDA* (Kimi Delta Attention, arXiv:2510.26692; `ops/kda.py`): q~, k~, v~ =
+  W_q h, W_k h, W_v h; each channel through a causal depthwise conv over
+  time of `conv_size` 4 taps, then SiLU; q = l2norm(q) / sqrt(d), k =
+  l2norm(k). Decay: a = W_f h + dt_bias (full rank), g = `kda_lower_bound`
+  x sigmoid(exp(A_log_head) a) in (-5, 0) per channel, alpha = exp(g).
+  beta = sigmoid(w_b . h). State S in R^{d x d}, float32, S_0 = 0:
+      S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+      o_t = S_t^T q_t
+  x = x + W_o [RMSNorm_head(o_t) * sigmoid(W_g h)]. No RoPE.
+- *MLA*: `mla_moe._mla_sublayer` with q = W_q h directly (no q latent),
+  RMSNorm per head on q and k a part at a time (the 128 score channels,
+  the 64 rotary ones), RoPE on the rotary channels, pairs (2i, 2i + 1)
+  with `rope_interleave` (the language model's published value), and
+  attn_head * sigmoid(w_gate,head . h) before W_o.
+- *experts*: sigmoid scores s; the choice on s + bias (no gradient) within
+  groups: a group's score is the sum of its two best biased scores, the
+  best `topk_group` of `n_group` groups stay, top-k among their experts;
+  weights the unbiased s of the chosen, normalised, x
+  `routed_scaling_factor`; plus the shared expert (`parallel/moe.route`).
+  A nonzero SwiGLU limit (`expert_swiglu_limits`,
+  `shared_swiglu_limits`, per published layer) in a held layer raises: its
+  form is not published.
+
+`layers` lists the published indices this program holds, in order (all of
+`n_layers_published` by default: the whole model). The expert layers that
+fill whole ALIGNED periods (indices p * period .. p * period + period - 1,
+none of them dense) run as ONE `lax.scan` over the stacked periods whose
+body is an inner scan over the period's stacked KDA layers and then its
+MLA layer: two layer bodies traced, whatever the depth. The others (dense
+layers; expert layers of a period that is not whole here, published 2-5)
+are unrolled. Remat is per layer; the flash call's `o` and `lse` and the
+KDA call's `o` are saved beside what the policy saves.
+
+The share: `mla_moe`'s (`n_experts_held`, `first_expert`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from functools import partial
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu._private import device_profiler
+from ray_tpu.models import llama, mla_moe
+from ray_tpu.models.llama import _residual, _rms_norm
+from ray_tpu.ops import kda as kda_op
+from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUALS
+from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridMoeConfig:
+    """`layers`: the published indices held here (None: all). `d_ff` is the
+    dense layers' width, `d_ff_expert` ONE expert's. The MLA and expert
+    fields are `mla_moe.MlaMoeConfig`'s, whose sublayers read them here."""
+    vocab_size: int = 157_184
+    d_model: int = 2560
+    n_layers_published: int = 42
+    layers: Optional[Tuple[int, ...]] = None
+    n_dense_layers: int = 2        # published layers 0 .. n_dense_layers - 1
+    period: int = 6                # MLA iff (i + 1) % period == 0
+    n_heads: int = 32
+    kda_head_dim: int = 128
+    conv_size: int = 4
+    kda_lower_bound: float = -5.0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    qk_head_norm: bool = True
+    attn_gate: bool = True
+    d_ff: int = 6144
+    d_ff_expert: int = 768
+    n_experts: int = 512
+    n_experts_held: int = 512
+    first_expert: int = 0
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    n_group: int = 8
+    topk_group: int = 4
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    expert_swiglu_limits: Optional[Tuple[float, ...]] = None
+    shared_swiglu_limits: Optional[Tuple[float, ...]] = None
+    rope_theta: float = 6_000_000.0
+    rope_interleave: bool = True
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    remat: bool = True
+    remat_policy: str = "dots"
+    loss_chunk_size: int = 0
+
+    def __post_init__(self):
+        for name in ("layers", "expert_swiglu_limits",
+                     "shared_swiglu_limits"):
+            v = getattr(self, name)
+            if v is not None and not isinstance(v, tuple):
+                object.__setattr__(self, name, tuple(v))
+        held = self.held_layers
+        if list(held) != sorted(set(held)) or not held \
+                or not 0 <= held[0] <= held[-1] < self.n_layers_published:
+            raise ValueError(f"layers {held} of {self.n_layers_published}")
+        if not (0 <= self.first_expert
+                and self.first_expert + self.n_experts_held <= self.n_experts):
+            raise ValueError("held experts outside the router's outputs")
+        if self.kda_lower_bound < -5.0 or self.kda_lower_bound >= 0:
+            raise ValueError("ops/kda.py takes a log decay in [-5, 0) a step")
+        for i in held:
+            if i < self.n_dense_layers and self.is_mla(i):
+                raise NotImplementedError("a dense layer that mixes with MLA")
+            for name in ("expert_swiglu_limits", "shared_swiglu_limits"):
+                limits = getattr(self, name)
+                if limits and i >= self.n_dense_layers and limits[i]:
+                    raise NotImplementedError(
+                        f"{name}[{i}] = {limits[i]}: the clamped SwiGLU's "
+                        "form is not published; hold layers whose limit is 0")
+
+    @staticmethod
+    def tiny(vocab_size: int = 512, **over) -> "HybridMoeConfig":
+        return HybridMoeConfig(**{**dict(
+            vocab_size=vocab_size, d_model=64, n_layers_published=12,
+            n_dense_layers=2, period=3, n_heads=4, kda_head_dim=16,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, d_ff=128, d_ff_expert=32, n_experts=16,
+            n_experts_held=16, experts_per_token=4, n_group=4, topk_group=2),
+            **over})
+
+    @property
+    def held_layers(self) -> Tuple[int, ...]:
+        return self.layers if self.layers is not None \
+            else tuple(range(self.n_layers_published))
+
+    @property
+    def held(self):
+        """`moe_layer`'s `held`: None where every expert is here."""
+        if self.n_experts_held == self.n_experts:
+            return None
+        return self.first_expert, self.n_experts_held
+
+    def is_mla(self, i: int) -> bool:
+        return (i + 1) % self.period == 0
+
+    def plan(self):
+        """-> (dense, loose, periods): the held layers' published indices,
+        split into the dense ones, the expert layers that run unrolled and
+        the first indices of the whole aligned periods, each in order; and
+        the execution order as segments ("dense", n), ("loose", n),
+        ("periods", n) of consecutive layers / periods."""
+        held = self.held_layers
+        dense = [i for i in held if i < self.n_dense_layers]
+        rest = [i for i in held if i >= self.n_dense_layers]
+        have, loose, periods, segments = set(rest), [], [], []
+
+        def add(kind):
+            if segments and segments[-1][0] == kind:
+                segments[-1][1] += 1
+            else:
+                segments.append([kind, 1])
+
+        for _ in dense:
+            add("dense")
+        i = 0
+        while i < len(rest):
+            first = rest[i]
+            if first % self.period == 0 and all(
+                    first + j in have for j in range(self.period)):
+                periods.append(first)
+                add("periods")
+                i += self.period
+            else:
+                loose.append(first)
+                add("loose")
+                i += 1
+        return dense, loose, periods, [tuple(s) for s in segments]
+
+    def num_params(self) -> int:
+        c = self
+        d = c.d_model
+        kda = kda_num_params(c) + 2 * d
+        mla = mla_moe.mla_num_params(c) + 2 * d
+        routed = (d * c.n_experts + c.n_experts + 3 * d * c.d_ff_expert
+                  * (c.n_experts_held + c.n_shared_experts))
+        total = 2 * c.vocab_size * d + d
+        for i in c.held_layers:
+            total += mla if c.is_mla(i) else kda
+            total += 3 * d * c.d_ff if i < c.n_dense_layers else routed
+        return total
+
+
+def kda_num_params(c) -> int:
+    """The KDA mixer's parameters (no layer norm)."""
+    hd = c.n_heads * c.kda_head_dim
+    return (6 * c.d_model * hd + c.d_model * c.n_heads
+            + 3 * c.conv_size * hd + c.n_heads + hd + c.kda_head_dim)
+
+
+# --------------------------------------------------------------------------
+# parameters
+# --------------------------------------------------------------------------
+
+_FFN_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"),
+             "w_down": ("mlp", "embed")}
+
+
+def _kda_axes(L):
+    proj = L + ("embed", "heads", "kv")
+    return {
+        "attn_norm": L + (None,), "wq": proj, "wk": proj, "wv": proj,
+        "conv_q": L + (None, "heads", "kv"), "conv_k": L + (None, "heads", "kv"),
+        "conv_v": L + (None, "heads", "kv"),
+        "w_f": proj, "dt_bias": L + ("heads", "kv"), "a_log": L + ("heads",),
+        "w_b": L + ("embed", "heads"), "w_g": proj, "o_norm": L + (None,),
+        "wo": L + ("heads", "kv", "embed"), "mlp_norm": L + (None,),
+    }
+
+
+def _mixer_axes(L, config, mla: bool):
+    return mla_moe._mla_axes(L, config) if mla else _kda_axes(L)
+
+
+def param_logical_axes(config: HybridMoeConfig) -> Dict[str, Any]:
+    c = config
+    dense, loose, periods, _ = c.plan()
+    L = ("layers",)
+    ffn = {k: L + v for k, v in _FFN_AXES.items()}
+    axes = {"embed": ("vocab", "embed"), "final_norm": (None,),
+            "lm_head": ("embed", "vocab")}
+    if dense:
+        axes["dense"] = {**_kda_axes(L), **ffn}
+    for name, mla in (("kda", False), ("mla", True)):
+        if any(c.is_mla(i) == mla for i in loose):
+            axes.setdefault("loose", {})[name] = {
+                **_mixer_axes(L, c, mla), **mla_moe._routed_axes(L)}
+    if periods:
+        axes["periods"] = {
+            "kda": {**_kda_axes(L + (None,)),
+                    **mla_moe._routed_axes(L + (None,))},
+            "mla": {**mla_moe._mla_axes(L, c), **mla_moe._routed_axes(L)}}
+    return axes
+
+
+def _init_kda(config, key):
+    """One layer's KDA mixer and its two layer norms. Fan-in scaled normal
+    projections; conv taps N(0, 1 / conv_size); `a_log` = log U(1, 16) a
+    head (flash-linear-attention's), `dt_bias` -U(1, 5) a channel: with a
+    unit-RMS input W_f h is ~N(0, 1), so a head's decay a token runs from
+    none (exp(A_log) 16) to ~0.8 (exp(A_log) 1), inside (-5, 0) always."""
+    c = config
+    h, d = c.n_heads, c.kda_head_dim
+    ks = jax.random.split(key, 12)
+    proj = lambda k: mla_moe._dense(c, k, (c.d_model, h, d), c.d_model)  # noqa: E731
+    conv = lambda k: mla_moe._dense(c, k, (c.conv_size, h, d), c.conv_size)  # noqa: E731
+    ones = partial(jnp.ones, dtype=c.dtype)
+    return {
+        "attn_norm": ones((c.d_model,)),
+        "wq": proj(ks[0]), "wk": proj(ks[1]), "wv": proj(ks[2]),
+        "conv_q": conv(ks[3]), "conv_k": conv(ks[4]), "conv_v": conv(ks[5]),
+        "w_f": proj(ks[6]),
+        "dt_bias": -jax.random.uniform(ks[7], (h, d), minval=1.0, maxval=5.0),
+        "a_log": jnp.log(jax.random.uniform(ks[8], (h,), minval=1.0,
+                                            maxval=16.0)),
+        "w_b": mla_moe._dense(c, ks[9], (c.d_model, h), c.d_model),
+        "w_g": proj(ks[10]), "o_norm": ones((d,)),
+        "wo": mla_moe._dense(c, ks[11], (h, d, c.d_model), h * d),
+        "mlp_norm": ones((c.d_model,)),
+    }
+
+
+def init(config: HybridMoeConfig, key) -> Dict[str, Any]:
+    """`mla_moe.init`'s rules (the embedding's rows N(0, 1), the router 0.02
+    normal, its bias float32 N(0, 0.01^2)) and `_init_kda`'s."""
+    c = config
+    dense, loose, periods, _ = c.plan()
+
+    def mixer(key, mla):
+        return mla_moe._init_mla(c, key) if mla else _init_kda(c, key)
+
+    def dense_layer(key):
+        k_mix, *ks = jax.random.split(key, 4)
+        return {**mixer(k_mix, False), **mla_moe._init_ffn(c, ks, (), c.d_ff)}
+
+    def expert_layer(key, mla):
+        k_mix, k_r, k_b, *ks = jax.random.split(key, 9)
+        return {**mixer(k_mix, mla), **mla_moe._init_routed(c, k_r, k_b, ks)}
+
+    def stack(key, n, mla):
+        return jax.vmap(partial(expert_layer, mla=mla))(
+            jax.random.split(key, n))
+
+    k_embed, k_dense, k_loose, k_periods, k_head = jax.random.split(key, 5)
+    params = {
+        "embed": mla_moe._dense(c, k_embed, (c.vocab_size, c.d_model), 1),
+        "final_norm": jnp.ones((c.d_model,), c.dtype),
+        "lm_head": mla_moe._dense(c, k_head, (c.d_model, c.vocab_size),
+                                  c.d_model),
+    }
+    if dense:
+        params["dense"] = jax.vmap(dense_layer)(
+            jax.random.split(k_dense, len(dense)))
+    for j, (name, mla) in enumerate((("kda", False), ("mla", True))):
+        n = sum(c.is_mla(i) == mla for i in loose)
+        if n:
+            params.setdefault("loose", {})[name] = stack(
+                jax.random.fold_in(k_loose, j), n, mla)
+    if periods:
+        k_kda, k_mla = jax.random.split(k_periods)
+        params["periods"] = {
+            "kda": jax.vmap(lambda k: stack(k, c.period - 1, False))(
+                jax.random.split(k_kda, len(periods))),
+            "mla": stack(k_mla, len(periods), True)}
+    return params
+
+
+# --------------------------------------------------------------------------
+# blocks
+# --------------------------------------------------------------------------
+
+def _short_conv(x, taps):
+    """x [B, S, H, D], taps [K, H, D] -> SiLU of the causal depthwise conv
+    over time: y_t = sum_j taps[j] x_{t - (K - 1) + j}, zeros before 0."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0), (0, 0)))
+    y = sum(padded[:, j:j + s].astype(jnp.float32)
+            * taps[j].astype(jnp.float32) for j in range(k))
+    return jax.nn.silu(y)
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _kda_sublayer(x, p, config: HybridMoeConfig, mesh=None,
+                  rules: Optional[LogicalAxisRules] = None):
+    """x [B, S, D] -> x + KDA(RMSNorm(x)) (the module's docstring)."""
+    c = config
+    d = c.kda_head_dim
+    h = _rms_norm(x, p["attn_norm"], c.norm_eps)
+    proj = lambda w: jnp.einsum("bsd,dhk->bshk", h, w)  # noqa: E731
+    heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
+    with jax.named_scope("kda.conv"):
+        q = _l2norm(_short_conv(proj(p["wq"]), p["conv_q"])) * d ** -0.5
+        k = _l2norm(_short_conv(proj(p["wk"]), p["conv_k"]))
+        v = _short_conv(proj(p["wv"]), p["conv_v"])
+    with jax.named_scope("kda.gates"):
+        a = proj(p["w_f"]).astype(jnp.float32) + p["dt_bias"]
+        g = c.kda_lower_bound * jax.nn.sigmoid(
+            jnp.exp(p["a_log"].astype(jnp.float32))[:, None] * a)
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", h, p["w_b"], preferred_element_type=jnp.float32))
+        gate = jax.nn.sigmoid(proj(p["w_g"]).astype(jnp.float32))
+    with jax.named_scope("kda.scan"):
+        o = kda_op.kda(
+            *(heads_first(t.astype(c.dtype)) for t in (q, k, v)),
+            heads_first(g), heads_first(beta))
+    o = _rms_norm(heads_first(o), p["o_norm"], c.norm_eps)
+    o = (o.astype(jnp.float32) * gate).astype(c.dtype)
+    device_profiler.count("kda.layers", 1)  # per lowering
+    x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    return _residual(x, mesh, rules)
+
+
+def _layer(x, p, positions, config, mesh, rules, mla: bool, dense: bool):
+    """One layer -> (x, the chosen experts [B * S, k] or None)."""
+    if mla:
+        x = mla_moe._mla_sublayer(x, p, positions, config, mesh, rules)
+    else:
+        x = _kda_sublayer(x, p, config, mesh, rules)
+    if dense:
+        return llama._mlp_sublayer(x, p, config, mesh, rules), None
+    return mla_moe._expert_sublayer(x, p, config, mesh, rules)
+
+
+def _checkpointed(fn, config):
+    """`mla_moe._checkpointed`, the KDA call's `o` saved too."""
+    return mla_moe._checkpointed(
+        fn, config, FLASH_RESIDUALS + kda_op.RESIDUAL_NAMES)
+
+
+def forward_hidden(params, tokens, config: HybridMoeConfig, mesh=None,
+                   rules: Optional[LogicalAxisRules] = None):
+    """tokens [B, S] -> (final-norm hidden states [B, S, D], the chosen
+    experts of every expert layer [L, B * S, k], in the layers' order)."""
+    c = config
+    b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
+                                    mesh=mesh, rules=rules)
+    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
+    body = lambda mla, dense=False: _checkpointed(partial(  # noqa: E731
+        _layer, positions=positions, config=c, mesh=mesh, rules=rules,
+        mla=mla, dense=dense), c)
+    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)  # noqa: E731
+    _, loose, _, segments = c.plan()
+    kda_layer, mla_layer = body(False), body(True)
+
+    def period(x, p):
+        x, chosen = jax.lax.scan(kda_layer, x, p["kda"])
+        x, last = mla_layer(x, p["mla"])
+        return x, jnp.concatenate([chosen, last[None]])
+
+    chosen = []
+    done = {"dense": 0, "loose": 0, "periods": 0, "kda": 0, "mla": 0}
+    for kind, n in segments:
+        first = done[kind]
+        done[kind] += n
+        if kind == "dense":
+            dense_layer = body(False, dense=True)
+            for i in range(first, first + n):
+                x, _ = dense_layer(x, at(params["dense"], i))
+        elif kind == "loose":
+            for i in loose[first:first + n]:
+                name = "mla" if c.is_mla(i) else "kda"
+                x, e = (mla_layer if c.is_mla(i) else kda_layer)(
+                    x, at(params["loose"][name], done[name]))
+                done[name] += 1
+                chosen.append(e[None])
+            device_profiler.count("pattern.layers_unrolled", n)
+        else:
+            x, e = jax.lax.scan(period, x, jax.tree.map(
+                lambda a: a[first:first + n], params["periods"]))
+            chosen.append(e.reshape((n * c.period,) + e.shape[2:]))
+            device_profiler.count("pattern.periods", n)  # per lowering
+    x = _rms_norm(x, params["final_norm"], c.norm_eps)
+    return x, jnp.concatenate(chosen) if chosen else None
+
+
+def forward(params, tokens, config: HybridMoeConfig, mesh=None,
+            rules: Optional[LogicalAxisRules] = None):
+    """tokens [B, S] -> next-token logits [B, S, V] float32."""
+    x, _ = forward_hidden(params, tokens, config, mesh, rules)
+    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
+
+
+def loss_fn(params, batch, config: HybridMoeConfig, mesh=None,
+            rules: Optional[LogicalAxisRules] = None):
+    """Next-token CE through `llama.chunked_ce`, masked by batch["mask"]
+    when given. Scalar return (make_train_step contract)."""
+    c = config
+    inputs, targets, mask = mla_moe._split(batch)
+    hidden, _ = forward_hidden(params, inputs, c, mesh, rules)
+    return llama.chunked_ce(hidden, params["lm_head"], targets, mask,
+                            chunk=c.loss_chunk_size or inputs.shape[1])
+
+
+@partial(jax.jit, static_argnames=("config",))
+def routing_stats(params, tokens, config: HybridMoeConfig):
+    """tokens [B, S + 1] -> int32 [expert layers]: the LIVE rows of each
+    expert layer, the (token, choice) pairs whose expert is held here.
+    Outside the train step, for tests and chip runs."""
+    c = config
+    _, chosen = forward_hidden(params, tokens[:, :-1], c)
+    local = chosen - c.first_expert
+    return jnp.sum((local >= 0) & (local < c.n_experts_held), axis=(1, 2),
+                   dtype=jnp.int32)

@@ -57,7 +57,7 @@ class MlaMoeConfig:
     n_layers: int = 40
     n_dense_layers: int = 1
     n_heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536        # 0: q = W_q x, no latent (`wq`)
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -73,6 +73,11 @@ class MlaMoeConfig:
     routed_scaling_factor: float = 2.5
     rope_theta: float = 32_000_000.0
     rope_interleave: bool = True
+    # per-head RMSNorm of q and k before RoPE, a part at a time
+    qk_head_norm: bool = False
+    attn_gate: bool = False        # attn_h * sigmoid(w_h . x) before W_o
+    n_group: int = 1               # the choice within groups (`moe.route`)
+    topk_group: int = 1
     norm_eps: float = 1e-6
     mtp_depth: int = 1
     mtp_loss_coef: float = 0.1
@@ -109,13 +114,7 @@ class MlaMoeConfig:
 
     def num_params(self) -> int:
         c = self
-        mla = (c.d_model * c.q_lora_rank + c.q_lora_rank
-               + c.q_lora_rank * c.n_heads
-               * (c.qk_nope_head_dim + c.qk_rope_head_dim)
-               + c.d_model * (c.kv_lora_rank + c.qk_rope_head_dim)
-               + c.kv_lora_rank + c.kv_lora_rank * c.n_heads
-               * (c.qk_nope_head_dim + c.v_head_dim)
-               + c.n_heads * c.v_head_dim * c.d_model + 2 * c.d_model)
+        mla = mla_num_params(c) + 2 * c.d_model
         dense = mla + 3 * c.d_model * c.d_ff
         expert = (mla + c.d_model * c.n_experts + c.n_experts
                   + 3 * c.d_model * c.d_ff_expert
@@ -127,15 +126,34 @@ class MlaMoeConfig:
                 + (c.n_layers - c.n_dense_layers) * expert + mtp)
 
 
+def mla_num_params(c) -> int:
+    """The mixer's parameters (no layer norm) under config `c`."""
+    d_qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+    q = (c.d_model * c.q_lora_rank + c.q_lora_rank
+         + c.q_lora_rank * c.n_heads * d_qk) if c.q_lora_rank \
+        else c.d_model * c.n_heads * d_qk
+    return (q + c.d_model * (c.kv_lora_rank + c.qk_rope_head_dim)
+            + c.kv_lora_rank + c.kv_lora_rank * c.n_heads
+            * (c.qk_nope_head_dim + c.v_head_dim)
+            + c.n_heads * c.v_head_dim * c.d_model
+            + (2 * d_qk if c.qk_head_norm else 0)
+            + (c.d_model * c.n_heads if c.attn_gate else 0))
+
+
 # --------------------------------------------------------------------------
 # parameters
 # --------------------------------------------------------------------------
 
-def _mla_axes(L):
+def _mla_axes(L, config):
+    q = {"wq_a": L + ("embed", None), "q_norm": L + (None,),
+         "wq_b": L + (None, "heads", "kv")} if config.q_lora_rank \
+        else {"wq": L + ("embed", "heads", "kv")}
+    if config.qk_head_norm:
+        q.update(q_head_norm=L + (None,), k_head_norm=L + (None,))
+    if config.attn_gate:
+        q["w_attn_gate"] = L + ("embed", "heads")
     return {
-        "attn_norm": L + (None,),
-        "wq_a": L + ("embed", None), "q_norm": L + (None,),
-        "wq_b": L + (None, "heads", "kv"),
+        "attn_norm": L + (None,), **q,
         "wkv_a": L + ("embed", None), "kv_norm": L + (None,),
         "wkv_b": L + (None, "heads", "kv"),
         "wo": L + ("heads", "kv", "embed"),
@@ -143,33 +161,95 @@ def _mla_axes(L):
     }
 
 
-def _expert_layer_axes(L):
+def _routed_axes(L):
+    """The router, the held experts and the shared expert of a layer."""
     ffn = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"),
            "w_down": ("mlp", "embed")}
     # the held experts' dim is NOT the `ep` axis's: a share has no exchange
     return {
-        **_mla_axes(L),
         "router": L + ("embed", None), "router_bias": L + (None,),
         "experts": {k: L + (None,) + v for k, v in ffn.items()},
         "shared": {k: L + v for k, v in ffn.items()},
     }
 
 
+def _expert_layer_axes(L, config):
+    return {**_mla_axes(L, config), **_routed_axes(L)}
+
+
 def param_logical_axes(config: MlaMoeConfig) -> Dict[str, Any]:
     L = ("layers",)
     axes = {
         "embed": ("vocab", "embed"),
-        "dense": {**_mla_axes(L), "w_gate": L + ("embed", "mlp"),
+        "dense": {**_mla_axes(L, config), "w_gate": L + ("embed", "mlp"),
                   "w_up": L + ("embed", "mlp"), "w_down": L + ("mlp", "embed")},
-        "layers": _expert_layer_axes(L),
+        "layers": _expert_layer_axes(L, config),
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
     if config.mtp_depth:
         axes["mtp"] = {"enorm": (None,), "hnorm": (None,),
                        "eh_proj": (None, "embed"),
-                       "block": _expert_layer_axes(L), "final_norm": (None,)}
+                       "block": _expert_layer_axes(L, config),
+                       "final_norm": (None,)}
     return axes
+
+
+def _dense(config, key, shape, fan_in):
+    return (jax.random.normal(key, shape, dtype=jnp.float32)
+            * (fan_in ** -0.5)).astype(config.dtype)
+
+
+def _init_ffn(config, keys, lead, width):
+    c = config
+    return {"w_gate": _dense(c, keys[0], lead + (c.d_model, width), c.d_model),
+            "w_up": _dense(c, keys[1], lead + (c.d_model, width), c.d_model),
+            "w_down": _dense(c, keys[2], lead + (width, c.d_model), width)}
+
+
+def _init_mla(config, key):
+    """One layer's mixer and its two layer norms."""
+    c = config
+    d_qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+    ones = partial(jnp.ones, dtype=c.dtype)
+    ks = jax.random.split(key, 5)
+    if c.q_lora_rank:
+        q = {"wq_a": _dense(c, ks[0], (c.d_model, c.q_lora_rank), c.d_model),
+             "q_norm": ones((c.q_lora_rank,)),
+             "wq_b": _dense(c, ks[1], (c.q_lora_rank, c.n_heads, d_qk),
+                            c.q_lora_rank)}
+    else:
+        q = {"wq": _dense(c, ks[0], (c.d_model, c.n_heads, d_qk), c.d_model)}
+    if c.qk_head_norm:
+        q.update(q_head_norm=ones((d_qk,)), k_head_norm=ones((d_qk,)))
+    if c.attn_gate:
+        q["w_attn_gate"] = _dense(c, jax.random.fold_in(key, 5),
+                                  (c.d_model, c.n_heads), c.d_model)
+    return {
+        "attn_norm": ones((c.d_model,)), **q,
+        "wkv_a": _dense(c, ks[2], (c.d_model, c.kv_lora_rank
+                                   + c.qk_rope_head_dim), c.d_model),
+        "kv_norm": ones((c.kv_lora_rank,)),
+        "wkv_b": _dense(c, ks[3], (c.kv_lora_rank, c.n_heads,
+                                   c.qk_nope_head_dim + c.v_head_dim),
+                        c.kv_lora_rank),
+        "wo": _dense(c, ks[4], (c.n_heads, c.v_head_dim, c.d_model),
+                     c.n_heads * c.v_head_dim),
+        "mlp_norm": ones((c.d_model,)),
+    }
+
+
+def _init_routed(config, k_r, k_b, ks):
+    """A layer's router, its bias, the held experts and the shared one."""
+    c = config
+    return {
+        "router": (jax.random.normal(k_r, (c.d_model, c.n_experts))
+                   * 0.02).astype(c.dtype),
+        "router_bias": jax.random.normal(k_b, (c.n_experts,)) * 0.01,
+        "experts": _init_ffn(c, ks[:3], (c.n_experts_held,), c.d_ff_expert),
+        "shared": _init_ffn(c, ks[3:], (),
+                            c.n_shared_experts * c.d_ff_expert),
+    }
 
 
 def init(config: MlaMoeConfig, key) -> Dict[str, Any]:
@@ -187,51 +267,16 @@ def init(config: MlaMoeConfig, key) -> Dict[str, Any]:
     Rows that stand out of the stream keep the tokens apart, so the loads
     are near even at any seed, as a deployment's balancing keeps them."""
     c = config
-    d_qk = c.qk_nope_head_dim + c.qk_rope_head_dim
     ones = partial(jnp.ones, dtype=c.dtype)
-
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, dtype=jnp.float32)
-                * (fan_in ** -0.5)).astype(c.dtype)
-
-    def ffn(keys, lead, width):
-        return {"w_gate": dense(keys[0], lead + (c.d_model, width), c.d_model),
-                "w_up": dense(keys[1], lead + (c.d_model, width), c.d_model),
-                "w_down": dense(keys[2], lead + (width, c.d_model), width)}
-
-    def mla(key):
-        ks = jax.random.split(key, 5)
-        return {
-            "attn_norm": ones((c.d_model,)),
-            "wq_a": dense(ks[0], (c.d_model, c.q_lora_rank), c.d_model),
-            "q_norm": ones((c.q_lora_rank,)),
-            "wq_b": dense(ks[1], (c.q_lora_rank, c.n_heads, d_qk),
-                          c.q_lora_rank),
-            "wkv_a": dense(ks[2], (c.d_model, c.kv_lora_rank
-                                   + c.qk_rope_head_dim), c.d_model),
-            "kv_norm": ones((c.kv_lora_rank,)),
-            "wkv_b": dense(ks[3], (c.kv_lora_rank, c.n_heads,
-                                   c.qk_nope_head_dim + c.v_head_dim),
-                           c.kv_lora_rank),
-            "wo": dense(ks[4], (c.n_heads, c.v_head_dim, c.d_model),
-                        c.n_heads * c.v_head_dim),
-            "mlp_norm": ones((c.d_model,)),
-        }
+    dense = partial(_dense, c)
 
     def dense_layer(key):
         k_attn, *ks = jax.random.split(key, 4)
-        return {**mla(k_attn), **ffn(ks, (), c.d_ff)}
+        return {**_init_mla(c, k_attn), **_init_ffn(c, ks, (), c.d_ff)}
 
     def expert_layer(key):
         k_attn, k_r, k_b, *ks = jax.random.split(key, 9)
-        return {
-            **mla(k_attn),
-            "router": (jax.random.normal(k_r, (c.d_model, c.n_experts))
-                       * 0.02).astype(c.dtype),
-            "router_bias": jax.random.normal(k_b, (c.n_experts,)) * 0.01,
-            "experts": ffn(ks[:3], (c.n_experts_held,), c.d_ff_expert),
-            "shared": ffn(ks[3:], (), c.n_shared_experts * c.d_ff_expert),
-        }
+        return {**_init_mla(c, k_attn), **_init_routed(c, k_r, k_b, ks)}
 
     k_embed, k_dense, k_layers, k_head, k_mtp = jax.random.split(key, 5)
     params = {
@@ -301,21 +346,45 @@ def _mla_sublayer(x, p, positions, config: MlaMoeConfig, mesh=None,
     h = _rms_norm(x, p["attn_norm"], c.norm_eps)
     with jax.named_scope("mla.latents"):
         up = partial(jnp.einsum, "bsr,rhk->bshk")
-        c_q = _rms_norm(h @ p["wq_a"], p["q_norm"], c.norm_eps)
-        q = up(c_q, p["wq_b"][..., :n_nope])
-        q_rope = _rope(up(c_q, _interleaved(p["wq_b"][..., n_nope:], c)),
-                       positions, c.rope_theta)
+        if c.q_lora_rank:
+            c_q = _rms_norm(h @ p["wq_a"], p["q_norm"], c.norm_eps)
+            w_q = p["wq_b"]
+        else:  # no q latent: the projection straight from the layer input
+            c_q, w_q = h, p["wq"]
+
+        def head_norm(x, name, rotary):
+            # the per-head norm, a part at a time (so the ONE rotary key
+            # stays every head's); the rotary channels' scales in the
+            # order `_interleaved` leaves the channels in
+            if not c.qk_head_norm:
+                return x
+            scale = _interleaved(p[name][n_nope:], c) if rotary \
+                else p[name][:n_nope]
+            return _rms_norm(x, scale, c.norm_eps)
+
+        turned = lambda x, name: _rope(  # noqa: E731
+            head_norm(x, name, True), positions, c.rope_theta)
+        q = head_norm(up(c_q, w_q[..., :n_nope]), "q_head_norm", False)
+        q_rope = turned(up(c_q, _interleaved(w_q[..., n_nope:], c)),
+                        "q_head_norm")
         kv_a = h @ jnp.concatenate(
             [p["wkv_a"][:, :n_lat], _interleaved(p["wkv_a"][:, n_lat:], c)],
             axis=-1)
         c_kv = _rms_norm(kv_a[..., :n_lat], p["kv_norm"], c.norm_eps)
-        k = up(c_kv, p["wkv_b"][..., :n_nope])
+        k = head_norm(up(c_kv, p["wkv_b"][..., :n_nope]), "k_head_norm",
+                      False)
         v = up(c_kv, p["wkv_b"][..., n_nope:])
         # one rotary key, the same for every head
-        k_rope = _rope(kv_a[..., None, n_lat:], positions, c.rope_theta)
+        k_rope = turned(kv_a[..., None, n_lat:], "k_head_norm")
     with jax.named_scope("mla.attend"):
         # scores over n_nope + n_rope channels, scaled by their root
         attn = _attention(q, k, v, q_rope, k_rope, mesh)
+    if c.attn_gate:
+        with jax.named_scope("mla.gate"):
+            attn = attn * jax.nn.sigmoid(jnp.einsum(
+                "bsd,dh->bsh", h, p["w_attn_gate"],
+                preferred_element_type=jnp.float32))[..., None].astype(
+                    attn.dtype)
     device_profiler.count("mla.layers", 1)  # per lowering
     device_profiler.count("mla.attend_parts", 1)
     x = x + jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
@@ -336,7 +405,8 @@ def _expert_sublayer(x, p, config: MlaMoeConfig, mesh=None,
     routed, aux = moe_layer(
         h.reshape(b * s, d), p["router"], p["experts"], c.experts_per_token,
         c.norm_topk_prob, score="sigmoid", router_bias=p["router_bias"],
-        weight_scale=c.routed_scaling_factor, held=c.held)
+        weight_scale=c.routed_scaling_factor, held=c.held,
+        n_group=c.n_group, topk_group=c.topk_group)
     with jax.named_scope("moe.shared"):
         sh = p["shared"]
         shared = (jax.nn.silu(h @ sh["w_gate"]) * (h @ sh["w_up"])) \
@@ -355,18 +425,18 @@ def _dense_layer(x, p, positions, config, mesh, rules):
     return llama._mlp_sublayer(x, p, config, mesh, rules)
 
 
-def _checkpointed(fn, config):
+def _checkpointed(fn, config, names=RESIDUAL_NAMES):
     """`fn` under `config.remat_policy` (llama's names) and, whatever that
-    saves, the flash call's own residuals beside it (its output and lse,
-    named by `ops/flash_attention.py`: 65 MiB a layer at B 4 x S 2048), so
-    the backward pass runs no second forward kernel. "full" saves nothing."""
+    saves, the kernels' own residuals `names` beside it (the flash call's
+    output and lse, named by `ops/flash_attention.py`: 65 MiB a layer at
+    B 4 x S 2048), so the backward pass runs no second forward kernel.
+    "full" saves nothing."""
     if not config.remat:
         return fn
     policy = _remat_policy(config)
     if policy is not None:
         policy = jax.checkpoint_policies.save_from_both_policies(
-            policy,
-            jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
+            policy, jax.checkpoint_policies.save_only_these_names(*names))
     return jax.checkpoint(fn, policy=policy)
 
 

@@ -51,8 +51,31 @@ class Routing(NamedTuple):
     experts: jax.Array  # [T, k] int32 the chosen experts, best first
 
 
+def _within_groups(biased, n_group: int, topk_group: int):
+    """biased [T, E] -> the same with -inf outside the `topk_group` best of
+    `n_group` contiguous groups of experts; a group's score is the sum of
+    its two best biased scores (DeepSeek-V3's `noaux_tc` form)."""
+    t, e = biased.shape
+    with jax.named_scope("moe.route_groups"):
+        grouped = biased.reshape(t, n_group, e // n_group)
+        # the two best as two max passes: `top_k` of [8192, 8, 64] is a
+        # full sort on the TPU, 2.5 ms a call on the v5e (PERF.md section
+        # 6, PR 39)
+        best = jnp.max(grouped, axis=-1)
+        first = jnp.argmax(grouped, axis=-1)
+        second = jnp.max(jnp.where(
+            first[..., None] == jnp.arange(e // n_group), -jnp.inf, grouped),
+            axis=-1)
+        _, kept = jax.lax.top_k(best + second, topk_group)
+        keep = jnp.any(kept[..., None] == jnp.arange(n_group), axis=1)
+        device_profiler.count("moe.groups_kept", topk_group)  # per lowering
+        return jnp.where(jnp.repeat(keep, e // n_group, axis=1), biased,
+                         -jnp.inf)
+
+
 def route(x, router_w, k: int, norm_topk_prob: bool = False, *,
-          score: str = "softmax", bias=None, scale: float = 1.0) -> Routing:
+          score: str = "softmax", bias=None, scale: float = 1.0,
+          n_group: int = 1, topk_group: int = 1) -> Routing:
     """x [T, D], router_w [D, E] -> the top-k choice per token, over ALL E
     experts whether or not they are held here. Logits, scores and weights
     are float32 whatever the model dtype: a bf16 logit would flip choices
@@ -64,19 +87,31 @@ def route(x, router_w, k: int, norm_topk_prob: bool = False, *,
     gets no gradient and the weights are the unbiased scores of the chosen.
     `norm_topk_prob` divides the k weights by their sum (over all k chosen,
     held or not; Mixtral and DeepSeek-V3; OLMoE leaves them as they are);
-    `scale` then multiplies them (`routed_scaling_factor`)."""
+    `scale` then multiplies them (`routed_scaling_factor`). With `n_group`
+    > 1 the choice is made WITHIN GROUPS: the experts are `n_group`
+    contiguous groups, the `topk_group` best groups stay (`_within_groups`,
+    on the biased scores) and the top-k is taken among their experts."""
     if score not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown scoring function {score!r}")
+    if n_group > 1 and (router_w.shape[1] % n_group
+                        or not 0 < topk_group <= n_group
+                        or k > topk_group * (router_w.shape[1] // n_group)):
+        raise ValueError(f"{router_w.shape[1]} experts in {n_group} groups, "
+                         f"{topk_group} kept, top-{k}")
     with jax.named_scope("moe.route"):
         logits = jnp.dot(x, router_w, precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
             else jax.nn.sigmoid(logits)
-        if bias is None:
+        if bias is None and n_group == 1:
             weights, experts = jax.lax.top_k(probs, k)
         else:
-            _, experts = jax.lax.top_k(
-                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+            biased = probs if bias is None else \
+                probs + jax.lax.stop_gradient(bias.astype(jnp.float32))
+            if n_group > 1:
+                biased = _within_groups(jax.lax.stop_gradient(biased),
+                                        n_group, topk_group)
+            _, experts = jax.lax.top_k(biased, k)
             # the chosen experts' own scores, as a masked sum over the
             # experts: a gather of T x k scalars out of [T, E] and the
             # scatter that transposes it take 0.67 and 0.57 ms on the v5e
@@ -365,13 +400,15 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
               score: str = "softmax", router_bias=None,
-              weight_scale: float = 1.0, held=None):
+              weight_scale: float = 1.0, held=None, n_group: int = 1,
+              topk_group: int = 1):
     """Dropless top-k SwiGLU experts. x [T, D]; router_w [D, E]; `experts`
     holds w_gate, w_up [E', D, F] and w_down [E', F, D]. -> (y [T, D] in
     x.dtype, MoEAux): y_t = sum_j w_tj * down_e(silu(gate_e x_t) * up_e x_t)
     over token t's k experts e, accumulated in float32. The choice and the
     weights w_tj are `route`'s (`score`: softmax or sigmoid scores,
-    `router_bias`, `norm_topk_prob`, `weight_scale`), over all E experts.
+    `router_bias`, `norm_topk_prob`, `weight_scale`, `n_group` and
+    `topk_group`), over all E experts.
 
     The share: `held=None` means every expert is here (E' = E). With
     `held=(first_expert, n_held)` the E' = n_held experts `first_expert ..`
@@ -397,7 +434,8 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
     t = x.shape[0]
     e = router_w.shape[1]
     routing = route(x, router_w, k, norm_topk_prob, score=score,
-                    bias=router_bias, scale=weight_scale)
+                    bias=router_bias, scale=weight_scale, n_group=n_group,
+                    topk_group=topk_group)
     if held is not None:
         first, n_held = held
         if not (0 <= first and first + n_held <= e) \

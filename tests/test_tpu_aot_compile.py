@@ -279,6 +279,20 @@ out["moe_combine_other_hits_below_the_last_capacity"] = [
     ln[:160] for ln in hits if "tpu_custom_call" not in ln
     and re.search(r"branch_0_fun", ln)]
 
+# the chunked delta rule (train-ling-1chip): the Pallas forward and the two
+# backward kernels at [4, 32, 2048, 128], and their events as the trace
+# will name them
+from ray_tpu.ops import kda as kda_op
+kda_args = (spec((4, 32, 2048, 128), bf16),) * 3 + (
+    spec((4, 32, 2048, 128), jnp.float32), spec((4, 32, 2048), jnp.float32))
+hlo = jax.jit(jax.value_and_grad(  # the value: or the forward is dead code
+    lambda *a: kda_op.kda(*a, use_pallas=True).astype(jnp.float32).sum(),
+    argnums=(0, 1, 2, 3, 4))).lower(*kda_args).compile().as_text()
+out["kda_calls"] = [
+    re.sub(r"custom-call\(.*", 'custom-call(%a), custom_call_target='
+           '"tpu_custom_call"', ln.strip())
+    for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
 eng = PagedInferenceEngine(params, cfg, max_batch=8, max_len=1024,
@@ -312,6 +326,29 @@ def test_flash_fwd_bwd_compiles_for_v5e(compiled):
     # forward, dq and dk/dv kernels
     assert compiled["flash_custom_calls"] >= 3
     assert compiled["flash_s2048"] == "compiled"
+
+
+def test_delta_rule_kernels_compile_for_v5e_under_their_own_signatures(
+        compiled):
+    """`ops/kda.py` at train-ling-1chip's shape: THREE Pallas calls (the
+    forward, the backward pass's walk forwards and its walk backwards),
+    each taken by the cell's KDA queries that are for it and by no flash
+    or grouped-matmul query."""
+    calls = compiled["kda_calls"]
+    assert len(calls) == 3, calls
+    query = lambda name: re.compile(json.load(open(os.path.join(  # noqa: E731
+        REPO_ROOT, "benchmarks", "metrics", name + ".json")))[
+            "trace_query"]["op"])
+    took = lambda name: [bool(query(name).search(c)) for c in calls]  # noqa: E731
+    assert sorted(took("kda_fwd_roofline")) == [False, False, True]
+    assert sorted(took("kda_bwd_roofline")) == [False, True, True]
+    assert [a or b for a, b in zip(took("kda_fwd_roofline"),
+                                   took("kda_bwd_roofline"))] == [True] * 3
+    assert took("kda_time_share") == [True] * 3
+    for other in ("mla_flash_fwd_roofline", "mla_flash_bwd_roofline",
+                  "bd_attention_time_share", "flash_fwd_roofline",
+                  "flash_bwd_roofline", "moe_gmm_roofline"):
+        assert took(other) == [False] * 3, other
 
 
 def test_paged_decode_compiles_for_v5e(compiled):
