@@ -35,7 +35,8 @@ float32 accumulation, except the solve, which is float32 throughout; the
 state is carried from chunk to chunk in float32 and rounded only as a
 matmul's operand. On a TPU the call is three Pallas kernels, each walking a
 (batch x head) group's chunks in sequence with the carried array resident
-in VMEM: the forward (`_fwd_kernel`: outputs `o` and the FINAL STATE
+in VMEM, the group's rows going through the solve two to an MXU pass
+(`_solve_rows`): the forward (`_fwd_kernel`: outputs `o` and the FINAL STATE
 `f32[b x h, d_k, d_v]`), and for the backward pass `_states_kernel` (the
 forward again less `o`, handing on each chunk's U~, (I + A)^-1 and incoming
 state) and `_bwd_kernel` (the chunks walked backwards, the state's
@@ -47,6 +48,7 @@ queries tell kernels apart by them. Elsewhere the same arithmetic in `jnp`
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -61,7 +63,11 @@ SUB = 16
 # exactly: at a tie `minimum` hands its gradient half to each side
 _EXP_CAP = 44.0
 _SOLVE_BASE = 8
-_HEADS_PER_STEP = 4
+# (batch, head) rows a grid step: their chunks are independent chains of
+# small dependent matmuls, and the MXU waits less the more of them a step
+# interleaves (PERF.md section 6, PR 40: 8 is a third faster than 4; at 16
+# the backward's second walk does not fit VMEM)
+_HEADS_PER_STEP = 8
 # saved by a layer's remat policy beside the flash call's (`mla_moe`)
 RESIDUAL_NAMES = ("kda.o",)
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -101,7 +107,9 @@ def _mm(spec, a, b, dtype):
 def _solve(a, t_pos, i_pos, mm):
     """a [..., C, C] float32, strictly lower triangular -> (I + a)^-1, in
     ten products `mm` of C x C (C 64, `t_pos` / `i_pos` the row's and the
-    column's index, broadcastable to a).
+    column's index, broadcastable to a). Or a [..., C, n C], n such matrices
+    side by side, `i_pos` each one's own column index and `mm` their n
+    products at once: `_solve_rows`.
 
     A diagonal block d of `_SOLVE_BASE` (8) rows is nilpotent, d^8 = 0, so
     (I + d)^-1 = (I - d)(I + d^2)(I + d^4), all blocks at once on the
@@ -125,21 +133,29 @@ def _solve(a, t_pos, i_pos, mm):
     step contracts the state. Here a block's powers stop at d^7, entries at
     most C(6, 3) = 20, and the pairing multiplies inverses, whose entries
     stay of order 1."""
-    c = a.shape[-1]
+    c = a.shape[-2]
+    issued = math.prod(a.shape[:-2])
+
+    def times(x, y):
+        # per lowering: the C x C products a call stands for, the calls made
+        device_profiler.count("kda.solve_products", issued * (a.shape[-1] // c))
+        device_profiler.count("kda.solve_passes_packed", issued)
+        return mm(x, y)
+
     same = lambda size: t_pos // size == i_pos // size  # noqa: E731
     x = jnp.where(same(_SOLVE_BASE), -a, 0.0)
     t = jnp.where(t_pos == i_pos, 1.0, 0.0) + x
     n = 2
     while n < _SOLVE_BASE:
-        x = mm(x, x)
-        t = t + mm(t, x)
+        x = times(x, x)
+        t = t + times(t, x)
         n *= 2
     size = _SOLVE_BASE
     while size < c:
         # a is strictly lower: of a pair's off-diagonal blocks only the
         # lower-left one is not zero
         pair = jnp.where(same(2 * size) & ~same(size), a, 0.0)
-        t = t - mm(mm(t, pair), t)
+        t = t - times(times(t, pair), t)
         size *= 2
     return t
 
@@ -285,14 +301,44 @@ def _chunk_terms(q, k, g, beta_row, mm):
         else jnp.where(t_pos >= i_pos, scores[:, c:], 0.0))
 
 
+def _pair_products(x, y, mm):
+    """x = [X1 | X2], y = [Y1 | Y2], [P, C, 2C] each -> [X1 Y1 | X2 Y2] in
+    ONE product `mm` a pair: y's halves go onto the diagonal of a [2C, 2C]
+    weight, and diag(Y1, Y2) adds exact zeros to each of the sums."""
+    c = x.shape[1]
+    block = lambda axis: jax.lax.broadcasted_iota(  # noqa: E731
+        jnp.int32, (x.shape[0], 2 * c, 2 * c), axis) // c
+    return mm(x, jnp.where(block(1) == block(2),
+                           jnp.concatenate([y, y], axis=1), 0.0))
+
+
+def _solve_rows(a, t_pos, i_pos, mm):
+    """`_solve` for a grid step's R rows, a [R, C, C]. A C x C product
+    fills a quarter of the 128 x 128 MXU and costs a whole pass (the weight
+    load, the pushes and the pops do not shrink with it), so where R is
+    even the rows go through the solve in LANE-PACKED PAIRS, row i beside
+    row R / 2 + i as [R / 2, C, 2C], two rows' products to a pass of full
+    width: the same float32 arithmetic, bit for bit. R odd (`_specs`' one
+    row a step where b x h is odd): `_solve` as it is."""
+    r, c, _ = a.shape
+    exact = lambda x, y: mm(x, y, 1, 0, exact=True)  # noqa: E731
+    if r % 2:
+        return _solve(a, t_pos, i_pos, exact)
+    wide = (r // 2, c, 2 * c)
+    m = _solve(jnp.concatenate([a[:r // 2], a[r // 2:]], axis=2),
+               jax.lax.broadcasted_iota(jnp.int32, wide, 1),
+               jax.lax.broadcasted_iota(jnp.int32, wide, 2) % c,
+               partial(_pair_products, mm=exact))
+    return jnp.concatenate([m[:, :, :c], m[:, :, c:]], axis=0)
+
+
 def _chunk_forward(q, k, v, g, beta_row, state, mm):
     """One chunk -> (o or None where q is, U~, (I + A)^-1, the next
     state), all float32."""
     t = _chunk_terms(q, k, g, beta_row, mm)
     c, d = k.shape[1:]
     cum = t["cum"]
-    m = _solve(t["araw"] * t["beta_col"], t["t_pos"], t["i_pos"],
-               lambda a, b: mm(a, b, 1, 0, exact=True))
+    m = _solve_rows(t["araw"] * t["beta_col"], t["t_pos"], t["i_pos"], mm)
     solved = m * beta_row
     u = mm(solved, v, 1, 0)
     w = mm(solved, k * jnp.exp(cum), 1, 0)
@@ -312,7 +358,7 @@ def _decay_column(last):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref):
-    """One chunk of `_HEADS_PER_STEP` (batch, head) rows: refs [R, C, D],
+    """One chunk of R (batch, head) rows (`_specs`): refs [R, C, D],
     beta [R, N, C] (the row's chunks, this step's picked by the grid's
     index), the state [R, d_k, d_v] float32 resident over the chunk axis."""
     from jax.experimental import pallas as pl
@@ -466,7 +512,7 @@ def _specs(rows, n, reverse=False):
     step, `held(*dims)`: a block that stays over the chunk axis)."""
     from jax.experimental import pallas as pl
 
-    per = _HEADS_PER_STEP if rows % _HEADS_PER_STEP == 0 else 1
+    per = math.gcd(rows, _HEADS_PER_STEP)
     at = (lambda j: n - 1 - j) if reverse else (lambda j: j)
     walked = lambda d, c=CHUNK: pl.BlockSpec(  # noqa: E731
         (per, c, d), lambda i, j: (i, at(j), 0))

@@ -49,6 +49,21 @@ REGIMES = {"g_near_0": (-0.05, 0.0, False), "g_minus_5": (-5.0, 30.0, False),
            "aligned_keys": (-0.05, 0.0, True)}
 
 
+def _everything(fn, args, w):
+    """fn(*args) -> (o, state): them and the five gradients of
+    sum(o * w) + sum(state), by `KDA_TENSORS`' names."""
+    def scalar(*a):
+        o, state = fn(*a)
+        return jnp.sum(o * w) + jnp.sum(state), (o, state)
+    grads, (o, state) = jax.grad(
+        scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    return dict(zip(KDA_TENSORS, (o, state) + grads))
+
+
+def _interpreted(*a):
+    return kda_op._kda(*a, False, True)
+
+
 @functools.lru_cache(maxsize=None)
 def _kda_case(regime, s):
     low, shift, aligned = REGIMES[regime]
@@ -66,18 +81,9 @@ def _kda_case(regime, s):
         jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, s))
                        + (4.0 if aligned else 0.0)))
     w = jax.random.normal(ks[5], (b, h, s, d))
-
-    def everything(fn):
-        def scalar(*a):
-            o, state = fn(*a)
-            return jnp.sum(o * w) + jnp.sum(state), (o, state)
-        grads, (o, state) = jax.grad(
-            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
-        return dict(zip(KDA_TENSORS, (o, state) + grads))
-
     with jax.default_matmul_precision("highest"):
-        return (everything(kda_op.kda_recurrence),
-                everything(lambda *a: kda_op._kda(*a, False, False)),
+        return (_everything(kda_op.kda_recurrence, args, w),
+                _everything(lambda *a: kda_op._kda(*a, False, False), args, w),
                 args)
 
 
@@ -102,15 +108,8 @@ def _kda_kernel_case(regime):
     want, _, args = _kda_case(regime, 100)
     w = jax.random.normal(jax.random.split(jax.random.PRNGKey(7), 7)[5],
                           args[0].shape)
-
-    def scalar(*a):
-        o, state = kda_op._kda(*a, False, True)
-        return jnp.sum(o * w) + jnp.sum(state), (o, state)
-
     with jax.default_matmul_precision("highest"):
-        grads, (o, state) = jax.grad(
-            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
-    return want, dict(zip(KDA_TENSORS, (o, state) + grads))
+        return want, _everything(_interpreted, args, w)
 
 
 @pytest.mark.parametrize("tensor", KDA_TENSORS)
@@ -130,11 +129,82 @@ def test_kda_kernel_in_the_interpreter_matches_the_recurrence(regime, tensor):
         atol=GRAD_ATOL if tensor == "dg" else ATOL)
 
 
+def _kda_counters_of(lower):
+    before = device_profiler.snapshot()["counters"]
+    lower()
+    return {k: v - before.get(k, 0)
+            for k, v in device_profiler.snapshot()["counters"].items()
+            if k.startswith("kda.") and v != before.get(k, 0)}
+
+
 def test_kda_counts_its_chunks():
+    """Per lowering: the chunks a sequence, and of the solve the C x C
+    products a kernel's grid step stands for (10 a row) beside the products
+    it issues: half where the rows go in lane-packed pairs (b x h 4: 4
+    rows a step), all of them at 3 (one row a step) and in the `jnp` form."""
     args = _kda_case("g_near_0", 100)[2]
-    before = device_profiler.snapshot()["counters"].get("kda.chunks", 0)
-    jax.jit(kda_op.kda).lower(*args)
-    assert device_profiler.snapshot()["counters"]["kda.chunks"] - before == 2
+    assert _kda_counters_of(lambda: jax.jit(kda_op.kda).lower(*args)) == {
+        "kda.chunks": 2, "kda.solve_products": 80,
+        "kda.solve_passes_packed": 80}
+    for heads, products, issued in ((4, 40, 20), (3, 10, 10)):
+        args = kda_chip_check.inputs(
+            "mixed", jax.random.PRNGKey(0), b=1, h=heads, s=100, d=32)[0]
+        # the kernels' traces are cached by shape: another test's lowering
+        # of this one would leave nothing to count
+        kda_op._kda_fwd_pallas.clear_cache()
+        assert _kda_counters_of(lambda: jax.jit(functools.partial(
+            kda_op.kda, interpret=True)).lower(*args)) == {
+                "kda.chunks": 2, "kda.solve_products": products,
+                "kda.solve_passes_packed": issued}, heads
+
+
+# --------------------------------------------------------------------------
+# the solve on lane-packed pairs of rows: the same arithmetic
+# --------------------------------------------------------------------------
+
+def test_a_pair_of_products_in_one_is_exact():
+    """[X1 | X2] . diag(Y1, Y2) == [X1 Y1 | X2 Y2] for float32 `highest`:
+    what the diagonal weight adds to each sum is exact zeros."""
+    kx, ky = jax.random.split(jax.random.PRNGKey(3))
+    c = kda_op.CHUNK
+    x = jax.random.normal(kx, (3, c, 2 * c)) * 1e3
+    y = jax.random.normal(ky, (3, c, 2 * c))
+    mm = kda_op._mm_in(jnp.float32)
+    exact = lambda a, b: mm(a, b, 1, 0, exact=True)  # noqa: E731
+    want = jnp.concatenate([exact(x[..., :c], y[..., :c]),
+                            exact(x[..., c:], y[..., c:])], axis=2)
+    got = kda_op._pair_products(x, y, exact)
+    assert got.shape == want.shape and bool(jnp.all(got == want))
+    # and `==` can tell: operands rounded to bf16 give another product
+    assert not bool(jnp.all(kda_op._mm_in(jnp.bfloat16)(
+        x[..., :c], y[..., :c], 1, 0) == want[..., :c]))
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_and_unpacked(kind, heads):
+    """The interpreted kernels on the tool's input `kind` at b x h =
+    `heads`, S no multiple of the chunk -> (o, the final state and the five
+    gradients as the tree has them, the same with the tool's
+    `unpacked_solve()`: every row's solve its own C x C products)."""
+    args, w = kda_chip_check.inputs(
+        kind, jax.random.PRNGKey(5), b=1, h=heads, s=150, d=32,
+        dtype=jnp.float32)
+    got = _everything(_interpreted, args, w)
+    with kda_chip_check.unpacked_solve():
+        return got, _everything(_interpreted, args, w)
+
+
+@pytest.mark.parametrize("tensor", KDA_TENSORS)
+@pytest.mark.parametrize("heads", [4, 8, 3])
+@pytest.mark.parametrize("kind", ["mixed", "aligned_keys"])
+def test_the_packed_solve_equals_the_unpacked_bit_for_bit(kind, heads, tensor):
+    """4 and 8 rows go through the solve in pairs (a grid step of two
+    pairs, of four), 3 rows one row a step as ever: o, the final state and
+    all five gradients EQUAL the unpacked solve's, not merely close."""
+    got, want = _packed_and_unpacked(kind, heads)
+    assert bool(jnp.all(jnp.isfinite(got[tensor])))
+    assert float(jnp.abs(got[tensor]).max()) > 0
+    assert bool(jnp.all(got[tensor] == want[tensor]))
 
 
 @functools.lru_cache(maxsize=None)

@@ -3,8 +3,9 @@
 `jax.experimental.topologies` describes a v5e 2x2 host to the compiler, so
 the Pallas kernels and the serving decode program are compiled for
 `TPU v5 lite` here, on the CPU sandbox — a reading of the compiler, not of
-a run (chip_smoke.py is the run). One subprocess does all of it: libtpu is
-loaded there, not into the test process.
+a run (chip_smoke.py is the run). Two subprocesses, one after the other, do
+all of it (the second with the compiler's dump on, for `ops/kda.py`'s
+kernels alone): libtpu is loaded there, not into the test process.
 
 It also pins the flash kernels' sequence limit (the kernels keep whole-
 sequence K/V, or q/dO, blocks in VMEM; see ops/flash_attention.py): the
@@ -24,7 +25,7 @@ pytest.importorskip("libtpu")
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_SCRIPT = r"""
+_HEAD = r"""
 import dataclasses
 import functools
 import json
@@ -57,6 +58,11 @@ def on_chip(tree):
     return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
 
 
+out = {"device_kind": topo.devices[0].device_kind}
+bf16 = jnp.bfloat16
+"""
+
+_SCRIPT = r"""
 def flash_grads(q, k, v):
     # use_pallas=True: the default follows jax.default_backend(), cpu here
     return jax.grad(
@@ -64,8 +70,6 @@ def flash_grads(q, k, v):
         .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
 
-out = {"device_kind": topo.devices[0].device_kind}
-bf16 = jnp.bfloat16
 lowered = jax.jit(flash_grads).lower(
     spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
     spec((4, 2048, 8, 128), bf16))
@@ -279,20 +283,6 @@ out["moe_combine_other_hits_below_the_last_capacity"] = [
     ln[:160] for ln in hits if "tpu_custom_call" not in ln
     and re.search(r"branch_0_fun", ln)]
 
-# the chunked delta rule (train-ling-1chip): the Pallas forward and the two
-# backward kernels at [4, 32, 2048, 128], and their events as the trace
-# will name them
-from ray_tpu.ops import kda as kda_op
-kda_args = (spec((4, 32, 2048, 128), bf16),) * 3 + (
-    spec((4, 32, 2048, 128), jnp.float32), spec((4, 32, 2048), jnp.float32))
-hlo = jax.jit(jax.value_and_grad(  # the value: or the forward is dead code
-    lambda *a: kda_op.kda(*a, use_pallas=True).astype(jnp.float32).sum(),
-    argnums=(0, 1, 2, 3, 4))).lower(*kda_args).compile().as_text()
-out["kda_calls"] = [
-    re.sub(r"custom-call\(.*", 'custom-call(%a), custom_call_target='
-           '"tpu_custom_call"', ln.strip())
-    for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
-
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
 eng = PagedInferenceEngine(params, cfg, max_batch=8, max_len=1024,
@@ -307,18 +297,56 @@ out["paged_decode"] = "compiled"
 print("RESULT " + json.dumps(out))
 """
 
+# A process of its own, so that the compiler's dump (`--xla_mosaic_dump_to`,
+# which libtpu reads once, at start-up) holds these three kernels only: the
+# whole of `_SCRIPT` would leave 1.6 GB behind.
+_KDA_SCRIPT = r"""
+# the chunked delta rule (train-ling-1chip): the Pallas forward and the two
+# backward kernels at [4, 32, 2048, 128], and their events as the trace
+# will name them
+import glob
+from ray_tpu.ops import kda as kda_op
+kda_args = (spec((4, 32, 2048, 128), bf16),) * 3 + (
+    spec((4, 32, 2048, 128), jnp.float32), spec((4, 32, 2048), jnp.float32))
+hlo = jax.jit(jax.value_and_grad(  # the value: or the forward is dead code
+    lambda *a: kda_op.kda(*a, use_pallas=True).astype(jnp.float32).sum(),
+    argnums=(0, 1, 2, 3, 4))).lower(*kda_args).compile().as_text()
+out["kda_calls"] = [
+    re.sub(r"custom-call\(.*", 'custom-call(%a), custom_call_target='
+           '"tpu_custom_call"', ln.strip())
+    for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+# a grid step's body is straight-line code, one `vdwg` an MXU pass
+out["kda_rows_a_step"] = kda_op._specs(4 * 32, 32)[0]
+for kernel in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
+    (path,) = glob.glob(os.path.join(
+        os.environ["MOSAIC_DUMP"], f"*-{kernel}-post-finalize-llo.txt"))
+    with open(path) as f:
+        out["kda" + kernel + "_vdwg"] = f.read().count('"llo.vdwg"')
+print("RESULT " + json.dumps(out))
+"""
 
-@pytest.fixture(scope="module")
-def compiled():
+
+def _run(script, **more):
     env = dict(os.environ, JAX_PLATFORMS="cpu", REPO_ROOT=REPO_ROOT,
                PYTHONPATH=REPO_ROOT + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
+               + os.environ.get("PYTHONPATH", ""), **more)
+    proc = subprocess.run([sys.executable, "-c", _HEAD + script], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     (line,) = [ln for ln in proc.stdout.splitlines()
                if ln.startswith("RESULT ")]
     return json.loads(line[len("RESULT "):])
+
+
+@pytest.fixture(scope="module")
+def compiled(tmp_path_factory):
+    """One after the other: a process keeps libtpu until it exits."""
+    out = _run(_SCRIPT)
+    dump = str(tmp_path_factory.mktemp("mosaic"))
+    out.update(_run(_KDA_SCRIPT, MOSAIC_DUMP=dump, LIBTPU_INIT_ARGS=" ".join(
+        [os.environ.get("LIBTPU_INIT_ARGS", ""),
+         "--xla_mosaic_dump_to=" + dump]).strip()))
+    return out
 
 
 def test_flash_fwd_bwd_compiles_for_v5e(compiled):
@@ -349,6 +377,18 @@ def test_delta_rule_kernels_compile_for_v5e_under_their_own_signatures(
                   "bd_attention_time_share", "flash_fwd_roofline",
                   "flash_bwd_roofline", "moe_gmm_roofline"):
         assert took(other) == [False] * 3, other
+    # From the compiler's own dump (`*-post-finalize-llo.txt`): the MXU
+    # passes a (batch, head) row costs a chunk. 76 in the forward and 74 in
+    # the first walk while every row's solve ran its own ten 64 x 64 float32
+    # `highest` products (60 passes); 46 and 44 with two rows to a pass
+    # (PERF.md section 6, PR 40). A later edit that unpacks the solve in
+    # silence fails here, not only in a benchmark. The second walk runs no
+    # solve: 39.
+    rows = compiled["kda_rows_a_step"]
+    assert rows % 2 == 0
+    assert compiled["kda_fwd_kernel_vdwg"] / rows < 60
+    assert compiled["kda_states_kernel_vdwg"] / rows < 60
+    assert compiled["kda_bwd_kernel_vdwg"] / rows < 60
 
 
 def test_paged_decode_compiles_for_v5e(compiled):

@@ -36,19 +36,29 @@ state reads (the delta rule rewrites the state every few chunks and the
 state enters every matmul as a bf16 operand anyway), so `mixed` alone
 cannot tell them apart; on `long_memory` it misses the bound several times
 over in o. Exit 1 if a tensor of the float32 state misses the bound on
-any input, the bf16 state passes everywhere on `long_memory`, or the
-product of powers passes everywhere on `aligned_keys`.
+any input, the bf16 state passes everywhere on `long_memory`, the
+product of powers passes everywhere on `aligned_keys`, or o or a gradient
+of `mixed` differs in any bit from the same kernels with every row's solve
+its own 64 x 64 products (`unpacked_solve()`: the MXU has to add the exact
+zeros of a lane-packed pair's product exactly).
 
 `ms`: `fwd` the forward kernel, `fwd_xla` the chunked form in XLA,
 `fwd_and_bwd` the gradient of a LINEAR function of o: the forward is dead
-code there, so it is the two backward kernels. Writes
-chiprun_out/kda_chip_check.json.
+code there, so it is the two backward kernels, on the host's clock; and the
+three kernels APART on the device's, from a short trace of value and
+gradient: `fwd_kernel_ms`, `states_ms` (the backward pass's walk forwards:
+the forward's solve again) and `bwd_ms` (its walk backwards), told apart by
+how many outputs a Pallas event has. Writes chiprun_out/kda_chip_check.json.
 """
 import argparse
 import contextlib
+import glob
 import json
 import os
+import re
+import statistics
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -90,49 +100,54 @@ def inputs(kind, key, b=4, h=32, s=2048, d=128, dtype=jnp.bfloat16):
 
 
 @contextlib.contextmanager
+def _kernels_with(name, value):
+    """Inside, `ops/kda.<name>` is `value`. The jit caches are dropped on
+    the way in and out: the kernels' traces are cached by shape."""
+    kept = getattr(K, name)
+    setattr(K, name, value)
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        setattr(K, name, kept)
+        jax.clear_caches()
+
+
 def bf16_state():
     """Inside, the kernels carry their state in bf16: `_chunk_forward` (a
     chunk's step in the forward kernel and in the backward pass's first
-    walk) hands on a rounded state. The jit caches are dropped on the way
-    in and out: the kernels' traces are cached by shape."""
+    walk) hands on a rounded state."""
     step = K._chunk_forward
 
     def rounded(*args):
         o, new, m, state = step(*args)
         return o, new, m, state.astype(jnp.bfloat16).astype(jnp.float32)
 
-    K._chunk_forward = rounded
-    jax.clear_caches()
-    try:
-        yield
-    finally:
-        K._chunk_forward = step
-        jax.clear_caches()
+    return _kernels_with("_chunk_forward", rounded)
 
 
-@contextlib.contextmanager
 def product_solve():
     """Inside, (I + A)^-1 is (I - A)(I + A^2)(I + A^4) ... (I + A^32), the
     product of the whole chunk's powers: what `ops/kda._solve` is not."""
-    solve = K._solve
-
     def product(a, t_pos, i_pos, mm):
         x = -a
         t = jnp.where(t_pos == i_pos, 1.0, 0.0) + x
         n = 2
-        while n < a.shape[-1]:
+        while n < a.shape[-2]:
             x = mm(x, x)
             t = t + mm(t, x)
             n *= 2
         return t
 
-    K._solve = product
-    jax.clear_caches()
-    try:
-        yield
-    finally:
-        K._solve = solve
-        jax.clear_caches()
+    return _kernels_with("_solve", product)
+
+
+def unpacked_solve():
+    """Inside, a grid step's rows go through `ops/kda._solve` one C x C
+    product a row, as before PR 40, and not in lane-packed pairs: the same
+    arithmetic, so o and the gradients have to EQUAL the kernels' own."""
+    return _kernels_with("_solve_rows", lambda a, t_pos, i_pos, mm: K._solve(
+        a, t_pos, i_pos, lambda x, y: mm(x, y, 1, 0, exact=True)))
 
 
 def with_grads(fn, w):
@@ -170,6 +185,44 @@ def timed(fn, *args, n=10):
     return (time.perf_counter() - t) / n * 1e3
 
 
+# a kernel by the number of its outputs
+KERNEL_OF_OUTPUTS = {2: "fwd_kernel_ms", 3: "states_ms", 5: "bwd_ms"}
+
+
+def kernel_of(event_name):
+    """A device event's name is its HLO text, `%name = (bf16[..]{..},
+    f32[..]{..}) custom-call(...` -> which of `ops/kda.py`'s kernels it is,
+    or None."""
+    m = re.match(r"%[\w.\-]+ = \((.*?)\) custom-call\(", event_name)
+    if not m or "tpu_custom_call" not in event_name:
+        return None
+    return KERNEL_OF_OUTPUTS.get(len(re.findall(r"\w+\[", m[1])))
+
+
+def kernel_ms(fn, *args, n=5):
+    """`fn` (compiled already) traced over `n` calls -> the milliseconds
+    of one event of each kernel on the device's clock; {} where the trace
+    holds no such event."""
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        paths = glob.glob(os.path.join(
+            d, "plugins", "profile", "*", "*.xplane.pb"))
+        planes = ProfileData.from_file(paths[0]).planes if paths else []
+        took = {}
+        for e in (e for p in planes if p.name.startswith("/device:TPU:")
+                  for ln in p.lines if ln.name == "XLA Ops"
+                  for e in ln.events):
+            took.setdefault(kernel_of(e.name), []).append(e.duration_ns * 1e-6)
+    return {kernel: statistics.mean(ms) for kernel, ms in took.items()
+            if kernel}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=39)
@@ -193,6 +246,13 @@ def main():
         "fwd": timed(jax.jit(kernel), *args),
         "fwd_xla": timed(jax.jit(lambda *x: K._kda_chunked(*x)[0]), *args),
         "fwd_and_bwd": timed(jax.jit(grad), *args)}
+    both = jax.jit(lambda *x: (kernel(*x), grad(*x)))
+    got = jax.block_until_ready(both(*args))
+    out["ms"].update(kernel_ms(both, *args))
+    with unpacked_solve():
+        out["equals_unpacked_solve"] = all(
+            bool(jnp.all(a == b)) for a, b in zip(
+                jax.tree.leaves(got), jax.tree.leaves(both(*args))))
     out["ok"] = all(v <= bound for kind, (bound, _) in KINDS.items()
                     for v in out[kind]["kernel"].values())
     # a NaN fails its bound too
@@ -204,7 +264,8 @@ def main():
     with open("chiprun_out/kda_chip_check.json", "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
-    return 0 if out["ok"] and out["control_fails"] else 1
+    return 0 if out["ok"] and out["control_fails"] \
+        and out["equals_unpacked_solve"] else 1
 
 
 if __name__ == "__main__":
