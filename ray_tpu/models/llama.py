@@ -237,7 +237,7 @@ def _flash(q, k, v, mesh, **rule):
 
 def _qkv(x, params, positions, config: LlamaConfig, lc=None):
     """The attention prologue every sublayer shares: pre-norm, the q/k/v
-    projections, QK-norm, RoPE on q and k. `lc` (training only) constrains
+    projections, QK-norm, RoPE on q and k (none where `rope_theta` is 0). `lc` (training only) constrains
     q and k to their logical layout between the norm and RoPE.
     -> q [B,S,H,K], k and v [B,S,kv,K]."""
     c = config
@@ -249,8 +249,9 @@ def _qkv(x, params, positions, config: LlamaConfig, lc=None):
     if lc is not None:
         q = lc(q, ("batch", "seq", "act_heads", "act_kv"))
         k = lc(k, ("batch", "seq", "act_heads", "act_kv"))
-    q = _rope(q, positions, c.rope_theta)
-    k = _rope(k, positions, c.rope_theta)
+    if c.rope_theta:  # 0: no rotary embedding (`models/nemotron_h.py`)
+        q = _rope(q, positions, c.rope_theta)
+        k = _rope(k, positions, c.rope_theta)
     return q, k, v
 
 

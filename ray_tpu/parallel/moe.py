@@ -6,7 +6,8 @@ serve both entry points:
 - `moe_layer`: the single-program dispatch, dropless. The T x k (token,
   slot) pairs are sorted by expert, the rows gathered in that order, and the
   experts' SwiGLU runs as three grouped matmuls over the ragged groups
-  (`ops/grouped_matmul.py`). The k combine weights are brought to sorted
+  (`ops/grouped_matmul.py`; two for the two-matrix `relu2` form,
+  `_expert_hidden`). The k combine weights are brought to sorted
   order too and applied BEFORE the down projection (it is linear), so the
   inverse permutation only brings the k results of a token back together
   for a plain sum: the backward pass needs none of the down projection's
@@ -310,8 +311,26 @@ def _sum_rows_bwd(res, g):
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
+def _expert_hidden(rows, experts, w_sorted, form: str, gmm):
+    """rows [M, D] in sorted order -> what the down projection reads, [M, F]
+    in rows.dtype, the combine weights `w_sorted` [M] applied: `form`
+    "swiglu" is silu(gate x) * up x (w_gate, w_up), "relu2" is relu(up x)^2
+    (w_up alone: Nemotron-H's experts). Formed in float32, rounded once.
+    `gmm(lhs, w)` is the grouped matmul over this dispatch's groups."""
+    f32 = jnp.float32
+    if form == "swiglu":
+        gate = gmm(rows, experts["w_gate"])
+        up = gmm(rows, experts["w_up"])
+        h = jax.nn.silu(gate.astype(f32)) * up.astype(f32)
+    elif form == "relu2":
+        h = jnp.square(jax.nn.relu(gmm(rows, experts["w_up"]).astype(f32)))
+    else:
+        raise ValueError(f"unknown expert form {form!r}")
+    return (h * w_sorted[:, None]).astype(rows.dtype)
+
+
 def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
-               cap: int):
+               cap: int, form: str = "swiglu"):
     """One capacity's program: the first `cap` sorted pairs (all the live
     ones, and at least one dead row unless every pair is live, or
     `_capacity_switch` would not have chosen it) through the held experts'
@@ -336,10 +355,7 @@ def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
         rows = _take_rows(x, token, slot)
         w_sorted = weights.reshape(-1)[pair]  # dead rows: gate, up are 0
     with jax.named_scope("moe.experts"):
-        gate = gmm(rows, experts["w_gate"])
-        up = gmm(rows, experts["w_up"])
-        h = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-             * w_sorted[:, None]).astype(x.dtype)
+        h = _expert_hidden(rows, experts, w_sorted, form, gmm)
         out = grouped_matmul(h, experts["w_down"], group_sizes)
     with jax.named_scope("moe.combine"):
         return _sum_rows(out, token, slot)
@@ -357,8 +373,9 @@ def _capacity_switch(caps, group_sizes, branch, *operands):
     return jax.lax.switch(index, [branch(c) for c in caps], *operands)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _held_experts(x, experts, weights, order, inverse, group_sizes, k, caps):
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _held_experts(x, experts, weights, order, inverse, group_sizes, k, caps,
+                  form):
     """The routed part of a share: `_held_rows` at the smallest of the
     static capacities `caps` that holds this step's live rows, so buffers,
     gathers and elementwise work are sized by what landed here (to a factor
@@ -371,23 +388,25 @@ def _held_experts(x, experts, weights, order, inverse, group_sizes, k, caps):
     (gate and up are recomputed, as remat "dots" recomputes them anyway).
     The combine weights get no gradient (`moe_layer` says why)."""
     return _capacity_switch(
-        caps, group_sizes, lambda cap: partial(_held_rows, k=k, cap=cap),
+        caps, group_sizes,
+        lambda cap: partial(_held_rows, k=k, cap=cap, form=form),
         x, experts, weights, order, inverse, group_sizes)
 
 
 def _held_experts_fwd(x, experts, weights, order, inverse, group_sizes, k,
-                      caps):
+                      caps, form):
     return (_held_experts(x, experts, weights, order, inverse, group_sizes,
-                          k, caps),
+                          k, caps, form),
             (x, experts, weights, order, inverse, group_sizes))
 
 
-def _held_experts_bwd(k, caps, res, g):
+def _held_experts_bwd(k, caps, form, res, g):
     def branch(cap):
         def grads(x, experts, weights, order, inverse, group_sizes, g):
             _, vjp = jax.vjp(
                 lambda x, e: _held_rows(x, e, weights, order, inverse,
-                                        group_sizes, k=k, cap=cap), x, experts)
+                                        group_sizes, k=k, cap=cap, form=form),
+                x, experts)
             return vjp(g)
         return grads
 
@@ -401,14 +420,20 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
               score: str = "softmax", router_bias=None,
               weight_scale: float = 1.0, held=None, n_group: int = 1,
-              topk_group: int = 1):
-    """Dropless top-k SwiGLU experts. x [T, D]; router_w [D, E]; `experts`
-    holds w_gate, w_up [E', D, F] and w_down [E', F, D]. -> (y [T, D] in
+              topk_group: int = 1, form: str = "swiglu", rows=None):
+    """Dropless top-k experts. x [T, D]; router_w [D, E]; `experts` holds
+    w_gate, w_up [E', D, F] and w_down [E', F, D]. -> (y [T, D] in
     x.dtype, MoEAux): y_t = sum_j w_tj * down_e(silu(gate_e x_t) * up_e x_t)
     over token t's k experts e, accumulated in float32. The choice and the
     weights w_tj are `route`'s (`score`: softmax or sigmoid scores,
     `router_bias`, `norm_topk_prob`, `weight_scale`, `n_group` and
     `topk_group`), over all E experts.
+
+    `form` "relu2": the experts are down_e(relu(up_e .)^2), two matrices
+    (`_expert_hidden`). `rows` [T, D']: what is DISPATCHED where that is not
+    what the router reads (experts in a latent: the router sees x at the
+    residual's width, the experts' matrices are [E', D', F] and [E', F, D']
+    and y is [T, D'], in rows.dtype); None: x itself.
 
     The share: `held=None` means every expert is here (E' = E). With
     `held=(first_expert, n_held)` the E' = n_held experts `first_expert ..`
@@ -436,18 +461,22 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
     routing = route(x, router_w, k, norm_topk_prob, score=score,
                     bias=router_bias, scale=weight_scale, n_group=n_group,
                     topk_group=topk_group)
+    if rows is None:
+        rows = x
+    else:
+        device_profiler.count("moe.latent_rows", t * k)  # per lowering
     if held is not None:
         first, n_held = held
         if not (0 <= first and first + n_held <= e) \
-                or experts["w_gate"].shape[0] != n_held:
+                or experts["w_down"].shape[0] != n_held:
             raise ValueError(f"held experts {held} of {e}, weights for "
-                             f"{experts['w_gate'].shape[0]}")
+                             f"{experts['w_down'].shape[0]}")
         caps = share_capacities(t, k, n_held, e)
         with jax.named_scope("moe.permute"):
             order, inverse, group_sizes = sort_held(
                 routing.experts, first, n_held)
-        y = _held_experts(x, experts, routing.weights, order, inverse,
-                          group_sizes, k, caps)
+        y = _held_experts(rows, experts, routing.weights, order, inverse,
+                          group_sizes, k, caps, form)
         device_profiler.count("moe.experts_held", n_held)
         device_profiler.count("moe.rows_capacity", caps[-1])
         device_profiler.count("moe.combine_slots", t * k * len(caps))
@@ -455,21 +484,19 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
     else:
         with jax.named_scope("moe.permute"):
             order, inverse, group_sizes = sort_by_expert(routing.experts, e)
-            rows = _permute(x, order, inverse, k)
+            rows = _permute(rows, order, inverse, k)
             w_sorted = _reorder(routing.weights.reshape(-1), order, inverse)
         with jax.named_scope("moe.experts"):
-            gate = grouped_matmul(rows, experts["w_gate"], group_sizes)
-            up = grouped_matmul(rows, experts["w_up"], group_sizes)
-            h = (jax.nn.silu(gate.astype(jnp.float32))
-                 * up.astype(jnp.float32)
-                 * w_sorted[:, None]).astype(x.dtype)
+            h = _expert_hidden(
+                rows, experts, w_sorted, form,
+                lambda lhs, w: grouped_matmul(lhs, w, group_sizes))
             out = grouped_matmul(h, experts["w_down"], group_sizes)
         with jax.named_scope("moe.combine"):
             y = _combine(out, order, inverse, k)
     # per lowering, as `flash.steps_*` are
     device_profiler.count("moe.rows_routed", t * k)
     device_profiler.count("moe.experts", e)
-    device_profiler.count("moe.gmm_calls", 3)
+    device_profiler.count("moe.gmm_calls", 3 if form == "swiglu" else 2)
     return y, MoEAux(routing.experts, *router_losses(routing))
 
 

@@ -283,6 +283,23 @@ out["moe_combine_other_hits_below_the_last_capacity"] = [
     ln[:160] for ln in hits if "tpu_custom_call" not in ln
     and re.search(r"branch_0_fun", ln)]
 
+# Mamba-2's chunked scan (train-nemotron3-1chip): the Pallas forward and
+# the two backward kernels at x [2, 2048, 128, 64], state 128, 8 groups,
+# and their events as the trace will name them
+from ray_tpu.ops import ssd as ssd_op
+ssd_args = (spec((2, 2048, 128, 64), bf16),
+            spec((2, 2048, 128), jnp.float32),
+            spec((2, 2048, 128), jnp.float32),
+            spec((2, 2048, 8, 128), bf16), spec((2, 2048, 8, 128), bf16))
+hlo = jax.jit(jax.value_and_grad(  # the value: or the forward is dead code
+    lambda *a: ssd_op.ssd_scan(*a, use_pallas=True)[0].astype(
+        jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))).lower(
+            *ssd_args).compile().as_text()
+out["ssd_calls"] = [
+    re.sub(r"custom-call\(.*", 'custom-call(%a), custom_call_target='
+           '"tpu_custom_call"', ln.strip())
+    for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+
 cfg = llama.LlamaConfig.small_1b()
 params = jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0)))
 eng = PagedInferenceEngine(params, cfg, max_batch=8, max_len=1024,
@@ -389,6 +406,30 @@ def test_delta_rule_kernels_compile_for_v5e_under_their_own_signatures(
     assert compiled["kda_fwd_kernel_vdwg"] / rows < 60
     assert compiled["kda_states_kernel_vdwg"] / rows < 60
     assert compiled["kda_bwd_kernel_vdwg"] / rows < 60
+
+
+def test_state_space_kernels_compile_for_v5e_under_their_own_signatures(
+        compiled):
+    """`ops/ssd.py` at train-nemotron3-1chip's shape: THREE Pallas calls (the
+    forward, the backward pass's walk forwards and its walk backwards),
+    each taken by the cell's SSD queries that are for it and by no flash
+    or grouped-matmul query the cell is listed under."""
+    calls = compiled["ssd_calls"]
+    assert len(calls) == 3, calls
+    query = lambda name: re.compile(json.load(open(os.path.join(  # noqa: E731
+        REPO_ROOT, "benchmarks", "metrics", name + ".json")))[
+            "trace_query"]["op"])
+    took = lambda name: [bool(query(name).search(c)) for c in calls]  # noqa: E731
+    assert sorted(took("ssd_fwd_roofline")) == [False, False, True]
+    assert sorted(took("ssd_bwd_roofline")) == [False, True, True]
+    assert [a or b for a, b in zip(took("ssd_fwd_roofline"),
+                                   took("ssd_bwd_roofline"))] == [True] * 3
+    assert took("ssd_time_share") == [True] * 3
+    for other in ("flash_fwd_roofline", "flash_bwd_roofline",
+                  "moe_gmm_roofline", "nemotron_moe_held_time_share",
+                  "mla_flash_fwd_roofline", "mla_flash_bwd_roofline",
+                  "bd_attention_time_share"):
+        assert took(other) == [False] * 3, other
 
 
 def test_paged_decode_compiles_for_v5e(compiled):

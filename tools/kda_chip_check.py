@@ -199,9 +199,10 @@ def kernel_of(event_name):
     return KERNEL_OF_OUTPUTS.get(len(re.findall(r"\w+\[", m[1])))
 
 
-def kernel_ms(fn, *args, n=5):
+def kernel_ms(fn, *args, n=5, kernel_of=kernel_of):
     """`fn` (compiled already) traced over `n` calls -> the milliseconds
-    of one event of each kernel on the device's clock; {} where the trace
+    of one event of each kernel (`kernel_of(event name)`: this tool's, or
+    `tools/ssd_chip_check.py`'s) on the device's clock; {} where the trace
     holds no such event."""
     from jax.profiler import ProfileData
 
