@@ -74,6 +74,29 @@ def _within_groups(biased, n_group: int, topk_group: int):
                          -jnp.inf)
 
 
+@jax.custom_jvp
+def _formed_first(weights):
+    """The k weights first, then whatever reads them: left to itself XLA
+    folds the sum over k of `route`'s denominators into the masked sum over
+    the experts, ONE reduce over [T, k x E]: another order of summation than
+    `top_k`'s values had (a last digit of the denominators, 2e-5 of the SDAR
+    cell's loss), and 0.42 ms at 16,384 x 8 x 128 on the v5e where the two
+    reduces take 0.08 and 0.01 (PERF.md section 6, PR 44). The barrier
+    stands in the value alone, the tangent passes it by: on a cotangent of
+    zeros (a share's weights are constants of the backward pass) a barrier
+    keeps the router's whole backward alive, two `highest` matmuls a layer
+    on zeros (Nemotron's cell -1.1%, Ling's -0.8%, same PR). The fold and
+    this barrier are pinned together for the v5e's compiler, with no chip:
+    `tests/test_tpu_aot_compile.py::test_route_denominators_as_compiled_for_v5e`
+    fails the day either is no longer needed or no longer enough."""
+    return jax.lax.optimization_barrier(weights)
+
+
+@_formed_first.defjvp
+def _formed_first_jvp(primals, tangents):
+    return _formed_first(*primals), tangents[0]
+
+
 def route(x, router_w, k: int, norm_topk_prob: bool = False, *,
           score: str = "softmax", bias=None, scale: float = 1.0,
           n_group: int = 1, topk_group: int = 1) -> Routing:
@@ -104,27 +127,40 @@ def route(x, router_w, k: int, norm_topk_prob: bool = False, *,
                          preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
             else jax.nn.sigmoid(logits)
-        if bias is None and n_group == 1:
-            weights, experts = jax.lax.top_k(probs, k)
-        else:
-            biased = probs if bias is None else \
-                probs + jax.lax.stop_gradient(bias.astype(jnp.float32))
-            if n_group > 1:
-                biased = _within_groups(jax.lax.stop_gradient(biased),
-                                        n_group, topk_group)
-            _, experts = jax.lax.top_k(biased, k)
-            # the chosen experts' own scores, as a masked sum over the
-            # experts: a gather of T x k scalars out of [T, E] and the
-            # scatter that transposes it take 0.67 and 0.57 ms on the v5e
-            # at 8,192 x 256, three times a layer (PERF.md section 6, PR 32)
-            chosen = experts[..., None] == jnp.arange(probs.shape[-1])
-            weights = jnp.sum(
-                jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
+        # the CHOICE alone comes from `top_k`, and no gradient goes through
+        # it; the bias is for the choice only
+        biased = jax.lax.stop_gradient(
+            probs if bias is None else probs + bias.astype(jnp.float32))
+        if n_group > 1:
+            biased = _within_groups(biased, n_group, topk_group)
+        _, experts = jax.lax.top_k(biased, k)
+        # the chosen experts' own scores, as a masked sum over the experts
+        # (p plus exact zeros, and on the way back one contribution a
+        # position): a gather of T x k scalars out of [T, E] and the scatter
+        # that transposes it, which is what autodiff makes of `top_k`'s
+        # values too, take 0.67 and 0.57 ms on the v5e at 8,192 x 256, three
+        # times a layer (PERF.md section 6, PR 32); the masked sum and its
+        # transpose 0.08 and 0.12 ms at 16,384 x 128 (PR 44)
+        chosen = experts[..., None] == jnp.arange(probs.shape[-1])
+        weights = jnp.sum(
+            jnp.where(chosen, probs[:, None, :], 0.0), axis=-1)
         if norm_topk_prob:
+            weights = _formed_first(weights)
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         if scale != 1.0:
             weights = weights * scale
         return Routing(logits, probs, weights, experts.astype(jnp.int32))
+
+
+def _count_by_expert(experts, n_experts: int):
+    """experts [...] int32 -> [n_experts] int32, the entries that chose each
+    expert: a comparison against `arange(n_experts)` with the entries along
+    the minor axis, and a sum over them. A `bincount` is a scatter-add, and
+    a scatter serialises on the TPU: 1.15 ms for 131,072 pairs into 128
+    bins on the v5e (PERF.md section 6, PR 44)."""
+    device_profiler.count("moe.counts_by_comparison", 1)  # per lowering
+    chose = experts.reshape(-1)[None, :] == jnp.arange(n_experts)[:, None]
+    return jnp.sum(chose, axis=1, dtype=jnp.int32)
 
 
 def router_losses(routing: Routing, axis_name=None):
@@ -136,8 +172,7 @@ def router_losses(routing: Routing, axis_name=None):
     mean_t logsumexp_i(logits)^2. With `axis_name` (inside a shard_map over
     tokens) the statistics are taken over every shard's tokens."""
     t, e = routing.probs.shape
-    counts = jnp.bincount(routing.experts.reshape(-1),
-                          length=e).astype(jnp.float32)
+    counts = _count_by_expert(routing.experts, e).astype(jnp.float32)
     p_mean = jnp.mean(routing.probs, axis=0)
     z = jnp.mean(jax.nn.logsumexp(routing.logits, axis=-1) ** 2)
     if axis_name is not None:
@@ -154,11 +189,9 @@ def sort_by_expert(experts, n_experts: int):
     (stable: within an expert, pairs keep their order), `inverse[p]` the
     sorted position of pair p, `group_sizes[i]` the number of pairs sent to
     expert i; they sum to T x k, so nothing is dropped."""
-    flat = experts.reshape(-1)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    order = jnp.argsort(experts.reshape(-1), stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
-    group_sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
-    return order, inverse, group_sizes
+    return order, inverse, _count_by_expert(experts, n_experts)
 
 
 # The three functions below move rows (or scalars) by a permutation of the
@@ -261,8 +294,8 @@ def sort_held(experts, first_expert: int, n_held: int):
     pairs = jnp.arange(local.shape[0], dtype=jnp.int32)
     keys, order = jax.lax.sort_key_val(local, pairs)
     inverse = jax.lax.sort_key_val(order, pairs)[1]
-    # counts without a scatter (a `bincount` of 65,536 pairs serialises on
-    # the TPU, PERF.md section 7): where each expert's run starts in the keys
+    # counts without a scatter (`_count_by_expert` says why), from the keys
+    # the sort has made anyway: where each expert's run starts in them
     starts = jnp.sum(keys[None, :] < jnp.arange(n_held + 1)[:, None], axis=1)
     return order, inverse, jnp.diff(starts).astype(jnp.int32)
 

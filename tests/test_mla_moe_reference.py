@@ -586,3 +586,6 @@ def test_counters_of_a_lowering():
     assert delta["moe.combine_slots"] == 2 * len(caps) * t * k
     assert delta["moe.combine_rows"] == 2 * sum(caps)
     assert delta["moe.experts"] == 2 * cfg.n_experts
+    # once a routed block on a share (the shares behind the load-balance
+    # term, which this model's loss leaves out: dead code in its program)
+    assert delta["moe.counts_by_comparison"] == 2

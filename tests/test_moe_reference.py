@@ -286,3 +286,179 @@ def test_bf16_program_against_float32_reference():
         params, {"inputs": inputs, "targets": targets}, cfg))
     want_loss = ref.loss(params, inputs, targets, model)
     assert abs(loss - want_loss) / want_loss < 6e-4, (loss, want_loss, msg)
+
+
+# What the router counts or picks out of [T, E] it does by comparison against
+# arange(E) and a dense reduction: a scatter (a `bincount`, autodiff's
+# transpose of `top_k`'s values) serialises on the TPU (PERF.md section 6,
+# PR 44).
+
+ROUTERS = {
+    "softmax": dict(score="softmax"),
+    "sigmoid_bias": dict(score="sigmoid", norm_topk_prob=True,
+                         weight_scale=2.5),
+    "grouped": dict(score="sigmoid", norm_topk_prob=True, weight_scale=2.5,
+                    n_group=4, topk_group=2),
+}
+
+
+@pytest.mark.parametrize("what", ["value", "gradient"])
+@pytest.mark.parametrize("router", list(ROUTERS))
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["all_held", "share"])
+def test_router_lowers_no_scatter(held, router, what):
+    """`moe_layer` and `router_losses`' two terms as a function of (x,
+    router_w, experts) alone, as jax lowers it: no `stablehlo.scatter`, in
+    the value or in the gradient, whichever way the experts are chosen."""
+    t, d, f, e, k = 32, 16, 8, 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
+    n_held = e if held is None else held[1]
+    experts = {"w_gate": jax.random.normal(ks[0], (n_held, d, f)) * d ** -0.5,
+               "w_up": jax.random.normal(ks[1], (n_held, d, f)) * d ** -0.5,
+               "w_down": jax.random.normal(ks[2], (n_held, f, d)) * f ** -0.5}
+    x = jax.random.normal(ks[3], (t, d))
+    router_w = jax.random.normal(ks[4], (d, e))
+    how = dict(ROUTERS[router], held=held)
+    if router != "softmax":
+        how["router_bias"] = 0.1 * jax.random.normal(ks[5], (e,))
+
+    def terms(x, router_w, experts):
+        y, aux = moe.moe_layer(x, router_w, experts, k, **how)
+        return jnp.sum(y ** 2) + 0.01 * aux.load_balance + 0.001 * aux.router_z
+
+    fn = terms if what == "value" else jax.grad(terms, argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(x, router_w, experts).as_text()
+    assert "stablehlo.scatter" not in text
+    # the program is there: the choice's sort, the count's compare
+    assert "chlo.top_k" in text or "stablehlo.sort" in text
+    assert "stablehlo.compare" in text
+
+
+def _choices(kind, t, k, e):
+    if kind == "random":
+        logits = jax.random.normal(jax.random.PRNGKey(t + k + e), (t, e))
+        return jax.lax.top_k(logits, k)[1]
+    if kind == "one_expert":        # every pair sent to ONE expert
+        return jnp.full((t, k), e - 2, jnp.int32)
+    # experts that get none: the choices come from the odd ones alone
+    logits = jax.random.normal(jax.random.PRNGKey(t + k + e), (t, e // 2))
+    return 2 * jax.lax.top_k(logits, k)[1] + 1
+
+
+@pytest.mark.parametrize("kind", ["random", "one_expert", "some_get_none"])
+@pytest.mark.parametrize("t,k,e", [(96, 2, 8), (40, 4, 64), (7, 1, 3)])
+def test_counts_are_bincounts(t, k, e, kind):
+    """`router_losses`' counts and `sort_by_expert`'s group sizes against
+    `np.bincount`: int32, exact, summing to T x k."""
+    if kind == "some_get_none" and e // 2 < k:
+        pytest.skip("fewer odd experts than choices")
+    experts = _choices(kind, t, k, e)
+    want = np.bincount(np.asarray(experts).reshape(-1), minlength=e)
+    if kind != "random":
+        assert (want == 0).sum() >= e // 2
+    counts = moe._count_by_expert(experts, e)
+    _, _, sizes = jax.jit(moe.sort_by_expert, static_argnums=1)(experts, e)
+    for got in (counts, sizes):
+        assert got.dtype == jnp.int32 and got.shape == (e,)
+        np.testing.assert_array_equal(got, want)
+        assert int(got.sum()) == t * k
+
+
+def test_router_losses_over_shards_equal_the_scattered_counts():
+    """Under `axis_name` in a `shard_map` over tokens: both terms are what
+    the whole batch gives on one device, and the load-balance term is the
+    one a `bincount` gives, to the last digit (the counts are integers)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    t, d, e, k, shards = 64, 16, 8, 2, 4
+    ks = jax.random.split(jax.random.PRNGKey(9), 2)
+    x = jax.random.normal(ks[0], (t, d))
+    router_w = jax.random.normal(ks[1], (d, e))
+    mesh = Mesh(np.array(jax.devices()[:shards]), ("ep",))
+
+    def old_losses(routing, axis_name=None):
+        counts = jnp.bincount(routing.experts.reshape(-1),
+                              length=e).astype(jnp.float32)
+        p_mean = jnp.mean(routing.probs, axis=0)
+        if axis_name is not None:
+            counts = jax.lax.pmean(counts, axis_name)
+            p_mean = jax.lax.pmean(p_mean, axis_name)
+        return e * jnp.sum(counts / (routing.probs.shape[0] * k) * p_mean)
+
+    def local(x_loc, router_w):
+        routing = moe.route(x_loc, router_w, k)
+        lb, rz = moe.router_losses(routing, "ep")
+        return lb, rz, old_losses(routing, "ep")
+
+    lb, rz, old_lb = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("ep"), P()), out_specs=(P(), P(), P()),
+        check_vma=False))(x, router_w)
+    assert float(lb) == float(old_lb)
+    whole = moe.route(x, router_w, k)
+    want_lb, want_rz = moe.router_losses(whole)
+    np.testing.assert_allclose(lb, want_lb, rtol=1e-6)
+    np.testing.assert_allclose(rz, want_rz, rtol=1e-6)
+    assert float(want_lb) == float(old_losses(whole))
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True],
+                         ids=["as_they_are", "normalised"])
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_route_weights_are_top_ks_values_and_gradient(score, norm_topk_prob):
+    """`route` takes the choice from `top_k` and the weights as a masked sum
+    over the experts: p plus exact zeros, and on the way back one
+    contribution a position. So the weights and d weights / d logits are
+    `==` those of `lax.top_k(probs, k)`'s values and its autodiff (a scatter
+    of T x k scalars), op by op on one device."""
+    t, e, k = 48, 16, 4
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    logits = 2.0 * jax.random.normal(ks[0], (t, e))
+    cotangent = jax.random.normal(ks[1], (t, k))
+    eye = jnp.eye(e)       # x @ I under `highest`: the logits themselves
+
+    def ours(logits):
+        return moe.route(logits, eye, k, norm_topk_prob, score=score).weights
+
+    def top_ks(logits):
+        probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        weights, _ = jax.lax.top_k(probs, k)
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return weights
+
+    np.testing.assert_array_equal(moe.route(logits, eye, k).logits, logits)
+    got, want = ours(logits), top_ks(logits)
+    assert got.dtype == want.dtype and bool(jnp.all(got == want))
+    grad = lambda fn: jax.grad(  # noqa: E731
+        lambda lg: jnp.sum(fn(lg) * cotangent))
+    got, want = grad(ours)(logits), grad(top_ks)(logits)
+    assert float(jnp.abs(want).max()) > 0
+    assert bool(jnp.all(got == want))
+    assert "scatter" in str(jax.make_jaxpr(grad(top_ks))(logits))
+    assert "scatter" not in str(jax.make_jaxpr(grad(ours))(logits))
+    # the barrier that keeps the k weights apart from their sum stands in
+    # the value alone: on a cotangent it would keep a share's router
+    # backward alive, on zeros (`moe._formed_first`)
+    assert str(jax.make_jaxpr(grad(ours))(logits)).count(
+        "optimization_barrier") == int(norm_topk_prob)
+
+
+@pytest.mark.parametrize("switches", [OLMOE, MIXTRAL], ids=["olmoe", "mixtral"])
+def test_counters_of_a_lowering(switches):
+    """What one lowering of the model's gradient counts (the scanned layers
+    lower once): a block that holds every expert counts by comparison at
+    two sites, the load-balance term's shares and the dispatch's groups."""
+    from ray_tpu._private import device_profiler
+
+    cfg, params, _ = _model(switches)
+    inputs, targets = _tokens(8)
+    before = dict(device_profiler.snapshot()["counters"])
+    jax.jit(jax.grad(lambda p: mixtral.loss_fn(
+        p, {"inputs": inputs, "targets": targets}, cfg))).lower(params)
+    after = device_profiler.snapshot()["counters"]
+    delta = {k: after.get(k, 0) - before.get(k, 0) for k in after}
+    assert delta["moe.counts_by_comparison"] == 2
+    assert delta["moe.rows_routed"] == inputs.size * cfg.experts_per_token
+    assert delta["moe.experts"] == cfg.n_experts
+    assert delta["moe.gmm_calls"] == 3
+    assert delta.get("moe.experts_held", 0) == 0

@@ -330,6 +330,7 @@ def test_lowering_counts_layers_by_kind():
     t_k = 2 * 24 * cfg.experts_per_token
     assert grew("moe.latent_rows") == 2 * t_k
     assert grew("moe.gmm_calls") == 2 * 2 and grew("moe.experts_held") == 8
+    assert grew("moe.counts_by_comparison") == 2  # once a routed block
 
 
 def test_param_axes_match_the_parameters():

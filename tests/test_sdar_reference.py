@@ -276,6 +276,9 @@ def test_counters_and_scopes_of_a_lowering():
     assert delta["moe.experts_held"] == 2
     assert delta["moe.rows_capacity"] == 2 * 2 * length * cfg.experts_per_token
     assert delta["moe.experts"] == cfg.n_experts
+    # a share counts by comparison at ONE site, the load-balance term's
+    # shares (`sort_held` has its groups from the sorted keys)
+    assert delta["moe.counts_by_comparison"] == 1
     text = lowered.as_text(debug_info=True)
     for scope in ("bd.noise", "bd.attend", "bd.loss"):
         assert scope in text, scope

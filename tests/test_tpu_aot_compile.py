@@ -58,6 +58,22 @@ def on_chip(tree):
     return jax.tree.map(lambda x: spec(x.shape, x.dtype), tree)
 
 
+def pair_scatters(hlo, pairs):
+    # the ` scatter(` instructions of a compiled program one of whose
+    # operands (the array scattered into, the indices, the updates) has
+    # `pairs` elements; an instruction's line names its operands only, so
+    # their shapes come from the lines that define them
+    elements, hits = {}, []
+    for ln in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", ln)
+        if m:
+            elements[m[1]] = int(np.prod([int(n) for n in m[2].split(",") if n]))
+        m = re.search(r" scatter\(([^)]*)\)", ln)
+        if m and pairs in [elements.get(a.strip()) for a in m[1].split(",")]:
+            hits.append(ln.strip()[:200])
+    return hits
+
+
 out = {"device_kind": topo.devices[0].device_kind}
 bf16 = jnp.bfloat16
 """
@@ -144,14 +160,23 @@ for name, (k, n) in (("gmm_up", (2048, 1024)), ("gmm_down", (1024, 2048))):
 
 
 def moe_layer_grads(x, router_w, experts):
-    # one layer as the cell's scan body runs it: under remat "dots"
+    # one layer as the cell's scan body runs it: under remat "dots", and
+    # WITH `router_losses`' two terms at the cell's coefficients, or the
+    # load-balance term's counts are dead code
+    def terms(x, router_w, experts):
+        y, aux = moe.moe_layer(x, router_w, experts, 8)
+        return y, aux.load_balance, aux.router_z
+
     layer = jax.checkpoint(
-        lambda x, router_w, experts: moe.moe_layer(x, router_w, experts, 8)[0],
-        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        terms, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+    def loss(*a):
+        y, load_balance, router_z = layer(*a)
+        return (y.astype(jnp.float32).sum() + 0.01 * load_balance
+                + 0.001 * router_z)
+
     # the value too, or the forward pass is dead code
-    return jax.value_and_grad(
-        lambda *a: layer(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(
-            x, router_w, experts)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, router_w, experts)
 
 
 # `moe_layer` too follows jax.default_backend() through `grouped_matmul`
@@ -165,9 +190,11 @@ hlo = jax.jit(moe_layer_grads).lower(
 out["moe_layer_custom_calls"] = hlo.count('custom_call_target="tpu_custom_call"')
 out["moe_layer_row_gathers"] = len(re.findall(
     r"= bf16\[65536,2048\]\S* gather\(", hlo))
-out["moe_layer_pair_scatters"] = [
-    ln.strip()[:200] for ln in hlo.splitlines()
-    if re.search(r" scatter\(", ln) and "[65536" in ln]
+out["moe_layer_pair_scatters"] = pair_scatters(hlo, 65536)
+# the count itself is there (forward, and again under remat): the compare
+# against the experts' numbers, summed over the pairs
+out["moe_layer_counts"] = len(re.findall(
+    r"= s32\[64\]\S* reduce\(.*op_name=\"[^\"]*reduce_sum", hlo))
 
 # train-4chip's step (forward + backward, 2 of its 11 layers, its widths
 # and batch) over fsdp 2 x tp 2 of the described 2x2. Off the chip flash
@@ -257,6 +284,33 @@ ops = [ln.strip() for ln in hlo.splitlines()]
 out["sdar_custom_calls"] = sum(
     1 for ln in ops if 'custom_call_target="tpu_custom_call"' in ln)
 out["sdar_dense"] = [ln[:160] for ln in ops if dense.search(ln)]
+# T x k = 131,072 (token, slot) pairs a routed block
+out["sdar_pair_scatters"] = pair_scatters(hlo, 131072)
+
+
+def folded_denominators(hlo):
+    # `route`'s sum over k of its weights folded into their masked sum over
+    # the experts: ONE reduce of the [T, k, E] select over both axes
+    return [ln.strip()[:120] for ln in hlo.splitlines() if re.search(
+        r"= f32\[16384\]\S* reduce\(.*dimensions=\{1,2\}", ln)]
+
+
+def route_weights(x, w):
+    return moe.route(x, w, 8, True).weights
+
+
+# what `moe._formed_first` is there for: absent from the objective, absent
+# from `route` alone at the cell's shapes, and there again with the barrier
+# taken out (a compiler that stops folding leaves the barrier dead weight)
+route_args = (spec((16384, 2048), bf16), spec((2048, 128), bf16))
+out["sdar_folded_denominators"] = folded_denominators(hlo)
+out["route_folded_denominators"] = folded_denominators(
+    jax.jit(route_weights).lower(*route_args).compile().as_text())
+formed_first, moe._formed_first = moe._formed_first, lambda weights: weights
+out["route_folded_denominators_without_the_barrier"] = folded_denominators(
+    jax.jit(lambda x, w: route_weights(x, w)).lower(
+        *route_args).compile().as_text())
+moe._formed_first = formed_first
 for name in ("bd_flash_fwd_roofline", "bd_flash_bwd_roofline",
              "bd_attention_time_share"):
     with open(os.path.join(os.environ["REPO_ROOT"], "benchmarks", "metrics",
@@ -506,14 +560,44 @@ def test_tp_boundary_is_not_an_all_reduce_for_v5e_2x2(compiled):
 
 def test_moe_layer_backward_as_compiled_for_v5e(compiled):
     """The gradient of one `moe_layer` under remat "dots" at
-    train-olmoe-1chip's shapes, as the v5e's compiler leaves it: 11 Pallas
+    train-olmoe-1chip's shapes, `router_losses`' two terms in the loss as
+    the cell's step has them, as the v5e's compiler leaves it: 11 Pallas
     calls (3 forward, gate and up recomputed, 6 backward; 12 when the top-k
     weights were applied after the down projection and the recomputation
-    reran it), 5 gathers of [65536, 2048] rows (6 then), and no scatter
-    over the 65,536 pairs: no gather is left to autodiff to transpose."""
+    reran it) and 5 gathers of [65536, 2048] rows (6 then)."""
     assert compiled["moe_layer_custom_calls"] == 11
     assert compiled["moe_layer_row_gathers"] == 5
+
+
+def test_router_as_compiled_for_v5e_scatters_no_pair(compiled):
+    """That gradient and train-sdar-1chip's compiled objective: no scatter
+    reads or writes an array of the T x k (token, slot) pairs, so no gather
+    is left to autodiff to transpose and nothing is counted by a scatter.
+    Two `bincount`s of 65,536 pairs a forward (0.57 ms each on the v5e;
+    1.15 ms for SDAR's 131,072) and the transpose of `top_k`'s values
+    (0.44 ms) stood here until PR 44, unseen while this file compiled the
+    layer without its loss terms: the counts were dead code (PERF.md
+    section 6). What is left scatters a few hundred scalars of the grouped
+    matmuls' plans."""
     assert compiled["moe_layer_pair_scatters"] == []
+    assert compiled["moe_layer_counts"] >= 1
+    assert compiled["sdar_pair_scatters"] == []
+
+
+def test_route_denominators_as_compiled_for_v5e(compiled):
+    """`moe._formed_first` and the fold it exists for, tied together. Left
+    to itself the v5e's compiler makes ONE reduce over `[16384, 8 x 128]`
+    of `route`'s masked sum over the experts and `norm_topk_prob`'s sum over
+    k, the denominators as a second output: 0.417 ms a call on the chip
+    where the two reduces take 0.09 (10 ms of train-sdar-1chip's step,
+    PERF.md section 6, PR 44), in another order of summation than `top_k`'s
+    values had. With the barrier no such reduce is in `route` alone nor in
+    the cell's compiled objective. Without it the fold is back: the day a
+    compiler stops folding, the last assertion fails and the barrier goes;
+    the day one folds through the barrier, the first two do."""
+    assert compiled["sdar_folded_denominators"] == []
+    assert compiled["route_folded_denominators"] == []
+    assert len(compiled["route_folded_denominators_without_the_barrier"]) == 1
 
 
 def test_flash_under_the_block_diffusion_rule_compiles_for_v5e(compiled):
