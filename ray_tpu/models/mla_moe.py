@@ -44,6 +44,7 @@ from ray_tpu._private import device_profiler
 from ray_tpu.models import llama
 from ray_tpu.models.llama import _remat_policy, _residual, _rms_norm, _rope
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES, flash_attention
+from ray_tpu.parallel import moe
 from ray_tpu.parallel.moe import moe_layer
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
@@ -541,3 +542,14 @@ def routing_stats(params, tokens, config: MlaMoeConfig):
     local = chosen - c.first_expert
     return jnp.sum((local >= 0) & (local < c.n_experts_held), axis=(1, 2),
                    dtype=jnp.int32)
+
+
+def routing_loads(params, tokens, config: MlaMoeConfig):
+    """-> float32, as `routing_stats`: each block's live rows over the rows
+    of the capacity it runs at (`moe.capacity_load`): what of its buffer
+    the row moves visit."""
+    c = config
+    rows = tokens.shape[0] * (tokens.shape[1] - 1)
+    return moe.capacity_load(
+        routing_stats(params, tokens, c), moe.share_capacities(
+            rows, c.experts_per_token, c.n_experts_held, c.n_experts))

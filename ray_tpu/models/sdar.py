@@ -42,6 +42,7 @@ from ray_tpu.models import llama, mixtral
 from ray_tpu.models.llama import _rms_norm
 from ray_tpu.models.mixtral import MixtralConfig
 from ray_tpu.ops.flash_attention import BlockDiffusion
+from ray_tpu.parallel import moe
 from ray_tpu.parallel.sharding import LogicalAxisRules
 
 
@@ -235,3 +236,15 @@ def routing_stats(params, tokens, config: SdarConfig):
     local = aux.experts - first
     return jnp.sum((local >= 0) & (local < n_held), axis=(1, 2),
                    dtype=jnp.int32)
+
+
+def routing_loads(params, tokens, config: SdarConfig):
+    """-> float32 [n_layers]: each layer's live rows over the rows of the
+    capacity it runs at (`moe.capacity_load`): what of its buffer the row
+    moves visit."""
+    c = config
+    rows = 2 * tokens.shape[0] * (tokens.shape[1] - 1)
+    _, n_held = c.held or (0, c.n_experts)
+    return moe.capacity_load(
+        routing_stats(params, tokens, c), moe.share_capacities(
+            rows, c.experts_per_token, n_held, c.n_experts))

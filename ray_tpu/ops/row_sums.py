@@ -1,8 +1,8 @@
 """Sum the rows of a buffer into their tokens: the combine of a share.
 
-`sum_rows_by_token(rows [cap, D], token [cap], slot [T, k])` adds up, for
-each of T tokens, the rows of `rows` that belong to it. `token[s]` is the
-token of row s, or T for a DEAD row (one past the rows that landed here);
+`sum_rows_by_token(rows [cap, D], token [cap], slot [T, k], live)` adds
+up, for each of T tokens, the rows of `rows` that belong to it. `token[s]` is
+the token of row s, or T for a DEAD row (the rows from `live` on);
 `slot[t, j]` is the row of token t's j-th pair, or a dead row where that
 pair's expert is absent. Dead rows are read by neither form below, so they
 may hold anything (the grouped matmuls leave them unwritten).
@@ -10,9 +10,11 @@ may hold anything (the grouped matmuls leave them unwritten).
 A chip that holds 1/8 of the experts sees one live pair a token on average
 where `slot` has k = 8: gathering `rows[slot]` moves T x k rows to add up
 the ~T that landed (`fusion_bf16_131072_2048`, 13.1% of train-sdar-1chip's
-step: PERF.md section 5, PR 34). On a TPU the work follows the `cap` rows
+step: PERF.md section 5, PR 34). On a TPU the work follows the LIVE rows
 instead: a sort of the `cap` token ids brings the live rows into token
-order, one gather of `cap` rows moves them, and each tile of `_TOKEN_TILE`
+order, first; a gather of the row tiles that hold one moves them
+(`ops/row_moves.py`, told `live`, the number of live rows: a plain gather
+of `cap` rows until PR 45), and each tile of `_TOKEN_TILE`
 tokens, whose rows are then one contiguous range, is summed on the MXU as
 onehot^T x rows by the megablox `tgmm` kernel `grouped_matmul.py` wraps
 (groups = token tiles, no scatter; a tile with no row comes out zero, rows
@@ -31,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.grouped_matmul import _clamp, _megablox, _pad_rows
+from ray_tpu.ops.row_moves import take_live_rows
 
 # tokens a group: the one-hot's width, and the rows of an output tile
 _TOKEN_TILE = 256
@@ -41,7 +44,7 @@ _ROW_TILE, _COLUMN_TILE = 256, 2048
 
 
 @partial(jax.jit, static_argnames=("t", "interpret"))
-def _sum_in_token_order(rows, token, t: int, interpret=False):
+def _sum_in_token_order(rows, token, live, t: int, interpret=False):
     """-> [T, D] in rows.dtype. `interpret` runs the kernel in the Pallas
     interpreter (the CPU tests). Jitted as megablox's own entry points are:
     lowering a step traces each capacity's combine four times (the
@@ -53,7 +56,7 @@ def _sum_in_token_order(rows, token, t: int, interpret=False):
     # live rows first, by token; dead rows (token T) last
     token_sorted, perm = jax.lax.sort_key_val(
         token, jnp.arange(cap, dtype=jnp.int32))
-    by_token = rows[perm]
+    by_token = take_live_rows(rows, perm, live)
     # rows of each token tile without a scatter, as `sort_held` counts its
     # groups: where each tile's run starts among the sorted tokens
     bounds = jnp.minimum(jnp.arange(n_tiles + 1) * tile, t)
@@ -68,13 +71,13 @@ def _sum_in_token_order(rows, token, t: int, interpret=False):
     return sums.reshape(n_tiles * tile, d)[:t]
 
 
-def sum_rows_by_token(rows, token, slot):
+def sum_rows_by_token(rows, token, slot, live):
     """-> [T, D]: the sum of each token's live rows, accumulated in float32,
     in rows.dtype. Which form runs is read off the platform and the dtype,
     as `grouped_matmul` picks its kernels."""
     t = slot.shape[0]
     if jax.default_backend() == "tpu" and rows.dtype == jnp.bfloat16:
-        return _sum_in_token_order(rows, token, t)
+        return _sum_in_token_order(rows, token, live, t)
     picked = jnp.where((token[slot] < t)[..., None],
                        rows[slot].astype(jnp.float32), 0.0)
     return jnp.sum(picked, axis=1).astype(rows.dtype)

@@ -18,7 +18,8 @@ serve both entry points:
   routes over ALL the router's experts and computes only the (token, slot)
   pairs whose expert is held (`_held_experts`): what the absent experts
   would add is left out, nothing that lands here is dropped, and device
-  time follows the rows that landed.
+  time follows the rows that landed: the grouped matmuls' and the row
+  moves' to a tile of rows, the elementwise work to a factor of two.
 - `moe_shard_map`: experts sharded over the `ep` mesh axis, token buffers
   exchanged with `lax.all_to_all`. The exchange needs a static buffer, so
   this path alone is capacity-bounded ([T, E, C] dispatch and combine
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
 from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.row_moves import row_tiles, take_live_rows
 from ray_tpu.ops.row_sums import sum_rows_by_token
 
 
@@ -270,8 +272,11 @@ def share_capacities(t: int, k: int, n_held: int, n_experts: int) -> tuple:
     ~4,000 blocks of sixteen seeds on the v5e), and 1-4% of a seed's blocks
     run the next capacity (PERF.md section 6, PR 32, on why not 1.25x or
     2.5x, measured while the combine still gathered T x k rows out of the
-    buffer; whether 1.5x pays now is open, section 7). Holding every expert
-    gives (T x k,)."""
+    buffer). Since PR 45 a capacity sizes the buffers and the elementwise
+    work over `[cap, F]` only: the row moves follow the live rows a tile at a
+    time (`ops/row_moves.py`), as the grouped matmuls do, so what 1.5x could
+    still save is a quarter of that elementwise work, for one more branch to
+    trace, lower and load (section 7). Holding every expert gives (T x k,)."""
     rows = t * k
     cap = -(-2 * (rows * n_held // n_experts) // 256) * 256
     caps = []
@@ -279,6 +284,18 @@ def share_capacities(t: int, k: int, n_held: int, n_experts: int) -> tuple:
         caps.append(cap)
         cap *= 2
     return tuple(caps) + (rows,)
+
+
+def capacity_load(live, caps):
+    """live [...] int32, the live rows of routed blocks -> float32 [...]:
+    each block's live rows over the rows of the capacity of `caps` it runs
+    at (`_capacity_switch`'s choice). That is the share of the buffer's row
+    tiles its row moves visit, to a tile (`ops/row_moves.py`); the rest of
+    the buffer is sized and masked, not moved. Outside the train step, for
+    `routing_loads` of the models."""
+    caps = jnp.asarray(caps)
+    index = jnp.sum(live[..., None] >= caps[:-1], axis=-1)
+    return live / caps[index]
 
 
 def sort_held(experts, first_expert: int, n_held: int):
@@ -302,43 +319,53 @@ def sort_held(experts, first_expert: int, n_held: int):
 
 # A share's two moves of rows, each the other's transpose (as `_permute` and
 # `_combine` are for the whole dispatch): `token[s]` is the token of sorted
-# row s, or T for a dead row (past the live ones); `slot[t, j]` the row of
+# row s, or T for a dead row (past the `live` ones); `slot[t, j]` the row of
 # token t's j-th pair, or a dead row for a pair whose expert is absent.
-# Both follow the buffer's rows, not the T x k slots, and neither reads a
-# dead row (`ops/row_sums.py`; a scatter-add of the live rows into their
-# tokens: 1.83 ms for 16,384 rows of 2,048, PERF.md section 6, PR 32).
+# Both follow the buffer's LIVE rows, not its `cap` rows nor the T x k slots
+# (`ops/row_moves.py`, `ops/row_sums.py`; a scatter-add of the live rows into
+# their tokens: 1.83 ms for 16,384 rows of 2,048, PERF.md section 6, PR 32).
+# No fill is made for a dead row of `_take_rows`' result: up to the end of
+# the last live row tile it holds some token's row (the dead marker clamped
+# into range: finite), past it what `ops/row_moves.py` leaves, zeros or, on
+# a TPU, memory nothing has written. Three readers, and who may read an
+# unwritten tile is nobody: the grouped matmuls' `gmm` visits live tiles
+# only and `_held_rows` masks its gate and up with `valid`; the weights'
+# gradient `tgmm` visits live tiles only and masks a tile's rows outside
+# their group; `_sum_rows`, on the way back, reads no dead row.
 
 @jax.custom_vjp
-def _take_rows(x, token, slot):
-    """x [T, D] -> [cap, D]: row s is token `token[s]`, zeros if dead."""
-    return x.at[token].get(mode="fill", fill_value=0)
+def _take_rows(x, token, slot, live):
+    """x [T, D] -> [cap, D]: row s is token `token[s]`; where dead, any
+    token's row (finite) up to the last live row tile's end and nothing of
+    ours past it: read by nothing that is not masked."""
+    return take_live_rows(x, jnp.minimum(token, x.shape[0] - 1), live)
 
 
-def _take_rows_fwd(x, token, slot):
-    return _take_rows(x, token, slot), (token, slot)
+def _take_rows_fwd(x, token, slot, live):
+    return _take_rows(x, token, slot, live), (token, slot, live)
 
 
 def _take_rows_bwd(res, g):
     # the grouped matmuls' backward leaves g's dead rows unwritten
-    return _sum_rows(g, *res), None, None
+    return _sum_rows(g, *res), None, None, None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 @jax.custom_vjp
-def _sum_rows(rows, token, slot):
+def _sum_rows(rows, token, slot, live):
     """rows [cap, D], anything where dead -> [T, D]: the sum of each token's
     live rows, accumulated in float32, in rows.dtype."""
-    return sum_rows_by_token(rows, token, slot)
+    return sum_rows_by_token(rows, token, slot, live)
 
 
-def _sum_rows_fwd(rows, token, slot):
-    return _sum_rows(rows, token, slot), (token, slot)
+def _sum_rows_fwd(rows, token, slot, live):
+    return _sum_rows(rows, token, slot, live), (token, slot, live)
 
 
 def _sum_rows_bwd(res, g):
-    return _take_rows(g, *res), None, None
+    return _take_rows(g, *res), None, None, None
 
 
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
@@ -369,7 +396,8 @@ def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
     `_capacity_switch` would not have chosen it) through the held experts'
     SwiGLU, summed back into their tokens -> [T, D] in x.dtype. Plain ops,
     differentiable in x and the experts' weights: rows past the live ones
-    read no token, and the kernels visit live tiles only and leave the rest
+    hold no token's pair (`_take_rows` says what they do hold), and the
+    kernels visit live tiles only and leave the rest
     unwritten. gate and up are masked there, so that h and, on the way
     back, their gradients are zero where dead (the weights' gradients read
     them); the down projection's result is read by `_sum_rows` alone."""
@@ -385,13 +413,13 @@ def _held_rows(x, experts, weights, order, inverse, group_sizes, k: int,
                          jnp.zeros((), lhs.dtype))
 
     with jax.named_scope("moe.permute"):
-        rows = _take_rows(x, token, slot)
+        rows = _take_rows(x, token, slot, live)
         w_sorted = weights.reshape(-1)[pair]  # dead rows: gate, up are 0
     with jax.named_scope("moe.experts"):
         h = _expert_hidden(rows, experts, w_sorted, form, gmm)
         out = grouped_matmul(h, experts["w_down"], group_sizes)
     with jax.named_scope("moe.combine"):
-        return _sum_rows(out, token, slot)
+        return _sum_rows(out, token, slot, live)
 
 
 def _capacity_switch(caps, group_sizes, branch, *operands):
@@ -410,9 +438,9 @@ def _capacity_switch(caps, group_sizes, branch, *operands):
 def _held_experts(x, experts, weights, order, inverse, group_sizes, k, caps,
                   form):
     """The routed part of a share: `_held_rows` at the smallest of the
-    static capacities `caps` that holds this step's live rows, so buffers,
-    gathers and elementwise work are sized by what landed here (to a factor
-    of two) and the grouped matmuls visit live tiles only.
+    static capacities `caps` that holds this step's live rows, so buffers
+    and elementwise work are sized by what landed here (to a factor of two)
+    and the grouped matmuls and the row moves visit live tiles only.
 
     A `custom_vjp` because of the switch: differentiated by jax, every
     branch would write zeros for every other branch's residuals on each
@@ -514,6 +542,11 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
         device_profiler.count("moe.rows_capacity", caps[-1])
         device_profiler.count("moe.combine_slots", t * k * len(caps))
         device_profiler.count("moe.combine_rows", sum(caps))
+        for tiles in map(row_tiles, caps):  # which buffers' moves loop
+            if tiles > 1:
+                device_profiler.count("moe.row_moves_tiled", tiles)
+            else:
+                device_profiler.count("moe.row_moves_whole", 1)
     else:
         with jax.named_scope("moe.permute"):
             order, inverse, group_sizes = sort_by_expert(routing.experts, e)

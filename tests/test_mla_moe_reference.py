@@ -17,7 +17,7 @@ import pytest
 from benchmarks import reference_joyai as ref
 from ray_tpu._private import device_profiler
 from ray_tpu.models import mixtral, mla_moe
-from ray_tpu.ops import row_sums
+from ray_tpu.ops import row_moves, row_sums
 from ray_tpu.ops.flash_attention import _reference_attention, flash_attention
 from ray_tpu.parallel import moe
 
@@ -213,9 +213,9 @@ def test_nothing_is_dropped_at_either_extreme(where):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-4)
 
 
-def _in_token_order(rows, token, slot):
+def _in_token_order(rows, token, slot, live):
     """`ops/row_sums.py`'s TPU form, in the Pallas interpreter."""
-    return row_sums._sum_in_token_order(rows, token, slot.shape[0],
+    return row_sums._sum_in_token_order(rows, token, live, slot.shape[0],
                                        interpret=True)
 
 
@@ -284,7 +284,7 @@ def test_sum_rows_in_token_order_is_the_gather_and_sum(where, shape, dtype):
             dtype)[slot].astype(jnp.float32), axis=1).astype(dtype)
         rows[live:] = np.nan
         rows = jnp.asarray(rows, dtype)
-        got = _in_token_order(rows, token, slot)
+        got = _in_token_order(rows, token, slot, jnp.int32(live))
         assert got.shape == (t, d) and got.dtype == dtype
         assert not np.isnan(np.asarray(got, np.float32)).any()
         # float32 sums in another order; bf16: the same sum rounded once
@@ -293,8 +293,114 @@ def test_sum_rows_in_token_order_is_the_gather_and_sum(where, shape, dtype):
             rtol=2e-6 if dtype == jnp.float32 else 2.0 ** -7, atol=1e-6)
         # and the form every other backend runs
         np.testing.assert_array_equal(
-            np.asarray(row_sums.sum_rows_by_token(rows, token, slot),
+            np.asarray(row_sums.sum_rows_by_token(rows, token, slot, live),
                        np.float32), np.asarray(want, np.float32))
+
+
+# a buffer of two and a half of `ops/row_moves.py`'s row tiles, so that its
+# last tile starts early, over T x k = cap slots; k = 2: a sum of two rows
+# is the same in either order, so the two forms agree bit for bit
+_MOVE_TILE = row_moves._ROW_TILE
+_MOVE_T, _MOVE_K, _MOVE_D = _MOVE_TILE * 5 // 4, 2, 128
+_MOVE_CAP = _MOVE_T * _MOVE_K
+
+
+@pytest.mark.parametrize("form", ["xla", "tpu"])
+@pytest.mark.parametrize("live", [0, 1, _MOVE_TILE - 1, _MOVE_TILE,
+                                  _MOVE_TILE + 1, _MOVE_CAP - 1])
+def test_a_shares_row_moves_visit_live_row_tiles_only(live, form, monkeypatch):
+    """`_take_rows` and `_sum_rows` (in token order, the TPU's form in the
+    Pallas interpreter), each the other's transpose, at the edges of
+    `take_live_rows`' loop over row tiles: no live row, one, a tile less
+    one, a tile, a tile and one, all but the dead row that absent pairs
+    point at. Result and gradient against the plain `x[token]` and
+    `rows[slot]`, bit for bit on the live rows; NaN in the dead rows of
+    what `_sum_rows` reads; dead rows of what `_take_rows` makes finite up
+    to the last live tile's end, and past it zero in the form every backend
+    runs, unwritten in the TPU's (its two Pallas calls in the interpreter,
+    where unwritten reads NaN)."""
+    assert row_moves.row_tiles(_MOVE_CAP) == 3
+    t, k, d, cap = _MOVE_T, _MOVE_K, _MOVE_D, _MOVE_CAP
+    monkeypatch.setattr(moe, "sum_rows_by_token", _in_token_order)
+    if form == "tpu":
+        monkeypatch.setattr(row_moves, "_buffer", partial(
+            row_moves._unwritten, interpret=True))
+        monkeypatch.setattr(row_moves, "_placed", partial(
+            row_moves._copied_in, interpret=True))
+    rng = np.random.default_rng(live)
+    pairs = rng.choice(t * k, live, replace=False)
+    token = jnp.asarray(np.concatenate(
+        [pairs // k, np.full(cap - live, t)]), jnp.int32)
+    slot = np.full(t * k, cap - 1)
+    slot[pairs] = np.arange(live)
+    slot = jnp.asarray(slot.reshape(t, k), jnp.int32)
+    n = jnp.int32(live)
+    bits = lambda a: np.asarray(a).view(np.uint16)  # noqa: E731
+    key_x, key_rows = jax.random.split(jax.random.PRNGKey(live))
+    x = jax.random.normal(key_x, (t, d), jnp.bfloat16)
+    rows = jax.random.normal(key_rows, (cap, d), jnp.bfloat16)
+    dirty = rows.at[live:].set(jnp.nan)
+    clean = rows.at[live:].set(0)
+
+    # the forward moves, and each one's gradient, which is the other move
+    taken, take_vjp = jax.vjp(lambda x: moe._take_rows(x, token, slot, n), x)
+    summed, sum_vjp = jax.vjp(
+        lambda r: moe._sum_rows(r, token, slot, n), dirty)
+    (d_x,), (d_rows,) = take_vjp(dirty), sum_vjp(x)
+    for got in (taken, d_rows):
+        np.testing.assert_array_equal(bits(got[:live]),
+                                      bits(x[token[:live]]))
+        past = -(-live // _MOVE_TILE) * _MOVE_TILE
+        assert np.isfinite(np.asarray(got[:past], np.float32)).all()
+        if form == "xla":
+            assert not np.asarray(got[past:], np.float32).any()
+        elif live < cap - _MOVE_TILE:   # or the last tile, started early
+            assert np.isnan(np.asarray(got[past:], np.float32)).all()
+    want = jnp.sum(clean[slot].astype(jnp.float32), axis=1).astype(rows.dtype)
+    for got in (summed, d_x):
+        np.testing.assert_array_equal(bits(got), bits(want))
+    # and the form every other backend sums in
+    np.testing.assert_array_equal(
+        bits(row_sums.sum_rows_by_token(dirty, token, slot, n)), bits(want))
+
+
+@pytest.mark.parametrize("where", ["none_held", "even", "heavy"])
+def test_what_a_dead_row_holds_reaches_no_live_value(where, monkeypatch):
+    """A share's layer with the dead rows of what `_take_rows` hands the
+    grouped matmuls set to the largest finite value: output and gradients
+    to x and the experts are the same bits as with what it makes itself
+    (some token's row, or zeros), at both capacities."""
+    cfg, params, _ = _model(SHARE)
+    p = jax.tree.map(lambda a: a[0], params["layers"])
+    bias = p["router_bias"].at[4:8].add(
+        {"none_held": -10.0, "even": 0.0, "heavy": 0.2}[where])
+    t, k = 512, cfg.experts_per_token
+    h = jax.random.normal(jax.random.PRNGKey(11), (t, cfg.d_model))
+
+    def value_and_grads():
+        def program(h, experts):
+            return moe.moe_layer(
+                h, p["router"], experts, k, cfg.norm_topk_prob,
+                score="sigmoid", router_bias=bias, held=(4, 4))[0]
+        return jax.jit(jax.value_and_grad(
+            lambda h, e: jnp.sum(program(h, e) ** 2), argnums=(0, 1)))(
+                h, p["experts"])
+
+    want = value_and_grads()
+    take, shapes = moe._take_rows, []
+
+    def take_and_dirty(x, token, slot, live):
+        rows = take(x, token, slot, live)
+        shapes.append(rows.shape[0])
+        return jnp.where((jnp.arange(rows.shape[0]) >= live)[:, None],
+                         jnp.finfo(rows.dtype).max, rows)
+
+    monkeypatch.setattr(moe, "_take_rows", take_and_dirty)
+    got = value_and_grads()
+    assert set(shapes) == {1024, 2048}
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("where", ["all_held", "none_held", "even", "heavy"])
@@ -305,9 +411,9 @@ def test_nothing_is_dropped_through_the_token_order_combine(where, monkeypatch):
     `_take_rows`' transpose)."""
     calls = []
 
-    def combine(rows, token, slot):
+    def combine(rows, token, slot, live):
         calls.append(rows.shape)
-        return _in_token_order(rows, token, slot)
+        return _in_token_order(rows, token, slot, live)
 
     monkeypatch.setattr(moe, "sum_rows_by_token", combine)
     test_nothing_is_dropped_at_either_extreme(where)
@@ -323,6 +429,12 @@ def test_routing_stats_counts_the_held_pairs_of_every_layer():
     assert len(want) == cfg.n_layers - cfg.n_dense_layers + cfg.mtp_depth
     np.testing.assert_array_equal(
         mla_moe.routing_stats(params, toks, cfg), want)
+    # and over the rows of the capacity each block runs at
+    caps = np.asarray(moe.share_capacities(
+        2 * 24, cfg.experts_per_token, 4, cfg.n_experts))
+    np.testing.assert_allclose(
+        mla_moe.routing_loads(params, toks, cfg),
+        [rows / caps[np.sum(rows >= caps[:-1])] for rows in want], rtol=1e-6)
 
 
 def test_seeded_weights_have_the_scales_the_cell_counts_on():
@@ -589,3 +701,29 @@ def test_counters_of_a_lowering():
     # once a routed block on a share (the shares behind the load-balance
     # term, which this model's loss leaves out: dead code in its program)
     assert delta["moe.counts_by_comparison"] == 2
+
+
+@pytest.mark.parametrize("t, tiled, whole", [(4096, 2 + 4, 0), (512, 0, 2)],
+                         ids=["tiled", "whole"])
+def test_counters_say_which_buffers_row_moves_lowered_as_a_loop(
+        t, tiled, whole):
+    """k 4, 4 of 16 experts held: capacities 2 t and 4 t rows. At 4,096
+    tokens they are two and four of `ops/row_moves.py`'s row tiles, at 512
+    each is less than one and its gathers are plain, by the buffer's shape."""
+    k, e, n_held, d, f = 4, 16, 4, 16, 8
+    caps = moe.share_capacities(t, k, n_held, e)
+    assert caps == (2 * t, 4 * t)
+    assert [row_moves.row_tiles(c) for c in caps] == (
+        [2, 4] if tiled else [1, 1])
+    shape = jax.ShapeDtypeStruct
+    before = dict(device_profiler.snapshot()["counters"])
+    jax.jit(lambda x, r, w: moe.moe_layer(
+        x, r, w, k, held=(4, n_held))[0]).lower(
+            shape((t, d), jnp.float32), shape((d, e), jnp.float32),
+            {"w_gate": shape((n_held, d, f), jnp.float32),
+             "w_up": shape((n_held, d, f), jnp.float32),
+             "w_down": shape((n_held, f, d), jnp.float32)})
+    after = device_profiler.snapshot()["counters"]
+    delta = {k: after.get(k, 0) - before.get(k, 0) for k in after}
+    assert delta.get("moe.row_moves_tiled", 0) == tiled
+    assert delta.get("moe.row_moves_whole", 0) == whole

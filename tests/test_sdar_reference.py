@@ -241,6 +241,12 @@ def test_routing_stats_counts_the_held_pairs_of_every_layer():
     want = ((chosen >= first) & (chosen < first + n)).sum(axis=(1, 2))
     np.testing.assert_array_equal(live, want)
     assert chosen.shape == (cfg.n_layers, 3 * 2 * 24, cfg.experts_per_token)
+    # and over the rows of the capacity each layer runs at
+    caps = np.asarray(moe.share_capacities(
+        3 * 2 * 24, cfg.experts_per_token, n, cfg.n_experts))
+    np.testing.assert_allclose(
+        sdar.routing_loads(params, toks, cfg),
+        [rows / caps[np.sum(rows >= caps[:-1])] for rows in want], rtol=1e-6)
 
 
 def test_seeded_weights_have_the_scales_the_cell_counts_on():

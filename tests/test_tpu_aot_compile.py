@@ -28,6 +28,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _HEAD = r"""
 import dataclasses
 import functools
+import hashlib
 import json
 import os
 import re
@@ -39,7 +40,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
 from ray_tpu.models import llama, mla_moe, sdar
-from ray_tpu.ops import grouped_matmul, row_sums
+from ray_tpu.ops import grouped_matmul, row_moves, row_sums
 from ray_tpu.ops.flash_attention import (
     BlockDiffusion, block_schedule, flash_attention)
 from ray_tpu.parallel import moe
@@ -72,6 +73,55 @@ def pair_scatters(hlo, pairs):
         if m and pairs in [elements.get(a.strip()) for a in m[1].split(",")]:
             hits.append(ln.strip()[:200])
     return hits
+
+
+def computations_of(hlo):
+    # the instructions of each computation of a compiled program, by name
+    computations, name = {}, None
+    for ln in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if m:
+            name = m[1]
+            computations[name] = []
+        elif ln.startswith("}"):
+            name = None
+        elif name:
+            computations[name].append(ln)
+    return computations
+
+
+def called_as(computations):
+    # computation -> the ways every computation above it is called, up to
+    # the entry: "body" of a while, "branch_computations" of a conditional,
+    # "calls" of a fusion
+    above = {}
+    for caller, lines in computations.items():
+        for ln in lines:
+            for how, names in re.findall(
+                    r"(calls|body|condition|to_apply|branch_computations)="
+                    r"\{?((?:%[\w.\-]+(?:, )?)+)", ln):
+                for name in re.findall(r"%([\w.\-]+)", names):
+                    above.setdefault(name, set()).add((caller, how))
+
+    def ways(name, seen=()):
+        return {how for caller, how in above.get(name, ())
+                if caller not in seen} | {
+            w for caller, _ in above.get(name, ()) if caller not in seen
+            for w in ways(caller, seen + (name,))}
+    return ways
+
+
+def same_program(hlo):
+    # a compiled program's text less what names the checkout and the lines
+    # of its sources: the tables of files and stack frames above the first
+    # computation, each instruction's metadata, a Pallas call's payload
+    lines = hlo.splitlines()
+    start = next(n for n, ln in enumerate(lines)
+                 if re.match(r"^(ENTRY )?%[\w.\-]+ \(", ln))
+    return hashlib.sha256("\n".join(
+        re.sub(r'(custom_call_target="tpu_custom_call").*', r"\1",
+               re.sub(r", metadata=\{[^}]*\}", "", ln))
+        for ln in lines[:1] + lines[start:]).encode()).hexdigest()
 
 
 out = {"device_kind": topo.devices[0].device_kind}
@@ -186,6 +236,7 @@ hlo = jax.jit(moe_layer_grads).lower(
     {"w_gate": spec((64, 2048, 1024), bf16),
      "w_up": spec((64, 2048, 1024), bf16),
      "w_down": spec((64, 1024, 2048), bf16)}).compile().as_text()
+out["moe_layer_program"] = same_program(hlo)
 # the OPTIMIZED program: after the compiler's own dead-code removal
 out["moe_layer_custom_calls"] = hlo.count('custom_call_target="tpu_custom_call"')
 out["moe_layer_row_gathers"] = len(re.findall(
@@ -215,16 +266,7 @@ hlo = jax.jit(jax.value_and_grad(
         params, {"inputs": tokens, "targets": tokens}).compile().as_text()
 # the top-level instructions of every `while` body: the scanned layers'
 # forward and backward (and chunked_ce's two)
-computations, name = {}, None
-for ln in hlo.splitlines():
-    m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
-    if m:
-        name = m[1]
-        computations[name] = []
-    elif ln.startswith("}"):
-        name = None
-    elif name:
-        computations[name].append(ln)
+computations = computations_of(hlo)
 in_loops = [ln for lines in list(computations.values()) for w in lines
             for body in re.findall(r" while\(.*body=%([\w.\-]+)", w)
             for ln in computations[body]]
@@ -269,8 +311,9 @@ out["flash_bd"] = "compiled"
 # compiler leaves it. `llama._flash` too follows jax.default_backend(), and
 # so does a share's combine (`ops/row_sums.py`)
 llama.flash_attention = functools.partial(flash_attention, use_pallas=True)
-moe.sum_rows_by_token = lambda rows, token, slot: (
-    row_sums._sum_in_token_order(rows, token, slot.shape[0]))
+moe.sum_rows_by_token = lambda rows, token, slot, live: (
+    row_sums._sum_in_token_order(rows, token, live, slot.shape[0]))
+row_moves._buffer, row_moves._placed = row_moves._unwritten, row_moves._copied_in
 cfg = sdar.SdarConfig(
     vocab_size=18992, d_model=2048, n_layers=1, n_heads=32, n_kv_heads=4,
     d_head=128, d_ff=768, n_experts=128, n_experts_held=16,
@@ -286,6 +329,42 @@ out["sdar_custom_calls"] = sum(
 out["sdar_dense"] = [ln[:160] for ln in ops if dense.search(ln)]
 # T x k = 131,072 (token, slot) pairs a routed block
 out["sdar_pair_scatters"] = pair_scatters(hlo, 131072)
+
+# a share's row moves in that layer (`ops/row_moves.py`): what is `select`ed
+# or gathered at a capacity's rows, and how each gather of a row tile of
+# 4,096 is reached from the entry
+caps = moe.share_capacities(16384, 8, 16, 128)
+
+
+def rows_of(ln):
+    # the rows of an instruction whose output is bf16[rows, 2048], or None
+    m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = bf16\[(\d+),2048\]", ln)
+    return m and int(m[1])
+
+
+out["sdar_caps"] = list(caps)
+out["sdar_fill_selects"] = [
+    ln[:120] for ln in ops
+    if rows_of(ln) in caps and " select(" in ln]
+computations = computations_of(hlo)
+ways = called_as(computations)
+out["sdar_row_gathers"] = sorted(
+    (rows_of(ln), sorted(ways(name)))
+    for name, lines in computations.items() for ln in lines
+    if " gather(" in ln and rows_of(ln) not in (None, 16384))  # the embedding's
+# the loops' two Pallas calls, by the names `ops/row_moves.py` gives them
+out["sdar_row_move_calls"] = {
+    name: sorted((rows_of(ln), sorted(ways(comp)))
+                 for comp, lines in computations.items() for ln in lines
+                 if rows_of(ln) and "tpu_custom_call" in ln
+                 and re.match(r"\s*(ROOT )?%" + name + r"[\w.]* = ", ln))
+    for name in ("unwritten", "row_tile")}
+out["sdar_buffer_copies"] = [
+    ln[:120] for ln in ops if rows_of(ln) in caps and re.search(
+        r"\S* (copy|dynamic-update-slice|broadcast)\(", ln)]
+out["sdar_conditionals"] = [
+    len(re.search(r"branch_computations=\{([^}]*)\}", ln)[1].split(","))
+    for ln in ops if re.search(r" conditional\(", ln)]
 
 
 def folded_denominators(hlo):
@@ -646,3 +725,43 @@ def test_a_share_combines_from_the_buffers_rows_as_compiled_for_v5e(compiled):
     assert compiled["moe_combine_other_hits_below_the_last_capacity"] == []
     # the experts' own kernels are there, and matched nothing
     assert compiled["sdar_custom_calls"] >= 4 + 9 + 6
+
+
+def test_a_shares_row_moves_visit_live_row_tiles_as_compiled_for_v5e(compiled):
+    """The same compiled layer: no `select` makes zeros for the dead rows of
+    a capacity's `[cap, 2048]` buffer (three a capacity stood here until
+    PR 45, `broadcast_select_fusion_bf16_32768_2048`, 0.41 ms each); no
+    gather is as long as a capacity: each of the five a capacity
+    (`_take_rows` forward, recomputed and as the combine's transpose, the
+    combine's re-ordering gather forward and as `_take_rows`' transpose)
+    gathers a row tile of 4,096 in the body of a `while` in a branch of the
+    capacity switch, so its trips follow the live rows, and one DMA
+    (`row_tile`) puts the tile into a buffer that nothing has written
+    (`unwritten`): no broadcast fills a buffer, no `dynamic-update-slice`
+    or copy moves one; and the step's only `conditional`s are the two
+    switches, forward and backward, three capacities each, which the three
+    `*_held_time_share` metrics count whole."""
+    caps = [32768, 65536, 131072]
+    assert compiled["sdar_caps"] == caps
+    assert compiled["sdar_fill_selects"] == []
+    assert compiled["sdar_row_gathers"] == [
+        [4096, ["body", "branch_computations", "calls"]]] * 15
+    assert compiled["sdar_row_move_calls"] == {
+        "unwritten": [[cap, ["branch_computations"]]
+                      for cap in caps for _ in range(5)],
+        "row_tile": [[cap, ["body", "branch_computations"]]
+                     for cap in caps for _ in range(5)]}
+    assert compiled["sdar_buffer_copies"] == []
+    assert compiled["sdar_conditionals"] == [3, 3]
+
+
+def test_the_whole_dispatch_compiles_to_the_parents_program(compiled):
+    """`test_moe_layer_backward_as_compiled_for_v5e`'s program, every
+    expert held (train-olmoe-1chip's block: `_permute`, `_combine`,
+    `sort_by_expert`), is instruction for instruction what the commit
+    before PR 45 compiled to: a share's row moves changed, the whole
+    dispatch runs none of them. The digest is of the optimised text less
+    paths, source lines and the Pallas calls' payloads (`same_program`); a
+    PR that means to change this block pins its own."""
+    assert compiled["moe_layer_program"] == (
+        "a547498e153528770e893a4dbecc036ed53f538ca2321e526c3bca9df27fbe09")
