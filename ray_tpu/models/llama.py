@@ -20,7 +20,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 from ray_tpu._private import device_profiler
 from ray_tpu.ops.flash_attention import flash_attention
@@ -154,15 +153,10 @@ def _remat_policy(config):
     name = getattr(config, "remat_policy", "full")
     if name == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if name == "dots_attn":
-        # "dots" + save the flash-attention outputs by name: pallas_call is
-        # not a dot, so under plain "dots" the whole attention forward
-        # kernel reruns inside the backward pass. Saving it costs
-        # B*S*H*D bf16 per layer (64 MB at bench shapes).
-        return jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names("attn_out"),
-        )
+    if name != "full":
+        raise ValueError(
+            f"remat_policy {name!r}: \"full\" (recompute everything) or "
+            "\"dots\" (save matmul outputs)")
     return None
 
 
@@ -341,7 +335,6 @@ def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
         # rows onto every head and turns v round with an all-to-all
         v = lc(v, ("batch", "seq", "act_heads", "act_kv"))
     attn = _attention(q, k, v, config, mesh, mask)
-    attn = _checkpoint_name(attn, "attn_out")
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
     return _residual(x, mesh, rules)
 

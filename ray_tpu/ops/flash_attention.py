@@ -292,8 +292,7 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     (1 / n of the tile's matmuls and exponentials; every score left out is
     one the mask sets to exactly 0). What decides is the rule's answer, not
     its type: `CAUSAL`'s diagonal tiles keep 10 of their 16 sub-tiles, so
-    no causal plan has such a step, and the in-parts kernels have no body
-    for one (`_run_row` refuses).
+    no causal plan has such a step.
 
     `executed_over_needed` is scores executed over scores the rule keeps.
     Starting point (before PR 26): steps of block_q x block_k whatever the
@@ -323,7 +322,7 @@ def _count_steps(*plans):
                           sum(p.steps_skipped for p in plans))
 
 
-def _run_row(plan, row, steps_ref, body, carry, finish, diagonal=None):
+def _run_row(plan, row, steps_ref, body, carry, finish, diagonal):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
     `body(masked)` -> a step (index, carry) -> carry; `diagonal` such a
     step for the plan's `DIAGONAL` ones.
@@ -342,11 +341,6 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal=None):
     5-8% (PERF.md §6, PR 26)."""
     from jax.experimental import pallas as pl
 
-    if plan.steps_diagonal and diagonal is None:
-        raise NotImplementedError(
-            "this kernel has no body for a diagonal step, and the plan has "
-            f"{plan.steps_diagonal}: only the whole-q kernels run a rule "
-            "that keeps a tile's diagonal sub-tiles alone")
     if not plan.static:
         step = body(plan.steps_masked > 0)
         finish(jax.lax.fori_loop(
@@ -367,18 +361,18 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal=None):
 
 
 def _grouped(x):
-    """[n * _SUB, ...] -> [n, _SUB, ...]: the rows of a square tile as the n
-    groups a diagonal step runs, each against its OWN `_SUB` positions of
-    the walked axis (the kernels' matmuls then take the groups as a batch).
-    On the chip the batch beats n sub-steps on slices of the carry: the
-    forward 3.14 ms for 3.97, with no diagonal step 3.39 (PERF.md §6,
-    PR 38)."""
-    return x.reshape(-1, _SUB, *x.shape[1:])
+    """[n * _SUB, ...] -> [n, _SUB, ...], of every array in `x`: the rows of
+    a square tile as the n groups a diagonal step runs, each against its OWN
+    `_SUB` positions of the walked axis (the kernels' matmuls then take the
+    groups as a batch). On the chip the batch beats n sub-steps on slices of
+    the carry: the forward 3.14 ms for 3.97, with no diagonal step 3.39
+    (PERF.md §6, PR 38)."""
+    return jax.tree.map(lambda a: a.reshape(-1, _SUB, *a.shape[1:]), x)
 
 
 def _flat(x):
     """`_grouped`'s inverse."""
-    return x.reshape(-1, *x.shape[2:])
+    return jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), x)
 
 
 def _walked_pos(start, shape):
@@ -399,6 +393,56 @@ def _mm(a, b, dim_a, dim_b):
     return jax.lax.dot_general(
         a, b, (((lead + dim_a,), (lead + dim_b,)), (batch, batch)),
         preferred_element_type=jnp.float32)
+
+
+# q and k reach the kernels as PARTS, tuples of one array or two whose
+# channels together are the channels a score contracts over: (q,) and (k,),
+# or, from latent attention's projections, (q [H, D], q_rope [H, R]) and
+# (k [H, D], ONE k_rope [R] for all heads). How many is known when a kernel
+# is built, so a call with one part runs one contraction a score and nothing
+# else; with two no [.., D + R] q and k are built in HBM, the rotary key is
+# never copied to H heads (`_spec`), and on the 128-wide MXU the two
+# contractions are the same two passes as one over D + R (PERF.md §6, PR 32).
+
+
+def _loaded(refs, rows=None):
+    """float32 of what each of the parts `refs` holds for this grid step,
+    or of its `rows`."""
+    return tuple((r[0, 0] if rows is None else r[0, 0, rows, :])
+                 .astype(jnp.float32) for r in refs)
+
+
+def _dot_parts(a, b):
+    """[rows of a, rows of b]: the parts' contractions over their channels,
+    summed."""
+    return functools.reduce(
+        jnp.add, (_mm(x, y, 1, 1) for x, y in zip(a, b)))
+
+
+def _store_parts(ref, parts):
+    """The block `ref` holds <- `parts`, side by side along its last dim."""
+    at = 0
+    for x in parts:
+        ref[0, 0, :, at:at + x.shape[-1]] = x.astype(ref.dtype)
+        at += x.shape[-1]
+
+
+def _spec(x, rows, walked):
+    """BlockSpec(s) on a grid (batch, head, i) over `x` [B, H, S, W], or over
+    each of a tuple of parts: `rows` of the sequence, block i of it where
+    the grid walks this operand (`walked`), else all of it (rows = the
+    padded sequence). An operand with ONE head is the rotary key every head
+    reads: head 0 whatever the grid's, not fetched again while the batch
+    stands."""
+    from jax.experimental import pallas as pl
+
+    def one(x):
+        head = x.shape[1] > 1
+        return pl.BlockSpec(
+            (1, 1, rows, x.shape[3]),
+            lambda b_, h_, i: (b_, h_ if head else 0, i if walked else 0, 0))
+
+    return jax.tree.map(one, x)
 
 
 def _pallas_call(kernel, plan, in_specs, **kw):
@@ -441,13 +485,13 @@ def _lane_chunks(x, op):
 # Pallas forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
+def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
                 block_q, plan, seq_q, seq_k, steps_ref=None):
     from jax.experimental import pallas as pl
 
     width = plan.width
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)  # [block_q, D]
+    q = _loaded(q_refs)  # ([block_q, D],), or with [block_q, R]
     # Causal with s_q != s_k (decode-style): query i corresponds to key
     # position i + (seq_k - seq_q), matching the oracle's tril(k=s_k-s_q).
     causal_offset = seq_k - seq_q
@@ -459,11 +503,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
         start + width); `_grouped` queries: each group after its own
         `_SUB` of them."""
         o, m, l = carry
-        k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-        if q.ndim == 3:
+        rows = pl.dslice(start, width)
+        k_blk = _loaded(k_refs, rows)
+        v_blk = v_ref[0, 0, rows, :].astype(jnp.float32)
+        if q_pos.ndim == 3:
             k_blk, v_blk = _grouped(k_blk), _grouped(v_blk)
-        s = _mm(q, k_blk, 1, 1) * scale  # [queries, width]
+        s = _dot_parts(q, k_blk) * scale  # [queries, width]
         if masked:
             k_pos = _walked_pos(start, q_pos.shape)
             # Mask padding rows of a partial final K block (manual
@@ -489,9 +534,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
             q, q_pos, _aligned(j * width, width), masked, carry)
 
     def diagonal(j, carry):
-        return tuple(map(_flat, attend(
+        return _flat(attend(
             _grouped(q), _grouped(q_pos[:, :_SUB]), j * width, True,
-            tuple(map(_grouped, carry)))))
+            _grouped(carry)))
 
     o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
@@ -510,50 +555,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, mask,
 
 
 def _pad_seq(x, block):
-    s = x.shape[2]
-    pad = (-s) % block
-    if pad == 0:
-        return x
-    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    """`x` [B, H, S, W], or every array of a tuple, with S padded up to a
+    multiple of `block`."""
+    def pad(x):
+        short = (-x.shape[2]) % block
+        if short == 0:
+            return x
+        return jnp.pad(x, ((0, 0), (0, 0), (0, short), (0, 0)))
+
+    return jax.tree.map(pad, x)
 
 
-def _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret):
-    from jax.experimental import pallas as pl
-
-    b, h, s_q, d = q.shape
-    s_k, d_v = k.shape[2], v.shape[3]
+def _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k, interpret):
+    b, h, s_q, _ = qs[0].shape
+    s_k, d_v = v.shape[2], v.shape[3]
     plan = block_schedule(s_q, s_k, block_q, block_k, mask)["fwd"]
     _count_steps(plan)
     # Pad to block multiples: dynamic_slice CLAMPS out-of-range starts, which
     # would silently shift the last partial block. The kernels mask padded
     # positions via the true seq_q/seq_k.
-    q = _pad_seq(q, block_q)
-    k = _pad_seq(k, plan.width)
-    v = _pad_seq(v, plan.width)
-    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-    grid = (b, h, s_q_pad // block_q)
+    qs = _pad_seq(qs, block_q)
+    ks, v = _pad_seq((ks, v), plan.width)
+    s_q_pad, s_k_pad = qs[0].shape[2], v.shape[2]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, mask=mask, block_q=block_q, plan=plan,
         seq_q=s_q, seq_k=s_k,
     )
+    out_shape = [
+        jax.ShapeDtypeStruct((b, h, s_q_pad, d_v), v.dtype),
+        jax.ShapeDtypeStruct((b, h, s_q_pad, 1), jnp.float32),
+    ]
     o, lse = _pallas_call(
         kernel, plan,
-        [
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, s_k_pad, d_v), lambda b_, h_, i: (b_, h_, 0, 0)),
-        ],
-        grid=grid,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d_v), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape[:3] + (d_v,), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_q_pad, 1), jnp.float32),
-        ],
+        [_spec(qs, block_q, True), _spec(ks, s_k_pad, False),
+         _spec(v, s_k_pad, False)],
+        grid=(b, h, s_q_pad // block_q),
+        out_specs=_spec(out_shape, block_q, True),
+        out_shape=out_shape,
         interpret=interpret,
-    )(q, k, v)
+    )(qs, ks, v)
     return o[:, :, :s_q], lse[:, :, :s_q]
 
 
@@ -561,13 +601,15 @@ def _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret):
 # Pallas backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   scale, mask, block_q, plan, seq_q, seq_k, steps_ref=None):
+def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   *, scale, mask, block_q, plan, seq_q, seq_k,
+                   steps_ref=None):
+    """dq_ref [block_q, D (+ R)]: the parts' gradients side by side."""
     from jax.experimental import pallas as pl
 
     width = plan.width
     qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)
+    q = _loaded(q_refs)
     do = do_ref[0, 0].astype(jnp.float32)
     lse = lse_ref[0, 0]      # [block_q, 1]
     delta = delta_ref[0, 0]  # [block_q, 1]
@@ -578,11 +620,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     def attend(q, do, lse, delta, q_pos, start, masked, dq):
         """dq of the queries `q` plus what the keys [start, start + width)
         give it; `_grouped` operands: each group's own `_SUB` of them."""
-        k_blk = k_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-        v_blk = v_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-        if q.ndim == 3:
+        rows = pl.dslice(start, width)
+        k_blk = _loaded(k_refs, rows)
+        v_blk = v_ref[0, 0, rows, :].astype(jnp.float32)
+        if q_pos.ndim == 3:
             k_blk, v_blk = _grouped(k_blk), _grouped(v_blk)
-        s = _mm(q, k_blk, 1, 1) * scale
+        s = _dot_parts(q, k_blk) * scale
         if masked:
             k_pos = _walked_pos(start, q_pos.shape)
             valid = k_pos < seq_k
@@ -594,7 +637,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
             p = jnp.where(valid, p, 0.0)
         dp = _mm(do, v_blk, 1, 1)
         ds = p * (dp - delta) * scale
-        return dq + _mm(ds, k_blk, 1, 0)
+        return tuple(d + _mm(ds, x, 1, 0) for d, x in zip(dq, k_blk))
 
     def step(masked):
         return lambda j, dq: attend(
@@ -602,28 +645,28 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
     def diagonal(j, dq):
         return _flat(attend(
-            *map(_grouped, (q, do, lse, delta, q_pos[:, :_SUB])), j * width,
+            *_grouped((q, do, lse, delta, q_pos[:, :_SUB])), j * width,
             True, _grouped(dq)))
 
-    def finish(dq):
-        dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-
-    _run_row(plan, qi, steps_ref, step, jnp.zeros_like(q), finish, diagonal)
+    _run_row(plan, qi, steps_ref, step, tuple(map(jnp.zeros_like, q)),
+             functools.partial(_store_parts, dq_ref), diagonal)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, scale, mask, block_k, plan,
                     seq_q, seq_k, steps_ref=None):
     """Works on the TRANSPOSED score tile, s^T = k q^T [block_k, width]:
     every product is then a plain or a last-dims-contracted matmul (dv +=
     p^T do, dp^T = v do^T, dk += ds^T q), where p^T do taken from an
     untransposed p has Mosaic transpose the whole tile first. lse and delta
-    come as rows ([steps, width]) to broadcast down the tile."""
+    come as rows ([steps, width]) to broadcast down the tile. dk_ref
+    [block_k, D (+ R)]: the parts' gradients side by side, of the rotary key
+    THIS head's share (the backward rule sums the heads')."""
     from jax.experimental import pallas as pl
 
     width = plan.width
     kj = pl.program_id(2)
-    k_blk = k_ref[0, 0].astype(jnp.float32)  # [block_k, D]
+    k_blk = _loaded(k_refs)  # ([block_k, D],), or with [block_k, R]
     v_blk = v_ref[0, 0].astype(jnp.float32)
     k_pos = kj * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_k, width), 0)
@@ -635,17 +678,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         them."""
         dk, dv = carry
         start = _aligned(i * width, width)
-        q = q_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.dslice(start, width), :].astype(jnp.float32)
+        rows = pl.dslice(start, width)
+        q = _loaded(q_refs, rows)
+        do = do_ref[0, 0, rows, :].astype(jnp.float32)
         lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
         delta = delta_ref[0, 0, pl.dslice(i, 1), :]
-        if k_blk.ndim == 3:
+        if k_pos.ndim == 3:
             q, do = _grouped(q), _grouped(do)
             # a group's lanes of the row: [n, 1, _SUB]
             lse, delta = (jnp.stack(
                 [x[:, a:a + _SUB] for a in range(0, width, _SUB)])
                 for x in (lse, delta))
-        s = _mm(k_blk, q, 1, 1) * scale  # [keys, width]
+        s = _dot_parts(k_blk, q) * scale  # [keys, width]
         if masked:
             q_row = _walked_pos(start, k_pos.shape)
             # Mask padding rows of a partial final Q block; when causal,
@@ -661,68 +705,61 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv = dv + _mm(p, do, 1, 0)
         dp = _mm(v_blk, do, 1, 1)
         ds = p * (dp - delta) * scale
-        dk = dk + _mm(ds, q, 1, 0)
-        return dk, dv
+        return tuple(d + _mm(ds, x, 1, 0) for d, x in zip(dk, q)), dv
 
     def step(masked):
         return lambda i, carry: attend(k_blk, v_blk, k_pos, i, masked, carry)
 
     def diagonal(i, carry):
-        return tuple(map(_flat, attend(
-            *map(_grouped, (k_blk, v_blk, k_pos[:, :_SUB])), i, True,
-            tuple(map(_grouped, carry)))))
+        return _flat(attend(
+            *_grouped((k_blk, v_blk, k_pos[:, :_SUB])), i, True,
+            _grouped(carry)))
 
     def finish(carry):
         dk, dv = carry
-        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+        _store_parts(dk_ref, dk)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
     _run_row(plan, kj, steps_ref, step,
-             (jnp.zeros_like(k_blk), jnp.zeros_like(v_blk)), finish, diagonal)
+             (tuple(map(jnp.zeros_like, k_blk)), jnp.zeros_like(v_blk)),
+             finish, diagonal)
 
 
-def _bwd_dq_pallas(q, k, v, do, lse, delta, mask, scale, block_q, plan,
+def _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q, plan,
                    interpret):
-    from jax.experimental import pallas as pl
-
-    b, h, s_q, d = q.shape
-    s_k, d_v = k.shape[2], v.shape[3]
+    b, h, s_q, _ = do.shape
+    s_k = v.shape[2]
     # Same padding rationale as the forward (dynamic_slice clamping).
-    q, do, lse, delta = (_pad_seq(x, block_q) for x in (q, do, lse, delta))
-    k, v = _pad_seq(k, plan.width), _pad_seq(v, plan.width)
-    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
+    qs, do, lse, delta = _pad_seq((qs, do, lse, delta), block_q)
+    ks, v = _pad_seq((ks, v), plan.width)
+    s_q_pad, s_k_pad = do.shape[2], v.shape[2]
     kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, mask=mask, block_q=block_q,
         plan=plan, seq_q=s_q, seq_k=s_k,
     )
+    out_shape = jax.ShapeDtypeStruct(
+        (b, h, s_q_pad, sum(x.shape[3] for x in qs)), qs[0].dtype)
     dq = _pallas_call(
         kernel, plan,
-        [
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, s_k_pad, d), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, s_k_pad, d_v), lambda b_, h_, i: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_q, d_v), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i: (b_, h_, i, 0)),
-        ],
+        [_spec(qs, block_q, True), _spec(ks, s_k_pad, False),
+         _spec(v, s_k_pad, False), _spec(do, block_q, True),
+         _spec(lse, block_q, True), _spec(delta, block_q, True)],
         grid=(b, h, s_q_pad // block_q),
-        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i: (b_, h_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=_spec(out_shape, block_q, True),
+        out_shape=out_shape,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(qs, ks, v, do, lse, delta)
     return dq[:, :, :s_q]
 
 
-def _bwd_dkv_pallas(q, k, v, do, lse, delta, mask, scale, block_k, plan,
+def _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k, plan,
                     interpret):
-    from jax.experimental import pallas as pl
-
-    b, h, s_q, d = q.shape
-    s_k, d_v = k.shape[2], v.shape[3]
+    b, h, s_q, _ = do.shape
+    s_k = v.shape[2]
     width = plan.width
-    q, do, lse, delta = (_pad_seq(x, width) for x in (q, do, lse, delta))
-    k, v = _pad_seq(k, block_k), _pad_seq(v, block_k)
-    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
+    qs, do, lse, delta = _pad_seq((qs, do, lse, delta), width)
+    ks, v = _pad_seq((ks, v), block_k)
+    s_q_pad, s_k_pad = do.shape[2], v.shape[2]
     # one row a loop step, for the transposed tile (see the kernel)
     n_steps = s_q_pad // width
     lse = lse.reshape(b, h, n_steps, width)
@@ -731,39 +768,35 @@ def _bwd_dkv_pallas(q, k, v, do, lse, delta, mask, scale, block_k, plan,
         _bwd_dkv_kernel, scale=scale, mask=mask, block_k=block_k,
         plan=plan, seq_q=s_q, seq_k=s_k,
     )
+    out_shape = [
+        jax.ShapeDtypeStruct(
+            (b, h, s_k_pad, sum(x.shape[3] for x in ks)), ks[0].dtype),
+        jax.ShapeDtypeStruct(v.shape, v.dtype),
+    ]
     dk, dv = _pallas_call(
         kernel, plan,
-        [
-            pl.BlockSpec((1, 1, s_q_pad, d), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d_v), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, s_q_pad, d_v), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, n_steps, width), lambda b_, h_, j: (b_, h_, 0, 0)),
-        ],
+        [_spec(qs, s_q_pad, False), _spec(ks, block_k, True),
+         _spec(v, block_k, True), _spec(do, s_q_pad, False),
+         _spec(lse, n_steps, False), _spec(delta, n_steps, False)],
         grid=(b, h, s_k_pad // block_k),
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d_v), lambda b_, h_, j: (b_, h_, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
+        out_specs=_spec(out_shape, block_k, True),
+        out_shape=out_shape,
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(qs, ks, v, do, lse, delta)
     return dk[:, :, :s_k], dv[:, :, :s_k]
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, do, mask, scale, block_q, block_k,
+def _flash_bwd_pallas(qs, ks, v, o, lse, do, mask, scale, block_q, block_k,
                       interpret):
-    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, mask)
+    """-> dq [B, H, S, D (+ R)], dk likewise (every head's share of a rotary
+    key's gradient), dv."""
+    plans = block_schedule(do.shape[2], v.shape[2], block_q, block_k, mask)
     _count_steps(plans["dq"], plans["dkv"])
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
-    dq = _bwd_dq_pallas(q, k, v, do, lse, delta, mask, scale, block_q,
+    dq = _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q,
                         plans["dq"], interpret)
-    dk, dv = _bwd_dkv_pallas(q, k, v, do, lse, delta, mask, scale, block_k,
+    dk, dv = _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k,
                              plans["dkv"], interpret)
     return dq, dk, dv
 
@@ -772,23 +805,54 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, mask, scale, block_q, block_k,
 # custom_vjp wrapper
 # --------------------------------------------------------------------------
 
+# Names of the forward rule's residuals (`jax.ad_checkpoint.checkpoint_name`):
+# a remat policy that saves them runs no second forward kernel in its
+# backward pass; under one that does not they cost nothing. lse is kept as
+# [B, H, S]: as [B, H, S, 1] its last dim pads to 128 lanes in the TPU's tiled
+# layout, 128x the bytes.
+RESIDUAL_NAMES = ("flash.o", "flash.lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bhsd(q, k, v, mask, scale, block_q, block_k, interpret):
-    o, _ = _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret)
-    return o
+def _flash_bhsd(qs, ks, v, mask, scale, block_q, block_k, interpret):
+    """qs, ks: q and k in parts (above), (q,) and (k,) [B, H, S, D] or (q,
+    q_rope [B, H, S, R]) and (k, k_rope [B, 1, S, R]); v [B, H, S, Dv] -> o
+    [B, H, S, Dv]."""
+    return _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k,
+                             interpret)[0]
 
 
-def _flash_fwd_rule(q, k, v, mask, scale, block_q, block_k, interpret):
-    o, lse = _flash_fwd_pallas(q, k, v, mask, scale, block_q, block_k, interpret)
-    return o, (q, k, v, o, lse)
+def _flash_fwd_rule(qs, ks, v, mask, scale, block_q, block_k, interpret):
+    from jax.ad_checkpoint import checkpoint_name
+
+    o, lse = _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k,
+                               interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return o, (qs, ks, v, o, lse)
 
 
 def _flash_bwd_rule(mask, scale, block_q, block_k, interpret, res, do):
-    q, k, v, o, lse = res
+    qs, ks, v, o, lse = res
     dq, dk, dv = _flash_bwd_pallas(
-        q, k, v, o, lse, do, mask, scale, block_q, block_k, interpret
-    )
-    return dq, dk, dv
+        qs, ks, v, o, lse[..., None], do, mask, scale, block_q, block_k,
+        interpret)
+
+    def of_parts(g, parts):
+        """g [B, H, S, D (+ R)] cut into the gradient of each part as it was
+        given: every head used the ONE rotary key, so its gradient is the
+        heads' sum."""
+        out, at = [], 0
+        for x in parts:
+            gx = g[..., at:at + x.shape[3]]
+            at += x.shape[3]
+            if x.shape[1] != g.shape[1]:
+                gx = jnp.sum(gx, axis=1, keepdims=True,
+                             dtype=jnp.float32).astype(x.dtype)
+            out.append(gx)
+        return tuple(out)
+
+    return of_parts(dq, qs), of_parts(dk, ks), dv
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -858,7 +922,8 @@ def flash_attention(
     if use_pallas or interpret:
         block_q = _clamp_block(block_q, s_q)
         block_k = _clamp_block(block_k, k.shape[1])
-        o = _flash_bhsd(qt, kt, vt, mask, scale, block_q, block_k, interpret)
+        o = _flash_bhsd((qt,), (kt,), vt, mask, scale, block_q, block_k,
+                        interpret)
     else:
         o = _reference_attention(qt, kt, vt, mask, scale)
     return o.transpose(0, 2, 1, 3)
@@ -905,343 +970,6 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
     )(q, k, v)
 
 
-# --------------------------------------------------------------------------
-# In parts: q and k as a D-wide part and a rotary part, ONE rotary key
-# --------------------------------------------------------------------------
-# Latent attention's projections make q as (q [H, D], q_rope [H, R]) and k
-# as (k [H, D], ONE k_rope [R] for all heads). These kernels take them so:
-# no [.., D + R] q and k are built in HBM and the rotary key is never
-# copied to H heads (its BlockSpec ignores the head). They are the three
-# kernels above (same schedule, `_run_row`, masks, float32 arithmetic) with
-# each score the SUM of two contractions, which on the 128-wide MXU are the
-# same two passes as one contraction over D + R (PERF.md §6, PR 32), and
-# with no body for a diagonal step: their one caller is causal, whose plans
-# have none, and `_run_row` refuses a plan that does. They stand below, not
-# folded into, the kernels above because a Mosaic payload carries the file
-# locations of its kernel's lines: moving those recompiles every other
-# caller (PERF.md §6, PR 29; §7 for the fold).
-
-# Names of the forward rule's residuals (`jax.ad_checkpoint.checkpoint_name`):
-# a remat policy that saves them runs no second forward kernel in its
-# backward pass. lse is kept as [B, H, S]: as [B, H, S, 1] its last dim pads
-# to 128 lanes in the TPU's tiled layout, 128x the bytes. Only the in-parts
-# rule names them: the rules above stand as they are.
-RESIDUAL_NAMES = ("flash.o", "flash.lse")
-
-
-def _scores(a, a_rope, b, b_rope, scale):
-    """[rows of a, rows of b]: a . b over D plus a_rope . b_rope over R."""
-    return (_mm(a, b, 1, 1) + _mm(a_rope, b_rope, 1, 1)) * scale
-
-
-def _fwd_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref, lse_ref, *,
-                      scale, mask, block_q, plan, seq_q, seq_k, steps_ref=None):
-    from jax.experimental import pallas as pl
-
-    width = plan.width
-    qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)    # [block_q, D]
-    qr = qr_ref[0, 0].astype(jnp.float32)  # [block_q, R]
-    causal_offset = seq_k - seq_q
-    q_pos = (qi * block_q + causal_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
-
-    def step(masked):
-        def body(j, carry):
-            o, m, l = carry
-            start = _aligned(j * width, width)
-            rows = pl.dslice(start, width)
-            s = _scores(q, qr, k_ref[0, 0, rows, :].astype(jnp.float32),
-                        kr_ref[0, 0, rows, :].astype(jnp.float32), scale)
-            if masked:
-                k_pos = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, width), 1)
-                valid = k_pos < seq_k
-                if mask is not None:
-                    valid = valid & mask.keep(q_pos, k_pos)
-                s = jnp.where(valid, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            if masked:
-                p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + _lane_chunks(p, jnp.add)
-            o_new = o * corr + _mm(
-                p, v_ref[0, 0, rows, :].astype(jnp.float32), 1, 0)
-            return o_new, m_new, l_new
-        return body
-
-    o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((block_q, width if width % 128 else 128),
-                   dtype=jnp.float32)
-
-    def finish(carry):
-        o, m, l = carry
-        l = jnp.maximum(jnp.sum(l, axis=-1, keepdims=True), 1e-20)
-        o_ref[0, 0] = (o / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m + jnp.log(l)
-
-    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish)
-
-
-def _bwd_dq_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
-                         delta_ref, dq_ref, *, scale, mask, block_q, plan,
-                         seq_q, seq_k, steps_ref=None):
-    """dq_ref [block_q, D + R]: both parts' gradients side by side."""
-    from jax.experimental import pallas as pl
-
-    width = plan.width
-    qi = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32)
-    qr = qr_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]      # [block_q, 1]
-    delta = delta_ref[0, 0]  # [block_q, 1]
-    causal_offset = seq_k - seq_q
-    q_pos = (qi * block_q + causal_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
-
-    def step(masked):
-        def body(j, carry):
-            dq, dqr = carry
-            start = _aligned(j * width, width)
-            rows = pl.dslice(start, width)
-            k_blk = k_ref[0, 0, rows, :].astype(jnp.float32)
-            kr_blk = kr_ref[0, 0, rows, :].astype(jnp.float32)
-            s = _scores(q, qr, k_blk, kr_blk, scale)
-            if masked:
-                k_pos = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, width), 1)
-                valid = k_pos < seq_k
-                if mask is not None:
-                    valid = valid & mask.keep(q_pos, k_pos)
-                s = jnp.where(valid, s, NEG_INF)
-            p = jnp.exp(s - lse)
-            if masked:
-                p = jnp.where(valid, p, 0.0)
-            dp = _mm(do, v_ref[0, 0, rows, :].astype(jnp.float32), 1, 1)
-            ds = p * (dp - delta) * scale
-            return dq + _mm(ds, k_blk, 1, 0), dqr + _mm(ds, kr_blk, 1, 0)
-        return body
-
-    d = q.shape[-1]
-
-    def finish(carry):
-        dq, dqr = carry
-        dq_ref[0, 0, :, :d] = dq.astype(dq_ref.dtype)
-        dq_ref[0, 0, :, d:] = dqr.astype(dq_ref.dtype)
-
-    _run_row(plan, qi, steps_ref, step, (jnp.zeros_like(q), jnp.zeros_like(qr)),
-             finish)
-
-
-def _bwd_dkv_parts_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref,
-                          lse_ref, delta_ref, dk_ref, dv_ref, *, scale,
-                          mask, block_k, plan, seq_q, seq_k, steps_ref=None):
-    """On the transposed tile, as `_bwd_dkv_kernel`. dk_ref [block_k, D + R]:
-    dk beside THIS head's share of the rotary key's gradient (the caller
-    sums the heads')."""
-    from jax.experimental import pallas as pl
-
-    width = plan.width
-    kj = pl.program_id(2)
-    k_blk = k_ref[0, 0].astype(jnp.float32)    # [block_k, D]
-    kr_blk = kr_ref[0, 0].astype(jnp.float32)  # [block_k, R]
-    v_blk = v_ref[0, 0].astype(jnp.float32)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, width), 0)
-    causal_offset = seq_k - seq_q
-
-    def step(masked):
-        def body(i, carry):
-            dk, dkr, dv = carry
-            start = _aligned(i * width, width)
-            rows = pl.dslice(start, width)
-            q = q_ref[0, 0, rows, :].astype(jnp.float32)
-            qr = qr_ref[0, 0, rows, :].astype(jnp.float32)
-            do = do_ref[0, 0, rows, :].astype(jnp.float32)
-            lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
-            delta = delta_ref[0, 0, pl.dslice(i, 1), :]
-            s = _scores(k_blk, kr_blk, q, qr, scale)  # [block_k, width]
-            if masked:
-                q_row = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_k, width), 1)
-                valid = q_row < seq_q
-                if mask is not None:
-                    valid = valid & mask.keep(q_row + causal_offset, k_pos)
-                s = jnp.where(valid, s, NEG_INF)
-            p = jnp.exp(s - lse)
-            if masked:
-                p = jnp.where(valid, p, 0.0)
-            dv = dv + _mm(p, do, 1, 0)
-            ds = p * (_mm(v_blk, do, 1, 1) - delta) * scale
-            return dk + _mm(ds, q, 1, 0), dkr + _mm(ds, qr, 1, 0), dv
-        return body
-
-    d = k_blk.shape[-1]
-
-    def finish(carry):
-        dk, dkr, dv = carry
-        dk_ref[0, 0, :, :d] = dk.astype(dk_ref.dtype)
-        dk_ref[0, 0, :, d:] = dkr.astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-
-    _run_row(plan, kj, steps_ref, step,
-             (jnp.zeros_like(k_blk), jnp.zeros_like(kr_blk),
-              jnp.zeros_like(v_blk)), finish)
-
-
-def _block(rows, width, walked, head=True):
-    """BlockSpec of [1, 1, rows, width] on a grid (batch, head, i): block i
-    of the sequence where the grid walks this operand (`walked`), else all
-    of it (rows = the padded sequence). `head=False`: head 0 whatever the
-    grid's, the one rotary key, not fetched again while the batch stands."""
-    from jax.experimental import pallas as pl
-
-    return pl.BlockSpec(
-        (1, 1, rows, width),
-        lambda b_, h_, i: (b_, h_ if head else 0, i if walked else 0, 0))
-
-
-def _flash_fwd_parts_pallas(q, qr, k, kr, v, mask, scale, block_q, block_k,
-                            interpret):
-    from jax.experimental import pallas as pl
-
-    b, h, s_q, d = q.shape
-    s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
-    plan = block_schedule(s_q, s_k, block_q, block_k, mask)["fwd"]
-    _count_steps(plan)
-    q, qr = _pad_seq(q, block_q), _pad_seq(qr, block_q)
-    k, kr, v = (_pad_seq(x, plan.width) for x in (k, kr, v))
-    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-    o, lse = _pallas_call(
-        functools.partial(
-            _fwd_parts_kernel, scale=scale, mask=mask, block_q=block_q,
-            plan=plan, seq_q=s_q, seq_k=s_k),
-        plan,
-        [
-            _block(block_q, d, True), _block(block_q, r, True),
-            _block(s_k_pad, d, False), _block(s_k_pad, r, False, head=False),
-            _block(s_k_pad, d_v, False),
-        ],
-        grid=(b, h, s_q_pad // block_q),
-        out_specs=[_block(block_q, d_v, True), _block(block_q, 1, True)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_q_pad, d_v), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_q_pad, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, qr, k, kr, v)
-    return o[:, :, :s_q], lse[:, :, :s_q]
-
-
-def _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask, scale,
-                         block_q, plan, interpret):
-    from jax.experimental import pallas as pl
-
-    b, h, s_q, d = q.shape
-    s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
-    q, qr, do, lse, delta = (_pad_seq(x, block_q)
-                             for x in (q, qr, do, lse, delta))
-    k, kr, v = (_pad_seq(x, plan.width) for x in (k, kr, v))
-    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-    dq = _pallas_call(
-        functools.partial(
-            _bwd_dq_parts_kernel, scale=scale, mask=mask, block_q=block_q,
-            plan=plan, seq_q=s_q, seq_k=s_k),
-        plan,
-        [
-            _block(block_q, d, True), _block(block_q, r, True),
-            _block(s_k_pad, d, False), _block(s_k_pad, r, False, head=False),
-            _block(s_k_pad, d_v, False), _block(block_q, d_v, True),
-            _block(block_q, 1, True), _block(block_q, 1, True),
-        ],
-        grid=(b, h, s_q_pad // block_q),
-        out_specs=_block(block_q, d + r, True),
-        out_shape=jax.ShapeDtypeStruct((b, h, s_q_pad, d + r), q.dtype),
-        interpret=interpret,
-    )(q, qr, k, kr, v, do, lse, delta)
-    return dq[:, :, :s_q]
-
-
-def _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask, scale,
-                          block_k, plan, interpret):
-    from jax.experimental import pallas as pl
-
-    b, h, s_q, d = q.shape
-    s_k, r, d_v = k.shape[2], qr.shape[3], v.shape[3]
-    width = plan.width
-    q, qr, do, lse, delta = (_pad_seq(x, width)
-                             for x in (q, qr, do, lse, delta))
-    k, kr, v = (_pad_seq(x, block_k) for x in (k, kr, v))
-    s_q_pad, s_k_pad = q.shape[2], k.shape[2]
-    # one row a loop step, for the transposed tile (see `_bwd_dkv_kernel`)
-    n_steps = s_q_pad // width
-    lse = lse.reshape(b, h, n_steps, width)
-    delta = delta.reshape(b, h, n_steps, width)
-    dk, dv = _pallas_call(
-        functools.partial(
-            _bwd_dkv_parts_kernel, scale=scale, mask=mask,
-            block_k=block_k, plan=plan, seq_q=s_q, seq_k=s_k),
-        plan,
-        [
-            _block(s_q_pad, d, False), _block(s_q_pad, r, False),
-            _block(block_k, d, True), _block(block_k, r, True, head=False),
-            _block(block_k, d_v, True), _block(s_q_pad, d_v, False),
-            _block(n_steps, width, False), _block(n_steps, width, False),
-        ],
-        grid=(b, h, s_k_pad // block_k),
-        out_specs=[_block(block_k, d + r, True), _block(block_k, d_v, True)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_k_pad, d + r), k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        interpret=interpret,
-    )(q, qr, k, kr, v, do, lse, delta)
-    return dk[:, :, :s_k], dv[:, :, :s_k]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_parts_bhsd(q, qr, k, kr, v, mask, scale, block_q, block_k,
-                      interpret):
-    """q, k [B, H, S, D], qr [B, H, S, R], kr [B, 1, S, R], v [B, H, S, Dv]
-    -> o [B, H, S, Dv]."""
-    return _flash_fwd_parts_pallas(q, qr, k, kr, v, mask, scale, block_q,
-                                   block_k, interpret)[0]
-
-
-def _flash_parts_fwd_rule(q, qr, k, kr, v, mask, scale, block_q, block_k,
-                          interpret):
-    from jax.ad_checkpoint import checkpoint_name
-
-    o, lse = _flash_fwd_parts_pallas(q, qr, k, kr, v, mask, scale, block_q,
-                                     block_k, interpret)
-    o = checkpoint_name(o, RESIDUAL_NAMES[0])
-    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
-    return o, (q, qr, k, kr, v, o, lse)
-
-
-def _flash_parts_bwd_rule(mask, scale, block_q, block_k, interpret, res, do):
-    q, qr, k, kr, v, o, lse = res
-    plans = block_schedule(q.shape[2], k.shape[2], block_q, block_k, mask)
-    _count_steps(plans["dq"], plans["dkv"])
-    lse = lse[..., None]
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-    dq = _bwd_dq_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask, scale,
-                              block_q, plans["dq"], interpret)
-    dk, dv = _bwd_dkv_parts_pallas(q, qr, k, kr, v, do, lse, delta, mask,
-                                   scale, block_k, plans["dkv"], interpret)
-    d = q.shape[-1]
-    # every head used the one rotary key: its gradient is the heads' sum
-    dkr = jnp.sum(dk[..., d:], axis=1, keepdims=True, dtype=jnp.float32)
-    return dq[..., :d], dq[..., d:], dk[..., :d], dkr.astype(kr.dtype), dv
-
-
-_flash_parts_bhsd.defvjp(_flash_parts_fwd_rule, _flash_parts_bwd_rule)
-
-
 def _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale, block_q, block_k,
                     use_pallas, interpret):
     """`flash_attention` with q and k in the parts latent attention's
@@ -1252,7 +980,7 @@ def _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale, block_q, block_k,
     heads], which is what the oracle path (off a TPU) builds and the kernels
     never do. The backward rule returns the gradient of each operand as
     given; the rotary key's, [B, S, 1, R], is the sum over heads and is
-    summed HERE (`_flash_parts_bwd_rule`), not by the caller."""
+    summed HERE (`_flash_bwd_rule`), not by the caller."""
     if k_rope is None or k_rope.shape[2] != 1:
         raise ValueError("q_rope comes with ONE rotary key, [B, S, 1, R]")
 
@@ -1260,8 +988,8 @@ def _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale, block_q, block_k,
         return x.transpose(0, 2, 1, 3)
 
     if use_pallas or interpret:
-        o = _flash_parts_bhsd(
-            t(q), t(q_rope), t(k), t(k_rope), t(v), mask, scale,
+        o = _flash_bhsd(
+            (t(q), t(q_rope)), (t(k), t(k_rope)), t(v), mask, scale,
             _clamp_block(block_q, q.shape[1]),
             _clamp_block(block_k, k.shape[1]), interpret)
     else:

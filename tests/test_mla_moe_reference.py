@@ -18,7 +18,9 @@ from benchmarks import reference_joyai as ref
 from ray_tpu._private import device_profiler
 from ray_tpu.models import mixtral, mla_moe
 from ray_tpu.ops import row_moves, row_sums
-from ray_tpu.ops.flash_attention import _reference_attention, flash_attention
+from ray_tpu.ops.flash_attention import (
+    BlockDiffusion, _clamp_block, _reference_attention, block_schedule,
+    flash_attention)
 from ray_tpu.parallel import moe
 
 # float32 against float32-"highest": ~1e2 additions per output of O(1)
@@ -539,15 +541,39 @@ def test_rope_interleave_is_a_common_permutation():
 
 
 @pytest.mark.parametrize("parts", [False, True], ids=["whole", "in_parts"])
-@pytest.mark.parametrize("shape", [(2, 256, 2, 192, 128), (1, 200, 3, 24, 16)],
-                         ids=["192_128", "24_16_partial_blocks"])
-def test_flash_attention_key_width_differs_from_value_width(shape, parts):
+@pytest.mark.parametrize("rule,tile,static,n_diagonal,shape", [
+    (True, 128, True, 0, (2, 256, 2, 192, 128)),
+    (True, 128, True, 0, (1, 200, 3, 24, 16)),
+    # rows of ten steps: one loop a grid row over the plan's table, the
+    # parts' gradients its carry
+    (True, 128, False, 0, (1, 1280, 2, 24, 16)),
+    # a noised and a clean copy of 256 tokens in two tiles a side: the x_t
+    # diagonal tile keeps its diagonal sub-tiles alone
+    (BlockDiffusion(256, 4), 256, True, 1, (2, 512, 2, 192, 128)),
+    # the same over partial blocks: tiles that end past the sequence or
+    # straddle the two copies; what is kept of two of those lies on their
+    # diagonal sub-tiles too
+    (BlockDiffusion(300, 4), 256, True, 3, (1, 600, 3, 24, 16)),
+    # four tiles a side: an x_t row runs an unmasked, a masked and a
+    # diagonal step, and they are not one contiguous range
+    (BlockDiffusion(512, 4), 256, True, 2, (1, 1024, 2, 24, 16)),
+], ids=["192_128", "24_16_partial_blocks", "24_16_loop",
+        "block_diffusion_192_128", "block_diffusion_24_16_partial_blocks",
+        "block_diffusion_24_16_rows"])
+def test_flash_attention_key_width_differs_from_value_width(
+        rule, tile, static, n_diagonal, shape, parts):
     """q, k [B, S, H, 192], v [B, S, H, 128] through the Pallas kernels in
     the interpreter against the jax.numpy oracle: forward, dq, dk, dv. In
     parts: q as (q, q_rope), k as (k, ONE rotary key for all heads): the
     values and all five gradients, the rotary key's summed over heads,
-    against the oracle on the concatenated q and k."""
+    against the oracle on the concatenated q and k. Causal, unrolled
+    (`static`) and as a loop, and under a rule whose plan has DIAGONAL steps
+    (`n_diagonal` in each kernel): the one set of kernels runs both forms of
+    a call under every kind of plan."""
     b, s, h, d_qk, d_v = shape
+    plans = block_schedule(s, s, *[_clamp_block(tile, s)] * 2, rule)
+    assert [(p.static, p.steps_diagonal) for p in plans.values()] == [
+        (static, n_diagonal)] * 3
     q, k = (jax.random.normal(jax.random.PRNGKey(i), (b, s, h, d_qk))
             for i in (0, 1))
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d_v))
@@ -567,13 +593,14 @@ def test_flash_attention_key_width_differs_from_value_width(shape, parts):
     def oracle(*a):
         q, k, v = whole(*a)
         t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731
-        return t(_reference_attention(t(q), t(k), t(v), True, d_qk ** -0.5))
+        return t(_reference_attention(t(q), t(k), t(v), rule, d_qk ** -0.5))
 
     def kernels(*a, **kw):
         if parts:
             q, q_rope, k, k_rope, v = a
             a, kw = (q, k, v), dict(kw, q_rope=q_rope, k_rope=k_rope)
-        return flash_attention(*a, causal=True, block_q=128, block_k=128, **kw)
+        return flash_attention(*a, causal=rule, block_q=tile, block_k=tile,
+                               **kw)
 
     with jax.default_matmul_precision("highest"):
         got, vjp = jax.vjp(partial(kernels, interpret=True), *args)

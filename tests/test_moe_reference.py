@@ -222,6 +222,10 @@ def test_backward_reruns_neither_down_projection_nor_unpermute(switches):
         if e.primitive.name.startswith("scatter"):
             assert pair_rows not in [v.aval.shape for v in e.invars], e
             assert e.outvars[0].aval.shape != pair_rows[:1], e
+    # a policy's name that is not known is refused, not read as "full"
+    with pytest.raises(ValueError, match="dots_attn"):
+        mixtral.loss_fn(params, {"inputs": inputs, "targets": targets},
+                        dataclasses.replace(cfg, remat_policy="dots_attn"))
 
 
 @pytest.mark.parametrize("m,k,n,sizes", [

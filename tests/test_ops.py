@@ -7,9 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, BlockDiffusion,
-    _bwd_dkv_parts_pallas, _bwd_dq_parts_pallas, _clamp_block,
-    _flash_fwd_parts_pallas, block_schedule, flash_attention)
+    _STATIC_BUDGET, _SUB, DIAGONAL, BlockDiffusion, _clamp_block,
+    block_schedule, flash_attention)
 
 
 def _make_qkv(B=1, S=128, H=2, D=64, kv_heads=None, seed=0):
@@ -507,33 +506,6 @@ def test_flash_attention_under_the_block_diffusion_rule(case):
     want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
                       jnp.repeat(v, rep, axis=2))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)
-
-
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
-def test_in_parts_kernels_refuse_a_diagonal_step(kernel):
-    """The in-parts kernels (latent attention, causal only) have no body for
-    a diagonal step: a plan that holds one is refused when the kernel is
-    built, not run as something else."""
-    rule, s, tile = BlockDiffusion(256, 4), 512, 256
-    plans = block_schedule(s, s, tile, tile, rule)
-    assert plans[kernel].steps_diagonal == 1
-    x = jnp.zeros((1, 2, s, 32), jnp.float32)
-    xr, kr = x[..., :16], x[:, :1, :, :16]
-    col = x[..., :1]
-    with pytest.raises(NotImplementedError, match="diagonal"):
-        if kernel == "fwd":
-            _flash_fwd_parts_pallas(x, xr, x, kr, x, rule, 1.0, tile, tile,
-                                    True)
-        elif kernel == "dq":
-            _bwd_dq_parts_pallas(x, xr, x, kr, x, x, col, col, rule, 1.0,
-                                 tile, plans["dq"], True)
-        else:
-            _bwd_dkv_parts_pallas(x, xr, x, kr, x, x, col, col, rule, 1.0,
-                                  tile, plans["dkv"], True)
-    # under CAUSAL, the rule they run, the same shapes build
-    o, _ = _flash_fwd_parts_pallas(x, xr, x, kr, x, CAUSAL, 1.0, tile, tile,
-                                   True)
-    assert o.shape == x.shape
 
 
 def test_block_diffusion_rule_wants_whole_blocks_over_both_halves():

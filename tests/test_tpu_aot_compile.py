@@ -39,7 +39,7 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
-from ray_tpu.models import llama, mla_moe, sdar
+from ray_tpu.models import llama, mla_moe, nemotron_h, sdar
 from ray_tpu.ops import grouped_matmul, row_moves, row_sums
 from ray_tpu.ops.flash_attention import (
     BlockDiffusion, block_schedule, flash_attention)
@@ -330,6 +330,25 @@ out["sdar_dense"] = [ln[:160] for ln in ops if dense.search(ln)]
 # T x k = 131,072 (token, slot) pairs a routed block
 out["sdar_pair_scatters"] = pair_scatters(hlo, 131072)
 
+# ONE checkpointed attention layer of `nemotron_h` (train-nemotron3-1chip:
+# GQA 32 / 2 x 128, no RoPE, B 2 x S 2048) under `_bodies`' policy, value
+# and gradient: the Pallas calls the v5e's compiler leaves in it
+attn_cfg = nemotron_h.NemotronHConfig(vocab_size=8, layers=(25,), mtp_depth=0)
+attn_p = jax.eval_shape(lambda: nemotron_h.init(
+    attn_cfg, jax.random.PRNGKey(0)))["one"]["attn"]
+attn = nemotron_h._bodies(
+    attn_cfg, jnp.broadcast_to(jnp.arange(2048), (2, 2048)), None, None)["*"]
+out["nemotron_attn_calls"] = [
+    re.match(r"%[\w.\-]+ = (.*?) custom-call\(", ln.strip())[1]
+    for ln in jax.jit(jax.value_and_grad(
+        lambda x, p: attn(x, p)[0].astype(jnp.float32).sum(),
+        argnums=(0, 1))).lower(
+            spec((2, 2048, attn_cfg.d_model), bf16),
+            on_chip(jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), attn_p))
+        ).compile().as_text().splitlines()
+    if 'custom_call_target="tpu_custom_call"' in ln]
+
 # a share's row moves in that layer (`ops/row_moves.py`): what is `select`ed
 # or gathered at a capacity's rows, and how each gather of a row tile of
 # 4,096 is reached from the entry
@@ -608,6 +627,21 @@ def test_latent_attention_in_parts_as_compiled_for_v5e(compiled):
     assert compiled["mla_flash_fwd_roofline_events"] == 1
     assert compiled["mla_flash_bwd_roofline_events"] == 2
     assert compiled["mla_parts_wide_ops"] == []
+
+
+def test_nemotron_attention_layer_saves_the_flash_residuals_for_v5e(compiled):
+    """One checkpointed attention layer of `nemotron_h` (GQA 32 / 2, the
+    whole-q form of the call) at train-nemotron3-1chip's widths and batch,
+    value and gradient. `_bodies` asks its policy to save the flash call's
+    `o` and `lse` by the names the ONE forward rule gives them, so, as in
+    the latent-attention layer above: THREE Pallas calls, not four, under
+    the output signatures `flash_fwd_roofline` and `flash_bwd_roofline`
+    read."""
+    out = "bf16[2,32,2048,128]"
+    calls = [re.sub(r"\{[^}]*\}", "", c)
+             for c in compiled["nemotron_attn_calls"]]
+    assert sorted(calls) == sorted([
+        f"({out}, f32[2,32,2048,1])", out, f"({out}, {out})"])
 
 
 @pytest.mark.parametrize("shape", ["gmm_up", "gmm_down"])
