@@ -257,9 +257,12 @@ def delta(after: Dict[str, Any], before: Dict[str, Any]) -> Dict[str, Any]:
                            "total_s": a["total_s"] - b["total_s"],
                            "max_s": a["max_s"],
                            "self_s": a["self_s"] - b["self_s"]}
+    # a name first counted between the two stays in at 0 ("counted, and it
+    # came to nothing": `flash.steps_unmasked` where every step is masked),
+    # so a ratio over it reads 0 and not "no such counter"
     counters = {name: n - before["counters"].get(name, 0)
                 for name, n in after["counters"].items()
-                if n != before["counters"].get(name, 0)}
+                if n != before["counters"].get(name)}
     return {"pid": after["pid"], "spans": spans, "counters": counters}
 
 

@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import llama, mla_moe
+from ray_tpu.models import layer_pattern, llama, mla_moe
 from ray_tpu.models.llama import _residual, _rms_norm
 from ray_tpu.ops import kda as kda_op
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUALS
@@ -105,6 +105,7 @@ class HybridMoeConfig:
     remat: bool = True
     remat_policy: str = "dots"
     loss_chunk_size: int = 0
+    score = "sigmoid"              # `mla_moe._expert_sublayer` reads it
 
     def __post_init__(self):
         for name in ("layers", "expert_swiglu_limits",
@@ -162,32 +163,8 @@ class HybridMoeConfig:
         the first indices of the whole aligned periods, each in order; and
         the execution order as segments ("dense", n), ("loose", n),
         ("periods", n) of consecutive layers / periods."""
-        held = self.held_layers
-        dense = [i for i in held if i < self.n_dense_layers]
-        rest = [i for i in held if i >= self.n_dense_layers]
-        have, loose, periods, segments = set(rest), [], [], []
-
-        def add(kind):
-            if segments and segments[-1][0] == kind:
-                segments[-1][1] += 1
-            else:
-                segments.append([kind, 1])
-
-        for _ in dense:
-            add("dense")
-        i = 0
-        while i < len(rest):
-            first = rest[i]
-            if first % self.period == 0 and all(
-                    first + j in have for j in range(self.period)):
-                periods.append(first)
-                add("periods")
-                i += self.period
-            else:
-                loose.append(first)
-                add("loose")
-                i += 1
-        return dense, loose, periods, [tuple(s) for s in segments]
+        return layer_pattern.segments(
+            self.held_layers, self.n_dense_layers, self.period)
 
     def num_params(self) -> int:
         c = self

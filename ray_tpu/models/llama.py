@@ -15,11 +15,13 @@ parallelism is native (SURVEY.md §5, §7).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu._private import device_profiler
 from ray_tpu.ops.flash_attention import flash_attention
@@ -186,18 +188,65 @@ def _qk_norm(q, k, params, config):
     return norm(q, params["q_norm"]), norm(k, params["k_norm"])
 
 
-def _rope(x, positions, theta):
-    # x: [B, S, H, D]; rotate pairs (d, d + D/2).
+class Rotary(NamedTuple):
+    """The rotary form of ONE KIND of attention layer, where a model has
+    several (`models/window_moe.py`): base `theta` (0: no rotary embedding);
+    `width`, the LEADING channels of a head that rotate, in pairs (d, d +
+    width / 2), the rest passing through (None: the whole head); `yarn`,
+    (factor, original_max_position, beta_fast, beta_slow), or None for the
+    plain frequencies; `attention_factor` multiplies cos and sin, so the
+    rotated channels of q and k carry it and the others do not."""
+    theta: float
+    width: Optional[int] = None
+    yarn: Optional[tuple] = None
+    attention_factor: float = 1.0
+
+    def inv_freq(self, d_head: int):
+        """float32 [width / 2]: theta ** (-2i / width), under `yarn`
+        blended as HF's `_compute_yarn_parameters` blends them: a pair that
+        turns more than `beta_fast` times over the original context keeps
+        its frequency, one that turns less than `beta_slow` times has it
+        divided by `factor`, a linear ramp over the pairs between."""
+        width = self.width or d_head
+        plain = self.theta ** (-np.arange(0, width, 2, dtype=np.float64)
+                               / width)
+        if self.yarn is None:
+            return jnp.asarray(plain, jnp.float32)
+        factor, original, beta_fast, beta_slow = self.yarn
+
+        def pair_turning(times):
+            return width * math.log(original / (times * 2 * math.pi)) \
+                / (2 * math.log(self.theta))
+
+        low = max(math.floor(pair_turning(beta_fast)), 0)
+        high = min(math.ceil(pair_turning(beta_slow)), width - 1)
+        ramp = np.clip((np.arange(width // 2) - low)
+                       / max(high - low, 0.001), 0, 1)
+        return jnp.asarray(plain * (1 - ramp) + plain / factor * ramp,
+                           jnp.float32)
+
+
+def _rope(x, positions, theta, rotary: Optional[Rotary] = None):
+    # x: [B, S, H, D]; rotate pairs (d, d + D/2); under `rotary`, of its
+    # leading `width` channels, at its frequencies, cos and sin scaled.
     d = x.shape[-1]
-    half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if rotary is None:
+        half = d // 2
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    else:
+        freqs = rotary.inv_freq(d)
+        half = freqs.shape[0]
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
     cos = jnp.cos(angles)[:, :, None, :]  # [B, S, 1, half]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+    if rotary is not None and rotary.attention_factor != 1.0:
+        cos, sin = cos * rotary.attention_factor, sin * rotary.attention_factor
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if 2 * half < d:
+        turned.append(x[..., 2 * half:].astype(jnp.float32))
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
 def _attention(q, k, v, config: LlamaConfig, mesh=None, mask=None):
@@ -229,10 +278,12 @@ def _flash(q, k, v, mesh, **rule):
     return flash_attention(q, k, v, **rule)
 
 
-def _qkv(x, params, positions, config: LlamaConfig, lc=None):
+def _qkv(x, params, positions, config: LlamaConfig, lc=None, rotary=None):
     """The attention prologue every sublayer shares: pre-norm, the q/k/v
-    projections, QK-norm, RoPE on q and k (none where `rope_theta` is 0). `lc` (training only) constrains
-    q and k to their logical layout between the norm and RoPE.
+    projections (as many heads as the layer's `wq` has), QK-norm, RoPE on q
+    and k: at `config.rope_theta` (none where it is 0), or in the form
+    `rotary` of the layer's kind (`Rotary`). `lc` (training only)
+    constrains q and k to their logical layout between the norm and RoPE.
     -> q [B,S,H,K], k and v [B,S,kv,K]."""
     c = config
     h = _rms_norm(x, params["attn_norm"], c.norm_eps)
@@ -243,9 +294,10 @@ def _qkv(x, params, positions, config: LlamaConfig, lc=None):
     if lc is not None:
         q = lc(q, ("batch", "seq", "act_heads", "act_kv"))
         k = lc(k, ("batch", "seq", "act_heads", "act_kv"))
-    if c.rope_theta:  # 0: no rotary embedding (`models/nemotron_h.py`)
-        q = _rope(q, positions, c.rope_theta)
-        k = _rope(k, positions, c.rope_theta)
+    theta = c.rope_theta if rotary is None else rotary.theta
+    if theta:  # 0: no rotary embedding (`models/nemotron_h.py`)
+        q = _rope(q, positions, theta, rotary)
+        k = _rope(k, positions, theta, rotary)
     return q, k, v
 
 
@@ -323,18 +375,37 @@ def _mlp_ring(h, params, mesh):
     )(h, params["w_gate"], params["w_up"], params["w_down"])
 
 
+def _head_gated(attn, h, w_gate):
+    """attn [B, S, H, K] * sigmoid(w_head . h) a head: h [B, S, D] the
+    layer's normed input, w_gate [D, H]; the gate in float32."""
+    return attn * jax.nn.sigmoid(jnp.einsum(
+        "bsd,dh->bsh", h, w_gate,
+        preferred_element_type=jnp.float32))[..., None].astype(attn.dtype)
+
+
 def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
-                   rules: Optional[LogicalAxisRules] = None, mask=None):
+                   rules: Optional[LogicalAxisRules] = None, mask=None,
+                   rotary=None):
     """Pre-norm attention block of the training layer (and of mixtral's):
-    causal, or under the static rule `mask` (`_attention`)."""
+    causal, or under the static rule `mask` (`_attention`). What may differ
+    by the KIND of a layer comes from the caller, not from `config`: the
+    rule, the rotary form (`rotary`), the number of query heads (the
+    layer's `wq`) and a gate per head on the output before `wo`,
+    attn_head * sigmoid(w_head . h), where the layer has a `w_attn_gate`
+    [D, H] (`mla_moe._mla_sublayer`'s form)."""
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
-    q, k, v = _qkv(x, params, positions, config, lc)
+    q, k, v = _qkv(x, params, positions, config, lc, rotary)
     if "tp" in _residual_seq_axes(x, mesh, rules):
         # v too leaves its projection with heads over tp, from the rows
         # gathered for q and k: left unsaid, the compiler projects the local
         # rows onto every head and turns v round with an all-to-all
         v = lc(v, ("batch", "seq", "act_heads", "act_kv"))
     attn = _attention(q, k, v, config, mesh, mask)
+    if "w_attn_gate" in params:
+        with jax.named_scope("attn.gate"):
+            # `_qkv`'s normed input: the compiler keeps one
+            h = _rms_norm(x, params["attn_norm"], config.norm_eps)
+            attn = _head_gated(attn, h, params["w_attn_gate"])
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
     return _residual(x, mesh, rules)
 

@@ -87,6 +87,8 @@ class MlaMoeConfig:
     remat: bool = True
     remat_policy: str = "dots"
     loss_chunk_size: int = 0
+    # what `_expert_sublayer` also reads of its config: a constant here
+    score = "sigmoid"
 
     def __post_init__(self):
         if self.mtp_depth not in (0, 1):
@@ -382,10 +384,7 @@ def _mla_sublayer(x, p, positions, config: MlaMoeConfig, mesh=None,
         attn = _attention(q, k, v, q_rope, k_rope, mesh)
     if c.attn_gate:
         with jax.named_scope("mla.gate"):
-            attn = attn * jax.nn.sigmoid(jnp.einsum(
-                "bsd,dh->bsh", h, p["w_attn_gate"],
-                preferred_element_type=jnp.float32))[..., None].astype(
-                    attn.dtype)
+            attn = llama._head_gated(attn, h, p["w_attn_gate"])
     device_profiler.count("mla.layers", 1)  # per lowering
     device_profiler.count("mla.attend_parts", 1)
     x = x + jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
@@ -395,7 +394,8 @@ def _mla_sublayer(x, p, positions, config: MlaMoeConfig, mesh=None,
 def _expert_sublayer(x, p, config: MlaMoeConfig, mesh=None,
                      rules: Optional[LogicalAxisRules] = None):
     """x [B, S, D] -> (x + routed + shared experts of RMSNorm(x), the
-    chosen experts [B * S, k])."""
+    chosen experts [B * S, k]). The scoring function is the config's
+    `score`; a layer without a `router_bias` chooses on the scores alone."""
     c = config
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         raise NotImplementedError(
@@ -405,7 +405,7 @@ def _expert_sublayer(x, p, config: MlaMoeConfig, mesh=None,
     h = _rms_norm(x, p["mlp_norm"], c.norm_eps)
     routed, aux = moe_layer(
         h.reshape(b * s, d), p["router"], p["experts"], c.experts_per_token,
-        c.norm_topk_prob, score="sigmoid", router_bias=p["router_bias"],
+        c.norm_topk_prob, score=c.score, router_bias=p.get("router_bias"),
         weight_scale=c.routed_scaling_factor, held=c.held,
         n_group=c.n_group, topk_group=c.topk_group)
     with jax.named_scope("moe.shared"):

@@ -1,0 +1,429 @@
+"""A decoder whose ATTENTION differs by layer: sliding-window layers among
+full (global) ones in a published pattern, each kind with its own number of
+query heads and its own rotary form, over sigmoid-routed experts beside a
+shared one (Laguna-XS.2 by config: `model_type` `laguna`), TPU-first,
+training only.
+
+All pre-norm, sharing `models/llama.py`'s RMSNorm, attention and SwiGLU
+sublayers, remat policy and chunked cross-entropy, and `models/mla_moe.py`'s
+expert sublayer. By the three per-layer lists of the config, layer i
+(published index), with h = RMSNorm(x):
+
+- *attention*, `layer_types[i]`: GQA over `heads_per_layer[i]` query heads
+  (so `wq` and `wo` differ in SHAPE by kind) and `n_kv_heads` KV heads of
+  `d_head` channels, scores scaled by d_head ** -0.5.
+  `sliding_attention`: query t sees keys t - `window` < j <= t
+  (`ops/flash_attention.SlidingWindow`: the kernels walk the tiles the
+  window touches and no others); `full_attention`: causal. RoPE in the
+  form `rope_parameters` gives the kind (`llama.Rotary`): its own theta, on
+  the leading `partial_rotary_factor` of a head, under YaRN (blended
+  frequencies, cos and sin times `attention_factor`) where `rope_type`
+  says so. With `attn_gate`, attn_head * sigmoid(w_head . h) before W_o;
+  with `qk_norm`, an RMSNorm of every head of q and k over its own
+  channels before RoPE (`llama._qk_norm`'s [D] form).
+- *feed-forward*, `mlp_layer_types[i]`: `dense`, a SwiGLU of `d_ff`;
+  `sparse`, `mla_moe._expert_sublayer` without a router bias: `score`
+  ("sigmoid" or "softmax") over `n_experts`, the top `experts_per_token`
+  on the scores, weights the chosen scores (over their sum with
+  `norm_topk_prob`) x `routed_scaling_factor`, experts SwiGLU of
+  `d_ff_expert`, plus one shared SwiGLU of `d_ff_shared` every token
+  passes, added unweighted. No auxiliary loss.
+
+`layers` lists the published indices this program holds, in order (all by
+default: the whole model). The leading dense layers are unrolled. The
+pattern's period is the distance from one full layer to the next, and a
+period here ENDS with its full layer (sliding, ..., sliding, full): the
+sparse layers that fill whole such periods run as ONE `lax.scan` over the
+stacked periods whose body is an inner scan over the period's stacked
+sliding layers and then its full layer: two layer bodies traced, whatever
+the depth. The others (published 37-39) are unrolled. Remat is per layer;
+the flash call's `o` and `lse` are saved beside what the policy saves.
+
+The share: `mla_moe`'s (`n_experts_held`, `first_expert`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from functools import partial
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu._private import device_profiler
+from ray_tpu.models import layer_pattern, llama, mla_moe
+from ray_tpu.models.llama import Rotary, _residual, _rms_norm
+from ray_tpu.ops.flash_attention import SlidingWindow
+from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+DENSE, SPARSE = "dense", "sparse"
+_SHORT = {FULL: "full", SLIDING: "sliding"}
+
+PUBLISHED_ROPE = {
+    FULL: {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+           "original_max_position_embeddings": 4096, "beta_slow": 1,
+           "beta_fast": 64, "attention_factor": 1.4158883083359672,
+           "partial_rotary_factor": 0.5},
+    SLIDING: {"rope_type": "default", "rope_theta": 10000,
+              "partial_rotary_factor": 1},
+}
+
+
+def _frozen(v):
+    """A JSON value as a hashable one: a config is a static argument."""
+    if isinstance(v, dict):
+        return tuple(sorted((k, _frozen(x)) for k, x in v.items()))
+    if isinstance(v, (list, tuple)):
+        return tuple(map(_frozen, v))
+    return v
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowMoeConfig:
+    """`layer_types`, `heads_per_layer`, `mlp_layer_types`: a layer's
+    attention kind, its query heads and its feed-forward kind by its
+    published index; `layers`: the published indices held here (None: all).
+    `rope_parameters`: the published group, {kind: its rotary form}."""
+    vocab_size: int = 100_352
+    d_model: int = 2048
+    layer_types: Tuple[str, ...] = tuple(
+        FULL if i % 4 == 0 else SLIDING for i in range(40))
+    heads_per_layer: Tuple[int, ...] = tuple(
+        48 if i % 4 == 0 else 64 for i in range(40))
+    mlp_layer_types: Tuple[str, ...] = (DENSE,) + (SPARSE,) * 39
+    layers: Optional[Tuple[int, ...]] = None
+    n_kv_heads: int = 8
+    d_head: int = 128
+    window: int = 512
+    rope_parameters: Any = _frozen(PUBLISHED_ROPE)
+    attn_gate: bool = True
+    qk_norm: bool = False
+    d_ff: int = 8192
+    d_ff_expert: int = 512
+    d_ff_shared: int = 512
+    n_experts: int = 256           # the router's outputs
+    n_experts_held: int = 256      # of them, the experts this program holds
+    first_expert: int = 0
+    experts_per_token: int = 8
+    score: str = "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    remat: bool = True
+    remat_policy: str = "dots"
+    loss_chunk_size: int = 0
+    # what the shared sublayers also read of their config: constants here
+    n_group = 1
+    topk_group = 1
+    use_ring_attention = False
+
+    def __post_init__(self):
+        for name in ("layer_types", "heads_per_layer", "mlp_layer_types",
+                     "layers", "rope_parameters"):
+            v = getattr(self, name)
+            if v is not None:
+                object.__setattr__(self, name, _frozen(v))
+        n = len(self.layer_types)
+        if not (len(self.heads_per_layer) == len(self.mlp_layer_types) == n):
+            raise ValueError("the three per-layer lists differ in length")
+        if set(self.layer_types) - {FULL, SLIDING} \
+                or set(self.mlp_layer_types) - {DENSE, SPARSE}:
+            raise ValueError("a layer's attention is full or sliding, its "
+                             "feed-forward dense or sparse")
+        held = self.held_layers
+        if list(held) != sorted(set(held)) or not held \
+                or not 0 <= held[0] <= held[-1] < n:
+            raise ValueError(f"layers {held} of {n}")
+        if not (0 <= self.first_expert
+                and self.first_expert + self.n_experts_held <= self.n_experts):
+            raise ValueError("held experts outside the router's outputs")
+        for kind in set(self.layer_types):
+            if len({h for h, t in zip(self.heads_per_layer, self.layer_types)
+                    if t == kind}) != 1:
+                raise NotImplementedError(
+                    f"{kind} layers with different numbers of heads: a "
+                    "kind's layers are stacked")
+        self.period  # raises where the pattern has none
+
+    @staticmethod
+    def tiny(vocab_size: int = 512, **over) -> "WindowMoeConfig":
+        """12 layers in the published pattern (a dense full layer, then
+        sliding x 3 : full), 6 : 4 query heads over 2 KV heads."""
+        rope = {FULL: dict(PUBLISHED_ROPE[FULL], factor=4, beta_fast=8,
+                           original_max_position_embeddings=16,
+                           rope_theta=10_000),
+                SLIDING: PUBLISHED_ROPE[SLIDING]}
+        return WindowMoeConfig(**{**dict(
+            vocab_size=vocab_size, d_model=64,
+            layer_types=tuple(FULL if i % 4 == 0 else SLIDING
+                              for i in range(12)),
+            heads_per_layer=tuple(4 if i % 4 == 0 else 6 for i in range(12)),
+            mlp_layer_types=(DENSE,) + (SPARSE,) * 11, n_kv_heads=2,
+            d_head=16, window=8, rope_parameters=rope, d_ff=128,
+            d_ff_expert=32, d_ff_shared=32, n_experts=16, n_experts_held=16,
+            experts_per_token=4), **over})
+
+    @property
+    def held_layers(self) -> Tuple[int, ...]:
+        return self.layers if self.layers is not None \
+            else tuple(range(len(self.layer_types)))
+
+    @property
+    def held(self):
+        """`moe_layer`'s `held`: None where every expert is here."""
+        if self.n_experts_held == self.n_experts:
+            return None
+        return self.first_expert, self.n_experts_held
+
+    @property
+    def n_dense_layers(self) -> int:
+        """The LEADING dense layers; a dense one further on is refused."""
+        n = next((i for i, t in enumerate(self.mlp_layer_types)
+                  if t != DENSE), len(self.mlp_layer_types))
+        if DENSE in self.mlp_layer_types[n:]:
+            raise NotImplementedError("a dense layer after a sparse one")
+        return n
+
+    @property
+    def period(self) -> int:
+        """Layers from one full layer to the next; a model of one kind of
+        attention, or of a pattern that does not repeat so, is refused."""
+        full = [i for i, t in enumerate(self.layer_types) if t == FULL]
+        steps = {b - a for a, b in zip(full, full[1:])}
+        if len(steps) != 1 or len(full) == len(self.layer_types):
+            raise NotImplementedError(
+                f"full attention at layers {full}: no one period")
+        return steps.pop()
+
+    def plan(self):
+        """`layer_pattern.segments` of the held layers: a whole period is
+        `period` consecutive held sparse layers that END with a full one."""
+        first_full = self.layer_types.index(FULL)
+        return layer_pattern.segments(
+            self.held_layers, self.n_dense_layers, self.period,
+            start=first_full + 1)
+
+    def kind(self, i: int) -> Tuple[str, str]:
+        """-> (attention, feed-forward) of published layer i, as the
+        parameter stacks are named: ("full" | "sliding", "dense" | "sparse")."""
+        return _SHORT[self.layer_types[i]], self.mlp_layer_types[i]
+
+    def heads(self, attn: str) -> int:
+        return next(h for h, t in zip(self.heads_per_layer, self.layer_types)
+                    if _SHORT[t] == attn)
+
+    def rotary(self, attn: str) -> Rotary:
+        group = dict(dict(self.rope_parameters)[
+            FULL if attn == "full" else SLIDING])
+        width = int(self.d_head * group.get("partial_rotary_factor", 1))
+        kind = group.get("rope_type", "default")
+        if kind not in ("default", "yarn"):
+            raise NotImplementedError(f"rope_type {kind!r}")
+        yarn = None if kind == "default" else (
+            group["factor"], group["original_max_position_embeddings"],
+            group.get("beta_fast", 32), group.get("beta_slow", 1))
+        return Rotary(float(group["rope_theta"]), width, yarn,
+                      float(group.get("attention_factor", 1.0)))
+
+    def num_params(self) -> int:
+        c = self
+        total = 2 * c.vocab_size * c.d_model + c.d_model
+        for i in c.held_layers:
+            attn, mlp = c.kind(i)
+            total += attn_num_params(c, c.heads(attn)) + 2 * c.d_model
+            total += 3 * c.d_model * c.d_ff if mlp == DENSE else (
+                c.d_model * c.n_experts + 3 * c.d_model
+                * (c.n_experts_held * c.d_ff_expert + c.d_ff_shared))
+        return total
+
+
+def attn_num_params(c, heads: int) -> int:
+    """The attention's parameters (no layer norm) at `heads` query heads."""
+    return (2 * c.d_model * c.d_head * (heads + c.n_kv_heads)
+            + (c.d_model * heads if c.attn_gate else 0)
+            + (2 * c.d_head if c.qk_norm else 0))
+
+
+# --------------------------------------------------------------------------
+# parameters
+# --------------------------------------------------------------------------
+
+def _layer_axes(L, config, mlp: str):
+    proj = L + ("embed", "heads", "kv")
+    axes = {"attn_norm": L + (None,), "wq": proj, "wk": proj, "wv": proj,
+            "wo": L + ("heads", "kv", "embed"), "mlp_norm": L + (None,)}
+    if config.attn_gate:
+        axes["w_attn_gate"] = L + ("embed", "heads")
+    if config.qk_norm:
+        axes.update(q_norm=L + (None,), k_norm=L + (None,))
+    if mlp == DENSE:
+        return {**axes, "w_gate": L + ("embed", "mlp"),
+                "w_up": L + ("embed", "mlp"), "w_down": L + ("mlp", "embed")}
+    routed = mla_moe._routed_axes(L)
+    del routed["router_bias"]  # the choice is on the scores themselves
+    return {**axes, **routed}
+
+
+def _stacks(config):
+    """-> ({(attention, feed-forward): unrolled layers of that kind}, the
+    scanned periods' number). A kind's unrolled layers are stacked under
+    `params["loose"]["<attention>_<feed-forward>"]`."""
+    dense, loose, periods, _ = config.plan()
+    one = {}
+    for i in dense + loose:
+        one[config.kind(i)] = one.get(config.kind(i), 0) + 1
+    return one, len(periods)
+
+
+def param_logical_axes(config: WindowMoeConfig) -> Dict[str, Any]:
+    c = config
+    L = ("layers",)
+    one, periods = _stacks(c)
+    axes = {"embed": ("vocab", "embed"), "final_norm": (None,),
+            "lm_head": ("embed", "vocab")}
+    if one:
+        axes["loose"] = {f"{attn}_{mlp}": _layer_axes(L, c, mlp)
+                         for attn, mlp in one}
+    if periods:
+        axes["periods"] = {"sliding": _layer_axes(L + (None,), c, SPARSE),
+                           "full": _layer_axes(L, c, SPARSE)}
+    return axes
+
+
+def _init_layer(config, attn: str, mlp: str, key):
+    """One layer: `mla_moe.init`'s rules (fan-in scaled normal matrices,
+    norm scales 1, the router 0.02 normal), at the kind's query heads."""
+    c = config
+    d, dh, heads = c.d_model, c.d_head, c.heads(attn)
+    ones = partial(jnp.ones, dtype=c.dtype)
+    dense = partial(mla_moe._dense, c)
+    ks = jax.random.split(key, 12)
+    p = {"attn_norm": ones((d,)),
+         "wq": dense(ks[0], (d, heads, dh), d),
+         "wk": dense(ks[1], (d, c.n_kv_heads, dh), d),
+         "wv": dense(ks[2], (d, c.n_kv_heads, dh), d),
+         "wo": dense(ks[3], (heads, dh, d), heads * dh),
+         "mlp_norm": ones((d,))}
+    if c.attn_gate:
+        p["w_attn_gate"] = dense(ks[4], (d, heads), d)
+    if c.qk_norm:
+        p.update(q_norm=ones((dh,)), k_norm=ones((dh,)))
+    if mlp == DENSE:
+        return {**p, **mla_moe._init_ffn(c, ks[5:8], (), c.d_ff)}
+    return {**p,
+            "router": (jax.random.normal(ks[5], (d, c.n_experts))
+                       * 0.02).astype(c.dtype),
+            "experts": mla_moe._init_ffn(c, ks[6:9], (c.n_experts_held,),
+                                         c.d_ff_expert),
+            "shared": mla_moe._init_ffn(c, ks[9:12], (), c.d_ff_shared)}
+
+
+def init(config: WindowMoeConfig, key) -> Dict[str, Any]:
+    """`_init_layer`'s rules; the embedding's rows N(0, 1) (`mla_moe.init`
+    on why), the head fan-in scaled."""
+    c = config
+    one, periods = _stacks(c)
+    k_embed, k_head, k_one, k_periods = jax.random.split(key, 4)
+    stack = lambda attn, mlp, key, n: jax.vmap(  # noqa: E731
+        partial(_init_layer, c, attn, mlp))(jax.random.split(key, n))
+    params = {
+        "embed": mla_moe._dense(c, k_embed, (c.vocab_size, c.d_model), 1),
+        "final_norm": jnp.ones((c.d_model,), c.dtype),
+        "lm_head": mla_moe._dense(c, k_head, (c.d_model, c.vocab_size),
+                                  c.d_model)}
+    if one:
+        params["loose"] = {
+            f"{attn}_{mlp}": stack(attn, mlp, jax.random.fold_in(k_one, j), n)
+            for j, ((attn, mlp), n) in enumerate(sorted(one.items()))}
+    if periods:
+        k_sliding, k_full = jax.random.split(k_periods)
+        params["periods"] = {
+            "sliding": jax.vmap(
+                lambda k: stack("sliding", SPARSE, k, c.period - 1))(
+                    jax.random.split(k_sliding, periods)),
+            "full": stack("full", SPARSE, k_full, periods)}
+    return params
+
+
+# --------------------------------------------------------------------------
+# blocks
+# --------------------------------------------------------------------------
+
+def _layer(x, p, positions, config, mesh, rules, attn: str, mlp: str):
+    """One layer -> (x, the chosen experts [B * S, k] or None)."""
+    c = config
+    mask = None
+    if attn == "sliding":
+        mask = SlidingWindow(c.window)
+    x = llama._attn_sublayer(x, p, positions, c, mesh, rules, mask=mask,
+                             rotary=c.rotary(attn))
+    if mlp == DENSE:
+        return llama._mlp_sublayer(x, p, c, mesh, rules), None
+    return mla_moe._expert_sublayer(x, p, c, mesh, rules)
+
+
+def forward_hidden(params, tokens, config: WindowMoeConfig, mesh=None,
+                   rules: Optional[LogicalAxisRules] = None):
+    """tokens [B, S] -> (final-norm hidden states [B, S, D], the chosen
+    experts of every sparse layer [L, B * S, k], in the layers' order)."""
+    c = config
+    b, s = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
+                                    mesh=mesh, rules=rules)
+    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
+    body = lambda attn, mlp: mla_moe._checkpointed(partial(  # noqa: E731
+        _layer, positions=positions, config=c, mesh=mesh, rules=rules,
+        attn=attn, mlp=mlp), c)
+    sliding_layer, full_layer = body("sliding", SPARSE), body("full", SPARSE)
+
+    def period(x, p):
+        x, chosen = jax.lax.scan(sliding_layer, x, p["sliding"])
+        x, last = full_layer(x, p["full"])
+        return x, jnp.concatenate([chosen, last[None]])
+
+    dense, loose, _, segments = c.plan()
+    unrolled, chosen = iter(dense + loose), []
+    done = {"periods": 0}
+    for kind, n in segments:
+        if kind == "periods":
+            first = done["periods"]
+            done["periods"] += n
+            x, e = jax.lax.scan(period, x, jax.tree.map(
+                lambda a: a[first:first + n], params["periods"]))
+            chosen.append(e.reshape((n * c.period,) + e.shape[2:]))
+            device_profiler.count("pattern.periods", n)  # per lowering
+            continue
+        for _ in range(n):
+            attn, mlp = c.kind(next(unrolled))
+            name = f"{attn}_{mlp}"
+            at = done.get(name, 0)
+            done[name] = at + 1
+            x, e = body(attn, mlp)(x, jax.tree.map(
+                lambda a: a[at], params["loose"][name]))
+            if e is not None:
+                chosen.append(e[None])
+        device_profiler.count("pattern.layers_unrolled", n)
+    x = _rms_norm(x, params["final_norm"], c.norm_eps)
+    return x, jnp.concatenate(chosen) if chosen else None
+
+
+def forward(params, tokens, config: WindowMoeConfig, mesh=None,
+            rules: Optional[LogicalAxisRules] = None):
+    """tokens [B, S] -> next-token logits [B, S, V] float32."""
+    x, _ = forward_hidden(params, tokens, config, mesh, rules)
+    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
+
+
+def loss_fn(params, batch, config: WindowMoeConfig, mesh=None,
+            rules: Optional[LogicalAxisRules] = None):
+    """Next-token CE through `llama.chunked_ce`, masked by batch["mask"]
+    when given. Scalar return (make_train_step contract)."""
+    c = config
+    inputs, targets, mask = mla_moe._split(batch)
+    hidden, _ = forward_hidden(params, inputs, c, mesh, rules)
+    return llama.chunked_ce(hidden, params["lm_head"], targets, mask,
+                            chunk=c.loss_chunk_size or inputs.shape[1])

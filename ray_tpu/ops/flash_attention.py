@@ -122,6 +122,30 @@ class BlockDiffusion(NamedTuple):
         return self.length * self.length + self.length * self.block
 
 
+class SlidingWindow(NamedTuple):
+    """Causal within a window: query i sees the `window` keys that end at
+    its own position, i - window < j <= i (itself included). Keeps
+    min(i + 1, window) scores a row. A tile below the diagonal is kept
+    whole only while the window spans it; the window's TRAILING tile is a
+    strict upper triangle (the keys a query has just lost), so it keeps
+    scores off its diagonal sub-tiles and is never a `DIAGONAL` step."""
+    window: int
+    scope = "swa.attend"
+
+    def keep(self, q_pos, k_pos):
+        return (q_pos >= k_pos) & (q_pos - k_pos < self.window)
+
+    def tile(self, q0, nq, k0, nk):
+        # q - k over the tile is every integer of [nearest, farthest]
+        nearest, farthest = q0 - (k0 + nk - 1), q0 + nq - 1 - k0
+        return (farthest >= 0 and nearest < self.window,
+                nearest >= 0 and farthest < self.window)
+
+    def needed(self, s_q, s_k):
+        return sum(min(max(r + s_k - s_q + 1, 0), self.window, s_k)
+                   for r in range(s_q))
+
+
 def _rule(causal, mask=None):
     """The rule a call runs under: `mask` if given, else what `causal`
     (a bool, or a rule already) stands for."""
@@ -885,10 +909,11 @@ def flash_attention(
 
     `mask`: a static rule for which (query, key) pairs are kept, in place
     of `causal` (which is the rule `CAUSAL`): e.g. `BlockDiffusion(length,
-    block)`. The kernels skip the tiles it keeps nothing of, run those it
-    keeps whole with no mask, and apply its predicate in the others, on a
-    tile's diagonal sub-tiles alone where they hold all it keeps
-    (`block_schedule`); no dense mask is built on the TPU path.
+    block)` or `SlidingWindow(window)`. The kernels skip the tiles it keeps
+    nothing of, run those it keeps whole with no mask, and apply its
+    predicate in the others, on a tile's diagonal sub-tiles alone where
+    they hold all it keeps (`block_schedule`); no dense mask is built on
+    the TPU path.
 
     In parts (latent attention): with `q_rope` [B, S, H, R] and `k_rope`
     [B, S, 1, R], ONE rotary key a (batch, position) for all heads, a score
@@ -915,6 +940,8 @@ def flash_attention(
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu" and not interpret
     mask = _rule(causal, mask)
+    if isinstance(mask, SlidingWindow):
+        device_profiler.count("flash.window_calls", 1)  # per lowering
     if q_rope is not None:
         return _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale,
                                block_q, block_k, use_pallas, interpret)

@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _STATIC_BUDGET, _SUB, DIAGONAL, BlockDiffusion, _clamp_block,
-    block_schedule, flash_attention)
+    _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, BlockDiffusion, SlidingWindow,
+    _clamp_block, _reference_attention, block_schedule, flash_attention)
 
 
 def _make_qkv(B=1, S=128, H=2, D=64, kv_heads=None, seed=0):
@@ -113,12 +113,24 @@ def _dense_block_diffusion(length, block):
         | (clean[None] & (blk[None] < blk[:, None]))
 
 
+def _dense_window(s_q, s_k, window):
+    """bool [s_q, s_k], written out from the rule's words: row r stands at
+    position r + s_k - s_q and sees the `window` keys that end there."""
+    at = np.arange(s_q)[:, None] + (s_k - s_q)
+    key = np.arange(s_k)[None, :]
+    return (key <= at) & (key > at - window)
+
+
 def _mask(s_q, s_k, causal, rows, cols):
-    """The dense mask of `causal` (True, False or a block-diffusion rule)
-    over a rows x cols padded area."""
+    """The dense mask of `causal` (True, False, a block-diffusion rule or a
+    window) over a rows x cols padded area."""
     r = np.arange(rows)[:, None]
     c = np.arange(cols)[None, :]
     m = (r < s_q) & (c < s_k)
+    if isinstance(causal, SlidingWindow):
+        rule = np.zeros((rows, cols), bool)
+        rule[:s_q, :s_k] = _dense_window(s_q, s_k, causal.window)
+        return m & rule
     if isinstance(causal, BlockDiffusion):
         rule = np.zeros((rows, cols), bool)
         rule[:s_q, :s_k] = _dense_block_diffusion(causal.length, causal.block)
@@ -170,6 +182,18 @@ _SCHEDULES = {
                           6.31, 6.31),
     "bd-l1280-b4-loops": ((2560, 2560, 128, 128, BlockDiffusion(1280, 4)),
                           1.2, 1.2),
+    # a window: 31 of the 256 tiles of 512 x 512 for 4,063,488 kept scores
+    "swa-w512-s8192": ((8192, 8192, None, None, SlidingWindow(512)),
+                       2.0, 2.0),
+    # windows and lengths that do not divide each other, blocks that differ
+    "swa-w1000-s2048-256x512": ((2048, 2048, 256, 512, SlidingWindow(1000)),
+                                1.27, 1.53),
+    "swa-w100-s320-padded": ((320, 320, 128, 128, SlidingWindow(100)),
+                             3.03, 3.03),
+    "swa-w200-cross-128-over-384": ((128, 384, 128, 128, SlidingWindow(200)),
+                                    1.92, 1.92),
+    "swa-w50-more-queries-than-keys": (
+        (384, 128, 128, 128, SlidingWindow(50)), 3.17, 3.17),
 }
 
 
@@ -334,8 +358,9 @@ def test_flash_attention_interior_and_edge_steps(case):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-@pytest.mark.parametrize("rule", [True, BlockDiffusion(256, 4)],
-                         ids=["causal", "block-diffusion"])
+@pytest.mark.parametrize("rule", [True, BlockDiffusion(256, 4),
+                                  SlidingWindow(100)],
+                         ids=["causal", "block-diffusion", "window"])
 def test_flash_attention_counts_its_steps(rule):
     """Building the kernels adds the schedule's step counts to the
     process's counters (per lowering, not per run); a diagonal step is a
@@ -347,10 +372,13 @@ def test_flash_attention_counts_its_steps(rule):
     plans = block_schedule(512, 512, 256, 256, rule)
     # under the rule: the one x_t diagonal tile, in each of three kernels
     assert sum(p.steps_diagonal for p in plans.values()) == (
-        0 if rule is True else 3)
+        3 if isinstance(rule, BlockDiffusion) else 0)
     before = device_profiler.snapshot()["counters"]
     jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **how)))(q)
     after = device_profiler.snapshot()["counters"]
+    # a call under the window rule, and no other, counts itself
+    assert after.get("flash.window_calls", 0) - before.get(
+        "flash.window_calls", 0) == isinstance(rule, SlidingWindow)
     for name, field in (("flash.steps_unmasked", "steps_unmasked"),
                         ("flash.steps_masked", "steps_masked"),
                         ("flash.steps_diagonal", "steps_diagonal"),
@@ -422,6 +450,7 @@ _DKV_PLANS = {
     "causal-s4096": (4096, True, 36, False),     # collapsed unrolled, PR 26
     "block-diffusion-l3072": (6144, BlockDiffusion(3072, 4), 48, False),
     "causal-s8192": (8192, True, 136, False),
+    "window-512-s8192": (8192, SlidingWindow(512), 31, False),
 }
 
 
@@ -503,6 +532,120 @@ def test_flash_attention_under_the_block_diffusion_rule(case):
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, axis=2)) \
         / 32 ** 0.5
     scores = jnp.where(_dense_block_diffusion(length, block), scores, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
+                      jnp.repeat(v, rep, axis=2))
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("s_q, s_k, window", [
+    (8192, 8192, 512), (300, 300, 77), (100, 260, 64), (260, 100, 64),
+    (64, 64, 1), (64, 64, 1000)])
+def test_sliding_window_rule_against_the_dense_mask(s_q, s_k, window):
+    """`keep`, `needed` and `tile` (in closed form: no tile is built) against
+    the dense mask, for windows and lengths that do not divide each other
+    and for s_q != s_k; tiles of every alignment, some past the end."""
+    rule, offset = SlidingWindow(window), s_k - s_q
+    dense = _dense_window(s_q, s_k, window)
+    np.testing.assert_array_equal(
+        rule.keep(np.arange(s_q)[:, None] + offset, np.arange(s_k)[None]),
+        dense)
+    assert rule.needed(s_q, s_k) == dense.sum()
+    if (s_q, window) == (8192, 512):
+        assert rule.needed(s_q, s_k) == 4_063_488
+        assert CAUSAL.needed(s_q, s_k) == 33_558_528
+    size = 512 if s_q > 512 else 48
+    padded = np.zeros((s_q + 2 * size, s_k + 2 * size), bool)
+    for r in range(padded.shape[0]):   # positions past the end keep the rule
+        padded[r] = rule.keep(np.full(padded.shape[1], r + offset),
+                              np.arange(padded.shape[1]))
+    for q0 in range(0, s_q, size // 3 * 2):
+        for k0 in range(0, s_k, size // 2):
+            for nq, nk in ((size, size), (size // 2, size), (1, 7)):
+                kept = padded[q0:q0 + nq, k0:k0 + nk]
+                assert rule.tile(q0 + offset, nq, k0, nk) == (
+                    bool(kept.any()), bool(kept.all())), (q0, nq, k0, nk)
+
+
+def test_sliding_window_schedule_at_the_cell_shape():
+    """train-laguna-1chip's two calls a period, S 8,192 in tiles of 512.
+    The window layers: a forward row walks its own tile and the one before
+    it (2 steps, not up to 16), both masked: the diagonal tile as under
+    `CAUSAL`, the window's TRAILING tile a strict upper triangle, which
+    keeps scores off its diagonal sub-tiles and so is never `DIAGONAL`.
+    Forward and dq are unrolled; dk/dv has 31 steps in all, over its budget
+    of 28: one loop, every step masked. The full layers run `CAUSAL` in
+    loops throughout (rows of up to 16, 136 steps)."""
+    rule = SlidingWindow(512)
+    plans = block_schedule(8192, 8192, 512, 512, rule)
+    for name, plan in plans.items():
+        # the first queries have no tile before theirs, the last keys
+        # none after
+        assert [len(r) for r in plan.rows] == (
+            [2] * 15 + [1] if name == "dkv" else [1] + [2] * 15)
+        assert (plan.steps_unmasked, plan.steps_masked, plan.steps_diagonal,
+                plan.steps_skipped) == (0, 31, 0, 225)
+        assert plan.static == (name != "dkv")
+        assert plan.executed_over_needed == pytest.approx(
+            31 * 512 * 512 / 4_063_488) == pytest.approx(2.0, rel=1e-3)
+    assert plans["fwd"].rows[5] == ((4, True), (5, True))
+    assert plans["dkv"].rows[5] == ((5, True), (6, True))
+    some, every = rule.tile(5 * 512, 512, 4 * 512, 512)
+    assert some and not every
+    # the trailing tile's sub-tile (0, 1), above its diagonal, is kept whole
+    assert rule.tile(5 * 512, 128, 4 * 512 + 128, 128) == (True, True)
+    causal = block_schedule(8192, 8192, 512, 512, True)
+    for plan in causal.values():
+        assert not plan.static and len(plan.tiles) == 136
+        assert max(map(len, plan.rows)) == 16 and plan.steps_skipped == 120
+
+
+# (q heads, kv heads, window, tile): the cell's 48 / 8 and 64 / 8 scaled
+# down; a window smaller than, equal to and larger than a tile, and one that
+# divides nothing; S 512 (384 for the padded case)
+_WINDOWS = {
+    "6-to-1-window-below-the-tile": (6, 1, 50, 128, 512),
+    "8-to-1-window-is-the-tile": (8, 1, 128, 128, 512),
+    "6-to-1-window-above-the-tile": (6, 1, 300, 128, 512),
+    "8-to-1-window-512-loops": (8, 1, 64, 64, 1024),
+    "8-to-1-padded": (8, 1, 100, 256, 384),
+    "6-to-1-window-past-the-sequence": (6, 1, 1000, 128, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOWS))
+def test_flash_attention_under_the_sliding_window_rule(case):
+    """Forward and the three gradients of the Pallas kernels (interpret
+    mode) under `mask=SlidingWindow(w)` against `_reference_attention`,
+    which builds the DENSE mask; GQA; and the oracle against attention
+    written out with the mask in the rule's words."""
+    heads, kv_heads, window, tile, s = _WINDOWS[case]
+    rule = SlidingWindow(window)
+    plans = block_schedule(s, s, tile, tile, rule)
+    if case == "8-to-1-window-512-loops":
+        assert not plans["dkv"].static and plans["fwd"].static
+    if case == "6-to-1-window-past-the-sequence":   # then it is causal
+        assert plans == block_schedule(s, s, tile, tile, True)
+    q, k, v = _make_qkv(S=s, H=heads, kv_heads=kv_heads, D=32, seed=window)
+
+    def loss(q, k, v, **how):
+        out = flash_attention(q, k, v, mask=rule, **how)
+        return jnp.sum(out ** 2), out
+
+    (_, out), g1 = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, interpret=True, block_q=tile, block_k=tile)
+    (_, ref), g2 = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, use_pallas=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+    t = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+    rep = heads // kv_heads
+    np.testing.assert_array_equal(ref, t(_reference_attention(
+        t(q), t(jnp.repeat(k, rep, axis=2)), t(jnp.repeat(v, rep, axis=2)),
+        rule, 32 ** -0.5)))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, axis=2)) \
+        / 32 ** 0.5
+    scores = jnp.where(_dense_window(s, s, window), scores, -jnp.inf)
     want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
                       jnp.repeat(v, rep, axis=2))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)

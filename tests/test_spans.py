@@ -131,6 +131,13 @@ def test_delta_of_two_snapshots():
     assert got["spans"]["t.delta"]["total_s"] >= 0.002
     assert got["counters"] == {"t.delta.n": 1}
     assert dp.delta(dp.snapshot(), dp.snapshot())["spans"] == {}
+    # a name first counted in between rides along at 0; one that stood
+    # before and did not move does not
+    before = dp.snapshot()
+    count("t.delta.none_of_them", 0)
+    count("t.delta.n", 0)
+    assert dp.delta(dp.snapshot(), before)["counters"] == {
+        "t.delta.none_of_them": 0}
 
 
 def test_span_survives_an_exception_and_leaves_the_stack_clean():

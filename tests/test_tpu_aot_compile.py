@@ -39,10 +39,10 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
-from ray_tpu.models import llama, mla_moe, nemotron_h, sdar
+from ray_tpu.models import llama, mla_moe, nemotron_h, sdar, window_moe
 from ray_tpu.ops import grouped_matmul, row_moves, row_sums
 from ray_tpu.ops.flash_attention import (
-    BlockDiffusion, block_schedule, flash_attention)
+    BlockDiffusion, SlidingWindow, block_schedule, flash_attention)
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import logical_sharding, param_shardings
@@ -329,6 +329,44 @@ out["sdar_custom_calls"] = sum(
 out["sdar_dense"] = [ln[:160] for ln in ops if dense.search(ln)]
 # T x k = 131,072 (token, slot) pairs a routed block
 out["sdar_pair_scatters"] = pair_scatters(hlo, 131072)
+
+# train-laguna-1chip's two flash calls at S 8,192 over 8 KV heads, as
+# `llama._attention` makes them: a window layer's, [1, 8192, 64, 128] under
+# `SlidingWindow(512)` in its scope, and a full layer's, [1, 8192, 48, 128],
+# causal; under the layer's remat policy as `window_moe` runs them, value
+# and gradient: the three kernels each, how the scope shows in their names,
+# and which of the cell's metrics would read each
+laguna = window_moe.WindowMoeConfig()
+laguna_queries = {}
+for name in ("swa_flash_fwd_roofline", "swa_flash_bwd_roofline",
+             "swa_attention_time_share", "laguna_full_attention_time_share"):
+    with open(os.path.join(os.environ["REPO_ROOT"], "benchmarks", "metrics",
+                           name + ".json")) as f:
+        laguna_queries[name] = re.compile(json.load(f)["trace_query"]["op"])
+for cell_call, n_heads, window in (
+        ("laguna_window", 64, SlidingWindow(512)), ("laguna_full", 48, None)):
+    attend = mla_moe._checkpointed(
+        lambda q, k, v, window=window: llama._attention(
+            q, k, v, laguna, None, window), laguna)
+    try:
+        laguna_hlo = jax.jit(jax.value_and_grad(
+            lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))).lower(
+                spec((1, 8192, n_heads, 128), bf16),
+                spec((1, 8192, 8, 128), bf16),
+                spec((1, 8192, 8, 128), bf16)).compile().as_text()
+    except Exception as e:  # noqa: BLE001 - a refusal is the finding
+        out[cell_call] = str(e)[:300]
+        continue
+    laguna_calls = [ln.strip() for ln in laguna_hlo.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in ln]
+    out[cell_call] = sorted(re.sub(r"\{[^}]*\}", "", re.match(
+        r"%[\w.\-]+ = (.*?) custom-call\(", ln)[1]) for ln in laguna_calls)
+    out[cell_call + "_scoped"] = ["swa.attend" in ln.split(" = ")[0]
+                                  for ln in laguna_calls]
+    out[cell_call + "_read_by"] = {
+        metric: sum(1 for ln in laguna_calls if query.search(ln))
+        for metric, query in laguna_queries.items()}
 
 # ONE checkpointed attention layer of `nemotron_h` (train-nemotron3-1chip:
 # GQA 32 / 2 x 128, no RoPE, B 2 x S 2048) under `_bodies`' policy, value
@@ -725,6 +763,30 @@ def test_flash_under_the_block_diffusion_rule_compiles_for_v5e(compiled):
         name: [True, 12, 12, 4] for name in ("fwd", "dq", "dkv")}
     assert compiled["flash_bd"] == "compiled"
     assert compiled["flash_bd_dense"] == []
+
+
+def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
+    """train-laguna-1chip's two calls, value and gradient, as the v5e's
+    compiler takes them: the window layer's three kernels at `[1, 64, 8192,
+    128]` (forward and dq unrolled at 2 steps a row, dk/dv a loop over 31)
+    and the full layer's at `[1, 48, 8192, 128]` (`CAUSAL`, loops of up to
+    16 and of 136), K and V of the whole sequence in VMEM. The window calls
+    carry their scope in their names (`%swa.attend.3`; the forward of a
+    layer outside a scan `%jvp_swa.attend_.1`), which is how the cell's four
+    attention metrics tell them from the full ones in one trace."""
+    out = "bf16[1,{},8192,128]".format
+    for name, heads in (("laguna_window", 64), ("laguna_full", 48)):
+        assert compiled[name] == sorted([
+            f"({out(heads)}, f32[1,{heads},8192,1])", out(heads),
+            f"({out(heads)}, {out(heads)})"]), compiled[name]
+    assert compiled["laguna_window_scoped"] == [True] * 3
+    assert compiled["laguna_full_scoped"] == [False] * 3
+    assert compiled["laguna_window_read_by"] == {
+        "swa_flash_fwd_roofline": 1, "swa_flash_bwd_roofline": 2,
+        "swa_attention_time_share": 3, "laguna_full_attention_time_share": 0}
+    assert compiled["laguna_full_read_by"] == {
+        "swa_flash_fwd_roofline": 0, "swa_flash_bwd_roofline": 0,
+        "swa_attention_time_share": 0, "laguna_full_attention_time_share": 3}
 
 
 def test_sdar_layer_as_compiled_for_v5e(compiled):
