@@ -128,7 +128,11 @@ class SlidingWindow(NamedTuple):
     min(i + 1, window) scores a row. A tile below the diagonal is kept
     whole only while the window spans it; the window's TRAILING tile is a
     strict upper triangle (the keys a query has just lost), so it keeps
-    scores off its diagonal sub-tiles and is never a `DIAGONAL` step."""
+    scores off its diagonal sub-tiles and is never a `DIAGONAL` step. What
+    a row of tiles keeps is a BAND: each 128-row group's keys are one run
+    of sub-tiles, a sub-tile on from the group's before (at window = tile
+    = 512: 5 of the 8 its own and the trailing tile span), which an
+    unrolled plan runs as ONE `Band` step in their place."""
     window: int
     scope = "swa.attend"
 
@@ -189,6 +193,17 @@ DIAGONAL = "diagonal"
 _SUB = 128
 
 
+class Band(NamedTuple):
+    """A step's kind too (truthy: a masked step): a grid row's ONLY step,
+    in place of the whole tiles it would walk. Each `_SUB`-row group of the
+    owned block runs against its own `run` sub-tiles of the walked axis,
+    which hold every score the rule keeps of the group: group g's start
+    `shift + g * _SUB` positions from the owned block's first, under the
+    predicate. Rows of one kind run ONE body, whichever the row."""
+    shift: int
+    run: int
+
+
 class KernelSchedule(NamedTuple):
     """One kernel's loop plan over one (batch, head): `tiles` are
     (q_start, q_rows, k_start, k_cols, masked), one per loop step, and
@@ -197,8 +212,12 @@ class KernelSchedule(NamedTuple):
     `static`: every grid row's steps run as straight-line code, each masked
     only if it needs it, and as `DIAGONAL` (`steps_diagonal` of the
     `steps_masked`) where the rule keeps nothing off the tile's diagonal
-    sub-tiles; otherwise ONE loop a grid row over `table`, every step
-    masked whole if any step is (`_run_row`)."""
+    sub-tiles, and a row whose groups each keep one run of sub-tiles, a
+    sub-tile on from the group before, as ONE `Band` step (`steps_band` of
+    the `steps_masked`; its tile starts where group 0's run does and spans
+    all the groups' runs, its row entry holds the index of the first whole
+    tile it stands for); otherwise ONE loop a grid row over `table`, every
+    step masked whole if any step is (`_run_row`)."""
     width: int
     static: bool
     tiles: tuple
@@ -206,6 +225,7 @@ class KernelSchedule(NamedTuple):
     steps_unmasked: int
     steps_masked: int
     steps_diagonal: int
+    steps_band: int
     steps_skipped: int
     executed_over_needed: float
 
@@ -252,6 +272,42 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
     def plan(kernel, width, n_rows, n_steps, tile, inside):
         """tile(row, step) -> (q0, nq, k0, nk); inside(step): the step lies
         within the true length of the axis the row walks."""
+
+        def owned_and_walked(i, j):
+            q0, nq, k0, nk = tile(i, j)
+            return ((q0, nq), (k0, nk)) if kernel == "fwd" \
+                else ((k0, nk), (q0, nq))
+
+        def band(i, steps):
+            """The `Band` step that does this grid row's whole-tile `steps`
+            on fewer sub-tiles, or None: every group keeps ONE run of
+            sub-tiles of the tiles' span, all as long, each a sub-tile on
+            from the last, no longer than two steps (a step's scores
+            [groups, _SUB, run * _SUB] have VMEM to fit)."""
+            if rule is None or not steps or [j for j, _ in steps] != list(
+                    range(steps[0][0], steps[-1][0] + 1)):
+                return None
+            (own0, own), (walked0, wide) = owned_and_walked(i, steps[0][0])
+            if own % _SUB or own == _SUB or wide % _SUB or own % wide:
+                return None
+            runs = set()
+            for a in range(0, own, _SUB):
+                kept = [b for b in range(0, len(steps) * wide, _SUB)
+                        if (rule.tile(own0 + a + offset, _SUB,
+                                      walked0 + b, _SUB) if kernel == "fwd"
+                            else rule.tile(walked0 + b + offset, _SUB,
+                                           own0 + a, _SUB))[0]]
+                if not kept or kept != list(
+                        range(kept[0], kept[0] + len(kept) * _SUB, _SUB)):
+                    return None
+                runs.add((walked0 + kept[0] - a - own0, len(kept)))
+            if len(runs) > 1:
+                return None
+            (shift, run), = runs
+            now = sum(1 if m == DIAGONAL else wide // _SUB for _, m in steps)
+            return Band(shift, run) if run < now and run * _SUB <= 2 * wide \
+                else None
+
         kept = []
         for i in range(n_rows):
             steps = []
@@ -266,23 +322,43 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
                     masked = DIAGONAL
                 steps.append((j, masked))
             kept.append(steps)
+        skipped = n_rows * n_steps - sum(map(len, kept))
         measure, budget = _STATIC_BUDGET[kernel]
+        banded = [[(steps[0][0], b)] if (b := band(i, steps)) else steps
+                  for i, steps in enumerate(kept)]
+        if measure(map(len, banded)) <= budget:
+            kept = banded  # a band step is straight-line code
         static = measure(map(len, kept)) <= budget
         if not static:
             any_masked = any(m for steps in kept for _, m in steps)
             kept = [[(j, any_masked) for j, _ in steps] for steps in kept]
         by_row = tuple(map(tuple, kept))
-        tiles = tuple(tile(i, j) + (masked,)
+
+        def tile_of(i, j, masked):
+            if not isinstance(masked, Band):
+                return tile(i, j) + (masked,)
+            (own0, own), _ = owned_and_walked(i, j)
+            owned = (own0, own)
+            walked = (own0 + masked.shift, own - _SUB + masked.run * _SUB)
+            return (owned + walked if kernel == "fwd" else walked + owned) \
+                + (masked,)
+
+        tiles = tuple(tile_of(i, j, masked)
                       for i, steps in enumerate(by_row) for j, masked in steps)
         masked = sum(bool(t[4]) for t in tiles)
-        # a diagonal step executes its sub-tiles alone
-        executed = sum(t[1] * (_SUB if t[4] == DIAGONAL else t[3])
-                       for t in tiles)
+
+        def executed(q0, nq, k0, nk, masked):
+            if isinstance(masked, Band):  # each group its run alone
+                return (nq if kernel == "fwd" else nk) * masked.run * _SUB
+            # a diagonal step executes its sub-tiles alone
+            return nq * (_SUB if masked == DIAGONAL else nk)
+
         return KernelSchedule(
             width, static, tiles, by_row, len(tiles) - masked, masked,
             sum(t[4] == DIAGONAL for t in tiles),
-            n_rows * n_steps - len(tiles),
-            executed / needed if needed else float("inf"))
+            sum(isinstance(t[4], Band) for t in tiles), skipped,
+            sum(executed(*t) for t in tiles) / needed if needed
+            else float("inf"))
 
     w = _step_width(block_q, block_k, rule)
     keys = plan(
@@ -316,7 +392,17 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     (1 / n of the tile's matmuls and exponentials; every score left out is
     one the mask sets to exactly 0). What decides is the rule's answer, not
     its type: `CAUSAL`'s diagonal tiles keep 10 of their 16 sub-tiles, so
-    no causal plan has such a step.
+    no causal plan has such a step. A grid row's steps TOGETHER are asked
+    the same way: where every 128-row group of the owned block keeps one
+    contiguous run of sub-tiles of the tiles' span, each group's as long
+    and a sub-tile on from the group's before (a window's rows: the kept
+    band runs along the diagonal), on fewer sub-tiles than the steps
+    execute and no longer than two steps, an unrolled plan runs the row as
+    ONE `Band` step, each group against its own run under the predicate;
+    every score left out is again one the mask sets to exactly 0. `CAUSAL`
+    groups keep runs of 1, 2, 3, ... sub-tiles that all start at 0 and a
+    block-diffusion row's kept tiles are not one range, so neither has a
+    band step; a plan too long to unroll with them keeps its whole tiles.
 
     `executed_over_needed` is scores executed over scores the rule keeps.
     Starting point (before PR 26): steps of block_q x block_k whatever the
@@ -327,7 +413,9 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     1.125 at S 4096; block diffusion at length 2,048, block 4 runs 24 of
     the 64 tiles of 512 x 512 for 4,202,496 kept scores, 1.497 as whole
     tiles, and its 4 x_t diagonal tiles (2,048 kept scores each) as
-    diagonal steps: 21 tiles' area, 1.310.
+    diagonal steps: 21 tiles' area, 1.310; `SlidingWindow(512)` at S 8,192
+    walked 31 tiles for 4,063,488 kept scores, 2.0, and runs 15 band steps
+    of 4 x [128, 640] and the first row's own tile: 1.274 (PR 48).
     """
     return _block_schedule(s_q, s_k, block_q, block_k, _rule(causal))
 
@@ -342,14 +430,18 @@ def _count_steps(*plans):
     # of the masked ones: run on the tile's diagonal sub-tiles alone
     device_profiler.count("flash.steps_diagonal",
                           sum(p.steps_diagonal for p in plans))
+    # and those that are a grid row's one `Band` step
+    device_profiler.count("flash.steps_band",
+                          sum(p.steps_band for p in plans))
     device_profiler.count("flash.tiles_skipped",
                           sum(p.steps_skipped for p in plans))
 
 
-def _run_row(plan, row, steps_ref, body, carry, finish, diagonal):
+def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
     `body(masked)` -> a step (index, carry) -> carry; `diagonal` such a
-    step for the plan's `DIAGONAL` ones.
+    step for the plan's `DIAGONAL` ones; `band(kind)` -> the carry after a
+    row's ONE `Band` step, which starts from none.
 
     The trip counts depend on the grid row, and Mosaic schedules nothing
     across the iterations of a loop: the MXU then waits out every step's
@@ -358,11 +450,17 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal):
     line code in which one step's matmuls run under its neighbours'
     softmax (1.3 ms), each step masked only if it needs it, and on its
     diagonal sub-tiles alone where they hold all the rule keeps of it (a
-    quarter of the work at 512). Otherwise the row's steps come from
-    `steps_ref` (`KernelSchedule.table`, in SMEM) and run in ONE loop, each
-    masked whole if any step of the plan is: on the v5e the mask costs 2%
-    of the kernel (it is not bound by the vector ALUs) and a second loop
-    5-8% (PERF.md §6, PR 26)."""
+    quarter of the work at 512); a row that is a band of sub-tiles (under
+    a window of 512 each 128-row group's 5 of the 8 its two tiles span) is
+    ONE step over the band, the groups a batch as a diagonal step's are,
+    and the forward rescales nothing for a second; rows of one kind of
+    band share ONE branch, the band placed by the grid row (the window
+    call's three kernels 9.67 -> 7.03 ms, 6.80 so; PERF.md §6, PR 48).
+    Otherwise the row's steps come from `steps_ref`
+    (`KernelSchedule.table`, in SMEM) and run in ONE loop, each masked
+    whole if any step of the plan is: on the v5e the mask costs 2% of the
+    kernel (it is not bound by the vector ALUs) and a second loop 5-8%
+    (PERF.md §6, PR 26)."""
     from jax.experimental import pallas as pl
 
     if not plan.static:
@@ -374,29 +472,61 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal):
 
     def branch(mine):
         def run():
+            if isinstance(mine[0][1], Band):
+                return finish(band(mine[0][1]))
             c = carry
             for j, masked in mine:
                 c = (diagonal if masked == DIAGONAL else body(masked))(j, c)
             finish(c)
         return run
 
+    # a band lies as far from its row's own block whichever the row: the
+    # rows of one kind of band share a branch (a window's 15 at S 8,192)
+    rows = {}
     for i, mine in enumerate(plan.rows):
-        pl.when(row == i)(branch(mine))
+        kind = mine[0][1]
+        rows.setdefault(kind if isinstance(kind, Band) else i, []).append(i)
+    for same in rows.values():
+        pl.when(functools.reduce(jnp.logical_or, [row == i for i in same]))(
+            branch(plan.rows[same[0]]))
 
 
 def _grouped(x):
     """[n * _SUB, ...] -> [n, _SUB, ...], of every array in `x`: the rows of
-    a square tile as the n groups a diagonal step runs, each against its OWN
-    `_SUB` positions of the walked axis (the kernels' matmuls then take the
-    groups as a batch). On the chip the batch beats n sub-steps on slices of
-    the carry: the forward 3.14 ms for 3.97, with no diagonal step 3.39
-    (PERF.md §6, PR 38)."""
+    an owned block as the n groups a diagonal or a band step runs, each
+    against its OWN positions of the walked axis (the kernels' matmuls then
+    take the groups as a batch). On the chip the batch beats n sub-steps on
+    slices of the carry: the forward 3.14 ms for 3.97, with no diagonal
+    step 3.39 (PERF.md §6, PR 38); a band step's groups as four sub-steps
+    straight from the refs: the forward 2.32 ms for the batch's 1.96, dq
+    and dk/dv within 5% either way (PERF.md §6, PR 48)."""
     return jax.tree.map(lambda a: a.reshape(-1, _SUB, *a.shape[1:]), x)
 
 
 def _flat(x):
     """`_grouped`'s inverse."""
     return jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), x)
+
+
+def _span(own_pos):
+    """How much of the walked axis a step reads whose owned block's
+    positions are `own_pos`, one a score ([rows, cols]: cols; groups [n,
+    _SUB, cols]: their runs overlap, each a sub-tile on from the last)."""
+    groups = own_pos.shape[0] if own_pos.ndim == 3 else 1
+    return (groups - 1) * _SUB + own_pos.shape[-1]
+
+
+def _runs(x, n):
+    """[(n - 1) * _SUB + cols, ...] -> [n, cols, ...], of every array in
+    `x`: what `_span` read of the walked axis as each group's own run. A
+    diagonal step's (cols = `_SUB`) do not overlap: `_grouped`."""
+    def runs(a):
+        cols = a.shape[0] - (n - 1) * _SUB
+        if cols == _SUB:
+            return _grouped(a)
+        return jnp.stack([a[g * _SUB:g * _SUB + cols] for g in range(n)])
+
+    return jax.tree.map(runs, x)
 
 
 def _walked_pos(start, shape):
@@ -519,20 +649,24 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
     # Causal with s_q != s_k (decode-style): query i corresponds to key
     # position i + (seq_k - seq_q), matching the oracle's tril(k=s_k-s_q).
     causal_offset = seq_k - seq_q
-    q_pos = (qi * block_q + causal_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
+
+    def own_pos(cols):
+        return (qi * block_q + causal_offset
+                + jax.lax.broadcasted_iota(jnp.int32, (block_q, cols), 0))
+
+    q_pos = own_pos(width)
 
     def attend(q, q_pos, start, masked, carry):
-        """(o, m, l) of the queries `q` at `q_pos` after the keys [start,
-        start + width); `_grouped` queries: each group after its own
-        `_SUB` of them."""
-        o, m, l = carry
-        rows = pl.dslice(start, width)
+        """(o, m, l) of the queries `q` at `q_pos` ([.., keys a query]:
+        its position, one a score) after the keys from `start` on, or with
+        no `carry` of these keys alone; `_grouped` queries: each group after
+        its own run of them, a sub-tile on from the last group's."""
+        rows = pl.dslice(start, _span(q_pos))
         k_blk = _loaded(k_refs, rows)
         v_blk = v_ref[0, 0, rows, :].astype(jnp.float32)
         if q_pos.ndim == 3:
-            k_blk, v_blk = _grouped(k_blk), _grouped(v_blk)
-        s = _dot_parts(q, k_blk) * scale  # [queries, width]
+            k_blk, v_blk = _runs((k_blk, v_blk), q_pos.shape[0])
+        s = _dot_parts(q, k_blk) * scale  # [queries, keys a query]
         if masked:
             k_pos = _walked_pos(start, q_pos.shape)
             # Mask padding rows of a partial final K block (manual
@@ -542,10 +676,15 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
             if mask is not None:
                 valid = valid & mask.keep(q_pos, k_pos)
             s = jnp.where(valid, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.max(s, axis=-1, keepdims=True)
+        if carry is not None:
+            o, m, l = carry
+            m_new = jnp.maximum(m, m_new)
         p = jnp.exp(s - m_new)
         if masked:
             p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+        if carry is None:  # nothing to rescale
+            return _mm(p, v_blk, 1, 0), m_new, _lane_chunks(p, jnp.add)
         corr = jnp.exp(m - m_new)
         # l stays one partial sum a lane: summed across lanes once,
         # after the loops, not in every step
@@ -562,6 +701,11 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
             _grouped(q), _grouped(q_pos[:, :_SUB]), j * width, True,
             _grouped(carry)))
 
+    def band(kind):
+        return _flat(attend(
+            _grouped(q), _grouped(own_pos(kind.run * _SUB)),
+            pl.multiple_of(qi * block_q + kind.shift, _SUB), True, None))
+
     o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
     # they broadcast along lanes with no relayout
@@ -575,7 +719,7 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
         o_ref[0, 0] = (o / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m + jnp.log(l)
 
-    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish, diagonal)
+    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish, diagonal, band)
 
 
 def _pad_seq(x, block):
@@ -638,17 +782,22 @@ def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     lse = lse_ref[0, 0]      # [block_q, 1]
     delta = delta_ref[0, 0]  # [block_q, 1]
     causal_offset = seq_k - seq_q
-    q_pos = (qi * block_q + causal_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, width), 0))
+
+    def own_pos(cols):
+        return (qi * block_q + causal_offset
+                + jax.lax.broadcasted_iota(jnp.int32, (block_q, cols), 0))
+
+    q_pos = own_pos(width)
 
     def attend(q, do, lse, delta, q_pos, start, masked, dq):
-        """dq of the queries `q` plus what the keys [start, start + width)
-        give it; `_grouped` operands: each group's own `_SUB` of them."""
-        rows = pl.dslice(start, width)
+        """dq of the queries `q` at `q_pos` (as in the forward) plus what
+        the keys from `start` on give it; `_grouped` operands: each group's
+        own run of them."""
+        rows = pl.dslice(start, _span(q_pos))
         k_blk = _loaded(k_refs, rows)
         v_blk = v_ref[0, 0, rows, :].astype(jnp.float32)
         if q_pos.ndim == 3:
-            k_blk, v_blk = _grouped(k_blk), _grouped(v_blk)
+            k_blk, v_blk = _runs((k_blk, v_blk), q_pos.shape[0])
         s = _dot_parts(q, k_blk) * scale
         if masked:
             k_pos = _walked_pos(start, q_pos.shape)
@@ -672,8 +821,15 @@ def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             *_grouped((q, do, lse, delta, q_pos[:, :_SUB])), j * width,
             True, _grouped(dq)))
 
-    _run_row(plan, qi, steps_ref, step, tuple(map(jnp.zeros_like, q)),
-             functools.partial(_store_parts, dq_ref), diagonal)
+    def band(kind):
+        return _flat(attend(
+            *_grouped((q, do, lse, delta, own_pos(kind.run * _SUB))),
+            pl.multiple_of(qi * block_q + kind.shift, _SUB), True,
+            _grouped(dq0)))
+
+    dq0 = tuple(map(jnp.zeros_like, q))
+    _run_row(plan, qi, steps_ref, step, dq0,
+             functools.partial(_store_parts, dq_ref), diagonal, band)
 
 
 def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
@@ -692,28 +848,33 @@ def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
     kj = pl.program_id(2)
     k_blk = _loaded(k_refs)  # ([block_k, D],), or with [block_k, R]
     v_blk = v_ref[0, 0].astype(jnp.float32)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, width), 0)
+
+    def own_pos(cols):
+        return kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, cols), 0)
+
+    k_pos = own_pos(width)
     causal_offset = seq_k - seq_q
 
-    def attend(k_blk, v_blk, k_pos, i, masked, carry):
-        """(dk, dv) of the keys `k_blk` at `k_pos` plus what the queries of
-        step i give them; `_grouped` keys: each group's own `_SUB` of
-        them."""
+    def attend(k_blk, v_blk, k_pos, start, stat, masked, carry):
+        """(dk, dv) of the keys `k_blk` at `k_pos` ([.., queries a key]: its
+        position, one a score) plus what the queries from `start` on give
+        them, `stat(ref)` the [1, queries] of lse or delta that are theirs;
+        `_grouped` keys: each group's own run of queries, a sub-tile on from
+        the last group's."""
         dk, dv = carry
-        start = _aligned(i * width, width)
-        rows = pl.dslice(start, width)
+        rows = pl.dslice(start, _span(k_pos))
         q = _loaded(q_refs, rows)
         do = do_ref[0, 0, rows, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.dslice(i, 1), :]      # [1, width]
-        delta = delta_ref[0, 0, pl.dslice(i, 1), :]
+        lse, delta = stat(lse_ref), stat(delta_ref)
         if k_pos.ndim == 3:
-            q, do = _grouped(q), _grouped(do)
-            # a group's lanes of the row: [n, 1, _SUB]
+            groups, _, run = k_pos.shape
+            q, do = _runs((q, do), groups)
+            # a group's lanes of the row: [n, 1, run]
             lse, delta = (jnp.stack(
-                [x[:, a:a + _SUB] for a in range(0, width, _SUB)])
+                [x[:, a:a + run] for a in range(0, groups * _SUB, _SUB)])
                 for x in (lse, delta))
-        s = _dot_parts(k_blk, q) * scale  # [keys, width]
+        s = _dot_parts(k_blk, q) * scale  # [keys, queries a key]
         if masked:
             q_row = _walked_pos(start, k_pos.shape)
             # Mask padding rows of a partial final Q block; when causal,
@@ -731,22 +892,46 @@ def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta) * scale
         return tuple(d + _mm(ds, x, 1, 0) for d, x in zip(dk, q)), dv
 
+    def whole(i, k_blk, v_blk, k_pos, masked, carry):
+        """`attend` for step i of the plan's width: one row of lse and
+        delta."""
+        return attend(k_blk, v_blk, k_pos, _aligned(i * width, width),
+                      lambda ref: ref[0, 0, pl.dslice(i, 1), :], masked,
+                      carry)
+
     def step(masked):
-        return lambda i, carry: attend(k_blk, v_blk, k_pos, i, masked, carry)
+        return lambda i, carry: whole(i, k_blk, v_blk, k_pos, masked, carry)
 
     def diagonal(i, carry):
-        return _flat(attend(
-            *_grouped((k_blk, v_blk, k_pos[:, :_SUB])), i, True,
+        return _flat(whole(
+            i, *_grouped((k_blk, v_blk, k_pos[:, :_SUB])), True,
             _grouped(carry)))
+
+    def band(kind):
+        pos = _grouped(own_pos(kind.run * _SUB))
+        # the band's queries, out of the rows of `width` they lie in: the
+        # first is the grid row's (whole steps an owned block) plus `shift`'s
+        first, lane = divmod(kind.shift, width)
+        first = first + kj * (block_k // width)
+
+        def stat(ref):
+            return jnp.concatenate(
+                [ref[0, 0, pl.dslice(first + r, 1), :]
+                 for r in range(_cdiv(lane + _span(pos), width))],
+                axis=1)[:, lane:lane + _span(pos)]
+
+        return _flat(attend(
+            *_grouped((k_blk, v_blk)), pos,
+            pl.multiple_of(kj * block_k + kind.shift, _SUB), stat, True,
+            _grouped(zero)))
 
     def finish(carry):
         dk, dv = carry
         _store_parts(dk_ref, dk)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
-    _run_row(plan, kj, steps_ref, step,
-             (tuple(map(jnp.zeros_like, k_blk)), jnp.zeros_like(v_blk)),
-             finish, diagonal)
+    zero = tuple(map(jnp.zeros_like, k_blk)), jnp.zeros_like(v_blk)
+    _run_row(plan, kj, steps_ref, step, zero, finish, diagonal, band)
 
 
 def _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q, plan,
@@ -912,8 +1097,8 @@ def flash_attention(
     block)` or `SlidingWindow(window)`. The kernels skip the tiles it keeps
     nothing of, run those it keeps whole with no mask, and apply its
     predicate in the others, on a tile's diagonal sub-tiles alone where
-    they hold all it keeps (`block_schedule`); no dense mask is built on
-    the TPU path.
+    they hold all it keeps, or on a row's band of sub-tiles in one step
+    (`block_schedule`); no dense mask is built on the TPU path.
 
     In parts (latent attention): with `q_rope` [B, S, H, R] and `k_rope`
     [B, S, 1, R], ONE rotary key a (batch, position) for all heads, a score

@@ -7,8 +7,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
-    _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, BlockDiffusion, SlidingWindow,
-    _clamp_block, _reference_attention, block_schedule, flash_attention)
+    _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, Band, BlockDiffusion,
+    SlidingWindow, _clamp_block, _reference_attention, block_schedule,
+    flash_attention)
 
 
 def _make_qkv(B=1, S=128, H=2, D=64, kv_heads=None, seed=0):
@@ -182,9 +183,32 @@ _SCHEDULES = {
                           6.31, 6.31),
     "bd-l1280-b4-loops": ((2560, 2560, 128, 128, BlockDiffusion(1280, 4)),
                           1.2, 1.2),
-    # a window: 31 of the 256 tiles of 512 x 512 for 4,063,488 kept scores
+    # a window: 31 of the 256 tiles of 512 x 512 hold its 4,063,488 kept
+    # scores (2.0 as whole tiles); 15 rows of two run as ONE band step, each
+    # 128-row group on 5 sub-tiles of their 8
     "swa-w512-s8192": ((8192, 8192, None, None, SlidingWindow(512)),
-                       2.0, 2.0),
+                       1.28, 1.28),
+    # band steps elsewhere: a window that is not the tile (3 and 2 sub-tiles
+    # a group), one that divides nothing (6 sub-tiles, both ends cut), a
+    # padded last block, s_q != s_k (a multiple of the sub-tile apart; 64
+    # apart a group's run is one sub-tile longer, and the key groups of
+    # dk/dv keep runs of different lengths: whole tiles there)
+    "swa-w256-s2048-band": ((2048, 2048, None, None, SlidingWindow(256)),
+                            1.74, 1.74),
+    "swa-w128-s1024-256x256-band": (
+        (1024, 1024, 256, 256, SlidingWindow(128)), 2.14, 2.14),
+    "swa-w600-s2048-band": ((2048, 2048, None, None, SlidingWindow(600)),
+                            1.5, 1.5),
+    "swa-w256-s900-256x256-padded-band": (
+        (900, 900, 256, 256, SlidingWindow(256)), 1.83, 1.83),
+    "swa-w256-cross-512-over-768-band": (
+        (512, 768, 256, 256, SlidingWindow(256)), 1.5, 1.75),
+    "swa-w256-cross-512-over-832-band": (
+        (512, 832, 256, 256, SlidingWindow(256)), 2.0, 3.0),
+    # no band: a run longer than two steps (9 sub-tiles at 512), and rows
+    # of 128 (one group: nothing to stagger)
+    "swa-w1024-s4096-no-band": ((4096, 4096, None, None, SlidingWindow(1024)),
+                                1.5, 1.5),
     # windows and lengths that do not divide each other, blocks that differ
     "swa-w1000-s2048-256x512": ((2048, 2048, 256, 512, SlidingWindow(1000)),
                                 1.27, 1.53),
@@ -194,6 +218,18 @@ _SCHEDULES = {
                                     1.92, 1.92),
     "swa-w50-more-queries-than-keys": (
         (384, 128, 128, 128, SlidingWindow(50)), 3.17, 3.17),
+}
+
+
+# the cases whose plans hold band steps: how many, forward / dq and dk/dv
+_BANDS = {
+    "swa-w512-s8192": (15, 15),
+    "swa-w256-s2048-band": (3, 3),
+    "swa-w128-s1024-256x256-band": (3, 3),
+    "swa-w600-s2048-band": (2, 2),
+    "swa-w256-s900-256x256-padded-band": (3, 3),
+    "swa-w256-cross-512-over-768-band": (2, 1),
+    "swa-w256-cross-512-over-832-band": (2, 0),
 }
 
 
@@ -211,9 +247,33 @@ def test_block_schedule_against_the_mask(case):
         plan = plans[name]
         rows = max(t[0] + t[1] for t in plan.tiles)
         cols = max(t[2] + t[3] for t in plan.tiles)
+        if name == "fwd":   # a band step may end inside the last whole step
+            cols = -(-cols // plan.width) * plan.width
+        else:
+            rows = -(-rows // plan.width) * plan.width
         mask = _mask(s_q, s_k, causal, rows, cols)
         painted = np.zeros((rows, cols), dtype=np.int32)
+        stood_for = 0   # whole tiles the band steps run in place of
         for q0, nq, k0, nk, masked in plan.tiles:
+            if isinstance(masked, Band):
+                # a row's only step, unrolled: each 128-row group of the
+                # owned block against its own run of sub-tiles, which start
+                # a sub-tile apart and span the tile's other side
+                assert plan.static
+                own0, own, walked0, span = (
+                    (q0, nq, k0, nk) if name == "fwd" else (k0, nk, q0, nq))
+                assert own == (block_q if name == "fwd" else block_k)
+                assert masked.shift == walked0 - own0 and masked.run * _SUB \
+                    == span - own + _SUB <= 2 * plan.width
+                assert own % plan.width == 0
+                stood_for += -(-(walked0 + span) // plan.width) \
+                    - walked0 // plan.width - 1
+                for a in range(0, own, _SUB):
+                    group = slice(own0 + a, own0 + a + _SUB)
+                    run = slice(walked0 + a, walked0 + a + masked.run * _SUB)
+                    painted[(group, run) if name == "fwd"
+                            else (run, group)] += 1
+                continue
             # the owned block, and a step of the plan's width along the other
             assert (nq, nk) == ((block_q, plan.width) if name == "fwd"
                                 else (plan.width, block_k))
@@ -257,7 +317,8 @@ def test_block_schedule_against_the_mask(case):
         measure, budget = _STATIC_BUDGET[name]
         assert plan.static == (measure(len(r) for r in plan.rows) <= budget)
         assert plan.steps_skipped == len(plan.rows) * (
-            (cols if name == "fwd" else rows) // plan.width) - len(plan.tiles)
+            (cols if name == "fwd" else rows) // plan.width) \
+            - len(plan.tiles) - stood_for
         assert [t[4] for t in plan.tiles] == [
             masked for row in plan.rows for _, masked in row]
         if plan.static:
@@ -275,6 +336,9 @@ def test_block_schedule_against_the_mask(case):
         if not isinstance(causal, BlockDiffusion):
             # CAUSAL's diagonal tiles keep 10 of their 16 sub-tiles
             assert plan.steps_diagonal == 0
+        assert plan.steps_band == sum(
+            isinstance(t[4], Band) for t in plan.tiles) == _BANDS.get(
+                case, (0, 0))[name == "dkv"]
         np.testing.assert_allclose(plan.executed_over_needed,
                                    painted.sum() / mask.sum())
         assert plan.executed_over_needed <= bound + 1e-9, name
@@ -363,8 +427,8 @@ def test_flash_attention_interior_and_edge_steps(case):
                          ids=["causal", "block-diffusion", "window"])
 def test_flash_attention_counts_its_steps(rule):
     """Building the kernels adds the schedule's step counts to the
-    process's counters (per lowering, not per run); a diagonal step is a
-    masked one too."""
+    process's counters (per lowering, not per run); a diagonal step and a
+    band step are masked ones too."""
     from ray_tpu._private import device_profiler
 
     q, k, v = _make_qkv(S=512)
@@ -373,6 +437,10 @@ def test_flash_attention_counts_its_steps(rule):
     # under the rule: the one x_t diagonal tile, in each of three kernels
     assert sum(p.steps_diagonal for p in plans.values()) == (
         3 if isinstance(rule, BlockDiffusion) else 0)
+    # under the window: the one row of two tiles, each group's 2 sub-tiles
+    # of their 4
+    assert sum(p.steps_band for p in plans.values()) == (
+        3 if isinstance(rule, SlidingWindow) else 0)
     before = device_profiler.snapshot()["counters"]
     jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **how)))(q)
     after = device_profiler.snapshot()["counters"]
@@ -382,6 +450,7 @@ def test_flash_attention_counts_its_steps(rule):
     for name, field in (("flash.steps_unmasked", "steps_unmasked"),
                         ("flash.steps_masked", "steps_masked"),
                         ("flash.steps_diagonal", "steps_diagonal"),
+                        ("flash.steps_band", "steps_band"),
                         ("flash.tiles_skipped", "steps_skipped")):
         assert after[name] - before.get(name, 0) == sum(
             getattr(plans[kernel], field) for kernel in ("fwd", "dq", "dkv"))
@@ -450,7 +519,10 @@ _DKV_PLANS = {
     "causal-s4096": (4096, True, 36, False),     # collapsed unrolled, PR 26
     "block-diffusion-l3072": (6144, BlockDiffusion(3072, 4), 48, False),
     "causal-s8192": (8192, True, 136, False),
-    "window-512-s8192": (8192, SlidingWindow(512), 31, False),
+    # 31 whole tiles, 3 over the budget, as 15 band steps and one tile
+    "window-512-s8192": (8192, SlidingWindow(512), 16, True),
+    # twice as long: 31 band steps and a tile are over it, so 63 whole tiles
+    "window-512-s16384": (16384, SlidingWindow(512), 63, False),
 }
 
 
@@ -467,7 +539,7 @@ def test_dkv_is_unrolled_under_a_budget_of_the_plans_total_steps(case):
         assert max(map(len, dkv.rows)) == 8
     if not static:
         # ONE loop a grid row, masked throughout, on whole tiles
-        assert dkv.steps_unmasked == 0 == dkv.steps_diagonal
+        assert dkv.steps_unmasked == 0 == dkv.steps_diagonal == dkv.steps_band
         assert dkv.table[-1, 0] == len(dkv.rows[-1])
     # forward and dq keep their cap on the longest row
     assert plans["fwd"].static == (max(map(len, plans["fwd"].rows)) <= 8)
@@ -568,40 +640,96 @@ def test_sliding_window_rule_against_the_dense_mask(s_q, s_k, window):
 
 def test_sliding_window_schedule_at_the_cell_shape():
     """train-laguna-1chip's two calls a period, S 8,192 in tiles of 512.
-    The window layers: a forward row walks its own tile and the one before
-    it (2 steps, not up to 16), both masked: the diagonal tile as under
-    `CAUSAL`, the window's TRAILING tile a strict upper triangle, which
-    keeps scores off its diagonal sub-tiles and so is never `DIAGONAL`.
-    Forward and dq are unrolled; dk/dv has 31 steps in all, over its budget
-    of 28: one loop, every step masked. The full layers run `CAUSAL` in
-    loops throughout (rows of up to 16, 136 steps)."""
+    The window layers: a forward row's kept scores lie in its own tile (a
+    lower triangle) and the one before it (the window's TRAILING tile, a
+    strict upper triangle, which keeps scores off its diagonal sub-tiles
+    and so is never `DIAGONAL`): 31 tiles, 2.0x the kept scores. Asked sub-
+    tile by sub-tile each 128-row group keeps 5 of the 8 sub-tiles the two
+    span, a sub-tile on from the group before, so the row is ONE band step
+    (20 sub-tiles for 32; 1.25x), but for the first, which has no tile
+    before it (dk/dv: the last key tile none after): 16 steps a plan, all
+    three unrolled, dk/dv's 16 under its budget of 28. The full layers run
+    `CAUSAL` in loops throughout (rows of up to 16, 136 steps)."""
     rule = SlidingWindow(512)
     plans = block_schedule(8192, 8192, 512, 512, rule)
     for name, plan in plans.items():
-        # the first queries have no tile before theirs, the last keys
-        # none after
-        assert [len(r) for r in plan.rows] == (
-            [2] * 15 + [1] if name == "dkv" else [1] + [2] * 15)
+        assert [len(r) for r in plan.rows] == [1] * 16
         assert (plan.steps_unmasked, plan.steps_masked, plan.steps_diagonal,
-                plan.steps_skipped) == (0, 31, 0, 225)
-        assert plan.static == (name != "dkv")
+                plan.steps_band, plan.steps_skipped) == (0, 16, 0, 15, 225)
+        assert plan.static
         assert plan.executed_over_needed == pytest.approx(
-            31 * 512 * 512 / 4_063_488) == pytest.approx(2.0, rel=1e-3)
-    assert plans["fwd"].rows[5] == ((4, True), (5, True))
-    assert plans["dkv"].rows[5] == ((5, True), (6, True))
+            (15 * 512 * 640 + 512 * 512) / 4_063_488) \
+            == pytest.approx(1.274, abs=1e-3)
+    assert plans["fwd"].rows[0] == ((0, True),)
+    # ONE kind of band a plan, so one body for its fifteen rows: a query
+    # row's keys start a tile before its own, a key row's queries with it
+    assert plans["fwd"].rows[5] == ((4, Band(-512, 5)),)
+    assert plans["fwd"].tiles[5] == (5 * 512, 512, 4 * 512, 1024,
+                                     Band(-512, 5))
+    assert plans["dkv"].rows[5] == ((5, Band(0, 5)),)
+    assert plans["dkv"].tiles[5] == (5 * 512, 1024, 5 * 512, 512, Band(0, 5))
+    assert {r[0][1] for r in plans["fwd"].rows[1:]} == {Band(-512, 5)}
+    assert {r[0][1] for r in plans["dkv"].rows[:15]} == {Band(0, 5)}
+    assert plans["dkv"].rows[15] == ((15, True),)
     some, every = rule.tile(5 * 512, 512, 4 * 512, 512)
     assert some and not every
     # the trailing tile's sub-tile (0, 1), above its diagonal, is kept whole
     assert rule.tile(5 * 512, 128, 4 * 512 + 128, 128) == (True, True)
+    # group 1 of row 5 (queries from 2,688): keys 2,176 - 2,815, the first
+    # and the last sub-tile cut, the three between whole, nothing beyond
+    assert [rule.tile(5 * 512 + 128, 128, k0, 128)
+            for k0 in range(4 * 512, 6 * 512, 128)] == [
+        (False, False), (True, False), (True, True), (True, True),
+        (True, True), (True, False), (False, False), (False, False)]
     causal = block_schedule(8192, 8192, 512, 512, True)
     for plan in causal.values():
         assert not plan.static and len(plan.tiles) == 136
         assert max(map(len, plan.rows)) == 16 and plan.steps_skipped == 120
+        assert plan.steps_band == 0
 
 
-# (q heads, kv heads, window, tile): the cell's 48 / 8 and 64 / 8 scaled
-# down; a window smaller than, equal to and larger than a tile, and one that
-# divides nothing; S 512 (384 for the padded case)
+# what `block_schedule` returned before there was a band step (PR 47), a
+# digest of every field it had, forward (= dq) and dk/dv
+_PLANS_BEFORE_THE_BAND_STEP = {
+    "causal-s2048": (2048, True, "4c4e522d37cca906", "ce93506bdc3f41ef"),
+    "causal-s4096": (4096, True, "9024c467dd946098", "26df8b9341770fef"),
+    "causal-s8192": (8192, True, "77112d431e8b43f9", "3f21b5c56cde178c"),
+    "block-diffusion-l2048": (4096, BlockDiffusion(2048, 4),
+                              "c68fce278d657c79", "6cc2327884bd7cd9"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLANS_BEFORE_THE_BAND_STEP))
+def test_plans_without_a_band_are_what_they_were(case):
+    """The plans of the other cells' calls (`CAUSAL` at S 2,048, 4,096 and
+    8,192, `BlockDiffusion(2048, 4)` over 2 x 2,048) hold no band step and
+    are, field for field, what the commit before the band step planned: the
+    rule's answers decide, and theirs fit no band (a causal group's runs
+    all start at 0; a block-diffusion row's tiles are not one range, its
+    x_t key tiles already run their diagonal alone)."""
+    import hashlib
+
+    s, rule, fwd, dkv = _PLANS_BEFORE_THE_BAND_STEP[case]
+    plans = block_schedule(s, s, 512, 512, rule)
+    assert plans["dq"] == plans["fwd"]
+    for plan, digest in ((plans["fwd"], fwd), (plans["dkv"], dkv)):
+        assert plan.steps_band == 0
+        assert not any(isinstance(t[4], Band) for t in plan.tiles)
+        assert hashlib.sha256(repr((
+            plan.width, plan.static, plan.tiles, plan.rows,
+            plan.steps_unmasked, plan.steps_masked, plan.steps_diagonal,
+            plan.steps_skipped, plan.executed_over_needed)).encode()
+        ).hexdigest()[:16] == digest
+
+
+# (q heads, kv heads, window, tile, S[, keys, band steps in the forward's plan
+# and in dk/dv's]): the cell's 48 / 8 and 64 / 8 scaled down; a window smaller
+# than, equal to and larger than a tile, and one that divides nothing; S 512
+# (384 for the padded case). Then the rows that run as ONE band step in all
+# three kernels: the cell's own form (window = tile = 512 in sub-tiles of
+# 128: 5 sub-tiles a group), a padded last block, s_q != s_k, a window that
+# is no multiple of the sub-tile; and one whose run is too long for a step
+# (whole tiles, the same answers)
 _WINDOWS = {
     "6-to-1-window-below-the-tile": (6, 1, 50, 128, 512),
     "8-to-1-window-is-the-tile": (8, 1, 128, 128, 512),
@@ -609,6 +737,11 @@ _WINDOWS = {
     "8-to-1-window-512-loops": (8, 1, 64, 64, 1024),
     "8-to-1-padded": (8, 1, 100, 256, 384),
     "6-to-1-window-past-the-sequence": (6, 1, 1000, 128, 256),
+    "8-to-1-band-window-is-the-tile-512": (8, 1, 512, 512, 1536, 1536, 2, 2),
+    "8-to-1-band-padded-last-block": (8, 1, 256, 256, 900, 900, 3, 3),
+    "6-to-1-band-more-keys-than-queries": (6, 1, 256, 256, 512, 768, 2, 1),
+    "8-to-1-band-window-divides-nothing": (8, 1, 200, 256, 768, 768, 2, 2),
+    "8-to-1-no-band-run-too-long": (8, 1, 640, 256, 1024, 1024, 0, 0),
 }
 
 
@@ -616,16 +749,22 @@ _WINDOWS = {
 def test_flash_attention_under_the_sliding_window_rule(case):
     """Forward and the three gradients of the Pallas kernels (interpret
     mode) under `mask=SlidingWindow(w)` against `_reference_attention`,
-    which builds the DENSE mask; GQA; and the oracle against attention
-    written out with the mask in the rule's words."""
-    heads, kv_heads, window, tile, s = _WINDOWS[case]
+    which builds the DENSE mask; GQA; band steps where the plan has them;
+    and the oracle against attention written out with the mask in the
+    rule's words."""
+    heads, kv_heads, window, tile, s, s_k, *bands = (
+        _WINDOWS[case] + _WINDOWS[case][4:5])[:8]
     rule = SlidingWindow(window)
-    plans = block_schedule(s, s, tile, tile, rule)
+    plans = block_schedule(s, s_k, tile, tile, rule)
+    if bands:
+        assert [plans[name].steps_band for name in ("fwd", "dq", "dkv")] \
+            == [bands[0], bands[0], bands[1]]
     if case == "8-to-1-window-512-loops":
         assert not plans["dkv"].static and plans["fwd"].static
     if case == "6-to-1-window-past-the-sequence":   # then it is causal
         assert plans == block_schedule(s, s, tile, tile, True)
-    q, k, v = _make_qkv(S=s, H=heads, kv_heads=kv_heads, D=32, seed=window)
+    q, k, v = _make_qkv(S=s_k, H=heads, kv_heads=kv_heads, D=32, seed=window)
+    q = q[:, :s]
 
     def loss(q, k, v, **how):
         out = flash_attention(q, k, v, mask=rule, **how)
@@ -645,7 +784,7 @@ def test_flash_attention_under_the_sliding_window_rule(case):
         rule, 32 ** -0.5)))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, axis=2)) \
         / 32 ** 0.5
-    scores = jnp.where(_dense_window(s, s, window), scores, -jnp.inf)
+    scores = jnp.where(_dense_window(s, s_k, window), scores, -jnp.inf)
     want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
                       jnp.repeat(v, rep, axis=2))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)

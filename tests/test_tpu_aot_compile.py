@@ -348,13 +348,26 @@ for cell_call, n_heads, window in (
     attend = mla_moe._checkpointed(
         lambda q, k, v, window=window: llama._attention(
             q, k, v, laguna, None, window), laguna)
+    laguna_call = jax.value_and_grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    laguna_shapes = (spec((1, 8192, n_heads, 128), bf16),
+                     spec((1, 8192, 8, 128), bf16),
+                     spec((1, 8192, 8, 128), bf16))
+    # the call as traced, its three kernels' bodies in it: no path, no
+    # line; less the addresses of the functions its parameters name
+    laguna_jaxpr = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(
+        laguna_call)(*laguna_shapes)))
+    out[cell_call + "_jaxpr"] = hashlib.sha256(
+        laguna_jaxpr.encode()).hexdigest()
+    # an unrolled plan's branches, one a `pl.when`, over the three kernels
+    out[cell_call + "_branches"] = len(re.findall(r"\bcond\[", laguna_jaxpr))
+    out[cell_call + "_band_steps"] = [
+        plan.steps_band for plan in block_schedule(
+            8192, 8192, 512, 512, window or True).values()]
     try:
-        laguna_hlo = jax.jit(jax.value_and_grad(
-            lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))).lower(
-                spec((1, 8192, n_heads, 128), bf16),
-                spec((1, 8192, 8, 128), bf16),
-                spec((1, 8192, 8, 128), bf16)).compile().as_text()
+        laguna_hlo = jax.jit(laguna_call).lower(
+            *laguna_shapes).compile().as_text()
     except Exception as e:  # noqa: BLE001 - a refusal is the finding
         out[cell_call] = str(e)[:300]
         continue
@@ -768,9 +781,16 @@ def test_flash_under_the_block_diffusion_rule_compiles_for_v5e(compiled):
 def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
     """train-laguna-1chip's two calls, value and gradient, as the v5e's
     compiler takes them: the window layer's three kernels at `[1, 64, 8192,
-    128]` (forward and dq unrolled at 2 steps a row, dk/dv a loop over 31)
-    and the full layer's at `[1, 48, 8192, 128]` (`CAUSAL`, loops of up to
-    16 and of 136), K and V of the whole sequence in VMEM. The window calls
+    128]` (all unrolled at ONE step a row: 15 band steps, each a batch of 4
+    groups x [128, 640] scores, and the first row's own tile (dk/dv: the
+    last's); the fifteen are one kind of band, a tile before the row's own
+    block or with it, and share ONE branch, so a kernel has two; before PR
+    48 two whole tiles a row, 16 branches, and dk/dv a loop over 31) and
+    the full layer's at
+    `[1, 48, 8192, 128]` (`CAUSAL`, loops of up to 16 and of 136: no band
+    step, and the call as traced, kernels and all, is to the letter what
+    the commit before the band step traced), K and V of the whole sequence
+    in VMEM. The window calls
     carry their scope in their names (`%swa.attend.3`; the forward of a
     layer outside a scan `%jvp_swa.attend_.1`), which is how the cell's four
     attention metrics tell them from the full ones in one trace."""
@@ -779,6 +799,14 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
         assert compiled[name] == sorted([
             f"({out(heads)}, f32[1,{heads},8192,1])", out(heads),
             f"({out(heads)}, {out(heads)})"]), compiled[name]
+    assert compiled["laguna_window_band_steps"] == [15] * 3
+    assert compiled["laguna_window_branches"] == 3 * 2
+    assert compiled["laguna_full_branches"] == 0   # loops
+    assert compiled["laguna_full_band_steps"] == [0] * 3
+    assert compiled["laguna_full_jaxpr"] == (
+        "108a1a8a73455e8e4f31f9f24c5ff321021286d1f3ea41e5cf16d89d56f8eb18")
+    assert compiled["laguna_window_jaxpr"] != (   # PR 47's
+        "0192ab54ecd8b9eece3b74fe696ad7f626f15efc50d82530140f6db30591e62a")
     assert compiled["laguna_window_scoped"] == [True] * 3
     assert compiled["laguna_full_scoped"] == [False] * 3
     assert compiled["laguna_window_read_by"] == {
