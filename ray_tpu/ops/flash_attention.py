@@ -9,12 +9,15 @@ through VMEM, matmuls hit the MXU in fp32 accumulation
 preferred_element_type).
 
 Layouts: public API takes [B, S, H, D]; kernels run [B, H, S, D].
-GQA is handled by repeating KV heads in the wrapper.
+GQA: K and V reach the kernels at the KV heads' count; a grid row of query
+head h reads KV head h // group (`_spec`), and the backward rule sums dk and
+dv over each group. No copy at the query heads' count is written to HBM.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -437,6 +440,16 @@ def _count_steps(*plans):
                           sum(p.steps_skipped for p in plans))
 
 
+def _count_fetches(qs, ks):
+    # Per lowering, as `_count_steps`, of the forward and of dq, which hold a
+    # head's whole K and V: the (batch, head) grid rows, and those of them
+    # that fetch a K and V of their own (a group of query heads shares its KV
+    # head's; the one rotary key of a call in parts is not counted).
+    b, h = qs[0].shape[:2]
+    device_profiler.count("flash.kv_head_fetches", b * ks[0].shape[1])
+    device_profiler.count("flash.head_rows", b * h)
+
+
 def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
     `body(masked)` -> a step (index, carry) -> carry; `diagonal` such a
@@ -581,20 +594,28 @@ def _store_parts(ref, parts):
         at += x.shape[-1]
 
 
-def _spec(x, rows, walked):
-    """BlockSpec(s) on a grid (batch, head, i) over `x` [B, H, S, W], or over
-    each of a tuple of parts: `rows` of the sequence, block i of it where
-    the grid walks this operand (`walked`), else all of it (rows = the
-    padded sequence). An operand with ONE head is the rotary key every head
-    reads: head 0 whatever the grid's, not fetched again while the batch
-    stands."""
+def _spec(x, heads, rows, walked):
+    """BlockSpec(s) on a grid (batch, head of `heads`, i) over `x` [B, n, S,
+    W], or over each of a tuple of parts: `rows` of the sequence, block i of
+    it where the grid walks this operand (`walked`), else all of it (rows =
+    the padded sequence). An operand with fewer heads than the grid is read
+    at head h * n // heads: the KV head of a group of `heads // n` query
+    heads, or (n = 1) the ONE rotary key every head reads. Its block index
+    stands while the grid walks the group's heads and their row blocks, so
+    it is fetched once a group, not once a grid head."""
     from jax.experimental import pallas as pl
 
     def one(x):
-        head = x.shape[1] > 1
-        return pl.BlockSpec(
-            (1, 1, rows, x.shape[3]),
-            lambda b_, h_, i: (b_, h_ if head else 0, i if walked else 0, 0))
+        n = x.shape[1]
+        group = heads // n
+
+        def index(b_, h_, i):
+            # chosen here, not traced: an operand with the grid's heads, and
+            # the one rotary key, lower to the index maps they always had
+            head = 0 if n == 1 else h_ if group == 1 else h_ // group
+            return b_, head, i if walked else 0, 0
+
+        return pl.BlockSpec((1, 1, rows, x.shape[3]), index)
 
     return jax.tree.map(one, x)
 
@@ -739,6 +760,7 @@ def _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k, interpret):
     s_k, d_v = v.shape[2], v.shape[3]
     plan = block_schedule(s_q, s_k, block_q, block_k, mask)["fwd"]
     _count_steps(plan)
+    _count_fetches(qs, ks)
     # Pad to block multiples: dynamic_slice CLAMPS out-of-range starts, which
     # would silently shift the last partial block. The kernels mask padded
     # positions via the true seq_q/seq_k.
@@ -755,10 +777,10 @@ def _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k, interpret):
     ]
     o, lse = _pallas_call(
         kernel, plan,
-        [_spec(qs, block_q, True), _spec(ks, s_k_pad, False),
-         _spec(v, s_k_pad, False)],
+        [_spec(qs, h, block_q, True), _spec(ks, h, s_k_pad, False),
+         _spec(v, h, s_k_pad, False)],
         grid=(b, h, s_q_pad // block_q),
-        out_specs=_spec(out_shape, block_q, True),
+        out_specs=_spec(out_shape, h, block_q, True),
         out_shape=out_shape,
         interpret=interpret,
     )(qs, ks, v)
@@ -840,8 +862,9 @@ def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
     p^T do, dp^T = v do^T, dk += ds^T q), where p^T do taken from an
     untransposed p has Mosaic transpose the whole tile first. lse and delta
     come as rows ([steps, width]) to broadcast down the tile. dk_ref
-    [block_k, D (+ R)]: the parts' gradients side by side, of the rotary key
-    THIS head's share (the backward rule sums the heads')."""
+    [block_k, D (+ R)]: the parts' gradients side by side, of a shared KV
+    head or rotary key THIS head's share, as dv_ref (the backward rule sums
+    the heads')."""
     from jax.experimental import pallas as pl
 
     width = plan.width
@@ -950,11 +973,11 @@ def _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q, plan,
         (b, h, s_q_pad, sum(x.shape[3] for x in qs)), qs[0].dtype)
     dq = _pallas_call(
         kernel, plan,
-        [_spec(qs, block_q, True), _spec(ks, s_k_pad, False),
-         _spec(v, s_k_pad, False), _spec(do, block_q, True),
-         _spec(lse, block_q, True), _spec(delta, block_q, True)],
+        [_spec(qs, h, block_q, True), _spec(ks, h, s_k_pad, False),
+         _spec(v, h, s_k_pad, False), _spec(do, h, block_q, True),
+         _spec(lse, h, block_q, True), _spec(delta, h, block_q, True)],
         grid=(b, h, s_q_pad // block_q),
-        out_specs=_spec(out_shape, block_q, True),
+        out_specs=_spec(out_shape, h, block_q, True),
         out_shape=out_shape,
         interpret=interpret,
     )(qs, ks, v, do, lse, delta)
@@ -980,15 +1003,15 @@ def _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k, plan,
     out_shape = [
         jax.ShapeDtypeStruct(
             (b, h, s_k_pad, sum(x.shape[3] for x in ks)), ks[0].dtype),
-        jax.ShapeDtypeStruct(v.shape, v.dtype),
+        jax.ShapeDtypeStruct((b, h) + v.shape[2:], v.dtype),
     ]
     dk, dv = _pallas_call(
         kernel, plan,
-        [_spec(qs, s_q_pad, False), _spec(ks, block_k, True),
-         _spec(v, block_k, True), _spec(do, s_q_pad, False),
-         _spec(lse, n_steps, False), _spec(delta, n_steps, False)],
+        [_spec(qs, h, s_q_pad, False), _spec(ks, h, block_k, True),
+         _spec(v, h, block_k, True), _spec(do, h, s_q_pad, False),
+         _spec(lse, h, n_steps, False), _spec(delta, h, n_steps, False)],
         grid=(b, h, s_k_pad // block_k),
-        out_specs=_spec(out_shape, block_k, True),
+        out_specs=_spec(out_shape, h, block_k, True),
         out_shape=out_shape,
         interpret=interpret,
     )(qs, ks, v, do, lse, delta)
@@ -997,10 +1020,11 @@ def _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k, plan,
 
 def _flash_bwd_pallas(qs, ks, v, o, lse, do, mask, scale, block_q, block_k,
                       interpret):
-    """-> dq [B, H, S, D (+ R)], dk likewise (every head's share of a rotary
-    key's gradient), dv."""
+    """-> dq [B, H, S, D (+ R)], dk likewise and dv, one a QUERY head (every
+    head's share of the gradient of the KV head, or rotary key, it read)."""
     plans = block_schedule(do.shape[2], v.shape[2], block_q, block_k, mask)
     _count_steps(plans["dq"], plans["dkv"])
+    _count_fetches(qs, ks)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
     dq = _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q,
@@ -1026,7 +1050,8 @@ RESIDUAL_NAMES = ("flash.o", "flash.lse")
 def _flash_bhsd(qs, ks, v, mask, scale, block_q, block_k, interpret):
     """qs, ks: q and k in parts (above), (q,) and (k,) [B, H, S, D] or (q,
     q_rope [B, H, S, R]) and (k, k_rope [B, 1, S, R]); v [B, H, S, Dv] -> o
-    [B, H, S, Dv]."""
+    [B, H, S, Dv]. k and v may have fewer heads than q, H / group of them
+    (GQA): they are read, and saved for the backward pass, as they are."""
     return _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k,
                              interpret)[0]
 
@@ -1047,24 +1072,51 @@ def _flash_bwd_rule(mask, scale, block_q, block_k, interpret, res, do):
         qs, ks, v, o, lse[..., None], do, mask, scale, block_q, block_k,
         interpret)
 
+    def shared(g, x):
+        """g [B, H, S, W], a gradient one a query head, as the gradient of
+        `x` [B, n, S, W]: a group of H / n heads read ONE KV head (n = 1:
+        every head the ONE rotary key), so its gradient is their sum, in
+        float32."""
+        b, n, *rest = x.shape
+        if n == g.shape[1]:
+            return g
+        if n == 1:
+            total = jnp.sum(g, axis=1, keepdims=True, dtype=jnp.float32)
+        else:  # [B, n, the group's heads, S, W]
+            total = jnp.sum(g.reshape(b, n, -1, *rest), axis=2,
+                            dtype=jnp.float32)
+        return total.astype(x.dtype)
+
     def of_parts(g, parts):
         """g [B, H, S, D (+ R)] cut into the gradient of each part as it was
-        given: every head used the ONE rotary key, so its gradient is the
-        heads' sum."""
+        given."""
         out, at = [], 0
         for x in parts:
-            gx = g[..., at:at + x.shape[3]]
+            out.append(shared(g[..., at:at + x.shape[3]], x))
             at += x.shape[3]
-            if x.shape[1] != g.shape[1]:
-                gx = jnp.sum(gx, axis=1, keepdims=True,
-                             dtype=jnp.float32).astype(x.dtype)
-            out.append(gx)
         return tuple(out)
 
-    return of_parts(dq, qs), of_parts(dk, ks), dv
+    return of_parts(dq, qs), of_parts(dk, ks), shared(dv, v)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _group(h, h_kv):
+    """Query heads a KV head."""
+    if h % h_kv != 0:
+        raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
+    return h // h_kv
+
+
+def _repeat_heads(x, times, axis=1):
+    """Every array of `x` with each head (`axis`) `times` times, side by
+    side, so head h of the result is head h // times: what the ORACLE takes
+    (a K and V a query head), and a tp mesh wider than the KV heads. The
+    kernels never need it."""
+    if times == 1:
+        return x
+    return jax.tree.map(lambda a: jnp.repeat(a, times, axis=axis), x)
 
 
 def flash_attention(
@@ -1082,7 +1134,11 @@ def flash_attention(
     use_pallas: Optional[bool] = None,
     interpret: bool = False, q_rope=None, k_rope=None, mask=None,
 ):
-    """Exact attention over [B, S, H, D] inputs (GQA: fewer KV heads OK).
+    """Exact attention over [B, S, H, D] inputs. GQA: k and v may have fewer
+    heads, H / group; the kernels read KV head h // group for query head h
+    (fetched once a group) and the backward rule sums dk and dv over each
+    group in float32, so K and V never exist at H heads in HBM. Only the
+    oracle path repeats them.
 
     Which path runs is read off the platform, never off a failure: with
     `use_pallas=None`, `jax.default_backend() == "tpu"` lowers to the
@@ -1113,13 +1169,7 @@ def flash_attention(
     (as [S, 1] columns the backward pass was refused at S 8192).
     """
     b, s_q, h, d = q.shape
-    h_kv = k.shape[2]
-    if h_kv != h:
-        if h % h_kv != 0:
-            raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
-        rep = h // h_kv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    group = _group(h, k.shape[2])
     if scale is None:
         scale = (d + (0 if q_rope is None else q_rope.shape[-1])) ** -0.5
     if use_pallas is None:
@@ -1136,8 +1186,9 @@ def flash_attention(
         block_k = _clamp_block(block_k, k.shape[1])
         o = _flash_bhsd((qt,), (kt,), vt, mask, scale, block_q, block_k,
                         interpret)
-    else:
-        o = _reference_attention(qt, kt, vt, mask, scale)
+    else:  # the oracle wants a K and V a query head
+        o = _reference_attention(qt, *_repeat_heads((kt, vt), group), mask,
+                                 scale)
     return o.transpose(0, 2, 1, 3)
 
 
@@ -1147,18 +1198,17 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
     GSPMD has no partitioning rule for a Pallas custom call, so without it
     XLA all-gathers q/k/v to every device and replicates the kernel. Batch
     rides ('dp','fsdp') and heads ride 'tp' explicitly; each shard runs the
-    kernel on its local [B/dp·fsdp, S, H/tp, D] block. KV heads are repeated
-    to match q heads first so the tp shard is uniform under GQA. A `mask`
+    kernel on its local [B/dp·fsdp, S, H/tp, D] block. Under GQA the KV heads
+    ride tp as they are where tp divides them: a shard's query heads are
+    whole groups, in order, over its own KV heads. KV heads tp does not
+    divide are repeated first, only as far as the fewest heads it does (2
+    KV heads over tp 4: each twice), not to the query heads' count. A `mask`
     rule speaks of positions only, so every shard runs under it as it is.
     In parts (`flash_attention`): `_sharded_in_parts` below.
     """
     from jax.sharding import PartitionSpec as P
-    h_kv = k.shape[2]
-    h = q.shape[2]
-    if h_kv != h:
-        rep = h // h_kv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    h, h_kv = q.shape[2], k.shape[2]
+    _group(h, h_kv)
     # incl. the inter-slice dcn axis: a replicated batch dim would
     # all-gather q/k/v across DCN before every attention call
     batch_axes = tuple(a for a in ("dcn", "dp", "fsdp")
@@ -1170,6 +1220,11 @@ def flash_attention_sharded(q, k, v, mesh, causal: bool = True, scale=None,
         batch_axes = ()
     head_axis = "tp" if (mesh.shape.get("tp", 1) > 1
                          and h % mesh.shape["tp"] == 0) else None
+    if head_axis:
+        # fewer KV heads than tp shards: each as often as gives every shard
+        # whole heads, the fewest that do (h is a multiple of both)
+        k, v = _repeat_heads(
+            (k, v), math.lcm(h_kv, mesh.shape["tp"]) // h_kv, axis=2)
     spec = P(batch_axes or None, None, head_axis, None)
     fn = functools.partial(flash_attention, causal=causal, scale=scale,
                            mask=mask, **kw)
@@ -1206,9 +1261,10 @@ def _flash_in_parts(q, q_rope, k, k_rope, v, mask, scale, block_q, block_k,
             _clamp_block(block_k, k.shape[1]), interpret)
     else:
         k_rope = jnp.broadcast_to(k_rope, q_rope.shape)
+        k, v = _repeat_heads((t(k), t(v)), q.shape[2] // k.shape[2])
         o = _reference_attention(
             t(jnp.concatenate([q, q_rope], axis=-1)),
-            t(jnp.concatenate([k, k_rope], axis=-1)), t(v), mask, scale)
+            jnp.concatenate([k, t(k_rope)], axis=-1), v, mask, scale)
     return t(o)
 
 

@@ -1,5 +1,8 @@
 """Pallas-op tests (interpret mode on CPU; the oracle is plain JAX)."""
 
+import hashlib
+import re
+
 import numpy as np
 import pytest
 
@@ -48,12 +51,28 @@ def test_flash_attention_grads():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
-def test_flash_attention_gqa():
-    q, k, v = _make_qkv(H=4, kv_heads=2)
-    out = flash_attention(q, k, v, causal=True, interpret=True,
-                          block_q=64, block_k=64)
-    ref = flash_attention(q, k, v, causal=True, use_pallas=False)
+@pytest.mark.parametrize("heads, kv_heads", [(4, 4), (4, 2), (8, 2), (16, 2),
+                                             (4, 1)],
+                         ids=["group-1", "group-2", "group-4", "group-8",
+                              "one-kv-head"])
+def test_flash_attention_gqa(heads, kv_heads):
+    """K and V at the KV heads' count: query head h reads KV head h //
+    group, and dk and dv come back at the KV heads' count, each the sum of
+    its group's."""
+    q, k, v = _make_qkv(H=heads, kv_heads=kv_heads, D=32, seed=heads)
+
+    def loss(q, k, v, **how):
+        out = flash_attention(q, k, v, causal=True, **how)
+        return jnp.sum(out ** 2), out
+
+    (_, out), g1 = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, interpret=True, block_q=64, block_k=64)
+    (_, ref), g2 = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        q, k, v, use_pallas=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
 def test_flash_attention_rejects_bad_heads():
@@ -431,7 +450,7 @@ def test_flash_attention_counts_its_steps(rule):
     band step are masked ones too."""
     from ray_tpu._private import device_profiler
 
-    q, k, v = _make_qkv(S=512)
+    q, k, v = _make_qkv(B=2, S=512, H=4, kv_heads=2)
     how = dict(causal=rule, interpret=True, block_q=256, block_k=256)
     plans = block_schedule(512, 512, 256, 256, rule)
     # under the rule: the one x_t diagonal tile, in each of three kernels
@@ -454,6 +473,12 @@ def test_flash_attention_counts_its_steps(rule):
                         ("flash.tiles_skipped", "steps_skipped")):
         assert after[name] - before.get(name, 0) == sum(
             getattr(plans[kernel], field) for kernel in ("fwd", "dq", "dkv"))
+    # the forward's and dq's (batch, head) grid rows, and those of them that
+    # fetch a K and V of their own: 2 KV heads under 4 query heads
+    assert after["flash.head_rows"] - before.get("flash.head_rows", 0) \
+        == 2 * 2 * 4
+    assert after["flash.kv_head_fetches"] - before.get(
+        "flash.kv_head_fetches", 0) == 2 * 2 * 2
 
 
 def test_block_diffusion_schedule_at_the_cell_shape():
@@ -567,6 +592,10 @@ _BLOCK_DIFFUSION = {
     # keys, or lies past the end
     "diagonal-l320-length-no-tile-multiple": (320, 4, 256, 2, 1, 3),
     "diagonal-gqa-8-to-1": (512, 4, 256, 8, 1, 2),
+    # KV heads shared by groups of 1, 4 and 8 query heads
+    "gqa-4-to-4-no-head-shared": (192, 3, 128, 4, 4, 0),
+    "diagonal-gqa-8-to-2-groups-of-4": (256, 4, 256, 8, 2, 1),
+    "gqa-16-to-2-groups-of-8": (64, 1, 64, 16, 2, 0),
     "diagonal-b3-block-cuts-the-sub-tile": (384, 3, 256, 2, 1, 0),
 }
 
@@ -742,6 +771,10 @@ _WINDOWS = {
     "6-to-1-band-more-keys-than-queries": (6, 1, 256, 256, 512, 768, 2, 1),
     "8-to-1-band-window-divides-nothing": (8, 1, 200, 256, 768, 768, 2, 2),
     "8-to-1-no-band-run-too-long": (8, 1, 640, 256, 1024, 1024, 0, 0),
+    # KV heads shared by groups of 1, 4 and 8 query heads, band steps in all
+    "4-to-4-band-no-head-shared": (4, 4, 128, 256, 512, 512, 1, 1),
+    "8-to-2-band-groups-of-4": (8, 2, 256, 256, 768, 768, 2, 2),
+    "16-to-2-band-groups-of-8": (16, 2, 256, 256, 512, 512, 1, 1),
 }
 
 
@@ -797,25 +830,122 @@ def test_block_diffusion_rule_wants_whole_blocks_over_both_halves():
         block_schedule(400, 400, 128, 128, BlockDiffusion(200, 3))
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of the jaxprs in its parameters
+    (a jit, a custom_vjp, a shard_map) too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(inner)
+
+
+def _operand_shapes(fn, *args, primitive="pallas_call"):
+    return [[v.aval.shape for v in eqn.invars]
+            for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == primitive]
+
+
+@pytest.mark.parametrize("rule", [True, BlockDiffusion(128, 4),
+                                  SlidingWindow(128)],
+                         ids=["causal", "block-diffusion", "window"])
+def test_gqa_call_hands_the_kernels_k_and_v_at_the_kv_heads_count(rule):
+    """On the kernel path no K or V exists at the query heads' count: the
+    three `pallas_call`s take K and V [B, H_kv, S, D] as they are (dk and dv
+    leave dk/dv one a QUERY head, the shape the roofline readers take, and
+    the rule sums them), and nothing K- or V-shaped with H heads is in the
+    forward's jaxpr."""
+    b, s_q, s_k, h, h_kv, d = 2, 128, 256, 8, 2, 32
+    if isinstance(rule, BlockDiffusion):
+        s_q = s_k
+    q = jax.ShapeDtypeStruct((b, s_q, h, d), jnp.float32)
+    k = v = jax.ShapeDtypeStruct((b, s_k, h_kv, d), jnp.float32)
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, causal=rule, use_pallas=True,
+                               block_q=128, block_k=128)
+
+    if s_q != s_k:  # then whatever is K-shaped with H heads is a copy
+        forward = str(jax.make_jaxpr(call)(q, k, v))
+        assert f"f32[{b},{h_kv},{s_k},{d}]" in forward
+        assert f"f32[{b},{h},{s_k},{d}]" not in forward
+        assert f"f32[{b},{s_k},{h},{d}]" not in forward
+    kernels = _operand_shapes(
+        jax.grad(lambda q, k, v: call(q, k, v).sum(), argnums=(0, 1, 2)),
+        q, k, v)
+    assert len(kernels) == 3  # forward, dq, dk/dv
+    for operands in kernels:
+        operands = [x for x in operands if len(x) == 4]  # not a loop's table
+        assert operands[0] == (b, h, s_q, d)
+        assert operands[1] == operands[2] == (b, h_kv, s_k, d)
+
+
+# sha256 of the call as traced (value and gradients, the three kernels'
+# bodies in it) at the commit before a KV head was shared (PR 48's): a call
+# whose K has the grid's heads, and a call in parts, whose one rotary key
+# was always read at head 0, trace to the same text
+_TRACED_BEFORE_KV_HEADS_WERE_SHARED = {
+    "mha-causal":
+        "8237da77fabdf3cf0d1bb2412f498021ddb902ac8dcc6329de3b6a2e924ecf99",
+    "mha-window-band":
+        "2c7d8fa75a720bdbc54cc4fc42c8034792345957ad52f2d48eac84793ad2da5b",
+    "mha-block-diffusion":
+        "8dfa7b3000014c1200f2383c66dd0d852007e5a84a12fc8c276c8559dce7f1cf",
+    "in-parts":
+        "ed4321487d2b2573e4046c6d050edb261e51faf26614010cfe15cb61ceb264bc",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TRACED_BEFORE_KV_HEADS_WERE_SHARED))
+def test_calls_with_no_shared_kv_head_trace_to_what_they_were(case):
+    rule = {"mha-window-band": SlidingWindow(256),
+            "mha-block-diffusion": BlockDiffusion(256, 4)}.get(case, True)
+    shapes = [(2, 512, 4, 64)] * 3
+    if case == "in-parts":
+        shapes += [(2, 512, 4, 32), (2, 512, 1, 32)]
+
+    def call(q, k, v, *rope):
+        parts = dict(zip(("q_rope", "k_rope"), rope))
+        return flash_attention(q, k, v, causal=rule, use_pallas=True,
+                               block_q=256, block_k=256, **parts) \
+            .astype(jnp.float32).sum()
+
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        call, argnums=tuple(range(len(shapes)))))(
+            *[jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes])
+    assert hashlib.sha256(re.sub(r" at 0x[0-9a-f]+", "", str(traced))
+                          .encode()).hexdigest() \
+        == _TRACED_BEFORE_KV_HEADS_WERE_SHARED[case]
+
+
+@pytest.mark.parametrize("kv_heads", [2, 1],
+                         ids=["tp-divides-kv-heads", "fewer-kv-heads-than-tp"])
 @pytest.mark.parametrize("interpret", [False, True], ids=["oracle", "kernels"])
-def test_flash_attention_rule_on_a_mesh(interpret):
+def test_flash_attention_rule_on_a_mesh(interpret, kv_heads):
     """`flash_attention_sharded` carries the rule through its shard_map
     (batch over fsdp, heads over tp; a rule speaks of positions only, so
     every shard runs under it as it is): values and gradients equal the
-    unsharded call's."""
+    unsharded call's. The KV heads ride tp as they are where tp divides
+    them (a shard's 4 query heads over its ONE of 2 KV heads); ONE KV head
+    under tp 2 is repeated to 2, a head a shard, never to the 8 of q."""
     from ray_tpu.ops.flash_attention import flash_attention_sharded
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
     mesh = build_mesh(MeshConfig(dp=1, fsdp=2, tp=2),
                       devices=jax.devices()[:4])
     rule = BlockDiffusion(128, 4)
-    q, k, v = _make_qkv(B=2, S=256, H=4, kv_heads=2, D=32, seed=9)
+    q, k, v = _make_qkv(B=2, S=256, H=8, kv_heads=kv_heads, D=32, seed=9)
     do = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+
+    def sharded(q, k, v):
+        return flash_attention_sharded(
+            q, k, v, mesh, mask=rule, interpret=interpret, block_q=128,
+            block_k=128)
+
+    (shards,) = _operand_shapes(sharded, q, k, v, primitive="shard_map")
+    assert shards == [(2, 256, 8, 32)] + [(2, 256, 2, 32)] * 2
     want, vjp_want = jax.vjp(
         lambda q, k, v: flash_attention(q, k, v, mask=rule), q, k, v)
-    got, vjp = jax.vjp(jax.jit(lambda q, k, v: flash_attention_sharded(
-        q, k, v, mesh, mask=rule, interpret=interpret, block_q=128,
-        block_k=128)), q, k, v)
+    got, vjp = jax.vjp(jax.jit(sharded), q, k, v)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     for a, w in zip(vjp(do), vjp_want(do)):
         assert a.shape == w.shape

@@ -75,6 +75,35 @@ def pair_scatters(hlo, pairs):
     return hits
 
 
+def flash_operands(hlo):
+    # per Pallas call of a compiled program, the shapes of its 4-d operands
+    # (q, K, V, dO, lse, delta; a loop plan's table of steps is 2-d); an
+    # instruction's line names its operands only, as in `pair_scatters`
+    shapes, calls = {}, []
+    for ln in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", ln)
+        if m:
+            shapes[m[1]] = m[2]
+        m = re.search(r' custom-call\(([^)]*)\), custom_call_target='
+                      r'"tpu_custom_call"', ln)
+        if m:
+            calls.append([x for x in (
+                shapes.get(re.sub(r"/\*.*?\*/", "", a).strip())
+                for a in m[1].split(",")) if x and x.count(",") == 3])
+    return calls
+
+
+def repeats(hlo, elements):
+    # the ` broadcast(` instructions of a compiled program, fused ones too,
+    # that copy an array to `elements` bf16 elements (a scalar's broadcast,
+    # `dimensions={}`, is no copy of an array)
+    return [ln.strip()[:160] for ln in hlo.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%[\w.\-]+ = bf16\[([\d,]+)\]\S* "
+                              r"broadcast\(", ln))
+            and "dimensions={}" not in ln
+            and np.prod([int(n) for n in m[1].split(",")]) == elements]
+
+
 def computations_of(hlo):
     # the instructions of each computation of a compiled program, by name
     computations, name = {}, None
@@ -140,8 +169,10 @@ lowered = jax.jit(flash_grads).lower(
     spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
     spec((4, 2048, 8, 128), bf16))
 out["flash_custom_calls"] = lowered.as_text().count("tpu_custom_call")
-lowered.compile()
+hlo = lowered.compile().as_text()
 out["flash_s2048"] = "compiled"
+out["flash_s2048_operands"] = flash_operands(hlo)
+out["flash_s2048_repeats"] = repeats(hlo, 4 * 32 * 2048 * 128)
 
 # MLA's call (train-joyai-1chip): keys 192 wide, values 128
 lowered = jax.jit(flash_grads).lower(
@@ -301,10 +332,12 @@ out["flash_causal_dkv_static"] = {
     str(s): block_schedule(s, s, 512, 512, True)["dkv"].static
     for s in (2048, 3584, 4096, 8192)}
 dense = re.compile(r"\[(\d+,)*4096,4096\]")
-out["flash_bd_dense"] = [ln.strip()[:160] for ln in
-                         lowered.compile().as_text().splitlines()
+hlo = lowered.compile().as_text()
+out["flash_bd_dense"] = [ln.strip()[:160] for ln in hlo.splitlines()
                          if dense.search(ln)]
 out["flash_bd"] = "compiled"
+out["flash_bd_operands"] = flash_operands(hlo)
+out["flash_bd_repeats"] = repeats(hlo, 4 * 32 * 4096 * 128)
 
 # ONE layer of the cell's model at its widths and batch (the share: 16 of
 # 128 experts), the whole objective, value and gradient, as the v5e's
@@ -377,6 +410,8 @@ for cell_call, n_heads, window in (
         r"%[\w.\-]+ = (.*?) custom-call\(", ln)[1]) for ln in laguna_calls)
     out[cell_call + "_scoped"] = ["swa.attend" in ln.split(" = ")[0]
                                   for ln in laguna_calls]
+    out[cell_call + "_operands"] = flash_operands(laguna_hlo)
+    out[cell_call + "_repeats"] = repeats(laguna_hlo, n_heads * 8192 * 128)
     out[cell_call + "_read_by"] = {
         metric: sum(1 for ln in laguna_calls if query.search(ln))
         for metric, query in laguna_queries.items()}
@@ -788,9 +823,7 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
     48 two whole tiles a row, 16 branches, and dk/dv a loop over 31) and
     the full layer's at
     `[1, 48, 8192, 128]` (`CAUSAL`, loops of up to 16 and of 136: no band
-    step, and the call as traced, kernels and all, is to the letter what
-    the commit before the band step traced), K and V of the whole sequence
-    in VMEM. The window calls
+    step), K and V of the whole sequence in VMEM. The window calls
     carry their scope in their names (`%swa.attend.3`; the forward of a
     layer outside a scan `%jvp_swa.attend_.1`), which is how the cell's four
     attention metrics tell them from the full ones in one trace."""
@@ -803,7 +836,10 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
     assert compiled["laguna_window_branches"] == 3 * 2
     assert compiled["laguna_full_branches"] == 0   # loops
     assert compiled["laguna_full_band_steps"] == [0] * 3
-    assert compiled["laguna_full_jaxpr"] == (
+    # both are GQA calls, 8 KV heads: since PR 49 neither traces a repeat
+    # of K and V, so neither is the text it was (tests/test_ops.py pins the
+    # calls with no shared KV head to theirs)
+    assert compiled["laguna_full_jaxpr"] != (     # PR 48's
         "108a1a8a73455e8e4f31f9f24c5ff321021286d1f3ea41e5cf16d89d56f8eb18")
     assert compiled["laguna_window_jaxpr"] != (   # PR 47's
         "0192ab54ecd8b9eece3b74fe696ad7f626f15efc50d82530140f6db30591e62a")
@@ -815,6 +851,34 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
     assert compiled["laguna_full_read_by"] == {
         "swa_flash_fwd_roofline": 0, "swa_flash_bwd_roofline": 0,
         "swa_attention_time_share": 0, "laguna_full_attention_time_share": 3}
+
+
+# the cells' GQA calls: (query heads, KV heads, batch, sequence)
+_GQA_CALLS = {
+    "laguna_window": (64, 8, 1, 8192), "laguna_full": (48, 8, 1, 8192),
+    "flash_bd": (32, 4, 4, 4096), "flash_s2048": (32, 8, 4, 2048)}
+
+
+@pytest.mark.parametrize("call", sorted(_GQA_CALLS))
+def test_gqa_flash_calls_read_k_and_v_at_the_kv_heads_count_for_v5e(
+        compiled, call):
+    """The cells' GQA calls, value and gradient, as compiled for the v5e:
+    `[1, 64 / 8, 8192, 128]` under the window rule and `[1, 48 / 8, 8192,
+    128]` causal (train-laguna-1chip), `[4, 32 / 4, 4096, 128]` under the
+    block-diffusion rule (train-sdar-1chip), `[4, 32 / 8, 2048, 128]`
+    (the Mistral cells). Each of the three kernels takes K and V at the KV
+    heads' count beside q (and dO) at the query heads', the index map
+    `head // group` compiles, and nothing in the program copies an array
+    to a K's or V's size at the query heads' count (before PR 49 two
+    `broadcast`s did, `bf16[8192,8,8,128]` in the window call)."""
+    h, h_kv, b, s = _GQA_CALLS[call]
+    operands = compiled[call + "_operands"]
+    assert len(operands) == 3
+    shared, own = f"bf16[{b},{h_kv},{s},128]", f"bf16[{b},{h},{s},128]"
+    for kernel in operands:
+        assert kernel.count(shared) == 2, kernel     # K and V
+        assert kernel.count(own) in (1, 2), kernel   # q; with dO
+    assert compiled[call + "_repeats"] == []
 
 
 def test_sdar_layer_as_compiled_for_v5e(compiled):
