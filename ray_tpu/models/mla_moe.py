@@ -45,7 +45,7 @@ from ray_tpu.models import llama
 from ray_tpu.models.llama import _remat_policy, _residual, _rms_norm, _rope
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES, flash_attention
 from ray_tpu.parallel import moe
-from ray_tpu.parallel.moe import moe_layer
+from ray_tpu.parallel.moe import moe_layer, route
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
 
@@ -391,11 +391,26 @@ def _mla_sublayer(x, p, positions, config: MlaMoeConfig, mesh=None,
     return _residual(x, mesh, rules)
 
 
+def _routing(h, p, config):
+    """h [T, D], what this model's router reads -> `route`'s choice at the
+    config's `score`; a layer without a `router_bias` chooses on the scores
+    alone."""
+    c = config
+    return route(h, p["router"], c.experts_per_token, c.norm_topk_prob,
+                 score=c.score, bias=p.get("router_bias"),
+                 scale=c.routed_scaling_factor, n_group=c.n_group,
+                 topk_group=c.topk_group)
+
+
 def _expert_sublayer(x, p, config: MlaMoeConfig, mesh=None,
-                     rules: Optional[LogicalAxisRules] = None):
+                     rules: Optional[LogicalAxisRules] = None, routing=None,
+                     form: str = "swiglu"):
     """x [B, S, D] -> (x + routed + shared experts of RMSNorm(x), the
-    chosen experts [B * S, k]). The scoring function is the config's
-    `score`; a layer without a `router_bias` chooses on the scores alone."""
+    chosen experts [B * S, k]). The choice is `_routing` of that normed
+    input, or `routing`, the same formed EARLIER from what the model's
+    router reads instead (`models/window_moe.py`: the attention's input);
+    the experts are of `form` (`moe_layer`), beside a shared SwiGLU every
+    token passes where the layer has one (`p["shared"]`)."""
     c = config
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         raise NotImplementedError(
@@ -403,11 +418,14 @@ def _expert_sublayer(x, p, config: MlaMoeConfig, mesh=None,
             "chip's share without the exchange): no `ep` mesh axis")
     b, s, d = x.shape
     h = _rms_norm(x, p["mlp_norm"], c.norm_eps)
-    routed, aux = moe_layer(
-        h.reshape(b * s, d), p["router"], p["experts"], c.experts_per_token,
-        c.norm_topk_prob, score=c.score, router_bias=p.get("router_bias"),
-        weight_scale=c.routed_scaling_factor, held=c.held,
-        n_group=c.n_group, topk_group=c.topk_group)
+    rows = h.reshape(b * s, d)
+    if routing is None:
+        routing = _routing(rows, p, c)
+    routed, aux = moe_layer(rows, None, p["experts"], c.experts_per_token,
+                            held=c.held, form=form, routing=routing)
+    if "shared" not in p:
+        return _residual(x + routed.reshape(b, s, d), mesh, rules), \
+            aux.experts
     with jax.named_scope("moe.shared"):
         sh = p["shared"]
         shared = (jax.nn.silu(h @ sh["w_gate"]) * (h @ sh["w_up"])) \

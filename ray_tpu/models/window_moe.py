@@ -1,8 +1,10 @@
 """A decoder whose ATTENTION differs by layer: sliding-window layers among
 full (global) ones in a published pattern, each kind with its own number of
-query heads and its own rotary form, over sigmoid-routed experts beside a
-shared one (Laguna-XS.2 by config: `model_type` `laguna`), TPU-first,
-training only.
+query heads and its own rotary form (or none), over routed experts, beside
+a shared one or not, whose router reads the feed-forward's input or, a
+sublayer EARLIER, the attention's (Laguna-XS.2 and SmallThinker-21BA3B by
+config: `model_type` `laguna`, `model_name` `smallthinker_21b_instruct`),
+TPU-first, training only.
 
 All pre-norm, sharing `models/llama.py`'s RMSNorm, attention and SwiGLU
 sublayers, remat policy and chunked cross-entropy, and `models/mla_moe.py`'s
@@ -18,7 +20,10 @@ expert sublayer. By the three per-layer lists of the config, layer i
   form `rope_parameters` gives the kind (`llama.Rotary`): its own theta, on
   the leading `partial_rotary_factor` of a head, under YaRN (blended
   frequencies, cos and sin times `attention_factor`) where `rope_type`
-  says so. With `attn_gate`, attn_head * sigmoid(w_head . h) before W_o;
+  says so; NO rotary embedding on q or k where `rope_layout[i]` is 0 (a
+  list of its own: the program reads each list for what it says, and
+  stacks a kind's layers, so a kind is all one or the other).
+  With `attn_gate`, attn_head * sigmoid(w_head . h) before W_o;
   with `qk_norm`, an RMSNorm of every head of q and k over its own
   channels before RoPE (`llama._qk_norm`'s [D] form).
 - *feed-forward*, `mlp_layer_types[i]`: `dense`, a SwiGLU of `d_ff`;
@@ -26,12 +31,19 @@ expert sublayer. By the three per-layer lists of the config, layer i
   ("sigmoid" or "softmax") over `n_experts`, the top `experts_per_token`
   on the scores, weights the chosen scores (over their sum with
   `norm_topk_prob`) x `routed_scaling_factor`, experts SwiGLU of
-  `d_ff_expert`, plus one shared SwiGLU of `d_ff_shared` every token
-  passes, added unweighted. No auxiliary loss.
+  `d_ff_expert` (`expert_form` "reglu": relu in silu's place), plus one
+  shared SwiGLU of `d_ff_shared` every token passes, added unweighted
+  (`d_ff_shared` 0: no shared expert, no such parameters). What the router
+  reads is `router_input`: "ffn_input", the sublayer's own normed input
+  RMSNorm(x + attention), as every other model here; "attention_input",
+  h = RMSNorm(x) that the ATTENTION reads, so the choice of experts is
+  formed before the attention call (`moe.route`, then
+  `moe_layer(routing=)`); "residual", x itself before that norm. No
+  auxiliary loss.
 
 `layers` lists the published indices this program holds, in order (all by
-default: the whole model). The leading dense layers are unrolled. The
-pattern's period is the distance from one full layer to the next, and a
+default: the whole model). The leading dense layers, if any, are unrolled.
+The pattern's period is the distance from one full layer to the next, and a
 period here ENDS with its full layer (sliding, ..., sliding, full): the
 sparse layers that fill whole such periods run as ONE `lax.scan` over the
 stacked periods whose body is an inner scan over the period's stacked
@@ -60,6 +72,9 @@ from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
 _SHORT = {FULL: "full", SLIDING: "sliding"}
+# what a sparse layer's router reads (`WindowMoeConfig.router_input`)
+FFN_INPUT, ATTENTION_INPUT, RESIDUAL = "ffn_input", "attention_input", \
+    "residual"
 
 PUBLISHED_ROPE = {
     FULL: {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
@@ -83,9 +98,12 @@ def _frozen(v):
 @dataclasses.dataclass(frozen=True)
 class WindowMoeConfig:
     """`layer_types`, `heads_per_layer`, `mlp_layer_types`: a layer's
-    attention kind, its query heads and its feed-forward kind by its
+    attention kind (or the 0 / 1 of a published `sliding_window_layout`: 1
+    a sliding layer), its query heads and its feed-forward kind by its
     published index; `layers`: the published indices held here (None: all).
-    `rope_parameters`: the published group, {kind: its rotary form}."""
+    `rope_parameters`: the published group, {kind: its rotary form};
+    `rope_layout`: 1 where a layer has a rotary embedding, 0 where none
+    (None: every layer has)."""
     vocab_size: int = 100_352
     d_model: int = 2048
     layer_types: Tuple[str, ...] = tuple(
@@ -98,6 +116,7 @@ class WindowMoeConfig:
     d_head: int = 128
     window: int = 512
     rope_parameters: Any = _frozen(PUBLISHED_ROPE)
+    rope_layout: Optional[Tuple[int, ...]] = None
     attn_gate: bool = True
     qk_norm: bool = False
     d_ff: int = 8192
@@ -110,6 +129,8 @@ class WindowMoeConfig:
     score: str = "sigmoid"
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 2.5
+    router_input: str = FFN_INPUT
+    expert_form: str = "swiglu"
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     remat: bool = True
@@ -122,13 +143,24 @@ class WindowMoeConfig:
 
     def __post_init__(self):
         for name in ("layer_types", "heads_per_layer", "mlp_layer_types",
-                     "layers", "rope_parameters"):
+                     "layers", "rope_parameters", "rope_layout"):
             v = getattr(self, name)
             if v is not None:
                 object.__setattr__(self, name, _frozen(v))
+        object.__setattr__(self, "layer_types", tuple(
+            {0: FULL, 1: SLIDING}.get(t, t) for t in self.layer_types))
         n = len(self.layer_types)
-        if not (len(self.heads_per_layer) == len(self.mlp_layer_types) == n):
-            raise ValueError("the three per-layer lists differ in length")
+        if not (len(self.heads_per_layer) == len(self.mlp_layer_types) == n
+                == len(self.rope_layout or self.layer_types)):
+            raise ValueError("the per-layer lists differ in length")
+        if self.router_input not in (FFN_INPUT, ATTENTION_INPUT, RESIDUAL):
+            raise ValueError(f"router_input {self.router_input!r}")
+        if self.expert_form not in ("swiglu", "reglu"):
+            raise ValueError(f"expert_form {self.expert_form!r}")
+        if self.expert_form != "swiglu" and self.d_ff_shared:
+            raise NotImplementedError(
+                "a shared expert beside experts of another form than "
+                "SwiGLU: the shared expert is a SwiGLU")
         if set(self.layer_types) - {FULL, SLIDING} \
                 or set(self.mlp_layer_types) - {DENSE, SPARSE}:
             raise ValueError("a layer's attention is full or sliding, its "
@@ -145,6 +177,11 @@ class WindowMoeConfig:
                     if t == kind}) != 1:
                 raise NotImplementedError(
                     f"{kind} layers with different numbers of heads: a "
+                    "kind's layers are stacked")
+            if len({r for r, t in zip(self.rope_layout or (), self.layer_types)
+                    if t == kind}) > 1:
+                raise NotImplementedError(
+                    f"{kind} layers with and without a rotary embedding: a "
                     "kind's layers are stacked")
         self.period  # raises where the pattern has none
 
@@ -165,6 +202,24 @@ class WindowMoeConfig:
             d_head=16, window=8, rope_parameters=rope, d_ff=128,
             d_ff_expert=32, d_ff_shared=32, n_experts=16, n_experts_held=16,
             experts_per_token=4), **over})
+
+    @staticmethod
+    def tiny_ahead(vocab_size: int = 512, n: int = 8,
+                   **over) -> "WindowMoeConfig":
+        """`tiny` in SmallThinker's pattern: the published 0 / 1 lists (full,
+        window, window, window; RoPE where the window is) over `n` layers, 7
+        query heads over ONE KV head in both kinds, no dense layer, no gate,
+        the router on the attention's input, softmax over the chosen, ReGLU
+        experts and no shared one; published layers 0-3 held."""
+        layout = tuple(int(i % 4 != 0) for i in range(n))
+        theta = {"rope_theta": 1_500_000}
+        return WindowMoeConfig.tiny(vocab_size, **{**dict(
+            layer_types=layout, rope_layout=layout,
+            heads_per_layer=(7,) * n, mlp_layer_types=(SPARSE,) * n,
+            n_kv_heads=1, rope_parameters={FULL: theta, SLIDING: theta},
+            attn_gate=False, d_ff_shared=0, score="softmax",
+            routed_scaling_factor=1.0, router_input=ATTENTION_INPUT,
+            expert_form="reglu", layers=(0, 1, 2, 3)), **over})
 
     @property
     def held_layers(self) -> Tuple[int, ...]:
@@ -216,8 +271,14 @@ class WindowMoeConfig:
                     if _SHORT[t] == attn)
 
     def rotary(self, attn: str) -> Rotary:
-        group = dict(dict(self.rope_parameters)[
-            FULL if attn == "full" else SLIDING])
+        """The kind's rotary form; theta 0, which `llama._qkv` reads as no
+        rotary embedding, where `rope_layout` gives the kind's layers 0."""
+        layer_type = FULL if attn == "full" else SLIDING
+        if self.rope_layout is not None and not next(
+                r for r, t in zip(self.rope_layout, self.layer_types)
+                if t == layer_type):
+            return Rotary(0.0)
+        group = dict(dict(self.rope_parameters)[layer_type])
         width = int(self.d_head * group.get("partial_rotary_factor", 1))
         kind = group.get("rope_type", "default")
         if kind not in ("default", "yarn"):
@@ -264,6 +325,8 @@ def _layer_axes(L, config, mlp: str):
                 "w_up": L + ("embed", "mlp"), "w_down": L + ("mlp", "embed")}
     routed = mla_moe._routed_axes(L)
     del routed["router_bias"]  # the choice is on the scores themselves
+    if not config.d_ff_shared:
+        del routed["shared"]
     return {**axes, **routed}
 
 
@@ -313,12 +376,13 @@ def _init_layer(config, attn: str, mlp: str, key):
         p.update(q_norm=ones((dh,)), k_norm=ones((dh,)))
     if mlp == DENSE:
         return {**p, **mla_moe._init_ffn(c, ks[5:8], (), c.d_ff)}
-    return {**p,
-            "router": (jax.random.normal(ks[5], (d, c.n_experts))
-                       * 0.02).astype(c.dtype),
-            "experts": mla_moe._init_ffn(c, ks[6:9], (c.n_experts_held,),
-                                         c.d_ff_expert),
-            "shared": mla_moe._init_ffn(c, ks[9:12], (), c.d_ff_shared)}
+    p.update(router=(jax.random.normal(ks[5], (d, c.n_experts))
+                     * 0.02).astype(c.dtype),
+             experts=mla_moe._init_ffn(c, ks[6:9], (c.n_experts_held,),
+                                       c.d_ff_expert))
+    if c.d_ff_shared:
+        p["shared"] = mla_moe._init_ffn(c, ks[9:12], (), c.d_ff_shared)
+    return p
 
 
 def init(config: WindowMoeConfig, key) -> Dict[str, Any]:
@@ -358,11 +422,21 @@ def _layer(x, p, positions, config, mesh, rules, attn: str, mlp: str):
     mask = None
     if attn == "sliding":
         mask = SlidingWindow(c.window)
+    routing = None
+    if mlp == SPARSE and c.router_input != FFN_INPUT:
+        # the router ahead of the attention: on what the attention reads
+        # (`llama._qkv`'s normed input: the compiler keeps one) or on the
+        # residual itself, so the choice is known before attention runs
+        h = x if c.router_input == RESIDUAL \
+            else _rms_norm(x, p["attn_norm"], c.norm_eps)
+        routing = mla_moe._routing(h.reshape(-1, c.d_model), p, c)
+        device_profiler.count("moe.routed_ahead", 1)  # per lowering
     x = llama._attn_sublayer(x, p, positions, c, mesh, rules, mask=mask,
                              rotary=c.rotary(attn))
     if mlp == DENSE:
         return llama._mlp_sublayer(x, p, c, mesh, rules), None
-    return mla_moe._expert_sublayer(x, p, c, mesh, rules)
+    return mla_moe._expert_sublayer(x, p, c, mesh, rules, routing=routing,
+                                    form=c.expert_form)
 
 
 def forward_hidden(params, tokens, config: WindowMoeConfig, mesh=None,

@@ -620,24 +620,67 @@ def _spec(x, heads, rows, walked):
     return jax.tree.map(one, x)
 
 
-def _pallas_call(kernel, plan, in_specs, **kw):
-    """`pl.pallas_call(kernel, in_specs=in_specs, **kw)`; a plan too long to
-    unroll hands the kernel its table of steps first, whole, in SMEM."""
+# What a kernel may keep in VMEM unless it states a limit of its own: the
+# compiler's scoped default, 16 MiB on the v5e (its refusal at S 16,384: "Ran
+# out of memory in memory space vmem ... Scoped allocation with size 16.75M
+# and limit 16.00M": one KV head's K and V whole, 2 x 4 MiB, in the
+# pipeline's two buffers, beside 0.75M of tiles; v6e's default is larger,
+# every generation's VMEM is 128 MiB). `_VMEM_WORKING` is what a kernel is
+# reckoned to need beside its blocks when asking whether the default holds it
+# (a step's float32 tiles of 512 x 512 and the carry; the compiler counts
+# under 1 MiB), `_VMEM_ROOM` what a stated limit leaves for them.
+_VMEM_DEFAULT = 16 * 2**20
+_VMEM_WORKING = 4 * 2**20
+_VMEM_ROOM = 16 * 2**20
+
+
+def _vmem_limit(specs, arrays):
+    """The VMEM limit to state for a kernel whose blocks are `specs` of
+    `arrays` (operands and results, shapes and dtypes), or None where the
+    default holds them: every block twice (the pipeline fetches the next
+    while the kernel reads one; the whole-sequence K and V, or q and dO, are
+    blocks too) plus the working set. A limit is what the kernel MAY use,
+    not what it takes: reckoned from the blocks, so a longer sequence states
+    a larger one and a call the default holds states none and lowers as it
+    always did (every call at S <= 8,192 of a cell: 8.5 MiB of blocks at
+    [8192, 128] K and V)."""
+    blocks = 2 * sum(
+        math.prod(spec.block_shape) * jnp.dtype(x.dtype).itemsize
+        for spec, x in zip(jax.tree.leaves(specs), jax.tree.leaves(arrays)))
+    if blocks + _VMEM_WORKING <= _VMEM_DEFAULT:
+        return None
+    return blocks + _VMEM_ROOM
+
+
+def _pallas_call(kernel, plan, in_specs, out_specs, out_shape, **kw):
+    """`pl.pallas_call(kernel, in_specs=in_specs, ...)` -> the call on its
+    operands; a plan too long to unroll hands the kernel its table of steps
+    first, whole, in SMEM; a call whose blocks are past the default VMEM
+    states its limit (`_vmem_limit`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if plan.static:
-        return pl.pallas_call(kernel, in_specs=in_specs, **kw)
+    def call(*operands):
+        limit = _vmem_limit((in_specs, out_specs), (operands, out_shape))
+        device_profiler.count("flash.kernels", 1)  # per lowering
+        device_profiler.count("flash.kernels_vmem_stated",
+                              int(limit is not None))
+        stated = {} if limit is None else {
+            "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
+        build = functools.partial(pl.pallas_call, out_specs=out_specs,
+                                  out_shape=out_shape, **stated, **kw)
+        if plan.static:
+            return build(kernel, in_specs=in_specs)(*operands)
 
-    @functools.wraps(kernel.func)
-    def with_steps(steps_ref, *refs):
-        return kernel(*refs, steps_ref=steps_ref)
+        @functools.wraps(kernel.func)
+        def with_steps(steps_ref, *refs):
+            return kernel(*refs, steps_ref=steps_ref)
 
-    return functools.partial(
-        pl.pallas_call(
+        return build(
             with_steps, in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-            + list(in_specs), **kw),
-        jnp.asarray(plan.table))
+            + list(in_specs))(jnp.asarray(plan.table), *operands)
+
+    return call
 
 
 def _aligned(start, width):
@@ -1163,10 +1206,22 @@ def flash_attention(
     over heads by the backward rule, not by the caller: `_flash_in_parts`.
 
     Sequence limit (v5e, libtpu 0.0.34, tests/test_tpu_aot_compile.py):
-    each kernel instance keeps the whole sequence's K and V (forward, dq)
-    or q and dO (dk/dv) in VMEM. Forward and backward compile at S 2048,
-    4096 and 8192 since dk/dv takes lse and delta as rows, [steps, width]
-    (as [S, 1] columns the backward pass was refused at S 8192).
+    each kernel instance keeps the whole sequence's K and V of ONE KV head
+    (forward, dq) or q and dO of one head (dk/dv) in VMEM, twice over (the
+    pipeline's two buffers). Under the compiler's default of 16 MiB a kernel
+    that holds: forward and backward compile at S 2048, 4096 and 8192 (8 MiB
+    of K and V at D 128; dk/dv takes lse and delta as rows, [steps, width]:
+    as [S, 1] columns the backward pass was refused at S 8192) and state
+    nothing, so they lower as they always did. Past it a call states its
+    own limit, reckoned from its blocks (`_vmem_limit`): S 16,384 (16 MiB of
+    K and V: refused at "16.75M of 16.00M" until PR 50) compiles and runs at
+    33 MiB of the chip's 128, under `CAUSAL` and `SlidingWindow` alike, and
+    S 32,768 would ask 49 MiB. What holds the sequence after that: VMEM
+    still, at about S 100,000 a head (a window row reads 4,608 of 16,384
+    keys it holds: fetching K and V by the blocks a row's steps touch is
+    what lifts it, PERF.md section 7), and before it HBM, where the
+    forward's lse leaves the kernel as [B, H, S, 1] float32, padded to 128
+    lanes (235 MB a call at 28 heads x 16,384).
     """
     b, s_q, h, d = q.shape
     group = _group(h, k.shape[2])

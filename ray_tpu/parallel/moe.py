@@ -6,13 +6,13 @@ serve both entry points:
 - `moe_layer`: the single-program dispatch, dropless. The T x k (token,
   slot) pairs are sorted by expert, the rows gathered in that order, and the
   experts' SwiGLU runs as three grouped matmuls over the ragged groups
-  (`ops/grouped_matmul.py`; two for the two-matrix `relu2` form,
-  `_expert_hidden`). The k combine weights are brought to sorted
-  order too and applied BEFORE the down projection (it is linear), so the
-  inverse permutation only brings the k results of a token back together
-  for a plain sum: the backward pass needs none of the down projection's
-  output, and under remat reruns neither it nor the un-permute. No
-  capacity, no dropped token, no [T, E, C] tensor.
+  (`ops/grouped_matmul.py`; three for the `reglu` form too, two for the
+  two-matrix `relu2` form, `_expert_hidden`). The k combine weights are
+  brought to sorted order too and applied BEFORE the down projection (it is
+  linear), so the inverse permutation only brings the k results of a token
+  back together for a plain sum: the backward pass needs none of the down
+  projection's output, and under remat reruns neither it nor the
+  un-permute. No capacity, no dropped token, no [T, E, C] tensor.
   Told which experts it holds (`held=(first_expert, n_held)`: one chip's
   share of an expert-parallel deployment, run without its exchange), it
   routes over ALL the router's experts and computes only the (token, slot)
@@ -371,17 +371,23 @@ def _sum_rows_bwd(res, g):
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
+# the gated forms' gates: down(gate(w_gate x) * w_up x), three matrices
+_GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
 def _expert_hidden(rows, experts, w_sorted, form: str, gmm):
     """rows [M, D] in sorted order -> what the down projection reads, [M, F]
     in rows.dtype, the combine weights `w_sorted` [M] applied: `form`
-    "swiglu" is silu(gate x) * up x (w_gate, w_up), "relu2" is relu(up x)^2
-    (w_up alone: Nemotron-H's experts). Formed in float32, rounded once.
-    `gmm(lhs, w)` is the grouped matmul over this dispatch's groups."""
+    "swiglu" is silu(gate x) * up x (w_gate, w_up), "reglu" relu(gate x) *
+    up x (the same matrices under the other gate: SmallThinker's experts),
+    "relu2" is relu(up x)^2 (w_up alone: Nemotron-H's experts). Formed in
+    float32, rounded once. `gmm(lhs, w)` is the grouped matmul over this
+    dispatch's groups."""
     f32 = jnp.float32
-    if form == "swiglu":
+    if form in _GATES:
         gate = gmm(rows, experts["w_gate"])
         up = gmm(rows, experts["w_up"])
-        h = jax.nn.silu(gate.astype(f32)) * up.astype(f32)
+        h = _GATES[form](gate.astype(f32)) * up.astype(f32)
     elif form == "relu2":
         h = jnp.square(jax.nn.relu(gmm(rows, experts["w_up"]).astype(f32)))
     else:
@@ -481,7 +487,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
               score: str = "softmax", router_bias=None,
               weight_scale: float = 1.0, held=None, n_group: int = 1,
-              topk_group: int = 1, form: str = "swiglu", rows=None):
+              topk_group: int = 1, form: str = "swiglu", rows=None,
+              routing: Routing = None):
     """Dropless top-k experts. x [T, D]; router_w [D, E]; `experts` holds
     w_gate, w_up [E', D, F] and w_down [E', F, D]. -> (y [T, D] in
     x.dtype, MoEAux): y_t = sum_j w_tj * down_e(silu(gate_e x_t) * up_e x_t)
@@ -490,11 +497,21 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
     `router_bias`, `norm_topk_prob`, `weight_scale`, `n_group` and
     `topk_group`), over all E experts.
 
-    `form` "relu2": the experts are down_e(relu(up_e .)^2), two matrices
-    (`_expert_hidden`). `rows` [T, D']: what is DISPATCHED where that is not
-    what the router reads (experts in a latent: the router sees x at the
-    residual's width, the experts' matrices are [E', D', F] and [E', F, D']
-    and y is [T, D'], in rows.dtype); None: x itself.
+    `form` "reglu": relu in silu's place; "relu2": the experts are
+    down_e(relu(up_e .)^2), two matrices (`_expert_hidden`). `rows` [T, D']:
+    what is DISPATCHED where that is not what the router reads (experts in a
+    latent: the router sees x at the residual's width, the experts' matrices
+    are [E', D', F] and [E', F, D'] and y is [T, D'], in rows.dtype); None: x
+    itself.
+
+    `routing`: the choice as `route` formed it EARLIER, from whatever that
+    model's router reads (a layer whose router stands before its attention:
+    the attention's input, `models/window_moe.py`), over the same T tokens
+    and all E experts. Then nothing is routed here: `router_w` and the
+    arguments that are `route`'s are not read (pass `router_w=None`), x is
+    what is dispatched, and dispatch, experts and combine run as for any
+    other caller, whole or a share. Any model may hand one in; `rows=` keeps
+    its meaning (a latent beside what the router read) and is not for this.
 
     The share: `held=None` means every expert is here (E' = E). With
     `held=(first_expert, n_held)` the E' = n_held experts `first_expert ..`
@@ -518,10 +535,14 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
     the down projection, the weights' gradient would need its output: remat
     "dots" would rerun the down matmul and the un-permute for it alone."""
     t = x.shape[0]
-    e = router_w.shape[1]
-    routing = route(x, router_w, k, norm_topk_prob, score=score,
-                    bias=router_bias, scale=weight_scale, n_group=n_group,
-                    topk_group=topk_group)
+    if routing is None:
+        routing = route(x, router_w, k, norm_topk_prob, score=score,
+                        bias=router_bias, scale=weight_scale, n_group=n_group,
+                        topk_group=topk_group)
+    elif routing.experts.shape != (t, k):
+        raise ValueError(f"a routing of {routing.experts.shape} for {t} "
+                         f"tokens, top-{k}")
+    e = routing.probs.shape[1]
     if rows is None:
         rows = x
     else:
@@ -562,7 +583,7 @@ def moe_layer(x, router_w, experts, k: int, norm_topk_prob: bool = False, *,
     # per lowering, as `flash.steps_*` are
     device_profiler.count("moe.rows_routed", t * k)
     device_profiler.count("moe.experts", e)
-    device_profiler.count("moe.gmm_calls", 3 if form == "swiglu" else 2)
+    device_profiler.count("moe.gmm_calls", 3 if form in _GATES else 2)
     return y, MoEAux(routing.experts, *router_losses(routing))
 
 

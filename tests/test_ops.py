@@ -775,6 +775,12 @@ _WINDOWS = {
     "4-to-4-band-no-head-shared": (4, 4, 128, 256, 512, 512, 1, 1),
     "8-to-2-band-groups-of-4": (8, 2, 256, 256, 768, 768, 2, 2),
     "16-to-2-band-groups-of-8": (16, 2, 256, 256, 512, 512, 1, 1),
+    # a window SEVERAL tiles wide under a group of 7 (SmallThinker's 28 / 4
+    # x window 4,096 = 8 tiles, scaled down): whole tiles INSIDE the window,
+    # never a band; the forward unrolled beside dk/dv in a loop, and all
+    # three in loops over rows of 9 steps, as the cell's are
+    "7-to-1-window-spans-4-tiles": (7, 1, 512, 128, 1024, 1024, 0, 0),
+    "7-to-1-window-spans-8-tiles-loops": (7, 1, 1024, 128, 1280, 1280, 0, 0),
 }
 
 
@@ -796,6 +802,15 @@ def test_flash_attention_under_the_sliding_window_rule(case):
         assert not plans["dkv"].static and plans["fwd"].static
     if case == "6-to-1-window-past-the-sequence":   # then it is causal
         assert plans == block_schedule(s, s, tile, tile, True)
+    if case.startswith("7-to-1-window-spans"):
+        tiles = window // tile   # a row: a trailing triangle, whole, diagonal
+        assert max(map(len, plans["fwd"].rows)) == tiles + 1
+        assert not plans["dkv"].static
+        assert plans["fwd"].static == (tiles + 1 <= _STATIC_BUDGET["fwd"][1])
+        if plans["fwd"].static:   # whole tiles inside the window: no mask
+            assert plans["fwd"].rows[tiles] == (
+                (0, True), *((j, False) for j in range(1, tiles)),
+                (tiles, True))
     q, k, v = _make_qkv(S=s_k, H=heads, kv_heads=kv_heads, D=32, seed=window)
     q = q[:, :s]
 
@@ -821,6 +836,63 @@ def test_flash_attention_under_the_sliding_window_rule(case):
     want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1),
                       jnp.repeat(v, rep, axis=2))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)
+
+
+def test_a_window_of_several_tiles_at_the_cell_shape():
+    """train-smallthinker-1chip's two calls, S 16,384 in tiles of 512. The
+    window layers (4,096 keys = 8 tiles): a row walks the window's trailing
+    tile (a strict upper triangle), 7 whole tiles and its own diagonal one,
+    9 steps, 252 a (batch, head) where `CAUSAL` walks 528 in rows of up to
+    32; a run of 9 tiles is no band (a band is at most two steps long), and
+    both are past every unroll budget: loops, every step under the mask.
+    What the loops execute over what the rules keep: 1.125 and 1.031."""
+    window = block_schedule(16384, 16384, 512, 512, SlidingWindow(4096))
+    causal = block_schedule(16384, 16384, 512, 512, True)
+    assert SlidingWindow(4096).needed(16384, 16384) == 58_722_304
+    assert CAUSAL.needed(16384, 16384) == 134_225_920
+    for plans, steps, longest, over in ((window, 252, 9, 1.125),
+                                        (causal, 528, 32, 1.031)):
+        for plan in plans.values():
+            assert not plan.static and plan.steps_band == 0
+            assert (len(plan.tiles), max(map(len, plan.rows))) \
+                == (steps, longest)
+            assert (plan.steps_unmasked, plan.steps_masked) == (0, steps)
+            assert plan.executed_over_needed == pytest.approx(over, abs=1e-3)
+    # asked tile by tile, 7 of a row's 9 need no mask
+    rule = SlidingWindow(4096)
+    assert [rule.tile(20 * 512, 512, k0 * 512, 512) for k0 in range(11, 22)] \
+        == [(False, False), (True, False)] + [(True, True)] * 7 \
+        + [(True, False), (False, False)]
+
+
+def test_a_kernel_states_a_vmem_limit_only_past_the_default():
+    """`_vmem_limit`: the blocks twice plus 4 MiB against the v5e's 16 MiB.
+    [8192, 128] bf16 K and V (every cell's longest call before PR 50) are
+    8.5 MiB of blocks twice over: no limit, the call lowers as it did;
+    [16384, 128] are 16.5 MiB: blocks + 16 MiB. Counted a lowering."""
+    from jax.experimental import pallas as pl
+
+    from ray_tpu._private import device_profiler
+    from ray_tpu.ops.flash_attention import _vmem_limit
+
+    def limit(s, dtype=jnp.bfloat16):
+        whole = pl.BlockSpec((1, 1, s, 128), lambda *i: (0, 0, 0, 0))
+        tile = pl.BlockSpec((1, 1, 512, 128), lambda *i: (0, 0, 0, 0))
+        x = jax.ShapeDtypeStruct((1, 4, s, 128), dtype)
+        return _vmem_limit(([tile, (whole,), whole], tile), ([x, (x,), x], x))
+
+    assert limit(2048) is None and limit(8192) is None
+    assert limit(16384) == 2 * (2 * 16384 + 2 * 512) * 128 * 2 + 16 * 2**20
+    assert limit(8192, jnp.float32) is not None
+    q, k, v = (jax.ShapeDtypeStruct((1, 256, h, 32), jnp.float32)
+               for h in (7, 1, 1))
+    before = device_profiler.snapshot()["counters"]
+    jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, use_pallas=True, block_q=128, block_k=128).sum()))(q, k, v)
+    after = device_profiler.snapshot()["counters"]
+    assert after["flash.kernels"] - before.get("flash.kernels", 0) == 3
+    assert after["flash.kernels_vmem_stated"] \
+        == before.get("flash.kernels_vmem_stated", 0)
 
 
 def test_block_diffusion_rule_wants_whole_blocks_over_both_halves():
@@ -950,3 +1022,63 @@ def test_flash_attention_rule_on_a_mesh(interpret, kv_heads):
     for a, w in zip(vjp(do), vjp_want(do)):
         assert a.shape == w.shape
         np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4)
+
+
+# --------------------------------------------------------------------------
+# the experts' forms over the grouped matmuls, under a routing handed in
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("held", [None, (4, 8)], ids=["whole", "share"])
+def test_reglu_experts_under_a_routing_handed_in_match_their_formula(held):
+    """`moe_layer(form="reglu", routing=)`: the choice formed EARLIER by
+    `route` from another tensor than the rows dispatched (a router ahead of
+    its attention), the experts down(relu(gate u) * up u): value and
+    `jax.vjp` against every held expert applied to every token under the
+    choice's weights; whole, the weights' gradient reaches what the ROUTER
+    read; on a share they are constants."""
+    from ray_tpu._private import device_profiler
+    from ray_tpu.parallel import moe
+
+    t, d, f, e, k = 48, 32, 24, 16, 4
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    read = jax.random.normal(ks[0], (t, d))     # what the router reads
+    u = jax.random.normal(ks[1], (t, d))        # what is dispatched
+    router = jax.random.normal(ks[2], (d, e)) * 0.5
+    first, n_held = held or (0, e)
+    mine = {"w_gate": jax.random.normal(ks[3], (n_held, d, f)) * d ** -0.5,
+            "w_up": jax.random.normal(ks[4], (n_held, d, f)) * d ** -0.5,
+            "w_down": jax.random.normal(ks[5], (n_held, f, d)) * f ** -0.5}
+
+    def formula(read, u, mine):
+        logits = read @ router
+        _, chosen = jax.lax.top_k(logits, k)
+        chose = jax.nn.one_hot(chosen, e)
+        w = jax.nn.softmax(jnp.sum(chose * logits[:, None], -1), -1)
+        w = jnp.sum(chose * w[..., None], 1)
+        if held:
+            w = jax.lax.stop_gradient(w)
+        return sum(w[:, first + i:first + i + 1] * (
+            (jax.nn.relu(u @ mine["w_gate"][i]) * (u @ mine["w_up"][i]))
+            @ mine["w_down"][i]) for i in range(n_held))
+
+    def layer(read, u, mine):
+        routing = moe.route(read, router, k, True, score="softmax")
+        return moe.moe_layer(u, None, mine, k, held=held, form="reglu",
+                             routing=routing)[0]
+
+    before = device_profiler.snapshot()["counters"]
+    with jax.default_matmul_precision("highest"):
+        got, vjp = jax.vjp(layer, read, u, mine)
+        want, vjp_want = jax.vjp(formula, read, u, mine)
+    after = device_profiler.snapshot()["counters"]
+    assert after.get("moe.latent_rows", 0) == before.get("moe.latent_rows", 0)
+    assert after["moe.gmm_calls"] - before.get("moe.gmm_calls", 0) == 3
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    g = jax.random.normal(jax.random.PRNGKey(12), got.shape)
+    grads, grads_want = vjp(g), vjp_want(g)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-5)
+    assert bool(jnp.any(grads[0] != 0)) == (held is None)
+    with pytest.raises(ValueError, match="routing"):
+        moe.moe_layer(u[:-1], None, mine, k, held=held, form="reglu",
+                      routing=moe.route(read, router, k, True))

@@ -538,3 +538,54 @@ def test_replica_hot_path_makes_no_registry_lookup(tiny_engine, monkeypatch):
         assert "decode.row_steps_active" in stats["counters"]
     finally:
         replica.shutdown()
+
+
+# ------------------------------------------- the kernels' and layers' counters
+
+def test_a_router_ahead_of_attention_and_the_flash_kernels_are_counted():
+    """The names a lowering leaves in the aggregate for a layer whose router
+    reads the attention's input (`models/window_moe.py`): `moe.routed_ahead`
+    beside `moe.experts_held`, `moe.rows_capacity`, `moe.gmm_calls`,
+    `pattern.layers_unrolled` and `flash.window_calls`; the scope
+    `moe.route` entered BEFORE the window call's `swa.attend`, the experts'
+    `moe.experts` after it; and a Pallas flash call's `flash.kernels`, with
+    `flash.kernels_vmem_stated` there and unmoved for a call the default
+    VMEM holds (`benchmarks/metrics/flash_vmem_stated_share.json` reads the
+    two)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import window_moe
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    cfg = window_moe.WindowMoeConfig.tiny_ahead(
+        vocab_size=64, remat=False, layers=(1,), n_experts_held=4)
+    params = jax.eval_shape(lambda: window_moe.init(cfg, jax.random.PRNGKey(0)))
+    before = dp.snapshot()["counters"]
+    traced = jax.make_jaxpr(
+        lambda p, t: window_moe.forward_hidden(p, t, cfg)[0])(
+            params, jax.ShapeDtypeStruct((1, 32), jnp.int32))
+    q, k = (jax.ShapeDtypeStruct((1, 256, h, 16), jnp.float32) for h in (7, 1))
+    jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, use_pallas=True, block_q=128, block_k=128))(q, k, k)
+    after = dp.snapshot()["counters"]
+    grew = {name: after[name] - before.get(name, 0) for name in (
+        "moe.routed_ahead", "moe.experts_held", "moe.rows_capacity",
+        "moe.gmm_calls", "pattern.layers_unrolled", "flash.window_calls",
+        "flash.kernels", "flash.kernels_vmem_stated")}
+    assert grew == {
+        "moe.routed_ahead": 1, "moe.experts_held": 4,
+        "moe.rows_capacity": 32 * 4, "moe.gmm_calls": 3,
+        "pattern.layers_unrolled": 1, "flash.window_calls": 1,
+        "flash.kernels": 1, "flash.kernels_vmem_stated": 0}
+
+    def scopes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scopes(sub)
+
+    entered = list(scopes(traced.jaxpr))
+    first = lambda name: next(  # noqa: E731
+        i for i, s in enumerate(entered) if name in s)
+    assert first("moe.route") < first("swa.attend") < first("moe.experts")

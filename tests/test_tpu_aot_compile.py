@@ -10,7 +10,11 @@ kernels alone): libtpu is loaded there, not into the test process.
 It also pins the flash kernels' sequence limit (the kernels keep whole-
 sequence K/V, or q/dO, blocks in VMEM; see ops/flash_attention.py): the
 BACKWARD pass was refused at S 8192 until dk/dv took lse and delta as rows,
-and compiles there now, as at S 4096.
+and compiles there now, as at S 4096, under the compiler's default VMEM
+limit, every call lowering to the text it always had; at S 16384 all three
+kernels were refused (16.75M of the 16.00M a kernel gets) until PR 50 had a
+call whose blocks are past the default state its own limit, and compile
+there now, under the window rule and causal.
 """
 
 import json
@@ -415,6 +419,37 @@ for cell_call, n_heads, window in (
     out[cell_call + "_read_by"] = {
         metric: sum(1 for ln in laguna_calls if query.search(ln))
         for metric, query in laguna_queries.items()}
+
+    # a call the default VMEM holds states no limit of its own
+    out[cell_call + "_vmem_limits"] = re.findall(
+        r"vmem_limit_bytes=(\d+)", laguna_jaxpr)
+
+# train-smallthinker-1chip's two flash calls at S 16,384, [1, 16384, 28, 128]
+# over 4 KV heads (a group of 7), under `SlidingWindow(4096)` and causal,
+# value and gradient: the three kernels each, and the VMEM limit each states
+for cell_call, window in (("smallthinker_window", SlidingWindow(4096)),
+                          ("smallthinker_full", None)):
+    long_call = jax.value_and_grad(
+        lambda q, k, v, window=window: flash_attention(
+            q, k, v, use_pallas=True, mask=window).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    long_shapes = (spec((1, 16384, 28, 128), bf16),
+                   spec((1, 16384, 4, 128), bf16),
+                   spec((1, 16384, 4, 128), bf16))
+    out[cell_call + "_vmem_limits"] = re.findall(
+        r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(long_call)(*long_shapes)))
+    out[cell_call + "_plans"] = [
+        [plan.static, len(plan.tiles), max(map(len, plan.rows))]
+        for plan in block_schedule(16384, 16384, 512, 512,
+                                   window or True).values()]
+    try:
+        long_hlo = jax.jit(long_call).lower(*long_shapes).compile().as_text()
+    except Exception as e:  # noqa: BLE001 - a refusal is the finding
+        out[cell_call] = str(e)[:300]
+        continue
+    out[cell_call] = "compiled"
+    out[cell_call + "_operands"] = flash_operands(long_hlo)
+    out[cell_call + "_repeats"] = repeats(long_hlo, 28 * 16384 * 128)
 
 # ONE checkpointed attention layer of `nemotron_h` (train-nemotron3-1chip:
 # GQA 32 / 2 x 128, no RoPE, B 2 x S 2048) under `_bodies`' policy, value
@@ -843,6 +878,14 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
         "108a1a8a73455e8e4f31f9f24c5ff321021286d1f3ea41e5cf16d89d56f8eb18")
     assert compiled["laguna_window_jaxpr"] != (   # PR 47's
         "0192ab54ecd8b9eece3b74fe696ad7f626f15efc50d82530140f6db30591e62a")
+    # and since PR 50, which has a longer call state its VMEM limit, both
+    # trace to the text PR 49 left: at S 8,192 nothing is stated
+    assert compiled["laguna_full_jaxpr"] == (
+        "dc1bde509d1bcbdffd1972a302135611f48b337c4a9784192d6e2610f0bb16f6")
+    assert compiled["laguna_window_jaxpr"] == (
+        "bb8f601aafbc03b1d2e47ad5becb97d305d31ef91a6140603b735510530a66e6")
+    assert compiled["laguna_full_vmem_limits"] == []
+    assert compiled["laguna_window_vmem_limits"] == []
     assert compiled["laguna_window_scoped"] == [True] * 3
     assert compiled["laguna_full_scoped"] == [False] * 3
     assert compiled["laguna_window_read_by"] == {
@@ -853,8 +896,28 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
         "swa_attention_time_share": 0, "laguna_full_attention_time_share": 3}
 
 
+def test_window_and_full_flash_calls_at_s16384_compile_for_v5e(compiled):
+    """train-smallthinker-1chip's two calls, value and gradient: `[1, 28,
+    16384, 128]` over 4 KV heads under `SlidingWindow(4096)` (8 tiles of
+    512: rows of 9 steps, 252 a (batch, head)) and `CAUSAL` (rows of up to
+    32, 528), all six kernels loops. One KV head's K and V whole are 2 x 4
+    MiB, twice over in the pipeline's buffers: the compiler refused every
+    one ("Scoped allocation with size 16.75M and limit 16.00M") until each
+    stated its own limit, its blocks twice plus 16 MiB (`_vmem_limit`): 32.5
+    to 33.3 MiB of the v5e's 128."""
+    for name, steps, longest in (("smallthinker_window", 252, 9),
+                                 ("smallthinker_full", 528, 32)):
+        assert compiled[name] == "compiled", compiled[name]
+        assert compiled[name + "_plans"] == [[False, steps, longest]] * 3
+        limits = sorted(map(int, compiled[name + "_vmem_limits"]))
+        assert len(limits) == 3   # forward, dq, dk/dv
+        assert 32 * 2**20 < limits[0] <= limits[-1] < 34 * 2**20, limits
+
+
 # the cells' GQA calls: (query heads, KV heads, batch, sequence)
 _GQA_CALLS = {
+    "smallthinker_window": (28, 4, 1, 16384),
+    "smallthinker_full": (28, 4, 1, 16384),
     "laguna_window": (64, 8, 1, 8192), "laguna_full": (48, 8, 1, 8192),
     "flash_bd": (32, 4, 4, 4096), "flash_s2048": (32, 8, 4, 2048)}
 
@@ -863,8 +926,9 @@ _GQA_CALLS = {
 def test_gqa_flash_calls_read_k_and_v_at_the_kv_heads_count_for_v5e(
         compiled, call):
     """The cells' GQA calls, value and gradient, as compiled for the v5e:
-    `[1, 64 / 8, 8192, 128]` under the window rule and `[1, 48 / 8, 8192,
-    128]` causal (train-laguna-1chip), `[4, 32 / 4, 4096, 128]` under the
+    `[1, 28 / 4, 16384, 128]` under the window rule and causal, a group of 7
+    (train-smallthinker-1chip), `[1, 64 / 8, 8192, 128]` under the window
+    rule and `[1, 48 / 8, 8192, 128]` causal (train-laguna-1chip), `[4, 32 / 4, 4096, 128]` under the
     block-diffusion rule (train-sdar-1chip), `[4, 32 / 8, 2048, 128]`
     (the Mistral cells). Each of the three kernels takes K and V at the KV
     heads' count beside q (and dO) at the query heads', the index map

@@ -4,7 +4,10 @@
 rule) against the plain reference `benchmarks/reference_laguna.py`, at tiny
 sizes on the CPU, seeded weights. The program runs in float32 here, so that
 routing cannot flip between the two: every difference is then summation
-order.
+order. The same module run as SmallThinker-21BA3B (no dense layer, a full
+layer first, no rotary embedding in the full layers, the router ahead of the
+attention, ReGLU experts and no shared one) against
+`benchmarks/reference_smallthinker.py`, at the end.
 """
 
 import dataclasses
@@ -16,6 +19,7 @@ import numpy as np
 import pytest
 
 from benchmarks import reference_laguna as ref
+from benchmarks import reference_smallthinker as ref_st
 from ray_tpu._private import device_profiler
 from ray_tpu.models import llama, mla_moe, window_moe
 from ray_tpu.models.window_moe import FULL, SLIDING
@@ -32,8 +36,8 @@ CUT = dict(layers=(0, 1, 2, 3, 4))    # the dense layer + one period
 SHARE = dict(n_experts_held=4, first_expert=4)
 
 
-def _model(seed=0, **over):
-    cfg = window_moe.WindowMoeConfig.tiny(
+def _model(seed=0, preset=window_moe.WindowMoeConfig.tiny, **over):
+    cfg = preset(
         vocab_size=256, dtype=jnp.float32, remat=False, loss_chunk_size=16,
         **over)
     params = window_moe.init(cfg, jax.random.PRNGKey(seed))
@@ -62,11 +66,12 @@ def _loss_and_gradients(cfg, params, toks):
             lambda p: window_moe.loss_fn(p, {"tokens": toks}, cfg)))(params)
 
 
-def _assert_loss_and_gradients(cfg, params, model, toks, atol=GRAD_ATOL):
+def _assert_loss_and_gradients(cfg, params, model, toks, atol=GRAD_ATOL,
+                               reference=ref):
     assert sum(a.size for a in jax.tree.leaves(params)) == cfg.num_params()
     got, g_got = _loss_and_gradients(cfg, params, toks)
-    want, g_want = jax.value_and_grad(
-        lambda p: ref.loss_value(p, toks[:, :-1], toks[:, 1:], model))(params)
+    want, g_want = jax.value_and_grad(lambda p: reference.loss_value(
+        p, toks[:, :-1], toks[:, 1:], model))(params)
     np.testing.assert_allclose(got, want, rtol=RTOL)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_got),
                             jax.tree.leaves(g_want)):
@@ -332,3 +337,147 @@ def test_an_ep_mesh_axis_is_refused():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("ep",))
     with pytest.raises(NotImplementedError, match="ep"):
         window_moe.forward_hidden(params, _tokens(0)[:, :-1], cfg, mesh)
+
+
+# --------------------------------------------------------------------------
+# the same module as SmallThinker-21BA3B: the router AHEAD of the attention
+# --------------------------------------------------------------------------
+
+def _ahead(seed=0, **over):
+    """SmallThinker's pattern at toy widths
+    (`WindowMoeConfig.tiny_ahead`)."""
+    return _model(seed, window_moe.WindowMoeConfig.tiny_ahead, **over)
+
+
+@pytest.mark.parametrize("over, plan", [
+    (SHARE, [("loose", 4)]),
+    (dict(layers=(0, 1, 2, 3, 4)), [("loose", 1), ("periods", 1)]),
+    (dict(router_input="residual"), [("loose", 4)]),
+    (dict(router_input="ffn_input", expert_form="swiglu"), [("loose", 4)])],
+    ids=["share", "whole_and_scanned", "router_reads_the_residual",
+         "router_reads_the_ffn_input_swiglu"])
+def test_a_router_ahead_of_attention_matches_the_reference(over, plan):
+    """Loss and every gradient leaf against `reference_smallthinker`, in the
+    three readings of what the router reads (the last with the experts'
+    other form): the cell's cut (published layers 0-3, one chip's share:
+    the combine weights constants), and every expert held, where the
+    router trains and its gradient reaches `attn_norm` and the residual
+    through h, layer 0 unrolled before one scanned period."""
+    cfg, params, model = _ahead(**over)
+    assert cfg.plan()[3] == plan and cfg.n_dense_layers == 0
+    assert "shared" not in params["loose"]["full_sparse"]
+    assert cfg.rotary("full").theta == 0 \
+        and cfg.rotary("sliding") == llama.Rotary(1_500_000.0, 16, None, 1.0)
+    _assert_loss_and_gradients(cfg, params, model, _tokens(7),
+                               reference=ref_st)
+
+
+def test_the_routers_gradient_reaches_the_attentions_norm_through_h():
+    """One layer whose V projection is zero: the attention adds nothing,
+    so what reaches `attn_norm` comes through the ROUTER alone. Every expert
+    held and the router ahead: a gradient, the reference's; the router on
+    the feed-forward's input, or a share (constant weights): exactly none.
+    And the readings differ: the same weights under the reference's other
+    readings are outside the tolerance even here, where the three tensors
+    differ by a norm's scales alone (the attention adds nothing)."""
+    toks = _tokens(9, rows=1)
+
+    def norm_gradient(**over):
+        cfg, params, model = _ahead(layers=(1,), **over)
+        params["loose"]["sliding_sparse"]["wv"] *= 0
+        grad = lambda f: jax.jit(jax.grad(f))(params)[  # noqa: E731
+            "loose"]["sliding_sparse"]["attn_norm"]
+        with jax.default_matmul_precision("highest"):
+            got = grad(lambda p: window_moe.loss_fn(p, {"tokens": toks}, cfg))
+        return got, model, params, grad
+
+    got, model, params, grad = norm_gradient()
+    want = grad(lambda p: ref_st.loss_value(p, toks[:, :-1], toks[:, 1:],
+                                            model))
+    assert float(jnp.abs(want).max()) > 1e-4
+    np.testing.assert_allclose(got, want, atol=GRAD_ATOL * float(
+        jnp.abs(want).max()))
+    assert not np.any(norm_gradient(router_input="ffn_input")[0])
+    assert not np.any(norm_gradient(**SHARE)[0])
+    losses = [float(ref_st.loss_value(
+        params, toks[:, :-1], toks[:, 1:], dict(model, router_input=reads)))
+        for reads in ("attention_input", "residual", "ffn_input")]
+    assert min(abs(a - b) for a in losses for b in losses if a is not b) \
+        > 10 * RTOL * losses[0]
+
+
+def test_the_four_shares_routed_parts_add_up_without_a_shared_expert():
+    """The routed parts of the 4 shares (experts 0-3, 4-7, 8-11, 12-15) of
+    a layer routed AHEAD (the choice from one tensor, the rows dispatched
+    another) are the uncut reference's whole layer: nothing is counted once
+    beside them."""
+    cfg, params, model = _ahead()
+    p = jax.tree.map(lambda a: a[0], params["loose"]["sliding_sparse"])
+    x, read = jax.random.normal(jax.random.PRNGKey(8), (2, 1, 24, cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        u = ref._rms(x[0], p["mlp_norm"], cfg.norm_eps)
+        dense_w, chosen = ref_st.route(read[0], p, model)
+        whole = x[0] + ref_st.experts(u, dense_w, p, model)
+        routing = mla_moe._routing(read[0], p, cfg)
+        total = x[0]
+        for first in range(0, 16, 4):
+            share = dataclasses.replace(cfg, n_experts_held=4,
+                                        first_expert=first)
+            part = dict(p, experts=jax.tree.map(
+                lambda a: a[first:first + 4], p["experts"]))
+            y, e = mla_moe._expert_sublayer(x, part, share, routing=routing,
+                                            form="reglu")
+            np.testing.assert_array_equal(e, chosen)
+            total = total + (y[0] - x[0])
+    np.testing.assert_allclose(total, whole, rtol=RTOL, atol=ATOL)
+
+
+def test_the_published_pattern_without_a_dense_layer():
+    """`plan()` with no dense layer and a full layer FIRST: layer 0
+    unrolled, twelve scanned periods (window x 3, full) from layer 1 on,
+    layers 49-51 unrolled; the cell's cut, layers 0-3, four unrolled
+    layers. 21,506,562,560 parameters whole (the published 21B),
+    656,529,920 as the cell holds it; the counters of one lowering."""
+    layout = tuple(int(i % 4 != 0) for i in range(52))
+    theta = {"rope_theta": 1_500_000}
+    whole = window_moe.WindowMoeConfig(
+        vocab_size=151_936, d_model=2560, layer_types=layout,
+        rope_layout=layout, heads_per_layer=(28,) * 52,
+        mlp_layer_types=("sparse",) * 52, n_kv_heads=4, d_head=128,
+        window=4096, rope_parameters={FULL: theta, SLIDING: theta},
+        attn_gate=False, d_ff_expert=768, d_ff_shared=0, n_experts=64,
+        n_experts_held=64, experts_per_token=6, score="softmax",
+        routed_scaling_factor=1.0, router_input="attention_input",
+        expert_form="reglu")
+    assert whole.layer_types[:5] == (FULL, SLIDING, SLIDING, SLIDING, FULL)
+    assert whole.plan() == ([], [0, 49, 50, 51], list(range(1, 49, 4)),
+                            [("loose", 1), ("periods", 12), ("loose", 3)])
+    assert whole.num_params() == 52 * 398_627_840 + 777_914_880 \
+        == 21_506_562_560
+    cell = dataclasses.replace(whole, layers=(0, 1, 2, 3), n_experts_held=16,
+                               vocab_size=37_984)
+    assert cell.plan() == ([], [0, 1, 2, 3], [], [("loose", 4)])
+    assert cell.num_params() == 4 * 115_512_320 + 194_480_640 == 656_529_920
+    shapes = jax.eval_shape(lambda: window_moe.init(cell, jax.random.PRNGKey(0)))
+    assert sum(a.size for a in jax.tree.leaves(shapes)) == 656_529_920
+    assert shapes["loose"]["sliding_sparse"]["wq"].shape == (3, 2560, 28, 128)
+    assert set(shapes["loose"]["full_sparse"]) == {
+        "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "router", "experts"}
+    axes = window_moe.param_logical_axes(cell)
+    assert jax.tree.structure(shapes) == jax.tree.structure(
+        axes, is_leaf=lambda x: isinstance(x, tuple))
+    mixed = list(layout)
+    mixed[5] = 0   # a window layer without RoPE among those with
+    with pytest.raises(NotImplementedError, match="rotary"):
+        dataclasses.replace(whole, rope_layout=tuple(mixed))
+    with pytest.raises(NotImplementedError, match="shared"):
+        dataclasses.replace(whole, d_ff_shared=768)
+    cfg, params, _ = _ahead(**SHARE)
+    before = device_profiler.snapshot()["counters"]
+    jax.jit(lambda p, t: window_moe.forward_hidden(p, t, cfg)[0]).lower(
+        params, _tokens(0)[:, :-1])
+    after = device_profiler.snapshot()["counters"]
+    grew = lambda k: after.get(k, 0) - before.get(k, 0)  # noqa: E731
+    assert grew("moe.routed_ahead") == 4 == grew("pattern.layers_unrolled")
+    assert grew("flash.window_calls") == 3 and grew("pattern.periods") == 0
+    assert grew("moe.experts_held") == 4 * 4 and grew("moe.gmm_calls") == 4 * 3
