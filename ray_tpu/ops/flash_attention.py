@@ -191,7 +191,8 @@ def _step_width(own, other, rule):
 # A step's kind, where `masked` stands: False, no mask at all; True, the
 # rule's predicate over the whole tile; DIAGONAL (truthy: a masked step too),
 # the predicate over the tile's aligned `_SUB` x `_SUB` diagonal sub-tiles
-# alone, which hold every score the rule keeps of the tile.
+# alone, which hold every score the rule keeps of the tile; a `Band` or a
+# `Triangle` (below).
 DIAGONAL = "diagonal"
 _SUB = 128
 
@@ -207,6 +208,26 @@ class Band(NamedTuple):
     run: int
 
 
+class Triangle(NamedTuple):
+    """A step's kind too (truthy: a masked step): a square tile of n x n
+    sub-tiles of which the rule keeps nothing on one side of the sub-tile
+    diagonal. Each HALF of the owned block's `_SUB`-row groups is one
+    masked step against the walked axis as far as the half's own end
+    (`leading`: a causal tile's queries) or from its start on (its keys,
+    which dk/dv owns; a window's trailing tile): the quarter of the tile
+    beyond the first half's end, or before the second's start, is not run
+    (`_halves`: 12 of 16 sub-tiles at 512 x 512, 10 of which hold scores)."""
+    leading: bool
+
+
+def _halves(n, leading):
+    """A `Triangle` step's two pieces over n x n sub-tiles, as (groups from,
+    to; walked sub-tiles from, to)."""
+    half = (n + 1) // 2
+    return [(lo, hi, 0, hi) if leading else (lo, hi, lo, n)
+            for lo, hi in ((0, half), (half, n))]
+
+
 class KernelSchedule(NamedTuple):
     """One kernel's loop plan over one (batch, head): `tiles` are
     (q_start, q_rows, k_start, k_cols, masked), one per loop step, and
@@ -219,8 +240,10 @@ class KernelSchedule(NamedTuple):
     sub-tile on from the group before, as ONE `Band` step (`steps_band` of
     the `steps_masked`; its tile starts where group 0's run does and spans
     all the groups' runs, its row entry holds the index of the first whole
-    tile it stands for); otherwise ONE loop a grid row over `table`, every
-    step masked whole if any step is (`_run_row`)."""
+    tile it stands for), and a tile the rule cuts along its sub-tile
+    diagonal as a `Triangle` (`steps_triangle` of the `steps_masked`);
+    otherwise ONE loop a grid row over `table`, every step masked whole if
+    any step is (`_run_row`)."""
     width: int
     static: bool
     tiles: tuple
@@ -229,6 +252,7 @@ class KernelSchedule(NamedTuple):
     steps_masked: int
     steps_diagonal: int
     steps_band: int
+    steps_triangle: int
     steps_skipped: int
     executed_over_needed: float
 
@@ -255,6 +279,13 @@ class KernelSchedule(NamedTuple):
 # 29.5 ms) where the block-diffusion call at 2 x 2048 (24: rows of 1, 1, 1,
 # 1, 8, 6, 4, 2) goes from 7.50 to 5.20 ms, causal S 3072 (21) from 5.21 to
 # 4.31 and S 3584 (28) from 6.83 to 5.65: a row of 8 is not what collapsed.
+# A `Triangle` step is more straight-line code than the whole step it stands
+# for and counts as ONE all the same: measured at twice the pieces it has now
+# (one a 128-row group, PR 51) dk/dv gained at every plan under the budget
+# (the block-diffusion call's 24 steps, 8 of them triangles, 4.58 -> 4.17 ms;
+# causal S 3072, 6 of 21, and S 3584, 7 of 28, at batch 2: 2.13 -> 2.02 and
+# 2.80 -> 2.66), and a forward row of 8 (S 4096) went from 1.93 to 1.96
+# while its dq went from 2.59 to 2.45.
 _STATIC_BUDGET = {"fwd": (max, 8), "dkv": (sum, 28)}
 
 
@@ -263,23 +294,37 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
     offset = s_k - s_q
     needed = s_q * s_k if rule is None else rule.needed(s_q, s_k)
 
-    def diagonal_only(q0, nq, k0, nk):
-        """Of this (masked) tile the rule keeps nothing off the aligned
-        diagonal sub-tiles: asked of the rule as the tile itself was."""
-        if rule is None or nq != nk or nq % _SUB or nq == _SUB:
-            return False
-        subs = range(0, nq, _SUB)
-        return not any(rule.tile(q0 + offset + a, _SUB, k0 + b, _SUB)[0]
-                       for a in subs for b in subs if a != b)
-
-    def plan(kernel, width, n_rows, n_steps, tile, inside):
-        """tile(row, step) -> (q0, nq, k0, nk); inside(step): the step lies
-        within the true length of the axis the row walks."""
+    def plan(kernel, width, n_rows, n_steps, tile, length):
+        """tile(row, step) -> (q0, nq, k0, nk); `length`: the true length of
+        the axis the row walks."""
 
         def owned_and_walked(i, j):
             q0, nq, k0, nk = tile(i, j)
             return ((q0, nq), (k0, nk)) if kernel == "fwd" \
                 else ((k0, nk), (q0, nq))
+
+        def sub_tile(own_at, walked_at):
+            """The rule's answer for the `_SUB` x `_SUB` sub-tile at these
+            positions of the owned and of the walked axis."""
+            q_at, k_at = (own_at, walked_at) if kernel == "fwd" \
+                else (walked_at, own_at)
+            return rule.tile(q_at + offset, _SUB, k_at, _SUB)
+
+        def cut(i, j):
+            """The kind that does this (masked) tile on fewer sub-tiles, or
+            None; square, of several sub-tiles, asked of the rule sub-tile
+            by sub-tile as the tile itself was. `DIAGONAL`: nothing is kept
+            off the aligned diagonal sub-tiles. A `Triangle`: nothing is
+            kept on one side of the sub-tile diagonal."""
+            (own0, own), (walked0, wide) = owned_and_walked(i, j)
+            if own != wide or own % _SUB or own == _SUB:
+                return None
+            subs = range(0, own, _SUB)
+            kept = {b < a for a in subs for b in subs if a != b
+                    and sub_tile(own0 + a, walked0 + b)[0]}
+            if not kept:
+                return DIAGONAL
+            return Triangle(kept.pop()) if len(kept) == 1 else None
 
         def band(i, steps):
             """The `Band` step that does this grid row's whole-tile `steps`
@@ -296,10 +341,7 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
             runs = set()
             for a in range(0, own, _SUB):
                 kept = [b for b in range(0, len(steps) * wide, _SUB)
-                        if (rule.tile(own0 + a + offset, _SUB,
-                                      walked0 + b, _SUB) if kernel == "fwd"
-                            else rule.tile(walked0 + b + offset, _SUB,
-                                           own0 + a, _SUB))[0]]
+                        if sub_tile(own0 + a, walked0 + b)[0]]
                 if not kept or kept != list(
                         range(kept[0], kept[0] + len(kept) * _SUB, _SUB)):
                     return None
@@ -320,9 +362,10 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
                     else rule.tile(q0 + offset, nq, k0, nk)
                 if not some:
                     continue
-                masked = not (every and inside(j))
-                if masked and diagonal_only(q0, nq, k0, nk):
-                    masked = DIAGONAL
+                _, (walked0, wide) = owned_and_walked(i, j)
+                masked = not (every and walked0 + wide <= length)
+                if masked and rule is not None:
+                    masked = cut(i, j) or masked
                 steps.append((j, masked))
             kept.append(steps)
         skipped = n_rows * n_steps - sum(map(len, kept))
@@ -353,26 +396,29 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
         def executed(q0, nq, k0, nk, masked):
             if isinstance(masked, Band):  # each group its run alone
                 return (nq if kernel == "fwd" else nk) * masked.run * _SUB
+            if isinstance(masked, Triangle):  # its two halves' pieces
+                return _SUB * _SUB * sum(
+                    (hi - lo) * (last - first) for lo, hi, first, last
+                    in _halves(nq // _SUB, masked.leading))
             # a diagonal step executes its sub-tiles alone
             return nq * (_SUB if masked == DIAGONAL else nk)
 
         return KernelSchedule(
             width, static, tiles, by_row, len(tiles) - masked, masked,
             sum(t[4] == DIAGONAL for t in tiles),
-            sum(isinstance(t[4], Band) for t in tiles), skipped,
+            sum(isinstance(t[4], Band) for t in tiles),
+            sum(isinstance(t[4], Triangle) for t in tiles), skipped,
             sum(executed(*t) for t in tiles) / needed if needed
             else float("inf"))
 
     w = _step_width(block_q, block_k, rule)
     keys = plan(
         "fwd", w, _cdiv(s_q, block_q), _cdiv(s_k, w),
-        lambda qi, j: (qi * block_q, block_q, j * w, w),
-        lambda j: (j + 1) * w <= s_k)
+        lambda qi, j: (qi * block_q, block_q, j * w, w), s_k)
     wq = _step_width(block_k, block_q, rule)
     queries = plan(
         "dkv", wq, _cdiv(s_k, block_k), _cdiv(s_q, wq),
-        lambda kj, i: (i * wq, wq, kj * block_k, block_k),
-        lambda i: (i + 1) * wq <= s_q)
+        lambda kj, i: (i * wq, wq, kj * block_k, block_k), s_q)
     return {"fwd": keys, "dq": keys, "dkv": queries}
 
 
@@ -395,7 +441,15 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     (1 / n of the tile's matmuls and exponentials; every score left out is
     one the mask sets to exactly 0). What decides is the rule's answer, not
     its type: `CAUSAL`'s diagonal tiles keep 10 of their 16 sub-tiles, so
-    no causal plan has such a step. A grid row's steps TOGETHER are asked
+    no causal plan has such a step. Such a tile is a `Triangle` instead:
+    where the rule keeps nothing in the sub-tiles on one side of the sub-
+    tile diagonal, an unrolled plan runs each half of the owned block's
+    groups against the walked axis no further than the half reaches, under
+    the predicate, and leaves out the quarter of the tile that holds
+    nothing (12 of 16 sub-tiles at 512 x 512): a causal call's diagonal
+    tiles, a block-diffusion call's x_0 and x_t -> x_0 diagonal tiles, a
+    window's trailing tile (the other hand) where its row is no band. A
+    grid row's steps TOGETHER are asked
     the same way: where every 128-row group of the owned block keeps one
     contiguous run of sub-tiles of the tiles' span, each group's as long
     and a sub-tile on from the group's before (a window's rows: the kept
@@ -412,13 +466,17 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     diagonal left of them, 512 x 1024 at S 2048: 6 steps of 512 x 1,024 a
     head where the causal half is 2.10 M scores, 1.5 in all three kernels.
     Steps under a rule are now at most square (`_step_width`), so causal
-    with an owned block of B rows gives 1 + B / S: 1.25 at 512 and S 2048,
-    1.125 at S 4096; block diffusion at length 2,048, block 4 runs 24 of
+    with an owned block of B rows gives 1 + B / S as whole tiles, 1.25 at
+    512 and S 2048 and 1.125 at S 4096, and with the diagonal tiles as
+    triangles (PR 51) 1.125 and 1.0625 (the forward and dq at S 4096; its
+    dk/dv is a loop); block diffusion at length 2,048, block 4 runs 24 of
     the 64 tiles of 512 x 512 for 4,202,496 kept scores, 1.497 as whole
-    tiles, and its 4 x_t diagonal tiles (2,048 kept scores each) as
-    diagonal steps: 21 tiles' area, 1.310; `SlidingWindow(512)` at S 8,192
-    walked 31 tiles for 4,063,488 kept scores, 2.0, and runs 15 band steps
-    of 4 x [128, 640] and the first row's own tile: 1.274 (PR 48).
+    tiles, its 4 x_t diagonal tiles (2,048 kept scores each) as diagonal
+    steps, 21 tiles' area, 1.310, and its 4 x_0 and 4 x_t -> x_0 diagonal
+    tiles as triangles: 19 tiles' area, 1.185; `SlidingWindow(512)` at S
+    8,192 walked 31 tiles for 4,063,488 kept scores, 2.0, and runs 15 band
+    steps of 4 x [128, 640] (PR 48) and the first row's own tile as a
+    triangle: 1.258.
     """
     return _block_schedule(s_q, s_k, block_q, block_k, _rule(causal))
 
@@ -436,6 +494,9 @@ def _count_steps(*plans):
     # and those that are a grid row's one `Band` step
     device_profiler.count("flash.steps_band",
                           sum(p.steps_band for p in plans))
+    # and those run on the kept side of their tile's sub-tile diagonal
+    device_profiler.count("flash.steps_triangle",
+                          sum(p.steps_triangle for p in plans))
     device_profiler.count("flash.tiles_skipped",
                           sum(p.steps_skipped for p in plans))
 
@@ -450,11 +511,13 @@ def _count_fetches(qs, ks):
     device_profiler.count("flash.head_rows", b * h)
 
 
-def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band):
+def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band,
+             part):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
     `body(masked)` -> a step (index, carry) -> carry; `diagonal` such a
     step for the plan's `DIAGONAL` ones; `band(kind)` -> the carry after a
-    row's ONE `Band` step, which starts from none.
+    row's ONE `Band` step, which starts from none; `part`: a half's share
+    of a `Triangle` step (`_triangle`).
 
     The trip counts depend on the grid row, and Mosaic schedules nothing
     across the iterations of a loop: the MXU then waits out every step's
@@ -463,7 +526,8 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band):
     line code in which one step's matmuls run under its neighbours'
     softmax (1.3 ms), each step masked only if it needs it, and on its
     diagonal sub-tiles alone where they hold all the rule keeps of it (a
-    quarter of the work at 512); a row that is a band of sub-tiles (under
+    quarter of the work at 512) or on the kept side of its sub-tile
+    diagonal (`_triangle`); a row that is a band of sub-tiles (under
     a window of 512 each 128-row group's 5 of the 8 its two tiles span) is
     ONE step over the band, the groups a batch as a diagonal step's are,
     and the forward rescales nothing for a second; rows of one kind of
@@ -489,7 +553,11 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band):
                 return finish(band(mine[0][1]))
             c = carry
             for j, masked in mine:
-                c = (diagonal if masked == DIAGONAL else body(masked))(j, c)
+                if isinstance(masked, Triangle):
+                    c = _triangle(masked, j, c, part)
+                else:
+                    c = (diagonal if masked == DIAGONAL
+                         else body(masked))(j, c)
             finish(c)
         return run
 
@@ -502,6 +570,37 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band):
     for same in rows.values():
         pl.when(functools.reduce(jnp.logical_or, [row == i for i in same]))(
             branch(plan.rows[same[0]]))
+
+
+def _triangle(kind, j, carry, part):
+    """`carry` (arrays of [owned block, ...]) after step j as a `Triangle`:
+    each half of the block through `part(j, rows, first, cols, its carry)`,
+    the half's `rows` after the `cols` positions of the walked axis that
+    start `first` into the tile, under the predicate. Two pieces and not
+    one a `_SUB`-row group, which would run the 10 sub-tiles that hold
+    scores alone: on the chip (PERF.md §6, PR 51) the three kernels
+    together take the same time either way (dq and dk/dv, which the vector
+    units bound, 6-10% under whole tiles by groups and 3-9% by halves; the
+    forward, which the MXU bounds, 0 and 3-5%: a matmul of 128 rows keeps
+    it no less busy than one of 512), and every piece is traced, lowered
+    and compiled again in each grid row's branch: by groups a causal call
+    took 2.4x the parent's time to trace, 9 s of `train-joyai-1chip`'s
+    set-up."""
+    out = []
+    n = jax.tree.leaves(carry)[0].shape[0] // _SUB
+    for lo, hi, first, last in _halves(n, kind.leading):
+        rows = slice(lo * _SUB, hi * _SUB)
+        out.append(part(j, rows, first * _SUB, (last - first) * _SUB,
+                        _rows(carry, rows)))
+    return jax.tree.map(lambda *halves: jax.lax.concatenate(halves, 0), *out)
+
+
+def _rows(x, rows):
+    """`rows` of every array in `x` (`lax.slice_in_dim`: a piece is traced
+    in every branch that runs it, and `x[rows]` costs several times the
+    tracing)."""
+    return jax.tree.map(
+        lambda a: jax.lax.slice_in_dim(a, rows.start, rows.stop), x)
 
 
 def _grouped(x):
@@ -714,9 +813,13 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
     # position i + (seq_k - seq_q), matching the oracle's tril(k=s_k-s_q).
     causal_offset = seq_k - seq_q
 
-    def own_pos(cols):
-        return (qi * block_q + causal_offset
-                + jax.lax.broadcasted_iota(jnp.int32, (block_q, cols), 0))
+    def own_pos(cols, rows=slice(0, block_q)):
+        # of `rows` of the block: built, never sliced from a wider one (the
+        # v5e's compiler aborts on a slice of a broadcast iota that spans
+        # lane tiles: "Check failed: limits[i] <= dim(i)")
+        return (qi * block_q + (causal_offset + rows.start)
+                + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows.stop - rows.start, cols), 0))
 
     q_pos = own_pos(width)
 
@@ -770,6 +873,10 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
             _grouped(q), _grouped(own_pos(kind.run * _SUB)),
             pl.multiple_of(qi * block_q + kind.shift, _SUB), True, None))
 
+    def part(j, rows, first, cols, carry):
+        return attend(_rows(q, rows), own_pos(cols, rows),
+                      j * width + first, True, carry)
+
     o0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     # m and l as columns ([block_q, 1] and lane partials), not 1-D rows:
     # they broadcast along lanes with no relayout
@@ -783,7 +890,8 @@ def _fwd_kernel(q_refs, k_refs, v_ref, o_ref, lse_ref, *, scale, mask,
         o_ref[0, 0] = (o / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m + jnp.log(l)
 
-    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish, diagonal, band)
+    _run_row(plan, qi, steps_ref, step, (o0, m0, l0), finish, diagonal, band,
+             part)
 
 
 def _pad_seq(x, block):
@@ -848,9 +956,10 @@ def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     delta = delta_ref[0, 0]  # [block_q, 1]
     causal_offset = seq_k - seq_q
 
-    def own_pos(cols):
-        return (qi * block_q + causal_offset
-                + jax.lax.broadcasted_iota(jnp.int32, (block_q, cols), 0))
+    def own_pos(cols, rows=slice(0, block_q)):
+        return (qi * block_q + (causal_offset + rows.start)
+                + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows.stop - rows.start, cols), 0))
 
     q_pos = own_pos(width)
 
@@ -892,9 +1001,14 @@ def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             pl.multiple_of(qi * block_q + kind.shift, _SUB), True,
             _grouped(dq0)))
 
+    def part(j, rows, first, cols, dq):
+        return attend(
+            *_rows((q, do, lse, delta), rows), own_pos(cols, rows),
+            j * width + first, True, dq)
+
     dq0 = tuple(map(jnp.zeros_like, q))
     _run_row(plan, qi, steps_ref, step, dq0,
-             functools.partial(_store_parts, dq_ref), diagonal, band)
+             functools.partial(_store_parts, dq_ref), diagonal, band, part)
 
 
 def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
@@ -915,9 +1029,11 @@ def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
     k_blk = _loaded(k_refs)  # ([block_k, D],), or with [block_k, R]
     v_blk = v_ref[0, 0].astype(jnp.float32)
 
-    def own_pos(cols):
-        return kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, cols), 0)
+    def own_pos(cols, rows=slice(0, block_k)):
+        # the whole block's adds no op: a loop plan traces to the text it had
+        return (kj * block_k + rows.start if rows.start else kj * block_k) \
+            + jax.lax.broadcasted_iota(
+                jnp.int32, (rows.stop - rows.start, cols), 0)
 
     k_pos = own_pos(width)
     causal_offset = seq_k - seq_q
@@ -991,13 +1107,20 @@ def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
             pl.multiple_of(kj * block_k + kind.shift, _SUB), stat, True,
             _grouped(zero)))
 
+    def part(i, rows, first, cols, carry):
+        return attend(
+            *_rows((k_blk, v_blk), rows), own_pos(cols, rows),
+            i * width + first,
+            lambda ref: ref[0, 0, pl.dslice(i, 1), first:first + cols],
+            True, carry)
+
     def finish(carry):
         dk, dv = carry
         _store_parts(dk_ref, dk)
         dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
     zero = tuple(map(jnp.zeros_like, k_blk)), jnp.zeros_like(v_blk)
-    _run_row(plan, kj, steps_ref, step, zero, finish, diagonal, band)
+    _run_row(plan, kj, steps_ref, step, zero, finish, diagonal, band, part)
 
 
 def _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q, plan,
@@ -1196,7 +1319,8 @@ def flash_attention(
     block)` or `SlidingWindow(window)`. The kernels skip the tiles it keeps
     nothing of, run those it keeps whole with no mask, and apply its
     predicate in the others, on a tile's diagonal sub-tiles alone where
-    they hold all it keeps, or on a row's band of sub-tiles in one step
+    they hold all it keeps, on the sub-tiles on the kept side of a tile's
+    sub-tile diagonal, or on a row's band of sub-tiles in one step
     (`block_schedule`); no dense mask is built on the TPU path.
 
     In parts (latent attention): with `q_rope` [B, S, H, R] and `k_rope`

@@ -11,8 +11,8 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import (
     _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, Band, BlockDiffusion,
-    SlidingWindow, _clamp_block, _reference_attention, block_schedule,
-    flash_attention)
+    SlidingWindow, Triangle, _clamp_block, _reference_attention,
+    block_schedule, flash_attention)
 
 
 def _make_qkv(B=1, S=128, H=2, D=64, kv_heads=None, seed=0):
@@ -160,14 +160,18 @@ def _mask(s_q, s_k, causal, rows, cols):
 
 # (s_q, s_k, block_q, block_k, causal): executed/needed bound of fwd/dq, of dk/dv
 _SCHEDULES = {
-    "s2048-default": ((2048, 2048, None, None, True), 1.25, 1.25),
-    # causal steps are cut to the owned block: 512 queries, 1,024 keys
-    "s2048-512x1024": ((2048, 2048, 512, 1024, True), 1.25, 1.5),
-    "s2048-1024x512": ((2048, 2048, 1024, 512, True), 1.5, 1.25),
-    "s2048-256x512": ((2048, 2048, 256, 512, True), 1.125, 1.25),
-    "s2048-256x256": ((2048, 2048, 256, 256, True), 1.125, 1.125),
+    # the four diagonal tiles in two halves, on 12 of their 16 sub-tiles
+    # (PR 51): 1.25 as whole tiles
+    "s2048-default": ((2048, 2048, None, None, True), 1.125, 1.125),
+    # causal steps are cut to the owned block: 512 queries, 1,024 keys; a
+    # tile that is not square (the owned block of 1,024 over steps of 512)
+    # runs whole, and so does every tile of a plan in a loop
+    "s2048-512x1024": ((2048, 2048, 512, 1024, True), 1.125, 1.5),
+    "s2048-1024x512": ((2048, 2048, 1024, 512, True), 1.5, 1.125),
+    "s2048-256x512": ((2048, 2048, 256, 512, True), 1.0625, 1.25),
+    "s2048-256x256": ((2048, 2048, 256, 256, True), 1.0625, 1.125),
     "s2048-noncausal": ((2048, 2048, 512, 1024, False), 1.0, 1.0),
-    "s4096-default": ((4096, 4096, None, None, True), 1.125, 1.125),
+    "s4096-default": ((4096, 4096, None, None, True), 1.0625, 1.125),
     "s8192-default": ((8192, 8192, None, None, True), 1.0625, 1.0625),
     "cross-128-over-384": ((128, 384, 128, 128, True), 1.2, 1.2),
     "cross-64-over-128": ((64, 128, None, None, True), 1.33, 1.33),
@@ -178,21 +182,24 @@ _SCHEDULES = {
     "s100-one-block": ((100, 100, None, None, True), 2.0, 2.0),
     "s128-one-block": ((128, 128, None, None, False), 1.0, 1.0),
     # block diffusion over [x_t ; x_0]: 24 of the 64 tiles of 512 x 512,
-    # the 4 x_t diagonal ones on their four 128-wide sub-tiles: 21 / 24 of
+    # the 4 x_t diagonal ones on their four 128-wide sub-tiles, the 4 x_0
+    # and the 4 x_t -> x_0 diagonal ones on 12 of their 16: 19 / 24 of
     # 1.4971
     "bd-l2048-b4": ((4096, 4096, None, None, BlockDiffusion(2048, 4)),
-                    1.31, 1.31),
-    # a block as wide as the sub-tile, and one wider (no diagonal step)
+                    1.1853, 1.1853),
+    # a block as wide as the sub-tile, and one wider (no diagonal step; at
+    # 256 the x_0 diagonal tiles keep both blocks on their diagonal whole,
+    # scores on both sides: whole tiles; the x_t -> x_0 ones triangles)
     "bd-l2048-b128": ((4096, 4096, None, None, BlockDiffusion(2048, 128)),
-                      1.3, 1.3),
+                      1.12, 1.12),
     "bd-l2048-b256": ((4096, 4096, None, None, BlockDiffusion(2048, 256)),
-                      1.4, 1.4),
+                      1.28, 1.28),
     "bd-l1024-b32-256": ((2048, 2048, 256, 256, BlockDiffusion(1024, 32)),
-                         1.34, 1.34),
+                         1.22, 1.22),
     # x_t ends inside a tile: that tile holds x_0 keys too, so is no
     # diagonal step (the first x_t tile is the only one)
     "bd-l640-b4-tile-cuts-the-halves": (
-        (1280, 1280, None, None, BlockDiffusion(640, 4)), 3.98, 3.98),
+        (1280, 1280, None, None, BlockDiffusion(640, 4)), 3.34, 3.34),
     "bd-l512-b4-128": ((1024, 1024, 128, 128, BlockDiffusion(512, 4)),
                        1.49, 1.49),
     # a block that does not divide the tile, a length that is no tile multiple
@@ -206,31 +213,32 @@ _SCHEDULES = {
     # scores (2.0 as whole tiles); 15 rows of two run as ONE band step, each
     # 128-row group on 5 sub-tiles of their 8
     "swa-w512-s8192": ((8192, 8192, None, None, SlidingWindow(512)),
-                       1.28, 1.28),
+                       1.26, 1.26),
     # band steps elsewhere: a window that is not the tile (3 and 2 sub-tiles
     # a group), one that divides nothing (6 sub-tiles, both ends cut), a
     # padded last block, s_q != s_k (a multiple of the sub-tile apart; 64
     # apart a group's run is one sub-tile longer, and the key groups of
     # dk/dv keep runs of different lengths: whole tiles there)
     "swa-w256-s2048-band": ((2048, 2048, None, None, SlidingWindow(256)),
-                            1.74, 1.74),
+                            1.6, 1.6),
     "swa-w128-s1024-256x256-band": (
-        (1024, 1024, 256, 256, SlidingWindow(128)), 2.14, 2.14),
+        (1024, 1024, 256, 256, SlidingWindow(128)), 2.0, 2.0),
     "swa-w600-s2048-band": ((2048, 2048, None, None, SlidingWindow(600)),
-                            1.5, 1.5),
+                            1.38, 1.38),
     "swa-w256-s900-256x256-padded-band": (
-        (900, 900, 256, 256, SlidingWindow(256)), 1.83, 1.83),
+        (900, 900, 256, 256, SlidingWindow(256)), 1.74, 1.74),
     "swa-w256-cross-512-over-768-band": (
-        (512, 768, 256, 256, SlidingWindow(256)), 1.5, 1.75),
+        (512, 768, 256, 256, SlidingWindow(256)), 1.5, 1.5),
     "swa-w256-cross-512-over-832-band": (
-        (512, 832, 256, 256, SlidingWindow(256)), 2.0, 3.0),
+        (512, 832, 256, 256, SlidingWindow(256)), 2.0, 2.5),
     # no band: a run longer than two steps (9 sub-tiles at 512), and rows
-    # of 128 (one group: nothing to stagger)
+    # of 128 (one group: nothing to stagger); a row's trailing tile (a
+    # strict upper triangle) and its own are triangles of either hand
     "swa-w1024-s4096-no-band": ((4096, 4096, None, None, SlidingWindow(1024)),
-                                1.5, 1.5),
+                                1.25, 1.25),
     # windows and lengths that do not divide each other, blocks that differ
     "swa-w1000-s2048-256x512": ((2048, 2048, 256, 512, SlidingWindow(1000)),
-                                1.27, 1.53),
+                                1.15, 1.53),
     "swa-w100-s320-padded": ((320, 320, 128, 128, SlidingWindow(100)),
                              3.03, 3.03),
     "swa-w200-cross-128-over-384": ((128, 384, 128, 128, SlidingWindow(200)),
@@ -252,6 +260,33 @@ _BANDS = {
 }
 
 
+# the cases whose plans hold triangle steps: how many, in the forward's, dq's
+# and dk/dv's (a plan in a loop, a tile that is not square or is one sub-
+# tile, a tile that keeps scores on both sides of its sub-tile diagonal: none)
+_TRIANGLES = {
+    "s2048-default": (4, 4, 4),
+    "s2048-512x1024": (4, 4, 0),
+    "s2048-1024x512": (0, 0, 4),
+    "s2048-256x512": (8, 8, 0),
+    "s2048-256x256": (8, 8, 0),
+    "s4096-default": (8, 8, 0),
+    "bd-l2048-b4": (8, 8, 8),
+    "bd-l2048-b128": (8, 8, 8),
+    "bd-l2048-b256": (4, 4, 4),
+    "bd-l1024-b32-256": (8, 8, 8),
+    "bd-l640-b4-tile-cuts-the-halves": (4, 4, 4),
+    "swa-w512-s8192": (1, 1, 1),
+    "swa-w600-s2048-band": (2, 2, 2),
+    "swa-w256-s2048-band": (1, 1, 1),
+    "swa-w128-s1024-256x256-band": (1, 1, 1),
+    "swa-w256-s900-256x256-padded-band": (1, 1, 1),
+    "swa-w256-cross-512-over-768-band": (0, 0, 2),
+    "swa-w256-cross-512-over-832-band": (0, 0, 4),
+    "swa-w1024-s4096-no-band": (14, 14, 14),
+    "swa-w1000-s2048-256x512": (12, 12, 0),
+}
+
+
 @pytest.mark.parametrize("case", sorted(_SCHEDULES))
 def test_block_schedule_against_the_mask(case):
     """Every step block_schedule calls unmasked has no masked score in it,
@@ -261,9 +296,10 @@ def test_block_schedule_against_the_mask(case):
     if block_q is None:
         block_q, block_k = _default_blocks(s_q, s_k)
     plans = block_schedule(s_q, s_k, block_q, block_k, causal)
-    assert plans["dq"] == plans["fwd"]
-    for name, bound in zip(("fwd", "dkv"), bounds):
-        plan = plans[name]
+    for kernel, bound in zip(("fwd", "dq", "dkv"),
+                             (bounds[0], bounds[0], bounds[1])):
+        plan = plans[kernel]
+        name = "dkv" if kernel == "dkv" else "fwd"   # which axis it owns
         rows = max(t[0] + t[1] for t in plan.tiles)
         cols = max(t[2] + t[3] for t in plan.tiles)
         if name == "fwd":   # a band step may end inside the last whole step
@@ -302,6 +338,21 @@ def test_block_schedule_against_the_mask(case):
                 assert plan.static and sub_tiled
                 for a in range(0, nq, _SUB):
                     painted[q0 + a:q0 + a + _SUB, k0 + a:k0 + a + _SUB] += 1
+            elif isinstance(masked, Triangle):
+                # unrolled only: each half of the OWNED block's 128-row
+                # groups is one step, against the walked axis up to the
+                # half's own end (leading) or from its start on; the
+                # quarter of the tile that is not run holds no score (the
+                # coverage below)
+                assert plan.static and sub_tiled
+                own0, walked0 = (q0, k0) if name == "fwd" else (k0, q0)
+                half = (nq // _SUB + 1) // 2 * _SUB
+                for lo, hi in ((0, half), (half, nq)):
+                    run = slice(walked0, walked0 + hi) if masked.leading \
+                        else slice(walked0 + lo, walked0 + nq)
+                    rows_ = slice(own0 + lo, own0 + hi)
+                    painted[(rows_, run) if name == "fwd"
+                            else (run, rows_)] += 1
             else:
                 painted[q0:q0 + nq, k0:k0 + nk] += 1
                 if masked and plan.static and sub_tiled and causal:
@@ -358,23 +409,30 @@ def test_block_schedule_against_the_mask(case):
         assert plan.steps_band == sum(
             isinstance(t[4], Band) for t in plan.tiles) == _BANDS.get(
                 case, (0, 0))[name == "dkv"]
+        assert plan.steps_triangle == sum(
+            isinstance(t[4], Triangle) for t in plan.tiles) == _TRIANGLES.get(
+                case, (0, 0, 0))[("fwd", "dq", "dkv").index(kernel)]
         np.testing.assert_allclose(plan.executed_over_needed,
                                    painted.sum() / mask.sum())
-        assert plan.executed_over_needed <= bound + 1e-9, name
+        assert plan.executed_over_needed <= bound + 1e-9, kernel
 
 
 def test_block_schedule_starting_point():
     """The schedule before PR 26 (steps of block_q x block_k up to the
-    diagonal, 512 x 1024) executed 1.5x the causal half at S 2048; the
-    defaults now execute at most 1.25x in all three kernels."""
+    diagonal, 512 x 1024) executed 1.5x the causal half at S 2048; square
+    steps 1.25x (PR 26); with the four diagonal tiles in two halves, on 12
+    of their 16 sub-tiles (PR 51), the defaults execute 1.125x in all three
+    kernels (1.0625x would be the 10 that hold scores)."""
     s, block_q, block_k = 2048, 512, 1024
     old = sum(-(-(qi + 1) * block_q // block_k) * block_q * block_k
               for qi in range(s // block_q))
     assert old / _mask(s, s, True, s, s).sum() == pytest.approx(1.5, abs=1e-3)
     for plan in block_schedule(s, s, *_default_blocks(s, s), True).values():
-        assert plan.executed_over_needed <= 1.25
-        # six of the ten steps a head lie wholly below the diagonal
-        assert (plan.steps_unmasked, plan.steps_masked) == (6, 4)
+        assert plan.executed_over_needed == pytest.approx(1.125, abs=1e-3)
+        # six of the ten steps a head lie wholly below the diagonal, the
+        # four on it are triangles
+        assert (plan.steps_unmasked, plan.steps_masked,
+                plan.steps_triangle) == (6, 4, 4)
 
 
 # Shapes where a grid row runs several steps, some unmasked and some masked:
@@ -460,6 +518,10 @@ def test_flash_attention_counts_its_steps(rule):
     # of their 4
     assert sum(p.steps_band for p in plans.values()) == (
         3 if isinstance(rule, SlidingWindow) else 0)
+    # a causal call's two diagonal tiles; the x_0 and the x_t -> x_0 tile;
+    # a window's first row, its own tile alone (the second is the band)
+    assert [p.steps_triangle for p in plans.values()] == [
+        1 if isinstance(rule, SlidingWindow) else 2] * 3
     before = device_profiler.snapshot()["counters"]
     jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, **how)))(q)
     after = device_profiler.snapshot()["counters"]
@@ -470,6 +532,7 @@ def test_flash_attention_counts_its_steps(rule):
                         ("flash.steps_masked", "steps_masked"),
                         ("flash.steps_diagonal", "steps_diagonal"),
                         ("flash.steps_band", "steps_band"),
+                        ("flash.steps_triangle", "steps_triangle"),
                         ("flash.tiles_skipped", "steps_skipped")):
         assert after[name] - before.get(name, 0) == sum(
             getattr(plans[kernel], field) for kernel in ("fwd", "dq", "dkv"))
@@ -487,24 +550,29 @@ def test_block_diffusion_schedule_at_the_cell_shape():
     diagonal tiles that keep 2,048 of 262,144 scores each), half of them
     with no mask; the rule keeps L^2 + L x block scores, HALF of what a
     causal call at S 4,096 keeps; an x_t row tile visits x_0 tiles 0..i and
-    then its own x_t tile, which is no contiguous range."""
+    then its own x_t tile, which is no contiguous range. The EIGHT masked
+    tiles that are not `DIAGONAL`, the four x_0 diagonal tiles (block-
+    causal, `k_blk <= q_blk`) and the four x_t -> x_0 diagonal tiles
+    (`k_blk < q_blk`), keep 10 of their 16 sub-tiles and are triangles,
+    run in two halves on 12."""
     length, block, tile = 2048, 4, 512
     rule = BlockDiffusion(length, block)
     plans = block_schedule(2 * length, 2 * length, tile, tile, rule)
     assert rule.needed(2 * length, 2 * length) \
         == length * length + length * block == 4_202_496
-    causal = block_schedule(2 * length, 2 * length, tile, tile, True)["fwd"]
-    assert sum(t[1] * t[3] for t in causal.tiles) \
-        / causal.executed_over_needed == pytest.approx(2 * 4_202_496, rel=2e-3)
+    assert CAUSAL.needed(2 * length, 2 * length) \
+        == pytest.approx(2 * 4_202_496, rel=2e-3)
     dense = _dense_block_diffusion(length, block)
     assert dense.sum() == 4_202_496
     for name, plan in plans.items():
         assert len(plan.tiles) == 24 and plan.steps_skipped == 40
-        # the x_t diagonal tiles run their four 128-wide sub-tiles: 21 / 24
-        # of the 1.497 that 24 whole tiles are
+        # the x_t diagonal tiles run their four 128-wide sub-tiles, the
+        # eight triangles 12 of 16: 19 / 24 of the 1.497 that 24 whole
+        # tiles are
         assert plan.executed_over_needed == pytest.approx(
-            21 * tile * tile / 4_202_496)
+            19 * tile * tile / 4_202_496) == pytest.approx(1.1852, abs=1e-4)
         by_kind = {"x0": 0, "xt_to_x0": 0, "xt_diagonal": 0}
+        hand = Triangle(name != "dkv")
         for q0, nq, k0, nk, masked in plan.tiles:
             assert dense[q0:q0 + nq, k0:k0 + nk].any()
             if q0 >= length:
@@ -517,10 +585,14 @@ def test_block_diffusion_schedule_at_the_cell_shape():
                 assert dense[q0:q0 + nq, k0:k0 + nk].sum() == length
                 by_kind["xt_diagonal"] += 1
             assert (masked == DIAGONAL) == (k0 < length)
+            # the tiles on the diagonal of each clean quadrant
+            assert (masked == hand) == (k0 >= length
+                                        and q0 % length == k0 - length)
         assert by_kind == {"x0": 10, "xt_to_x0": 10, "xt_diagonal": 4}
         # all three unrolled, each step masked only if it needs it
         assert plan.static and plan.steps_diagonal == 4
-        assert (plan.steps_unmasked, plan.steps_masked) == (12, 12)
+        assert (plan.steps_unmasked, plan.steps_masked,
+                plan.steps_triangle) == (12, 12, 8)
     fwd, dkv = plans["fwd"], plans["dkv"]
     assert [len(r) for r in fwd.rows] == [2, 3, 4, 5, 1, 2, 3, 4]
     assert [j for j, _ in fwd.rows[2]] == [2, 4, 5, 6]   # own tile, x_0 0..2
@@ -530,8 +602,13 @@ def test_block_diffusion_schedule_at_the_cell_shape():
     assert [len(r) for r in dkv.rows] == [1, 1, 1, 1, 8, 6, 4, 2]
     assert dkv.rows[0] == ((0, DIAGONAL),)
     assert [j for j, _ in dkv.rows[4]] == list(range(8))
-    assert dkv.rows[5] == ((1, True), (2, False), (3, False),
-                           (5, True), (6, False), (7, False))
+    assert dkv.rows[5] == ((1, Triangle(False)), (2, False), (3, False),
+                           (5, Triangle(False)), (6, False), (7, False))
+    for q0 in (512, 512 + length):   # both kinds, sub-tile by sub-tile
+        assert [[rule.tile(q0 + a, 128, length + 512 + b, 128)
+                 for b in range(0, 512, 128)] for a in range(0, 512, 128)] \
+            == [[(True, True)] * g + [(True, False)] + [(False, False)]
+                * (3 - g) for g in range(4)]
 
 
 # what dk/dv's budget is for: the plan's steps in all (a head's, at 512 x
@@ -565,6 +642,7 @@ def test_dkv_is_unrolled_under_a_budget_of_the_plans_total_steps(case):
     if not static:
         # ONE loop a grid row, masked throughout, on whole tiles
         assert dkv.steps_unmasked == 0 == dkv.steps_diagonal == dkv.steps_band
+        assert dkv.steps_triangle == 0
         assert dkv.table[-1, 0] == len(dkv.rows[-1])
     # forward and dq keep their cap on the longest row
     assert plans["fwd"].static == (max(map(len, plans["fwd"].rows)) <= 8)
@@ -676,20 +754,22 @@ def test_sliding_window_schedule_at_the_cell_shape():
     tile by sub-tile each 128-row group keeps 5 of the 8 sub-tiles the two
     span, a sub-tile on from the group before, so the row is ONE band step
     (20 sub-tiles for 32; 1.25x), but for the first, which has no tile
-    before it (dk/dv: the last key tile none after): 16 steps a plan, all
-    three unrolled, dk/dv's 16 under its budget of 28. The full layers run
+    before it (dk/dv: the last key tile none after) and is a triangle (12
+    sub-tiles for 16): 16 steps a plan, all three unrolled, dk/dv's 16
+    under its budget of 28. The full layers run
     `CAUSAL` in loops throughout (rows of up to 16, 136 steps)."""
     rule = SlidingWindow(512)
     plans = block_schedule(8192, 8192, 512, 512, rule)
     for name, plan in plans.items():
         assert [len(r) for r in plan.rows] == [1] * 16
         assert (plan.steps_unmasked, plan.steps_masked, plan.steps_diagonal,
-                plan.steps_band, plan.steps_skipped) == (0, 16, 0, 15, 225)
+                plan.steps_band, plan.steps_triangle, plan.steps_skipped) \
+            == (0, 16, 0, 15, 1, 225)
         assert plan.static
         assert plan.executed_over_needed == pytest.approx(
-            (15 * 512 * 640 + 512 * 512) / 4_063_488) \
-            == pytest.approx(1.274, abs=1e-3)
-    assert plans["fwd"].rows[0] == ((0, True),)
+            (15 * 512 * 640 + 12 * 128 * 128) / 4_063_488) \
+            == pytest.approx(1.258, abs=1e-3)
+    assert plans["fwd"].rows[0] == ((0, Triangle(True)),)
     # ONE kind of band a plan, so one body for its fifteen rows: a query
     # row's keys start a tile before its own, a key row's queries with it
     assert plans["fwd"].rows[5] == ((4, Band(-512, 5)),)
@@ -699,7 +779,7 @@ def test_sliding_window_schedule_at_the_cell_shape():
     assert plans["dkv"].tiles[5] == (5 * 512, 1024, 5 * 512, 512, Band(0, 5))
     assert {r[0][1] for r in plans["fwd"].rows[1:]} == {Band(-512, 5)}
     assert {r[0][1] for r in plans["dkv"].rows[:15]} == {Band(0, 5)}
-    assert plans["dkv"].rows[15] == ((15, True),)
+    assert plans["dkv"].rows[15] == ((15, Triangle(False)),)
     some, every = rule.tile(5 * 512, 512, 4 * 512, 512)
     assert some and not every
     # the trailing tile's sub-tile (0, 1), above its diagonal, is kept whole
@@ -714,35 +794,50 @@ def test_sliding_window_schedule_at_the_cell_shape():
     for plan in causal.values():
         assert not plan.static and len(plan.tiles) == 136
         assert max(map(len, plan.rows)) == 16 and plan.steps_skipped == 120
-        assert plan.steps_band == 0
+        assert plan.steps_band == 0 == plan.steps_triangle
 
 
 # what `block_schedule` returned before there was a band step (PR 47), a
-# digest of every field it had, forward (= dq) and dk/dv
+# digest of every field it had, and the plan's triangle steps, for the
+# forward, dq and dk/dv: the plans in LOOPS (S 8,192; dk/dv at S 4,096) are
+# those plans still; the unrolled ones run their diagonal tiles as triangle
+# steps since PR 51 and are pinned to that PR's
 _PLANS_BEFORE_THE_BAND_STEP = {
-    "causal-s2048": (2048, True, "4c4e522d37cca906", "ce93506bdc3f41ef"),
-    "causal-s4096": (4096, True, "9024c467dd946098", "26df8b9341770fef"),
-    "causal-s8192": (8192, True, "77112d431e8b43f9", "3f21b5c56cde178c"),
+    "causal-s2048": (2048, True,
+        ("632862a2f69ef061", 4),
+        ("632862a2f69ef061", 4),
+        ("2e432b05487077ab", 4)),
+    "causal-s4096": (4096, True,
+        ("b55b3d926bff0b31", 8),
+        ("b55b3d926bff0b31", 8),
+        ("26df8b9341770fef", 0)),
+    "causal-s8192": (8192, True, ("77112d431e8b43f9", 0),
+                     ("77112d431e8b43f9", 0), ("3f21b5c56cde178c", 0)),
     "block-diffusion-l2048": (4096, BlockDiffusion(2048, 4),
-                              "c68fce278d657c79", "6cc2327884bd7cd9"),
+        ("10908d9460d329ae", 8),
+        ("10908d9460d329ae", 8),
+        ("193573bc7a39e7ee", 8)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_PLANS_BEFORE_THE_BAND_STEP))
 def test_plans_without_a_band_are_what_they_were(case):
     """The plans of the other cells' calls (`CAUSAL` at S 2,048, 4,096 and
-    8,192, `BlockDiffusion(2048, 4)` over 2 x 2,048) hold no band step and
-    are, field for field, what the commit before the band step planned: the
+    8,192, `BlockDiffusion(2048, 4)` over 2 x 2,048) hold no band step: the
     rule's answers decide, and theirs fit no band (a causal group's runs
     all start at 0; a block-diffusion row's tiles are not one range, its
-    x_t key tiles already run their diagonal alone)."""
+    x_t key tiles already run their diagonal alone). A plan in a loop is,
+    field for field, what the commit before the band step planned; an
+    unrolled one differs from it by its triangle steps alone (PR 51)."""
     import hashlib
 
-    s, rule, fwd, dkv = _PLANS_BEFORE_THE_BAND_STEP[case]
+    s, rule, *pinned = _PLANS_BEFORE_THE_BAND_STEP[case]
     plans = block_schedule(s, s, 512, 512, rule)
-    assert plans["dq"] == plans["fwd"]
-    for plan, digest in ((plans["fwd"], fwd), (plans["dkv"], dkv)):
+    for kernel, (digest, triangles) in zip(("fwd", "dq", "dkv"), pinned):
+        plan = plans[kernel]
         assert plan.steps_band == 0
+        assert plan.steps_triangle == triangles
+        assert plan.static or not triangles
         assert not any(isinstance(t[4], Band) for t in plan.tiles)
         assert hashlib.sha256(repr((
             plan.width, plan.static, plan.tiles, plan.rows,
@@ -838,6 +933,72 @@ def test_flash_attention_under_the_sliding_window_rule(case):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(want), atol=2e-5)
 
 
+# (q heads, kv heads, rule, tile, s_q, s_k, triangle steps in the forward's
+# plan and in dk/dv's[, rotary channels]): the calls whose plans cut a tile
+# along its sub-tile diagonal, scaled down from the cells'
+_TRIANGLE_CALLS = {
+    "causal-s1024-tile512": (2, 2, True, 512, 1024, 1024, 2, 2),
+    "causal-s2048-tile512": (1, 1, True, 512, 2048, 2048, 4, 4),
+    "causal-s768-tile384-three-sub-tiles": (2, 1, True, 384, 768, 768, 2, 2),
+    "gqa-8-to-2": (8, 2, True, 256, 512, 512, 2, 2),
+    # keys wider than values: 128 + 64 channels, ONE rotary key head
+    "in-parts-128-and-64": (2, 2, True, 256, 512, 512, 2, 2, 64),
+    # both kinds of its triangles: x_0's diagonal tiles (k_blk <= q_blk) and
+    # the x_t -> x_0 ones (k_blk < q_blk), beside two DIAGONAL steps
+    "block-diffusion-both-kinds": (4, 1, BlockDiffusion(512, 4), 256, 1024,
+                                   1024, 4, 4),
+    # more keys than queries: a tile, a sub-tile or half a sub-tile apart the
+    # diagonal leaves the tiles it crosses one side of their sub-tile
+    # diagonal empty
+    "keys-512-ahead": (2, 1, True, 256, 512, 1024, 2, 2),
+    "keys-128-ahead": (2, 1, True, 256, 512, 640, 2, 2),
+    "keys-64-ahead": (2, 1, True, 256, 512, 576, 2, 2),
+    # the last block padded, queries and keys
+    "padded-last-block": (2, 1, True, 256, 900, 900, 4, 4),
+    # a window of two tiles: a row's trailing tile (a strict upper triangle)
+    # and its own, triangles of either hand in every kernel
+    "window-two-tiles-both-hands": (2, 1, SlidingWindow(512), 256, 1024,
+                                    1024, 6, 6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TRIANGLE_CALLS))
+def test_flash_attention_runs_a_cut_tile_on_its_kept_sub_tiles(case):
+    """Forward and the three gradients of the Pallas kernels (interpret
+    mode) where the plans hold `Triangle` steps, against
+    `_reference_attention`, which builds the DENSE mask."""
+    heads, kv_heads, rule, tile, s_q, s_k, fwd, dkv, *rope = \
+        _TRIANGLE_CALLS[case]
+    plans = block_schedule(s_q, s_k, _clamp_block(tile, s_q),
+                           _clamp_block(tile, s_k), rule)
+    assert [plans[name].steps_triangle for name in ("fwd", "dq", "dkv")] \
+        == [fwd, fwd, dkv]
+    assert all(plan.static for plan in plans.values())
+    d = 128 if rope else 32
+    q, k, v = _make_qkv(S=s_k, H=heads, kv_heads=kv_heads, D=d, seed=s_q)
+    parts = ()
+    if rope:
+        keys = jax.random.split(jax.random.PRNGKey(11), 2)
+        parts = (jax.random.normal(keys[0], (1, s_k, heads, rope[0])),
+                 jax.random.normal(keys[1], (1, s_k, 1, rope[0])))
+
+    def loss(q, k, v, *parts, **how):
+        rotary = dict(zip(("q_rope", "k_rope"), parts))
+        if rotary:
+            rotary["q_rope"] = rotary["q_rope"][:, :s_q]
+        out = flash_attention(q[:, :s_q], k, v, causal=rule, **rotary, **how)
+        return jnp.sum(out ** 2), out
+
+    wrt = tuple(range(3 + len(parts)))
+    (_, out), g1 = jax.value_and_grad(loss, argnums=wrt, has_aux=True)(
+        q, k, v, *parts, interpret=True, block_q=tile, block_k=tile)
+    (_, ref), g2 = jax.value_and_grad(loss, argnums=wrt, has_aux=True)(
+        q, k, v, *parts, use_pallas=False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
 def test_a_window_of_several_tiles_at_the_cell_shape():
     """train-smallthinker-1chip's two calls, S 16,384 in tiles of 512. The
     window layers (4,096 keys = 8 tiles): a row walks the window's trailing
@@ -853,7 +1014,8 @@ def test_a_window_of_several_tiles_at_the_cell_shape():
     for plans, steps, longest, over in ((window, 252, 9, 1.125),
                                         (causal, 528, 32, 1.031)):
         for plan in plans.values():
-            assert not plan.static and plan.steps_band == 0
+            assert not plan.static
+            assert plan.steps_band == 0 == plan.steps_triangle
             assert (len(plan.tiles), max(map(len, plan.rows))) \
                 == (steps, longest)
             assert (plan.steps_unmasked, plan.steps_masked) == (0, steps)
@@ -952,25 +1114,41 @@ def test_gqa_call_hands_the_kernels_k_and_v_at_the_kv_heads_count(rule):
 
 
 # sha256 of the call as traced (value and gradients, the three kernels'
-# bodies in it) at the commit before a KV head was shared (PR 48's): a call
-# whose K has the grid's heads, and a call in parts, whose one rotary key
-# was always read at head 0, trace to the same text
-_TRACED_BEFORE_KV_HEADS_WERE_SHARED = {
-    "mha-causal":
-        "8237da77fabdf3cf0d1bb2412f498021ddb902ac8dcc6329de3b6a2e924ecf99",
-    "mha-window-band":
-        "2c7d8fa75a720bdbc54cc4fc42c8034792345957ad52f2d48eac84793ad2da5b",
-    "mha-block-diffusion":
-        "8dfa7b3000014c1200f2383c66dd0d852007e5a84a12fc8c276c8559dce7f1cf",
-    "in-parts":
-        "ed4321487d2b2573e4046c6d050edb261e51faf26614010cfe15cb61ceb264bc",
+# bodies in it), a call whose K has the grid's heads and a call in parts,
+# whose one rotary key was always read at head 0. In tiles of 128, one
+# sub-tile, no plan has a triangle step, and the calls trace to the text of
+# the commit before a KV head was shared (PR 48's), which is PR 50's too;
+# in tiles of 256 each call's diagonal tiles are triangle steps since PR 51
+# (3 of their 4 sub-tiles) and the text is that PR's, pinned anew
+_TRACED = {
+    ("mha-causal", 128):
+        "2f81ed3e594cfca114fd5beea3ea75f2b0d3b9baac459527691cc87cccd298e6",
+    ("mha-window-band", 128):
+        "678c51e2ec7607ea3a2d3900eec3f30a62275af07b7f1f60aa05aa8865cac2aa",
+    ("mha-block-diffusion", 128):
+        "c3e3e19bba2a3cfb52021c6178a62c39f886c2cbc4fdc41833e01248f07274a5",
+    ("in-parts", 128):
+        "3200cb59f77ba6f1fe56bda16aae410ea6af478fb892cdbce1be9caa5eaba83c",
+    ("mha-causal", 256):
+        "f08638ea6d6d8edf8bca076ae0535ea4794caa27e8d9f2720b8d02604cc012f7",
+    ("mha-window-band", 256):
+        "7d6f0ca553e8a7c3228a88f0343c3ce56d3901e3b4ca0f9aef60e623c9504754",
+    ("mha-block-diffusion", 256):
+        "5db41ee75362228cd5b04ddc60d8f982cd7c953da35788317ea0a7309a3f2b7a",
+    ("in-parts", 256):
+        "dff156c5b3399d9aab1c2504fefb983807eab28f582d81c8ba8d36b02eec0dd2",
 }
 
 
-@pytest.mark.parametrize("case", sorted(_TRACED_BEFORE_KV_HEADS_WERE_SHARED))
-def test_calls_with_no_shared_kv_head_trace_to_what_they_were(case):
+@pytest.mark.parametrize("case, tile", sorted(_TRACED),
+                         ids=["-".join(map(str, key)) for key in
+                              sorted(_TRACED)])
+def test_calls_with_no_shared_kv_head_trace_to_what_they_were(case, tile):
     rule = {"mha-window-band": SlidingWindow(256),
             "mha-block-diffusion": BlockDiffusion(256, 4)}.get(case, True)
+    plans = block_schedule(512, 512, tile, tile, rule)
+    assert all(bool(plan.steps_triangle) == (tile == 256)
+               for plan in plans.values())
     shapes = [(2, 512, 4, 64)] * 3
     if case == "in-parts":
         shapes += [(2, 512, 4, 32), (2, 512, 1, 32)]
@@ -978,15 +1156,14 @@ def test_calls_with_no_shared_kv_head_trace_to_what_they_were(case):
     def call(q, k, v, *rope):
         parts = dict(zip(("q_rope", "k_rope"), rope))
         return flash_attention(q, k, v, causal=rule, use_pallas=True,
-                               block_q=256, block_k=256, **parts) \
+                               block_q=tile, block_k=tile, **parts) \
             .astype(jnp.float32).sum()
 
     traced = jax.make_jaxpr(jax.value_and_grad(
         call, argnums=tuple(range(len(shapes)))))(
             *[jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes])
     assert hashlib.sha256(re.sub(r" at 0x[0-9a-f]+", "", str(traced))
-                          .encode()).hexdigest() \
-        == _TRACED_BEFORE_KV_HEADS_WERE_SHARED[case]
+                          .encode()).hexdigest() == _TRACED[case, tile]
 
 
 @pytest.mark.parametrize("kv_heads", [2, 1],

@@ -173,6 +173,10 @@ lowered = jax.jit(flash_grads).lower(
     spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
     spec((4, 2048, 8, 128), bf16))
 out["flash_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+out["flash_s2048_vmem_limits"] = re.findall(
+    r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(flash_grads)(
+        spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
+        spec((4, 2048, 8, 128), bf16))))
 hlo = lowered.compile().as_text()
 out["flash_s2048"] = "compiled"
 out["flash_s2048_operands"] = flash_operands(hlo)
@@ -322,16 +326,25 @@ out["tp_all_to_alls"] = len(re.findall(r" all-to-all\(", hlo))
 # [4, 4096, 32, 128] over the concatenation [x_t ; x_0] of 2 x 2,048, 4 kv
 # heads, forward and backward
 rule = BlockDiffusion(2048, 4)
-lowered = jax.jit(lambda q, k, v: jax.grad(
+bd_grads = jax.grad(
     lambda q, k, v: flash_attention(q, k, v, use_pallas=True, mask=rule)
-    .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)).lower(
-        spec((4, 4096, 32, 128), bf16), spec((4, 4096, 4, 128), bf16),
-        spec((4, 4096, 4, 128), bf16))
+    .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+bd_shapes = (spec((4, 4096, 32, 128), bf16), spec((4, 4096, 4, 128), bf16),
+             spec((4, 4096, 4, 128), bf16))
+lowered = jax.jit(bd_grads).lower(*bd_shapes)
 out["flash_bd_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+out["flash_bd_vmem_limits"] = re.findall(
+    r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(bd_grads)(*bd_shapes)))
 out["flash_bd_plans"] = {
     name: [plan.static, plan.steps_unmasked, plan.steps_masked,
-           plan.steps_diagonal]
+           plan.steps_diagonal, plan.steps_triangle]
     for name, plan in block_schedule(4096, 4096, 512, 512, rule).items()}
+# the causal calls compiled above ([4, 32 / 8, 2048, 128], the call in parts
+# at [4, 32, 2048, 128 + 64]): their diagonal tiles as triangle steps
+out["flash_s2048_plans"] = {
+    name: [plan.static, plan.steps_unmasked, plan.steps_masked,
+           plan.steps_triangle]
+    for name, plan in block_schedule(2048, 2048, 512, 512, True).items()}
 out["flash_causal_dkv_static"] = {
     str(s): block_schedule(s, s, 512, 512, True)["dkv"].static
     for s in (2048, 3584, 4096, 8192)}
@@ -402,6 +415,9 @@ for cell_call, n_heads, window in (
     out[cell_call + "_band_steps"] = [
         plan.steps_band for plan in block_schedule(
             8192, 8192, 512, 512, window or True).values()]
+    out[cell_call + "_triangle_steps"] = [
+        plan.steps_triangle for plan in block_schedule(
+            8192, 8192, 512, 512, window or True).values()]
     try:
         laguna_hlo = jax.jit(laguna_call).lower(
             *laguna_shapes).compile().as_text()
@@ -436,10 +452,14 @@ for cell_call, window in (("smallthinker_window", SlidingWindow(4096)),
     long_shapes = (spec((1, 16384, 28, 128), bf16),
                    spec((1, 16384, 4, 128), bf16),
                    spec((1, 16384, 4, 128), bf16))
+    long_jaxpr = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(
+        long_call)(*long_shapes)))
+    out[cell_call + "_jaxpr"] = hashlib.sha256(long_jaxpr.encode()).hexdigest()
     out[cell_call + "_vmem_limits"] = re.findall(
-        r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(long_call)(*long_shapes)))
+        r"vmem_limit_bytes=(\d+)", long_jaxpr)
     out[cell_call + "_plans"] = [
-        [plan.static, len(plan.tiles), max(map(len, plan.rows))]
+        [plan.static, len(plan.tiles), max(map(len, plan.rows)),
+         plan.steps_triangle]
         for plan in block_schedule(16384, 16384, 512, 512,
                                    window or True).values()]
     try:
@@ -637,6 +657,24 @@ def compiled(tmp_path_factory):
         [os.environ.get("LIBTPU_INIT_ARGS", ""),
          "--xla_mosaic_dump_to=" + dump]).strip()))
     return out
+
+
+def test_flash_calls_with_triangle_steps_compile_for_v5e(compiled):
+    """The cells' unrolled causal and block-causal calls since PR 51 runs a
+    tile the rule cuts along its sub-tile diagonal in two halves, on 12 of
+    its 16 sub-tiles: causal `[4, 32, 2048, 128]` over 8 KV heads (4 of a kernel's 10
+    steps), the call in parts at `[4, 32, 2048, 128 + 64]` (the same plan)
+    and `BlockDiffusion(2048, 4)` at `[4, 32, 4096, 128]` over 4 KV heads
+    (8 of 24), all three kernels of each, under the default VMEM limit."""
+    assert compiled["flash_s2048_plans"] == {
+        name: [True, 6, 4, 4] for name in ("fwd", "dq", "dkv")}
+    assert compiled["flash_bd_plans"] == {
+        name: [True, 12, 12, 4, 8] for name in ("fwd", "dq", "dkv")}
+    for call in ("flash_s2048", "flash_mla", "flash_bd"):
+        assert compiled[call] == "compiled", call
+    assert len(compiled["mla_parts_calls"]) == 3
+    assert compiled["flash_s2048_vmem_limits"] == [] \
+        == compiled["flash_bd_vmem_limits"]
 
 
 def test_flash_fwd_bwd_compiles_for_v5e(compiled):
@@ -838,12 +876,14 @@ def test_flash_under_the_block_diffusion_rule_compiles_for_v5e(compiled):
     """train-sdar-1chip's call, q `[4, 4096, 32, 128]` over [x_t ; x_0] with
     4 kv heads under `BlockDiffusion(2048, 4)`: forward, dq and dk/dv (24
     steps a head in all: unrolled too since PR 38), each with its four x_t
-    diagonal tiles as diagonal steps, compile for the v5e as the three
+    diagonal tiles as diagonal steps and, since PR 51, the four x_0 and the
+    four x_t -> x_0 diagonal tiles as triangle steps (12 of their 16 sub-
+    tiles), compile for the v5e under the default VMEM limit as the three
     Pallas calls they were, and no [4096, 4096] score or mask array is in
     the compiled program."""
     assert compiled["flash_bd_custom_calls"] == 3
     assert compiled["flash_bd_plans"] == {
-        name: [True, 12, 12, 4] for name in ("fwd", "dq", "dkv")}
+        name: [True, 12, 12, 4, 8] for name in ("fwd", "dq", "dkv")}
     assert compiled["flash_bd"] == "compiled"
     assert compiled["flash_bd_dense"] == []
 
@@ -879,11 +919,18 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
     assert compiled["laguna_window_jaxpr"] != (   # PR 47's
         "0192ab54ecd8b9eece3b74fe696ad7f626f15efc50d82530140f6db30591e62a")
     # and since PR 50, which has a longer call state its VMEM limit, both
-    # trace to the text PR 49 left: at S 8,192 nothing is stated
+    # traced to the text PR 49 left: at S 8,192 nothing is stated. The full
+    # call, loops, still does; the window call's first row, its own tile
+    # alone, is a triangle step since PR 51 (dk/dv: the last row), the one
+    # branch of its two that is not the band's: PR 51's text
     assert compiled["laguna_full_jaxpr"] == (
         "dc1bde509d1bcbdffd1972a302135611f48b337c4a9784192d6e2610f0bb16f6")
-    assert compiled["laguna_window_jaxpr"] == (
+    assert compiled["laguna_window_jaxpr"] != (   # PR 49's
         "bb8f601aafbc03b1d2e47ad5becb97d305d31ef91a6140603b735510530a66e6")
+    assert compiled["laguna_window_jaxpr"] == (
+        "9a53f067d2f5c29ded6141f7308f6e78097b17c71ef90f7399fce5544f81ef3f")
+    assert compiled["laguna_window_triangle_steps"] == [1] * 3
+    assert compiled["laguna_full_triangle_steps"] == [0] * 3
     assert compiled["laguna_full_vmem_limits"] == []
     assert compiled["laguna_window_vmem_limits"] == []
     assert compiled["laguna_window_scoped"] == [True] * 3
@@ -905,10 +952,16 @@ def test_window_and_full_flash_calls_at_s16384_compile_for_v5e(compiled):
     one ("Scoped allocation with size 16.75M and limit 16.00M") until each
     stated its own limit, its blocks twice plus 16 MiB (`_vmem_limit`): 32.5
     to 33.3 MiB of the v5e's 128."""
-    for name, steps, longest in (("smallthinker_window", 252, 9),
-                                 ("smallthinker_full", 528, 32)):
+    for name, steps, longest, digest in (
+            ("smallthinker_window", 252, 9, "d3732652207dac49e97f774bd6afe134"
+             "b1f7e6e46443b8102bb1a7b6cdbde59f"),
+            ("smallthinker_full", 528, 32, "5a39bff16b81f9759f3dc161688c75fc"
+             "e7b3ecb087b456794d390d078b58a956")):
         assert compiled[name] == "compiled", compiled[name]
-        assert compiled[name + "_plans"] == [[False, steps, longest]] * 3
+        # loops: no triangle step, and the call traces to the text it had
+        # before there was one (PR 50's)
+        assert compiled[name + "_plans"] == [[False, steps, longest, 0]] * 3
+        assert compiled[name + "_jaxpr"] == digest
         limits = sorted(map(int, compiled[name + "_vmem_limits"]))
         assert len(limits) == 3   # forward, dq, dk/dv
         assert 32 * 2**20 < limits[0] <= limits[-1] < 34 * 2**20, limits
