@@ -31,8 +31,33 @@ tree to that).
 `ray_tpu.shutdown()`: nothing here is reset by it); `delta` subtracts two
 snapshots; `merge` grafts a snapshot taken in another process (rank 0 of a
 gang hands its start-up spans back on a return value the driver waits for
-anyway, and on `finish()` what it did since its session began: spans and
-counters, never the ring) under the span that is open on the calling thread.
+anyway, and on `finish()` what it did since its session began: spans,
+counters and stall records, never the ring) under the span that is open on
+the calling thread.
+
+A step function keeps a record of every step (`StepCadence`, one per
+`train.make_train_step`): after each dispatch has returned it hands the
+dispatch's two ends to `mark`, which reads (`MarkReader`), where the work
+happens and
+without ever waiting for the device: `now()`, the calling thread's CPU time
+(`time.thread_time_ns`), the first line of `/proc/stat` (the HOST's steal,
+iowait, idle and busy jiffies), `getrusage(RUSAGE_SELF)` (involuntary
+context switches, major faults), the listeners' running totals of `host.gc`
+and `jit.*`, and whether a `jax.profiler` trace is running (jax's own state,
+through `sys.modules`). A source that is missing reads None. Two successive
+marks give `train.step.interval` (start of one dispatch to the start of the
+next, with `step`, `dispatch_s`, `on_cpu_s`, `off_cpu_s` in the ring),
+`train.step.off_cpu` (the interval less the thread's CPU: it waited for the
+device's result, a lock, the GIL, or the OS); the first
+interval holds the step's compile and is left out, one across which a trace
+started or stopped goes under `train.step.profiler_toggle` alone. An interval
+longer than the running median of the last 32 by more than 0.1 s AND 20% is
+a stall: its lost time is `train.step.stall`, and one record of it (what the
+OS, the collector and jax did meanwhile, which spans of OTHER threads,
+closed or still open, ran beside it, and for how long no other thread was
+heard, which tells a process that stood still from a step that waited alone:
+the ring's reader) is kept in `snapshot()["stalls"]`, the eight longest, and
+logged as one warning line.
 
 Beside the spans, for a repeated device program (`DeviceStepProfiler`: the
 engine's decode wave) the per-step phase records behind `ray-tpu profile
@@ -57,12 +82,16 @@ from __future__ import annotations
 
 import bisect
 import gc
+import logging
 import os
+import statistics
 import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
+
+logger = logging.getLogger(__name__)
 
 PHASES = ("input_wait", "h2d", "compile", "device_execute", "reply")
 
@@ -74,7 +103,8 @@ TRACE_PREFIX = "rt."
 _EPOCH_NS = time.time_ns() - time.perf_counter_ns()
 
 _span_lock = threading.Lock()   # the list of per-thread tables, not the path
-# (thread, spans, counters) of every live thread that has timed something
+# (thread, spans, counters, the thread's own attributes of `_local`: its open
+# span is `["top"]`) of every live thread that has timed something
 _tables: List[tuple] = []
 # what threads that have ended left behind: [{spans}, {counters}]
 _retired: List[dict] = [{}, {}]
@@ -113,7 +143,7 @@ class _Table(threading.local):
             for entry in [e for e in _tables if not e[0].is_alive()]:
                 _tables.remove(entry)
                 _fold(entry[1], entry[2], *_retired)
-            _tables.append((thread, self.spans, self.counters))
+            _tables.append((thread, self.spans, self.counters, vars(self)))
 
 
 _local = _Table()
@@ -154,6 +184,11 @@ class _Span:
     @property
     def seconds(self) -> float:
         return self.ns * 1e-9
+
+    @property
+    def ends(self) -> tuple:
+        """(start, end) on `now()`'s clock, once the span has ended."""
+        return self._t0, self._t0 + self.ns
 
     def __enter__(self):
         table = _local
@@ -217,15 +252,17 @@ def count(name: str, n: int = 1) -> None:
 
 def snapshot(recent: int = 0) -> Dict[str, Any]:
     """This process's aggregate, over every thread that timed something:
-    `spans` {name: {count, total_s, max_s, self_s}}, `counters` {name: n}
-    and, with `recent`, the ring's newest records oldest first (`start` and
-    `end` in seconds since the epoch)."""
+    `spans` {name: {count, total_s, max_s, self_s}}, `counters` {name: n},
+    `stalls` (the eight longest stall records, see `StepCadence`) and, with
+    `recent`, the ring's newest records oldest first (`start` and `end` in
+    seconds since the epoch)."""
     spans: Dict[str, list] = {}
     counters: Dict[str, int] = {}
     with _span_lock:
         _fold(*_retired, spans, counters)
         tables = list(_tables)
-    for _thread, s, c in tables:
+        stalls = [dict(s) for s in _stalls]
+    for _thread, s, c, _own in tables:
         _fold(s, c, spans, counters)  # copies each table in one C call
     _fold(_listened, {}, spans, counters)
     out: Dict[str, Any] = {
@@ -234,6 +271,7 @@ def snapshot(recent: int = 0) -> Dict[str, Any]:
                          "max_s": longest * 1e-9, "self_s": own * 1e-9}
                   for name, (n, total, longest, own) in spans.items()},
         "counters": counters,
+        "stalls": stalls,
     }
     if recent > 0:
         out["recent"] = [
@@ -246,7 +284,8 @@ def snapshot(recent: int = 0) -> Dict[str, Any]:
 
 def delta(after: Dict[str, Any], before: Dict[str, Any]) -> Dict[str, Any]:
     """What happened between two snapshots of ONE process (`max_s` is the
-    later snapshot's: a maximum cannot be subtracted)."""
+    later snapshot's: a maximum cannot be subtracted; `stalls` those the
+    earlier one does not hold)."""
     spans = {}
     for name, a in after["spans"].items():
         b = before["spans"].get(name)
@@ -263,28 +302,335 @@ def delta(after: Dict[str, Any], before: Dict[str, Any]) -> Dict[str, Any]:
     counters = {name: n - before["counters"].get(name, 0)
                 for name, n in after["counters"].items()
                 if n != before["counters"].get(name)}
-    return {"pid": after["pid"], "spans": spans, "counters": counters}
+    # a stall record is never edited, and one that left the eight longest
+    # does not come back: what the earlier snapshot lacks began after it
+    seen = before.get("stalls") or ()
+    stalls = [s for s in after.get("stalls") or () if s not in seen]
+    return {"pid": after["pid"], "spans": spans, "counters": counters,
+            "stalls": stalls}
 
 
 def merge(other: Optional[Dict[str, Any]]) -> None:
     """Graft another process's snapshot (or delta) into this one under the
     same names, as children of the span open on the calling thread: that
     span's self time then leaves out what the other process accounted for
-    (the sum of its self times, which is what its spans cover). What came
-    over the wire may be None (the other side failed to build it) or of
-    another shape: then nothing is merged, and nothing raised."""
+    (the sum of its self times, which is what its spans cover). Its stall
+    records join this process's (the eight longest stay), one warning line
+    each: the process that waited for a gang says which step froze, and
+    beside what. What came over the wire may be None (the other side failed
+    to build it) or of another shape: then nothing is merged, and nothing
+    raised."""
     try:
         spans = {str(name): [int(a["count"]), int(a["total_s"] * 1e9),
                              int(a["max_s"] * 1e9), int(a["self_s"] * 1e9)]
                  for name, a in (other.get("spans") or {}).items()}
         counters = {str(name): n + 0
                     for name, n in (other.get("counters") or {}).items()}
+        stalls = [dict(s, interval_s=s["interval_s"] + 0.0)
+                  for s in other.get("stalls") or ()]
+        lines = [stall_line(s) for s in stalls]
     except Exception:  # noqa: BLE001 — not a snapshot: all or nothing
         return
     table = _local
     _fold(spans, counters, table.spans, table.counters)
     if table.top is not None:
         table.top._child_ns += sum(a[3] for a in spans.values())
+    for stall, line in zip(stalls, lines):
+        _keep_stall(stall)
+        logger.warning("%s", line)
+
+
+# -- a step function's cadence, and the stalls it names ----------------------
+
+# An interval is a stall when it is longer than the running median by more
+# than BOTH (PERF.md §6, PR 52: a share cell's steps swing by tens of ms with
+# the capacity that ran, a frozen process loses 0.25 s or more).
+STALL_OVER_S = 0.1
+STALL_OVER_SHARE = 0.2
+CADENCE_KEPT = 32        # intervals the running median is over
+CADENCE_BEFORE_STALLS = 8
+STALLS_KEPT = 8          # the longest, a process
+_OVERLAPPING_KEPT = 8
+_STALL_LOG_EVERY_NS = 10 * 10**9
+try:
+    _JIFFY_S = 1.0 / os.sysconf("SC_CLK_TCK")
+except (AttributeError, ValueError, OSError):
+    _JIFFY_S = 0.01
+_JIT_NAMES = ("jit.trace", "jit.lower", "jit.compile", "jit.cache_load")
+
+_stalls: List[dict] = []   # under `_span_lock`, longest first
+
+
+class Mark(NamedTuple):
+    """What `StepCadence.mark` reads; every source but the two clocks may
+    be missing (None)."""
+    now: int                       # `now()`
+    cpu: int                       # `time.thread_time_ns()`: this thread's
+    host: Optional[tuple]          # jiffies (steal, iowait, idle, busy)
+    rusage: Optional[tuple]        # (ru_nivcsw, ru_majflt) of the process
+    gc: Optional[tuple]            # (count, ns) of `host.gc`
+    jit: Optional[tuple]           # (programs at the backend, jit self ns)
+    tracing: Optional[bool]        # a `jax.profiler` trace is running
+
+
+def host_jiffies(path: str = "/proc/stat") -> Optional[tuple]:
+    """(steal, iowait, idle, busy) of all the host's CPUs together, in
+    jiffies since boot, from the first line of `/proc/stat`: `cpu  user nice
+    system idle iowait irq softirq steal ...`. None where there is no such
+    file or line, or where the line is all zeros: a kernel that keeps no
+    such count (gVisor's, which every TPU machine of PERF.md's runs has)."""
+    try:
+        with open(path, "rb") as f:
+            fields = f.readline().split()
+        if fields[0] != b"cpu":
+            return None
+        user, nice, system, idle, iowait, irq, softirq, steal = map(
+            int, fields[1:9])
+    except (OSError, ValueError, IndexError):
+        return None
+    busy = user + nice + system + irq + softirq
+    return (steal, iowait, idle, busy) if idle or busy else None
+
+
+def _rusage() -> Optional[tuple]:
+    """(ru_nivcsw, ru_majflt) of the process; None where there is no
+    `getrusage`, or where it has counted no switch and no fault of any kind
+    by the time a step has run: a kernel that keeps no such count (gVisor's
+    again), as the all-zero `/proc/stat`."""
+    try:
+        import resource
+
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+    except (ImportError, OSError, ValueError):
+        return None
+    if not (usage.ru_nvcsw or usage.ru_nivcsw or usage.ru_minflt
+            or usage.ru_majflt):
+        return None
+    return usage.ru_nivcsw, usage.ru_majflt
+
+
+def _profiler_running() -> Optional[bool]:
+    """jax's own record of a running trace; None (not known) where jax is
+    not in the process or keeps it elsewhere. Never the import."""
+    state = getattr(sys.modules.get("jax._src.profiler"),
+                    "_profile_state", None)
+    try:
+        return state.profile_session is not None
+    except AttributeError:
+        return None
+
+
+class MarkReader:
+    """The sources of a mark, read on the calling thread: two clocks, one
+    line of `/proc/stat`, one `getrusage`, a few dict reads; nothing here
+    waits for the device or takes a lock. What the OS is asked is the
+    mark's cost (PERF.md §6, PR 52: a system call is 5-70 us under gVisor),
+    so a source that is missing when first asked is not asked again."""
+
+    def __init__(self):
+        self._host: Optional[Callable] = host_jiffies
+        self._rusage: Optional[Callable] = _rusage
+
+    def __call__(self) -> Mark:
+        host = self._host and self._host()
+        if host is None:
+            self._host = None
+        rusage = self._rusage and self._rusage()
+        if rusage is None:
+            self._rusage = None
+        collections = _listened.get("host.gc")
+        jit = None
+        if _JIT_NAMES[0] in _listened:   # the listeners are installed
+            mine = _local.spans        # a jit's events fire on its caller
+            found = [mine[name] for name in _JIT_NAMES if name in mine]
+            compiled = mine.get("jit.compile")
+            jit = (compiled[0] if compiled else 0, sum(a[3] for a in found))
+        return Mark(now(), time.thread_time_ns(), host, rusage,
+                    collections and (collections[0], collections[1]), jit,
+                    _profiler_running())
+
+
+def _delta(after: Optional[tuple], before: Optional[tuple], i: int,
+           scale: float = 1):
+    """Field `i` of a source between two marks; None where either lacks it."""
+    if after is None or before is None:
+        return None
+    return (after[i] - before[i]) * scale
+
+
+def _overlapping(start_ns: int, end_ns: int) -> tuple:
+    """What OTHER threads' spans covered of [start, end): the ring's records
+    and the spans still open, by name and thread, the largest first; and the
+    longest stretch of it, in ns, in which no other thread started or ended
+    a span (the background threads wake every 0.5-1 s: where they were
+    silent throughout, the whole process stood still, not the step alone)."""
+    me = _local.thread
+    found: Dict[tuple, list] = {}   # (name, thread, open) -> [ns, count]
+    heard = [start_ns, end_ns]      # when another thread read the clock
+
+    def add(name, thread, t0, t1, still_open):
+        ns = min(t1, end_ns) - max(t0, start_ns)
+        if ns > 0 and thread != me:
+            a = found.setdefault((name, thread, still_open), [0, 0])
+            a[0] += ns
+            a[1] += 1
+            heard.extend(t for t in (t0, t1) if start_ns < t < end_ns)
+
+    for name, t0, t1, _parent, _attrs, thread in list(_ring):
+        add(name, thread, t0, t1, False)
+    with _span_lock:
+        tables = list(_tables)
+    for thread, _spans, _counters, own in tables:
+        sp = own.get("top")
+        while sp is not None:   # the open span and those around it
+            # one that is being entered this instant has no start yet
+            add(sp.name, thread.name, getattr(sp, "_t0", end_ns), end_ns,
+                True)
+            sp = sp._parent
+    heard.sort()
+    silent = max(b - a for a, b in zip(heard, heard[1:]))
+    largest = sorted(found.items(), key=lambda kv: -kv[1][0])
+    return [dict({"name": name, "thread": thread, "overlap_s": ns * 1e-9,
+                  "count": n}, **({"open": True} if still_open else {}))
+            for (name, thread, still_open), (ns, n)
+            in largest[:_OVERLAPPING_KEPT]], silent
+
+
+def _keep_stall(stall: dict) -> None:
+    with _span_lock:
+        _stalls.append(stall)
+        _stalls.sort(key=lambda s: -s["interval_s"])
+        del _stalls[STALLS_KEPT:]
+
+
+def stall_line(stall: dict) -> str:
+    """One stall record as one line of a log."""
+    def s(key, form="%.3f"):
+        value = stall.get(key)
+        return "?" if value is None else form % value
+
+    beside = ", ".join(
+        "%s@%s %.3f s x %d%s" % (o["name"], o["thread"], o["overlap_s"],
+                                 o.get("count", 1),
+                                 " (open)" if o.get("open") else "")
+        for o in stall.get("overlapping") or ()) or "no span of another thread"
+    return (
+        f"train step {stall.get('step')} of pid {stall.get('pid')} stalled: "
+        f"{s('interval_s')} s for a median of {s('median_s')} "
+        f"(dispatch {s('dispatch_s')}; over {s('marks_s')} s: on the CPU "
+        f"{s('on_cpu_s')}, off it {s('off_cpu_s')}; host steal "
+        f"{s('steal_s')} s, iowait {s('iowait_s')} s, busy {s('host_busy_share', '%.0f')}%; "
+        f"{s('nivcsw', '%d')} involuntary switches, {s('majflt', '%d')} "
+        f"major faults; gc {s('gc_s')} s x {s('gc_count', '%d')}, jit "
+        f"{s('jit_s')} s x {s('compiles', '%d')}; no other thread heard for "
+        f"{s('others_silent_s')} s of it) beside {beside}")
+
+
+class StepCadence:
+    """The record a step function keeps of every step; see the module's
+    docstring. `read` is what a mark reads (a `MarkReader` of its own; a
+    test hands in numbers). One per step function, called by the thread
+    that steps."""
+
+    def __init__(self, read: Optional[Callable[[], Mark]] = None):
+        self._read = read or MarkReader()
+        self._last: Optional[tuple] = None    # (start, end, Mark)
+        self._before: Optional[Mark] = None   # the mark before that one
+        self._step = 0                        # dispatches marked
+        self._kept: deque = deque(maxlen=CADENCE_KEPT)
+        self._logged_ns: Optional[int] = None
+
+    def mark(self, start_ns: int, end_ns: int) -> None:
+        """After a dispatch that began at `start_ns` and returned at
+        `end_ns` (`now()`'s clock): read the sources, and account for the
+        interval that this dispatch's start closed."""
+        m = self._read()
+        last, self._last = self._last, (start_ns, end_ns, m)
+        self._step += 1
+        if last is None:
+            # watched from here on: "none seen" reads 0, not "no such span"
+            watched = ["train.step.stall"]
+            if m.tracing is not None:
+                watched.append("train.step.profiler_toggle")
+            for name in watched:
+                _local.spans.setdefault(name, [0, 0, 0, 0])
+            return
+        start0, end0, m0 = last
+        before, self._before = self._before, m0
+        if self._step == 2:
+            # the first interval: the step's trace, lowering and compile or
+            # load, which `train.step.dispatch`'s `max_s` has. Kept for the
+            # median, which one long interval among eight does not move.
+            self._kept.append(start_ns - start0)
+            return
+        step = self._step - 1   # the call whose start opened the interval
+        if m.tracing != m0.tracing and None not in (m.tracing, m0.tracing):
+            record("train.step.profiler_toggle", start0, start_ns, step=step)
+            return
+        # The thread's CPU between the two marks, which closed the
+        # dispatches at the interval's two ends: the interval's own as far
+        # as one dispatch is like the next.
+        interval = start_ns - start0
+        off_cpu = max(0, interval - (m.cpu - m0.cpu))
+        record("train.step.interval", start0, start_ns, step=step,
+               dispatch_s=(end0 - start0) * 1e-9,
+               on_cpu_s=(m.cpu - m0.cpu) * 1e-9, off_cpu_s=off_cpu * 1e-9)
+        record("train.step.off_cpu", 0, off_cpu, ring=False)
+        kept = self._kept
+        if len(kept) >= CADENCE_BEFORE_STALLS:
+            median = statistics.median(kept)
+            over = interval - median
+            if over > STALL_OVER_S * 1e9 and over > STALL_OVER_SHARE * median:
+                # the opening dispatch was itself long (a recompile, a
+                # freeze inside it): what happened meanwhile lies before
+                # the mark that closed it, so read from the mark before
+                long_dispatch = end0 - start0 > STALL_OVER_S * 1e9
+                try:
+                    self._stalled(step, start0, end0, start_ns, median,
+                                  before if long_dispatch and before else m0,
+                                  m)
+                except Exception:  # noqa: BLE001 — the step goes on
+                    logger.exception("no record of the stall at step %d",
+                                     step)
+        kept.append(interval)
+
+    def _stalled(self, step: int, start0: int, end0: int, start_ns: int,
+                 median: float, m0: Mark, m: Mark) -> None:
+        """Off the path of a healthy step: the one record of a stall.
+        `marks_s` is the time between the two marks that every field but
+        the interval's own is over."""
+        interval = start_ns - start0
+        record("train.step.stall", start_ns - int(interval - median),
+               start_ns, step=step)
+        on_cpu = m.cpu - m0.cpu
+        jiffies = None if None in (m.host, m0.host) else sum(m.host) - sum(
+            m0.host)
+        overlapping, silent = _overlapping(start0, start_ns)
+        stall = {
+            "pid": os.getpid(), "step": step,
+            "start": (start0 + _EPOCH_NS) * 1e-9,
+            "interval_s": interval * 1e-9, "median_s": median * 1e-9,
+            "dispatch_s": (end0 - start0) * 1e-9,
+            "marks_s": (m.now - m0.now) * 1e-9,
+            "on_cpu_s": on_cpu * 1e-9,
+            "off_cpu_s": max(0, m.now - m0.now - on_cpu) * 1e-9,
+            "steal_s": _delta(m.host, m0.host, 0, _JIFFY_S),
+            "iowait_s": _delta(m.host, m0.host, 1, _JIFFY_S),
+            "host_busy_share": 100.0 * _delta(m.host, m0.host, 3) / jiffies
+            if jiffies else None,
+            "nivcsw": _delta(m.rusage, m0.rusage, 0),
+            "majflt": _delta(m.rusage, m0.rusage, 1),
+            "gc_count": _delta(m.gc, m0.gc, 0),
+            "gc_s": _delta(m.gc, m0.gc, 1, 1e-9),
+            "compiles": _delta(m.jit, m0.jit, 0),
+            "jit_s": _delta(m.jit, m0.jit, 1, 1e-9),
+            "others_silent_s": silent * 1e-9, "overlapping": overlapping,
+        }
+        _keep_stall(stall)
+        if self._logged_ns is None or \
+                m.now - self._logged_ns >= _STALL_LOG_EVERY_NS:
+            self._logged_ns = m.now
+            logger.warning("%s", stall_line(stall))
 
 
 # -- compile telemetry (jax.monitoring backend_compile listener) ------------

@@ -227,7 +227,8 @@ class BackendExecutor:
         timed and counted since the session began; rank 0's is merged into
         this process's aggregate (under the `train.fit` span open here), as
         `backend._round` does with the start-up spans: every rank's would
-        multiply each total by the world."""
+        multiply each total by the world. Its stall records come with it,
+        and `merge` logs one line each: which step froze, and beside what."""
         if self.worker_group is not None:
             try:
                 left = ray_tpu.get([

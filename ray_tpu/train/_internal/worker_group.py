@@ -114,9 +114,9 @@ class TrainWorker:
 
     def finish(self, timeout: float = 30.0) -> Optional[dict]:
         """End the session; returns what this process timed and counted
-        since `init_session` (`device_profiler.delta`: `spans` and
-        `counters`, plain dicts of numbers, no ring), or None where that
-        could not be had."""
+        since `init_session` (`device_profiler.delta`: `spans`, `counters`
+        and the `stalls` its step functions recorded, plain dicts of numbers
+        and short strings, no ring), or None where that could not be had."""
         if self._train_thread is not None:
             self._train_thread.join(timeout)
         from ray_tpu.train._internal import session as session_mod

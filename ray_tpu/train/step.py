@@ -1,11 +1,14 @@
 """Sharded training-step builder: params + optimizer over a mesh, one jit.
 
-The per-worker inner loop of JaxTrainer (SURVEY §7: "train loop is a jax.jit
-step with NamedSharding over the mesh"): build shardings from the model's
-logical axes, init params directly into sharded buffers (jit with
-out_shardings so no host-side full copy ever exists), and compile a
-donated-buffer train step. Optimizer state inherits parameter shardings
-(ZeRO-style: optimizer shards wherever params shard).
+The per-worker inner loop of JaxTrainer (SURVEY §7): shardings from the
+model's logical axes, params initialised directly into sharded buffers (jit
+with out_shardings: no host-side full copy), and a donated-buffer train step;
+optimizer state inherits the parameter shardings (ZeRO-style).
+
+The step keeps a record of every step (`device_profiler.StepCadence`): once
+the dispatch has returned, a mark reads two clocks, `/proc/stat`'s first line,
+`getrusage` and the gc / jit listeners' totals, and never waits for the
+device; a step far over the median names what ran beside it (a stall record).
 """
 
 from __future__ import annotations
@@ -16,12 +19,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu._private.device_profiler import span
+from ray_tpu._private.device_profiler import StepCadence, span
 from ray_tpu.parallel.sharding import (
-    LogicalAxisRules,
-    logical_sharding,
-    param_shardings,
-)
+    LogicalAxisRules, logical_sharding, param_shardings)
 
 
 @dataclasses.dataclass
@@ -148,9 +148,11 @@ def make_train_step(
 
     def wrapped(state: TrainState, batch) -> Tuple[TrainState, Dict[str, Any]]:
         # host time of the dispatch (jit returns before the device ends)
-        with span("train.step.dispatch"):
+        with span("train.step.dispatch") as dispatch:
             out, metrics = jitted(_as_dict(state), batch)
+        cadence.mark(*dispatch.ends)   # while the device runs the step
         return TrainState(**out), metrics
 
+    cadence = StepCadence()   # below `wrapped`: the lines above stay put
     wrapped.lower = lambda state, batch: jitted.lower(_as_dict(state), batch)
     return wrapped

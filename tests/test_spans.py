@@ -343,7 +343,10 @@ def test_fit_brings_the_gang_workers_record_home(
     for name in ("jit.trace", "jit.lower", "jit.compile"):
         assert spans[name]["count"] >= 2, name   # the init and the step
         assert 0 < spans[name]["self_s"] <= spans[name]["total_s"] + 1e-9
-    first = spans["train.step.dispatch"]["max_s"]
+    # (`max_s` is the later snapshot's: a longer step of another test of this
+    # process would stand there; this fit's two dispatches' sum bounds it)
+    first = min(spans["train.step.dispatch"]["max_s"],
+                spans["train.step.dispatch"]["total_s"])
     # ... and the spans open around those jits leave them out of their own
     # time: what they do not count as theirs is jit self time (an identity
     # of the accounting, whatever the host's load does to the first call)
