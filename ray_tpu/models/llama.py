@@ -474,34 +474,104 @@ def forward(params, tokens, config: LlamaConfig, mesh=None,
 def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256,
                denominator=None):
     """Cross-entropy without materializing full [B,S,V] fp32 logits: the
-    sequence is scanned in chunks and each chunk's logits are rematerialized
-    in the backward pass. `mask` [B,S] weights each position's term (0/1, or
-    any float32 weight); the sum is divided by the mask's sum, or by
-    `denominator` when the weights are no count."""
-    b, s, d = hidden.shape
-    n = s // chunk
-    rem = s - n * chunk
+    sequence is scanned in chunks of `chunk` positions (what is left over
+    takes the same body after the scan). `mask` [B,S] weights each
+    position's term (0/1, or any float32 weight); the sum is divided by the
+    mask's sum, or by `denominator` when the weights are no count.
 
-    def body(carry, xs):
-        h_ck, t_ck, m_ck = xs
-        logits = (h_ck @ lm_head).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, t_ck[..., None], axis=-1)[..., 0]
-        return carry + jnp.sum(nll * m_ck), None
+    Differentiated, a chunk's logits are formed ONCE: the forward pass
+    takes, beside each chunk's loss, the gradient of the whole loss by that
+    chunk's logits, (softmax - onehot) * weight / denominator in float32
+    rounded to the logits' dtype, and multiplies it out while the logits are
+    there. So the forward holds d loss / d hidden `[B, S, D]` and
+    d loss / d lm_head `[D, V]` for a cotangent of 1, and the backward pass
+    only scales the two by the cotangent it is given (in float32, rounded
+    once). d lm_head accumulates over the chunks in `lm_head`'s dtype, as
+    the transposed scan's carry did: in float32 every chunk would read and
+    write a second `[D, V]` array twice as wide. `targets`, `mask` and
+    `denominator` are data and get no cotangent.
 
+    Not differentiated (an evaluation, a reference check), only the loss is
+    formed. Counted as traced: `ce.chunks`, and of them `ce.chunks_fused`,
+    the chunks whose gradient is formed with their logits."""
+    b, s, _ = hidden.shape
     if mask is None:
         mask = jnp.ones((b, s), jnp.float32)
-    h_main = hidden[:, :n * chunk].reshape(b, n, chunk, d).transpose(1, 0, 2, 3)
-    t_main = targets[:, :n * chunk].reshape(b, n, chunk).transpose(1, 0, 2)
-    m_main = mask[:, :n * chunk].reshape(b, n, chunk).transpose(1, 0, 2)
-    total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0),
-                            (h_main, t_main, m_main))
-    if rem:
-        total, _ = body(total, (hidden[:, n * chunk:], targets[:, n * chunk:],
-                                mask[:, n * chunk:]))
-    if denominator is not None:
-        return total / denominator
-    return total / jnp.maximum(jnp.sum(mask), 1.0)
+    if denominator is None:
+        denominator = jnp.maximum(jnp.sum(mask), 1.0)
+    return _chunked_ce(hidden, lm_head, targets, mask,
+                       jnp.asarray(denominator, jnp.float32), chunk)
+
+
+def _ce_chunks(chunk, hidden, targets, mask, fused):
+    """([n, B, chunk, ...] stacks of the whole chunks, the remainder's
+    [B, rem, ...] or None where the chunk divides S) of hidden, targets and
+    mask; and the chunks counted."""
+    b, s, _ = hidden.shape
+    n = s // chunk
+    main = tuple(
+        jnp.moveaxis(a[:, :n * chunk].reshape(b, n, chunk, *a.shape[2:]), 1, 0)
+        for a in (hidden, targets, mask))
+    rest = tuple(a[:, n * chunk:] for a in (hidden, targets, mask)) \
+        if s > n * chunk else None
+    chunks = n + (rest is not None)
+    device_profiler.count("ce.chunks", chunks)
+    device_profiler.count("ce.chunks_fused", chunks * fused)
+    return main, rest
+
+
+def _ce_chunk(lm_head, h_ck, t_ck, m_ck):
+    """A chunk's float32 log-probabilities [B, chunk, V], where its targets
+    stand in them, and its weighted sum of -log p(target). The target's term
+    is picked by comparison, not gathered: a gather has the compiler write
+    the float32 [B, chunk, V] array to HBM to read one element a row."""
+    logits = (h_ck @ lm_head).astype(jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    hit = t_ck[..., None] == jnp.arange(logp.shape[-1])
+    nll = -jnp.sum(jnp.where(hit, logp, 0.0), axis=-1)
+    return logp, hit, jnp.sum(nll * m_ck)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunked_ce(hidden, lm_head, targets, mask, denominator, chunk):
+    def body(total, xs):
+        return total + _ce_chunk(lm_head, *xs)[2], None
+
+    main, rest = _ce_chunks(chunk, hidden, targets, mask, fused=False)
+    total, _ = jax.lax.scan(body, jnp.float32(0.0), main)
+    if rest is not None:
+        total, _ = body(total, rest)
+    return total / denominator
+
+
+def _chunked_ce_fwd(hidden, lm_head, targets, mask, denominator, chunk):
+    def body(carry, xs):
+        total, dw = carry
+        h_ck, t_ck, m_ck = xs
+        logp, hit, nll = _ce_chunk(lm_head, h_ck, t_ck, m_ck)
+        dlogits = ((jnp.exp(logp) - hit) * (m_ck / denominator)[..., None]
+                   ).astype(jnp.result_type(h_ck, lm_head))
+        dh_ck = jnp.einsum("bcv,dv->bcd", dlogits, lm_head)
+        dw = dw + jnp.einsum("bcd,bcv->dv", h_ck, dlogits).astype(dw.dtype)
+        return (total + nll, dw), dh_ck.astype(h_ck.dtype)
+
+    main, rest = _ce_chunks(chunk, hidden, targets, mask, fused=True)
+    (total, dw), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.zeros_like(lm_head)), main)
+    b, _, d = hidden.shape
+    dh = jnp.moveaxis(dh, 0, 1).reshape(b, -1, d)
+    if rest is not None:
+        (total, dw), dh_rest = body((total, dw), rest)
+        dh = jnp.concatenate([dh, dh_rest], axis=1)
+    return total / denominator, (dh, dw)
+
+
+def _chunked_ce_bwd(chunk, held, g):
+    return tuple((g * x.astype(jnp.float32)).astype(x.dtype)
+                 for x in held) + (None, None, None)
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 
 
 def _cached_attention(q, k_cache, v_cache, lengths, config: LlamaConfig):
@@ -687,7 +757,8 @@ def loss_fn(params, batch, config: LlamaConfig, mesh=None,
     """Next-token cross-entropy. batch: {"tokens": [B, S]} (targets are the
     shifted tokens) or explicit {"inputs", "targets", "mask"}.
     With config.loss_chunk_size > 0 the CE is computed chunk-by-chunk over
-    the sequence (see chunked_ce) so full-vocab logits never materialize."""
+    the sequence (see chunked_ce) so full-vocab logits never materialize,
+    and a chunk's logits are formed once: its gradient in the same pass."""
     if "inputs" in batch:
         inputs, targets = batch["inputs"], batch["targets"]
         mask = batch.get("mask")

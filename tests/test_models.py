@@ -171,26 +171,98 @@ def test_vit_trains_on_mesh():
     assert np.isfinite(float(m["loss"]))
 
 
-def test_llama_chunked_ce_matches_plain():
-    """chunked_ce must equal the full-logits CE exactly (incl. masks and a
-    sequence length not divisible by the chunk)."""
+def _plain_ce(hidden, lm_head, targets, mask=None, denominator=None):
+    """The full-logits CE `chunked_ce` has to equal, for autodiff."""
+    logp = jax.nn.log_softmax((hidden @ lm_head).astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    if mask is None:
+        mask = jnp.ones(nll.shape, jnp.float32)
+    if denominator is None:
+        denominator = jnp.maximum(jnp.sum(mask), 1.0)
+    return jnp.sum(nll * mask) / denominator
+
+
+def _ce_operands(seq=32, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    hidden = jax.random.normal(keys[0], (2, seq, 16)).astype(dtype)
+    lm_head = (0.3 * jax.random.normal(keys[1], (16, 50))).astype(dtype)
+    targets = jax.random.randint(keys[2], (2, seq), 0, 50)
+    kept = (jax.random.uniform(keys[3], (2, seq)) > 0.3).astype(jnp.float32)
+    return hidden, lm_head, targets, kept
+
+
+def _dots(jaxpr):
+    """The output shapes of every `dot_general`, loop bodies' too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn.outvars[0].aval.shape
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub)
+
+
+@pytest.mark.parametrize("case", [
+    "no_mask", "mask_of_0_and_1", "float_weights_over_a_denominator",
+    "chunk_does_not_divide_s", "cotangent_of_0.3", "bf16_operands"])
+def test_llama_chunked_ce_matches_plain(case):
+    """`chunked_ce`'s loss, d / d hidden and d / d lm_head (formed in its
+    forward pass, scaled in its backward) against autodiff of the
+    full-logits CE on the same operands."""
+    hidden, lm_head, targets, kept = _ce_operands(
+        seq=29 if case == "chunk_does_not_divide_s" else 32,
+        dtype=jnp.bfloat16 if case == "bf16_operands" else jnp.float32)
+    mask, denominator = {
+        "no_mask": (None, None),
+        "float_weights_over_a_denominator": (1.7 * kept, 13.0),
+    }.get(case, (kept, None))
+    cotangent = 0.3 if case == "cotangent_of_0.3" else 1.0
+    want, (want_dh, want_dw) = jax.value_and_grad(
+        lambda h, w: cotangent * _plain_ce(h, w, targets, mask, denominator),
+        argnums=(0, 1))(hidden, lm_head)
+    got, (got_dh, got_dw) = jax.jit(jax.value_and_grad(
+        lambda h, w: cotangent * llama.chunked_ce(
+            h, w, targets, mask, chunk=8, denominator=denominator),
+        argnums=(0, 1)))(hidden, lm_head)
+    assert abs(float(got) - float(want)) < 1e-5 * float(want)
+    # bf16: the products round to 8 bits, and d lm_head adds its chunks up
+    # in bf16 where the plain CE has one product
+    rel = 2e-2 if case == "bf16_operands" else 1e-5
+    for g, w in ((got_dh, want_dh), (got_dw, want_dw)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        w = np.asarray(w.astype(jnp.float32))
+        assert np.abs(w).max() > 0
+        np.testing.assert_allclose(np.asarray(g.astype(jnp.float32)), w,
+                                   rtol=0, atol=rel * np.abs(w).max())
+
+
+def test_llama_chunked_ce_undifferentiated_forms_no_gradient():
+    """`jax.jit(chunked_ce)` with no gradient asked for multiplies hidden by
+    lm_head and nothing else: no [rows, V] x [V, D] product (d hidden) and
+    no [D, rows] x [rows, V] (d lm_head), in the scan or in the remainder's
+    chunk. Differentiated, each of the two bodies holds the three."""
+    hidden, lm_head, targets, kept = _ce_operands(seq=29)
+
+    def loss(h, w):
+        return llama.chunked_ce(h, w, targets, kept, chunk=8)
+
+    assert list(_dots(jax.make_jaxpr(loss)(hidden, lm_head).jaxpr)) == [
+        (2, 8, 50), (2, 5, 50)]
+    assert sorted(_dots(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        hidden, lm_head).jaxpr)) == sorted([
+            (2, 8, 50), (2, 8, 16), (16, 50), (2, 5, 50), (2, 5, 16),
+            (16, 50)])
+    # through a model's loss_fn too: llama's, chunked against whole
     cfg = _f32(llama.LlamaConfig)
     params = llama.init(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(5), (2, 30), 0,
                               cfg.vocab_size)
-    mask = (jax.random.uniform(jax.random.PRNGKey(6), (2, 29)) > 0.3)
     batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:],
-             "mask": mask.astype(jnp.float32)}
-    plain = float(llama.loss_fn(params, batch, cfg))
+             "mask": kept[:, :29]}
     ccfg = llama.LlamaConfig(**{**cfg.__dict__, "loss_chunk_size": 8})
-    chunked = float(llama.loss_fn(params, batch, ccfg))
-    assert abs(plain - chunked) < 1e-5
-    # Gradients agree too.
     g1 = jax.grad(lambda p: llama.loss_fn(p, batch, cfg))(params)
     g2 = jax.grad(lambda p: llama.loss_fn(p, batch, ccfg))(params)
-    np.testing.assert_allclose(np.asarray(g1["lm_head"]),
-                               np.asarray(g2["lm_head"]),
-                               rtol=1e-4, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
 
 
 # ----------------------------------------------------------------------- T5

@@ -592,3 +592,40 @@ def test_a_router_ahead_of_attention_and_the_flash_kernels_are_counted():
     first = lambda name: next(  # noqa: E731
         i for i, s in enumerate(entered) if name in s)
     assert first("moe.route") < first("swa.attend") < first("moe.experts")
+
+
+def test_the_heads_chunks_are_counted_where_their_gradient_is_formed():
+    """`llama.chunked_ce` counts every chunk it traces, the remainder's
+    too, under `ce.chunks`, and under `ce.chunks_fused` those of the forward
+    rule of its `custom_vjp`, whose gradient is formed with their logits: a
+    trace of the loss alone leaves the second where it was. The two names
+    are what `benchmarks/metrics/ce_fused_chunk_share.json` divides."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    hidden = jax.ShapeDtypeStruct((2, 29, 16), jnp.float32)
+    lm_head = jax.ShapeDtypeStruct((16, 50), jnp.float32)
+    targets = jnp.zeros((2, 29), jnp.int32)
+
+    def loss(h, w):
+        return llama.chunked_ce(h, w, targets, chunk=8)
+
+    def grew(fn):
+        before = dp.snapshot()["counters"]
+        jax.make_jaxpr(fn)(hidden, lm_head)
+        after = dp.snapshot()["counters"]
+        return {name: after[name] - before.get(name, 0)
+                for name in ("ce.chunks", "ce.chunks_fused")}
+
+    assert grew(loss) == {"ce.chunks": 4, "ce.chunks_fused": 0}
+    assert grew(jax.grad(loss, argnums=(0, 1))) == {
+        "ce.chunks": 4, "ce.chunks_fused": 4}
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "metrics",
+            "ce_fused_chunk_share.json")) as f:
+        spec = json.load(f)
+    assert (spec["over"], spec["under"]) == (["ce.chunks_fused"], ["ce.chunks"])

@@ -123,6 +123,13 @@ def computations_of(hlo):
     return computations
 
 
+def loop_bodies(computations):
+    # the instructions of every `while` body of a compiled program
+    return [computations[body] for lines in list(computations.values())
+            for w in lines
+            for body in re.findall(r" while\(.*body=%([\w.\-]+)", w)]
+
+
 def called_as(computations):
     # computation -> the ways every computation above it is called, up to
     # the entry: "body" of a while, "branch_computations" of a conditional,
@@ -304,11 +311,11 @@ hlo = jax.jit(jax.value_and_grad(
     lambda p, b: llama.loss_fn(p, b, cfg, mesh))).lower(
         params, {"inputs": tokens, "targets": tokens}).compile().as_text()
 # the top-level instructions of every `while` body: the scanned layers'
-# forward and backward (and chunked_ce's two)
+# forward and backward (and chunked_ce's one, its gradient formed with its
+# logits)
 computations = computations_of(hlo)
-in_loops = [ln for lines in list(computations.values()) for w in lines
-            for body in re.findall(r" while\(.*body=%([\w.\-]+)", w)
-            for ln in computations[body]]
+loops = loop_bodies(computations)
+in_loops = [ln for body in loops for ln in body]
 out["tp_loops"] = len(in_loops) > 0
 out["tp_all_reduces_in_loops"] = [
     ln.strip()[:160] for ln in in_loops
@@ -321,6 +328,31 @@ out["tp_permutes_in_loops"] = sum(
     1 for ln in in_loops if re.search(
         r"= \(bf16\[8,1024,4096\]\S*, .* collective-permute-start\(", ln))
 out["tp_all_to_alls"] = len(re.findall(r" all-to-all\(", hlo))
+# the head's loops: those with lm_head's [., 32768 / tp] in them
+head_loops = [body for body in loops
+              if any(re.search(r"\[[\d,]*16384\]", ln) for ln in body)]
+out["tp_head_loops"] = len(head_loops)
+out["tp_head_loop_collectives"] = sum(
+    1 for body in head_loops for ln in body if re.search(
+        r" (all-gather|all-reduce|reduce-scatter|all-to-all"
+        r"|collective-permute-start)\(|calls=%all-reduce-scatter", ln))
+
+# the head alone at train-smallthinker-1chip's widths (S 16,384, D 2,560,
+# V 37,984, chunks of 1,024), value and gradient: the matrix products in
+# its loops, a fusion's own among them, by the shape they put out
+hlo = jax.jit(jax.value_and_grad(
+    lambda h, w, t: llama.chunked_ce(h, w, t, chunk=1024),
+    argnums=(0, 1))).lower(
+        spec((1, 16384, 2560), bf16), spec((2560, 37984), bf16),
+        spec((1, 16384), jnp.int32)).compile().as_text()
+computations = computations_of(hlo)
+out["head_loop_products"] = sorted(
+    m[1] for body in loop_bodies(computations) for ln in body
+    for inner in [ln] + [
+        x for name in re.findall(r" fusion\(.*calls=%([\w.\-]+)", ln)
+        for x in computations[name]]
+    if (m := re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])\S* "
+                      r"convolution\(", inner)))
 
 # the flash call under the block-diffusion rule (train-sdar-1chip): q
 # [4, 4096, 32, 128] over the concatenation [x_t ; x_0] of 2 x 2,048, 4 kv
@@ -828,6 +860,22 @@ def test_tp_boundary_is_not_an_all_reduce_for_v5e_2x2(compiled):
     assert compiled["tp_reduce_scatters_in_loops"] >= 2
     assert compiled["tp_permutes_in_loops"] == 5
     assert compiled["tp_all_to_alls"] == 0
+
+
+def test_the_head_forms_its_gradient_with_its_logits_for_v5e(compiled):
+    """`llama.chunked_ce`, value and gradient, as the v5e's compiler leaves
+    it. Alone at train-smallthinker-1chip's widths its loop holds THREE
+    products of 1,024 x 2,560 x 37,984, the logits, d hidden and d lm_head
+    (four until PR 53: the backward pass formed the logits again). In
+    train-4chip's step over fsdp 2 x tp 2 it is ONE loop, and that loop holds
+    no more collectives than the two loops it replaces held between them
+    (11: in each, lm_head gathered over fsdp, the chunk's rows over the
+    batch and the softmax's sums over tp; d hidden's sum over tp in the
+    backward one): 6, a forward's and d hidden's."""
+    assert compiled["head_loop_products"] == [
+        "bf16[1024,2560]", "bf16[1024,37984]", "bf16[2560,37984]"]
+    assert compiled["tp_head_loops"] == 1
+    assert 0 < compiled["tp_head_loop_collectives"] <= 11
 
 
 def test_moe_layer_backward_as_compiled_for_v5e(compiled):
