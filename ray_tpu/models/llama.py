@@ -155,10 +155,18 @@ def _remat_policy(config):
     name = getattr(config, "remat_policy", "full")
     if name == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    if name == "residuals":
+        # nothing of the layer's own: with `mla_moe._checkpointed`, which
+        # adds the kernels' named residuals, a layer keeps its input, the
+        # flash call's o and lse and the scan's y and recomputes the rest
+        # (a long sequence: "dots" would keep ~1.9 GB a layer of
+        # `granite_hybrid` at S 32,768)
+        return jax.checkpoint_policies.nothing_saveable
     if name != "full":
         raise ValueError(
-            f"remat_policy {name!r}: \"full\" (recompute everything) or "
-            "\"dots\" (save matmul outputs)")
+            f"remat_policy {name!r}: \"full\" (recompute everything), "
+            "\"dots\" (save matmul outputs) or \"residuals\" (the "
+            "kernels' named residuals alone)")
     return None
 
 
@@ -249,23 +257,28 @@ def _rope(x, positions, theta, rotary: Optional[Rotary] = None):
     return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
-def _attention(q, k, v, config: LlamaConfig, mesh=None, mask=None):
+def _attention(q, k, v, config: LlamaConfig, mesh=None, mask=None,
+               scale=None):
     """Causal flash attention, or under `mask` (a static rule of
-    `ops/flash_attention.py`) in its scope, which names the Pallas events."""
+    `ops/flash_attention.py`) in its scope, which names the Pallas events;
+    scores times `scale` where the caller gives one (None: the kernels'
+    d_head ** -0.5)."""
     if mask is not None:
         if config.use_ring_attention:
             raise NotImplementedError("ring attention is causal only")
         with jax.named_scope(mask.scope):
-            return _flash(q, k, v, mesh, mask=mask)
+            return _flash(q, k, v, mesh, mask=mask, scale=scale)
     if config.use_ring_attention and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention_sharded
 
+        if scale is not None:
+            raise NotImplementedError("ring attention scales by d_head ** -0.5")
         rep = config.n_heads // config.n_kv_heads
         if rep > 1:
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         return ring_attention_sharded(q, k, v, mesh, causal=True)
-    return _flash(q, k, v, mesh, causal=True)
+    return _flash(q, k, v, mesh, causal=True, scale=scale)
 
 
 def _flash(q, k, v, mesh, **rule):
@@ -385,14 +398,16 @@ def _head_gated(attn, h, w_gate):
 
 def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
                    rules: Optional[LogicalAxisRules] = None, mask=None,
-                   rotary=None):
+                   rotary=None, scale=None, branch=None):
     """Pre-norm attention block of the training layer (and of mixtral's):
     causal, or under the static rule `mask` (`_attention`). What may differ
     by the KIND of a layer comes from the caller, not from `config`: the
     rule, the rotary form (`rotary`), the number of query heads (the
     layer's `wq`) and a gate per head on the output before `wo`,
     attn_head * sigmoid(w_head . h), where the layer has a `w_attn_gate`
-    [D, H] (`mla_moe._mla_sublayer`'s form)."""
+    [D, H] (`mla_moe._mla_sublayer`'s form), the scores' `scale` where it
+    is not d_head ** -0.5 and `branch`, a multiplier on what the block adds
+    to the residual (`models/granite_hybrid.py`'s published two)."""
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     q, k, v = _qkv(x, params, positions, config, lc, rotary)
     if "tp" in _residual_seq_axes(x, mesh, rules):
@@ -400,30 +415,40 @@ def _attn_sublayer(x, params, positions, config: LlamaConfig, mesh=None,
         # gathered for q and k: left unsaid, the compiler projects the local
         # rows onto every head and turns v round with an all-to-all
         v = lc(v, ("batch", "seq", "act_heads", "act_kv"))
-    attn = _attention(q, k, v, config, mesh, mask)
+    attn = _attention(q, k, v, config, mesh, mask, scale)
     if "w_attn_gate" in params:
         with jax.named_scope("attn.gate"):
             # `_qkv`'s normed input: the compiler keeps one
             h = _rms_norm(x, params["attn_norm"], config.norm_eps)
             attn = _head_gated(attn, h, params["w_attn_gate"])
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, params["wo"])
+    x = x + _scaled(jnp.einsum("bshk,hkd->bsd", attn, params["wo"]), branch)
     return _residual(x, mesh, rules)
 
 
+def _scaled(out, branch):
+    """A block's output times its residual multiplier, the product formed
+    in float32 and rounded once; None: as it is."""
+    if branch is None:
+        return out
+    return (out.astype(jnp.float32) * branch).astype(out.dtype)
+
+
 def _mlp_sublayer(x, params, config: LlamaConfig, mesh=None,
-                  rules: Optional[LogicalAxisRules] = None):
-    """Pre-norm SwiGLU MLP block shared by training and decode paths."""
+                  rules: Optional[LogicalAxisRules] = None, branch=None):
+    """Pre-norm SwiGLU MLP block shared by training and decode paths;
+    `branch` as `_attn_sublayer`'s."""
     c = config
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
     h = _rms_norm(x, params["mlp_norm"], c.norm_eps)
     if _residual_seq_axes(x, mesh, rules) == ("tp",) \
             and c.d_ff % mesh.shape["tp"] == 0:
-        return _residual(x + _mlp_ring(h, params, mesh), mesh, rules)
+        return _residual(x + _scaled(_mlp_ring(h, params, mesh), branch),
+                         mesh, rules)
     gate = jnp.einsum("bsd,df->bsf", h, params["w_gate"])
     up = jnp.einsum("bsd,df->bsf", h, params["w_up"])
     gate = lc(gate, ("batch", "seq", "act_mlp"))
     ff = jax.nn.silu(gate) * up
-    x = x + jnp.einsum("bsf,fd->bsd", ff, params["w_down"])
+    x = x + _scaled(jnp.einsum("bsf,fd->bsd", ff, params["w_down"]), branch)
     return _residual(x, mesh, rules)
 
 

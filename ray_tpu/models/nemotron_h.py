@@ -356,15 +356,17 @@ def _conv_silu(x, taps, bias):
     return jax.nn.silu(y + bias.astype(jnp.float32))
 
 
-def _mamba_sublayer(x, p, config: NemotronHConfig, mesh=None,
-                    rules: Optional[LogicalAxisRules] = None):
-    """x [B, S, D] -> x + Mamba-2(RMSNorm(x)) (the module's docstring)."""
+def mamba_mixer(h, p, config):
+    """The Mamba-2 mixer of the module's docstring on h [B, S, D], a
+    layer's normed input -> [B, S, D], before the residual is added (shared
+    with `models/granite_hybrid.py`, which scales it first). `config` gives
+    `mamba_heads`, `mamba_head_dim`, `n_groups`, `state_size`, `d_inner`,
+    `conv_dim`, `chunk_size` (the scan's chunk), `norm_eps` and `dtype`."""
     c = config
-    b, s, _ = x.shape
+    b, s, _ = h.shape
     heads, groups = c.mamba_heads, c.n_groups
     wide, gn = c.d_inner, c.n_groups * c.state_size
     f32 = jnp.float32
-    h = _rms_norm(x, p["norm"], c.norm_eps)
     with jax.named_scope("ssd.project"):
         zxbcdt = h @ p["w_in"]
         z = zxbcdt[..., :wide]
@@ -377,7 +379,7 @@ def _mamba_sublayer(x, p, config: NemotronHConfig, mesh=None,
             p["a_log"], xbc[..., wide:wide + gn].reshape(
                 b, s, groups, c.state_size),
             xbc[..., wide + gn:].reshape(b, s, groups, c.state_size),
-            p["d_skip"], p["dt_bias"])
+            p["d_skip"], p["dt_bias"], chunk=c.chunk_size)
     with jax.named_scope("ssd.gate"):
         gated = (y.reshape(b, s, wide).astype(f32)
                  * jax.nn.silu(z.astype(f32))).reshape(b, s, groups, -1)
@@ -385,7 +387,14 @@ def _mamba_sublayer(x, p, config: NemotronHConfig, mesh=None,
             jnp.mean(gated * gated, axis=-1, keepdims=True) + c.norm_eps)
         gated = gated.reshape(b, s, wide).astype(c.dtype) * p["gate_norm"]
     device_profiler.count("ssd.layers", 1)  # per lowering
-    return _residual(x + gated @ p["w_out"], mesh, rules)
+    return gated @ p["w_out"]
+
+
+def _mamba_sublayer(x, p, config: NemotronHConfig, mesh=None,
+                    rules: Optional[LogicalAxisRules] = None):
+    """x [B, S, D] -> x + Mamba-2(RMSNorm(x)) (the module's docstring)."""
+    h = _rms_norm(x, p["norm"], config.norm_eps)
+    return _residual(x + mamba_mixer(h, p, config), mesh, rules)
 
 
 def _expert_sublayer(x, p, config: NemotronHConfig, mesh=None,

@@ -733,6 +733,21 @@ _VMEM_WORKING = 4 * 2**20
 _VMEM_ROOM = 16 * 2**20
 
 
+def _in_vmem(block_shape):
+    """Elements a block takes in VMEM, whose tiles are 128 lanes wide: a
+    64-wide head's K takes a 128-wide one's room (the v5e compiler on the
+    first call with a 64-wide head as its only part, [1, 32768, 8, 64] K and
+    V: "Scoped allocation with size 33.00M and limit 32.25M", where the
+    unpadded blocks were reckoned at 16.25M). A [rows, 1] column (lse,
+    delta) is counted as it is: its padding, under 1 MiB a kernel, is in
+    the working set's room, and the calls at 128-wide heads state the
+    limits they always did."""
+    *lead, lanes = block_shape
+    if lanes > 1:
+        lanes = -(-lanes // 128) * 128
+    return math.prod(lead) * lanes
+
+
 def _vmem_limit(specs, arrays):
     """The VMEM limit to state for a kernel whose blocks are `specs` of
     `arrays` (operands and results, shapes and dtypes), or None where the
@@ -744,7 +759,7 @@ def _vmem_limit(specs, arrays):
     always did (every call at S <= 8,192 of a cell: 8.5 MiB of blocks at
     [8192, 128] K and V)."""
     blocks = 2 * sum(
-        math.prod(spec.block_shape) * jnp.dtype(x.dtype).itemsize
+        _in_vmem(spec.block_shape) * jnp.dtype(x.dtype).itemsize
         for spec, x in zip(jax.tree.leaves(specs), jax.tree.leaves(arrays)))
     if blocks + _VMEM_WORKING <= _VMEM_DEFAULT:
         return None
@@ -1340,7 +1355,8 @@ def flash_attention(
     own limit, reckoned from its blocks (`_vmem_limit`): S 16,384 (16 MiB of
     K and V: refused at "16.75M of 16.00M" until PR 50) compiles and runs at
     33 MiB of the chip's 128, under `CAUSAL` and `SlidingWindow` alike, and
-    S 32,768 would ask 49 MiB. What holds the sequence after that: VMEM
+    S 32,768 asks 49 MiB, at D 64 too (a 64-wide head's block is padded to
+    128 lanes in VMEM: `_in_vmem`). What holds the sequence after that: VMEM
     still, at about S 100,000 a head (a window row reads 4,608 of 16,384
     keys it holds: fetching K and V by the blocks a row's steps touch is
     what lifts it, PERF.md section 7), and before it HBM, where the

@@ -10,8 +10,9 @@ shared by the `H / G` heads of a group):
 
 `ssd_recurrence` is that definition, token by token (tests, chip checks).
 `ssd_scan(x, delta, a, b, c) -> (y, the final state)`, x [B, S, H, P],
-delta and a [B, S, H], b and c [B, S, G, N], is its chunked form (chunk
-`CHUNK` = 128, the configuration's `chunk_size`), a `custom_vjp`. With c_t
+delta and a [B, S, H], b and c [B, S, G, N], is its chunked form (`chunk=`
+tokens a chunk, `CHUNK` = 128 unless the caller says: the configuration's
+`chunk_size`; a chunk does not change the function), a `custom_vjp`. With c_t
 the running sum of a inside a chunk, H the chunk's incoming state:
 
     Y  = ((C B^T) * L) (Delta x) + exp(c_t) C_t H^T,   L[t, s] = exp(c_t - c_s)
@@ -21,8 +22,9 @@ the running sum of a inside a chunk, H the chunk's incoming state:
 Every exponent is a difference that is <= 0 (a <= 0, so c falls): nothing
 is divided by a cumulative decay and no power of a chunk is multiplied by
 another (`ops/kda.py`'s docstring on what that costs). A chunk is plain
-matmuls of 128 x 128 x {P, N}: C B^T once a GROUP, one product a head for
-the part inside the chunk, one each for the state's read and its update.
+matmuls of Q x Q x {P, N} (Q the chunk): C B^T once a GROUP, one product a
+head for the part inside the chunk, one each for the state's read and its
+update.
 
 Matmul operands are rounded to x's dtype (bf16 in the model) with float32
 accumulation; the state is carried from chunk to chunk in float32 and
@@ -31,7 +33,15 @@ by XLA before the call (a cumulative sum of [B, S, H] scalars). On a TPU
 the call is three Pallas kernels over a grid of (batch x group, chunks),
 the chunk axis in sequence with the carried array resident in VMEM, B and C
 loaded once a group, the group's heads side by side in the lanes as the
-projection leaves them ([B, S, H x P]: no transpose before or after):
+projection leaves them ([B, S, H x P]: no transpose before or after). A
+group wider than `_BLOCK_LANES` (1,024 lanes: 16 heads of 64) is walked in
+HEAD BLOCKS of that width, each a grid row of its own (`_head_blocks`): the
+blocks of x, y and the states are then the narrower group's, B and C are
+still fetched by group, and a block's part of dB and dC leaves the kernel
+beside the others' and is summed outside (Granite-4.0-H's ONE group of 64
+heads runs as four blocks of 16: at 4,096 lanes the state and its cotangent
+alone are 2 MiB each and the per-head loop is four times the text). A group
+that fits a block lowers as it always did. The kernels:
 `_fwd_kernel` (outputs y and the FINAL STATE), and for the backward pass
 `_states_kernel` (the state's walk again, handing on each chunk's incoming
 state) and `_bwd_kernel` (the chunks walked backwards, the state's
@@ -58,6 +68,8 @@ CHUNK = 128
 RESIDUAL_NAMES = ("ssd.y",)
 _HIGHEST = jax.lax.Precision.HIGHEST
 _LANES = 128
+# the widest block of heads a kernel takes at once (`_head_blocks`)
+_BLOCK_LANES = 1024
 
 
 def ssd_recurrence(x, delta, a, b, c):
@@ -101,7 +113,7 @@ def _pad_tokens(arrays, pad):
             for v in arrays]
 
 
-def _ssd_chunked(x, delta, a, b, c):
+def _ssd_chunked(x, delta, a, b, c, chunk=CHUNK):
     """The chunked form in `jnp` -> (y [B, S, H, P] in x.dtype, the final
     state [B, H, P, N] float32)."""
     dtype = x.dtype
@@ -109,14 +121,14 @@ def _ssd_chunked(x, delta, a, b, c):
     g, n_state = b.shape[2:]
     rep = h // g
     f32 = jnp.float32
-    x, delta, a, b, c = _pad_tokens([x, delta, a, b, c], -s % CHUNK)
-    n = x.shape[1] // CHUNK
-    xs = x.astype(f32).reshape(bsz, n, CHUNK, g, rep, p)
-    cum = jnp.cumsum(a.astype(f32).reshape(bsz, n, CHUNK, g, rep), axis=2)
-    xd = xs * delta.astype(f32).reshape(bsz, n, CHUNK, g, rep)[..., None]
-    bs, cs = (v.astype(f32).reshape(bsz, n, CHUNK, g, n_state)
+    x, delta, a, b, c = _pad_tokens([x, delta, a, b, c], -s % chunk)
+    n = x.shape[1] // chunk
+    xs = x.astype(f32).reshape(bsz, n, chunk, g, rep, p)
+    cum = jnp.cumsum(a.astype(f32).reshape(bsz, n, chunk, g, rep), axis=2)
+    xd = xs * delta.astype(f32).reshape(bsz, n, chunk, g, rep)[..., None]
+    bs, cs = (v.astype(f32).reshape(bsz, n, chunk, g, n_state)
               for v in (b, c))
-    lower = (jnp.arange(CHUNK)[:, None] >= jnp.arange(CHUNK))[
+    lower = (jnp.arange(chunk)[:, None] >= jnp.arange(chunk))[
         None, None, :, :, None, None]
     decay = jnp.exp(jnp.where(
         lower, cum[:, :, :, None] - cum[:, :, None], -jnp.inf))
@@ -126,7 +138,7 @@ def _ssd_chunked(x, delta, a, b, c):
     written = _mm("bnsgrp,bnsgk->bngrpk",
                   xd * jnp.exp(last[:, :, None] - cum)[..., None], bs, dtype)
 
-    def chunk(state, t):
+    def step(state, t):
         c_t, cum_t, last_t, written_t = t
         read = _mm("btgk,bgrpk->btgrp", c_t, state, dtype) \
             * jnp.exp(cum_t)[..., None]
@@ -135,8 +147,8 @@ def _ssd_chunked(x, delta, a, b, c):
     along = lambda v: jnp.moveaxis(v, 1, 0)  # noqa: E731
     state = jnp.zeros((bsz, g, rep, p, n_state), f32)
     state, read = jax.lax.scan(
-        chunk, state, tuple(along(v) for v in (cs, cum, last, written)))
-    y = (inside + along(read)).reshape(bsz, n * CHUNK, h, p)[:, :s]
+        step, state, tuple(along(v) for v in (cs, cum, last, written)))
+    y = (inside + along(read)).reshape(bsz, n * chunk, h, p)[:, :s]
     return y.astype(dtype), state.reshape(bsz, h, p, n_state)
 
 
@@ -367,44 +379,71 @@ def _bwd_kernel(x_ref, dl_ref, cc_ref, cr_ref, b_ref, c_ref, dy_ref, h_ref,
     dcr_ref[0] = d_cr
 
 
-def _flat(x, delta, a, b, c, *more):
+def _head_blocks(heads: int, p: int) -> int:
+    """The blocks a group of `heads` heads is walked in: 1 where its heads
+    x P lanes fit `_BLOCK_LANES`, else the fewest equal blocks that do."""
+    blocks = 1
+    while heads % blocks or heads // blocks * p > _BLOCK_LANES:
+        blocks += 1
+        if blocks > heads:
+            raise ValueError(f"a head of {p} lanes is past {_BLOCK_LANES}")
+    return blocks
+
+
+def _flat(x, delta, a, b, c, *more, chunk, blocks):
     """-> (the kernels' operands, padded to whole chunks: x and `more`
-    [B, S, H x P], Delta and the running log decay [B x G, S, H / G], the
-    latter's transpose, b and c [B, S, G x N]; the chunks' number)."""
+    [B, S, H x P], Delta and the running log decay [B x G x blocks, S,
+    H / G / blocks] (a grid row's heads), the latter's transpose, b and c
+    [B, S, G x N]; the chunks' number)."""
     bsz, s, h, p = x.shape
-    g = b.shape[2]
-    wide = _pad_tokens([x, *more], -s % CHUNK)
-    delta, a, b, c = _pad_tokens([delta, a, b, c], -s % CHUNK)
+    g = b.shape[2] * blocks
+    wide = _pad_tokens([x, *more], -s % chunk)
+    delta, a, b, c = _pad_tokens([delta, a, b, c], -s % chunk)
     s = wide[0].shape[1]
-    n = s // CHUNK
+    n = s // chunk
     by_group = lambda v: jnp.swapaxes(  # noqa: E731
         v.astype(jnp.float32).reshape(bsz, s, g, h // g), 1, 2).reshape(
             bsz * g, s, h // g)
     cum = by_group(jnp.cumsum(
-        a.astype(jnp.float32).reshape(bsz, n, CHUNK, h), axis=2))
+        a.astype(jnp.float32).reshape(bsz, n, chunk, h), axis=2))
     lanes = lambda v: v.reshape(bsz, s, -1)  # noqa: E731
     x, *more = (lanes(v) for v in wide)
     return (x, by_group(delta), cum, jnp.swapaxes(cum, 1, 2), lanes(b),
             lanes(c), *more), n
 
 
-def _specs(g: int, n: int, heads: int, p: int, n_state: int, reverse=False):
-    """Block specs of a grid (batch x group, chunks): `lanes(width)` a [B,
-    S, G x width] array's chunk of this group, `cols` / `rows` the [B x G,
-    S, heads] scalars' and their transpose's, `held` the state's block,
-    which stays over the chunk axis, `walked` a chunk's block of the
-    [B x G, chunks x N, heads x P] states."""
+def _specs(g: int, n: int, heads: int, p: int, n_state: int, chunk: int,
+           blocks: int = 1, reverse=False):
+    """Block specs of a grid (batch x group x blocks, chunks), `heads` the
+    heads of ONE block: `lanes(width)` a [B, S, rows a batch x width]
+    array's chunk of this grid row, `group(width)` a [B, S, G x width]
+    array's chunk of this row's GROUP (B and C), `cols` / `rows` the
+    [B x G x blocks, S, heads] scalars' and their transpose's, `held` this
+    row's block of the state [B x G, N, blocks x heads x P], which stays
+    over the chunk axis, `walked` a chunk's block of the [B x G, chunks x N,
+    blocks x heads x P] states. With one block a group the index maps are
+    the ones there always were (chosen here, not traced)."""
     from jax.experimental import pallas as pl
 
     at = (lambda j: n - 1 - j) if reverse else (lambda j: j)
+    per = g * blocks  # grid rows a batch
+    if blocks == 1:
+        group = lambda i: i % g  # noqa: E731
+        state = lambda i, j: (i, j, 0)  # noqa: E731
+    else:
+        group = lambda i: i % per // blocks  # noqa: E731
+        state = lambda i, j: (i // blocks, j, i % blocks)  # noqa: E731
     return dict(
         lanes=lambda width: pl.BlockSpec(
-            (1, CHUNK, width), lambda i, j: (i // g, at(j), i % g)),
-        cols=pl.BlockSpec((1, CHUNK, heads), lambda i, j: (i, at(j), 0)),
-        rows=pl.BlockSpec((1, heads, CHUNK), lambda i, j: (i, 0, at(j))),
-        held=pl.BlockSpec((1, n_state, heads * p), lambda i, j: (i, 0, 0)),
-        walked=pl.BlockSpec(
-            (1, n_state, heads * p), lambda i, j: (i, at(j), 0)))
+            (1, chunk, width), lambda i, j: (i // per, at(j), i % per)),
+        group=lambda width: pl.BlockSpec(
+            (1, chunk, width), lambda i, j: (i // per, at(j), group(i))),
+        cols=pl.BlockSpec((1, chunk, heads), lambda i, j: (i, at(j), 0)),
+        rows=pl.BlockSpec((1, heads, chunk), lambda i, j: (i, 0, at(j))),
+        held=pl.BlockSpec((1, n_state, heads * p),
+                          lambda i, j: state(i, 0)),
+        walked=pl.BlockSpec((1, n_state, heads * p),
+                            lambda i, j: state(i, at(j))))
 
 
 def _params():
@@ -414,6 +453,14 @@ def _params():
         dimension_semantics=("parallel", "arbitrary"))
 
 
+def _count_kernels(n: int, heads: int, p: int):
+    """Per lowering: the kernels of a call, and of them those lowered for
+    a group of more than `_BLOCK_LANES` lanes (walked in head blocks)."""
+    device_profiler.count("ssd.kernels", n)
+    device_profiler.count("ssd.kernels_wide_group",
+                          n * (heads * p > _BLOCK_LANES))
+
+
 def _state_out(state, bsz, g, heads, p):
     """The kernels' [B x G, N, heads x P] -> [B, H, P, N]."""
     n_state = state.shape[1]
@@ -421,21 +468,24 @@ def _state_out(state, bsz, g, heads, p):
                          (0, 1, 3, 4, 2)).reshape(bsz, g * heads, p, n_state)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _ssd_fwd_pallas(x, delta, a, b, c, interpret=False):
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _ssd_fwd_pallas(x, delta, a, b, c, chunk=CHUNK, interpret=False):
     from jax.experimental import pallas as pl
 
     bsz, s, h, p = x.shape
     g, n_state = b.shape[2:]
     heads = h // g
-    args, n = _flat(x, delta, a, b, c)
-    sp = _specs(g, n, heads, p, n_state)
+    blocks = _head_blocks(heads, p)
+    block = heads // blocks
+    _count_kernels(1, heads, p)
+    args, n = _flat(x, delta, a, b, c, chunk=chunk, blocks=blocks)
+    sp = _specs(g, n, block, p, n_state, chunk, blocks)
     y, state = pl.pallas_call(
         partial(_fwd_kernel, p=p),
-        grid=(bsz * g, n),
-        in_specs=[sp["lanes"](heads * p), sp["cols"], sp["cols"], sp["rows"],
-                  sp["lanes"](n_state), sp["lanes"](n_state)],
-        out_specs=[sp["lanes"](heads * p), sp["held"]],
+        grid=(bsz * g * blocks, n),
+        in_specs=[sp["lanes"](block * p), sp["cols"], sp["cols"], sp["rows"],
+                  sp["group"](n_state), sp["group"](n_state)],
+        out_specs=[sp["lanes"](block * p), sp["held"]],
         out_shape=[jax.ShapeDtypeStruct(args[0].shape, x.dtype),
                    jax.ShapeDtypeStruct((bsz * g, n_state, heads * p),
                                         jnp.float32)],
@@ -445,60 +495,71 @@ def _ssd_fwd_pallas(x, delta, a, b, c, interpret=False):
     return (y[:, :s].reshape(x.shape), _state_out(state, bsz, g, heads, p))
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _ssd_bwd_pallas(x, delta, a, b, c, dy, dstate, interpret=False):
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _ssd_bwd_pallas(x, delta, a, b, c, dy, dstate, chunk=CHUNK,
+                    interpret=False):
     """The five gradients from TWO calls: `_states_kernel` forwards, then
     `_bwd_kernel` backwards. What the second hands back of the running sums
     (c as columns, less c as rows) becomes a's gradient here: a reversed
-    cumulative sum inside each chunk."""
+    cumulative sum inside each chunk. A group walked in head blocks leaves
+    a part of dB and dC a block, summed here in float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bsz, s, h, p = x.shape
     g, n_state = b.shape[2:]
     heads = h // g
-    (x_, dl_, cc_, cr_, b_, c_, dy_), n = _flat(x, delta, a, b, c, dy)
+    blocks = _head_blocks(heads, p)
+    block = heads // blocks
+    _count_kernels(2, heads, p)
+    (x_, dl_, cc_, cr_, b_, c_, dy_), n = _flat(
+        x, delta, a, b, c, dy, chunk=chunk, blocks=blocks)
     f32 = jnp.float32
     shaped = jax.ShapeDtypeStruct
-    rows = bsz * g
-    sp = _specs(g, n, heads, p, n_state)
+    rows = bsz * g * blocks
+    sp = _specs(g, n, block, p, n_state, chunk, blocks)
     states = pl.pallas_call(
         partial(_states_kernel, p=p),
         grid=(rows, n),
-        in_specs=[sp["lanes"](heads * p), sp["cols"], sp["cols"], sp["rows"],
-                  sp["lanes"](n_state)],
+        in_specs=[sp["lanes"](block * p), sp["cols"], sp["cols"], sp["rows"],
+                  sp["group"](n_state)],
         out_specs=sp["walked"],
-        out_shape=shaped((rows, n * n_state, heads * p), f32),
-        scratch_shapes=[pltpu.VMEM((n_state, heads * p), f32)],
+        out_shape=shaped((bsz * g, n * n_state, heads * p), f32),
+        scratch_shapes=[pltpu.VMEM((n_state, block * p), f32)],
         compiler_params=_params(),
         interpret=interpret,
     )(x_, dl_, cc_, cr_, b_)
     dstate = jnp.transpose(
         dstate.astype(f32).reshape(bsz, g, heads, p, n_state),
-        (0, 1, 4, 2, 3)).reshape(rows, n_state, heads * p)
-    sp = _specs(g, n, heads, p, n_state, reverse=True)
-    per_head = shaped((rows, n * CHUNK, heads), f32)
+        (0, 1, 4, 2, 3)).reshape(bsz * g, n_state, heads * p)
+    sp = _specs(g, n, block, p, n_state, chunk, blocks, reverse=True)
+    per_head = shaped((rows, n * chunk, block), f32)
+    by_block = lambda v, like: shaped(  # noqa: E731
+        v.shape[:2] + (blocks * v.shape[2],), like.dtype)
     dx, db, dc, ddl, dcc, dcr = pl.pallas_call(
         partial(_bwd_kernel, p=p),
         grid=(rows, n),
-        in_specs=[sp["lanes"](heads * p), sp["cols"], sp["cols"], sp["rows"],
-                  sp["lanes"](n_state), sp["lanes"](n_state),
-                  sp["lanes"](heads * p), sp["walked"], sp["held"]],
-        out_specs=[sp["lanes"](heads * p), sp["lanes"](n_state),
+        in_specs=[sp["lanes"](block * p), sp["cols"], sp["cols"], sp["rows"],
+                  sp["group"](n_state), sp["group"](n_state),
+                  sp["lanes"](block * p), sp["walked"], sp["held"]],
+        out_specs=[sp["lanes"](block * p), sp["lanes"](n_state),
                    sp["lanes"](n_state), sp["cols"], sp["cols"], sp["rows"]],
-        out_shape=[shaped(x_.shape, x.dtype), shaped(b_.shape, b.dtype),
-                   shaped(c_.shape, c.dtype), per_head, per_head,
-                   shaped((rows, heads, n * CHUNK), f32)],
-        scratch_shapes=[pltpu.VMEM((n_state, heads * p), f32)],
+        out_shape=[shaped(x_.shape, x.dtype), by_block(b_, b), by_block(c_, c),
+                   per_head, per_head, shaped((rows, block, n * chunk), f32)],
+        scratch_shapes=[pltpu.VMEM((n_state, block * p), f32)],
         compiler_params=_params(),
         interpret=interpret,
     )(x_, dl_, cc_, cr_, b_, c_, dy_, states, dstate)
     by_token = lambda v: jnp.swapaxes(  # noqa: E731
-        v.reshape(bsz, g, n * CHUNK, heads), 1, 2).reshape(
-            bsz, n * CHUNK, h)
-    d_cum = by_token(dcc - jnp.swapaxes(dcr, 1, 2)).reshape(bsz, n, CHUNK, h)
+        v.reshape(bsz, g * blocks, n * chunk, block), 1, 2).reshape(
+            bsz, n * chunk, h)
+    d_cum = by_token(dcc - jnp.swapaxes(dcr, 1, 2)).reshape(bsz, n, chunk, h)
     d_a = jnp.flip(jnp.cumsum(jnp.flip(d_cum, 2), axis=2), 2).reshape(
-        bsz, n * CHUNK, h)
+        bsz, n * chunk, h)
+    if blocks > 1:
+        db, dc = (jnp.sum(v.reshape(bsz, n * chunk, g, blocks, n_state),
+                          axis=3, dtype=f32).astype(v.dtype)
+                  for v in (db, dc))
     return (dx[:, :s].reshape(x.shape), by_token(ddl)[:, :s].astype(delta.dtype),
             d_a[:, :s].astype(a.dtype), db[:, :s].reshape(b.shape),
             dc[:, :s].reshape(c.shape))
@@ -508,44 +569,48 @@ def _ssd_bwd_pallas(x, delta, a, b, c, dy, dstate, interpret=False):
 # the call
 # --------------------------------------------------------------------------
 
-def _forward(x, delta, a, b, c, use_pallas, interpret):
+def _forward(x, delta, a, b, c, chunk, use_pallas, interpret):
     device_profiler.count("ssd.calls", 1)  # per lowering
-    device_profiler.count("ssd.chunks", -(-x.shape[1] // CHUNK))
+    device_profiler.count("ssd.chunks", -(-x.shape[1] // chunk))
     if use_pallas or interpret:
-        return _ssd_fwd_pallas(x, delta, a, b, c, interpret=interpret)
-    return _ssd_chunked(x, delta, a, b, c)
+        return _ssd_fwd_pallas(x, delta, a, b, c, chunk=chunk,
+                               interpret=interpret)
+    return _ssd_chunked(x, delta, a, b, c, chunk)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _ssd(x, delta, a, b, c, use_pallas, interpret):
-    return _forward(x, delta, a, b, c, use_pallas, interpret)
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _ssd(x, delta, a, b, c, use_pallas, interpret, chunk=CHUNK):
+    return _forward(x, delta, a, b, c, chunk, use_pallas, interpret)
 
 
-def _ssd_fwd_rule(x, delta, a, b, c, use_pallas, interpret):
-    y, state = _forward(x, delta, a, b, c, use_pallas, interpret)
+def _ssd_fwd_rule(x, delta, a, b, c, use_pallas, interpret, chunk):
+    y, state = _forward(x, delta, a, b, c, chunk, use_pallas, interpret)
     return (checkpoint_name(y, RESIDUAL_NAMES[0]), state), (x, delta, a, b, c)
 
 
-def _ssd_bwd_rule(use_pallas, interpret, res, cotangents):
+def _ssd_bwd_rule(use_pallas, interpret, chunk, res, cotangents):
     """On a TPU the two backward kernels; elsewhere XLA's transpose of the
     chunked arithmetic."""
     if use_pallas or interpret:
-        return _ssd_bwd_pallas(*res, *cotangents, interpret=interpret)
-    return jax.vjp(_ssd_chunked, *res)[1](cotangents)
+        return _ssd_bwd_pallas(*res, *cotangents, chunk=chunk,
+                               interpret=interpret)
+    return jax.vjp(partial(_ssd_chunked, chunk=chunk), *res)[1](cotangents)
 
 
 _ssd.defvjp(_ssd_fwd_rule, _ssd_bwd_rule)
 
 
-def ssd_scan(x, delta, a, b, c, *, use_pallas=None, interpret=False):
+def ssd_scan(x, delta, a, b, c, *, chunk=CHUNK, use_pallas=None,
+             interpret=False):
     """x [B, S, H, P], delta (> 0) and a (<= 0, the log decay a step)
     [B, S, H] float32, b and c [B, S, G, N] -> (y [B, S, H, P] in x.dtype,
-    the final state [B, H, P, N] float32). `use_pallas=None`: the Pallas
-    kernels on a TPU, `jnp` elsewhere (`interpret=True` runs the kernels in
-    the Pallas interpreter)."""
+    the final state [B, H, P, N] float32), walked `chunk` tokens at a time.
+    `use_pallas=None`: the Pallas kernels on a TPU, `jnp` elsewhere
+    (`interpret=True` runs the kernels in the Pallas interpreter)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu" and not interpret
-    return _ssd(x, delta, a, b, c, bool(use_pallas), bool(interpret))
+    return _ssd(x, delta, a, b, c, bool(use_pallas), bool(interpret),
+                int(chunk))
 
 
 def ssd(x, dt, a_log, b, c, d_skip, dt_bias, **how):

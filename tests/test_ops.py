@@ -1259,3 +1259,233 @@ def test_reglu_experts_under_a_routing_handed_in_match_their_formula(held):
     with pytest.raises(ValueError, match="routing"):
         moe.moe_layer(u[:-1], None, mine, k, held=held, form="reglu",
                       routing=moe.route(read, router, k, True))
+
+
+# --------------------------------------------------------------------------
+# PR 55: the scan at ONE wide group and a chunk of the caller's, the flash
+# call at a 64-wide head with a scale of its own, and the pins that the
+# calls of the nine cells there were trace to what they did
+# --------------------------------------------------------------------------
+
+import functools  # noqa: E402
+
+from ray_tpu._private import device_profiler  # noqa: E402
+from ray_tpu.ops import ssd as ssd_op  # noqa: E402
+
+_SSD_TENSORS = ("y", "state", "dx", "ddelta", "da", "db", "dc")
+# (heads, head dim, chunk, S, how): ONE group throughout. `jnp` at a chunk
+# of 128 and 256 with S no multiple of either; the kernels interpreted at a
+# group of 2,048 lanes, past `_BLOCK_LANES`, which they walk in two blocks
+# of 16 heads (the Granite cell's block), and at a narrow one under a chunk
+# of the caller's
+_SSD_CASES = {
+    "jnp-chunk128": (8, 16, 128, 300, "jnp"),
+    "jnp-chunk256": (8, 16, 256, 300, "jnp"),
+    "kernels-wide-group-chunk256": (32, 64, 256, 300, "kernel"),
+    "kernels-wide-group-chunk128": (32, 64, 128, 200, "kernel"),
+    "kernels-narrow-group-chunk64": (8, 16, 64, 150, "kernel"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _ssd_one_group(case):
+    """-> ({tensor: got}, {tensor: by the recurrence}): y, the final state
+    and the five gradients of sum(y * w) + sum(state)."""
+    h, p, chunk, s, how = _SSD_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(55), 6)
+    delta = jax.nn.softplus(jax.random.normal(ks[1], (1, s, h)) - 2.0)
+    a = -jnp.exp(jax.random.normal(ks[2], (h,))) * delta
+    args = (jax.random.normal(ks[0], (1, s, h, p)), delta, a,
+            jax.random.normal(ks[3], (1, s, 1, 8)),
+            jax.random.normal(ks[4], (1, s, 1, 8)))
+    w = jax.random.normal(ks[5], (1, s, h, p))
+
+    def everything(fn):
+        def scalar(*a):
+            y, state = fn(*a)
+            return jnp.sum(y * w) + jnp.sum(state), (y, state)
+        grads, out = jax.grad(scalar, argnums=(0, 1, 2, 3, 4),
+                              has_aux=True)(*args)
+        return dict(zip(_SSD_TENSORS, out + grads))
+
+    with jax.default_matmul_precision("highest"):
+        return (everything(lambda *a: ssd_op.ssd_scan(
+            *a, chunk=chunk, use_pallas=False, interpret=how == "kernel")),
+            everything(ssd_op.ssd_recurrence))
+
+
+@pytest.mark.parametrize("tensor", _SSD_TENSORS)
+@pytest.mark.parametrize("case", list(_SSD_CASES))
+def test_ssd_at_one_group_and_the_callers_chunk_matches_the_recurrence(
+        case, tensor):
+    got, want = _ssd_one_group(case)
+    assert bool(jnp.all(jnp.isfinite(got[tensor])))
+    scale = float(jnp.abs(want[tensor]).max())
+    np.testing.assert_allclose(got[tensor] / scale, want[tensor] / scale,
+                               atol=2e-5)
+
+
+def test_ssd_walks_a_wide_group_in_head_blocks_and_counts_it():
+    """64 heads of 64 (4,096 lanes) are four blocks of 16, Nemotron's 16 of
+    64 one; the kernels of a wide group are counted as such, at the call's
+    own chunk."""
+    assert ssd_op._head_blocks(64, 64) == 4
+    assert ssd_op._head_blocks(16, 64) == 1
+    assert ssd_op._head_blocks(24, 64) == 2     # 12 + 12: equal blocks
+    assert ssd_op._head_blocks(3, 1024) == 3
+    with pytest.raises(ValueError, match="past"):
+        ssd_op._head_blocks(2, 2048)
+    f32 = jnp.float32
+    shaped = jax.ShapeDtypeStruct
+
+    def lowered(h, g, s, chunk):
+        before = device_profiler.snapshot()["counters"]
+        args = (shaped((1, s, h, 64), f32), shaped((1, s, h), f32),
+                shaped((1, s, h), f32), shaped((1, s, g, 8), f32),
+                shaped((1, s, g, 8), f32))
+        jax.make_jaxpr(jax.grad(lambda *a: ssd_op.ssd_scan(
+            *a, chunk=chunk, interpret=True)[0].sum()))(*args)
+        after = device_profiler.snapshot()["counters"]
+        return {k: after.get(k, 0) - before.get(k, 0) for k in (
+            "ssd.kernels", "ssd.kernels_wide_group", "ssd.chunks")}
+
+    assert lowered(32, 1, 520, 256) == {
+        "ssd.kernels": 3, "ssd.kernels_wide_group": 3, "ssd.chunks": 3}
+    assert lowered(32, 2, 520, 128) == {
+        "ssd.kernels": 3, "ssd.kernels_wide_group": 0, "ssd.chunks": 5}
+
+
+# sha256 of `ssd_scan`'s value and gradients as traced at the Nemotron
+# cell's shape, x [2, 2048, 128, 64] in 8 groups of 16 heads, chunk 128,
+# the three kernels' bodies and their index maps in it: PR 53's text
+_SSD_NEMOTRON = \
+    "e4ffb51d722de1bf0346e42c4604a1fb232de66ff1bdf14a0884a0b1a19004f7"
+
+
+def _traced_digest(fn, shapes):
+    traced = jax.make_jaxpr(fn)(*shapes)
+    return hashlib.sha256(re.sub(r" at 0x[0-9a-f]+", "", str(traced))
+                          .encode()).hexdigest()
+
+
+def test_the_nemotron_cells_scan_traces_to_what_it_was():
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    shaped = jax.ShapeDtypeStruct
+    shapes = (shaped((2, 2048, 128, 64), bf16), shaped((2, 2048, 128), f32),
+              shaped((2, 2048, 128), f32), shaped((2, 2048, 8, 128), bf16),
+              shaped((2, 2048, 8, 128), bf16))
+    assert _traced_digest(jax.value_and_grad(
+        lambda *a: ssd_op.ssd_scan(*a, use_pallas=True)[0].astype(f32).sum(),
+        argnums=(0, 1, 2, 3, 4)), shapes) == _SSD_NEMOTRON
+
+
+# sha256 of the flash call, value and gradients as traced, at each of the
+# nine cells' shapes before PR 55: (batch, S, heads, KV heads, head dim, the
+# rule, the rotary part's width of a call in parts); train-4chip's is a tp 2
+# shard's. PR 53's text: PR 55 reckons a VMEM block's lanes in tiles of 128
+# (`_in_vmem`), which moves no limit a call at 128-wide heads states.
+_CELL_CALLS = {
+    "train-1chip": (
+        (4, 2048, 32, 8, 128, True, 0),
+        "b772782c7fd6f57623e7d27765407b6b3b97891394ed7695215260872eb7ba07"),
+    "train-4chip": (
+        (2, 2048, 16, 4, 128, True, 0),
+        "99a83cf3a27c794978987d35001874d9defc7a2f933b03a54b0acbf0df3c18e8"),
+    "train-olmoe-1chip": (
+        (4, 2048, 16, 16, 128, True, 0),
+        "3af1f557b548b45aabded6666b0a6dd85e88dee02355adf8eace1f3d501b94b6"),
+    "train-joyai-1chip": (
+        (4, 2048, 32, 32, 128, True, 64),
+        "215c588077323543d19182c8008f836f56704813bb26f8ba3b573700a4ba7bb6"),
+    "train-sdar-1chip": (
+        (4, 4096, 32, 4, 128, BlockDiffusion(2048, 4), 0),
+        "8657c189f1ec87658e2fbe91178505526f92671d6cb9b4074592f0a06c3094f6"),
+    "train-ling-1chip": (
+        (4, 2048, 32, 32, 128, True, 64),
+        "215c588077323543d19182c8008f836f56704813bb26f8ba3b573700a4ba7bb6"),
+    "train-nemotron3-1chip": (
+        (2, 2048, 32, 2, 128, True, 0),
+        "301461c060948943a88cd17b4a11a9538d36cb30b0a945eac53dd3dcd5c08fd0"),
+    "train-laguna-1chip.window": (
+        (1, 8192, 64, 8, 128, SlidingWindow(512), 0),
+        "85e93091c021de5c2b0afebcf1b3397725138daf6675ce33c754cee1c00aeadf"),
+    "train-laguna-1chip.full": (
+        (1, 8192, 48, 8, 128, True, 0),
+        "7ff939d3d30c658b9972b51bb2b077416905de44852f40179fe78c0127978c4f"),
+    "train-smallthinker-1chip.window": (
+        (1, 16384, 28, 4, 128, SlidingWindow(4096), 0),
+        "8332b7cc033fbbf67497fa801fdee5a1cb22fa30f3fea404c06ee19ce17f0320"),
+    "train-smallthinker-1chip.full": (
+        (1, 16384, 28, 4, 128, True, 0),
+        "96dc2d03780a36589bdcaf470a2166eef087a032d0c9490f8519997f13077f7e"),
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_CALLS))
+def test_the_nine_cells_flash_calls_trace_to_what_they_were(cell):
+    (b, s, h, kv, d, rule, rope), digest = _CELL_CALLS[cell]
+    shapes = [(b, s, h, d), (b, s, kv, d), (b, s, kv, d)]
+    if rope:
+        shapes += [(b, s, h, rope), (b, s, 1, rope)]
+
+    def call(q, k, v, *parts):
+        return flash_attention(
+            q, k, v, causal=rule, use_pallas=True,
+            **dict(zip(("q_rope", "k_rope"), parts))).astype(jnp.float32).sum()
+
+    assert _traced_digest(
+        jax.value_and_grad(call, argnums=tuple(range(len(shapes)))),
+        [jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes]) == digest
+
+
+@functools.lru_cache(maxsize=None)
+def _d64_case(how):
+    """A [2, 320, 8, 64] x [2, 320, 2, 64] causal call at a scale that is
+    not 64 ** -0.5 -> o and the three gradients of sum(o * w)."""
+    ks = jax.random.split(jax.random.PRNGKey(64), 4)
+    q = jax.random.normal(ks[0], (2, 320, 8, 64))
+    k = 4.0 * jax.random.normal(ks[1], (2, 320, 2, 64))
+    v = jax.random.normal(ks[2], (2, 320, 2, 64))
+    w = jax.random.normal(ks[3], q.shape)
+    scale = {"kernels": 1 / 64, "oracle": 1 / 64, "sqrt_d": 1 / 8}[how]
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               block_q=128, block_k=128,
+                               interpret=how == "kernels", use_pallas=False)
+
+    with jax.default_matmul_precision("highest"):
+        return (call(q, k, v),) + jax.grad(
+            lambda *a: jnp.sum(call(*a) * w), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("tensor", range(4), ids=["o", "dq", "dk", "dv"])
+def test_flash_at_a_64_wide_head_with_a_scale_of_its_own(tensor):
+    """The kernels (interpreted) against the oracle, forward and backward;
+    the same oracle at 64 ** -0.5 is another function."""
+    got, want = _d64_case("kernels")[tensor], _d64_case("oracle")[tensor]
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(got / scale, want / scale, atol=2e-5)
+    other = _d64_case("sqrt_d")[tensor]
+    assert float(jnp.abs(other - want).max()) / scale > 1e-2
+
+
+def test_a_64_wide_heads_blocks_are_reckoned_at_128_lanes_in_vmem():
+    """What the v5e compiler refused at S 32,768, D 64: one KV head's K and
+    V whole take a 128-wide head's room; a [rows, 1] column is counted as it
+    is, so the limits the calls at 128-wide heads state did not move."""
+    import importlib
+
+    # (`ray_tpu.ops.flash_attention` the attribute is the function)
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    assert fa._in_vmem((1, 1, 32768, 64)) == fa._in_vmem((1, 1, 32768, 128))
+    assert fa._in_vmem((1, 1, 512, 192)) == 512 * 256
+    assert fa._in_vmem((1, 1, 512, 1)) == 512
+    grads = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, scale=1 / 64, use_pallas=True).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(
+        jax.ShapeDtypeStruct((1, 32768, 32, 64), jnp.bfloat16),
+        *[jax.ShapeDtypeStruct((1, 32768, 8, 64), jnp.bfloat16)] * 2)
+    limits = [int(x) for x in re.findall(r"vmem_limit_bytes=(\d+)",
+                                         str(grads))]
+    assert len(limits) == 3 and min(limits) > 48 * 2**20
