@@ -273,7 +273,8 @@ def forward_hidden(params, tokens, config: GraniteHybridConfig, mesh=None,
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
                                     mesh=mesh, rules=rules)
-    x = llama._scaled(table[tokens].astype(c.dtype), c.embedding_multiplier)
+    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = llama._scaled(x, c.embedding_multiplier)
     x = _residual(x, mesh, rules)
     body = {kind: mla_moe._checkpointed(
         partial(_layer, positions=positions, config=c, mesh=mesh,

@@ -378,7 +378,8 @@ def forward_hidden(params, tokens, config: HybridMoeConfig, mesh=None,
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
                                     mesh=mesh, rules=rules)
-    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
+    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = _residual(x, mesh, rules)
     body = lambda mla, dense=False: _checkpointed(partial(  # noqa: E731
         _layer, positions=positions, config=c, mesh=mesh, rules=rules,
         mla=mla, dense=dense), c)

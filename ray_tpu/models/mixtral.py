@@ -158,7 +158,8 @@ def hidden_states(params, tokens, config: MixtralConfig, mesh=None,
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     # the table's embed dim in the activation layout: llama.forward_hidden
     table = lc(params["embed"], ("vocab", "act_embed"))
-    x = lc(table[tokens].astype(c.dtype), ("batch", "seq", "act_embed"))
+    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = lc(x, ("batch", "seq", "act_embed"))
 
     def layer_fn(x, layer_p):
         x = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules, mask)

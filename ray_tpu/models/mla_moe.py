@@ -468,7 +468,8 @@ def forward_hidden(params, tokens, config: MlaMoeConfig, mesh=None,
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     table = lc(params["embed"], ("vocab", "act_embed"))
-    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
+    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = _residual(x, mesh, rules)
     kw = dict(positions=positions, config=c, mesh=mesh, rules=rules)
     dense = _checkpointed(partial(_dense_layer, **kw), c)
     x, _ = jax.lax.scan(lambda x, p: (dense(x, p), None), x, params["dense"])
@@ -488,7 +489,8 @@ def mtp_hidden(params, hidden, next_tokens, config: MlaMoeConfig, mesh=None,
     b, s = next_tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     with jax.named_scope("mtp.block"):
-        emb = params["embed"][next_tokens].astype(c.dtype)
+        emb = llama.embed_rows(params["embed"], next_tokens,
+                               mesh).astype(c.dtype)
         x = jnp.concatenate([_rms_norm(emb, p["enorm"], c.norm_eps),
                              _rms_norm(hidden, p["hnorm"], c.norm_eps)],
                             axis=-1) @ p["eh_proj"]

@@ -454,7 +454,8 @@ def forward_hidden(params, tokens, config: NemotronHConfig, mesh=None,
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
                                     mesh=mesh, rules=rules)
-    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
+    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = _residual(x, mesh, rules)
     body = _bodies(c, positions, mesh, rules)
 
     def pair(x, p):
@@ -494,7 +495,8 @@ def mtp_hidden(params, hidden, next_tokens, config: NemotronHConfig,
     b, s = next_tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     with jax.named_scope("mtp.block"):
-        emb = params["embed"][next_tokens].astype(c.dtype)
+        emb = llama.embed_rows(params["embed"], next_tokens,
+                               mesh).astype(c.dtype)
         x = jnp.concatenate([_rms_norm(emb, p["enorm"], c.norm_eps),
                              _rms_norm(hidden, p["hnorm"], c.norm_eps)],
                             axis=-1) @ p["eh_proj"]

@@ -20,7 +20,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _remat_policy, _rms_norm, _rope
+from ray_tpu.models.llama import _remat_policy, _rms_norm, _rope, embed_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,7 +165,7 @@ def forward_encoder(params, src_tokens, config: T5Config):
     c = config
     mask = src_tokens != c.pad_id
     bias = _pad_bias(mask)
-    x = params["embed"].astype(c.dtype)[src_tokens]
+    x = embed_rows(params["embed"].astype(c.dtype), src_tokens)
     positions = jnp.arange(src_tokens.shape[1])[None, :]
 
     def layer_fn(x, p):
@@ -192,7 +192,7 @@ def forward_decoder(params, enc_hidden, src_mask, tgt_tokens,
     causal = jnp.where(
         jnp.tril(jnp.ones((T, T), bool)), 0.0, -1e9)[None, None, :, :]
     cross_bias = _pad_bias(src_mask)
-    x = params["embed"].astype(c.dtype)[tgt_tokens]
+    x = embed_rows(params["embed"].astype(c.dtype), tgt_tokens)
 
     def layer_fn(x, p):
         h = _rms_norm(x, p["ln1"], c.norm_eps)

@@ -448,7 +448,8 @@ def forward_hidden(params, tokens, config: WindowMoeConfig, mesh=None,
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
                                     mesh=mesh, rules=rules)
-    x = _residual(table[tokens].astype(c.dtype), mesh, rules)
+    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = _residual(x, mesh, rules)
     body = lambda attn, mlp: mla_moe._checkpointed(partial(  # noqa: E731
         _layer, positions=positions, config=c, mesh=mesh, rules=rules,
         attn=attn, mlp=mlp), c)

@@ -23,6 +23,18 @@ accumulated in float32 is that row, so the result is the float32 sum of a
 token's rows rounded once, as the gather form's; elsewhere, and for a
 dtype the MXU would round, the gather form stands (PERF.md section 6,
 PR 35, has both forms' times).
+
+`sum_rows_by_index(rows [T, D], index [T], n)` is the same sum for rows that
+are ALL live and carry their destination: row i of its [n, D] result is the
+sum of the rows whose index is i, zero where none is. It is the gradient of
+a gather of rows, `table[index]`, by the table (`models/llama.embed_rows`):
+XLA's scatter-add puts one row after the other into a zero table (1 us a
+row of 2,560 on the v5e, 0.12-0.34 us a row of 2,048 or 4,096: 2.2 to 9.1
+times the sorted sum's time at the ten cells' shapes), and a bf16 one rounds
+after every row (PERF.md section 6, PR 56, has both forms' times; which
+widths take which form: `sums_by_index_in_order`). With no dead row to
+skip, the rows are gathered whole: the loop over row tiles carries buffers
+of its own, which a step at the edge of HBM has no room for.
 """
 
 from __future__ import annotations
@@ -45,18 +57,21 @@ _ROW_TILE, _COLUMN_TILE = 256, 2048
 
 @partial(jax.jit, static_argnames=("t", "interpret"))
 def _sum_in_token_order(rows, token, live, t: int, interpret=False):
-    """-> [T, D] in rows.dtype. `interpret` runs the kernel in the Pallas
-    interpreter (the CPU tests). Jitted as megablox's own entry points are:
-    lowering a step traces each capacity's combine four times (the
-    `custom_vjp`'s primal and its forward rule, twice more on the way back),
-    and the kernel's plan of visits is 70 ms of tracing a time."""
+    """-> [T, D] in rows.dtype. `live` None: every row is live. `interpret`
+    runs the kernel in the Pallas interpreter (the CPU tests). Jitted as
+    megablox's own entry points are: lowering a step traces each capacity's
+    combine four times (the `custom_vjp`'s primal and its forward rule,
+    twice more on the way back), and the kernel's plan of visits is 70 ms of
+    tracing a time."""
     cap, d = rows.shape
     tile = min(_TOKEN_TILE, t)
     n_tiles = -(-t // tile)
     # live rows first, by token; dead rows (token T) last
     token_sorted, perm = jax.lax.sort_key_val(
         token, jnp.arange(cap, dtype=jnp.int32))
-    by_token = take_live_rows(rows, perm, live)
+    # every row live (`live` None): one plain gather moves them, no loop
+    by_token = rows.at[perm].get(mode="promise_in_bounds") if live is None \
+        else take_live_rows(rows, perm, live)
     # rows of each token tile without a scatter, as `sort_held` counts its
     # groups: where each tile's run starts among the sorted tokens
     bounds = jnp.minimum(jnp.arange(n_tiles + 1) * tile, t)
@@ -71,13 +86,43 @@ def _sum_in_token_order(rows, token, live, t: int, interpret=False):
     return sums.reshape(n_tiles * tile, d)[:t]
 
 
+def sums_in_order(dtype) -> bool:
+    """Whether a sum of rows of `dtype` takes the sorted form here: read off
+    the platform and the dtype, as `grouped_matmul` picks its kernels."""
+    return jax.default_backend() == "tpu" and dtype == jnp.bfloat16
+
+
 def sum_rows_by_token(rows, token, slot, live):
     """-> [T, D]: the sum of each token's live rows, accumulated in float32,
-    in rows.dtype. Which form runs is read off the platform and the dtype,
-    as `grouped_matmul` picks its kernels."""
+    in rows.dtype."""
     t = slot.shape[0]
-    if jax.default_backend() == "tpu" and rows.dtype == jnp.bfloat16:
+    if sums_in_order(rows.dtype):
         return _sum_in_token_order(rows, token, live, t)
     picked = jnp.where((token[slot] < t)[..., None],
                        rows[slot].astype(jnp.float32), 0.0)
     return jnp.sum(picked, axis=1).astype(rows.dtype)
+
+
+def sums_by_index_in_order(dtype, width: int) -> bool:
+    """Whether `sum_rows_by_index` takes the sorted form for rows [., width]:
+    where a share's combine does, and XLA's scatter-add pays by the row. On
+    the v5e it adds a row whose width is a power of two, or three times one,
+    in 0.06-0.23 us (1,024, 1,536, 2,048, 3,072, 4,096) and any other tried
+    in 0.5-3.6 us (2,560, 3,584, 4,608, 5,120; 6,144 reads 0.63):
+    `tools/embed_grad_chip_check.py --widths`. Where the scatter is fast the
+    sorted sum is still 2-4 times faster alone, but it is 1-2 ms of a step,
+    and as another program at the end of the backward pass it cost
+    `train-1chip` 11 ms: the compiler kept one stacked weight fewer in fast
+    memory through the layers' loop (PERF.md section 6, PR 56)."""
+    odd = width // (width & -width)
+    return sums_in_order(dtype) and odd > 3
+
+
+def sum_rows_by_index(rows, index, n: int):
+    """-> [n, D] in rows.dtype: the sum of the rows of each index in
+    [0, n); a row whose index is n or more is dropped by either form. Sorted,
+    a float32 sum rounded once; elsewhere the scatter-add, which rounds in
+    rows.dtype after every row."""
+    if sums_by_index_in_order(rows.dtype, rows.shape[1]):
+        return _sum_in_token_order(rows, index, None, n)
+    return jnp.zeros((n, rows.shape[1]), rows.dtype).at[index].add(rows)

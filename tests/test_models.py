@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu._private import device_profiler
 from ray_tpu.models import llama, mixtral, vit
+from ray_tpu.ops import row_sums
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_sharding
 from ray_tpu.train.step import init_train_state, make_train_step
@@ -374,3 +376,87 @@ def test_t5_trains_on_mesh():
     batch = {"src": jax.device_put(src, bs), "tgt": jax.device_put(tgt, bs)}
     state, m = step(state, batch)
     assert np.isfinite(float(m["loss"]))
+
+
+# ------------------------------------------------- the embedding's gradient
+
+# case: (V, the tokens' shape, how they are drawn, the table's dtype, D,
+# the devices of the mesh, whether the table is the head too)
+_BF16, _F32 = jnp.bfloat16, jnp.float32
+_EMBED_CASES = {
+    "every_token_distinct": (512, (512,), "distinct", _BF16, 640, 1, False),
+    "one_token_t_times": (512, (300,), "one", _BF16, 640, 1, False),
+    "ids_absent_from_the_batch": (1024, (64,), "some", _BF16, 640, 1, False),
+    "v_no_multiple_of_256": (300, (4, 96), "some", _BF16, 640, 1, False),
+    "v_below_one_tile": (100, (2, 150), "some", _BF16, 640, 1, False),
+    "tokens_b_s": (512, (4, 128), "some", _BF16, 640, 1, False),
+    "tokens_t": (512, (512,), "some", _BF16, 640, 1, False),
+    "table_is_the_head_too": (300, (2, 64), "some", _BF16, 640, 1, True),
+    "float32_table": (300, (4, 96), "some", _F32, 640, 1, False),
+    "rows_a_power_of_two_wide": (300, (2, 48), "some", _BF16, 1024, 1, False),
+    "mesh_of_two_devices": (512, (4, 128), "some", _BF16, 640, 2, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_EMBED_CASES))
+def test_llama_embed_rows_gradient_is_the_sum_by_token(case, monkeypatch):
+    """`llama.embed_rows` is `table[tokens]` bit for bit, and its d table,
+    where the sorted sum forms it (a TPU's form, here with the platform's
+    test taken out and the kernel in the Pallas interpreter), is the
+    float32 scatter-add of the cotangent's rows rounded ONCE, bit for bit:
+    a repeated id gets the sum of all its rows, an absent one a zero row.
+    A float32 table, rows a power of two wide (XLA's scatter-add is fast
+    there; the other cases' are 5 x 128) and a mesh of more than one device
+    take the scatter-add and equal autodiff's gradient of `table[tokens]`
+    exactly. The cotangents
+    are eighths up to 8, so that a float32 sum of them is exact in any
+    order."""
+    v, shape, draw, dtype, d, devices, tied = _EMBED_CASES[case]
+    monkeypatch.setattr(row_sums, "sums_in_order",
+                        lambda dt: dt == jnp.bfloat16)
+    monkeypatch.setattr(row_sums, "_sum_in_token_order", partial(
+        row_sums._sum_in_token_order, interpret=True))
+    t = int(np.prod(shape))
+    rng = np.random.default_rng(sorted(_EMBED_CASES).index(case))
+    tokens = jnp.asarray({
+        "distinct": lambda: rng.permutation(v)[:t],
+        "one": lambda: np.full(t, 7),
+        "some": lambda: rng.integers(0, v, t),
+    }[draw]().reshape(shape), jnp.int32)
+    table = jnp.asarray(rng.standard_normal((v, d)), dtype)
+    eighths = lambda *s: jnp.asarray(  # noqa: E731
+        rng.integers(-64, 65, s) / 8, jnp.float32)
+    w, h, u = eighths(*shape, d), eighths(8, d), eighths(8, v)
+    mesh = build_mesh(MeshConfig(dp=devices), devices=jax.devices()[:devices])
+
+    def loss(look_up, table):
+        out = jnp.sum(look_up(table).astype(jnp.float32) * w)
+        if tied:  # Granite's head: the same table, transposed
+            out += jnp.sum((h.astype(dtype) @ table.T).astype(jnp.float32) * u)
+        return out
+
+    ours = partial(loss, lambda tb: llama.embed_rows(tb, tokens, mesh))
+    plain = partial(loss, lambda tb: tb[tokens])
+    assert jnp.array_equal(llama.embed_rows(table, tokens, mesh), table[tokens])
+    before = device_profiler.snapshot()["counters"]
+    got = jax.jit(jax.grad(ours))(table)
+    after = device_profiler.snapshot()["counters"]
+    sort = dtype == jnp.bfloat16 and d == 640 and devices == 1
+    assert {k: after[k] - before.get(k, 0) for k in (
+        "embed.grad_rows", "embed.grad_rows_sorted")} == {
+            "embed.grad_rows": t, "embed.grad_rows_sorted": t * sort}
+    autodiff = jax.jit(jax.grad(plain))(table)
+    assert got.dtype == autodiff.dtype == dtype and got.shape == (v, d)
+    if not sort:
+        assert jnp.array_equal(got, autodiff)
+        return
+    want = jnp.zeros((v, d), jnp.float32).at[tokens].add(w).astype(dtype)
+    absent = np.setdiff1d(np.arange(v), np.asarray(tokens))
+    if draw != "distinct":
+        assert absent.size and not np.asarray(want)[absent].any()
+    if tied:
+        want = want + jax.grad(lambda tb: loss(
+            lambda tb: jnp.zeros(shape + (d,), dtype), tb))(table)
+    assert jnp.array_equal(got, want)
+    if draw == "one":  # the scatter-add rounds after every row it adds
+        assert not jnp.array_equal(autodiff, want)

@@ -629,3 +629,55 @@ def test_the_heads_chunks_are_counted_where_their_gradient_is_formed():
             "ce_fused_chunk_share.json")) as f:
         spec = json.load(f)
     assert (spec["over"], spec["under"]) == (["ce.chunks_fused"], ["ce.chunks"])
+
+
+def test_the_embeddings_gradient_rows_are_counted_by_the_form_that_sums_them(
+        monkeypatch):
+    """`llama.embed_rows` counts, once per trace of its backward rule, the
+    T rows of the cotangent under `embed.grad_rows` and, where the sorted
+    sum forms d table, under `embed.grad_rows_sorted` too (0 is added where
+    the scatter-add stands, so both names are there once either is); a
+    trace of the lookup alone counts nothing. The two names are what
+    `benchmarks/metrics/embed_grad_sorted_row_share.json` divides:
+    `counter_readers.ratio` reads 100 where every row is summed sorted, 0
+    where none is, and leaves the metric out of a program with neither."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import counter_readers
+    from ray_tpu.models import llama
+    from ray_tpu.ops import row_sums
+
+    names = ("embed.grad_rows", "embed.grad_rows_sorted")
+    table = jax.ShapeDtypeStruct((300, 640), jnp.bfloat16)  # 5 x 128 wide
+    tokens = jnp.zeros((2, 24), jnp.int32)
+
+    def lookup(tb):
+        return llama.embed_rows(tb, tokens).astype(jnp.float32).sum()
+
+    def grew(fn):
+        before = dp.snapshot()["counters"]
+        jax.make_jaxpr(fn)(table)
+        after = dp.snapshot()["counters"]
+        return {name: after.get(name, 0) - before.get(name, 0)
+                for name in names}
+
+    assert grew(lookup) == dict.fromkeys(names, 0)
+    assert grew(jax.grad(lookup)) == {names[0]: 48, names[1]: 0}  # no TPU
+    monkeypatch.setattr(row_sums, "sums_in_order",
+                        lambda dtype: dtype == jnp.bfloat16)
+    assert grew(jax.grad(lookup)) == {names[0]: 48, names[1]: 48}
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "metrics",
+            "embed_grad_sorted_row_share.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter_readers.ratio"
+    assert (spec["over"], spec["under"]) == ([names[1]], [names[0]])
+    read = lambda counters: counter_readers.ratio(  # noqa: E731
+        spec, {"counters": counters}, {})
+    assert read({names[0]: 48, names[1]: 48, "ce.chunks": 4}) == 100
+    assert read({names[0]: 48, names[1]: 0}) == 0
+    assert read({"ce.chunks": 4}) is None

@@ -337,6 +337,39 @@ out["tp_head_loop_collectives"] = sum(
         r" (all-gather|all-reduce|reduce-scatter|all-to-all"
         r"|collective-permute-start)\(|calls=%all-reduce-scatter", ln))
 
+# the embedding table's gradient in that step: the scatter-add autodiff
+# makes of the lookup (a mesh of four devices), and the table's all-gathers
+out["tp_table_scatters"] = len(pair_scatters(hlo, 32768 * 4096))
+out["tp_table_all_gathers"] = len(re.findall(
+    r"= bf16\[32768,4096\]\S* all-gather\(", hlo))
+out["tp_sum_kernels"] = len(re.findall(r"%tgmm[\w.]* = ", hlo))
+
+# the embedding lookup's gradient alone at train-smallthinker-1chip's shape
+# (T 16,384 rows, V 37,984, D 2,560) on one chip: off the chip the backward
+# rule keeps autodiff's scatter-add (`row_sums.sums_in_order` follows
+# jax.default_backend(), cpu here), with the platform's test taken out it
+# is the sorted sum; and at train-1chip's (8,192 rows, V 32,768, D 4,096: a
+# power of two), where the scatter-add stands on the chip too
+on_tpu = lambda dtype: dtype == bf16
+for name, sorts, (n_rows, vocab, width) in (
+        ("scatter", row_sums.sums_in_order, (16384, 37984, 2560)),
+        ("sorted", on_tpu, (16384, 37984, 2560)),
+        ("whole_lanes", on_tpu, (8192, 32768, 4096))):
+    kept, row_sums.sums_in_order = row_sums.sums_in_order, sorts
+    hlo = jax.jit(jax.grad(lambda table, tokens, w: jnp.sum(
+        llama.embed_rows(table, tokens).astype(jnp.float32) * w))).lower(
+            spec((vocab, width), bf16), spec((1, n_rows), jnp.int32),
+            spec((1, n_rows, width), jnp.float32)).compile().as_text()
+    row_sums.sums_in_order = kept
+    out["embed_grad_" + name] = {
+        "table_scatters": len(pair_scatters(hlo, vocab * width)),
+        "kernels": [m[1] for ln in hlo.splitlines() if (m := re.match(
+            r"\s*(?:ROOT )?%(\w+)[\w.]* = (\w+\[[\d,]*\])\S* custom-call\(.*"
+            r'custom_call_target="tpu_custom_call"', ln))],
+        "row_gathers": len(re.findall(
+            r"= bf16\[%d,%d\]\S* gather\(" % (n_rows, width), hlo)),
+        "sorts": len(re.findall(r" sort\(", hlo))}
+
 # the head alone at train-smallthinker-1chip's widths (S 16,384, D 2,560,
 # V 37,984, chunks of 1,024), value and gradient: the matrix products in
 # its loops, a fusion's own among them, by the shape they put out
@@ -876,6 +909,41 @@ def test_the_head_forms_its_gradient_with_its_logits_for_v5e(compiled):
         "bf16[1024,2560]", "bf16[1024,37984]", "bf16[2560,37984]"]
     assert compiled["tp_head_loops"] == 1
     assert 0 < compiled["tp_head_loop_collectives"] <= 11
+
+
+def test_the_embeddings_gradient_is_a_sorted_sum_as_compiled_for_v5e(compiled):
+    """`llama.embed_rows`' gradient by the table at
+    train-smallthinker-1chip's shape (16,384 rows of 2,560 into 37,984), as
+    the v5e's compiler leaves it. With the sorted sum: ONE sort of the ids,
+    ONE gather of the 16,384 rows into token order, ONE Pallas call, `tgmm`,
+    and no scatter whose operand or result is `[37984, 2560]`. The form it
+    replaces on one chip, and which stands everywhere else, holds that
+    scatter and no kernel; it is the parent's text:
+
+        ROOT %scatter-add.1 = bf16[37984,2560] scatter(%param_0.2,
+            %transpose.6, %transpose.7), update_window_dims={1}, ...
+        ROOT %fusion = bf16[37984,2560] fusion(%get-tuple-element,
+            %constant.3, %convert_bitcast_fusion, %broadcast_clamp_fusion),
+            kind=kCustom, calls=%fused_computation.2
+
+    (`fusion_bf16_37984_2560` in the cell's trace: 17.8 ms a step; XLA sorts
+    the ids and gathers the updates itself, then adds row after row). At
+    train-1chip's shape, rows of 4,096, a power of two wide, that adding is
+    fast and the scatter-add stands on the chip as well.
+
+    train-4chip's step over fsdp 2 x tp 2 keeps the scatter-add, into the
+    whole `[32768, 4096]` table, and gathers the table once, for the forward
+    lookup, as the parent does: its optimised text less the instructions'
+    numbers is the parent's, line for line (PR 56: 4,375 lines)."""
+    assert compiled["embed_grad_sorted"] == {
+        "table_scatters": 0, "kernels": ["tgmm"], "row_gathers": 1,
+        "sorts": 1}
+    for kept in ("embed_grad_scatter", "embed_grad_whole_lanes"):
+        assert compiled[kept]["table_scatters"] == 1
+        assert compiled[kept]["kernels"] == []
+    assert compiled["tp_table_scatters"] == 1
+    assert compiled["tp_table_all_gathers"] == 1
+    assert compiled["tp_sum_kernels"] == 0
 
 
 def test_moe_layer_backward_as_compiled_for_v5e(compiled):
