@@ -445,12 +445,18 @@ def _mlp_sublayer(x, params, config: LlamaConfig, mesh=None,
             and c.d_ff % mesh.shape["tp"] == 0:
         return _residual(x + _scaled(_mlp_ring(h, params, mesh), branch),
                          mesh, rules)
+    x = x + _scaled(_swiglu(h, params, lc), branch)
+    return _residual(x, mesh, rules)
+
+
+def _swiglu(h, params, lc):
+    """W_down (silu(W_gate h) * W_up h), h [B, S, D] the normed input; `lc`
+    constrains the gate to its logical layout."""
     gate = jnp.einsum("bsd,df->bsf", h, params["w_gate"])
     up = jnp.einsum("bsd,df->bsf", h, params["w_up"])
     gate = lc(gate, ("batch", "seq", "act_mlp"))
     ff = jax.nn.silu(gate) * up
-    x = x + _scaled(jnp.einsum("bsf,fd->bsd", ff, params["w_down"]), branch)
-    return _residual(x, mesh, rules)
+    return jnp.einsum("bsf,fd->bsd", ff, params["w_down"])
 
 
 def _layer(x, params, positions, config: LlamaConfig, mesh=None,
@@ -543,12 +549,19 @@ def forward(params, tokens, config: LlamaConfig, mesh=None,
 
 
 def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256,
-               denominator=None):
+               denominator=None, groups: int = 1):
     """Cross-entropy without materializing full [B,S,V] fp32 logits: the
     sequence is scanned in chunks of `chunk` positions (what is left over
     takes the same body after the scan). `mask` [B,S] weights each
     position's term (0/1, or any float32 weight); the sum is divided by the
     mask's sum, or by `denominator` when the weights are no count.
+
+    `groups` > 1: `lm_head` [D, groups x V] is several heads side by side
+    (prediction heads over one vocabulary), the softmax runs over each
+    V-wide group of its columns, and `targets` and `mask` are [B, S, groups],
+    each group's own target and weight: ONE matmul a chunk against the whole
+    head, not one a group. A term's weight is `mask` over the denominator,
+    whichever group it is in.
 
     Differentiated, a chunk's logits are formed ONCE: the forward pass
     takes, beside each chunk's loss, the gradient of the whole loss by that
@@ -564,12 +577,19 @@ def chunked_ce(hidden, lm_head, targets, mask=None, chunk: int = 256,
 
     Not differentiated (an evaluation, a reference check), only the loss is
     formed. Counted as traced: `ce.chunks`, and of them `ce.chunks_fused`,
-    the chunks whose gradient is formed with their logits."""
+    the chunks whose gradient is formed with their logits; `ce.groups`, the
+    groups of a call's head."""
     b, s, _ = hidden.shape
+    if targets.shape != ((b, s) if groups == 1 else (b, s, groups)) \
+            or lm_head.shape[1] % groups:
+        raise ValueError(
+            f"targets {targets.shape} and a head {lm_head.shape} in "
+            f"{groups} groups for hidden states {hidden.shape}")
     if mask is None:
-        mask = jnp.ones((b, s), jnp.float32)
+        mask = jnp.ones(targets.shape, jnp.float32)
     if denominator is None:
         denominator = jnp.maximum(jnp.sum(mask), 1.0)
+    device_profiler.count("ce.groups", groups)
     return _chunked_ce(hidden, lm_head, targets, mask,
                        jnp.asarray(denominator, jnp.float32), chunk)
 
@@ -592,11 +612,15 @@ def _ce_chunks(chunk, hidden, targets, mask, fused):
 
 
 def _ce_chunk(lm_head, h_ck, t_ck, m_ck):
-    """A chunk's float32 log-probabilities [B, chunk, V], where its targets
-    stand in them, and its weighted sum of -log p(target). The target's term
-    is picked by comparison, not gathered: a gather has the compiler write
-    the float32 [B, chunk, V] array to HBM to read one element a row."""
+    """A chunk's float32 log-probabilities [B, chunk, V] (in groups, `t_ck`
+    [B, chunk, groups]: [B, chunk, groups, V / groups], each group's own
+    softmax), where its targets stand in them, and its weighted sum of
+    -log p(target). The target's term is picked by comparison, not gathered:
+    a gather has the compiler write the float32 [B, chunk, V] array to HBM to
+    read one element a row."""
     logits = (h_ck @ lm_head).astype(jnp.float32)
+    if t_ck.ndim == 3:
+        logits = logits.reshape(*t_ck.shape, -1)
     logp = jax.nn.log_softmax(logits, axis=-1)
     hit = t_ck[..., None] == jnp.arange(logp.shape[-1])
     nll = -jnp.sum(jnp.where(hit, logp, 0.0), axis=-1)
@@ -622,6 +646,8 @@ def _chunked_ce_fwd(hidden, lm_head, targets, mask, denominator, chunk):
         logp, hit, nll = _ce_chunk(lm_head, h_ck, t_ck, m_ck)
         dlogits = ((jnp.exp(logp) - hit) * (m_ck / denominator)[..., None]
                    ).astype(jnp.result_type(h_ck, lm_head))
+        if t_ck.ndim == 3:  # the groups side by side again
+            dlogits = dlogits.reshape(*h_ck.shape[:2], -1)
         dh_ck = jnp.einsum("bcv,dv->bcd", dlogits, lm_head)
         dw = dw + jnp.einsum("bcd,bcv->dv", h_ck, dlogits).astype(dw.dtype)
         return (total + nll, dw), dh_ck.astype(h_ck.dtype)

@@ -153,6 +153,83 @@ class SlidingWindow(NamedTuple):
                    for r in range(s_q))
 
 
+class EvaWindows(NamedTuple):
+    """EVA attention (Zheng et al., ICLR 2023, as EvaByte ships it) over a
+    key axis that is NOT the query axis: the keys are the concatenation
+    [length // chunk chunk summaries ; length bytes] and the `length`
+    queries stand, as every call's do, at the keys' END, so row n's position
+    is its own byte key. Byte n (window n // window) sees, in ONE softmax,
+    the bytes of its own window up to itself, and the summaries of every
+    chunk of every EARLIER window; never a summary of its own window, so no
+    summary it sees holds a byte later than itself. `length` is whole chunks
+    and `window` whole chunks; the last window may be partial (its summaries
+    are keys no query sees). Keeps, at length 32,768, window 2,048, chunk
+    16, 33,570,816 local + 31,457,280 summary scores a (batch, head) where
+    causal attention keeps 536,887,296."""
+    length: int
+    window: int
+    chunk: int
+    scope = "eva.attend"
+
+    @property
+    def summaries(self):
+        return self.length // self.chunk
+
+    def _over(self, pos, n):
+        """pos // n, a shift where n is a power of two (the kernels')."""
+        if n & (n - 1) == 0:
+            return pos >> (n.bit_length() - 1)
+        return pos // n
+
+    def keep(self, q_pos, k_pos):
+        ns = self.summaries
+        q_win = self._over(q_pos - ns, self.window)
+        local = (k_pos >= ns) & (k_pos <= q_pos) \
+            & (self._over(k_pos - ns, self.window) == q_win)
+        earlier = self._over(k_pos, self.window // self.chunk) < q_win
+        return local | ((k_pos < ns) & earlier)
+
+    def tile(self, q0, nq, k0, nk):
+        ns, w = self.summaries, self.window
+        n0, n1 = max(q0 - ns, 0), min(q0 + nq - ns, self.length) - 1
+        k1 = min(k0 + nk, ns + self.length) - 1
+        k0 = max(k0, 0)
+        if n0 > n1 or k0 > k1:
+            return False, False
+        some, every = False, True
+        if k0 < ns:  # summaries c0..c1: kept where their window is earlier
+            per = w // self.chunk
+            c0, c1 = k0, min(k1, ns - 1)
+            some |= c0 // per < n1 // w
+            every &= c1 // per < n0 // w
+        if k1 >= ns:  # bytes j0..j1: the query's own window, up to itself
+            j0, j1 = max(k0 - ns, 0), k1 - ns
+            some |= any(
+                min(n1, win * w + w - 1) >= max(j0, win * w)
+                for win in range(max(n0 // w, j0 // w),
+                                 min(n1 // w, j1 // w) + 1))
+            every &= n0 // w == n1 // w == j0 // w == j1 // w and j1 <= n0
+        return some, some and every
+
+    def kept(self, s_q, s_k):
+        """-> (local, summary) scores kept a (batch, head)."""
+        ns, w = self.summaries, self.window
+        if s_q != self.length or s_k != ns + self.length \
+                or self.length % self.chunk or w % self.chunk:
+            raise ValueError(
+                f"{self} is a rule for {self.length} queries over "
+                f"{ns} summaries + {self.length} bytes of whole chunks; "
+                f"got {s_q} x {s_k}")
+        whole, rest = divmod(self.length, w)
+        local = whole * (w * (w + 1) // 2) + rest * (rest + 1) // 2
+        summary = (w // self.chunk) * (
+            w * (whole * (whole - 1) // 2) + rest * whole)
+        return local, summary
+
+    def needed(self, s_q, s_k):
+        return sum(self.kept(s_q, s_k))
+
+
 def _rule(causal, mask=None):
     """The rule a call runs under: `mask` if given, else what `causal`
     (a bool, or a rule already) stands for."""
@@ -287,6 +364,13 @@ class KernelSchedule(NamedTuple):
 # 2.80 -> 2.66), and a forward row of 8 (S 4096) went from 1.93 to 1.96
 # while its dq went from 2.59 to 2.45.
 _STATIC_BUDGET = {"fwd": (max, 8), "dkv": (sum, 28)}
+# And no plan of more steps IN ALL than the largest measured to gain is
+# unrolled, the forward's neither: every grid row's branch is code of the one
+# kernel, and `EvaWindows` at S 32,768 (64 rows of at most 8 steps, 304 in
+# all: 54 MB of generated code) ran the forward at 269 ms and dq at 330 where
+# the loop plans take 17.0 and 17.6 (PERF.md §6, PR 57). 36 is causal S 4096
+# (above); between 36 and 304 nothing is measured.
+_STATIC_STEPS = 36
 
 
 @functools.lru_cache(maxsize=256)
@@ -370,11 +454,16 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
             kept.append(steps)
         skipped = n_rows * n_steps - sum(map(len, kept))
         measure, budget = _STATIC_BUDGET[kernel]
+
+        def unrolled(rows):
+            return measure(map(len, rows)) <= budget \
+                and sum(map(len, rows)) <= _STATIC_STEPS
+
         banded = [[(steps[0][0], b)] if (b := band(i, steps)) else steps
                   for i, steps in enumerate(kept)]
-        if measure(map(len, banded)) <= budget:
+        if unrolled(banded):
             kept = banded  # a band step is straight-line code
-        static = measure(map(len, kept)) <= budget
+        static = unrolled(kept)
         if not static:
             any_masked = any(m for steps in kept for _, m in steps)
             kept = [[(j, any_masked) for j, _ in steps] for steps in kept]
@@ -1331,8 +1420,9 @@ def flash_attention(
 
     `mask`: a static rule for which (query, key) pairs are kept, in place
     of `causal` (which is the rule `CAUSAL`): e.g. `BlockDiffusion(length,
-    block)` or `SlidingWindow(window)`. The kernels skip the tiles it keeps
-    nothing of, run those it keeps whole with no mask, and apply its
+    block)`, `SlidingWindow(window)`, or `EvaWindows(length, window, chunk)`
+    over MORE keys than queries (`ops/eva.py`). The kernels skip the tiles it
+    keeps nothing of, run those it keeps whole with no mask, and apply its
     predicate in the others, on a tile's diagonal sub-tiles alone where
     they hold all it keeps, on the sub-tiles on the kept side of a tile's
     sub-tile diagonal, or on a row's band of sub-tiles in one step

@@ -460,3 +460,142 @@ def test_llama_embed_rows_gradient_is_the_sum_by_token(case, monkeypatch):
     assert jnp.array_equal(got, want)
     if draw == "one":  # the scatter-add rounds after every row it adds
         assert not jnp.array_equal(autodiff, want)
+
+
+# --------------------------------------------------------------------------
+# `chunked_ce(groups=)`: several heads side by side over one vocabulary
+# --------------------------------------------------------------------------
+
+def _grouped_operands(seq=40, groups=3, vocab=10, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(57), 4)
+    hidden = jax.random.normal(keys[0], (2, seq, 16)).astype(dtype)
+    lm_head = (0.3 * jax.random.normal(keys[1], (16, groups * vocab))
+               ).astype(dtype)
+    targets = jax.random.randint(keys[2], (2, seq, groups), 0, vocab)
+    weights = jax.random.uniform(keys[3], (2, seq, groups))
+    return hidden, lm_head, targets, weights
+
+
+def _plain_grouped_ce(hidden, lm_head, targets, weights, denominator):
+    """The plain grouped log-softmax: each V-wide group of the head's
+    columns its own softmax against its own target and weight."""
+    b, s, groups = targets.shape
+    logp = jax.nn.log_softmax(
+        (hidden @ lm_head).astype(jnp.float32).reshape(b, s, groups, -1), -1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+    return jnp.sum(nll * weights) / denominator
+
+
+@pytest.mark.parametrize("case", [
+    "chunk_divides_s", "chunk_does_not_divide_s", "one_chunk",
+    "no_mask", "bf16_operands"])
+def test_llama_chunked_ce_in_groups_matches_the_plain_grouped_softmax(case):
+    """Value, d / d hidden and d / d lm_head, ONE matmul a chunk against the
+    whole head (the dots' shapes say so)."""
+    dtype = jnp.bfloat16 if case == "bf16_operands" else jnp.float32
+    hidden, lm_head, targets, weights = _grouped_operands(dtype=dtype)
+    chunk = {"chunk_does_not_divide_s": 16, "one_chunk": 40}.get(case, 8)
+    if case == "no_mask":
+        weights, denominator = None, None
+        plain_w, plain_d = jnp.ones(targets.shape), float(targets.size)
+    else:
+        denominator = plain_d = 7.0
+        plain_w = weights
+    want, want_g = jax.value_and_grad(
+        lambda h, w: _plain_grouped_ce(h, w, targets, plain_w, plain_d),
+        argnums=(0, 1))(hidden, lm_head)
+    loss = lambda h, w: llama.chunked_ce(  # noqa: E731
+        h, w, targets, weights, chunk=chunk, denominator=denominator,
+        groups=3)
+    got, got_g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
+        hidden, lm_head)
+    assert float(jax.jit(loss)(hidden, lm_head)) == pytest.approx(
+        float(want), rel=1e-5)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    rel = 2e-2 if case == "bf16_operands" else 1e-5
+    for g, w in zip(got_g, want_g):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        w = np.asarray(w.astype(jnp.float32))
+        np.testing.assert_allclose(np.asarray(g.astype(jnp.float32)), w,
+                                   rtol=0, atol=rel * np.abs(w).max())
+    if case == "chunk_divides_s":
+        assert sorted(_dots(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+            hidden, lm_head).jaxpr)) == sorted([
+                (2, 8, 30), (2, 8, 16), (16, 30)])
+
+
+def test_llama_chunked_ce_refuses_targets_that_are_not_its_groups():
+    hidden, lm_head, targets, _ = _grouped_operands()
+    with pytest.raises(ValueError, match="groups"):
+        llama.chunked_ce(hidden, lm_head, targets, chunk=8)
+    with pytest.raises(ValueError, match="groups"):
+        llama.chunked_ce(hidden, lm_head, targets[..., 0], chunk=8, groups=3)
+    with pytest.raises(ValueError, match="groups"):
+        llama.chunked_ce(hidden, lm_head[:, :29], targets, chunk=8, groups=3)
+
+
+# sha256 of `chunked_ce`, value and gradients as traced, at each of the ten
+# cells' head shapes (batch, S, d_model, vocabulary rows; train-4chip's a
+# tp 2 shard's; chunks of 1,024): PR 56's text, which `groups=` (PR 57) must
+# leave as it was
+_CE_CALLS = {
+    "train-1chip": ((4, 2048, 4096, 32768),
+        "31efe6649826183d4edb1dcf3221dbe328c46476d5fd348eb050c8aaab1f70bd"),
+    "train-4chip.shard": ((8, 2048, 4096, 16384),
+        "c10aa7325b9c8cd1effc6b3f965c2f474686478582d664b546505f3d58698e7e"),
+    "train-olmoe-1chip": ((4, 2048, 2048, 50304),
+        "06c0184d6a5d17ed34f03758101365d2fa984719bd6b44fadec7c819f0c2a582"),
+    "train-joyai-1chip": ((4, 2048, 2048, 16160),
+        "d193a6592cf501ef82968470a2fb9d3fe0d3b41fa3571698b2b378517581fe8a"),
+    "train-sdar-1chip": ((4, 2048, 2048, 18992),
+        "9f5d9ea5aae14e5be02ee3a6d633744a11e65c963a9ee1bb0179933046f18bff"),
+    "train-ling-1chip": ((4, 2048, 2560, 19648),
+        "e4fe00c75b10a9feb299300ffc2f1d030cb7b280feed205bdd98504586d7367d"),
+    "train-nemotron3-1chip": ((2, 2048, 4096, 16384),
+        "7a5e4189e2936f9cb6ba65c82d00011bd67d8e8b31125395c5435494a506f41b"),
+    "train-laguna-1chip": ((1, 8192, 2048, 12544),
+        "40060c483f12a91fd4626eb0dac02163c662773778f65857acd0edbf69e5e229"),
+    "train-smallthinker-1chip": ((1, 16384, 2560, 37984),
+        "78f592d959aba1dae6ffb12d8bd802799308792ff2f824f9eb6b11f4a14da730"),
+    "train-granite4-1chip": ((1, 32768, 2048, 100352),
+        "2e9dd25c99e26e5a349d7e68837b72c45c13ef639942f817949f58aa931a43f6"),
+}
+_CE_WITH_WEIGHTS = \
+    "7f63f6cf56bbe856bb690678b99c1c42b429d9bb7a11dd417562a5f1c8ef448b"
+_CE_UNDIFFERENTIATED = \
+    "c1903270e7a1ba56831e320b0bf36a93547d83f8d57c0069be886359726d87a5"
+
+
+def _traced_digest(fn, shapes):
+    import hashlib
+    import re
+
+    traced = jax.make_jaxpr(fn)(*shapes)
+    return hashlib.sha256(re.sub(r" at 0x[0-9a-f]+", "", str(traced))
+                          .encode()).hexdigest()
+
+
+@pytest.mark.parametrize("cell", list(_CE_CALLS))
+def test_the_ten_cells_chunked_ce_traces_to_what_it_was(cell):
+    (b, s, d, v), digest = _CE_CALLS[cell]
+    shaped = jax.ShapeDtypeStruct
+    assert _traced_digest(
+        jax.value_and_grad(
+            lambda h, w, t: llama.chunked_ce(h, w, t, chunk=1024),
+            argnums=(0, 1)),
+        (shaped((b, s, d), jnp.bfloat16), shaped((d, v), jnp.bfloat16),
+         shaped((b, s), jnp.int32))) == digest
+
+
+def test_chunked_ce_with_weights_and_undifferentiated_trace_as_they_did():
+    shaped = jax.ShapeDtypeStruct
+    shapes = (shaped((4, 2048, 2048), jnp.bfloat16),
+              shaped((2048, 18992), jnp.bfloat16),
+              shaped((4, 2048), jnp.int32), shaped((4, 2048), jnp.float32))
+    assert _traced_digest(jax.value_and_grad(
+        lambda h, w, t, m: llama.chunked_ce(
+            h, w, t, m, chunk=1024, denominator=8192.0),
+        argnums=(0, 1)), shapes) == _CE_WITH_WEIGHTS
+    assert _traced_digest(
+        lambda h, w, t, m: llama.chunked_ce(h, w, t, m, chunk=1024),
+        shapes) == _CE_UNDIFFERENTIATED

@@ -9,8 +9,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import eva
 from ray_tpu.ops.flash_attention import (
-    _STATIC_BUDGET, _SUB, CAUSAL, DIAGONAL, Band, BlockDiffusion,
+    _STATIC_BUDGET, _STATIC_STEPS, _SUB, CAUSAL, DIAGONAL, Band,
+    BlockDiffusion, EvaWindows,
     SlidingWindow, Triangle, _clamp_block, _reference_attention,
     block_schedule, flash_attention)
 
@@ -385,7 +387,9 @@ def test_block_schedule_against_the_mask(case):
         # unrolled by the longest row (forward; dq runs its plan) or by the
         # plan's steps in all (dk/dv)
         measure, budget = _STATIC_BUDGET[name]
-        assert plan.static == (measure(len(r) for r in plan.rows) <= budget)
+        assert plan.static == (
+            measure(len(r) for r in plan.rows) <= budget
+            and sum(len(r) for r in plan.rows) <= _STATIC_STEPS)
         assert plan.steps_skipped == len(plan.rows) * (
             (cols if name == "fwd" else rows) // plan.width) \
             - len(plan.tiles) - stood_for
@@ -644,8 +648,11 @@ def test_dkv_is_unrolled_under_a_budget_of_the_plans_total_steps(case):
         assert dkv.steps_unmasked == 0 == dkv.steps_diagonal == dkv.steps_band
         assert dkv.steps_triangle == 0
         assert dkv.table[-1, 0] == len(dkv.rows[-1])
-    # forward and dq keep their cap on the longest row
-    assert plans["fwd"].static == (max(map(len, plans["fwd"].rows)) <= 8)
+    # forward and dq keep their cap on the longest row, under the cap on a
+    # plan's steps in all (block diffusion at L 3,072: rows of 8, 48 steps)
+    assert plans["fwd"].static == (
+        max(map(len, plans["fwd"].rows)) <= 8
+        and len(plans["fwd"].tiles) <= _STATIC_STEPS)
 
 
 # (length, block, tile[, q heads, kv heads, diagonal steps a plan]): the three
@@ -901,7 +908,9 @@ def test_flash_attention_under_the_sliding_window_rule(case):
         tiles = window // tile   # a row: a trailing triangle, whole, diagonal
         assert max(map(len, plans["fwd"].rows)) == tiles + 1
         assert not plans["dkv"].static
-        assert plans["fwd"].static == (tiles + 1 <= _STATIC_BUDGET["fwd"][1])
+        assert plans["fwd"].static == (
+            tiles + 1 <= _STATIC_BUDGET["fwd"][1]
+            and len(plans["fwd"].tiles) <= _STATIC_STEPS)
         if plans["fwd"].static:   # whole tiles inside the window: no mask
             assert plans["fwd"].rows[tiles] == (
                 (0, True), *((j, False) for j in range(1, tiles)),
@@ -1418,12 +1427,16 @@ _CELL_CALLS = {
     "train-smallthinker-1chip.full": (
         (1, 16384, 28, 4, 128, True, 0),
         "96dc2d03780a36589bdcaf470a2166eef087a032d0c9490f8519997f13077f7e"),
+    # PR 56's text (the call at 64-wide heads and a scale of its own)
+    "train-granite4-1chip": (
+        (1, 32768, 32, 8, 64, True, 0, 1 / 64),
+        "dce8ecc9e3ddcd3e7dc6b8b57ed59295174c1fdec6830c77c2e2335ed4cff254"),
 }
 
 
 @pytest.mark.parametrize("cell", list(_CELL_CALLS))
 def test_the_nine_cells_flash_calls_trace_to_what_they_were(cell):
-    (b, s, h, kv, d, rule, rope), digest = _CELL_CALLS[cell]
+    (b, s, h, kv, d, rule, rope, *scale), digest = _CELL_CALLS[cell]
     shapes = [(b, s, h, d), (b, s, kv, d), (b, s, kv, d)]
     if rope:
         shapes += [(b, s, h, rope), (b, s, 1, rope)]
@@ -1431,6 +1444,7 @@ def test_the_nine_cells_flash_calls_trace_to_what_they_were(cell):
     def call(q, k, v, *parts):
         return flash_attention(
             q, k, v, causal=rule, use_pallas=True,
+            **dict(zip(("scale",), scale)),
             **dict(zip(("q_rope", "k_rope"), parts))).astype(jnp.float32).sum()
 
     assert _traced_digest(
@@ -1489,3 +1503,182 @@ def test_a_64_wide_heads_blocks_are_reckoned_at_128_lanes_in_vmem():
     limits = [int(x) for x in re.findall(r"vmem_limit_bytes=(\d+)",
                                          str(grads))]
     assert len(limits) == 3 and min(limits) > 48 * 2**20
+
+
+# --------------------------------------------------------------------------
+# EVA: a rule over a key axis that is not the query axis (`EvaWindows`)
+# --------------------------------------------------------------------------
+
+def _dense_eva(length, window, chunk):
+    """[length, length / chunk + length] bool, written from w(.) and c(.):
+    the summaries' columns first."""
+    n = np.arange(length)[:, None]
+    j = np.arange(length)[None, :]
+    c = np.arange(length // chunk)[None, :]
+    return np.concatenate(
+        [(c * chunk) // window < n // window,
+         (j // window == n // window) & (j <= n)], axis=1)
+
+
+@pytest.mark.parametrize("length, window, chunk", [
+    (128, 32, 4), (96, 32, 4), (80, 32, 4), (256, 64, 8), (120, 24, 4),
+    (1536, 512, 16)])
+def test_eva_windows_rule_against_the_dense_mask(length, window, chunk):
+    """`keep`, `kept` / `needed` and `tile` (in closed form) against the
+    dense mask over EVERY tile of several sizes and alignments, some past
+    either end; a last window that is partial; a window that is no power of
+    two (the predicate divides where it cannot shift)."""
+    rule = EvaWindows(length, window, chunk)
+    n_sum = length // chunk
+    s_k = n_sum + length
+    dense = _dense_eva(length, window, chunk)
+    np.testing.assert_array_equal(
+        rule.keep(np.arange(length)[:, None] + n_sum, np.arange(s_k)[None]),
+        dense)
+    assert rule.kept(length, s_k) == (dense[:, n_sum:].sum(),
+                                      dense[:, :n_sum].sum())
+    assert rule.needed(length, s_k) == dense.sum()
+    for nq, nk in ((8, 8), (16, 16), (32, 16), (7, 5), (64, 64), (128, 128)):
+        for q0 in range(-nq, s_k + nq, nq):
+            rows = np.arange(max(q0, n_sum), min(q0 + nq, s_k)) - n_sum
+            for k0 in range(0, s_k + nk, nk):
+                kept = dense[rows][:, k0:k0 + nk]
+                want = (bool(kept.any()), bool(kept.all())) if kept.size \
+                    else (False, False)
+                assert tuple(map(bool, rule.tile(q0, nq, k0, nk))) == want, \
+                    (q0, nq, k0, nk)
+
+
+def test_eva_windows_rule_wants_its_own_lengths():
+    rule = EvaWindows(128, 32, 4)
+    for s_q, s_k in ((128, 128), (64, 160), (128, 161)):
+        with pytest.raises(ValueError, match="summaries"):
+            rule.needed(s_q, s_k)
+    with pytest.raises(ValueError, match="whole chunks"):
+        EvaWindows(130, 32, 4).needed(130, 162)
+
+
+def test_eva_windows_schedule_at_the_cell_shape():
+    """train-evabyte-1chip's call: 32,768 queries over [2,048 summaries ;
+    32,768 bytes] in tiles of 512. A forward row of window w walks w // 4
+    whole summary tiles, the one of which it sees w mod 4 of the four
+    128-wide columns, and up to 4 tiles of its own window, the last its
+    causal diagonal tile: no row has more than 8 steps, the forward's budget,
+    but the 64 rows are 304 steps in all, and unrolled they ran 16 x slower
+    on the v5e than as loops (PERF.md section 6, PR 57), so no plan of more
+    than `_STATIC_STEPS` steps in all is unrolled: the three kernels walk
+    their 304 tiles in loops, every step masked. dk/dv's longest row (a
+    summary tile's) walks 60 query tiles."""
+    rule = EvaWindows(32768, 2048, 16)
+    assert rule.kept(32768, 34816) == (33_570_816, 31_457_280)
+    assert rule.needed(32768, 34816) == 65_028_096
+    assert CAUSAL.needed(32768, 32768) == 536_887_296
+    plans = block_schedule(32768, 34816, 512, 512, rule)
+    fwd, dkv = plans["fwd"], plans["dkv"]
+    assert plans["dq"] is fwd
+    assert max(map(len, fwd.rows)) == 8 == _STATIC_BUDGET["fwd"][1]
+    assert len(fwd.tiles) == 304 > _STATIC_STEPS == 36
+    assert not fwd.static and not dkv.static
+    for plan in (fwd, dkv):
+        assert (plan.steps_unmasked, plan.steps_masked,
+                plan.steps_triangle) == (0, 304, 0)
+        assert plan.steps_skipped == 64 * 68 - 304
+        assert plan.executed_over_needed == pytest.approx(
+            304 * 512 * 512 / 65_028_096)                      # 1.2255
+    # 160 tiles of the windows' own bytes + 144 of summaries
+    own = sum(1 for t in fwd.tiles if t[2] >= 2048)
+    assert (own, len(fwd.tiles) - own) == (160, 144)
+    assert max(map(len, dkv.rows)) == 60
+    # two windows are under the cap and unrolled, four are not
+    short = block_schedule(4096, 4352, 512, 512, EvaWindows(4096, 2048, 16))
+    assert short["fwd"].static and len(short["fwd"].tiles) == 32
+    assert not block_schedule(8192, 8704, 512, 512,
+                              EvaWindows(8192, 2048, 16))["fwd"].static
+
+
+_EVA_CALLS = {
+    # (S, window, chunk, heads, head dim, blocks): the summaries' keys make
+    # s_k > s_q and no multiple of the blocks
+    "one_tile": (128, 32, 4, 2, 16, 512),
+    "partial_last_window": (80, 32, 4, 2, 16, 512),
+    "several_tiles_unrolled": (1024, 256, 16, 1, 128, 256),
+    "loop_plans": (2560, 1280, 16, 1, 128, 128),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _eva_case(case, how):
+    s, window, chunk, h, d, block = _EVA_CALLS[case]
+    ks = jax.random.split(jax.random.PRNGKey(57), 6)
+    q, k, v, w = (jax.random.normal(key, (1, s, h, d)) for key in ks[:4])
+    phi, mu = (jax.random.normal(key, (h, d)) for key in ks[4:])
+
+    def loss(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+            argnums=(0, 1, 2, 3, 4))(q, k, v, phi / d ** 0.5, mu)
+
+    if how == "definition":
+        return loss(lambda *a: eva.eva_attention_reference(
+            *a, window, chunk))
+    return loss(lambda *a: eva.eva_attention(
+        *a, window, chunk, block_q=block, block_k=block,
+        interpret=how == "kernels"))
+
+
+@pytest.mark.parametrize("tensor", range(6),
+                         ids=["o", "dq", "dk", "dv", "dphi", "dmu"])
+@pytest.mark.parametrize("how", ["oracle", "kernels"])
+@pytest.mark.parametrize("case", sorted(_EVA_CALLS))
+def test_eva_attention_matches_its_definition(case, how, tensor):
+    """`ops/eva.eva_attention` (the pooling, then the flash call under
+    `EvaWindows` over [summaries ; bytes], s_k > s_q) against the dense
+    float32 definition, forward and every gradient, phi's and mu's through
+    the summaries' rows of dk and dv among them; in `jnp` (the oracle path)
+    and in the Pallas interpreter."""
+    if (case, how) == ("loop_plans", "kernels"):
+        s, window, chunk, *_ = _EVA_CALLS[case]
+        plans = block_schedule(s, s + s // chunk, 128, 128,
+                               EvaWindows(s, window, chunk))
+        assert not plans["fwd"].static and not plans["dkv"].static
+    got, want = _eva_case(case, how), _eva_case(case, "definition")
+    got = (got[0],) + got[1]
+    want = (want[0],) + want[1]
+    scale = float(jnp.abs(want[tensor]).max())
+    np.testing.assert_allclose(np.asarray(got[tensor]) / scale,
+                               np.asarray(want[tensor]) / scale, atol=3e-5)
+
+
+def test_eva_attention_counts_its_scores_by_kind():
+    from ray_tpu._private import device_profiler
+
+    q = jnp.zeros((1, 1024, 1, 128))
+    before = device_profiler.snapshot()["counters"]
+    jax.make_jaxpr(lambda q: eva.eva_attention(
+        q, q, q, q[0, 0], q[0, 0], 256, 16))(q)
+    after = device_profiler.snapshot()["counters"]
+    moved = {k: after[k] - before.get(k, 0) for k in after
+             if k.startswith("eva.")}
+    # 4 windows of 256: 4 x 32,896 own bytes, 256 x 16 x (0 + 1 + 2 + 3)
+    assert moved == {"eva.calls": 1, "eva.scores_local": 131_584,
+                     "eva.scores_summary": 24_576}
+
+
+def test_summarise_is_a_softmax_over_each_chunks_bytes():
+    """A chunk whose phi . k_j is large at ONE byte is that byte's key plus
+    mu and that byte's value; phi 0 is the plain mean."""
+    k = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 2, 8))
+    v = jax.random.normal(jax.random.PRNGKey(2), (1, 32, 2, 8))
+    mu = jax.random.normal(jax.random.PRNGKey(3), (2, 8))
+    k_sum, v_sum = eva.summarise(k, v, jnp.zeros((2, 8)), mu, 4)
+    np.testing.assert_allclose(
+        k_sum, k.reshape(1, 8, 4, 2, 8).mean(2) + mu, atol=1e-6)
+    np.testing.assert_allclose(v_sum, v.reshape(1, 8, 4, 2, 8).mean(2),
+                               atol=1e-6)
+    peaked = k.at[0, 5].set(0.0).at[0, 5, :, 0].set(40.0)   # chunk 1, byte 1
+    phi = jnp.zeros((2, 8)).at[:, 0].set(1.0)
+    k_sum, v_sum = eva.summarise(peaked, v, phi, mu, 4)
+    np.testing.assert_allclose(k_sum[0, 1], peaked[0, 5] + mu, atol=1e-4)
+    np.testing.assert_allclose(v_sum[0, 1], v[0, 5], atol=1e-4)
+    with pytest.raises(ValueError, match="whole chunks"):
+        eva.summarise(k[:, :30], v[:, :30], phi, mu, 4)

@@ -2,7 +2,7 @@
 """python3 tools/embed_grad_chip_check.py [--seed n] [--widths]: the
 embedding table's gradient ALONE, ON THE CHIP (any other backend exits 3
 before anything is computed), in both forms `models/llama.embed_rows`'
-backward rule picks from, at the ten training cells' (T rows of the
+backward rule picks from, at the eleven training cells' (T rows of the
 cotangent, V, D), bf16.
 
 THE FORMS. `scatter`: `jnp.zeros((V, D)).at[tokens].add(d)`, what autodiff
@@ -62,6 +62,8 @@ CELLS = {
     "train-laguna-1chip": (8192, 12544, 2048),
     "train-smallthinker-1chip": (16384, 37984, 2560),
     "train-granite4-1chip": (32768, 100352, 2048),
+    # 320 byte rows: ~102 rows of the cotangent collide in each (PR 57)
+    "train-evabyte-1chip": (32768, 320, 4096),
 }
 
 

@@ -1,22 +1,32 @@
 #!/usr/bin/env python3
-"""python3 tools/flash_chip_check.py [--seq s --heads h --kv-heads g
---head-dim d --scale x --seed n]: ONE causal flash call of
-`ops/flash_attention.py` alone ON THE CHIP (any other backend exits 3), at
-the Granite cell's shape unless told: q `[1, 32768, 32, 64]`, k and v
-`[1, 32768, 8, 64]`, scale 1 / 64, the first call with a 64-wide head as its
-only part and the first past S 16,384.
+"""python3 tools/flash_chip_check.py [--rule causal|window|eva --seq s
+--seq-k t --heads h --kv-heads g --head-dim d --scale x --window w --chunk c
+--seed n]: ONE flash call of `ops/flash_attention.py` alone ON THE CHIP (any
+other backend exits 3) under the rule named, at the Granite cell's shape
+unless told: causal, q `[1, 32768, 32, 64]`, k and v `[1, 32768, 8, 64]`,
+scale 1 / 64. `--rule window --window w`: `SlidingWindow(w)`. `--rule eva
+--window w --chunk c`: `ops/eva.eva_attention`, the pooling of the chunk
+summaries and the call under `EvaWindows` over `--seq-k` = s + s / c keys
+(the EvaByte cell: `--rule eva --heads 32 --kv-heads 32 --head-dim 128
+--scale 0.08838834764831845 --window 2048 --chunk 16`), with the gradients
+of phi and mu beside q's, k's and v's. `--seq-k` past `--seq` under the
+other rules: the queries stand at the keys' end.
 
 Against a plain oracle that such a sequence still fits: the cotangent of o is
 zero outside `BLOCKS` blocks of 256 query rows (the first, one in the middle,
 the last), so o is compared on those rows, dq on those rows (it is zero
-elsewhere, which is checked too), dk and dv whole: the oracle is float32
-`jnp` softmax attention of each block against ALL keys under the block's
-dense causal mask, differentiated by XLA. Relative error in the Frobenius
-norm a tensor, bf16 operands on both sides; `BOUND` is what bf16 products
-with float32 accumulation keep. The control that has to FAIL: the same
-oracle at scale d ** -0.5. `ms`: the three kernels on the device's clock
-from a short trace, beside `bound_ms` (`benchmarks/opcount_granite4.py`'s
-kept scores over the peak, or the operands' bytes). Writes
+elsewhere, which is checked too), the other gradients whole: the oracle is
+float32 `jnp` softmax attention of each block against ALL keys under the
+block's dense mask, written here from the positions (EVA's from
+`benchmarks/reference_evabyte.py`'s `summaries`, `visible` and `attend`),
+differentiated by XLA. Relative error in the Frobenius norm a tensor, bf16
+operands on both sides; `BOUND` is what bf16 products with float32
+accumulation keep. The controls that have to FAIL: the same oracle at
+another scale (d ** -0.5, or half the scale where that IS the scale) and,
+under `eva`, with the summaries left out and with the two kinds of key
+normalised apart. `ms`: the three kernels on the device's clock from a short
+trace, beside `bound_ms` (the rule's kept scores over the peak, or the
+operands' bytes, by the opcount module that counts the rule). Writes
 chiprun_out/flash_chip_check.json.
 """
 import argparse
@@ -30,35 +40,57 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from ray_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from ray_tpu.ops import eva  # noqa: E402
+from ray_tpu.ops.flash_attention import SlidingWindow, flash_attention  # noqa: E402
 from tools.kda_chip_check import kernel_ms  # noqa: E402
 
 BOUND = 2e-2
 ROWS = 256
-NAMES = ("o", "dq", "dk", "dv")
 
 
-def oracle(q, k, v, blocks, scale):
+def attend(scores, kept, values):
+    return jax.nn.softmax(jnp.where(kept, scores, -jnp.inf), -1) @ values
+
+
+def oracle(q, k, v, blocks, scale, keep, attend=attend):
     """-> o of the query rows of `blocks` [len(blocks) * ROWS, H, D],
-    float32: softmax(scale q k^T + causal) v a block, a KV head at a time."""
-    s, h = q.shape[1], q.shape[2]
-    rep = h // k.shape[2]
-    q, k, v = (x[0].astype(jnp.float32) for x in (q, k, v))
-    out = []
+    float32: `attend`(scale q k^T, keep(rows), v) a block and head (one
+    traced body, mapped over the blocks and the heads), `keep` the block's
+    dense mask [ROWS, keys] from its rows' indices."""
+    rep = q.shape[2] // k.shape[2]
+    q, k, v = (jnp.moveaxis(x[0].astype(jnp.float32), 1, 0)
+               for x in (q, k, v))                       # [heads, S, D]
+    k, v = (jnp.repeat(x, rep, axis=0) for x in (k, v))
+
+    def block(blk):
+        rows = blk * ROWS + jnp.arange(ROWS)
+        kept = keep(rows)
+        # a head's [ROWS, keys] scores are formed again in the backward
+        # pass, not kept for every block and head (13 GB at 34,816 keys)
+        return jax.lax.map(jax.checkpoint(
+            lambda qkv: attend((qkv[0][rows] @ qkv[1].T) * scale, kept,
+                               qkv[2])), (q, k, v))      # [heads, ROWS, D]
+
     with jax.default_matmul_precision("highest"):
-        for blk in blocks:
-            rows = jnp.arange(blk * ROWS, (blk + 1) * ROWS)
-            keep = rows[:, None] >= jnp.arange(s)[None]
-            heads = []
-            for g in range(k.shape[1]):
-                scores = jnp.einsum(
-                    "qrd,td->rqt", q[rows][:, g * rep:(g + 1) * rep],
-                    k[:, g]) * scale
-                probs = jax.nn.softmax(
-                    jnp.where(keep[None], scores, -jnp.inf), -1)
-                heads.append(jnp.einsum("rqt,td->qrd", probs, v[:, g]))
-            out.append(jnp.concatenate(heads, 1))
-    return jnp.concatenate(out)
+        out = jax.lax.map(block, jnp.asarray(blocks))
+    return jnp.moveaxis(out, 1, 2).reshape(-1, q.shape[0], q.shape[2])
+
+
+def eva_oracle(q, k, v, phi, mu, blocks, scale, window, chunk, **how):
+    """The blocks' rows of EVA attention by the plain reference's pieces: an
+    explicit softmax a chunk, then the dense mask over [summaries ; bytes]."""
+    from benchmarks import reference_evabyte as ref
+
+    f32 = jnp.float32
+    s = q.shape[1]
+    with jax.default_matmul_precision("highest"):
+        k_sum, v_sum = ref.summaries(k[0].astype(f32), v[0].astype(f32),
+                                     phi.astype(f32), mu.astype(f32), chunk)
+    return oracle(
+        q, jnp.concatenate([k_sum, k[0].astype(f32)])[None],
+        jnp.concatenate([v_sum, v[0].astype(f32)])[None], blocks, scale,
+        lambda rows: jnp.concatenate(
+            ref.visible(rows[:, None], s, window, chunk), -1), **how)
 
 
 def kernel_of(event_name):
@@ -74,73 +106,140 @@ def kernel_of(event_name):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=55)
+    ap.add_argument("--rule", choices=("causal", "window", "eva"),
+                    default="causal")
     ap.add_argument("--seq", type=int, default=32768)
+    ap.add_argument("--seq-k", type=int, default=None)
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv-heads", type=int, default=8)
     ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--scale", type=float, default=0.015625)
+    ap.add_argument("--window", type=int, default=2048)
+    ap.add_argument("--chunk", type=int, default=16)
     a = ap.parse_args()
     if jax.default_backend() != "tpu":
         print(f"flash_chip_check: backend {jax.default_backend()!r}, not a "
               "TPU: run it through the chip tool", file=sys.stderr)
         return 3
-    from benchmarks import opcount_granite4 as counts
+    from benchmarks import opcount_evabyte, opcount_granite4, opcount_laguna
     from benchmarks import peaks
 
     s, h, g, d = a.seq, a.heads, a.kv_heads, a.head_dim
+    is_eva = a.rule == "eva"
+    s_k = s + s // a.chunk if is_eva else a.seq_k or s
+    if a.seq_k not in (None, s_k):
+        ap.error(f"--rule eva has {s_k} keys")
     n = s // ROWS
     blocks = sorted({0, n // 2, n - 1})
-    ks = jax.random.split(jax.random.PRNGKey(a.seed), 4)
+    ks = jax.random.split(jax.random.PRNGKey(a.seed), 6)
     bf16 = jnp.bfloat16
-    # keys of RMS 8: at scale 1 / 64 the scores have RMS ~1 over 64 channels
+    # keys of RMS 1 / (scale sqrt d): the scores have RMS ~1
+    rows_k = s if is_eva else s_k
     q = jax.random.normal(ks[0], (1, s, h, d)).astype(bf16)
-    k = (8.0 * jax.random.normal(ks[1], (1, s, g, d))).astype(bf16)
-    v = jax.random.normal(ks[2], (1, s, g, d)).astype(bf16)
+    k = (jax.random.normal(ks[1], (1, rows_k, g, d))
+         / (a.scale * d ** 0.5)).astype(bf16)
+    v = jax.random.normal(ks[2], (1, rows_k, g, d)).astype(bf16)
+    args, names = (q, k, v), ("o", "dq", "dk", "dv")
+    if is_eva:  # pooling logits of RMS ~1 too; an offset of the keys' size
+        phi = (jax.random.normal(ks[4], (g, d)) / d ** 0.5).astype(bf16)
+        mu = jax.random.normal(ks[5], (g, d)).astype(bf16)
+        args, names = args + (phi, mu), names + ("dphi", "dmu")
     picked = jnp.concatenate(
         [jnp.arange(b * ROWS, (b + 1) * ROWS) for b in blocks])
     w_rows = jax.random.normal(ks[3], (len(picked), h, d))
     w = jnp.zeros((1, s, h, d)).at[0, picked].set(w_rows)
+    at_end = jnp.arange(s_k)[None] - (s_k - s)   # a key's index as a query's
 
-    def kernel(q, k, v):
-        return flash_attention(q, k, v, causal=True, scale=a.scale,
-                               use_pallas=True)
+    if is_eva:
+        def kernel(*x):
+            return eva.eva_attention(*x, a.window, a.chunk, scale=a.scale,
+                                     use_pallas=True)
+
+        def plain(scale, **how):
+            return lambda *x: eva_oracle(*x, blocks, scale, a.window,
+                                         a.chunk, **how)
+    else:
+        rule = SlidingWindow(a.window) if a.rule == "window" else True
+
+        def kernel(*x):
+            return flash_attention(*x, causal=rule, scale=a.scale,
+                                   use_pallas=True)
+
+        def keep(rows):
+            kept = rows[:, None] >= at_end
+            if a.rule == "window":
+                kept &= rows[:, None] - at_end < a.window
+            return kept
+
+        def plain(scale, **how):
+            return lambda *x: oracle(*x, blocks, scale, keep, **how)
+
+    every = tuple(range(len(args)))
 
     def run(fn, loss):
-        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
-        return (jax.jit(fn)(q, k, v),) + grads
+        return (jax.jit(fn)(*args),) + jax.jit(
+            jax.grad(loss, argnums=every))(*args)
 
-    got = run(lambda q, k, v: kernel(q, k, v)[0, picked],
-              lambda q, k, v: jnp.sum(kernel(q, k, v).astype(jnp.float32) * w))
+    got = run(lambda *x: kernel(*x)[0, picked],
+              lambda *x: jnp.sum(kernel(*x).astype(jnp.float32) * w))
 
-    def against(scale):
-        want = run(lambda q, k, v: oracle(q, k, v, blocks, scale),
-                   lambda q, k, v: jnp.sum(oracle(q, k, v, blocks, scale)
-                                           * w_rows))
+    def against(fn):
+        want = run(fn, lambda *x: jnp.sum(fn(*x) * w_rows))
         rel = lambda x, y: float(  # noqa: E731
             jnp.linalg.norm(x.astype(jnp.float32) - y.astype(jnp.float32))
             / jnp.linalg.norm(y.astype(jnp.float32)))
-        return {name: rel(x, y) for name, x, y in zip(NAMES, got, want)}
+        return {name: rel(x, y) for name, x, y in zip(names, got, want)}
 
+    other = d ** -0.5 if abs(a.scale - d ** -0.5) > 1e-6 else a.scale / 2
+    controls = {"another_scale": plain(other)}
+    if is_eva:
+        n_sum = s // a.chunk
+
+        def apart(scores, kept, values):
+            out = 0.0
+            for part in (slice(0, n_sum), slice(n_sum, None)):
+                probs = jax.nn.softmax(jnp.where(
+                    kept[:, part], scores[:, part], -1e30), -1)
+                out = out + jnp.where(
+                    jnp.any(kept[:, part], -1, keepdims=True),
+                    probs @ values[part], 0.0)
+            return out
+
+        controls["no_summaries"] = plain(a.scale, attend=lambda sc, kept, vals:
+                                         attend(sc, kept.at[:, :n_sum].set(
+                                             False), vals))
+        controls["normalised_apart"] = plain(a.scale, attend=apart)
     out = {"device": jax.devices()[0].device_kind, "seed": a.seed,
-           "shape": [1, s, h, d], "kv_heads": g, "scale": a.scale,
-           "bound": BOUND, "rows_compared": len(picked),
-           "kernel": against(a.scale),
-           "scale_of_sqrt_d": against(d ** -0.5)}
+           "rule": a.rule, "shape": [1, s, h, d], "keys": s_k, "kv_heads": g,
+           "scale": a.scale, "bound": BOUND, "rows_compared": len(picked),
+           "kernel": against(plain(a.scale)),
+           "controls": {name: against(fn) for name, fn in controls.items()}}
     rest = jnp.ones((s,), bool).at[picked].set(False)
     out["dq_outside_is_zero"] = bool(jnp.all(got[1][0, rest] == 0))
-    both = jax.jit(lambda q, k, v: (kernel(q, k, v), jax.grad(
-        lambda q, k, v: jnp.sum(kernel(q, k, v).astype(jnp.float32) * w),
-        argnums=(0, 1, 2))(q, k, v)))
-    jax.block_until_ready(both(q, k, v))
-    out["ms"] = kernel_ms(both, q, k, v, n=3, kernel_of=kernel_of)
+    both = jax.jit(lambda *x: (kernel(*x), jax.grad(
+        lambda *x: jnp.sum(kernel(*x).astype(jnp.float32) * w),
+        argnums=every)(*x)))
+    jax.block_until_ready(both(*args))
+    out["ms"] = kernel_ms(both, *args, n=3, kernel_of=kernel_of)
     peak = peaks.peaks("TPU v5 lite")
-    out["bound_ms"] = {
-        name: 1e3 * counts.bound_seconds(*fn(1, h, s, d, g / h), peak)
-        for name, fn in (("fwd", counts.flash_fwd), ("bwd", counts.flash_bwd))}
+    if is_eva:
+        counted = {"fwd": opcount_evabyte.eva_flash_fwd(
+            1, h, s, d, a.window, a.chunk), "bwd": opcount_evabyte.
+            eva_flash_bwd(1, h, s, d, a.window, a.chunk)}
+    elif a.rule == "window":
+        counted = {"fwd": opcount_laguna.swa_flash_fwd(
+            1, h, s, d, a.window, g / h), "bwd": opcount_laguna.swa_flash_bwd(
+                1, h, s, d, a.window, g / h)}
+    else:
+        counted = {"fwd": opcount_granite4.flash_fwd(1, h, s, d, g / h),
+                   "bwd": opcount_granite4.flash_bwd(1, h, s, d, g / h)}
+    out["bound_ms"] = {name: 1e3 * opcount_granite4.bound_seconds(*c, peak)
+                       for name, c in counted.items()}
     out["ok"] = all(x <= BOUND for x in out["kernel"].values()) \
         and out["dq_outside_is_zero"]
-    out["control_fails"] = any(
-        not x <= BOUND for x in out["scale_of_sqrt_d"].values())
+    out["control_fails"] = all(
+        any(not x <= BOUND for x in errs.values())
+        for errs in out["controls"].values())
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/flash_chip_check.json", "w") as f:
         json.dump(out, f, indent=1)
