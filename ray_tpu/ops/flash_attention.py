@@ -17,6 +17,7 @@ dv over each group. No copy at the query heads' count is written to HBM.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from typing import NamedTuple, Optional
 
@@ -305,6 +306,20 @@ def _halves(n, leading):
             for lo, hi in ((0, half), (half, n))]
 
 
+class SharedRows(NamedTuple):
+    """The grid rows of a loop plan that are ONE row, placed by the grid row:
+    `rows` run `steps`, (step index minus row x `stride`, masked), as one
+    branch of straight-line code (`_run_row`) in the order given: the tiles
+    the rule keeps whole first, with no mask, then those it cuts, whole
+    under the predicate; `stride`: the steps of the plan's width an owned
+    block spans. Under `SlidingWindow(4096)` at S 16,384 in tiles of 512: 24
+    of the 32 rows, each seven tiles with no mask, then the window's
+    trailing tile and the row's own."""
+    rows: tuple
+    stride: int
+    steps: tuple
+
+
 class KernelSchedule(NamedTuple):
     """One kernel's loop plan over one (batch, head): `tiles` are
     (q_start, q_rows, k_start, k_cols, masked), one per loop step, and
@@ -320,7 +335,10 @@ class KernelSchedule(NamedTuple):
     tile it stands for), and a tile the rule cuts along its sub-tile
     diagonal as a `Triangle` (`steps_triangle` of the `steps_masked`);
     otherwise ONE loop a grid row over `table`, every step masked whole if
-    any step is (`_run_row`)."""
+    any step is (`_run_row`), but for the rows of `shared` (None: no such
+    rows), the plan's majority of same-shaped rows, which run one unrolled
+    branch between them, each step masked only if it needs it, in `rows`
+    and `tiles` too: the counts are of what runs."""
     width: int
     static: bool
     tiles: tuple
@@ -332,6 +350,13 @@ class KernelSchedule(NamedTuple):
     steps_triangle: int
     steps_skipped: int
     executed_over_needed: float
+    shared: Optional[SharedRows] = None
+
+    @property
+    def steps_shared(self):
+        """The steps, of `tiles`, that run in the shared branch."""
+        return len(self.shared.rows) * len(self.shared.steps) \
+            if self.shared else 0
 
     @property
     def table(self):
@@ -351,7 +376,8 @@ class KernelSchedule(NamedTuple):
 # the v5e at 512 x 512 (PERF.md §6, PR 26, 34, 38). Forward and dq (dq runs
 # the forward's plan): the LONGEST grid row, 8 steps: 4 (causal S 2048) is
 # the gain this exists for, 8 (S 4096, 36 steps in all) takes the forward
-# from 5.80 to 4.03 ms and dq gains too. dk/dv: the plan's steps IN ALL, 28:
+# from 5.80 to 4.03 ms and dq gains too (a row of 9 exists only in plans past
+# `_STATIC_STEPS`: `_SHARED_BUDGET`). dk/dv: the plan's steps IN ALL, 28:
 # its code grows faster, and at the 36 of causal S 4096 it collapsed (8.7 ->
 # 29.5 ms) where the block-diffusion call at 2 x 2048 (24: rows of 1, 1, 1,
 # 1, 8, 6, 4, 2) goes from 7.50 to 5.20 ms, causal S 3072 (21) from 5.21 to
@@ -371,6 +397,27 @@ _STATIC_BUDGET = {"fwd": (max, 8), "dkv": (sum, 28)}
 # the loop plans take 17.0 and 17.6 (PERF.md §6, PR 57). 36 is causal S 4096
 # (above); between 36 and 304 nothing is measured.
 _STATIC_STEPS = 36
+# The longest row that a loop plan's same-shaped rows run as ONE shared
+# branch (`SharedRows`), by kernel (dq runs the forward's plan); 0: never.
+# The kernel's code is then that row's steps beside one loop body, however
+# many the rows, so neither budget above speaks of it. 9 is the one length
+# measured on the v5e (PERF.md §6, PR 58: q [1, 16384, 28, 128] over 4 KV
+# heads under `SlidingWindow(4096)`, 24 of 32 rows of 9 steps; ms a call, the
+# loop and then the branch): forward 8.70 -> 6.08, dq 9.02 -> 7.88, dk/dv
+# 13.18 -> 10.91. Two things that were measured decide its form. The ORDER:
+# with the trailing tile first, as the row walks, and as a `Triangle`, the
+# forward read 8.52 (the halves' carries are joined again, and every step
+# after that ran slower); the tiles with no mask first, 6.17. And what the
+# cell's SET-UP pays for every body a kernel traces and lowers, in each of
+# its 15 lowerings: 9 steps and 2 triangles as python's straight-line code
+# were +11.6 s of 58.8 (the bound is 10%); each run of steps of one kind as
+# ONE traced body, unrolled at lowering, and the triangles still (dq 7.66,
+# dk/dv 10.42: +0.6% of the cell's tokens a second) +4.6 s; the cut tiles
+# whole under the mask, as they are now, 56.7 / 58.1 s where the parent read
+# 57.5 / 59.1. The mask is no cost in a kernel alone (no mask at all, wrong,
+# for the timing: 5.97 / 7.83 / 10.68), but on all 9 steps it cost the cell
+# 1.3%.
+_SHARED_BUDGET = {"fwd": 9, "dkv": 9}
 
 
 @functools.lru_cache(maxsize=256)
@@ -459,14 +506,43 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
             return measure(map(len, rows)) <= budget \
                 and sum(map(len, rows)) <= _STATIC_STEPS
 
+        def same_rows(rows):
+            """The `SharedRows` of a plan too long to unroll, or None: its
+            largest group of grid rows of one shape (the steps' indices
+            from the row's own, and their kinds), if that is several rows,
+            holds half the plan's steps or more, is one contiguous range of
+            steps a row and fits the kernel's `_SHARED_BUDGET`."""
+            stride, rest = divmod(owned_and_walked(0, 0)[0][1], width)
+            if rest:
+                return None
+            groups = {}
+            for i, steps in enumerate(rows):
+                groups.setdefault(tuple(
+                    (j - i * stride, m) for j, m in steps), []).append(i)
+            steps, same = max(groups.items(),
+                              key=lambda g: len(g[0]) * len(g[1]))
+            if len(same) < 2 or not 0 < len(steps) <= _SHARED_BUDGET[kernel] \
+                    or 2 * len(steps) * len(same) < sum(map(len, rows)) \
+                    or [at for at, _ in steps] != list(range(
+                        steps[0][0], steps[0][0] + len(steps))):
+                return None
+            # the tiles with no mask first, then those under it, whole
+            return SharedRows(tuple(same), stride, tuple(sorted(
+                ((at, bool(m)) for at, m in steps), key=lambda s: s[1])))
+
         banded = [[(steps[0][0], b)] if (b := band(i, steps)) else steps
                   for i, steps in enumerate(kept)]
         if unrolled(banded):
             kept = banded  # a band step is straight-line code
         static = unrolled(kept)
+        shared = None if static else same_rows(kept)
         if not static:
             any_masked = any(m for steps in kept for _, m in steps)
-            kept = [[(j, any_masked) for j, _ in steps] for steps in kept]
+            kept = [sorted((i * shared.stride + at, m)
+                           for at, m in shared.steps)
+                    if shared and i in shared.rows
+                    else [(j, any_masked) for j, _ in steps]
+                    for i, steps in enumerate(kept)]
         by_row = tuple(map(tuple, kept))
 
         def tile_of(i, j, masked):
@@ -498,7 +574,7 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
             sum(isinstance(t[4], Band) for t in tiles),
             sum(isinstance(t[4], Triangle) for t in tiles), skipped,
             sum(executed(*t) for t in tiles) / needed if needed
-            else float("inf"))
+            else float("inf"), shared)
 
     w = _step_width(block_q, block_k, rule)
     keys = plan(
@@ -548,7 +624,19 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     every score left out is again one the mask sets to exactly 0. `CAUSAL`
     groups keep runs of 1, 2, 3, ... sub-tiles that all start at 0 and a
     block-diffusion row's kept tiles are not one range, so neither has a
-    band step; a plan too long to unroll with them keeps its whole tiles.
+    band step; a plan too long to unroll with them keeps its whole tiles,
+    every one under the mask, in ONE loop a grid row. But for the rows of
+    ONE SHAPE: where a loop plan's largest group of rows whose steps lie as
+    far from the row's own block, kind for kind, is several rows, holds
+    half the plan's steps or more, is one contiguous range a row and no
+    longer than `_SHARED_BUDGET`, those rows run one branch of straight-
+    line code between them, placed by the grid row (`SharedRows`, PR 58):
+    the tiles the rule keeps whole with no mask, then the ones it cuts,
+    whole under the predicate; the other rows run the loop. A window of
+    several tiles has such rows (4,096 in tiles of 512 at S 16,384: 24 of
+    32 rows are 7 tiles with no mask between the trailing tile and their
+    own). No two causal rows are alike, and neither are most of a block-
+    diffusion or an `EvaWindows` plan's: those plans are what they were.
 
     `executed_over_needed` is scores executed over scores the rule keeps.
     Starting point (before PR 26): steps of block_q x block_k whatever the
@@ -565,7 +653,9 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     tiles as triangles: 19 tiles' area, 1.185; `SlidingWindow(512)` at S
     8,192 walked 31 tiles for 4,063,488 kept scores, 2.0, and runs 15 band
     steps of 4 x [128, 640] (PR 48) and the first row's own tile as a
-    triangle: 1.258.
+    triangle: 1.258; `SlidingWindow(4096)` at S 16,384 walks 252 tiles for
+    58,722,304 kept scores, 1.125, 168 of them with no mask since 24 rows
+    share a branch (PR 58).
     """
     return _block_schedule(s_q, s_k, block_q, block_k, _rule(causal))
 
@@ -588,6 +678,9 @@ def _count_steps(*plans):
                           sum(p.steps_triangle for p in plans))
     device_profiler.count("flash.tiles_skipped",
                           sum(p.steps_skipped for p in plans))
+    # of them all: those of a loop plan's rows that run ONE shared branch
+    device_profiler.count("flash.steps_shared_row",
+                          sum(p.steps_shared for p in plans))
 
 
 def _count_fetches(qs, ks):
@@ -626,14 +719,40 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band,
     (`KernelSchedule.table`, in SMEM) and run in ONE loop, each masked
     whole if any step of the plan is: on the v5e the mask costs 2% of the
     kernel (it is not bound by the vector ALUs) and a second loop 5-8%
-    (PERF.md §6, PR 26)."""
+    (PERF.md §6, PR 26). A loop plan's `shared` rows are one row placed by
+    the grid row, as a band is: they run ONE branch of their steps unrolled,
+    the step indices traced (`row * stride + offset`), each masked only if
+    it needs it, and only the other rows the loop (the window call of 9
+    steps a row, its three kernels 30.9 -> 24.9 ms; PERF.md §6, PR 58)."""
     from jax.experimental import pallas as pl
 
     if not plan.static:
         step = body(plan.steps_masked > 0)
-        finish(jax.lax.fori_loop(
-            0, steps_ref[row, 0],
-            lambda t, c: step(steps_ref[row, t + 1], c), carry))
+
+        def loop():
+            finish(jax.lax.fori_loop(
+                0, steps_ref[row, 0],
+                lambda t, c: step(steps_ref[row, t + 1], c), carry))
+
+        if plan.shared is None:
+            return loop()
+        rows, stride, steps = plan.shared
+        first = row * stride
+
+        def shared():
+            c = carry
+            # a run of steps of one kind is traced ONCE and unrolled when
+            # the kernel is lowered: a body a step, 11 a kernel, was 11.6 s
+            # of the cell's set-up (PERF.md §6, PR 58)
+            for masked, run in itertools.groupby(steps, lambda s: s[1]):
+                one = body(masked)
+                for at, n, apart in _progressions([at for at, _ in run]):
+                    c = jax.lax.fori_loop(
+                        0, n, lambda t, c, at=at, apart=apart: one(
+                            first + at + t * apart, c), c, unroll=True)
+            finish(c)
+
+        jax.lax.cond(_among(row, rows), shared, loop)
         return
 
     def branch(mine):
@@ -659,6 +778,29 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band,
     for same in rows.values():
         pl.when(functools.reduce(jnp.logical_or, [row == i for i in same]))(
             branch(plan.rows[same[0]]))
+
+
+def _progressions(xs):
+    """`xs` cut into runs that each step by one difference, as (first,
+    length, difference)."""
+    out = []
+    for x in xs:
+        if out and out[-1][1] == 1:
+            out[-1][1:] = 2, x - out[-1][0]
+        elif out and x == out[-1][0] + out[-1][1] * out[-1][2]:
+            out[-1][1] += 1
+        else:
+            out.append([x, 1, 1])
+    return out
+
+
+def _among(row, rows):
+    """Whether `row` (traced) is one of `rows`: two compares a run of them,
+    not one a row (24 compares are as much to trace as a step's body)."""
+    return functools.reduce(jnp.logical_or, [
+        (row >= lo) & (row < lo + n * apart) & ((row - lo) % apart == 0)
+        if apart > 1 else (row >= lo) & (row < lo + n)
+        for lo, n, apart in _progressions(rows)])
 
 
 def _triangle(kind, j, carry, part):

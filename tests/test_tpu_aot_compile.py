@@ -1063,19 +1063,22 @@ def test_window_and_full_flash_calls_at_s16384_compile_for_v5e(compiled):
     """train-smallthinker-1chip's two calls, value and gradient: `[1, 28,
     16384, 128]` over 4 KV heads under `SlidingWindow(4096)` (8 tiles of
     512: rows of 9 steps, 252 a (batch, head)) and `CAUSAL` (rows of up to
-    32, 528), all six kernels loops. One KV head's K and V whole are 2 x 4
+    32, 528), all six kernels loops, the window's with one unrolled branch
+    for their 24 interior rows beside it. One KV head's K and V whole are 2 x 4
     MiB, twice over in the pipeline's buffers: the compiler refused every
     one ("Scoped allocation with size 16.75M and limit 16.00M") until each
     stated its own limit, its blocks twice plus 16 MiB (`_vmem_limit`): 32.5
     to 33.3 MiB of the v5e's 128."""
     for name, steps, longest, digest in (
-            ("smallthinker_window", 252, 9, "d3732652207dac49e97f774bd6afe134"
-             "b1f7e6e46443b8102bb1a7b6cdbde59f"),
+            ("smallthinker_window", 252, 9,
+             "90e3c633de915098825a425902ebe3d2"
+             "c46da60b34a1b16e30e3e8e5d416f2c5"),
             ("smallthinker_full", 528, 32, "5a39bff16b81f9759f3dc161688c75fc"
              "e7b3ecb087b456794d390d078b58a956")):
         assert compiled[name] == "compiled", compiled[name]
-        # loops: no triangle step, and the call traces to the text it had
-        # before there was one (PR 50's)
+        # loops: no triangle step. The causal call traces to the text it had
+        # before there was one (PR 50's); the window call's 24 rows of ONE
+        # shape share an unrolled branch (PR 58's)
         assert compiled[name + "_plans"] == [[False, steps, longest, 0]] * 3
         assert compiled[name + "_jaxpr"] == digest
         limits = sorted(map(int, compiled[name + "_vmem_limits"]))
