@@ -22,10 +22,11 @@ s = d_head ** -0.5):
 
 The residual stream is float32 and every sublayer's input bf16: the norm
 rounds once, the matmuls run in bf16 with float32 accumulation, and a
-branch's output is added in float32. The SwiGLU is `llama._swiglu` and the
-rotary embedding `llama._rope`; the heads' loss is ONE `llama.chunked_ce`
-call over the whole head in `pred_heads` groups. Layers are alike and
-scanned; remat is per layer (`mla_moe._checkpointed`): under "residuals" a
+branch's output is added in float32. Over the layer library
+(`models/blocks.py`): the SwiGLU is `blocks.swiglu` and the rotary embedding
+`blocks.rope`; the heads' loss is ONE `blocks.chunked_ce` call over the whole
+head in `pred_heads` groups. Layers are alike and scanned; remat is per
+layer (`blocks.checkpointed`): under "residuals" a
 layer keeps its float32 input and the flash call's o and lse and recomputes
 the rest, the MLP in blocks of `_MLP_ROWS` rows, each recomputed and
 differentiated on its own, so that gate, up and their product exist a block
@@ -42,8 +43,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama, mla_moe
-from ray_tpu.models.llama import _residual
+from ray_tpu.models import blocks
+from ray_tpu.models.blocks import residual
 from ray_tpu.ops import eva
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUALS
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
@@ -106,17 +107,9 @@ def param_logical_axes(config: EvaByteConfig) -> Dict[str, Any]:
     return {
         "embed": ("vocab", "embed"),
         "layers": {
-            "attn_norm": L + (None,),
-            "wq": L + ("embed", "heads", "kv"),
-            "wk": L + ("embed", "heads", "kv"),
-            "wv": L + ("embed", "heads", "kv"),
-            "wo": L + ("heads", "kv", "embed"),
-            "phi": L + ("heads", "kv"),
+            **blocks.attn_axes(L), "phi": L + ("heads", "kv"),
             "mu": L + ("heads", "kv"),
-            "mlp_norm": L + (None,),
-            "w_gate": L + ("embed", "mlp"),
-            "w_up": L + ("embed", "mlp"),
-            "w_down": L + ("mlp", "embed"),
+            "mlp_norm": L + (None,), **blocks.ffn_axes(L),
         },
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
@@ -184,16 +177,16 @@ def _eva_sublayer(x, p, positions, config, mesh, rules):
     h = _norm(x, p["attn_norm"], c)
     q, k, v = (jnp.einsum("bsd,dhk->bshk", h, p[w])
                for w in ("wq", "wk", "wv"))
-    q = llama._rope(q, positions, c.rope_theta)
-    k = llama._rope(k, positions, c.rope_theta)
+    q = blocks.rope(q, positions, c.rope_theta)
+    k = blocks.rope(k, positions, c.rope_theta)
     attn = eva.eva_attention(q, k, v, p["phi"], p["mu"], c.window, c.chunk,
                              mesh=mesh)
     out = jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
-    return _residual(x + out.astype(jnp.float32), mesh, rules)
+    return residual(x + out.astype(jnp.float32), mesh, rules)
 
 
 def _mlp_sublayer(x, p, config, mesh, rules):
-    """x + `llama._swiglu`(norm(x)), in blocks of `_MLP_ROWS` rows where
+    """x + `blocks.swiglu`(norm(x)), in blocks of `_MLP_ROWS` rows where
     they divide a longer sequence."""
     c = config
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
@@ -201,13 +194,13 @@ def _mlp_sublayer(x, p, config, mesh, rules):
     weights = {w: p[w] for w in ("w_gate", "w_up", "w_down")}
     b, s, d = h.shape
     if s <= _MLP_ROWS or s % _MLP_ROWS:
-        out = llama._swiglu(h, weights, lc)
+        out = blocks.swiglu(h, weights, lc)
     else:
-        block = jax.checkpoint(lambda rows: llama._swiglu(rows, weights, lc))
+        block = jax.checkpoint(lambda rows: blocks.swiglu(rows, weights, lc))
         out = jax.lax.map(block, jnp.moveaxis(
             h.reshape(b, -1, _MLP_ROWS, d), 1, 0))
         out = jnp.moveaxis(out, 0, 1).reshape(b, s, d)
-    return _residual(x + out.astype(jnp.float32), mesh, rules)
+    return residual(x + out.astype(jnp.float32), mesh, rules)
 
 
 def _layer(x, p, positions, config, mesh, rules):
@@ -219,13 +212,9 @@ def forward_hidden(params, tokens, config: EvaByteConfig, mesh=None,
                    rules: Optional[LogicalAxisRules] = None):
     """tokens [B, S] -> final-norm hidden states [B, S, D] (bf16)."""
     c = config
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
-                                    mesh=mesh, rules=rules)
-    x = llama.embed_rows(table, tokens, mesh).astype(jnp.float32)
-    x = _residual(x, mesh, rules)
-    layer = mla_moe._checkpointed(
+    x, positions = blocks.embed_tokens(params, tokens, mesh, rules)
+    x = residual(x.astype(jnp.float32), mesh, rules)
+    layer = blocks.checkpointed(
         partial(_layer, positions=positions, config=c, mesh=mesh,
                 rules=rules), c, FLASH_RESIDUALS)
     x, _ = jax.lax.scan(lambda x, p: (layer(x, p), None), x,
@@ -264,14 +253,14 @@ def head_targets(targets, mask, heads: int):
 
 def loss_fn(params, batch, config: EvaByteConfig, mesh=None,
             rules: Optional[LogicalAxisRules] = None):
-    """The `pred_heads` heads' mean CE through ONE `llama.chunked_ce` over
+    """The `pred_heads` heads' mean CE through ONE `blocks.chunked_ce` over
     the whole head in groups, rows masked by batch["mask"] when given.
     Scalar return (make_train_step contract)."""
     c = config
-    inputs, targets, mask = mla_moe._split(batch)
+    inputs, targets, mask = blocks.split_batch(batch)
     hidden = forward_hidden(params, inputs, c, mesh, rules)
     targets, weights = head_targets(targets, mask, c.pred_heads)
-    return llama.chunked_ce(
+    return blocks.chunked_ce(
         hidden, params["lm_head"], targets, weights,
         chunk=c.loss_chunk_size or inputs.shape[1], denominator=1.0,
         groups=c.pred_heads)

@@ -13,24 +13,23 @@ As HF `modeling_granitemoehybrid` computes it (RMSNorm eps `norm_eps`):
                   h = RMSNorm(x)
     logits = RMSNorm(x_L) E^T / logits_scaling
 
-- `mamba`: `nemotron_h.mamba_mixer` (Mamba-2 through `ops/ssd.py`, walked
+- `mamba`: `mixers.mamba_sublayer` (Mamba-2 through `ops/ssd.py`, walked
   `chunk_size` tokens at a time): at `n_groups` 1 the gated RMSNorm is ONE
   norm over all H x P channels and B and C are shared by all heads.
-- `attention`: `llama._attn_sublayer` at `rope_theta` 0 (`nope`), causal,
+- `attention`: `blocks.attn_sublayer` at `rope_theta` 0 (`nope`), causal,
   softmax(`attention_multiplier` q k^T) v: the multiplier is the scores'
   scale, 1 / 64 at 64-wide heads where d_head ** -0.5 would be 1 / 8.
-- the MLP: `llama._mlp_sublayer` (the published `shared_mlp` with its
+- the MLP: `blocks.mlp_sublayer` (the published `shared_mlp` with its
   [gate | up] projection as two matrices).
-- the head IS the embedding: `llama.chunked_ce` on E^T (the fused backward
+- the head IS the embedding: `blocks.chunked_ce` on E^T (the fused backward
   pass), so the embedding's gradient is the sum of its two uses.
 
 No multiplier is folded into a weight: a checkpoint's weights do not carry
-one. `layers` lists the published indices held here (all by default). Whole
-aligned periods of the pattern (`period` layers: nine `mamba` to one
-`attention`, published) run as ONE `lax.scan` over the stacked periods whose
-body scans each run of `mamba` layers, so two kinds of layer body are traced
-whatever the depth; other layers run unrolled. Remat is per layer
-(`mla_moe._checkpointed`): under "residuals" a layer keeps its input, the
+one. `layers` lists the published indices held here (all by default).
+`layer_pattern.walk` scans the whole aligned periods of the pattern (`period`
+layers: nine `mamba` to one `attention`, published), each run of `mamba`
+layers a scan inside, and unrolls the other layers. Remat is per layer
+(`blocks.checkpointed`): under "residuals" a layer keeps its input, the
 scan's `y` and the flash call's `o` and `lse`, and recomputes the rest.
 """
 
@@ -45,15 +44,14 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import layer_pattern, llama, mla_moe, nemotron_h
-from ray_tpu.models.llama import _residual, _rms_norm
+from ray_tpu.models import blocks, layer_pattern, mixers
+from ray_tpu.models.blocks import residual, rms_norm, scaled
 from ray_tpu.ops import ssd as ssd_op
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUALS
-from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
+from ray_tpu.parallel.sharding import LogicalAxisRules
 
 KINDS = ("mamba", "attention")
 PUBLISHED_PATTERN = (("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4
-_MLP = ("mlp_norm", "w_gate", "w_up", "w_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,7 +59,8 @@ class GraniteHybridConfig:
     """`pattern`: a layer's kind by its published index (`layer_types`);
     `layers`: the published indices held here (None: all). The Mamba-2
     fields carry `nemotron_h.NemotronHConfig`'s names and the attention and
-    MLP fields `llama.LlamaConfig`'s, whose sublayers read them."""
+    MLP fields `llama.LlamaConfig`'s: the same sublayers (`mixers.py`,
+    `blocks.py`) read them."""
     vocab_size: int = 100_352
     d_model: int = 2048
     pattern: Tuple[str, ...] = PUBLISHED_PATTERN
@@ -91,7 +90,7 @@ class GraniteHybridConfig:
     remat: bool = True
     remat_policy: str = "residuals"
     loss_chunk_size: int = 0
-    # what `llama._attn_sublayer` also reads of its config: constants here
+    # what `blocks.attn_sublayer` also reads of its config: constants here
     qk_norm = False
     use_ring_attention = False
 
@@ -99,10 +98,8 @@ class GraniteHybridConfig:
         for name in ("pattern", "layers"):
             if isinstance(getattr(self, name), list):
                 object.__setattr__(self, name, tuple(getattr(self, name)))
-        held, kinds = self.held_layers, self.pattern
-        if list(held) != sorted(set(held)) or not held \
-                or not 0 <= held[0] <= held[-1] < len(kinds):
-            raise ValueError(f"layers {held} of {len(kinds)}")
+        kinds = self.pattern
+        self.held_layers  # raises where they are not the pattern's, in order
         if set(kinds) - set(KINDS):
             raise ValueError(f"a layer is one of {KINDS}")
         if any(k != kinds[i % self.period] for i, k in enumerate(kinds)):
@@ -124,8 +121,7 @@ class GraniteHybridConfig:
 
     @property
     def held_layers(self) -> Tuple[int, ...]:
-        return self.layers if self.layers is not None \
-            else tuple(range(len(self.pattern)))
+        return layer_pattern.held_layers(self.layers, len(self.pattern))
 
     @property
     def d_inner(self) -> int:
@@ -161,7 +157,7 @@ class GraniteHybridConfig:
 def layer_num_params(c, kind: str) -> int:
     """One layer's parameters: its mixer, its MLP and its two norms."""
     d = c.d_model
-    mixer = nemotron_h.layer_num_params(c, "M" if kind == "mamba" else "*")
+    mixer = mixers.mixer_num_params(c, "M" if kind == "mamba" else "*")
     return mixer + 3 * d * c.d_ff + 2 * d
 
 
@@ -170,9 +166,8 @@ def layer_num_params(c, kind: str) -> int:
 # --------------------------------------------------------------------------
 
 def _layer_axes(L, kind: str):
-    return {**nemotron_h._layer_axes(L, "M" if kind == "mamba" else "*"),
-            "mlp_norm": L + (None,), "w_gate": L + ("embed", "mlp"),
-            "w_up": L + ("embed", "mlp"), "w_down": L + ("mlp", "embed")}
+    return {**mixers.mixer_axes(L, "M" if kind == "mamba" else "*"),
+            "mlp_norm": L + (None,), **blocks.ffn_axes(L)}
 
 
 def _kinds_of(config, indices):
@@ -193,15 +188,14 @@ def param_logical_axes(config: GraniteHybridConfig) -> Dict[str, Any]:
 
 
 def _init_layer(config, kind: str, key):
-    """`nemotron_h._init_layer`'s mixer (fan-in scaled normal matrices, norm
+    """`mixers.init_mixer`'s mixer (fan-in scaled normal matrices, norm
     scales 1, Mamba-2's own initialisation of `A_log`, `dt_bias` and D) and
     a fan-in scaled MLP."""
     c = config
     k_mix, *ks = jax.random.split(key, 4)
-    return {**nemotron_h._init_layer(c, "M" if kind == "mamba" else "*",
-                                     k_mix),
+    return {**mixers.init_mixer(c, "M" if kind == "mamba" else "*", k_mix),
             "mlp_norm": jnp.ones((c.d_model,), c.dtype),
-            **mla_moe._init_ffn(c, ks, (), c.d_ff)}
+            **blocks.init_ffn(c, ks, (), c.d_ff)}
 
 
 def init(config: GraniteHybridConfig, key) -> Dict[str, Any]:
@@ -221,8 +215,8 @@ def init(config: GraniteHybridConfig, key) -> Dict[str, Any]:
             lead + (-1,)))
 
     params = {
-        "embed": mla_moe._dense(c, k_embed, (c.vocab_size, c.d_model),
-                                c.embedding_multiplier ** 2),
+        "embed": blocks.dense(c, k_embed, (c.vocab_size, c.d_model),
+                              c.embedding_multiplier ** 2),
         "final_norm": jnp.ones((c.d_model,), c.dtype)}
     if loose:
         params["loose"] = {
@@ -240,96 +234,50 @@ def init(config: GraniteHybridConfig, key) -> Dict[str, Any]:
 # blocks
 # --------------------------------------------------------------------------
 
-def _mamba_sublayer(x, p, config, mesh=None, rules=None):
-    """x [B, S, D] -> x + residual_multiplier * Mamba-2(RMSNorm(x))."""
-    c = config
-    h = _rms_norm(x, p["norm"], c.norm_eps)
-    out = nemotron_h.mamba_mixer(h, p, c)
-    return _residual(x + llama._scaled(out, c.residual_multiplier), mesh,
-                     rules)
-
-
-def _layer(x, p, positions, config, mesh, rules, kind: str):
+def layer(x, p, positions, config, mesh, rules, kind: str):
     """One layer: its mixer, then its MLP, each branch times
-    `residual_multiplier`."""
+    `residual_multiplier` -> (x, None: no layer routes)."""
     c = config
     if kind == "mamba":
-        x = _mamba_sublayer(x, p, c, mesh, rules)
+        x = mixers.mamba_sublayer(x, p, c, mesh, rules,
+                                  c.residual_multiplier)
         device_profiler.count("granite.layers_mamba", 1)  # per lowering
     else:
-        x = llama._attn_sublayer(
+        x = blocks.attn_sublayer(
             x, p, positions, c, mesh, rules, scale=c.attention_multiplier,
             branch=c.residual_multiplier)
         device_profiler.count("granite.layers_attention", 1)
-    return llama._mlp_sublayer(x, p, c, mesh, rules,
-                               branch=c.residual_multiplier)
+    return blocks.mlp_sublayer(x, p, c, mesh, rules,
+                               branch=c.residual_multiplier), None
 
 
 def forward_hidden(params, tokens, config: GraniteHybridConfig, mesh=None,
                    rules: Optional[LogicalAxisRules] = None):
     """tokens [B, S] -> final-norm hidden states [B, S, D]."""
     c = config
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
-                                    mesh=mesh, rules=rules)
-    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
-    x = llama._scaled(x, c.embedding_multiplier)
-    x = _residual(x, mesh, rules)
-    body = {kind: mla_moe._checkpointed(
-        partial(_layer, positions=positions, config=c, mesh=mesh,
+    x, positions = blocks.embed_tokens(params, tokens, mesh, rules)
+    x = residual(scaled(x.astype(c.dtype), c.embedding_multiplier), mesh,
+                 rules)
+    bodies = {kind: blocks.checkpointed(
+        partial(layer, positions=positions, config=c, mesh=mesh,
                 rules=rules, kind=kind), c,
         FLASH_RESIDUALS + ssd_op.RESIDUAL_NAMES) for kind in KINDS}
-    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)  # noqa: E731
-
-    def period(x, p):
-        done = dict.fromkeys(KINDS, 0)
-        for kind, n in c.runs():
-            first = done[kind]
-            done[kind] += n
-            if n == 1:
-                x = body[kind](x, at(p[kind], first))
-            else:
-                x, _ = jax.lax.scan(
-                    lambda x, q, kind=kind: (body[kind](x, q), None), x,
-                    jax.tree.map(lambda a: a[first:first + n], p[kind]))
-        return x, None
-
     loose, _, segments = c.plan()
-    done = {"loose": 0, "periods": 0, **dict.fromkeys(KINDS, 0)}
-    for seg, n in segments:
-        first = done[seg]
-        done[seg] += n
-        if seg == "loose":
-            for i in loose[first:first + n]:
-                kind = c.pattern[i]
-                x = body[kind](x, at(params["loose"][kind], done[kind]))
-                done[kind] += 1
-            device_profiler.count("pattern.layers_unrolled", n)
-        else:
-            x, _ = jax.lax.scan(period, x, jax.tree.map(
-                lambda a: a[first:first + n], params["periods"]))
-            device_profiler.count("pattern.periods", n)  # per lowering
-    return _rms_norm(x, params["final_norm"], c.norm_eps)
-
-
-def forward(params, tokens, config: GraniteHybridConfig, mesh=None,
-            rules: Optional[LogicalAxisRules] = None):
-    """tokens [B, S] -> next-token logits [B, S, V] float32."""
-    x = forward_hidden(params, tokens, config, mesh, rules)
-    return jnp.einsum("bsd,vd->bsv", x, params["embed"]).astype(
-        jnp.float32) / config.logits_scaling
+    x, _ = layer_pattern.walk(
+        x, segments, [c.pattern[i] for i in loose], params.get("loose"),
+        params.get("periods"), c.runs(), bodies.__getitem__)
+    return rms_norm(x, params["final_norm"], c.norm_eps)
 
 
 def loss_fn(params, batch, config: GraniteHybridConfig, mesh=None,
             rules: Optional[LogicalAxisRules] = None):
     """Next-token CE of RMSNorm(x_L) E^T / `logits_scaling` through
-    `llama.chunked_ce`, masked by batch["mask"] when given: the division is
+    `blocks.chunked_ce`, masked by batch["mask"] when given: the division is
     applied to the hidden states, which a power of two (the published 8)
     scales exactly. Scalar return (make_train_step contract)."""
     c = config
-    inputs, targets, mask = mla_moe._split(batch)
+    inputs, targets, mask = blocks.split_batch(batch)
     hidden = forward_hidden(params, inputs, c, mesh, rules)
-    hidden = llama._scaled(hidden, 1.0 / c.logits_scaling)
-    return llama.chunked_ce(hidden, params["embed"].T, targets, mask,
-                            chunk=c.loss_chunk_size or inputs.shape[1])
+    hidden = scaled(hidden, 1.0 / c.logits_scaling)
+    return blocks.chunked_ce(hidden, params["embed"].T, targets, mask,
+                             chunk=c.loss_chunk_size or inputs.shape[1])

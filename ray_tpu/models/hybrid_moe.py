@@ -6,9 +6,11 @@ layers to a period, over sigmoid-routed experts chosen within groups
 Layer i (published index) mixes with MLA when (i + 1) % `period` == 0 and
 with KDA otherwise; its second sublayer is a dense SwiGLU (`d_ff`) when
 i < `n_dense_layers` and routed + shared experts otherwise. All pre-norm,
-sharing `models/llama.py`'s RMSNorm, RoPE, SwiGLU sublayer, remat policy and
-chunked cross-entropy, and `models/mla_moe.py`'s MLA and expert sublayers.
-With h = RMSNorm(x), per head (H heads, d = `kda_head_dim` 128):
+over the layer library: `models/blocks.py` (RMSNorm, the SwiGLU sublayer,
+remat, the loss), `models/mixers.py` (MLA), `models/experts.py` (the routed
+block) and `models/layer_pattern.py` (the plan and its walk); KDA is this
+module's own. With h = RMSNorm(x), per head (H heads, d = `kda_head_dim`
+128):
 
 - *KDA* (Kimi Delta Attention, arXiv:2510.26692; `ops/kda.py`): q~, k~, v~ =
   W_q h, W_k h, W_v h; each channel through a causal depthwise conv over
@@ -19,7 +21,7 @@ With h = RMSNorm(x), per head (H heads, d = `kda_head_dim` 128):
       S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
       o_t = S_t^T q_t
   x = x + W_o [RMSNorm_head(o_t) * sigmoid(W_g h)]. No RoPE.
-- *MLA*: `mla_moe._mla_sublayer` with q = W_q h directly (no q latent),
+- *MLA*: `mixers.mla_sublayer` with q = W_q h directly (no q latent),
   RMSNorm per head on q and k a part at a time (the 128 score channels,
   the 64 rotary ones), RoPE on the rotary channels, pairs (2i, 2i + 1)
   with `rope_interleave` (the language model's published value), and
@@ -34,16 +36,14 @@ With h = RMSNorm(x), per head (H heads, d = `kda_head_dim` 128):
   form is not published.
 
 `layers` lists the published indices this program holds, in order (all of
-`n_layers_published` by default: the whole model). The expert layers that
-fill whole ALIGNED periods (indices p * period .. p * period + period - 1,
-none of them dense) run as ONE `lax.scan` over the stacked periods whose
-body is an inner scan over the period's stacked KDA layers and then its
-MLA layer: two layer bodies traced, whatever the depth. The others (dense
-layers; expert layers of a period that is not whole here, published 2-5)
-are unrolled. Remat is per layer; the flash call's `o` and `lse` and the
-KDA call's `o` are saved beside what the policy saves.
+`n_layers_published` by default: the whole model). `layer_pattern.walk` runs
+them: the expert layers that fill whole ALIGNED periods (indices p * period
+.. p * period + period - 1, none of them dense) as one scan, a period its
+stacked KDA layers and then its MLA layer; the others (dense layers; expert
+layers of a period that is not whole here, published 2-5) unrolled. Remat
+is per layer, the flash call's `o` and `lse` and the KDA call's `o` saved.
 
-The share: `mla_moe`'s (`n_experts_held`, `first_expert`).
+The share: `experts.py`'s (`n_experts_held`, `first_expert`).
 """
 
 from __future__ import annotations
@@ -56,18 +56,19 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import layer_pattern, llama, mla_moe
-from ray_tpu.models.llama import _residual, _rms_norm
+from ray_tpu.models import blocks, experts, layer_pattern, mixers
+from ray_tpu.models.blocks import residual, rms_norm
 from ray_tpu.ops import kda as kda_op
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUALS
-from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
+from ray_tpu.parallel.sharding import LogicalAxisRules
 
 
 @dataclasses.dataclass(frozen=True)
-class HybridMoeConfig:
+class HybridMoeConfig(experts.Share):
     """`layers`: the published indices held here (None: all). `d_ff` is the
     dense layers' width, `d_ff_expert` ONE expert's. The MLA and expert
-    fields are `mla_moe.MlaMoeConfig`'s, whose sublayers read them here."""
+    fields carry `mla_moe.MlaMoeConfig`'s names: the same sublayers
+    (`mixers.mla_sublayer`, `experts.expert_sublayer`) read them."""
     vocab_size: int = 157_184
     d_model: int = 2560
     n_layers_published: int = 42
@@ -105,7 +106,7 @@ class HybridMoeConfig:
     remat: bool = True
     remat_policy: str = "dots"
     loss_chunk_size: int = 0
-    score = "sigmoid"              # `mla_moe._expert_sublayer` reads it
+    score = "sigmoid"              # `experts.routing` reads it
 
     def __post_init__(self):
         for name in ("layers", "expert_swiglu_limits",
@@ -114,12 +115,7 @@ class HybridMoeConfig:
             if v is not None and not isinstance(v, tuple):
                 object.__setattr__(self, name, tuple(v))
         held = self.held_layers
-        if list(held) != sorted(set(held)) or not held \
-                or not 0 <= held[0] <= held[-1] < self.n_layers_published:
-            raise ValueError(f"layers {held} of {self.n_layers_published}")
-        if not (0 <= self.first_expert
-                and self.first_expert + self.n_experts_held <= self.n_experts):
-            raise ValueError("held experts outside the router's outputs")
+        self.held  # raises where the share is outside the router's outputs
         if self.kda_lower_bound < -5.0 or self.kda_lower_bound >= 0:
             raise ValueError("ops/kda.py takes a log decay in [-5, 0) a step")
         for i in held:
@@ -144,15 +140,8 @@ class HybridMoeConfig:
 
     @property
     def held_layers(self) -> Tuple[int, ...]:
-        return self.layers if self.layers is not None \
-            else tuple(range(self.n_layers_published))
-
-    @property
-    def held(self):
-        """`moe_layer`'s `held`: None where every expert is here."""
-        if self.n_experts_held == self.n_experts:
-            return None
-        return self.first_expert, self.n_experts_held
+        return layer_pattern.held_layers(self.layers,
+                                         self.n_layers_published)
 
     def is_mla(self, i: int) -> bool:
         return (i + 1) % self.period == 0
@@ -170,7 +159,7 @@ class HybridMoeConfig:
         c = self
         d = c.d_model
         kda = kda_num_params(c) + 2 * d
-        mla = mla_moe.mla_num_params(c) + 2 * d
+        mla = mixers.mla_num_params(c) + 2 * d
         routed = (d * c.n_experts + c.n_experts + 3 * d * c.d_ff_expert
                   * (c.n_experts_held + c.n_shared_experts))
         total = 2 * c.vocab_size * d + d
@@ -191,10 +180,6 @@ def kda_num_params(c) -> int:
 # parameters
 # --------------------------------------------------------------------------
 
-_FFN_AXES = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"),
-             "w_down": ("mlp", "embed")}
-
-
 def _kda_axes(L):
     proj = L + ("embed", "heads", "kv")
     return {
@@ -207,28 +192,24 @@ def _kda_axes(L):
     }
 
 
-def _mixer_axes(L, config, mla: bool):
-    return mla_moe._mla_axes(L, config) if mla else _kda_axes(L)
-
-
 def param_logical_axes(config: HybridMoeConfig) -> Dict[str, Any]:
     c = config
     dense, loose, periods, _ = c.plan()
     L = ("layers",)
-    ffn = {k: L + v for k, v in _FFN_AXES.items()}
     axes = {"embed": ("vocab", "embed"), "final_norm": (None,),
             "lm_head": ("embed", "vocab")}
     if dense:
-        axes["dense"] = {**_kda_axes(L), **ffn}
+        axes["dense"] = {**_kda_axes(L), **blocks.ffn_axes(L)}
     for name, mla in (("kda", False), ("mla", True)):
         if any(c.is_mla(i) == mla for i in loose):
             axes.setdefault("loose", {})[name] = {
-                **_mixer_axes(L, c, mla), **mla_moe._routed_axes(L)}
+                **(mixers.mla_axes(L, c) if mla else _kda_axes(L)),
+                **experts.routed_axes(L)}
     if periods:
         axes["periods"] = {
             "kda": {**_kda_axes(L + (None,)),
-                    **mla_moe._routed_axes(L + (None,))},
-            "mla": {**mla_moe._mla_axes(L, c), **mla_moe._routed_axes(L)}}
+                    **experts.routed_axes(L + (None,))},
+            "mla": {**mixers.mla_axes(L, c), **experts.routed_axes(L)}}
     return axes
 
 
@@ -241,8 +222,8 @@ def _init_kda(config, key):
     c = config
     h, d = c.n_heads, c.kda_head_dim
     ks = jax.random.split(key, 12)
-    proj = lambda k: mla_moe._dense(c, k, (c.d_model, h, d), c.d_model)  # noqa: E731
-    conv = lambda k: mla_moe._dense(c, k, (c.conv_size, h, d), c.conv_size)  # noqa: E731
+    proj = lambda k: blocks.dense(c, k, (c.d_model, h, d), c.d_model)  # noqa: E731
+    conv = lambda k: blocks.dense(c, k, (c.conv_size, h, d), c.conv_size)  # noqa: E731
     ones = partial(jnp.ones, dtype=c.dtype)
     return {
         "attn_norm": ones((c.d_model,)),
@@ -252,9 +233,9 @@ def _init_kda(config, key):
         "dt_bias": -jax.random.uniform(ks[7], (h, d), minval=1.0, maxval=5.0),
         "a_log": jnp.log(jax.random.uniform(ks[8], (h,), minval=1.0,
                                             maxval=16.0)),
-        "w_b": mla_moe._dense(c, ks[9], (c.d_model, h), c.d_model),
+        "w_b": blocks.dense(c, ks[9], (c.d_model, h), c.d_model),
         "w_g": proj(ks[10]), "o_norm": ones((d,)),
-        "wo": mla_moe._dense(c, ks[11], (h, d, c.d_model), h * d),
+        "wo": blocks.dense(c, ks[11], (h, d, c.d_model), h * d),
         "mlp_norm": ones((c.d_model,)),
     }
 
@@ -266,15 +247,15 @@ def init(config: HybridMoeConfig, key) -> Dict[str, Any]:
     dense, loose, periods, _ = c.plan()
 
     def mixer(key, mla):
-        return mla_moe._init_mla(c, key) if mla else _init_kda(c, key)
+        return mixers.init_mla(c, key) if mla else _init_kda(c, key)
 
     def dense_layer(key):
         k_mix, *ks = jax.random.split(key, 4)
-        return {**mixer(k_mix, False), **mla_moe._init_ffn(c, ks, (), c.d_ff)}
+        return {**mixer(k_mix, False), **blocks.init_ffn(c, ks, (), c.d_ff)}
 
     def expert_layer(key, mla):
         k_mix, k_r, k_b, *ks = jax.random.split(key, 9)
-        return {**mixer(k_mix, mla), **mla_moe._init_routed(c, k_r, k_b, ks)}
+        return {**mixer(k_mix, mla), **experts.init_routed(c, k_r, k_b, ks)}
 
     def stack(key, n, mla):
         return jax.vmap(partial(expert_layer, mla=mla))(
@@ -282,10 +263,10 @@ def init(config: HybridMoeConfig, key) -> Dict[str, Any]:
 
     k_embed, k_dense, k_loose, k_periods, k_head = jax.random.split(key, 5)
     params = {
-        "embed": mla_moe._dense(c, k_embed, (c.vocab_size, c.d_model), 1),
+        "embed": blocks.dense(c, k_embed, (c.vocab_size, c.d_model), 1),
         "final_norm": jnp.ones((c.d_model,), c.dtype),
-        "lm_head": mla_moe._dense(c, k_head, (c.d_model, c.vocab_size),
-                                  c.d_model),
+        "lm_head": blocks.dense(c, k_head, (c.d_model, c.vocab_size),
+                                c.d_model),
     }
     if dense:
         params["dense"] = jax.vmap(dense_layer)(
@@ -327,7 +308,7 @@ def _kda_sublayer(x, p, config: HybridMoeConfig, mesh=None,
     """x [B, S, D] -> x + KDA(RMSNorm(x)) (the module's docstring)."""
     c = config
     d = c.kda_head_dim
-    h = _rms_norm(x, p["attn_norm"], c.norm_eps)
+    h = rms_norm(x, p["attn_norm"], c.norm_eps)
     proj = lambda w: jnp.einsum("bsd,dhk->bshk", h, w)  # noqa: E731
     heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
     with jax.named_scope("kda.conv"):
@@ -345,28 +326,22 @@ def _kda_sublayer(x, p, config: HybridMoeConfig, mesh=None,
         o = kda_op.kda(
             *(heads_first(t.astype(c.dtype)) for t in (q, k, v)),
             heads_first(g), heads_first(beta))
-    o = _rms_norm(heads_first(o), p["o_norm"], c.norm_eps)
+    o = rms_norm(heads_first(o), p["o_norm"], c.norm_eps)
     o = (o.astype(jnp.float32) * gate).astype(c.dtype)
     device_profiler.count("kda.layers", 1)  # per lowering
     x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    return _residual(x, mesh, rules)
+    return residual(x, mesh, rules)
 
 
-def _layer(x, p, positions, config, mesh, rules, mla: bool, dense: bool):
+def layer(x, p, positions, config, mesh, rules, mla: bool, dense: bool):
     """One layer -> (x, the chosen experts [B * S, k] or None)."""
     if mla:
-        x = mla_moe._mla_sublayer(x, p, positions, config, mesh, rules)
+        x = mixers.mla_sublayer(x, p, positions, config, mesh, rules)
     else:
         x = _kda_sublayer(x, p, config, mesh, rules)
     if dense:
-        return llama._mlp_sublayer(x, p, config, mesh, rules), None
-    return mla_moe._expert_sublayer(x, p, config, mesh, rules)
-
-
-def _checkpointed(fn, config):
-    """`mla_moe._checkpointed`, the KDA call's `o` saved too."""
-    return mla_moe._checkpointed(
-        fn, config, FLASH_RESIDUALS + kda_op.RESIDUAL_NAMES)
+        return blocks.mlp_sublayer(x, p, config, mesh, rules), None
+    return experts.expert_sublayer(x, p, config, mesh, rules)
 
 
 def forward_hidden(params, tokens, config: HybridMoeConfig, mesh=None,
@@ -374,75 +349,27 @@ def forward_hidden(params, tokens, config: HybridMoeConfig, mesh=None,
     """tokens [B, S] -> (final-norm hidden states [B, S, D], the chosen
     experts of every expert layer [L, B * S, k], in the layers' order)."""
     c = config
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
-                                    mesh=mesh, rules=rules)
-    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
-    x = _residual(x, mesh, rules)
-    body = lambda mla, dense=False: _checkpointed(partial(  # noqa: E731
-        _layer, positions=positions, config=c, mesh=mesh, rules=rules,
-        mla=mla, dense=dense), c)
-    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)  # noqa: E731
-    _, loose, _, segments = c.plan()
-    kda_layer, mla_layer = body(False), body(True)
-
-    def period(x, p):
-        x, chosen = jax.lax.scan(kda_layer, x, p["kda"])
-        x, last = mla_layer(x, p["mla"])
-        return x, jnp.concatenate([chosen, last[None]])
-
-    chosen = []
-    done = {"dense": 0, "loose": 0, "periods": 0, "kda": 0, "mla": 0}
-    for kind, n in segments:
-        first = done[kind]
-        done[kind] += n
-        if kind == "dense":
-            dense_layer = body(False, dense=True)
-            for i in range(first, first + n):
-                x, _ = dense_layer(x, at(params["dense"], i))
-        elif kind == "loose":
-            for i in loose[first:first + n]:
-                name = "mla" if c.is_mla(i) else "kda"
-                x, e = (mla_layer if c.is_mla(i) else kda_layer)(
-                    x, at(params["loose"][name], done[name]))
-                done[name] += 1
-                chosen.append(e[None])
-            device_profiler.count("pattern.layers_unrolled", n)
-        else:
-            x, e = jax.lax.scan(period, x, jax.tree.map(
-                lambda a: a[first:first + n], params["periods"]))
-            chosen.append(e.reshape((n * c.period,) + e.shape[2:]))
-            device_profiler.count("pattern.periods", n)  # per lowering
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    return x, jnp.concatenate(chosen) if chosen else None
-
-
-def forward(params, tokens, config: HybridMoeConfig, mesh=None,
-            rules: Optional[LogicalAxisRules] = None):
-    """tokens [B, S] -> next-token logits [B, S, V] float32."""
-    x, _ = forward_hidden(params, tokens, config, mesh, rules)
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
+    x, positions = blocks.embed_tokens(params, tokens, mesh, rules)
+    x = residual(x.astype(c.dtype), mesh, rules)
+    # remat a layer; the flash call's and the KDA call's outputs saved too
+    body = lambda mla, dense=False: blocks.checkpointed(partial(  # noqa: E731
+        layer, positions=positions, config=c, mesh=mesh, rules=rules,
+        mla=mla, dense=dense), c, FLASH_RESIDUALS + kda_op.RESIDUAL_NAMES)
+    dense, loose, _, segments = c.plan()
+    x, chosen = layer_pattern.walk(
+        x, segments,
+        ["dense"] * len(dense) + ["mla" if c.is_mla(i) else "kda"
+                                  for i in loose],
+        {"dense": params.get("dense"), **params.get("loose", {})},
+        params.get("periods"), [("kda", c.period - 1), ("mla", None)],
+        {"dense": body(False, dense=True), "kda": body(False),
+         "mla": body(True)}.__getitem__)
+    return rms_norm(x, params["final_norm"], c.norm_eps), chosen
 
 
 def loss_fn(params, batch, config: HybridMoeConfig, mesh=None,
             rules: Optional[LogicalAxisRules] = None):
-    """Next-token CE through `llama.chunked_ce`, masked by batch["mask"]
+    """Next-token CE (`blocks.next_token_loss`), masked by batch["mask"]
     when given. Scalar return (make_train_step contract)."""
-    c = config
-    inputs, targets, mask = mla_moe._split(batch)
-    hidden, _ = forward_hidden(params, inputs, c, mesh, rules)
-    return llama.chunked_ce(hidden, params["lm_head"], targets, mask,
-                            chunk=c.loss_chunk_size or inputs.shape[1])
-
-
-@partial(jax.jit, static_argnames=("config",))
-def routing_stats(params, tokens, config: HybridMoeConfig):
-    """tokens [B, S + 1] -> int32 [expert layers]: the LIVE rows of each
-    expert layer, the (token, choice) pairs whose expert is held here.
-    Outside the train step, for tests and chip runs."""
-    c = config
-    _, chosen = forward_hidden(params, tokens[:, :-1], c)
-    local = chosen - c.first_expert
-    return jnp.sum((local >= 0) & (local < c.n_experts_held), axis=(1, 2),
-                   dtype=jnp.int32)
+    return blocks.next_token_loss(forward_hidden, None, params, batch, config,
+                                  mesh, rules)

@@ -1,10 +1,13 @@
 """Llama stack + routed experts: the Mixtral and OLMoE families, TPU-first.
 
-The attention sublayer is `models/llama.py`'s (GQA or MHA, RoPE, optional
-QK-norm); the MLP is top-k routed SwiGLU experts through `parallel/moe.py`:
-one dropless sorted dispatch over grouped matmuls, or, on a mesh with an
-`ep` axis, experts sharded across chips with a capacity-bounded
-`all_to_all` exchange. The two families differ in static config only:
+`LlamaConfig` and llama's parameters with the dense MLP taken out
+(whole-model reuse: one of the two model-to-model edges left, with
+`sdar -> mixtral`); the attention sublayer is `blocks.attn_sublayer` (GQA or
+MHA, RoPE, optional QK-norm); the MLP is top-k routed SwiGLU experts through
+`parallel/moe.py`: one dropless sorted dispatch over grouped matmuls, or, on
+a mesh with an `ep` axis, experts sharded across chips with a
+capacity-bounded `all_to_all` exchange. The two families differ in static
+config only:
 
 - Mixtral: GQA, top-2 of 8, the k weights renormalised (`norm_topk_prob`).
 - OLMoE: MHA, `qk_norm`, top-8 of 64, weights not renormalised, and a
@@ -23,14 +26,15 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
-from ray_tpu.models.llama import LlamaConfig, _remat_policy, _rms_norm
+from ray_tpu.models import blocks, experts, llama
+from ray_tpu.models.blocks import remat_policy, rms_norm
+from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.parallel.moe import MoEAux, moe_layer, moe_shard_map
 from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
 
 
 @dataclasses.dataclass(frozen=True)
-class MixtralConfig(LlamaConfig):
+class MixtralConfig(LlamaConfig, experts.Share):
     """`d_ff` is the width of ONE expert. `n_experts` is the router's
     outputs; with `n_experts_held` fewer than that this program is one chip's
     share of an expert-parallel deployment run without its exchange: it
@@ -52,13 +56,6 @@ class MixtralConfig(LlamaConfig):
             n_kv_heads=2, d_head=32, d_ff=256, max_seq_len=512,
             n_experts=4, experts_per_token=2,
         )
-
-    @property
-    def held(self):
-        """`moe_layer`'s `held`: None where every expert is here."""
-        if self.n_experts_held in (None, self.n_experts):
-            return None
-        return self.first_expert, self.n_experts_held
 
     def num_params(self) -> int:
         base = super().num_params()
@@ -123,7 +120,7 @@ def _expert_ffn(p, x):
     return (jax.nn.silu(gate) * up) @ p["w_down"]
 
 
-def _moe_block(h, layer_p, config: MixtralConfig, mesh):
+def moe_block(h, layer_p, config: MixtralConfig, mesh):
     """h: [B,S,D] -> (out [B,S,D], MoEAux of this layer)."""
     c = config
     b, s, d = h.shape
@@ -156,19 +153,19 @@ def hidden_states(params, tokens, config: MixtralConfig, mesh=None,
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    # the table's embed dim in the activation layout: llama.forward_hidden
+    # the table's embed dim in the activation layout: `blocks.embed_tokens`
     table = lc(params["embed"], ("vocab", "act_embed"))
-    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
+    x = blocks.embed_rows(table, tokens, mesh).astype(c.dtype)
     x = lc(x, ("batch", "seq", "act_embed"))
 
     def layer_fn(x, layer_p):
-        x = llama._attn_sublayer(x, layer_p, positions, c, mesh, rules, mask)
-        h2 = _rms_norm(x, layer_p["mlp_norm"], c.norm_eps)
-        moe_out, aux = _moe_block(h2, layer_p, c, mesh)
+        x = blocks.attn_sublayer(x, layer_p, positions, c, mesh, rules, mask)
+        h2 = rms_norm(x, layer_p["mlp_norm"], c.norm_eps)
+        moe_out, aux = moe_block(h2, layer_p, c, mesh)
         return lc(x + moe_out, ("batch", "seq", "act_embed")), aux
 
     if c.remat:
-        layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
+        layer_fn = jax.checkpoint(layer_fn, policy=remat_policy(c))
 
     return jax.lax.scan(layer_fn, x, params["layers"])
 
@@ -178,7 +175,7 @@ def forward_hidden(params, tokens, config: MixtralConfig, mesh=None,
     """tokens [B,S] -> (final-norm hidden states [B,S,D], MoEAux per
     layer, as `hidden_states`)."""
     x, aux = hidden_states(params, tokens, config, mesh, rules)
-    return _rms_norm(x, params["final_norm"], config.norm_eps), aux
+    return rms_norm(x, params["final_norm"], config.norm_eps), aux
 
 
 def forward(params, tokens, config: MixtralConfig, mesh=None,
@@ -198,18 +195,12 @@ def aux_loss(aux: MoEAux, config: MixtralConfig):
 def loss_fn(params, batch, config: MixtralConfig, mesh=None,
             rules: Optional[LogicalAxisRules] = None):
     """Next-token CE (masked by batch["mask"] when given; in sequence chunks
-    of `loss_chunk_size`, or one chunk, through `llama.chunked_ce`) +
+    of `loss_chunk_size`, or one chunk, through `blocks.chunked_ce`) +
     `aux_loss`, whose terms are statistics of EVERY token of the batch,
     masked or not.
     Scalar return (make_train_step contract, train/step.py:100)."""
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-        mask = batch.get("mask")
-    else:
-        tokens = batch["tokens"]
-        inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        mask = None
+    inputs, targets, mask = blocks.split_batch(batch)
     hidden, aux = forward_hidden(params, inputs, config, mesh, rules)
-    ce = llama.chunked_ce(hidden, params["lm_head"], targets, mask,
-                          chunk=config.loss_chunk_size or hidden.shape[1])
+    ce = blocks.chunked_ce(hidden, params["lm_head"], targets, mask,
+                           chunk=config.loss_chunk_size or hidden.shape[1])
     return ce + aux_loss(aux, config)

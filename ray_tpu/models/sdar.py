@@ -1,13 +1,15 @@
 """Block-diffusion training of a routed-experts decoder: the SDAR family
 (SDAR-30B-A3B-Chat by config: Qwen3-MoE layers), TPU-first.
 
-The layers are `models/mixtral.py`'s (llama's attention sublayer + top-k
-routed SwiGLU experts through `parallel/moe.py`, all experts or one chip's
-share, `n_experts_held`) with Qwen3's per-head QK-norm: an RMSNorm of every
-head over its own `d_head` channels, one `[d_head]` scale for all q heads
-and one for all kv heads (`llama._qk_norm` reads the form off the scale's
-shape). What differs is the training step, BD3-LM's vectorised block-
-diffusion objective (arXiv:2503.09573), which SDAR's modelling code follows:
+The layers are `models/mixtral.py`'s, through `mixtral.hidden_states`
+(whole-model reuse: one of the two model-to-model edges left, with
+`mixtral -> llama`): `blocks.attn_sublayer` + top-k routed SwiGLU experts
+through `parallel/moe.py`, all experts or one chip's share,
+`n_experts_held`, with Qwen3's per-head QK-norm: an RMSNorm of every head
+over its own `d_head` channels, one `[d_head]` scale for all q heads and one
+for all kv heads (`blocks.qk_norm` reads the form off the scale's shape).
+What differs is the training step, BD3-LM's vectorised block-diffusion
+objective (arXiv:2503.09573), which SDAR's modelling code follows:
 
 - x_0 [B, L] is the data. Per row t ~ U(0, 1), p = (1 - eps) t + eps; each
   token is replaced by `mask_token_id` with probability p, independently
@@ -38,11 +40,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import llama, mixtral
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models import blocks, experts, mixtral
 from ray_tpu.models.mixtral import MixtralConfig
 from ray_tpu.ops.flash_attention import BlockDiffusion
-from ray_tpu.parallel import moe
 from ray_tpu.parallel.sharding import LogicalAxisRules
 
 
@@ -175,8 +175,8 @@ def forward_hidden(params, x_t, x_0, config: SdarConfig, mesh=None,
     """-> (final-norm hidden states of the x_t half [B, L, D], MoEAux per
     layer): the head never sees the x_0 half."""
     x, aux = hidden_states(params, x_t, x_0, config, mesh, rules)
-    return _rms_norm(x[:, :x_0.shape[1]], params["final_norm"],
-                     config.norm_eps), aux
+    return blocks.rms_norm(x[:, :x_0.shape[1]], params["final_norm"],
+                           config.norm_eps), aux
 
 
 def _data(batch):
@@ -185,7 +185,7 @@ def _data(batch):
     return batch["tokens"][:, :-1], None
 
 
-def _noised(batch, x_0, config: SdarConfig):
+def noised_batch(batch, x_0, config: SdarConfig):
     """(noised, p, x_t): the batch's own draw (`noise_mask` [B, L] bool,
     `noise_p` [B]) where a data pipeline made one, else `noise`'s."""
     with jax.named_scope("bd.noise"):
@@ -207,7 +207,7 @@ def loss_fn(params, batch, config: SdarConfig, mesh=None,
     contract)."""
     c = config
     x_0, mask = _data(batch)
-    noised, p, x_t = _noised(batch, x_0, c)
+    noised, p, x_t = noised_batch(batch, x_0, c)
     hidden, aux = forward_hidden(params, x_t, x_0, c, mesh, rules)
     with jax.named_scope("bd.loss"):
         weights = noised.astype(jnp.float32) / p[:, None]
@@ -215,7 +215,7 @@ def loss_fn(params, batch, config: SdarConfig, mesh=None,
             weights = weights * mask
         data_tokens = jnp.float32(x_0.size) if mask is None \
             else jnp.maximum(jnp.sum(mask), 1.0)
-        ce = llama.chunked_ce(
+        ce = blocks.chunked_ce(
             hidden, params["lm_head"], x_0, weights,
             chunk=c.loss_chunk_size or hidden.shape[1],
             denominator=data_tokens)
@@ -230,21 +230,14 @@ def routing_stats(params, tokens, config: SdarConfig):
     for tests and chip runs."""
     c = config
     x_0, _ = _data({"tokens": tokens})
-    _, _, x_t = _noised({}, x_0, c)
+    _, _, x_t = noised_batch({}, x_0, c)
     _, aux = hidden_states(params, x_t, x_0, c)
-    first, n_held = c.held or (0, c.n_experts)
-    local = aux.experts - first
-    return jnp.sum((local >= 0) & (local < n_held), axis=(1, 2),
-                   dtype=jnp.int32)
+    return experts.live_rows(aux.experts, c)
 
 
 def routing_loads(params, tokens, config: SdarConfig):
     """-> float32 [n_layers]: each layer's live rows over the rows of the
-    capacity it runs at (`moe.capacity_load`): what of its buffer the row
-    moves visit."""
-    c = config
-    rows = 2 * tokens.shape[0] * (tokens.shape[1] - 1)
-    _, n_held = c.held or (0, c.n_experts)
-    return moe.capacity_load(
-        routing_stats(params, tokens, c), moe.share_capacities(
-            rows, c.experts_per_token, n_held, c.n_experts))
+    capacity it runs at (`experts.capacity_loads`)."""
+    return experts.capacity_loads(
+        routing_stats(params, tokens, config),
+        2 * tokens.shape[0] * (tokens.shape[1] - 1), config)

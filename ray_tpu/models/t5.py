@@ -20,7 +20,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _remat_policy, _rms_norm, _rope, embed_rows
+from ray_tpu.models.blocks import (
+    embed_rows, ffn_axes, remat_policy, rms_norm, rope)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,23 +74,18 @@ def param_logical_axes(config: T5Config) -> Dict[str, Any]:
         "wv": L + ("embed", "heads", "kv"),
         "wo": L + ("heads", "kv", "embed"),
     }
-    mlp = lambda L: {  # noqa: E731
-        "w_gate": L + ("embed", "mlp"),
-        "w_up": L + ("embed", "mlp"),
-        "w_down": L + ("mlp", "embed"),
-    }
     return {
         "embed": ("vocab", "embed"),
         "enc_layers": {
             "ln1": E + (None,), **attn(E),
-            "ln2": E + (None,), **mlp(E),
+            "ln2": E + (None,), **ffn_axes(E),
         },
         "dec_layers": {
             "ln1": D + (None,),
             **{f"self_{k}": v for k, v in attn(D).items()},
             "ln2": D + (None,),
             **{f"cross_{k}": v for k, v in attn(D).items()},
-            "ln3": D + (None,), **mlp(D),
+            "ln3": D + (None,), **ffn_axes(D),
         },
         "enc_final_ln": (None,),
         "dec_final_ln": (None,),
@@ -169,18 +165,18 @@ def forward_encoder(params, src_tokens, config: T5Config):
     positions = jnp.arange(src_tokens.shape[1])[None, :]
 
     def layer_fn(x, p):
-        h = _rms_norm(x, p["ln1"], c.norm_eps)
-        q = _rope(_heads(h, p["wq"]), positions, c.rope_theta)
-        k = _rope(_heads(h, p["wk"]), positions, c.rope_theta)
+        h = rms_norm(x, p["ln1"], c.norm_eps)
+        q = rope(_heads(h, p["wq"]), positions, c.rope_theta)
+        k = rope(_heads(h, p["wk"]), positions, c.rope_theta)
         x = x + _attend(q, k, _heads(h, p["wv"]), bias, p["wo"], c)
-        h = _rms_norm(x, p["ln2"], c.norm_eps)
+        h = rms_norm(x, p["ln2"], c.norm_eps)
         return x + _gated_mlp(h, p)
 
     if c.remat:
-        layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
+        layer_fn = jax.checkpoint(layer_fn, policy=remat_policy(c))
     x, _ = jax.lax.scan(lambda x, p: (layer_fn(x, p), None), x,
                         params["enc_layers"])
-    return _rms_norm(x, params["enc_final_ln"], c.norm_eps), mask
+    return rms_norm(x, params["enc_final_ln"], c.norm_eps), mask
 
 
 def forward_decoder(params, enc_hidden, src_mask, tgt_tokens,
@@ -195,24 +191,24 @@ def forward_decoder(params, enc_hidden, src_mask, tgt_tokens,
     x = embed_rows(params["embed"].astype(c.dtype), tgt_tokens)
 
     def layer_fn(x, p):
-        h = _rms_norm(x, p["ln1"], c.norm_eps)
-        q = _rope(_heads(h, p["self_wq"]), positions, c.rope_theta)
-        k = _rope(_heads(h, p["self_wk"]), positions, c.rope_theta)
+        h = rms_norm(x, p["ln1"], c.norm_eps)
+        q = rope(_heads(h, p["self_wq"]), positions, c.rope_theta)
+        k = rope(_heads(h, p["self_wk"]), positions, c.rope_theta)
         x = x + _attend(q, k, _heads(h, p["self_wv"]), causal,
                         p["self_wo"], c)
-        h = _rms_norm(x, p["ln2"], c.norm_eps)
+        h = rms_norm(x, p["ln2"], c.norm_eps)
         x = x + _attend(_heads(h, p["cross_wq"]),
                         _heads(enc_hidden, p["cross_wk"]),
                         _heads(enc_hidden, p["cross_wv"]),
                         cross_bias, p["cross_wo"], c)
-        h = _rms_norm(x, p["ln3"], c.norm_eps)
+        h = rms_norm(x, p["ln3"], c.norm_eps)
         return x + _gated_mlp(h, p)
 
     if c.remat:
-        layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
+        layer_fn = jax.checkpoint(layer_fn, policy=remat_policy(c))
     x, _ = jax.lax.scan(lambda x, p: (layer_fn(x, p), None), x,
                         params["dec_layers"])
-    x = _rms_norm(x, params["dec_final_ln"], c.norm_eps)
+    x = rms_norm(x, params["dec_final_ln"], c.norm_eps)
     return (x @ params["lm_head"]).astype(jnp.float32)
 
 

@@ -15,7 +15,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _remat_policy
+from ray_tpu.models.blocks import remat_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,7 +163,7 @@ def forward(params, images, config: ViTConfig):
         return x + ff
 
     if c.remat:
-        layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
+        layer_fn = jax.checkpoint(layer_fn, policy=remat_policy(c))
     x, _ = jax.lax.scan(lambda x, p: (layer_fn(x, p), None), x,
                         params["layers"])
     x = _ln(x, params["final_ln_scale"], params["final_ln_bias"], c.norm_eps)

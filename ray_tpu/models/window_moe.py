@@ -6,10 +6,11 @@ sublayer EARLIER, the attention's (Laguna-XS.2 and SmallThinker-21BA3B by
 config: `model_type` `laguna`, `model_name` `smallthinker_21b_instruct`),
 TPU-first, training only.
 
-All pre-norm, sharing `models/llama.py`'s RMSNorm, attention and SwiGLU
-sublayers, remat policy and chunked cross-entropy, and `models/mla_moe.py`'s
-expert sublayer. By the three per-layer lists of the config, layer i
-(published index), with h = RMSNorm(x):
+All pre-norm, over the layer library: `models/blocks.py` (RMSNorm, the
+attention and SwiGLU sublayers, remat, the loss), `models/experts.py` (the
+routed block) and `models/layer_pattern.py` (the plan and its walk). By the
+three per-layer lists of the config, layer i (published index), with
+h = RMSNorm(x):
 
 - *attention*, `layer_types[i]`: GQA over `heads_per_layer[i]` query heads
   (so `wq` and `wo` differ in SHAPE by kind) and `n_kv_heads` KV heads of
@@ -17,7 +18,7 @@ expert sublayer. By the three per-layer lists of the config, layer i
   `sliding_attention`: query t sees keys t - `window` < j <= t
   (`ops/flash_attention.SlidingWindow`: the kernels walk the tiles the
   window touches and no others); `full_attention`: causal. RoPE in the
-  form `rope_parameters` gives the kind (`llama.Rotary`): its own theta, on
+  form `rope_parameters` gives the kind (`blocks.Rotary`): its own theta, on
   the leading `partial_rotary_factor` of a head, under YaRN (blended
   frequencies, cos and sin times `attention_factor`) where `rope_type`
   says so; NO rotary embedding on q or k where `rope_layout[i]` is 0 (a
@@ -25,9 +26,9 @@ expert sublayer. By the three per-layer lists of the config, layer i
   stacks a kind's layers, so a kind is all one or the other).
   With `attn_gate`, attn_head * sigmoid(w_head . h) before W_o;
   with `qk_norm`, an RMSNorm of every head of q and k over its own
-  channels before RoPE (`llama._qk_norm`'s [D] form).
+  channels before RoPE (`blocks.qk_norm`'s [D] form).
 - *feed-forward*, `mlp_layer_types[i]`: `dense`, a SwiGLU of `d_ff`;
-  `sparse`, `mla_moe._expert_sublayer` without a router bias: `score`
+  `sparse`, `experts.expert_sublayer` without a router bias: `score`
   ("sigmoid" or "softmax") over `n_experts`, the top `experts_per_token`
   on the scores, weights the chosen scores (over their sum with
   `norm_topk_prob`) x `routed_scaling_factor`, experts SwiGLU of
@@ -37,21 +38,19 @@ expert sublayer. By the three per-layer lists of the config, layer i
   reads is `router_input`: "ffn_input", the sublayer's own normed input
   RMSNorm(x + attention), as every other model here; "attention_input",
   h = RMSNorm(x) that the ATTENTION reads, so the choice of experts is
-  formed before the attention call (`moe.route`, then
-  `moe_layer(routing=)`); "residual", x itself before that norm. No
+  formed before the attention call (`experts.routing`, then
+  `expert_sublayer(ahead=)`); "residual", x itself before that norm. No
   auxiliary loss.
 
 `layers` lists the published indices this program holds, in order (all by
-default: the whole model). The leading dense layers, if any, are unrolled.
-The pattern's period is the distance from one full layer to the next, and a
-period here ENDS with its full layer (sliding, ..., sliding, full): the
-sparse layers that fill whole such periods run as ONE `lax.scan` over the
-stacked periods whose body is an inner scan over the period's stacked
-sliding layers and then its full layer: two layer bodies traced, whatever
-the depth. The others (published 37-39) are unrolled. Remat is per layer;
-the flash call's `o` and `lse` are saved beside what the policy saves.
+default: the whole model). The pattern's period is the distance from one full
+layer to the next, and a period here ENDS with its full layer (sliding, ...,
+sliding, full): `layer_pattern.walk` scans the sparse layers that fill whole
+such periods, a period its stacked sliding layers and then its full layer,
+and unrolls the others (the leading dense layers; published 37-39). Remat is
+per layer, the flash call's `o` and `lse` saved.
 
-The share: `mla_moe`'s (`n_experts_held`, `first_expert`).
+The share: `experts.py`'s (`n_experts_held`, `first_expert`).
 """
 
 from __future__ import annotations
@@ -64,10 +63,10 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import layer_pattern, llama, mla_moe
-from ray_tpu.models.llama import Rotary, _residual, _rms_norm
+from ray_tpu.models import blocks, experts, layer_pattern
+from ray_tpu.models.blocks import Rotary, residual, rms_norm
 from ray_tpu.ops.flash_attention import SlidingWindow
-from ray_tpu.parallel.sharding import LogicalAxisRules, with_logical_constraint
+from ray_tpu.parallel.sharding import LogicalAxisRules
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -96,7 +95,7 @@ def _frozen(v):
 
 
 @dataclasses.dataclass(frozen=True)
-class WindowMoeConfig:
+class WindowMoeConfig(experts.Share):
     """`layer_types`, `heads_per_layer`, `mlp_layer_types`: a layer's
     attention kind (or the 0 / 1 of a published `sliding_window_layout`: 1
     a sliding layer), its query heads and its feed-forward kind by its
@@ -165,13 +164,8 @@ class WindowMoeConfig:
                 or set(self.mlp_layer_types) - {DENSE, SPARSE}:
             raise ValueError("a layer's attention is full or sliding, its "
                              "feed-forward dense or sparse")
-        held = self.held_layers
-        if list(held) != sorted(set(held)) or not held \
-                or not 0 <= held[0] <= held[-1] < n:
-            raise ValueError(f"layers {held} of {n}")
-        if not (0 <= self.first_expert
-                and self.first_expert + self.n_experts_held <= self.n_experts):
-            raise ValueError("held experts outside the router's outputs")
+        self.held_layers  # raises where they are not the lists', in order
+        self.held  # raises where the share is outside the router's outputs
         for kind in set(self.layer_types):
             if len({h for h, t in zip(self.heads_per_layer, self.layer_types)
                     if t == kind}) != 1:
@@ -223,15 +217,7 @@ class WindowMoeConfig:
 
     @property
     def held_layers(self) -> Tuple[int, ...]:
-        return self.layers if self.layers is not None \
-            else tuple(range(len(self.layer_types)))
-
-    @property
-    def held(self):
-        """`moe_layer`'s `held`: None where every expert is here."""
-        if self.n_experts_held == self.n_experts:
-            return None
-        return self.first_expert, self.n_experts_held
+        return layer_pattern.held_layers(self.layers, len(self.layer_types))
 
     @property
     def n_dense_layers(self) -> int:
@@ -271,7 +257,7 @@ class WindowMoeConfig:
                     if _SHORT[t] == attn)
 
     def rotary(self, attn: str) -> Rotary:
-        """The kind's rotary form; theta 0, which `llama._qkv` reads as no
+        """The kind's rotary form; theta 0, which `blocks.qkv` reads as no
         rotary embedding, where `rope_layout` gives the kind's layers 0."""
         layer_type = FULL if attn == "full" else SLIDING
         if self.rope_layout is not None and not next(
@@ -313,17 +299,14 @@ def attn_num_params(c, heads: int) -> int:
 # --------------------------------------------------------------------------
 
 def _layer_axes(L, config, mlp: str):
-    proj = L + ("embed", "heads", "kv")
-    axes = {"attn_norm": L + (None,), "wq": proj, "wk": proj, "wv": proj,
-            "wo": L + ("heads", "kv", "embed"), "mlp_norm": L + (None,)}
+    axes = {**blocks.attn_axes(L), "mlp_norm": L + (None,)}
     if config.attn_gate:
         axes["w_attn_gate"] = L + ("embed", "heads")
     if config.qk_norm:
         axes.update(q_norm=L + (None,), k_norm=L + (None,))
     if mlp == DENSE:
-        return {**axes, "w_gate": L + ("embed", "mlp"),
-                "w_up": L + ("embed", "mlp"), "w_down": L + ("mlp", "embed")}
-    routed = mla_moe._routed_axes(L)
+        return {**axes, **blocks.ffn_axes(L)}
+    routed = experts.routed_axes(L)
     del routed["router_bias"]  # the choice is on the scores themselves
     if not config.d_ff_shared:
         del routed["shared"]
@@ -362,7 +345,7 @@ def _init_layer(config, attn: str, mlp: str, key):
     c = config
     d, dh, heads = c.d_model, c.d_head, c.heads(attn)
     ones = partial(jnp.ones, dtype=c.dtype)
-    dense = partial(mla_moe._dense, c)
+    dense = partial(blocks.dense, c)
     ks = jax.random.split(key, 12)
     p = {"attn_norm": ones((d,)),
          "wq": dense(ks[0], (d, heads, dh), d),
@@ -375,13 +358,13 @@ def _init_layer(config, attn: str, mlp: str, key):
     if c.qk_norm:
         p.update(q_norm=ones((dh,)), k_norm=ones((dh,)))
     if mlp == DENSE:
-        return {**p, **mla_moe._init_ffn(c, ks[5:8], (), c.d_ff)}
+        return {**p, **blocks.init_ffn(c, ks[5:8], (), c.d_ff)}
     p.update(router=(jax.random.normal(ks[5], (d, c.n_experts))
                      * 0.02).astype(c.dtype),
-             experts=mla_moe._init_ffn(c, ks[6:9], (c.n_experts_held,),
-                                       c.d_ff_expert))
+             experts=blocks.init_ffn(c, ks[6:9], (c.n_experts_held,),
+                                     c.d_ff_expert))
     if c.d_ff_shared:
-        p["shared"] = mla_moe._init_ffn(c, ks[9:12], (), c.d_ff_shared)
+        p["shared"] = blocks.init_ffn(c, ks[9:12], (), c.d_ff_shared)
     return p
 
 
@@ -394,10 +377,10 @@ def init(config: WindowMoeConfig, key) -> Dict[str, Any]:
     stack = lambda attn, mlp, key, n: jax.vmap(  # noqa: E731
         partial(_init_layer, c, attn, mlp))(jax.random.split(key, n))
     params = {
-        "embed": mla_moe._dense(c, k_embed, (c.vocab_size, c.d_model), 1),
+        "embed": blocks.dense(c, k_embed, (c.vocab_size, c.d_model), 1),
         "final_norm": jnp.ones((c.d_model,), c.dtype),
-        "lm_head": mla_moe._dense(c, k_head, (c.d_model, c.vocab_size),
-                                  c.d_model)}
+        "lm_head": blocks.dense(c, k_head, (c.d_model, c.vocab_size),
+                                c.d_model)}
     if one:
         params["loose"] = {
             f"{attn}_{mlp}": stack(attn, mlp, jax.random.fold_in(k_one, j), n)
@@ -416,27 +399,27 @@ def init(config: WindowMoeConfig, key) -> Dict[str, Any]:
 # blocks
 # --------------------------------------------------------------------------
 
-def _layer(x, p, positions, config, mesh, rules, attn: str, mlp: str):
+def layer(x, p, positions, config, mesh, rules, attn: str, mlp: str):
     """One layer -> (x, the chosen experts [B * S, k] or None)."""
     c = config
     mask = None
     if attn == "sliding":
         mask = SlidingWindow(c.window)
-    routing = None
+    ahead = None
     if mlp == SPARSE and c.router_input != FFN_INPUT:
         # the router ahead of the attention: on what the attention reads
-        # (`llama._qkv`'s normed input: the compiler keeps one) or on the
+        # (`blocks.qkv`'s normed input: the compiler keeps one) or on the
         # residual itself, so the choice is known before attention runs
         h = x if c.router_input == RESIDUAL \
-            else _rms_norm(x, p["attn_norm"], c.norm_eps)
-        routing = mla_moe._routing(h.reshape(-1, c.d_model), p, c)
+            else rms_norm(x, p["attn_norm"], c.norm_eps)
+        ahead = experts.routing(h.reshape(-1, c.d_model), p, c)
         device_profiler.count("moe.routed_ahead", 1)  # per lowering
-    x = llama._attn_sublayer(x, p, positions, c, mesh, rules, mask=mask,
+    x = blocks.attn_sublayer(x, p, positions, c, mesh, rules, mask=mask,
                              rotary=c.rotary(attn))
     if mlp == DENSE:
-        return llama._mlp_sublayer(x, p, c, mesh, rules), None
-    return mla_moe._expert_sublayer(x, p, c, mesh, rules, routing=routing,
-                                    form=c.expert_form)
+        return blocks.mlp_sublayer(x, p, c, mesh, rules), None
+    return experts.expert_sublayer(x, p, c, mesh, rules, ahead=ahead,
+                                   form=c.expert_form)
 
 
 def forward_hidden(params, tokens, config: WindowMoeConfig, mesh=None,
@@ -444,61 +427,27 @@ def forward_hidden(params, tokens, config: WindowMoeConfig, mesh=None,
     """tokens [B, S] -> (final-norm hidden states [B, S, D], the chosen
     experts of every sparse layer [L, B * S, k], in the layers' order)."""
     c = config
-    b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    table = with_logical_constraint(params["embed"], ("vocab", "act_embed"),
-                                    mesh=mesh, rules=rules)
-    x = llama.embed_rows(table, tokens, mesh).astype(c.dtype)
-    x = _residual(x, mesh, rules)
-    body = lambda attn, mlp: mla_moe._checkpointed(partial(  # noqa: E731
-        _layer, positions=positions, config=c, mesh=mesh, rules=rules,
-        attn=attn, mlp=mlp), c)
-    sliding_layer, full_layer = body("sliding", SPARSE), body("full", SPARSE)
-
-    def period(x, p):
-        x, chosen = jax.lax.scan(sliding_layer, x, p["sliding"])
-        x, last = full_layer(x, p["full"])
-        return x, jnp.concatenate([chosen, last[None]])
-
+    x, positions = blocks.embed_tokens(params, tokens, mesh, rules)
+    x = residual(x.astype(c.dtype), mesh, rules)
+    # a kind of layer is named as its unrolled stack is; a period's two
+    # stacks, "sliding" and "full", hold sparse layers. A wrapper a layer:
+    # every unrolled layer is traced on its own
+    body = lambda kind: blocks.checkpointed(partial(  # noqa: E731
+        layer, positions=positions, config=c, mesh=mesh, rules=rules,
+        attn=kind.split("_")[0], mlp=kind.split("_")[1]), c)
     dense, loose, _, segments = c.plan()
-    unrolled, chosen = iter(dense + loose), []
-    done = {"periods": 0}
-    for kind, n in segments:
-        if kind == "periods":
-            first = done["periods"]
-            done["periods"] += n
-            x, e = jax.lax.scan(period, x, jax.tree.map(
-                lambda a: a[first:first + n], params["periods"]))
-            chosen.append(e.reshape((n * c.period,) + e.shape[2:]))
-            device_profiler.count("pattern.periods", n)  # per lowering
-            continue
-        for _ in range(n):
-            attn, mlp = c.kind(next(unrolled))
-            name = f"{attn}_{mlp}"
-            at = done.get(name, 0)
-            done[name] = at + 1
-            x, e = body(attn, mlp)(x, jax.tree.map(
-                lambda a: a[at], params["loose"][name]))
-            if e is not None:
-                chosen.append(e[None])
-        device_profiler.count("pattern.layers_unrolled", n)
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    return x, jnp.concatenate(chosen) if chosen else None
-
-
-def forward(params, tokens, config: WindowMoeConfig, mesh=None,
-            rules: Optional[LogicalAxisRules] = None):
-    """tokens [B, S] -> next-token logits [B, S, V] float32."""
-    x, _ = forward_hidden(params, tokens, config, mesh, rules)
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
+    x, chosen = layer_pattern.walk(
+        x, segments, ["_".join(c.kind(i)) for i in dense + loose],
+        params.get("loose"),
+        {f"{attn}_{SPARSE}": stack
+         for attn, stack in params.get("periods", {}).items()},
+        [(f"sliding_{SPARSE}", c.period - 1), (f"full_{SPARSE}", None)], body)
+    return rms_norm(x, params["final_norm"], c.norm_eps), chosen
 
 
 def loss_fn(params, batch, config: WindowMoeConfig, mesh=None,
             rules: Optional[LogicalAxisRules] = None):
-    """Next-token CE through `llama.chunked_ce`, masked by batch["mask"]
+    """Next-token CE (`blocks.next_token_loss`), masked by batch["mask"]
     when given. Scalar return (make_train_step contract)."""
-    c = config
-    inputs, targets, mask = mla_moe._split(batch)
-    hidden, _ = forward_hidden(params, inputs, c, mesh, rules)
-    return llama.chunked_ce(hidden, params["lm_head"], targets, mask,
-                            chunk=c.loss_chunk_size or inputs.shape[1])
+    return blocks.next_token_loss(forward_hidden, None, params, batch, config,
+                                  mesh, rules)

@@ -27,7 +27,7 @@ PR 35, has both forms' times).
 `sum_rows_by_index(rows [T, D], index [T], n)` is the same sum for rows that
 are ALL live and carry their destination: row i of its [n, D] result is the
 sum of the rows whose index is i, zero where none is. It is the gradient of
-a gather of rows, `table[index]`, by the table (`models/llama.embed_rows`):
+a gather of rows, `table[index]`, by the table (`models/blocks.embed_rows`):
 XLA's scatter-add puts one row after the other into a zero table (1 us a
 row of 2,560 on the v5e, 0.12-0.34 us a row of 2,048 or 4,096: 2.2 to 9.1
 times the sorted sum's time at the ten cells' shapes), and a bf16 one rounds

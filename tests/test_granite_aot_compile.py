@@ -29,7 +29,7 @@ import optax
 from jax.experimental import topologies
 
 from ray_tpu import train
-from ray_tpu.models import granite_hybrid, llama
+from ray_tpu.models import blocks, granite_hybrid
 from ray_tpu.ops import ssd as ssd_op
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -57,7 +57,7 @@ def kernels(hlo):
 
 
 # `use_pallas` follows jax.default_backend(), cpu here
-llama.flash_attention = partial(flash_attention, use_pallas=True)
+blocks.flash_attention = partial(flash_attention, use_pallas=True)
 scan = ssd_op.ssd_scan
 ssd_op.ssd_scan = partial(scan, use_pallas=True)
 

@@ -15,8 +15,8 @@ import pytest
 
 from benchmarks import reference_granite4 as ref
 from ray_tpu._private import device_profiler
+from ray_tpu.models import blocks
 from ray_tpu.models import granite_hybrid as G
-from ray_tpu.models import llama
 
 # float32 against float32-"highest" (tests/test_nemotron_h_reference.py)
 LOSS_RTOL = 2e-5
@@ -172,8 +172,8 @@ def test_the_tied_embeddings_gradient_is_the_sum_of_its_two_uses():
 
     def untied(embed, head):
         hidden = G.forward_hidden(dict(params, embed=embed), inputs, cfg)
-        hidden = llama._scaled(hidden, 1.0 / cfg.logits_scaling)
-        return llama.chunked_ce(hidden, head, targets, chunk=inputs.shape[1])
+        hidden = blocks.scaled(hidden, 1.0 / cfg.logits_scaling)
+        return blocks.chunked_ce(hidden, head, targets, chunk=inputs.shape[1])
 
     with jax.default_matmul_precision("highest"):
         lookup, head = jax.grad(untied, argnums=(0, 1))(

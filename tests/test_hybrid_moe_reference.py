@@ -17,7 +17,7 @@ import pytest
 
 from benchmarks import reference_ling as ref
 from ray_tpu._private import device_profiler
-from ray_tpu.models import hybrid_moe, mla_moe
+from ray_tpu.models import blocks, experts, hybrid_moe, mixers, mla_moe
 from ray_tpu.ops import kda as kda_op
 from ray_tpu.parallel import moe
 from tools import kda_chip_check
@@ -336,10 +336,10 @@ def test_scanned_periods_equal_the_same_layers_unrolled():
         x = params["embed"][toks]
         positions = jnp.broadcast_to(jnp.arange(toks.shape[1]), toks.shape)
         for n, (i, p) in enumerate(ref.layer_params(params, model)):
-            x, e = hybrid_moe._layer(x, p, positions, cfg, None, None,
+            x, e = hybrid_moe.layer(x, p, positions, cfg, None, None,
                                      mla=cfg.is_mla(i), dense=False)
             np.testing.assert_array_equal(e, chosen[n])
-        x = hybrid_moe._rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = blocks.rms_norm(x, params["final_norm"], cfg.norm_eps)
     np.testing.assert_allclose(got, x, rtol=RTOL, atol=ATOL)
 
 
@@ -404,7 +404,7 @@ def test_the_published_count_of_parameters():
     mla = 31_966_080 + 2 * 2560
     routed = 2560 * 512 + 512 + 17 * 5_898_240
     assert hybrid_moe.kda_num_params(cfg) == 63_049_888
-    assert mla_moe.mla_num_params(cfg) == 31_966_080
+    assert mixers.mla_num_params(cfg) == 31_966_080
     assert cfg.num_params() == (
         2 * 19_648 * 2560 + 2560 + kda + 3 * 2560 * 6144
         + 5 * (kda + routed) + mla + routed)
@@ -482,18 +482,18 @@ def test_the_shares_add_up_to_the_uncut_layer():
     cfg, params, _ = _model(**CUT)
     p = jax.tree.map(lambda a: a[0, 0], params["periods"]["kda"])
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 24, cfg.d_model))
-    h = hybrid_moe._rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    h = blocks.rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     shared = (jax.nn.silu(h @ p["shared"]["w_gate"])
               * (h @ p["shared"]["w_up"])) @ p["shared"]["w_down"]
     with jax.default_matmul_precision("highest"):
-        whole, chosen = mla_moe._expert_sublayer(x, p, cfg)
+        whole, chosen = experts.expert_sublayer(x, p, cfg)
         total = x + shared
         for first in range(0, 16, 4):
             share = dataclasses.replace(cfg, n_experts_held=4,
                                         first_expert=first)
             part = dict(p, experts=jax.tree.map(
                 lambda a: a[first:first + 4], p["experts"]))
-            y, e = mla_moe._expert_sublayer(x, part, share)
+            y, e = experts.expert_sublayer(x, part, share)
             np.testing.assert_array_equal(e, chosen)
             total = total + (y - x - shared)
     np.testing.assert_allclose(total, whole, rtol=RTOL, atol=ATOL)

@@ -16,7 +16,7 @@ import pytest
 
 from benchmarks import reference_joyai as ref
 from ray_tpu._private import device_profiler
-from ray_tpu.models import mixtral, mla_moe
+from ray_tpu.models import blocks, experts, mixers, mixtral, mla_moe
 from ray_tpu.ops import row_moves, row_sums
 from ray_tpu.ops.flash_attention import (
     BlockDiffusion, _clamp_block, _reference_attention, block_schedule,
@@ -127,7 +127,7 @@ def test_eight_shares_and_the_shared_expert_once_make_the_whole_layer():
     # and the whole layer through the program with every expert held
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 64, cfg.d_model))
     with jax.default_matmul_precision("highest"):
-        got, _ = mla_moe._expert_sublayer(x, p, cfg)
+        got, _ = experts.expert_sublayer(x, p, cfg)
     hn = ref._rms(x[0], p["mlp_norm"], cfg.norm_eps)
     routed, shared, _ = ref.experts(hn, p, model)
     np.testing.assert_allclose(got[0], x[0] + routed + shared,
@@ -467,11 +467,11 @@ def test_unit_embedding_rows_keep_the_tokens_apart_at_the_router(seed):
     positions = jnp.broadcast_to(jnp.arange(128), (4, 128))
 
     def shared_over_own(embed):
-        x = mla_moe._dense_layer(
+        x = mla_moe.dense_layer(
             embed[toks], jax.tree.map(lambda a: a[0], params["dense"]),
             positions, cfg, None, None)
         p = jax.tree.map(lambda a: a[0], params["layers"])
-        h = mla_moe._rms_norm(mla_moe._mla_sublayer(x, p, positions, cfg),
+        h = blocks.rms_norm(mixers.mla_sublayer(x, p, positions, cfg),
                               p["mlp_norm"], cfg.norm_eps)
         logits = np.asarray(h.reshape(4 * 128, -1) @ p["router"])
         return logits.mean(axis=0).std() / logits.std(axis=0).mean()
@@ -485,11 +485,11 @@ def test_unit_embedding_rows_keep_the_tokens_apart_at_the_router(seed):
 
 def test_mtp_shift_and_mask():
     targets = jnp.arange(1, 11).reshape(2, 5)
-    shifted, mask = mla_moe.mtp_targets(targets)
+    shifted, mask = blocks.mtp_targets(targets)
     np.testing.assert_array_equal(shifted[:, :-1], targets[:, 1:])
     np.testing.assert_array_equal(mask, [[1, 1, 1, 1, 0]] * 2)
     rows = jnp.array([[1.0] * 5, [0.0] * 5])
-    np.testing.assert_array_equal(mla_moe.mtp_targets(targets, rows)[1],
+    np.testing.assert_array_equal(blocks.mtp_targets(targets, rows)[1],
                                   [[1, 1, 1, 1, 0], [0] * 5])
     # the MTP loss does not see the last position's (padded) target, and
     # its weight is `mtp_loss_coef`
@@ -518,11 +518,11 @@ def test_rope_interleave_is_a_common_permutation():
     to the last bit (a permutation of a linear map's output channels), for
     the per-head up-projection and for the shared rotary key's columns."""
     cfg = mla_moe.MlaMoeConfig.tiny()
-    pairs = partial(mla_moe._interleaved, config=cfg)
+    pairs = partial(mixers.interleaved, config=cfg)
     q, k = (jax.random.normal(jax.random.PRNGKey(i), (1, 12, 2, 8))
             for i in (0, 1))
     pos = jnp.arange(12)[None]
-    rope = partial(mla_moe._rope, positions=pos, theta=cfg.rope_theta)
+    rope = partial(blocks.rope, positions=pos, theta=cfg.rope_theta)
     got = jnp.einsum("bshr,bthr->bhst", rope(pairs(q)), rope(pairs(k)))
     want = jnp.einsum("shr,thr->hst", ref._rope(q[0], cfg.rope_theta, True),
                       ref._rope(k[0], cfg.rope_theta, True))
@@ -530,7 +530,7 @@ def test_rope_interleave_is_a_common_permutation():
     np.testing.assert_array_equal(pairs(jnp.arange(8)), [0, 2, 4, 6, 1, 3, 5, 7])
     plain = dataclasses.replace(cfg, rope_interleave=False)
     np.testing.assert_array_equal(
-        mla_moe._interleaved(jnp.arange(8), plain), jnp.arange(8))
+        mixers.interleaved(jnp.arange(8), plain), jnp.arange(8))
     c_q = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 48))
     w_up = jax.random.normal(jax.random.PRNGKey(3), (48, 2, 8))
     w_key = jax.random.normal(jax.random.PRNGKey(4), (48, 8))
@@ -697,7 +697,7 @@ def test_mixtral_through_the_changed_moe_layer():
 
     with pytest.raises(NotImplementedError):
         c2, p2, _ = _model(SHARE)
-        mla_moe._expert_sublayer(
+        experts.expert_sublayer(
             jnp.zeros((1, 8, 64)),
             jax.tree.map(lambda a: a[0], p2["layers"]), c2, EpMesh())
 

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import llama, mixtral, vit
+from ray_tpu.models import blocks, llama, mixtral, vit
 from ray_tpu.ops import row_sums
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import LogicalAxisRules, logical_sharding
@@ -221,7 +221,7 @@ def test_llama_chunked_ce_matches_plain(case):
         lambda h, w: cotangent * _plain_ce(h, w, targets, mask, denominator),
         argnums=(0, 1))(hidden, lm_head)
     got, (got_dh, got_dw) = jax.jit(jax.value_and_grad(
-        lambda h, w: cotangent * llama.chunked_ce(
+        lambda h, w: cotangent * blocks.chunked_ce(
             h, w, targets, mask, chunk=8, denominator=denominator),
         argnums=(0, 1)))(hidden, lm_head)
     assert abs(float(got) - float(want)) < 1e-5 * float(want)
@@ -244,7 +244,7 @@ def test_llama_chunked_ce_undifferentiated_forms_no_gradient():
     hidden, lm_head, targets, kept = _ce_operands(seq=29)
 
     def loss(h, w):
-        return llama.chunked_ce(h, w, targets, kept, chunk=8)
+        return blocks.chunked_ce(h, w, targets, kept, chunk=8)
 
     assert list(_dots(jax.make_jaxpr(loss)(hidden, lm_head).jaxpr)) == [
         (2, 8, 50), (2, 5, 50)]
@@ -400,7 +400,7 @@ _EMBED_CASES = {
 
 @pytest.mark.parametrize("case", sorted(_EMBED_CASES))
 def test_llama_embed_rows_gradient_is_the_sum_by_token(case, monkeypatch):
-    """`llama.embed_rows` is `table[tokens]` bit for bit, and its d table,
+    """`blocks.embed_rows` is `table[tokens]` bit for bit, and its d table,
     where the sorted sum forms it (a TPU's form, here with the platform's
     test taken out and the kernel in the Pallas interpreter), is the
     float32 scatter-add of the cotangent's rows rounded ONCE, bit for bit:
@@ -435,9 +435,9 @@ def test_llama_embed_rows_gradient_is_the_sum_by_token(case, monkeypatch):
             out += jnp.sum((h.astype(dtype) @ table.T).astype(jnp.float32) * u)
         return out
 
-    ours = partial(loss, lambda tb: llama.embed_rows(tb, tokens, mesh))
+    ours = partial(loss, lambda tb: blocks.embed_rows(tb, tokens, mesh))
     plain = partial(loss, lambda tb: tb[tokens])
-    assert jnp.array_equal(llama.embed_rows(table, tokens, mesh), table[tokens])
+    assert jnp.array_equal(blocks.embed_rows(table, tokens, mesh), table[tokens])
     before = device_profiler.snapshot()["counters"]
     got = jax.jit(jax.grad(ours))(table)
     after = device_profiler.snapshot()["counters"]
@@ -504,7 +504,7 @@ def test_llama_chunked_ce_in_groups_matches_the_plain_grouped_softmax(case):
     want, want_g = jax.value_and_grad(
         lambda h, w: _plain_grouped_ce(h, w, targets, plain_w, plain_d),
         argnums=(0, 1))(hidden, lm_head)
-    loss = lambda h, w: llama.chunked_ce(  # noqa: E731
+    loss = lambda h, w: blocks.chunked_ce(  # noqa: E731
         h, w, targets, weights, chunk=chunk, denominator=denominator,
         groups=3)
     got, got_g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(
@@ -527,11 +527,11 @@ def test_llama_chunked_ce_in_groups_matches_the_plain_grouped_softmax(case):
 def test_llama_chunked_ce_refuses_targets_that_are_not_its_groups():
     hidden, lm_head, targets, _ = _grouped_operands()
     with pytest.raises(ValueError, match="groups"):
-        llama.chunked_ce(hidden, lm_head, targets, chunk=8)
+        blocks.chunked_ce(hidden, lm_head, targets, chunk=8)
     with pytest.raises(ValueError, match="groups"):
-        llama.chunked_ce(hidden, lm_head, targets[..., 0], chunk=8, groups=3)
+        blocks.chunked_ce(hidden, lm_head, targets[..., 0], chunk=8, groups=3)
     with pytest.raises(ValueError, match="groups"):
-        llama.chunked_ce(hidden, lm_head[:, :29], targets, chunk=8, groups=3)
+        blocks.chunked_ce(hidden, lm_head[:, :29], targets, chunk=8, groups=3)
 
 
 # sha256 of `chunked_ce`, value and gradients as traced, at each of the ten
@@ -581,7 +581,7 @@ def test_the_ten_cells_chunked_ce_traces_to_what_it_was(cell):
     shaped = jax.ShapeDtypeStruct
     assert _traced_digest(
         jax.value_and_grad(
-            lambda h, w, t: llama.chunked_ce(h, w, t, chunk=1024),
+            lambda h, w, t: blocks.chunked_ce(h, w, t, chunk=1024),
             argnums=(0, 1)),
         (shaped((b, s, d), jnp.bfloat16), shaped((d, v), jnp.bfloat16),
          shaped((b, s), jnp.int32))) == digest
@@ -593,9 +593,9 @@ def test_chunked_ce_with_weights_and_undifferentiated_trace_as_they_did():
               shaped((2048, 18992), jnp.bfloat16),
               shaped((4, 2048), jnp.int32), shaped((4, 2048), jnp.float32))
     assert _traced_digest(jax.value_and_grad(
-        lambda h, w, t, m: llama.chunked_ce(
+        lambda h, w, t, m: blocks.chunked_ce(
             h, w, t, m, chunk=1024, denominator=8192.0),
         argnums=(0, 1)), shapes) == _CE_WITH_WEIGHTS
     assert _traced_digest(
-        lambda h, w, t, m: llama.chunked_ce(h, w, t, m, chunk=1024),
+        lambda h, w, t, m: blocks.chunked_ce(h, w, t, m, chunk=1024),
         shapes) == _CE_UNDIFFERENTIATED

@@ -17,7 +17,7 @@ import pytest
 
 from benchmarks import reference_nemotron3 as ref
 from ray_tpu._private import device_profiler
-from ray_tpu.models import llama, nemotron_h
+from ray_tpu.models import blocks, nemotron_h
 from ray_tpu.ops import ssd as ssd_op
 from ray_tpu.parallel import moe
 from tools import ssd_chip_check
@@ -294,11 +294,11 @@ def test_scanned_pairs_equal_the_same_layers_unrolled():
         positions = jnp.broadcast_to(jnp.arange(toks.shape[1]), toks.shape)
         n = 0
         for _, kind, p in ref.layer_params(params, model):
-            x, e = nemotron_h._layer(x, p, positions, cfg, None, None, kind)
+            x, e = nemotron_h.layer(x, p, positions, cfg, None, None, kind)
             if e is not None:
                 np.testing.assert_array_equal(e, chosen[n])
                 n += 1
-        x = nemotron_h._rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = blocks.rms_norm(x, params["final_norm"], cfg.norm_eps)
     assert n == 3
     np.testing.assert_allclose(got, x, rtol=RTOL, atol=ATOL)
 
@@ -388,18 +388,18 @@ def test_the_shares_add_up_to_the_uncut_layer():
     cfg, params, _ = _model(**CUT)
     p = jax.tree.map(lambda a: a[0], params["pairs"]["experts"])
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 24, cfg.d_model))
-    h = nemotron_h._rms_norm(x, p["mlp_norm"], cfg.norm_eps)
+    h = blocks.rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     shared = jnp.square(jax.nn.relu(h @ p["shared"]["w_up"])) \
         @ p["shared"]["w_down"]
     with jax.default_matmul_precision("highest"):
-        whole, chosen = nemotron_h._expert_sublayer(x, p, cfg)
+        whole, chosen = nemotron_h.expert_sublayer(x, p, cfg)
         total = x + shared
         for first in range(0, 16, 4):
             share = dataclasses.replace(cfg, n_experts_held=4,
                                         first_expert=first)
             part = dict(p, experts=jax.tree.map(
                 lambda a: a[first:first + 4], p["experts"]))
-            y, e = nemotron_h._expert_sublayer(x, part, share)
+            y, e = nemotron_h.expert_sublayer(x, part, share)
             np.testing.assert_array_equal(e, chosen)
             total = total + (y - x - shared)
     np.testing.assert_allclose(total, whole, rtol=RTOL, atol=ATOL)
@@ -474,16 +474,16 @@ def test_the_swiglu_lowering_is_the_one_there_was(held):
 
 
 def test_attention_without_a_rotary_embedding():
-    """`rope_theta` 0 leaves q and k as projected (`llama._qkv`); any other
+    """`rope_theta` 0 leaves q and k as projected (`blocks.qkv`); any other
     value turns them."""
     cfg, params, _ = _model(**CUT)
     p = jax.tree.map(lambda a: a[0], params["one"]["attn"])
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, cfg.d_model))
     positions = jnp.broadcast_to(jnp.arange(24), (2, 24))
-    h = llama._rms_norm(x, p["attn_norm"], cfg.norm_eps)
-    q, k, _ = llama._qkv(x, p, positions, cfg)
+    h = blocks.rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    q, k, _ = blocks.qkv(x, p, positions, cfg)
     np.testing.assert_array_equal(q, jnp.einsum("bsd,dhk->bshk", h, p["wq"]))
     np.testing.assert_array_equal(k, jnp.einsum("bsd,dhk->bshk", h, p["wk"]))
-    turned, _, _ = llama._qkv(x, p, positions,
+    turned, _, _ = blocks.qkv(x, p, positions,
                               dataclasses.replace(cfg, rope_theta=1e4))
     assert float(jnp.abs(turned - q).max()) > 0.1

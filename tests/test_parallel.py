@@ -291,7 +291,7 @@ def test_llama_seq_sharded_residual_matches_single_device(mesh_cfg, ring):
     """The residual stream between sublayers is sequence-sharded over tp
     (and sp): the loss and EVERY gradient leaf are the single-device
     program's, and the boundaries were lowered in that layout. With tp
-    alone on the sequence the MLP runs as `llama._mlp_ring` (two chips,
+    alone on the sequence the MLP runs as `blocks.mlp_ring` (two chips,
     and four: every chunk of the ring in its place); with sp beside it,
     as the compiler lays it out."""
     from ray_tpu.models import llama
@@ -348,7 +348,7 @@ def test_collective_report_seq_sharded_residual():
     all-gather, against the whole-sequence layout (the same rules with
     "res_seq" over sp alone, which is what the residual had before): the
     activations now reach attention's column-parallel matmuls through
-    all-gathers (the MLP's go round `llama._mlp_ring` as
+    all-gathers (the MLP's go round `blocks.mlp_ring` as
     collective-permutes), and where the backend forms reduce-scatters the
     all-reduced bytes fall. (XLA's CPU pipeline keeps each as an all-reduce
     whose result it slices; the v5e's compiler fuses them,

@@ -16,7 +16,7 @@ import pytest
 
 from benchmarks import reference_sdar as ref
 from ray_tpu._private import device_profiler
-from ray_tpu.models import llama, mixtral, sdar
+from ray_tpu.models import blocks, llama, mixtral, sdar
 from ray_tpu.ops.flash_attention import (
     BlockDiffusion, block_schedule, flash_attention)
 from ray_tpu.parallel import moe
@@ -160,10 +160,10 @@ def test_eight_shares_make_the_whole_layer():
     pos = jnp.tile(jnp.arange(8), 2)
     with jax.default_matmul_precision("highest"):
         want_x, _, _ = ref._layer(x, p, pos, mask, model)
-        got = llama._attn_sublayer(x[None], p, pos[None], cfg,
+        got = blocks.attn_sublayer(x[None], p, pos[None], cfg,
                                    mask=BlockDiffusion(8, cfg.block))
-        routed, _ = mixtral._moe_block(
-            llama._rms_norm(got, p["mlp_norm"], cfg.norm_eps), p, cfg, None)
+        routed, _ = mixtral.moe_block(
+            blocks.rms_norm(got, p["mlp_norm"], cfg.norm_eps), p, cfg, None)
     np.testing.assert_allclose((got + routed)[0], want_x, rtol=RTOL, atol=ATOL)
 
 
@@ -172,7 +172,7 @@ def test_the_clean_half_does_not_depend_on_the_noised_half():
     hidden states as they are, bit for bit; the x_t half moves."""
     cfg, params, _ = _model(SHARE)
     x_0 = _tokens(5)[:, :-1]
-    _, _, x_t = sdar._noised({}, x_0, cfg)
+    _, _, x_t = sdar.noised_batch({}, x_0, cfg)
     other = jnp.where(x_t == cfg.mask_id, x_0, cfg.mask_id)
     length = x_0.shape[1]
     a, _ = sdar.hidden_states(params, x_t, x_0, cfg)
@@ -193,18 +193,18 @@ def test_at_block_one_the_clean_half_is_the_causal_model():
     `mixtral.forward_hidden` on x_0 alone, the path every other cell runs."""
     cfg, params, _ = _model(WHOLE, block=1)
     x_0 = _tokens(6)[:, :-1]
-    _, _, x_t = sdar._noised({}, x_0, cfg)
+    _, _, x_t = sdar.noised_batch({}, x_0, cfg)
     length = x_0.shape[1]
     with jax.default_matmul_precision("highest"):
         x, _ = sdar.hidden_states(params, x_t, x_0, cfg)
         want, _ = mixtral.forward_hidden(params, x_0, cfg)
-    got = llama._rms_norm(x[:, length:], params["final_norm"], cfg.norm_eps)
+    got = blocks.rms_norm(x[:, length:], params["final_norm"], cfg.norm_eps)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("form", ["per_head", "all_channels"])
 def test_qk_norm_by_the_shape_of_its_scale(form):
-    """`llama._qk_norm` reads the form off the scale: [D] is Qwen3's
+    """`blocks.qk_norm` reads the form off the scale: [D] is Qwen3's
     RMSNorm of every head over its own D channels (one scale for all
     heads), [H, D] OLMoE's one RMSNorm over all H x D channels, unchanged."""
     cfg = llama.LlamaConfig.tiny()
@@ -215,7 +215,7 @@ def test_qk_norm_by_the_shape_of_its_scale(form):
     shape = (lambda n: (d,)) if form == "per_head" else (lambda n: (n, d))
     g_q = 1.0 + 0.3 * jax.random.normal(jax.random.PRNGKey(2), shape(h))
     g_k = 1.0 + 0.3 * jax.random.normal(jax.random.PRNGKey(3), shape(kv))
-    got_q, got_k = llama._qk_norm(q, k, {"q_norm": g_q, "k_norm": g_k}, cfg)
+    got_q, got_k = blocks.qk_norm(q, k, {"q_norm": g_q, "k_norm": g_k}, cfg)
 
     def want(x, g):
         x = np.asarray(x, np.float64)

@@ -595,7 +595,7 @@ def test_a_router_ahead_of_attention_and_the_flash_kernels_are_counted():
 
 
 def test_the_heads_chunks_are_counted_where_their_gradient_is_formed():
-    """`llama.chunked_ce` counts every chunk it traces, the remainder's
+    """`blocks.chunked_ce` counts every chunk it traces, the remainder's
     too, under `ce.chunks`, and under `ce.chunks_fused` those of the forward
     rule of its `custom_vjp`, whose gradient is formed with their logits: a
     trace of the loss alone leaves the second where it was. The two names
@@ -605,14 +605,14 @@ def test_the_heads_chunks_are_counted_where_their_gradient_is_formed():
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama
+    from ray_tpu.models import blocks
 
     hidden = jax.ShapeDtypeStruct((2, 29, 16), jnp.float32)
     lm_head = jax.ShapeDtypeStruct((16, 50), jnp.float32)
     targets = jnp.zeros((2, 29), jnp.int32)
 
     def loss(h, w):
-        return llama.chunked_ce(h, w, targets, chunk=8)
+        return blocks.chunked_ce(h, w, targets, chunk=8)
 
     def grew(fn):
         before = dp.snapshot()["counters"]
@@ -633,7 +633,7 @@ def test_the_heads_chunks_are_counted_where_their_gradient_is_formed():
 
 def test_the_embeddings_gradient_rows_are_counted_by_the_form_that_sums_them(
         monkeypatch):
-    """`llama.embed_rows` counts, once per trace of its backward rule, the
+    """`blocks.embed_rows` counts, once per trace of its backward rule, the
     T rows of the cotangent under `embed.grad_rows` and, where the sorted
     sum forms d table, under `embed.grad_rows_sorted` too (0 is added where
     the scatter-add stands, so both names are there once either is); a
@@ -647,7 +647,7 @@ def test_the_embeddings_gradient_rows_are_counted_by_the_form_that_sums_them(
     import jax.numpy as jnp
 
     from benchmarks import counter_readers
-    from ray_tpu.models import llama
+    from ray_tpu.models import blocks
     from ray_tpu.ops import row_sums
 
     names = ("embed.grad_rows", "embed.grad_rows_sorted")
@@ -655,7 +655,7 @@ def test_the_embeddings_gradient_rows_are_counted_by_the_form_that_sums_them(
     tokens = jnp.zeros((2, 24), jnp.int32)
 
     def lookup(tb):
-        return llama.embed_rows(tb, tokens).astype(jnp.float32).sum()
+        return blocks.embed_rows(tb, tokens).astype(jnp.float32).sum()
 
     def grew(fn):
         before = dp.snapshot()["counters"]

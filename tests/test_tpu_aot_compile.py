@@ -43,7 +43,8 @@ from jax.experimental import topologies
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.inference.paged_engine import PagedInferenceEngine
-from ray_tpu.models import llama, mla_moe, nemotron_h, sdar, window_moe
+from ray_tpu.models import (
+    blocks, llama, mixers, mla_moe, nemotron_h, sdar, window_moe)
 from ray_tpu.ops import grouped_matmul, row_moves, row_sums
 from ray_tpu.ops.flash_attention import (
     BlockDiffusion, SlidingWindow, block_schedule, flash_attention)
@@ -198,17 +199,17 @@ lowered.compile()
 out["flash_mla"] = "compiled"
 
 # the same call as `mla_moe` makes it, IN PARTS (q, rotary q, k, ONE rotary
-# key, v): one checkpointed `_mla_sublayer` at train-joyai-1chip's widths
+# key, v): one checkpointed `mixers.mla_sublayer` at train-joyai-1chip's widths
 # and batch, value and gradient, as the v5e's compiler leaves it
 cfg = mla_moe.MlaMoeConfig(max_seq_len=2048)
 layer_p = jax.eval_shape(lambda: mla_moe.init(
     dataclasses.replace(cfg, vocab_size=8, n_layers=1, d_ff=8),
     jax.random.PRNGKey(0)))["dense"]
-layer = mla_moe._checkpointed(functools.partial(
-    mla_moe._mla_sublayer, config=cfg,
+layer = blocks.checkpointed(functools.partial(
+    mixers.mla_sublayer, config=cfg,
     positions=jnp.broadcast_to(jnp.arange(2048), (4, 2048))), cfg)
 # use_pallas=True: `mla_moe` too follows jax.default_backend()
-mla_moe.flash_attention = functools.partial(flash_attention, use_pallas=True)
+blocks.flash_attention = functools.partial(flash_attention, use_pallas=True)
 hlo = jax.jit(jax.value_and_grad(
     lambda x, p: layer(x, p).astype(jnp.float32).sum(), argnums=(0, 1))).lower(
         spec((4, 2048, cfg.d_model), bf16),
@@ -357,7 +358,7 @@ for name, sorts, (n_rows, vocab, width) in (
         ("whole_lanes", on_tpu, (8192, 32768, 4096))):
     kept, row_sums.sums_in_order = row_sums.sums_in_order, sorts
     hlo = jax.jit(jax.grad(lambda table, tokens, w: jnp.sum(
-        llama.embed_rows(table, tokens).astype(jnp.float32) * w))).lower(
+        blocks.embed_rows(table, tokens).astype(jnp.float32) * w))).lower(
             spec((vocab, width), bf16), spec((1, n_rows), jnp.int32),
             spec((1, n_rows, width), jnp.float32)).compile().as_text()
     row_sums.sums_in_order = kept
@@ -374,7 +375,7 @@ for name, sorts, (n_rows, vocab, width) in (
 # V 37,984, chunks of 1,024), value and gradient: the matrix products in
 # its loops, a fusion's own among them, by the shape they put out
 hlo = jax.jit(jax.value_and_grad(
-    lambda h, w, t: llama.chunked_ce(h, w, t, chunk=1024),
+    lambda h, w, t: blocks.chunked_ce(h, w, t, chunk=1024),
     argnums=(0, 1))).lower(
         spec((1, 16384, 2560), bf16), spec((2560, 37984), bf16),
         spec((1, 16384), jnp.int32)).compile().as_text()
@@ -423,9 +424,9 @@ out["flash_bd_repeats"] = repeats(hlo, 4 * 32 * 4096 * 128)
 
 # ONE layer of the cell's model at its widths and batch (the share: 16 of
 # 128 experts), the whole objective, value and gradient, as the v5e's
-# compiler leaves it. `llama._flash` too follows jax.default_backend(), and
+# compiler leaves it. `blocks.flash` too follows jax.default_backend(), and
 # so does a share's combine (`ops/row_sums.py`)
-llama.flash_attention = functools.partial(flash_attention, use_pallas=True)
+blocks.flash_attention = functools.partial(flash_attention, use_pallas=True)
 moe.sum_rows_by_token = lambda rows, token, slot, live: (
     row_sums._sum_in_token_order(rows, token, live, slot.shape[0]))
 row_moves._buffer, row_moves._placed = row_moves._unwritten, row_moves._copied_in
@@ -446,7 +447,7 @@ out["sdar_dense"] = [ln[:160] for ln in ops if dense.search(ln)]
 out["sdar_pair_scatters"] = pair_scatters(hlo, 131072)
 
 # train-laguna-1chip's two flash calls at S 8,192 over 8 KV heads, as
-# `llama._attention` makes them: a window layer's, [1, 8192, 64, 128] under
+# `blocks.attention` makes them: a window layer's, [1, 8192, 64, 128] under
 # `SlidingWindow(512)` in its scope, and a full layer's, [1, 8192, 48, 128],
 # causal; under the layer's remat policy as `window_moe` runs them, value
 # and gradient: the three kernels each, how the scope shows in their names,
@@ -460,8 +461,8 @@ for name in ("swa_flash_fwd_roofline", "swa_flash_bwd_roofline",
         laguna_queries[name] = re.compile(json.load(f)["trace_query"]["op"])
 for cell_call, n_heads, window in (
         ("laguna_window", 64, SlidingWindow(512)), ("laguna_full", 48, None)):
-    attend = mla_moe._checkpointed(
-        lambda q, k, v, window=window: llama._attention(
+    attend = blocks.checkpointed(
+        lambda q, k, v, window=window: blocks.attention(
             q, k, v, laguna, None, window), laguna)
     laguna_call = jax.value_and_grad(
         lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
@@ -537,13 +538,13 @@ for cell_call, window in (("smallthinker_window", SlidingWindow(4096)),
     out[cell_call + "_repeats"] = repeats(long_hlo, 28 * 16384 * 128)
 
 # ONE checkpointed attention layer of `nemotron_h` (train-nemotron3-1chip:
-# GQA 32 / 2 x 128, no RoPE, B 2 x S 2048) under `_bodies`' policy, value
+# GQA 32 / 2 x 128, no RoPE, B 2 x S 2048) under `bodies`' policy, value
 # and gradient: the Pallas calls the v5e's compiler leaves in it
 attn_cfg = nemotron_h.NemotronHConfig(vocab_size=8, layers=(25,), mtp_depth=0)
 attn_p = jax.eval_shape(lambda: nemotron_h.init(
     attn_cfg, jax.random.PRNGKey(0)))["one"]["attn"]
-attn = nemotron_h._bodies(
-    attn_cfg, jnp.broadcast_to(jnp.arange(2048), (2, 2048)), None, None)["*"]
+attn = nemotron_h.bodies(
+    attn_cfg, jnp.broadcast_to(jnp.arange(2048), (2, 2048)), None, None)["attn"]
 out["nemotron_attn_calls"] = [
     re.match(r"%[\w.\-]+ = (.*?) custom-call\(", ln.strip())[1]
     for ln in jax.jit(jax.value_and_grad(
@@ -833,7 +834,7 @@ def test_flash_with_keys_wider_than_values_compiles_for_v5e(compiled):
 
 
 def test_latent_attention_in_parts_as_compiled_for_v5e(compiled):
-    """One checkpointed `mla_moe._mla_sublayer`, value and gradient, at
+    """One checkpointed `mixers.mla_sublayer`, value and gradient, at
     train-joyai-1chip's widths: the flash call takes q `[4, 32, 2048, 128]`,
     the rotary q `[.., 64]`, k, ONE rotary key `[4, 1, 2048, 64]` and v, and
     its three kernels keep the output signatures that
@@ -884,7 +885,7 @@ def test_tp_boundary_is_not_an_all_reduce_for_v5e_2x2(compiled):
     layer loop (four did, exposed: PERF.md §6, PR 30). Attention's two sums
     over tp (after wo; the dx of q/k/v) are `all-reduce-scatter` fusions
     with half the rows out; the MLP's transfers are the five
-    collective-permutes of `llama._mlp_ring` (forward: the rows in, the
+    collective-permutes of `blocks.mlp_ring` (forward: the rows in, the
     sums out; backward: the rows again for the recomputation, and the two
     transposes); v is projected with heads over tp like q and k, so nothing
     is turned round by an all-to-all."""
@@ -896,7 +897,7 @@ def test_tp_boundary_is_not_an_all_reduce_for_v5e_2x2(compiled):
 
 
 def test_the_head_forms_its_gradient_with_its_logits_for_v5e(compiled):
-    """`llama.chunked_ce`, value and gradient, as the v5e's compiler leaves
+    """`blocks.chunked_ce`, value and gradient, as the v5e's compiler leaves
     it. Alone at train-smallthinker-1chip's widths its loop holds THREE
     products of 1,024 x 2,560 x 37,984, the logits, d hidden and d lm_head
     (four until PR 53: the backward pass formed the logits again). In
@@ -912,7 +913,7 @@ def test_the_head_forms_its_gradient_with_its_logits_for_v5e(compiled):
 
 
 def test_the_embeddings_gradient_is_a_sorted_sum_as_compiled_for_v5e(compiled):
-    """`llama.embed_rows`' gradient by the table at
+    """`blocks.embed_rows`' gradient by the table at
     train-smallthinker-1chip's shape (16,384 rows of 2,560 into 37,984), as
     the v5e's compiler leaves it. With the sorted sum: ONE sort of the ids,
     ONE gather of the 16,384 rows into token order, ONE Pallas call, `tgmm`,

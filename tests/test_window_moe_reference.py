@@ -21,7 +21,7 @@ import pytest
 from benchmarks import reference_laguna as ref
 from benchmarks import reference_smallthinker as ref_st
 from ray_tpu._private import device_profiler
-from ray_tpu.models import llama, mla_moe, window_moe
+from ray_tpu.models import blocks, experts, window_moe
 from ray_tpu.models.window_moe import FULL, SLIDING
 
 # float32 against float32-"highest": ~1e2 additions per output of O(1)
@@ -136,12 +136,12 @@ def test_scanned_periods_equal_the_same_layers_unrolled():
         positions = jnp.broadcast_to(jnp.arange(toks.shape[1]), toks.shape)
         n = 0
         for i, p in ref.layer_params(params, model):
-            x, e = window_moe._layer(x, p, positions, cfg, None, None,
+            x, e = window_moe.layer(x, p, positions, cfg, None, None,
                                      *cfg.kind(i))
             if e is not None:
                 np.testing.assert_array_equal(e, chosen[n])
                 n += 1
-        x = llama._rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = blocks.rms_norm(x, params["final_norm"], cfg.norm_eps)
     assert n == 11
     np.testing.assert_allclose(got, x, rtol=RTOL, atol=ATOL)
 
@@ -193,7 +193,7 @@ def test_yarn_table_at_the_published_numbers():
     both against numbers worked by hand."""
     cfg = window_moe.WindowMoeConfig()
     rotary = cfg.rotary("full")
-    assert rotary == llama.Rotary(500000.0, 64, (64, 4096, 64, 1),
+    assert rotary == blocks.Rotary(500000.0, 64, (64, 4096, 64, 1),
                                   1.4158883083359672)
     assert rotary.attention_factor == pytest.approx(0.1 * np.log(64) + 1)
     assert ref.yarn_bounds(64, 500000, 4096, 64, 1) == (5, 16)
@@ -211,7 +211,7 @@ def test_yarn_table_at_the_published_numbers():
     np.testing.assert_array_equal(got, table)
     assert factor == rotary.attention_factor
     # the sliding layers: the whole head at theta 10,000, no factor
-    assert cfg.rotary("sliding") == llama.Rotary(10000.0, 128, None, 1.0)
+    assert cfg.rotary("sliding") == blocks.Rotary(10000.0, 128, None, 1.0)
     np.testing.assert_allclose(
         cfg.rotary("sliding").inv_freq(128),
         10000.0 ** (-np.arange(64) / 64), rtol=1e-6)
@@ -224,7 +224,7 @@ def test_partial_rotary_leaves_the_other_channels_untouched():
     cfg = window_moe.WindowMoeConfig()
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, 3, 128))
     positions = jnp.broadcast_to(jnp.arange(40), (2, 40))
-    got = llama._rope(x, positions, 500000.0, cfg.rotary("full"))
+    got = blocks.rope(x, positions, 500000.0, cfg.rotary("full"))
     np.testing.assert_array_equal(got[..., 64:], x[..., 64:])
     table, factor = ref.rope_table(dataclasses.asdict(cfg), FULL)
     want = jax.vmap(lambda row: ref._rope(row, table, factor))(x)
@@ -234,8 +234,8 @@ def test_partial_rotary_leaves_the_other_channels_untouched():
                                rtol=1e-6)
     # (the table made in float64 and rounded once, `_rope`'s in float32)
     np.testing.assert_allclose(
-        llama._rope(x, positions, 10000.0, llama.Rotary(10000.0, 128)),
-        llama._rope(x, positions, 10000.0), atol=1e-5)
+        blocks.rope(x, positions, 10000.0, blocks.Rotary(10000.0, 128)),
+        blocks.rope(x, positions, 10000.0), atol=1e-5)
 
 
 # --------------------------------------------------------------------------
@@ -258,7 +258,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                         first_expert=first)
             part = dict(p, experts=jax.tree.map(
                 lambda a: a[first:first + 4], p["experts"]))
-            y, e = mla_moe._expert_sublayer(x, part, share)
+            y, e = experts.expert_sublayer(x, part, share)
             np.testing.assert_array_equal(e, chosen)
             total = total + (y[0] - x[0] - shared)
     np.testing.assert_allclose(total, whole, rtol=RTOL, atol=ATOL)
@@ -367,7 +367,7 @@ def test_a_router_ahead_of_attention_matches_the_reference(over, plan):
     assert cfg.plan()[3] == plan and cfg.n_dense_layers == 0
     assert "shared" not in params["loose"]["full_sparse"]
     assert cfg.rotary("full").theta == 0 \
-        and cfg.rotary("sliding") == llama.Rotary(1_500_000.0, 16, None, 1.0)
+        and cfg.rotary("sliding") == blocks.Rotary(1_500_000.0, 16, None, 1.0)
     _assert_loss_and_gradients(cfg, params, model, _tokens(7),
                                reference=ref_st)
 
@@ -418,14 +418,14 @@ def test_the_four_shares_routed_parts_add_up_without_a_shared_expert():
         u = ref._rms(x[0], p["mlp_norm"], cfg.norm_eps)
         dense_w, chosen = ref_st.route(read[0], p, model)
         whole = x[0] + ref_st.experts(u, dense_w, p, model)
-        routing = mla_moe._routing(read[0], p, cfg)
+        routing = experts.routing(read[0], p, cfg)
         total = x[0]
         for first in range(0, 16, 4):
             share = dataclasses.replace(cfg, n_experts_held=4,
                                         first_expert=first)
             part = dict(p, experts=jax.tree.map(
                 lambda a: a[first:first + 4], p["experts"]))
-            y, e = mla_moe._expert_sublayer(x, part, share, routing=routing,
+            y, e = experts.expert_sublayer(x, part, share, ahead=routing,
                                             form="reglu")
             np.testing.assert_array_equal(e, chosen)
             total = total + (y[0] - x[0])

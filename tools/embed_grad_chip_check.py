@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """python3 tools/embed_grad_chip_check.py [--seed n] [--widths]: the
 embedding table's gradient ALONE, ON THE CHIP (any other backend exits 3
-before anything is computed), in both forms `models/llama.embed_rows`'
+before anything is computed), in both forms `models/blocks.embed_rows`'
 backward rule picks from, at the eleven training cells' (T rows of the
 cotangent, V, D), bf16.
 
