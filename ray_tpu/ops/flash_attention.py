@@ -334,11 +334,15 @@ class KernelSchedule(NamedTuple):
     all the groups' runs, its row entry holds the index of the first whole
     tile it stands for), and a tile the rule cuts along its sub-tile
     diagonal as a `Triangle` (`steps_triangle` of the `steps_masked`);
-    otherwise ONE loop a grid row over `table`, every step masked whole if
-    any step is (`_run_row`), but for the rows of `shared` (None: no such
-    rows), the plan's majority of same-shaped rows, which run one unrolled
-    branch between them, each step masked only if it needs it, in `rows`
-    and `tiles` too: the counts are of what runs."""
+    otherwise loops over `table`, a grid row's steps (`_run_row`): first
+    several a body, as straight-line code, for each size of `body` in turn
+    `bodies[row]`'s count of bodies of that many steps, the row's whole
+    tiles, with no mask; then the rest one an iteration, each masked whole if
+    any step of the plan is (`_LOOP_BODY`; `body` (): all of them so); but
+    for the rows of `shared` (None: no such rows), the plan's majority of
+    same-shaped rows, which run one unrolled branch between them, each step
+    masked only if it needs it. `rows` and `tiles` hold a row's steps in the
+    order and under the mask they run: the counts are of what runs."""
     width: int
     static: bool
     tiles: tuple
@@ -351,6 +355,8 @@ class KernelSchedule(NamedTuple):
     steps_skipped: int
     executed_over_needed: float
     shared: Optional[SharedRows] = None
+    body: tuple = ()
+    bodies: tuple = ()
 
     @property
     def steps_shared(self):
@@ -359,16 +365,28 @@ class KernelSchedule(NamedTuple):
             if self.shared else 0
 
     @property
+    def steps_loop_body(self):
+        """The steps, of `tiles`, that run inside a loop's body of several
+        steps."""
+        return sum(k * n for row in self.bodies
+                   for k, n in zip(self.body, row) if k > 1)
+
+    @property
     def table(self):
-        """int32 [grid rows, 1 + most steps a row]: a row's number of steps,
-        then their indices; what a loop plan's kernel reads from SMEM."""
+        """What a loop plan's kernel reads from SMEM, int32 [grid rows, 1 +
+        the sizes of `body` + most steps a row]: the steps the row runs one
+        at a time, its bodies of each size, then the steps' indices, the
+        bodies' first."""
         import numpy as np
 
-        out = np.zeros((len(self.rows), 1 + max(map(len, self.rows))),
+        lead = 1 + len(self.body)
+        out = np.zeros((len(self.rows), lead + max(map(len, self.rows))),
                        np.int32)
         for i, steps in enumerate(self.rows):
-            out[i, 0] = len(steps)
-            out[i, 1:1 + len(steps)] = [j for j, _ in steps]
+            if self.body:
+                out[i, 1:lead] = self.bodies[i]
+            out[i, 0] = len(steps) - np.dot(out[i, 1:lead], self.body)
+            out[i, lead:lead + len(steps)] = [j for j, _ in steps]
         return out
 
 
@@ -418,6 +436,35 @@ _STATIC_STEPS = 36
 # for the timing: 5.97 / 7.83 / 10.68), but on all 9 steps it cost the cell
 # 1.3%.
 _SHARED_BUDGET = {"fwd": 9, "dkv": 9}
+# How many steps a body of a loop plan's loop runs as straight-line code, by
+# kernel (dq runs the forward's plan): a row's steps fill bodies of the first
+# size, what is left bodies of the next, and the rest run one an iteration
+# ((): all of them, the loop before PR 60). What fills bodies is the row's
+# WHOLE tiles alone, first in the row and with NO mask. One traced step in a
+# `fori_loop(unroll=True)` a size, so a kernel's code is 4 + 2 + 1 steps
+# however long the plan. Measured on the v5e (PERF.md §6, PR 60;
+# `tools/flash_chip_check.py --sweep`), forward + dq + dk/dv ms a call, the
+# loop of one step and then the form: `CAUSAL` at q [1, 8192, 48, 128] over 8
+# KV heads 27.22 -> 24.52 (the forward 7.38 -> 6.47, dq 8.18 -> 7.50, dk/dv
+# 11.66 -> 10.55), at [1, 16384, 28, 128] over 4 60.28 -> 51.96 (16.30 ->
+# 13.00, 18.17 -> 16.40, 25.81 -> 22.56), at [1, 32768, 32, 64] over 8 268.4
+# -> 225.8 (72.5 -> 55.0, 81.4 -> 72.5, 114.6 -> 98.4); `EvaWindows(32768,
+# 2048, 16)` at [1, 32768, 32, 128] 56.50 -> 48.64 (17.02 -> 14.49, 17.57 ->
+# 14.75, 21.92 -> 19.40). The other forms, the three kernels' sum against
+# the loop's at those four calls: (2,) -8.3 / -10.8 / -12.5 / -14.6%; (4,)
+# -10.1 / -13.9 / -15.9 / -9.1% (EVA's forward rows hold at most 7 whole
+# tiles and 64 of them fewer than 4: 96 of 304 steps in bodies, 160 at (4,
+# 2)); (8,) -7.3 / -13.3 / -16.7 / -3.1%; (4, 2) -9.9 / -13.8 / -15.9 /
+# -13.9%, the one form within a point of the best at all four; (8, 4, 2) -9.5
+# / -14.5 / -17.3 / -12.6%; a last size of 1 (the whole tiles that fill no
+# body with no mask either, a third loop) (4, 2, 1) -8.7 / -13.1 / -15.5 /
+# -14.7%. Bodies of ANY of a row's steps, in the row's order and under the
+# plan's mask (tried, not kept): (2,) -6.4 / -7.8 / -8.3 / -0.2%, (4,) -8.5 /
+# -10.8 / -12.0 / -1.8%, (8,) -7.0 / -10.9 / -13.0 / +0.7%: the predicate is
+# a quarter of what the straight line gains under `CAUSAL` and all of it
+# under `EvaWindows`, whose predicate is a dozen vector ops a score. The 8
+# edge rows of `SlidingWindow(4096)` at [1, 16384, 28, 128]: 24.87 -> 24.6.
+_LOOP_BODY = {"fwd": (4, 2), "dkv": (4, 2)}
 
 
 @functools.lru_cache(maxsize=256)
@@ -536,13 +583,31 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
             kept = banded  # a band step is straight-line code
         static = unrolled(kept)
         shared = None if static else same_rows(kept)
+        body, bodies = (), ()
         if not static:
             any_masked = any(m for steps in kept for _, m in steps)
-            kept = [sorted((i * shared.stride + at, m)
-                           for at, m in shared.steps)
-                    if shared and i in shared.rows
-                    else [(j, any_masked) for j, _ in steps]
-                    for i, steps in enumerate(kept)]
+            body = _LOOP_BODY[kernel]
+
+            def looped(steps):
+                """A row of the loop as it runs -> (its steps, its bodies of
+                each size): every step masked if any of the plan is, but for
+                the whole tiles that fill bodies with no mask, which lead."""
+                lead = [j for j, m in steps if not m]
+                counts, left = [], len(lead)
+                for k in body:
+                    counts.append(left // k)
+                    left %= k
+                lead = lead[:len(lead) - left]
+                rest = [(j, any_masked) for j, _ in steps if j not in lead]
+                return [(j, False) for j in lead] + rest, tuple(counts)
+
+            kept, bodies = zip(*(
+                (sorted((i * shared.stride + at, m)
+                        for at, m in shared.steps), (0,) * len(body))
+                if shared and i in shared.rows else looped(steps)
+                for i, steps in enumerate(kept)))
+            if not any(map(any, bodies)):
+                body, bodies = (), ()
         by_row = tuple(map(tuple, kept))
 
         def tile_of(i, j, masked):
@@ -574,7 +639,7 @@ def _block_schedule(s_q, s_k, block_q, block_k, rule):
             sum(isinstance(t[4], Band) for t in tiles),
             sum(isinstance(t[4], Triangle) for t in tiles), skipped,
             sum(executed(*t) for t in tiles) / needed if needed
-            else float("inf"), shared)
+            else float("inf"), shared, body, bodies)
 
     w = _step_width(block_q, block_k, rule)
     keys = plan(
@@ -624,8 +689,15 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     every score left out is again one the mask sets to exactly 0. `CAUSAL`
     groups keep runs of 1, 2, 3, ... sub-tiles that all start at 0 and a
     block-diffusion row's kept tiles are not one range, so neither has a
-    band step; a plan too long to unroll with them keeps its whole tiles,
-    every one under the mask, in ONE loop a grid row. But for the rows of
+    band step; a plan too long to unroll with them keeps its whole tiles
+    and runs them in loops, a grid row's steps from a table: the tiles the
+    rule keeps whole FIRST, four a body of straight-line code with no mask,
+    then two a body (`_LOOP_BODY`, PR 60: a loop's iterations overlap
+    nothing, the steps of one body do), and what is left, the tiles it cuts
+    among it, one an iteration under the mask (`CAUSAL` at S 8,192 in tiles
+    of 512: 112 of a kernel's 136 steps in bodies; at S 16,384, 480 of 528;
+    at S 32,768, 1,984 of 2,080; `EvaWindows(32768, 2048, 16)`, 160 of
+    304). But for the rows of
     ONE SHAPE: where a loop plan's largest group of rows whose steps lie as
     far from the row's own block, kind for kind, is several rows, holds
     half the plan's steps or more, is one contiguous range a row and no
@@ -636,7 +708,7 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     several tiles has such rows (4,096 in tiles of 512 at S 16,384: 24 of
     32 rows are 7 tiles with no mask between the trailing tile and their
     own). No two causal rows are alike, and neither are most of a block-
-    diffusion or an `EvaWindows` plan's: those plans are what they were.
+    diffusion or an `EvaWindows` plan's: every row of theirs loops.
 
     `executed_over_needed` is scores executed over scores the rule keeps.
     Starting point (before PR 26): steps of block_q x block_k whatever the
@@ -655,7 +727,7 @@ def block_schedule(s_q, s_k, block_q, block_k, causal):
     steps of 4 x [128, 640] (PR 48) and the first row's own tile as a
     triangle: 1.258; `SlidingWindow(4096)` at S 16,384 walks 252 tiles for
     58,722,304 kept scores, 1.125, 168 of them with no mask since 24 rows
-    share a branch (PR 58).
+    share a branch (PR 58) and 24 more in the 8 edge rows' bodies (PR 60).
     """
     return _block_schedule(s_q, s_k, block_q, block_k, _rule(causal))
 
@@ -681,6 +753,9 @@ def _count_steps(*plans):
     # of them all: those of a loop plan's rows that run ONE shared branch
     device_profiler.count("flash.steps_shared_row",
                           sum(p.steps_shared for p in plans))
+    # and those that run inside a loop's body of several steps
+    device_profiler.count("flash.steps_loop_body",
+                          sum(p.steps_loop_body for p in plans))
 
 
 def _count_fetches(qs, ks):
@@ -716,10 +791,16 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band,
     band share ONE branch, the band placed by the grid row (the window
     call's three kernels 9.67 -> 7.03 ms, 6.80 so; PERF.md §6, PR 48).
     Otherwise the row's steps come from `steps_ref`
-    (`KernelSchedule.table`, in SMEM) and run in ONE loop, each masked
-    whole if any step of the plan is: on the v5e the mask costs 2% of the
-    kernel (it is not bound by the vector ALUs) and a second loop 5-8%
-    (PERF.md §6, PR 26). A loop plan's `shared` rows are one row placed by
+    (`KernelSchedule.table`, in SMEM) and run in loops, and since Mosaic
+    overlaps what ONE iteration holds, an iteration holds several steps: for
+    each size of `plan.body` a loop of bodies of that many steps (the row's
+    whole tiles, with no mask; ONE traced step a size, unrolled when the
+    kernel is lowered, so the code is as long whatever the plan), then a
+    loop of the steps left, one an iteration, each masked whole if any step
+    of the plan is (`CAUSAL` at S 16,384, rows of up to 32 steps, the three
+    kernels 60.3 -> 52.0 ms; `_LOOP_BODY`, PERF.md §6, PR 60; at rows of 4
+    steps a second loop cost 5-8% and the mask 2%, PR 26). A loop plan's
+    `shared` rows are one row placed by
     the grid row, as a band is: they run ONE branch of their steps unrolled,
     the step indices traced (`row * stride + offset`), each masked only if
     it needs it, and only the other rows the loop (the window call of 9
@@ -730,9 +811,19 @@ def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band,
         step = body(plan.steps_masked > 0)
 
         def loop():
+            c, first = carry, 1 + len(plan.body)
+            whole = body(False)   # what fills bodies needs no mask
+            for at, k in enumerate(plan.body, 1):
+                c = jax.lax.fori_loop(
+                    0, steps_ref[row, at],
+                    lambda i, c, k=k, first=first: jax.lax.fori_loop(
+                        0, k, lambda t, c: whole(
+                            steps_ref[row, first + i * k + t], c), c,
+                        unroll=True), c)
+                first = first + k * steps_ref[row, at]
             finish(jax.lax.fori_loop(
                 0, steps_ref[row, 0],
-                lambda t, c: step(steps_ref[row, t + 1], c), carry))
+                lambda t, c: step(steps_ref[row, t + first], c), c))
 
         if plan.shared is None:
             return loop()

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops import eva
 from ray_tpu.ops.flash_attention import (
-    _STATIC_BUDGET, _STATIC_STEPS, _SUB, CAUSAL, DIAGONAL, Band,
+    _LOOP_BODY, _STATIC_BUDGET, _STATIC_STEPS, _SUB, CAUSAL, DIAGONAL, Band,
     BlockDiffusion, EvaWindows, SharedRows,
     SlidingWindow, Triangle, _clamp_block, _reference_attention,
     block_schedule, flash_attention)
@@ -158,6 +158,11 @@ def _mask(s_q, s_k, causal, rows, cols):
         rule = np.zeros((rows, cols), bool)
         rule[:s_q, :s_k] = _dense_block_diffusion(causal.length, causal.block)
         return m & rule
+    if isinstance(causal, EvaWindows):
+        rule = np.zeros((rows, cols), bool)
+        rule[:s_q, :s_k] = _dense_eva(causal.length, causal.window,
+                                      causal.chunk)
+        return m & rule
     return m & (c <= r + (s_k - s_q)) if causal else m
 
 
@@ -258,6 +263,32 @@ _SCHEDULES = {
         (3000, 3000, 256, 256, SlidingWindow(1024)), 1.286, 1.286),
     "swa-w1024-s2560-128x128-shared-rows": (
         (2560, 2560, 128, 128, SlidingWindow(1024)), 1.125, 1.125),
+    # loop plans whose rows run their whole tiles in bodies of 4 and of 2
+    # steps with no mask (PR 60): more keys than queries, a padded last
+    # block, and `EvaWindows`, whose rows' whole tiles are not one range
+    "cross-640-over-1408-128x128-loop-bodies": (
+        (640, 1408, 128, 128, True), 1.06, 1.06),
+    "s1200-128x128-padded-loop-bodies": (
+        (1200, 1200, 128, 128, True), 1.26, 1.26),
+    "eva-l2560-w1280-c16-128x128-loop-bodies": (
+        (2560, 2720, 128, 128, EvaWindows(2560, 1280, 16)), 1.32, 1.32),
+}
+
+
+# the cases whose loop plans run steps in bodies: how many, forward / dq and
+# dk/dv
+_LOOP_BODIES = {
+    "s8192-default": (112, 112),
+    "s4096-default": (0, 24),
+    "s2048-256x256": (0, 24),      # dk/dv: 36 steps in rows of up to 8
+    "bd-l1280-b4-loops": (80, 90),
+    # the edge rows of a plan whose interior rows share a branch
+    "swa-w2048-s4096-256x256-shared-rows": (24, 24),
+    "swa-w1024-s3000-256x256-shared-rows-padded": (4, 2),
+    "swa-w1024-s2560-128x128-shared-rows": (24, 24),
+    "cross-640-over-1408-128x128-loop-bodies": (38, 32),
+    "s1200-128x128-padded-loop-bodies": (40, 32),
+    "eva-l2560-w1280-c16-128x128-loop-bodies": (64, 64),
 }
 
 
@@ -429,8 +460,20 @@ def test_block_schedule_against_the_mask(case):
                         for at, m in plan.shared.steps))
                     assert [m for _, m in plan.shared.steps] == sorted(
                         m for _, m in row)
-                else:
-                    assert all(m is True for _, m in row)
+        # the loop's rows: the whole tiles that fill bodies of a size lead,
+        # with no mask; every other step under it
+        assert plan.steps_loop_body == _LOOP_BODIES.get(
+            case, (0, 0))[name == "dkv"]
+        if not plan.static:
+            for i, row in enumerate(plan.rows):
+                if plan.shared and i in plan.shared.rows:
+                    continue
+                lead = sum(k * n for k, n in zip(
+                    plan.body, plan.bodies[i] if plan.body else ()))
+                assert [m for _, m in row] \
+                    == [False] * lead + [True] * (len(row) - lead)
+                whole = [j for j, m in row[:lead]]
+                assert whole == sorted(whole)
         tiles = iter(plan.tiles)
         for i, row in enumerate(plan.rows):
             # unrolled: a step is masked only if a score in it is not valid
@@ -508,6 +551,14 @@ _MIXED = {
     "s1216-128x128-noncausal-loops": dict(s_q=1216, s_k=1216, block_q=128,
                                           block_k=128, causal=False,
                                           static=()),
+    # loops whose rows run their whole tiles in bodies of 4 and of 2 steps
+    # (PR 60): more keys than queries (every row holds 6 or more), and a
+    # padded last block (dk/dv's last query tile is under the mask in every
+    # row: rows of 0 to 8 whole tiles, none and one body of each size)
+    "cross-640-over-1408-loop-bodies": dict(s_q=640, s_k=1408, block_q=128,
+                                            block_k=128, static=()),
+    "s1200-128x128-padded-loop-bodies": dict(s_q=1200, s_k=1200, block_q=128,
+                                             block_k=128, static=()),
 }
 
 
@@ -523,7 +574,9 @@ def test_flash_attention_interior_and_edge_steps(case):
     for name, plan in plans.items():
         assert plan.static == (name in static)
         assert plan.steps_masked
-        assert bool(plan.steps_unmasked) == plan.static
+        # a loop's steps with no mask are those of its bodies
+        assert plan.steps_unmasked == plan.steps_loop_body or plan.static
+        assert plan.steps_unmasked
     assert max(len(steps) for steps in plans["fwd"].rows) > 1
     keys = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(keys[0], (1, s_q, 2, 64), dtype=jnp.float32)
@@ -686,9 +739,13 @@ def test_dkv_is_unrolled_under_a_budget_of_the_plans_total_steps(case):
     if case in ("block-diffusion-l2048", "causal-s4096"):
         assert max(map(len, dkv.rows)) == 8
     if not static:
-        # ONE loop a grid row, masked throughout, on whole tiles, but for the
+        # loops on whole tiles, masked throughout but for the whole tiles
+        # that fill a loop's bodies (a window of one tile has none) and the
         # rows of one shape where they are the plan's majority
-        assert dkv.steps_unmasked == 0 == dkv.steps_diagonal == dkv.steps_band
+        assert dkv.steps_unmasked == dkv.steps_loop_body == {
+            "causal-s4096": 24, "block-diffusion-l3072": 30,
+            "causal-s8192": 112, "window-512-s16384": 0}[case]
+        assert dkv.steps_diagonal == 0 == dkv.steps_band
         assert (dkv.shared is not None) == (case == "window-512-s16384")
         if dkv.shared:   # its steps are masked ones in the loop's rows too
             assert dkv.shared == SharedRows(
@@ -696,7 +753,9 @@ def test_dkv_is_unrolled_under_a_budget_of_the_plans_total_steps(case):
             assert dkv.rows[31] == ((31, True),)
         assert dkv.steps_shared == (62 if dkv.shared else 0)
         assert dkv.steps_triangle == 0
-        assert dkv.table[-1, 0] == len(dkv.rows[-1])
+        lead = 1 + len(dkv.body)   # the steps one at a time, the bodies
+        assert dkv.table[-1, 0] + np.dot(dkv.table[-1, 1:lead], dkv.body) \
+            == len(dkv.rows[-1])
     # forward and dq keep their cap on the longest row, under the cap on a
     # plan's steps in all (block diffusion at L 3,072: rows of 8, 48 steps)
     assert plans["fwd"].static == (
@@ -866,9 +925,9 @@ _PLANS_BEFORE_THE_BAND_STEP = {
     "causal-s4096": (4096, True,
         ("b55b3d926bff0b31", 8),
         ("b55b3d926bff0b31", 8),
-        ("26df8b9341770fef", 0)),
-    "causal-s8192": (8192, True, ("77112d431e8b43f9", 0),
-                     ("77112d431e8b43f9", 0), ("3f21b5c56cde178c", 0)),
+        ("d71427cd32afbf3f", 0)),
+    "causal-s8192": (8192, True, ("6a5cedcafb14667f", 0),
+                     ("6a5cedcafb14667f", 0), ("337e6d5831d1eced", 0)),
     "block-diffusion-l2048": (4096, BlockDiffusion(2048, 4),
         ("10908d9460d329ae", 8),
         ("10908d9460d329ae", 8),
@@ -890,9 +949,10 @@ def test_plans_without_a_band_are_what_they_were(case):
     8,192, `BlockDiffusion(2048, 4)` over 2 x 2,048) hold no band step: the
     rule's answers decide, and theirs fit no band (a causal group's runs
     all start at 0; a block-diffusion row's tiles are not one range, its
-    x_t key tiles already run their diagonal alone). A plan in a loop is,
-    field for field, what the commit before the band step planned; an
-    unrolled one differs from it by its triangle steps alone (PR 51)."""
+    x_t key tiles already run their diagonal alone). An unrolled plan
+    differs from what the commit before the band step planned by its
+    triangle steps alone (PR 51), a plan in a loop by the whole tiles that
+    fill its loop's bodies, with no mask (PR 60)."""
     s, rule, *pinned = _PLANS_BEFORE_THE_BAND_STEP[case]
     plans = block_schedule(s, s, 512, 512, rule)
     for kernel, (digest, triangles) in zip(("fwd", "dq", "dkv"), pinned):
@@ -1022,25 +1082,17 @@ _TRIANGLE_CALLS = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_TRIANGLE_CALLS))
-def test_flash_attention_runs_a_cut_tile_on_its_kept_sub_tiles(case):
-    """Forward and the three gradients of the Pallas kernels (interpret
-    mode) where the plans hold `Triangle` steps, against
-    `_reference_attention`, which builds the DENSE mask."""
-    heads, kv_heads, rule, tile, s_q, s_k, fwd, dkv, *rope = \
-        _TRIANGLE_CALLS[case]
-    plans = block_schedule(s_q, s_k, _clamp_block(tile, s_q),
-                           _clamp_block(tile, s_k), rule)
-    assert [plans[name].steps_triangle for name in ("fwd", "dq", "dkv")] \
-        == [fwd, fwd, dkv]
-    assert all(plan.static for plan in plans.values())
+def _against_the_dense_mask(heads, kv_heads, rule, tile, s_q, s_k, rope=0):
+    """Forward and the gradients of every operand, the Pallas kernels in
+    interpret mode against `_reference_attention`, which builds the DENSE
+    mask; `rope`: the rotary channels of a call in parts."""
     d = 128 if rope else 32
     q, k, v = _make_qkv(S=s_k, H=heads, kv_heads=kv_heads, D=d, seed=s_q)
     parts = ()
     if rope:
         keys = jax.random.split(jax.random.PRNGKey(11), 2)
-        parts = (jax.random.normal(keys[0], (1, s_k, heads, rope[0])),
-                 jax.random.normal(keys[1], (1, s_k, 1, rope[0])))
+        parts = (jax.random.normal(keys[0], (1, s_k, heads, rope)),
+                 jax.random.normal(keys[1], (1, s_k, 1, rope)))
 
     def loss(q, k, v, *parts, **how):
         rotary = dict(zip(("q_rope", "k_rope"), parts))
@@ -1059,6 +1111,67 @@ def test_flash_attention_runs_a_cut_tile_on_its_kept_sub_tiles(case):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+@pytest.mark.parametrize("case", sorted(_TRIANGLE_CALLS))
+def test_flash_attention_runs_a_cut_tile_on_its_kept_sub_tiles(case):
+    """Forward and the three gradients of the Pallas kernels (interpret
+    mode) where the plans hold `Triangle` steps, against
+    `_reference_attention`, which builds the DENSE mask."""
+    heads, kv_heads, rule, tile, s_q, s_k, fwd, dkv, *rope = \
+        _TRIANGLE_CALLS[case]
+    plans = block_schedule(s_q, s_k, _clamp_block(tile, s_q),
+                           _clamp_block(tile, s_k), rule)
+    assert [plans[name].steps_triangle for name in ("fwd", "dq", "dkv")] \
+        == [fwd, fwd, dkv]
+    assert all(plan.static for plan in plans.values())
+    _against_the_dense_mask(heads, kv_heads, rule, tile, s_q, s_k, *rope)
+
+
+# (q heads, kv heads, rule, tile, s_q, s_k, the steps of the forward's plan and
+# of dk/dv's that run in a loop's bodies[, rotary channels]): loop plans whose
+# rows run their whole tiles several a body, `_LOOP_BODY`'s 4 and then 2,
+# with no mask, and the rest one an iteration under it
+_LOOP_BODY_CALLS = {
+    # rows of 0 to 9 whole tiles: shorter than a body (0, 1), one body of 2
+    # (2, 3), one of 4 (4, 5), one of each (6, 7), two of 4 (8, 9)
+    "causal-rows-of-1-to-10": (2, 2, True, 128, 1280, 1280, 40, 40),
+    "gqa-4-to-1": (4, 1, True, 128, 1280, 1280, 40, 40),
+    # more keys than queries: the shortest row holds 6 whole tiles
+    "keys-768-ahead": (2, 1, True, 128, 640, 1408, 38, 32),
+    # the last block padded, queries and keys: dk/dv's last query tile is
+    # under the mask in every row
+    "padded-last-block": (2, 1, True, 128, 1200, 1200, 40, 32),
+    # keys wider than values: 128 + 64 channels, ONE rotary key head
+    "in-parts-128-and-64": (2, 2, True, 128, 1280, 1280, 40, 40, 64),
+    # no rule at all, the last block padded: every tile but a row's last is
+    # whole
+    "no-rule-padded": (2, 1, False, 128, 1216, 1216, 80, 80),
+    # a block-diffusion row's whole tiles are x_0's before its block
+    "block-diffusion": (2, 1, BlockDiffusion(1280, 4), 128, 2560, 2560,
+                        80, 90),
+    # a window's edge rows loop beside the rows that share a branch
+    "window-edge-rows-beside-shared-rows": (
+        2, 1, SlidingWindow(1024), 128, 2560, 2560, 24, 24),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LOOP_BODY_CALLS))
+def test_flash_attention_where_a_loops_body_runs_several_steps(case):
+    """Forward and the gradients of every operand of the Pallas kernels
+    (interpret mode) where a loop plan's rows run steps in bodies, against
+    `_reference_attention`, which builds the DENSE mask."""
+    heads, kv_heads, rule, tile, s_q, s_k, fwd, dkv, *rope = \
+        _LOOP_BODY_CALLS[case]
+    plans = block_schedule(s_q, s_k, tile, tile, rule)
+    assert [(plans[name].static, plans[name].steps_loop_body)
+            for name in ("fwd", "dq", "dkv")] \
+        == [(False, fwd), (False, fwd), (False, dkv)]
+    assert all(plan.body == _LOOP_BODY["fwd"] == (4, 2)
+               for plan in plans.values())
+    assert (plans["fwd"].shared is not None) == isinstance(rule,
+                                                           SlidingWindow)
+    _against_the_dense_mask(heads, kv_heads, rule, tile, s_q, s_k, *rope)
+
+
 def test_a_window_of_several_tiles_at_the_cell_shape():
     """train-smallthinker-1chip's two calls, S 16,384 in tiles of 512. The
     window layers (4,096 keys = 8 tiles): a row walks the window's trailing
@@ -1069,9 +1182,12 @@ def test_a_window_of_several_tiles_at_the_cell_shape():
     rows are ONE row, placed by the grid row (the forward's and dq's 8-31,
     dk/dv's 0-23): they share one unrolled branch, the 7 steps with no mask
     first and then the two cut tiles, whole under the mask, and the 8 edge
-    rows keep the loop, every step under the mask. No two causal rows are
-    alike: its loops are what they were. What runs over what the rules
-    keep is the loops': 1.125 and 1.031."""
+    rows keep the loop. No two causal rows are alike: its rows all loop. A
+    row of the loop runs its whole tiles in bodies of 4 and then of 2 steps
+    with no mask (PR 60: 480 of the causal plan's 528 steps, 24 of the edge
+    rows' 36) and what is left, its diagonal tile among it, one step an
+    iteration under the mask. What runs over what the rules keep is the
+    loops': 1.125 and 1.031."""
     window = block_schedule(16384, 16384, 512, 512, SlidingWindow(4096))
     causal = block_schedule(16384, 16384, 512, 512, True)
     assert SlidingWindow(4096).needed(16384, 16384) == 58_722_304
@@ -1085,9 +1201,20 @@ def test_a_window_of_several_tiles_at_the_cell_shape():
             assert (len(plan.tiles), max(map(len, plan.rows))) \
                 == (steps, longest)
             assert plan.executed_over_needed == pytest.approx(over, abs=1e-3)
-    for plan in causal.values():
+    for name, plan in causal.items():
         assert plan.shared is None and plan.steps_shared == 0
-        assert (plan.steps_unmasked, plan.steps_masked) == (0, 528)
+        assert (plan.steps_unmasked, plan.steps_masked) == (480, 48)
+        assert plan.steps_loop_body == 480 and plan.body == (4, 2)
+        # a row of n whole tiles (the forward's row i: i; dk/dv's: 31 - i):
+        # (n // 4, n % 4 // 2) bodies, then its diagonal tile and the odd
+        # whole one under the mask; in the table after the three counts,
+        # the whole tiles first
+        assert plan.bodies == tuple((n // 4, n % 4 // 2) for n in (
+            range(31, -1, -1) if name == "dkv" else range(32)))
+        assert plan.table.shape == (32, 3 + 32)
+        longest = 0 if name == "dkv" else 31
+        assert plan.table[longest].tolist() == [2, 7, 1] + (
+            list(range(1, 31)) + [0, 31] if name == "dkv" else list(range(32)))
     for name, plan in window.items():
         # from the row's own block: the forward's and dq's rows walk back
         # over the keys, dk/dv's on over the queries
@@ -1101,10 +1228,14 @@ def test_a_window_of_several_tiles_at_the_cell_shape():
             if i in plan.shared.rows:   # the branch, placed by the row
                 assert row == tuple(sorted(
                     (i + at, m) for at, m in plan.shared.steps))
-            else:                       # the loop's, masked whole
-                assert 1 <= len(row) <= 8 and all(m is True for _, m in row)
-        assert (plan.steps_unmasked, plan.steps_masked) == (168, 84)
-        assert plan.table.shape == (32, 10)   # the edge rows read it still
+            else:   # the loop's: whole tiles in bodies, the rest masked
+                assert 1 <= len(row) <= 8
+                lead = 4 * plan.bodies[i][0] + 2 * plan.bodies[i][1]
+                assert [m for _, m in row] \
+                    == [False] * lead + [True] * (len(row) - lead)
+        assert (plan.steps_unmasked, plan.steps_masked) == (192, 60)
+        assert plan.steps_loop_body == 24
+        assert plan.table.shape == (32, 12)   # the edge rows read it still
     # asked tile by tile, 7 of a row's 9 need no mask
     rule = SlidingWindow(4096)
     assert [rule.tile(20 * 512, 512, k0 * 512, 512) for k0 in range(11, 22)] \
@@ -1113,21 +1244,22 @@ def test_a_window_of_several_tiles_at_the_cell_shape():
 
 
 # the plans of the other cells' long calls, at 512 x 512: (queries, keys, rule,
-# the digests of the forward's / dq's plan and of dk/dv's at the commit before
-# a loop plan's same-shaped rows shared a branch, PR 57's). No two causal
-# rows are alike; Laguna's window plan is unrolled; EVA's largest group (16
-# rows of 4 steps) holds 64 of the forward's 304 steps
+# the digests of the forward's / dq's plan and of dk/dv's (the loops' since PR
+# 60, whose bodies run whole tiles with no mask; Laguna's window plan, which
+# is unrolled, PR 57's still), the steps in a loop's bodies of all a kernel's
+# steps). No two causal rows are alike; EVA's largest group (16 rows of 4
+# steps) holds 64 of the forward's 304 steps
 _PLANS_WITH_NO_SHARED_ROWS = {
     "causal-s8192": (8192, 8192, True,
-                     "77112d431e8b43f9", "3f21b5c56cde178c"),
+                     "6a5cedcafb14667f", "337e6d5831d1eced", (112, 136)),
     "causal-s16384": (16384, 16384, True,
-                      "e4aa1d90ceb2cdfe", "3b3fe548c5054e18"),
+                      "fd0e0a92e41c9a71", "3aea0742811aea95", (480, 528)),
     "causal-s32768": (32768, 32768, True,
-                      "dc85867d203affb7", "692fcf3161b22d20"),
+                      "d3535a2460e63cb0", "ff525787c888fe4a", (1984, 2080)),
     "window-512-s8192": (8192, 8192, SlidingWindow(512),
-                         "93608cf8e569bd02", "bc8b28505b3b45cb"),
+                         "93608cf8e569bd02", "bc8b28505b3b45cb", (0, 16)),
     "eva-s32768": (32768, 34816, EvaWindows(32768, 2048, 16),
-                   "6e157575ab9741f8", "414bde02b65d4170"),
+                   "69514c7086e30892", "a79726d245f489da", (160, 304)),
 }
 
 
@@ -1136,12 +1268,18 @@ def test_rows_share_a_branch_only_where_most_of_a_loop_plan_is_one_row(case):
     """The other cells' calls plan what they planned, field by field, and no
     row of theirs shares a branch: what decides is read off the plan (several
     rows of one shape that hold half its steps), not off the rule's type."""
-    s_q, s_k, rule, fwd, dkv = _PLANS_WITH_NO_SHARED_ROWS[case]
+    s_q, s_k, rule, fwd, dkv, (in_bodies, steps) = \
+        _PLANS_WITH_NO_SHARED_ROWS[case]
     plans = block_schedule(s_q, s_k, 512, 512, rule)
     assert plans["dq"] is plans["fwd"]
     for plan, digest in ((plans["fwd"], fwd), (plans["dkv"], dkv)):
         assert plan.shared is None and plan.steps_shared == 0
         assert _plan_digest(plan) == digest
+        # the four cells' loop calls (PR 60): the whole tiles of each row
+        # in bodies of 4 and of 2 steps with no mask, in every kernel
+        assert (plan.steps_loop_body, len(plan.tiles)) == (in_bodies, steps)
+        assert plan.steps_unmasked == in_bodies
+        assert plan.body == (() if plan.static else _LOOP_BODY["fwd"])
 
 
 # (q heads, kv heads, window, tile or (block_q, block_k), S, the rows that
@@ -1219,7 +1357,8 @@ def test_runs_that_step_by_one_difference():
 def test_flash_attention_counts_the_steps_of_a_shared_branch():
     """`flash.steps_shared_row`: the steps, a (batch, head), of the rows that
     run a shared branch, a lowering of each kernel as its siblings are; a
-    plan with no such rows adds nothing."""
+    plan with no such rows adds nothing. `flash.steps_loop_body`: those of
+    the loop's rows that run in its bodies of several steps."""
     from ray_tpu._private import device_profiler
 
     def counted(rule, s):
@@ -1231,18 +1370,23 @@ def test_flash_attention_counts_the_steps_of_a_shared_branch():
             block_k=256).sum()))(q, k, v)
         after = device_profiler.snapshot()["counters"]
         return {name: after[name] - before.get(name, 0) for name in (
-            "flash.steps_shared_row", "flash.steps_unmasked",
-            "flash.steps_masked", "flash.steps_triangle")}
+            "flash.steps_shared_row", "flash.steps_loop_body",
+            "flash.steps_unmasked", "flash.steps_masked",
+            "flash.steps_triangle")}
 
     # 12 rows, 8 of them the one row of 5 steps, in each of three kernels: 3
     # whole tiles with no mask and the two the rule cuts; rows of 1, 2, 3, 4
-    # loop
+    # loop, the last two with a body of 2 whole tiles
     assert counted(SlidingWindow(1024), 3072) == {
-        "flash.steps_shared_row": 3 * 8 * 5, "flash.steps_unmasked": 3 * 8 * 3,
-        "flash.steps_masked": 3 * (8 * 2 + 10), "flash.steps_triangle": 0}
+        "flash.steps_shared_row": 3 * 8 * 5, "flash.steps_loop_body": 3 * 4,
+        "flash.steps_unmasked": 3 * (8 * 3 + 4),
+        "flash.steps_masked": 3 * (8 * 2 + 6), "flash.steps_triangle": 0}
+    # rows of 1 to 12 steps, 0 to 11 of them whole tiles: 2 x (0 + 0 + 1 +
+    # 1) + 6 x 4 + 2 x 8 of them in bodies of 4, then 6 rows a body of 2
     assert counted(True, 3072) == {
-        "flash.steps_shared_row": 0, "flash.steps_unmasked": 0,
-        "flash.steps_masked": 3 * 78, "flash.steps_triangle": 0}
+        "flash.steps_shared_row": 0, "flash.steps_loop_body": 3 * 60,
+        "flash.steps_unmasked": 3 * 60, "flash.steps_masked": 3 * 18,
+        "flash.steps_triangle": 0}
 
 
 def test_a_kernel_states_a_vmem_limit_only_past_the_default():
@@ -1627,20 +1771,23 @@ _CELL_CALLS = {
     "train-laguna-1chip.window": (
         (1, 8192, 64, 8, 128, SlidingWindow(512), 0),
         "85e93091c021de5c2b0afebcf1b3397725138daf6675ce33c754cee1c00aeadf"),
+    # PR 60's text, as the three loop calls below: a loop plan's rows run
+    # their whole tiles in bodies of 4 and of 2 steps with no mask
     "train-laguna-1chip.full": (
         (1, 8192, 48, 8, 128, True, 0),
-        "7ff939d3d30c658b9972b51bb2b077416905de44852f40179fe78c0127978c4f"),
-    # PR 58's text: 24 of its 32 grid rows share ONE unrolled branch
+        "a97d9981022efb68a7972dfb3dca6d2d4081431d7746cc796e99cfa4b6be3e84"),
+    # 24 of its 32 grid rows share ONE unrolled branch (PR 58); the 8 edge
+    # rows' loop has the bodies
     "train-smallthinker-1chip.window": (
         (1, 16384, 28, 4, 128, SlidingWindow(4096), 0),
-        "37bcaf34be8c475d3ca060adc9b6fb0d264954a1e58e5cbdfb6bc4d2b5aea492"),
+        "df6c2055f1a4e5b0e032276d74336c82524c1eb62e28f3023a783bb514e1894c"),
     "train-smallthinker-1chip.full": (
         (1, 16384, 28, 4, 128, True, 0),
-        "96dc2d03780a36589bdcaf470a2166eef087a032d0c9490f8519997f13077f7e"),
-    # PR 56's text (the call at 64-wide heads and a scale of its own)
+        "cd86a4b7c3e2fae3a0089d5b36304e8efa5445b9eac0bbeaf5e685c5703ffed7"),
+    # the call at 64-wide heads and a scale of its own
     "train-granite4-1chip": (
         (1, 32768, 32, 8, 64, True, 0, 1 / 64),
-        "dce8ecc9e3ddcd3e7dc6b8b57ed59295174c1fdec6830c77c2e2335ed4cff254"),
+        "7b457fd5873f820913e6dbd630db66a42a8c16096d3dfb0a9305bbb72289e210"),
 }
 
 
@@ -1777,8 +1924,10 @@ def test_eva_windows_schedule_at_the_cell_shape():
     but the 64 rows are 304 steps in all, and unrolled they ran 16 x slower
     on the v5e than as loops (PERF.md section 6, PR 57), so no plan of more
     than `_STATIC_STEPS` steps in all is unrolled: the three kernels walk
-    their 304 tiles in loops, every step masked. dk/dv's longest row (a
-    summary tile's) walks 60 query tiles."""
+    their 304 tiles in loops, the 192 the rule keeps whole in bodies of 4
+    and of 2 steps with no mask where a row's fill one (160), the others
+    one an iteration under the mask (PR 60). dk/dv's longest row (a summary
+    tile's) walks 60 query tiles."""
     rule = EvaWindows(32768, 2048, 16)
     assert rule.kept(32768, 34816) == (33_570_816, 31_457_280)
     assert rule.needed(32768, 34816) == 65_028_096
@@ -1791,7 +1940,8 @@ def test_eva_windows_schedule_at_the_cell_shape():
     assert not fwd.static and not dkv.static
     for plan in (fwd, dkv):
         assert (plan.steps_unmasked, plan.steps_masked,
-                plan.steps_triangle) == (0, 304, 0)
+                plan.steps_triangle) == (160, 144, 0)
+        assert plan.steps_loop_body == 160 and plan.body == (4, 2)
         assert plan.steps_skipped == 64 * 68 - 304
         assert plan.executed_over_needed == pytest.approx(
             304 * 512 * 512 / 65_028_096)                      # 1.2255

@@ -47,10 +47,12 @@ from ray_tpu.models import (
     blocks, llama, mixers, mla_moe, nemotron_h, sdar, window_moe)
 from ray_tpu.ops import grouped_matmul, row_moves, row_sums
 from ray_tpu.ops.flash_attention import (
-    BlockDiffusion, SlidingWindow, block_schedule, flash_attention)
+    BlockDiffusion, EvaWindows, SlidingWindow, block_schedule,
+    flash_attention)
 from ray_tpu.parallel import moe
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import logical_sharding, param_shardings
+from tools import step_lowering_hash
 
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
 one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
@@ -181,6 +183,8 @@ lowered = jax.jit(flash_grads).lower(
     spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
     spec((4, 2048, 8, 128), bf16))
 out["flash_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+# train-1chip's call as lowered, each kernel's assembly without locations
+out["flash_s2048_lowered"] = step_lowering_hash.digest(lowered.as_text())
 out["flash_s2048_vmem_limits"] = re.findall(
     r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(flash_grads)(
         spec((4, 2048, 32, 128), bf16), spec((4, 2048, 8, 128), bf16),
@@ -537,6 +541,33 @@ for cell_call, window in (("smallthinker_window", SlidingWindow(4096)),
     out[cell_call + "_operands"] = flash_operands(long_hlo)
     out[cell_call + "_repeats"] = repeats(long_hlo, 28 * 16384 * 128)
 
+# the four cells' calls whose plans are LOOPS: the steps a kernel's plan runs
+# in its loop's bodies, of all its steps; and the two not compiled above,
+# value and gradient: Granite's at a 64-wide head and EvaByte's flash call
+# under its rule, over [2,048 summaries ; 32,768 bytes]
+for cell_call, rule, q_shape, keys, kv, scale in (
+        ("laguna_full", True, (1, 8192, 48, 128), 8192, 8, None),
+        ("smallthinker_full", True, (1, 16384, 28, 128), 16384, 4, None),
+        ("granite", True, (1, 32768, 32, 64), 32768, 8, 1 / 64),
+        ("evabyte", EvaWindows(32768, 2048, 16), (1, 32768, 32, 128), 34816,
+         32, None)):
+    out[cell_call + "_loop_bodies"] = [
+        [plan.static, list(plan.body), plan.steps_loop_body, len(plan.tiles)]
+        for plan in block_schedule(q_shape[1], keys, 512, 512, rule).values()]
+    if cell_call in out:
+        continue
+    loop_call = jax.value_and_grad(
+        lambda q, k, v, rule=rule, scale=scale: flash_attention(
+            q, k, v, use_pallas=True, causal=rule, scale=scale)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    kv_shape = (1, keys, kv, q_shape[3])
+    try:
+        jax.jit(loop_call).lower(spec(q_shape, bf16), spec(kv_shape, bf16),
+                                 spec(kv_shape, bf16)).compile()
+        out[cell_call] = "compiled"
+    except Exception as e:  # noqa: BLE001 - a refusal is the finding
+        out[cell_call] = str(e)[:300]
+
 # ONE checkpointed attention layer of `nemotron_h` (train-nemotron3-1chip:
 # GQA 32 / 2 x 128, no RoPE, B 2 x S 2048) under `bodies`' policy, value
 # and gradient: the Pallas calls the v5e's compiler leaves in it
@@ -741,6 +772,39 @@ def test_flash_calls_with_triangle_steps_compile_for_v5e(compiled):
     assert len(compiled["mla_parts_calls"]) == 3
     assert compiled["flash_s2048_vmem_limits"] == [] \
         == compiled["flash_bd_vmem_limits"]
+
+
+def test_the_dense_cells_flash_call_lowers_to_the_text_it_had(compiled):
+    """train-1chip's call, `[4, 2048, 32, 128]` causal over 8 KV heads, value
+    and gradient, as LOWERED for the v5e, every kernel's assembly in it less
+    its locations (`tools/step_lowering_hash.py`): the text of the commit
+    before a loop plan's rows ran steps in bodies (PR 59's). Its plans are
+    unrolled, 10 steps a kernel, and reach none of that."""
+    assert compiled["flash_s2048_lowered"] == (
+        "ab940e3f54001e440bfbe9d7beed7028"
+        "02dd11282941af318a6e65cd4505fb57")
+
+
+@pytest.mark.parametrize("call, steps, in_bodies", [
+    ("laguna_full", 136, 112), ("smallthinker_full", 528, 480),
+    ("granite", 2080, 1984), ("evabyte", 304, 160)])
+def test_loop_plans_run_their_whole_tiles_in_bodies_for_v5e(
+        compiled, call, steps, in_bodies):
+    """The four cells' flash calls whose plans are loops, as the v5e's
+    compiler takes them since a row runs its whole tiles four and then two a
+    body of straight-line code with no mask (`_LOOP_BODY`): `CAUSAL` at `[1,
+    48, 8192, 128]` over 8 KV heads, at `[1, 28, 16384, 128]` over 4, at
+    `[1, 32, 32768, 64]` over 8 (the call the compiler once refused by 764
+    KiB of VMEM: `_in_vmem`), and `EvaWindows(32768, 2048, 16)` at `[1, 32,
+    32768, 128]` over 34,816 keys: forward, dq and dk/dv each compile, under
+    the limits the calls stated before (`_vmem_limit`: the bodies' score
+    tiles are the compiler's to place)."""
+    # a refusal is a string that says why; Laguna's call, compiled under its
+    # layer's policy, is its kernels' signatures
+    assert compiled[call] == "compiled" or isinstance(compiled[call], list), \
+        compiled[call]
+    assert compiled[call + "_loop_bodies"] \
+        == [[False, [4, 2], in_bodies, steps]] * 3
 
 
 def test_flash_fwd_bwd_compiles_for_v5e(compiled):
@@ -1037,11 +1101,14 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
         "0192ab54ecd8b9eece3b74fe696ad7f626f15efc50d82530140f6db30591e62a")
     # and since PR 50, which has a longer call state its VMEM limit, both
     # traced to the text PR 49 left: at S 8,192 nothing is stated. The full
-    # call, loops, still does; the window call's first row, its own tile
+    # call's loops run their rows' whole tiles in bodies of 4 and of 2 steps
+    # since PR 60: its text; the window call's first row, its own tile
     # alone, is a triangle step since PR 51 (dk/dv: the last row), the one
     # branch of its two that is not the band's: PR 51's text
-    assert compiled["laguna_full_jaxpr"] == (
+    assert compiled["laguna_full_jaxpr"] != (     # PR 49's, until PR 60
         "dc1bde509d1bcbdffd1972a302135611f48b337c4a9784192d6e2610f0bb16f6")
+    assert compiled["laguna_full_jaxpr"] == (
+        "55ce70dccebd147dea6455459dd25d5b7340b859c3823d68944876e4a3db7d89")
     assert compiled["laguna_window_jaxpr"] != (   # PR 49's
         "bb8f601aafbc03b1d2e47ad5becb97d305d31ef91a6140603b735510530a66e6")
     assert compiled["laguna_window_jaxpr"] == (
@@ -1064,22 +1131,24 @@ def test_window_and_full_flash_calls_at_s16384_compile_for_v5e(compiled):
     """train-smallthinker-1chip's two calls, value and gradient: `[1, 28,
     16384, 128]` over 4 KV heads under `SlidingWindow(4096)` (8 tiles of
     512: rows of 9 steps, 252 a (batch, head)) and `CAUSAL` (rows of up to
-    32, 528), all six kernels loops, the window's with one unrolled branch
-    for their 24 interior rows beside it. One KV head's K and V whole are 2 x 4
+    32, 528), all six kernels loops (a row's whole tiles four and two a
+    body, PR 60), the window's with one unrolled branch for their 24 interior
+    rows beside it. One KV head's K and V whole are 2 x 4
     MiB, twice over in the pipeline's buffers: the compiler refused every
     one ("Scoped allocation with size 16.75M and limit 16.00M") until each
     stated its own limit, its blocks twice plus 16 MiB (`_vmem_limit`): 32.5
     to 33.3 MiB of the v5e's 128."""
     for name, steps, longest, digest in (
             ("smallthinker_window", 252, 9,
-             "90e3c633de915098825a425902ebe3d2"
-             "c46da60b34a1b16e30e3e8e5d416f2c5"),
-            ("smallthinker_full", 528, 32, "5a39bff16b81f9759f3dc161688c75fc"
-             "e7b3ecb087b456794d390d078b58a956")):
+             "8cc14de4d1e6c3d572c2299b996f1a9a"
+             "3d12a50dc4e6724790a6ba517caed9e0"),
+            ("smallthinker_full", 528, 32, "976e9b502281bfccc59fbd55bc8be43d"
+             "05ee7201c370fcfd1a041e4b260e3efa")):
         assert compiled[name] == "compiled", compiled[name]
-        # loops: no triangle step. The causal call traces to the text it had
-        # before there was one (PR 50's); the window call's 24 rows of ONE
-        # shape share an unrolled branch (PR 58's)
+        # loops: no triangle step. The window call's 24 rows of ONE shape
+        # share an unrolled branch (PR 58); the loops, all of the causal
+        # call's rows and the window call's 8 edge rows, run a row's whole
+        # tiles in bodies of 4 and of 2 steps with no mask: PR 60's texts
         assert compiled[name + "_plans"] == [[False, steps, longest, 0]] * 3
         assert compiled[name + "_jaxpr"] == digest
         limits = sorted(map(int, compiled[name + "_vmem_limits"]))

@@ -28,8 +28,24 @@ normalised apart. `ms`: the three kernels on the device's clock from a short
 trace, beside `bound_ms` (the rule's kept scores over the peak, or the
 operands' bytes, by the opcount module that counts the rule). Writes
 chiprun_out/flash_chip_check.json.
+
+`--sweep`: no oracle; the same call at several sizes of a loop plan's bodies
+(`ops/flash_attention._LOOP_BODY`, set here for the process: no body, which
+is one step an iteration, then bodies of 2, of 4 and of 8 of a row's whole
+tiles, then the ladder 4, 2; or `--bodies '4,2;4,2,1'`: those ladders in
+turn), ms a kernel, the steps that run in bodies, and the largest relative
+error of o and the gradients against the first form's. (Bodies of ANY steps
+under the plan's mask, which PR 60 measured and did not keep, are not a form
+the program has.) What `_LOOP_BODY`'s comment was read from
+(PERF.md §6, PR 60), at the four cells' loop calls: the default (Granite's);
+`--seq 8192 --heads 48 --kv-heads 8 --head-dim 128 --scale 0.08838834764831845`
+(Laguna's full layers); `--seq 16384 --heads 28 --kv-heads 4 --head-dim 128
+--scale 0.08838834764831845` (SmallThinker's, and with `--rule window --window
+4096` its window layers' edge rows); EvaByte's above. Appends a line to
+chiprun_out/flash_loop_body_sweep.jsonl.
 """
 import argparse
+import importlib
 import json
 import os
 import re
@@ -41,7 +57,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.ops import eva  # noqa: E402
-from ray_tpu.ops.flash_attention import SlidingWindow, flash_attention  # noqa: E402
+from ray_tpu.ops.flash_attention import (  # noqa: E402
+    EvaWindows, SlidingWindow, block_schedule, flash_attention)
 from tools.kda_chip_check import kernel_ms  # noqa: E402
 
 BOUND = 2e-2
@@ -103,8 +120,49 @@ def kernel_of(event_name):
             ("bf16", "bf16"): "dkv_ms"}.get(tuple(outputs))
 
 
+def value_and_grads(kernel, w, n):
+    """jit of (o, the gradients of sum(o * w) by the first `n` operands)."""
+    return jax.jit(lambda *x: (kernel(*x), jax.grad(
+        lambda *x: jnp.sum(kernel(*x).astype(jnp.float32) * w),
+        argnums=tuple(range(n)))(*x)))
+
+
+FORMS = [(), (2,), (4,), (8,), (4, 2)]
+
+
+def sweep(kernel, args, w, rule, s, s_k, forms):
+    """-> a record a form of `forms`: the three kernels' ms, the steps of
+    the forward's and dk/dv's plans that run in bodies, and how far o and
+    the gradients are from the first form's."""
+    flash = importlib.import_module("ray_tpu.ops.flash_attention")
+    out, first = [], None
+    for form in forms:
+        flash._LOOP_BODY = {"fwd": form, "dkv": form}
+        flash._block_schedule.cache_clear()
+        plans = block_schedule(s, s_k, 512, 512, rule)
+        both = value_and_grads(kernel, w, len(args))
+        o, grads = jax.block_until_ready(both(*args))
+        got = [x.astype(jnp.float32) for x in (o,) + tuple(grads)]
+        first = first or got
+        out.append({
+            "body": form,
+            "ms": kernel_ms(both, *args, n=3, kernel_of=kernel_of),
+            "steps_loop_body": [plans[name].steps_loop_body
+                                for name in ("fwd", "dkv")],
+            "steps": [len(plans[name].tiles) for name in ("fwd", "dkv")],
+            "rel_err_to_first": max(
+                float(jnp.linalg.norm(x - y) / jnp.linalg.norm(y))
+                for x, y in zip(got, first))})
+        print(json.dumps(out[-1]), flush=True)
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--bodies", default=None, help="with --sweep: the sizes "
+                    "of a loop's bodies in place of the default forms, as "
+                    "'4,2;2,1'")
     ap.add_argument("--seed", type=int, default=55)
     ap.add_argument("--rule", choices=("causal", "window", "eva"),
                     default="causal")
@@ -174,6 +232,19 @@ def main():
         def plain(scale, **how):
             return lambda *x: oracle(*x, blocks, scale, keep, **how)
 
+    if a.sweep:
+        rule = EvaWindows(s, a.window, a.chunk) if is_eva \
+            else SlidingWindow(a.window) if a.rule == "window" else True
+        out = {"device": jax.devices()[0].device_kind, "seed": a.seed,
+               "rule": a.rule, "shape": [1, s, h, d], "keys": s_k,
+               "kv_heads": g, "forms": sweep(
+                   kernel, args, w, rule, s, s_k, FORMS if not a.bodies else [
+                       tuple(map(int, sizes.split(",")))
+                       for sizes in a.bodies.split(";")])}
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/flash_loop_body_sweep.jsonl", "a") as f:
+            f.write(json.dumps(out) + "\n")
+        return 0
     every = tuple(range(len(args)))
 
     def run(fn, loss):
@@ -216,9 +287,7 @@ def main():
            "controls": {name: against(fn) for name, fn in controls.items()}}
     rest = jnp.ones((s,), bool).at[picked].set(False)
     out["dq_outside_is_zero"] = bool(jnp.all(got[1][0, rest] == 0))
-    both = jax.jit(lambda *x: (kernel(*x), jax.grad(
-        lambda *x: jnp.sum(kernel(*x).astype(jnp.float32) * w),
-        argnums=every)(*x)))
+    both = value_and_grads(kernel, w, len(args))
     jax.block_until_ready(both(*args))
     out["ms"] = kernel_ms(both, *args, n=3, kernel_of=kernel_of)
     peak = peaks.peaks("TPU v5 lite")
