@@ -228,17 +228,20 @@ def _residual_seq_axes(x, mesh, rules) -> tuple:
     without one (the paged forward's ambient mesh is the compiler's)."""
     if mesh is None:
         return ()
-    axes = logical_sharding(mesh, _RESIDUAL, rules, x.shape).spec[1]
+    axes = logical_sharding(mesh, _RESIDUAL, rules, x.shape[-3:]).spec[1]
     return axes if isinstance(axes, tuple) else (axes,) if axes else ()
 
 
 def residual(x, mesh=None, rules: Optional[LogicalAxisRules] = None):
-    """Hold the residual stream to its layout between sublayers."""
+    """Hold the residual stream to its layout between sublayers: x
+    [B, S, D], or n streams of it [n, B, S, D] (`models/streams.py`), the
+    streams' dim whole on every chip."""
     if "tp" in _residual_seq_axes(x, mesh, rules):
         # per LOWERING of a boundary, not per run (the scanned layer body
         # lowers once for all layers)
         device_profiler.count("tp.seq_sharded_boundaries")
-    return with_logical_constraint(x, _RESIDUAL, mesh=mesh, rules=rules)
+    axes = _RESIDUAL if x.ndim == 3 else (None,) + _RESIDUAL
+    return with_logical_constraint(x, axes, mesh=mesh, rules=rules)
 
 
 def mlp_ring(h, params, mesh):
@@ -332,19 +335,27 @@ def scaled(out, branch):
     return (out.astype(jnp.float32) * branch).astype(out.dtype)
 
 
+def gated_mlp(h, params, config, mesh=None,
+              rules: Optional[LogicalAxisRules] = None):
+    """The SwiGLU MLP of h [B, S, D], a layer's normed input, before the
+    residual is added (`mlp_sublayer` adds it; `models/streams.py` spreads
+    it over the streams): the ring over `tp` where the rows arrive
+    scattered over it, `swiglu` elsewhere."""
+    lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
+    if _residual_seq_axes(h, mesh, rules) == ("tp",) \
+            and config.d_ff % mesh.shape["tp"] == 0:
+        return mlp_ring(h, params, mesh)
+    return swiglu(h, params, lc)
+
+
 def mlp_sublayer(x, params, config, mesh=None,
                   rules: Optional[LogicalAxisRules] = None, branch=None):
     """Pre-norm SwiGLU MLP block shared by training and decode paths;
     `branch` as `attn_sublayer`'s."""
-    c = config
-    lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
-    h = rms_norm(x, params["mlp_norm"], c.norm_eps)
-    if _residual_seq_axes(x, mesh, rules) == ("tp",) \
-            and c.d_ff % mesh.shape["tp"] == 0:
-        return residual(x + scaled(mlp_ring(h, params, mesh), branch),
-                         mesh, rules)
-    x = x + scaled(swiglu(h, params, lc), branch)
-    return residual(x, mesh, rules)
+    h = rms_norm(x, params["mlp_norm"], config.norm_eps)
+    return residual(
+        x + scaled(gated_mlp(h, params, config, mesh, rules), branch),
+        mesh, rules)
 
 
 def swiglu(h, params, lc):

@@ -69,36 +69,47 @@ def routing(h, p, config):
                      topk_group=c.topk_group)
 
 
-def expert_sublayer(x, p, config, mesh=None,
-                    rules: Optional[LogicalAxisRules] = None, ahead=None,
-                    form: str = "swiglu"):
-    """x [B, S, D] -> (x + routed + shared experts of RMSNorm(x), the
-    chosen experts [B * S, k]). The choice is `routing` of that normed
-    input, or `ahead`, the same formed EARLIER from what the model's router
-    reads instead (`models/window_moe.py`: the attention's input); the
-    experts are of `form` (`moe_layer`), beside a shared SwiGLU every token
-    passes where the layer has one (`p["shared"]`)."""
+def expert_parts(h, p, config, mesh=None, ahead=None, form: str = "swiglu"):
+    """The routed block of h [B, S, D], a layer's normed input, before the
+    residual is added -> (the held experts' part [B, S, D], the shared
+    expert's [B, S, D] or None where the layer has none, the chosen experts
+    [B * S, k]): two parts, because `expert_sublayer` adds them to x one
+    after the other. The choice is `routing` of h, or `ahead`, the same
+    formed EARLIER from what the model's router reads instead
+    (`models/window_moe.py`: the attention's input); the experts are of
+    `form` (`moe_layer`), beside a shared SwiGLU every token passes where
+    the layer has one (`p["shared"]`)."""
     c = config
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         raise NotImplementedError(
             "these experts run in one program (all of them, or one chip's "
             "share without the exchange): no `ep` mesh axis")
-    b, s, d = x.shape
-    h = blocks.rms_norm(x, p["mlp_norm"], c.norm_eps)
+    b, s, d = h.shape
     rows = h.reshape(b * s, d)
     if ahead is None:
         ahead = routing(rows, p, c)
     routed, aux = moe.moe_layer(rows, None, p["experts"], c.experts_per_token,
                                 held=c.held, form=form, routing=ahead)
     if "shared" not in p:
-        return blocks.residual(x + routed.reshape(b, s, d), mesh, rules), \
-            aux.experts
+        return routed.reshape(b, s, d), None, aux.experts
     with jax.named_scope("moe.shared"):
         sh = p["shared"]
         shared = (jax.nn.silu(h @ sh["w_gate"]) * (h @ sh["w_up"])) \
             @ sh["w_down"]
-    x = x + routed.reshape(b, s, d) + shared
-    return blocks.residual(x, mesh, rules), aux.experts
+    return routed.reshape(b, s, d), shared, aux.experts
+
+
+def expert_sublayer(x, p, config, mesh=None,
+                    rules: Optional[LogicalAxisRules] = None, ahead=None,
+                    form: str = "swiglu"):
+    """x [B, S, D] -> (x + routed + shared experts of RMSNorm(x), the
+    chosen experts [B * S, k]) (`expert_parts`)."""
+    h = blocks.rms_norm(x, p["mlp_norm"], config.norm_eps)
+    routed, shared, chosen = expert_parts(h, p, config, mesh, ahead, form)
+    x = x + routed
+    if shared is not None:
+        x = x + shared
+    return blocks.residual(x, mesh, rules), chosen
 
 
 def live_rows(chosen, config):

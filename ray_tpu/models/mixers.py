@@ -107,9 +107,13 @@ def interleaved(w, config):
     return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
 
 
-def mla_sublayer(x, p, positions, config, mesh=None,
-                 rules: Optional[LogicalAxisRules] = None):
-    """x [B, S, D] -> x + MLA(RMSNorm(x)).
+def mla_mixer(h, p, positions, config, mesh=None, rotary=None, scale=None):
+    """MLA on h [B, S, D], a layer's normed input -> [B, S, D], before the
+    residual is added (`mla_sublayer` adds it; `models/streams.py` spreads
+    it over the streams). `rotary` (`blocks.Rotary`): the rotary parts'
+    form where it is not plain RoPE at `config.rope_theta` (YaRN's blended
+    frequencies); `scale`: the scores' where it is not (128 + 64) ** -0.5
+    (DeepSeek's `mscale` under YaRN).
 
     The flash call takes its operands in the parts the projections make:
     `wq_b` [r, H, 128 + 64] and `wkv_b` [r, H, 128 + 128] stay the published
@@ -123,7 +127,6 @@ def mla_sublayer(x, p, positions, config, mesh=None,
     and `blocks.checkpointed` saves its result beside them."""
     c = config
     n_nope, n_lat = c.qk_nope_head_dim, c.kv_lora_rank
-    h = rms_norm(x, p["attn_norm"], c.norm_eps)
     with jax.named_scope("mla.latents"):
         up = partial(jnp.einsum, "bsr,rhk->bshk")
         if c.q_lora_rank:
@@ -143,7 +146,7 @@ def mla_sublayer(x, p, positions, config, mesh=None,
             return rms_norm(x, scale, c.norm_eps)
 
         turned = lambda x, name: rope(  # noqa: E731
-            head_norm(x, name, True), positions, c.rope_theta)
+            head_norm(x, name, True), positions, c.rope_theta, rotary)
         q = head_norm(up(c_q, w_q[..., :n_nope]), "q_head_norm", False)
         q_rope = turned(up(c_q, interleaved(w_q[..., n_nope:], c)),
                         "q_head_norm")
@@ -159,13 +162,21 @@ def mla_sublayer(x, p, positions, config, mesh=None,
     with jax.named_scope("mla.attend"):
         # scores over n_nope + n_rope channels, scaled by their root
         attn = blocks.flash(q, k, v, mesh, causal=True, q_rope=q_rope,
-                            k_rope=k_rope)
+                            k_rope=k_rope, scale=scale)
     if c.attn_gate:
         with jax.named_scope("mla.gate"):
             attn = blocks.head_gated(attn, h, p["w_attn_gate"])
     device_profiler.count("mla.layers", 1)  # per lowering
     device_profiler.count("mla.attend_parts", 1)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
+    return jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
+
+
+def mla_sublayer(x, p, positions, config, mesh=None,
+                 rules: Optional[LogicalAxisRules] = None, rotary=None,
+                 scale=None):
+    """x [B, S, D] -> x + MLA(RMSNorm(x)) (`mla_mixer`)."""
+    h = rms_norm(x, p["attn_norm"], config.norm_eps)
+    x = x + mla_mixer(h, p, positions, config, mesh, rotary, scale)
     return blocks.residual(x, mesh, rules)
 
 

@@ -26,6 +26,16 @@ decoder of three kinds of block, all pre-norm:
   one expert layer of its own, its own final norm, the SHARED embedding and
   lm_head, predicting t_{i+2}; loss = CE + `mtp_loss_coef` * CE_mtp.
 
+With `hc_mult` n > 0 the residual path is n streams (`models/streams.py`,
+Xing4.0 by config): the embedding is copied into the n, each sublayer reads
+a learned mix of them and its output is spread over them by another, with a
+Sinkhorn-normalised n x n map on the streams themselves, two connections a
+layer; the final norm (and the MTP block's, after its own n streams) takes
+their sum. With `rope_scaling` (the published YaRN group) the 64 rotary
+channels turn at the blended frequencies and the scores are scaled by
+DeepSeek's `mscale`. 0 and None: the plain path above, the same program
+as before these fields were.
+
 The share: with `n_experts_held` < `n_experts` this program is one chip of
 an expert-parallel deployment run without its exchange: the router keeps
 all `n_experts` outputs, the layer computes the pairs whose expert is in
@@ -37,6 +47,7 @@ what the absent experts would add is left out (`moe_layer`'s `held`).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional
 
@@ -44,10 +55,10 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
-from ray_tpu.models import blocks, experts
+from ray_tpu.models import blocks, experts, streams
 from ray_tpu.models.blocks import checkpointed, residual, rms_norm
 from ray_tpu.models.mixers import (
-    init_mla, mla_axes, mla_num_params, mla_sublayer)
+    init_mla, mla_axes, mla_mixer, mla_num_params, mla_sublayer)
 from ray_tpu.parallel.sharding import LogicalAxisRules
 
 
@@ -77,6 +88,15 @@ class MlaMoeConfig(experts.Share):
     routed_scaling_factor: float = 2.5
     rope_theta: float = 32_000_000.0
     rope_interleave: bool = True
+    # the published YaRN group: `factor`, `original_max_position_embeddings`,
+    # `beta_fast`, `beta_slow`, `mscale`, `mscale_all_dim` (None: plain RoPE)
+    rope_scaling: Any = None
+    # the residual path: 0, one stream; n, `models/streams.py`'s n
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    h_res_clamp_min: float = -30.0   # on H_res's logits, before exp
+    h_res_clamp_max: float = 30.0
     # per-head RMSNorm of q and k before RoPE, a part at a time
     qk_head_norm: bool = False
     attn_gate: bool = False        # attn_h * sigmoid(w_h . x) before W_o
@@ -99,6 +119,36 @@ class MlaMoeConfig(experts.Share):
         if not 0 <= self.n_dense_layers <= self.n_layers:
             raise ValueError("n_dense_layers outside [0, n_layers]")
         self.held  # raises where the share is outside the router's outputs
+        if isinstance(self.rope_scaling, dict):  # hashable, as jit wants
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+
+    @property
+    def rotary(self) -> Optional[blocks.Rotary]:
+        """The rotary parts' form under `rope_scaling`, as HF
+        `modeling_deepseek_v3` reads the group: YaRN's blended frequencies,
+        cos and sin times mscale(`mscale`) / mscale(`mscale_all_dim`)."""
+        if self.rope_scaling is None:
+            return None
+        g = dict(self.rope_scaling)
+        if g.get("type", g.get("rope_type")) != "yarn":
+            raise NotImplementedError(f"rope_scaling {g}")
+        return blocks.Rotary(
+            float(self.rope_theta), None,
+            (g["factor"], g["original_max_position_embeddings"],
+             g.get("beta_fast", 32), g.get("beta_slow", 1)),
+            _mscale(g["factor"], g.get("mscale", 1))
+            / _mscale(g["factor"], g.get("mscale_all_dim", 0)))
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """The scores' scale under `rope_scaling`: (128 + 64) ** -0.5 times
+        mscale(`mscale_all_dim`) ** 2; None: the kernels' own."""
+        if self.rope_scaling is None:
+            return None
+        g = dict(self.rope_scaling)
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 \
+            * _mscale(g["factor"], g.get("mscale_all_dim", 0)) ** 2
 
     @staticmethod
     def tiny(vocab_size: int = 512, **over) -> "MlaMoeConfig":
@@ -111,7 +161,9 @@ class MlaMoeConfig(experts.Share):
 
     def num_params(self) -> int:
         c = self
-        mla = mla_num_params(c) + 2 * c.d_model
+        mla = mla_num_params(c) + 2 * c.d_model + (
+            2 * streams.connection_num_params(c.hc_mult, c.d_model)
+            if c.hc_mult else 0)
         dense = mla + 3 * c.d_model * c.d_ff
         expert = (mla + c.d_model * c.n_experts + c.n_experts
                   + 3 * c.d_model * c.d_ff_expert
@@ -123,19 +175,42 @@ class MlaMoeConfig(experts.Share):
                 + (c.n_layers - c.n_dense_layers) * expert + mtp)
 
 
+def _mscale(factor: float, mscale: float) -> float:
+    """DeepSeek's `yarn_get_mscale`."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 # --------------------------------------------------------------------------
 # parameters
 # --------------------------------------------------------------------------
 
+def _connection_axes(L, config):
+    """The two connections of a layer, where the path has streams."""
+    if not config.hc_mult:
+        return {}
+    return {"hc_attn": streams.connection_axes(L),
+            "hc_mlp": streams.connection_axes(L)}
+
+
+def _init_connections(config, key):
+    if not config.hc_mult:
+        return {}
+    k_attn, k_mlp = jax.random.split(jax.random.fold_in(key, 61))
+    return {"hc_attn": streams.init_connection(config, k_attn),
+            "hc_mlp": streams.init_connection(config, k_mlp)}
+
+
 def _expert_layer_axes(L, config):
-    return {**mla_axes(L, config), **experts.routed_axes(L)}
+    return {**mla_axes(L, config), **experts.routed_axes(L),
+            **_connection_axes(L, config)}
 
 
 def param_logical_axes(config: MlaMoeConfig) -> Dict[str, Any]:
     L = ("layers",)
     axes = {
         "embed": ("vocab", "embed"),
-        "dense": {**mla_axes(L, config), **blocks.ffn_axes(L)},
+        "dense": {**mla_axes(L, config), **blocks.ffn_axes(L),
+                  **_connection_axes(L, config)},
         "layers": _expert_layer_axes(L, config),
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
@@ -151,7 +226,8 @@ def param_logical_axes(config: MlaMoeConfig) -> Dict[str, Any]:
 def init(config: MlaMoeConfig, key) -> Dict[str, Any]:
     """Fan-in scaled normal weights in `config.dtype`, norm scales 1, the
     router 0.02 normal, the router's bias float32 N(0, 0.01^2): not zero, so
-    that it changes choices wherever two scores lie that close.
+    that it changes choices wherever two scores lie that close; a
+    connection's maps as `streams.init_connection` seeds them.
 
     The embedding's rows are N(0, 1), of unit RMS like every sublayer's
     normed input, and not fan-in scaled (a lookup sums over nothing). With
@@ -168,12 +244,14 @@ def init(config: MlaMoeConfig, key) -> Dict[str, Any]:
 
     def dense_layer(key):
         k_attn, *ks = jax.random.split(key, 4)
-        return {**init_mla(c, k_attn), **blocks.init_ffn(c, ks, (), c.d_ff)}
+        return {**init_mla(c, k_attn), **blocks.init_ffn(c, ks, (), c.d_ff),
+                **_init_connections(c, key)}
 
     def expert_layer(key):
         k_attn, k_r, k_b, *ks = jax.random.split(key, 9)
         return {**init_mla(c, k_attn),
-                **experts.init_routed(c, k_r, k_b, ks)}
+                **experts.init_routed(c, k_r, k_b, ks),
+                **_init_connections(c, key)}
 
     k_embed, k_dense, k_layers, k_head, k_mtp = jax.random.split(key, 5)
     params = {
@@ -201,14 +279,57 @@ def init(config: MlaMoeConfig, key) -> Dict[str, Any]:
 # layers
 # --------------------------------------------------------------------------
 
+def _mla(x, p, positions, config, mesh, rules):
+    """The layer's first sublayer: on one stream `mla_sublayer`; on n
+    streams a connection around MLA(RMSNorm(h))."""
+    c = config
+    if not c.hc_mult:
+        return mla_sublayer(x, p, positions, c, mesh, rules, c.rotary,
+                            c.attn_scale)
+
+    def branch(h):
+        return mla_mixer(rms_norm(h, p["attn_norm"], c.norm_eps), p,
+                         positions, c, mesh, c.rotary, c.attn_scale), None
+
+    return streams.connect(x, p["hc_attn"], branch, c, mesh, rules)[0]
+
+
 def expert_layer(x, p, positions, config, mesh, rules):
-    x = mla_sublayer(x, p, positions, config, mesh, rules)
-    return experts.expert_sublayer(x, p, config, mesh, rules)
+    c = config
+    x = _mla(x, p, positions, c, mesh, rules)
+    if not c.hc_mult:
+        return experts.expert_sublayer(x, p, c, mesh, rules)
+
+    def branch(h):
+        routed, shared, chosen = experts.expert_parts(
+            rms_norm(h, p["mlp_norm"], c.norm_eps), p, c, mesh)
+        return routed + shared, chosen
+
+    return streams.connect(x, p["hc_mlp"], branch, c, mesh, rules)
 
 
 def dense_layer(x, p, positions, config, mesh, rules):
-    x = mla_sublayer(x, p, positions, config, mesh, rules)
-    return blocks.mlp_sublayer(x, p, config, mesh, rules)
+    c = config
+    x = _mla(x, p, positions, c, mesh, rules)
+    if not c.hc_mult:
+        return blocks.mlp_sublayer(x, p, c, mesh, rules)
+
+    def branch(h):
+        return blocks.gated_mlp(rms_norm(h, p["mlp_norm"], c.norm_eps), p,
+                                c, mesh, rules), None
+
+    return streams.connect(x, p["hc_mlp"], branch, c, mesh, rules)[0]
+
+
+def _enter(x, config, mesh, rules):
+    """The residual path's start: x [B, S, D] as the layers carry it."""
+    if config.hc_mult:
+        return streams.expand(x, config.hc_mult, mesh, rules)
+    return residual(x, mesh, rules)
+
+
+def _leave(x, config):
+    return streams.reduce(x) if config.hc_mult else x
 
 
 def forward_hidden(params, tokens, config: MlaMoeConfig, mesh=None,
@@ -217,13 +338,13 @@ def forward_hidden(params, tokens, config: MlaMoeConfig, mesh=None,
     experts of every expert layer [L, B * S, k])."""
     c = config
     x, positions = blocks.embed_tokens(params, tokens, mesh, rules)
-    x = residual(x.astype(c.dtype), mesh, rules)
+    x = _enter(x.astype(c.dtype), c, mesh, rules)
     kw = dict(positions=positions, config=c, mesh=mesh, rules=rules)
     dense = checkpointed(partial(dense_layer, **kw), c)
     x, _ = jax.lax.scan(lambda x, p: (dense(x, p), None), x, params["dense"])
     x, chosen = jax.lax.scan(checkpointed(partial(expert_layer, **kw), c),
                              x, params["layers"])
-    return rms_norm(x, params["final_norm"], c.norm_eps), chosen
+    return rms_norm(_leave(x, c), params["final_norm"], c.norm_eps), chosen
 
 
 def mtp_hidden(params, hidden, next_tokens, config: MlaMoeConfig, mesh=None,
@@ -245,10 +366,10 @@ def mtp_hidden(params, hidden, next_tokens, config: MlaMoeConfig, mesh=None,
         block = checkpointed(partial(
             expert_layer, positions=positions, config=c, mesh=mesh,
             rules=rules), c)
-        x, chosen = block(residual(x, mesh, rules),
+        x, chosen = block(_enter(x, c, mesh, rules),
                           jax.tree.map(lambda a: a[0], p["block"]))
         device_profiler.count("mtp.depth", 1)  # per lowering
-        return rms_norm(x, p["final_norm"], c.norm_eps), chosen
+        return rms_norm(_leave(x, c), p["final_norm"], c.norm_eps), chosen
 
 
 def forward(params, tokens, config: MlaMoeConfig, mesh=None,

@@ -681,3 +681,60 @@ def test_the_embeddings_gradient_rows_are_counted_by_the_form_that_sums_them(
     assert read({names[0]: 48, names[1]: 48, "ce.chunks": 4}) == 100
     assert read({names[0]: 48, names[1]: 0}) == 0
     assert read({"ce.chunks": 4}) is None
+
+
+def test_the_residual_paths_connections_are_counted_and_scoped():
+    """`models/streams.py` under `models/mla_moe.py` (`hc_mult` 4): a
+    lowering leaves `hc.connections` (2 a layer body traced: the scanned
+    expert layers lower ONE body), `hc.sinkhorn_iters` (20 a connection) and
+    `hc.rows_mixed` (tokens x 4 a connection) in the aggregate, and the
+    scopes `hc.expand`, `hc.maps`, `hc.pre`, `hc.post`, `hc.reduce` in the
+    jaxpr, the maps' before the pre-mix's before the sublayer's before the
+    post-mix's; a model on one stream leaves none of them. The names are
+    what PERF.md section 3 and `benchmarks/metrics/hc_time_share.json` go
+    by."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import mla_moe
+
+    names = ("hc.connections", "hc.sinkhorn_iters", "hc.rows_mixed")
+
+    def lowered(cfg):
+        params = jax.eval_shape(lambda: mla_moe.init(cfg, jax.random.PRNGKey(0)))
+        before = dp.snapshot()["counters"]
+        traced = jax.make_jaxpr(
+            lambda p, t: mla_moe.forward_hidden(p, t, cfg)[0])(
+                params, jax.ShapeDtypeStruct((2, 32), jnp.int32))
+        after = dp.snapshot()["counters"]
+        return traced, {n: after.get(n, 0) - before.get(n, 0) for n in names}
+
+    def scopes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scopes(sub)
+
+    tiny = dict(vocab_size=64, remat=False, n_experts_held=4, mtp_depth=0)
+    traced, grew = lowered(mla_moe.MlaMoeConfig.tiny(
+        hc_mult=4, n_layers=4, **tiny))
+    # a dense layer's body and the three expert layers' one
+    assert grew == {"hc.connections": 2 * 2, "hc.sinkhorn_iters": 20 * 4,
+                    "hc.rows_mixed": 4 * 4 * 64}
+    entered = list(scopes(traced.jaxpr))
+    first = lambda name: next(  # noqa: E731
+        i for i, s in enumerate(entered) if name in s)
+    assert first("hc.expand") < first("hc.maps") < first("hc.pre") \
+        < first("mla.attend") < first("hc.post") < first("hc.reduce")
+    traced, grew = lowered(mla_moe.MlaMoeConfig.tiny(n_layers=4, **tiny))
+    assert grew == dict.fromkeys(names, 0)
+    assert not [s for s in scopes(traced.jaxpr) if "hc." in s]
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "metrics",
+                           "hc_time_share.json")) as f:
+        spec = json.load(f)
+    # by shape, and by the scope's name where a kernel carries it
+    assert spec["reader"] == "moe_readers.op_time_share"
+    assert "hc\\." in spec["trace_query"]["op"]

@@ -1,8 +1,8 @@
 """The shape of `ray_tpu/models/`: model modules depend DOWN on the layer
-library (`blocks`, `experts`, `mixers`, `layer_pattern`) and never sideways
-on each other's private names; `layer_pattern.walk` runs a plan as a plain
-loop over the held layers would; `experts.live_rows` counts what a share
-holds. Tier-1 (tests/test_models.py is the slow tier, whole). The file's
+library (`blocks`, `experts`, `mixers`, `streams`, `layer_pattern`) and never
+sideways on each other's private names; `layer_pattern.walk` runs a plan as
+a plain loop over the held layers would; `experts.live_rows` counts what a
+share holds. Tier-1 (tests/test_models.py is the slow tier, whole). The file's
 name sorts it LAST: `--dist loadfile` hands files to workers in order, and
 a file added in the middle moves what runs beside the one load-sensitive
 host-plane test at the end (`test_workflow_dag.py`'s handshake of two
@@ -35,7 +35,7 @@ MODULES = sorted(f[:-3] for f in os.listdir(MODELS_DIR)
 # (`MixtralConfig(LlamaConfig)`; `SdarConfig(MixtralConfig)` and
 # `mixtral.hidden_states`)
 LIBRARY = {"blocks": set(), "layer_pattern": set(), "experts": {"blocks"},
-           "mixers": {"blocks"}}
+           "mixers": {"blocks"}, "streams": {"blocks"}}
 MODEL_EDGES = {"mixtral": {"llama"}, "sdar": {"mixtral"}}
 
 
