@@ -26,15 +26,30 @@ the chip's tiles, and with [.., B, S] kept apart through Sinkhorn's steps
 the v5e compiler refuses Xing4.0's step at the depth it places this way
 ("Used 15.95G of 15.75G hbm"; PERF.md section 6, PR 61). vec(X) . phi is X's
 own bf16 values against phi's with float32 sums, times 1 / r afterwards (a
-scalar a token), so no normed copy of X is made. Plain `jnp`: the pre-mix,
-the post-mix and their transposes are passes over X that the compiler fuses
-as it can; one kernel for a connection is a later PR's
-(`benchmarks/opcount_xing.hc_bytes` is its roofline).
+scalar a token), so no normed copy of X is made.
+
+TWO FORMS of a connection, told apart by what the code can observe and by
+nothing else (`ops/stream_mix.fused`: a TPU, bf16 streams, D a multiple of
+128, the tokens a multiple of the token tile, blocks that fit a call's
+default VMEM, one device). Where it holds, a connection is FOUR PALLAS CALLS,
+two forward and two backward (`ops/stream_mix.py`, PR 62): `hc.pre` reads X
+once and writes h and the maps, `hc.post` reads X, y and the maps and writes
+X'; on the way back `hc.post`'s call writes dy and the maps' cotangents,
+`hc.pre`'s recomputes Sinkhorn's iterates in VMEM and writes dX once. The
+maps `maps` returns are that first call's outputs, not a second computation.
+Elsewhere, and as the tests' oracle, plain `jnp` as below: the pre-mix, the
+post-mix and their transposes are passes over X that the compiler fuses as it
+can. `benchmarks/opcount_xing.hc_bytes` is the path's roofline; the four
+calls make 37 passes of [tokens, D] where it counts 20 (the sublayer sits
+between the pre-mix and the post-mix and its cotangent arrives after the
+post-mix's backward, so X is read four times).
 
 A config gives `hc_sinkhorn_iters`, `hc_eps`, `h_res_clamp_min` / `_max` (lo,
 hi) and `norm_eps`; n is X's leading dim. Scopes `hc.expand`, `hc.maps`,
-`hc.pre`, `hc.post`, `hc.reduce`; counters per LOWERING `hc.connections`,
-`hc.sinkhorn_iters`, `hc.rows_mixed` (tokens x n a connection).
+`hc.pre`, `hc.post`, `hc.reduce` (a Pallas call's events are named by its
+scope: `%hc.pre...`); counters per LOWERING `hc.connections`,
+`hc.sinkhorn_iters`, `hc.rows_mixed` (tokens x n a connection) and
+`hc.rows_fused` (the same, of the connections that took the Pallas calls).
 """
 
 from __future__ import annotations
@@ -47,7 +62,9 @@ import jax.numpy as jnp
 
 from ray_tpu._private import device_profiler
 from ray_tpu.models import blocks
+from ray_tpu.ops import stream_mix
 from ray_tpu.parallel.sharding import LogicalAxisRules
+
 
 def n_maps(n: int) -> int:
     """phi's outputs: H_pre's n, H_post's n, H_res's n x n."""
@@ -103,10 +120,8 @@ def sinkhorn(M, iters: int, eps: float):
     return M
 
 
-def maps(X, p, config):
-    """X [n, B, S, D] -> (H_pre [n, B, S], H_post [n, B, S], H_res
-    [n, n, B, S]) of connection `p`, float32; inside, the tokens are one
-    dim."""
+def _maps(X, p, config):
+    """`maps` in plain `jnp`."""
     c = config
     n, b, s, _ = X.shape
     with jax.named_scope("hc.maps"):
@@ -123,9 +138,59 @@ def maps(X, p, config):
                                c.h_res_clamp_min, c.h_res_clamp_max))
         res = sinkhorn(res.reshape(n, n, b * s), c.hc_sinkhorn_iters,
                        c.hc_eps)
-        device_profiler.count("hc.sinkhorn_iters", c.hc_sinkhorn_iters)
         return (pre.reshape(n, b, s), post.reshape(n, b, s),
                 res.reshape(n, n, b, s))
+
+
+def pre_mix(X, p, config, mesh=None):
+    """The first half of connection `p`: X [n, B, S, D] -> (h [B, S, D],
+    what `post_mix` and `maps` take: X and the maps, as rows of
+    `ops/stream_mix.py` where its calls ran, else as `maps` returns them)."""
+    n, b, s, d = X.shape
+    device_profiler.count("hc.sinkhorn_iters", config.hc_sinkhorn_iters)
+    if stream_mix.fused(X, mesh):
+        with jax.named_scope("hc.pre"):
+            h, rows, X = stream_mix.pre_mix(
+                X.reshape(n, b * s, d), stream_mix.phi_rows(p["phi"]),
+                stream_mix.coef_rows(p["alpha"], p["b"], n),
+                stream_mix.spec_of(config, n), stream_mix.INTERPRET)
+        return h.reshape(b, s, d), (X.reshape(n, b, s, d), rows)
+    mixed = _maps(X, p, config)
+    with jax.named_scope("hc.pre"):
+        h = sum(mixed[0][i][..., None] * X[i].astype(jnp.float32)
+                for i in range(n)).astype(X.dtype)
+    return h, (X, mixed)
+
+
+def post_mix(mixed, y):
+    """`pre_mix`'s second result and y = F(h) [B, S, D] -> X' [n, B, S, D]."""
+    X, mapped = mixed
+    n = X.shape[0]
+    with jax.named_scope("hc.post"):
+        if not isinstance(mapped, tuple):
+            return stream_mix.post_mix(
+                X.reshape(n, -1, X.shape[-1]), y.reshape(-1, y.shape[-1]),
+                mapped, stream_mix.INTERPRET).reshape(X.shape)
+        f32 = jnp.float32
+        _, post, res = (m[..., None] for m in mapped)
+        # column j of H_res [n, B, S, 1] against stream j [B, S, D]: all n
+        # rows of X' leave ONE elementwise pass (a stack of n rows formed
+        # apart costs a pass more to put them together)
+        return (sum(res[:, j] * X[j].astype(f32) for j in range(n))
+                + post * y.astype(f32)).astype(X.dtype)
+
+
+def maps(X, p, config, mesh=None):
+    """X [n, B, S, D] -> (H_pre [n, B, S], H_post [n, B, S], H_res
+    [n, n, B, S]) of connection `p`, float32; inside, the tokens are one
+    dim. The maps `connect` mixes with: `pre_mix`'s."""
+    n, b, s, _ = X.shape
+    mapped = pre_mix(X, p, config, mesh)[1][1]
+    if isinstance(mapped, tuple):
+        return mapped
+    flat = stream_mix.maps_of(mapped, n)
+    return (flat[:n].reshape(n, b, s), flat[n:2 * n].reshape(n, b, s),
+            flat[2 * n:].reshape(n, n, b, s))
 
 
 def connect(X, p, branch, config, mesh=None,
@@ -139,18 +204,12 @@ def connect(X, p, branch, config, mesh=None,
         raise NotImplementedError(
             "the streams are mixed a token at a time on whole rows: no "
             "`tp` or `sp` mesh axis yet")
-    n = X.shape[0]
-    f32 = jnp.float32
-    pre, post, res = (m[..., None] for m in maps(X, p, config))
-    with jax.named_scope("hc.pre"):
-        h = sum(pre[i] * X[i].astype(f32) for i in range(n)).astype(X.dtype)
+    rows = X.shape[0] * math.prod(X.shape[1:3])
+    h, mixed = pre_mix(X, p, config, mesh)
     y, aux = branch(h)
-    with jax.named_scope("hc.post"):
-        # column j of H_res [n, B, S, 1] against stream j [B, S, D]: all n
-        # rows of X' leave ONE elementwise pass (a stack of n rows formed
-        # apart costs a pass more to put them together)
-        X = (sum(res[:, j] * X[j].astype(f32) for j in range(n))
-             + post * y.astype(f32)).astype(X.dtype)
+    X = post_mix(mixed, y)
     device_profiler.count("hc.connections", 1)  # per lowering
-    device_profiler.count("hc.rows_mixed", n * math.prod(X.shape[1:3]))
+    device_profiler.count("hc.rows_mixed", rows)
+    device_profiler.count("hc.rows_fused",
+                          0 if isinstance(mixed[1], tuple) else rows)
     return blocks.residual(X, mesh, rules), aux

@@ -686,8 +686,11 @@ def test_the_embeddings_gradient_rows_are_counted_by_the_form_that_sums_them(
 def test_the_residual_paths_connections_are_counted_and_scoped():
     """`models/streams.py` under `models/mla_moe.py` (`hc_mult` 4): a
     lowering leaves `hc.connections` (2 a layer body traced: the scanned
-    expert layers lower ONE body), `hc.sinkhorn_iters` (20 a connection) and
-    `hc.rows_mixed` (tokens x 4 a connection) in the aggregate, and the
+    expert layers lower ONE body), `hc.sinkhorn_iters` (20 a connection),
+    `hc.rows_mixed` (tokens x 4 a connection) and `hc.rows_fused` (the rows
+    of the connections that took `ops/stream_mix.py`'s Pallas calls: none
+    on the CPU, all of them under the tests' interpreter seam, where the
+    scopes `hc.pre` and `hc.post` name the calls) in the aggregate, and the
     scopes `hc.expand`, `hc.maps`, `hc.pre`, `hc.post`, `hc.reduce` in the
     jaxpr, the maps' before the pre-mix's before the sublayer's before the
     post-mix's; a model on one stream leaves none of them. The names are
@@ -700,7 +703,8 @@ def test_the_residual_paths_connections_are_counted_and_scoped():
 
     from ray_tpu.models import mla_moe
 
-    names = ("hc.connections", "hc.sinkhorn_iters", "hc.rows_mixed")
+    names = ("hc.connections", "hc.sinkhorn_iters", "hc.rows_mixed",
+             "hc.rows_fused")
 
     def lowered(cfg):
         params = jax.eval_shape(lambda: mla_moe.init(cfg, jax.random.PRNGKey(0)))
@@ -722,7 +726,7 @@ def test_the_residual_paths_connections_are_counted_and_scoped():
         hc_mult=4, n_layers=4, **tiny))
     # a dense layer's body and the three expert layers' one
     assert grew == {"hc.connections": 2 * 2, "hc.sinkhorn_iters": 20 * 4,
-                    "hc.rows_mixed": 4 * 4 * 64}
+                    "hc.rows_mixed": 4 * 4 * 64, "hc.rows_fused": 0}
     entered = list(scopes(traced.jaxpr))
     first = lambda name: next(  # noqa: E731
         i for i, s in enumerate(entered) if name in s)
@@ -738,3 +742,27 @@ def test_the_residual_paths_connections_are_counted_and_scoped():
     # by shape, and by the scope's name where a kernel carries it
     assert spec["reader"] == "moe_readers.op_time_share"
     assert "hc\\." in spec["trace_query"]["op"]
+    # bf16 streams of 128 tokens and 128 channels under the interpreter
+    # seam: every connection takes the Pallas calls, under the same scopes
+    from ray_tpu.ops import stream_mix
+    fused = mla_moe.MlaMoeConfig.tiny(
+        hc_mult=4, n_layers=4, d_model=128, dtype=jnp.bfloat16,
+        **{**tiny, "vocab_size": 128})
+    stream_mix.INTERPRET = True
+    try:
+        params = jax.eval_shape(
+            lambda: mla_moe.init(fused, jax.random.PRNGKey(0)))
+        before = dp.snapshot()["counters"]
+        traced = jax.make_jaxpr(
+            lambda p, t: mla_moe.forward_hidden(p, t, fused)[0])(
+                params, jax.ShapeDtypeStruct((2, 64), jnp.int32))
+        after = dp.snapshot()["counters"]
+    finally:
+        stream_mix.INTERPRET = False
+    grew = {n: after.get(n, 0) - before.get(n, 0) for n in names}
+    assert grew == {"hc.connections": 2 * 2, "hc.sinkhorn_iters": 20 * 4,
+                    "hc.rows_mixed": 4 * 4 * 128, "hc.rows_fused": 4 * 4 * 128}
+    entered = list(scopes(traced.jaxpr))
+    assert first("hc.expand") < first("hc.pre") < first("mla.attend") \
+        < first("hc.post") < first("hc.reduce")
+    assert not [s for s in entered if "hc.maps" in s]

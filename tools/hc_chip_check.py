@@ -19,11 +19,20 @@ comparison that the cell's `correct` holds (`benchmarks/train_hc_cell.py`:
 - `ms`: a call's busy time on the device's clock (the union of the `XLA Ops`
   events of a short trace) around a branch that costs nothing beside it
   (y = h * g, g [3584]), forward alone and forward + backward, beside
-  `bound_ms`, `opcount_xing.hc_bytes` over the chip's 819 GB/s;
+  `bound_ms`, `opcount_xing.hc_bytes` over the chip's 819 GB/s; BOTH FORMS
+  side by side: `forms.kernels` (the four Pallas calls of
+  `ops/stream_mix.py`, what the program takes on the chip; `calls_ms` has
+  each call's own events) and `forms.jnp` (`stream_mix.fused` answered
+  False: the plain `jnp` path), each with `passes`: its milliseconds as
+  passes over one [tokens, D] bf16 array at the HBM's peak, where
+  `hc_bytes` counts 4 n + 4 = 20 and the four calls read and write 14
+  forward and 37 forward + backward;
 - the controls, each through the same comparison: `one_sinkhorn_iteration`
   (1 for 20), `static_maps` (alpha = 0: the biases alone) and `bf16_maps`
-  (`streams.maps` replaced by `maps_in_bf16` below). Each has to MISS a
-  limit; exit 1 if one passes, or if the program itself misses.
+  (the maps of `maps_in_bf16` below), each put where `streams.connect` AND
+  `streams.maps` get their maps, `streams.pre_mix` (the first two run
+  through the Pallas calls). Each has to MISS a limit; exit 1 if one
+  passes, or if the program itself misses.
 
 `--cell`: what the cell's own worker compares before it trains
 (`train_hc_cell.path_errors`: the first expert layer's `hc_mlp` of the
@@ -51,6 +60,15 @@ Exit 1 if the program misses the loss's or the logits' limit, or if
 the size of the bf16 streams' own rounding there too: the connection's maps
 hold that one).
 
+`--step LAYERS`: and nothing else: the cell's model cut to LAYERS layers (2:
+one dense, one expert layer and the MTP block; 9: the cell's depth), four
+AdamW steps of the jitted train step on a seeded batch under a watchdog: a
+step that has not come back `--watchdog` seconds (300) after the process
+started ends the process with its stack printed (the v5e HUNG in a step
+that held a Pallas call stating 37 MiB or more of VMEM beside a share's
+routed block: PERF.md section 6, PR 62); prints each step's loss and
+milliseconds. Run it after any change to what the path's calls state.
+
 Writes chiprun_out/hc_chip_check.json.
 """
 import argparse
@@ -75,6 +93,7 @@ from benchmarks import reference_xing as ref  # noqa: E402
 from benchmarks import train_hc_cell  # noqa: E402
 from benchmarks.train_cell import LOSS_TOLERANCE  # noqa: E402
 from ray_tpu.models import mla_moe, streams  # noqa: E402
+from ray_tpu.ops import stream_mix  # noqa: E402
 
 CONFIG = os.path.join(ROOT, "benchmarks", "configs",
                       "xing4.0-29b-a4b-train-1chip.json")
@@ -94,8 +113,8 @@ def cell_config():
 
 
 def maps_in_bf16(X, p, config):
-    """`streams.maps` with everything after the float32 sums over the
-    channels in bfloat16, the nearest precision below the one it states."""
+    """The maps with everything after the float32 sums over the channels
+    in bfloat16, the nearest precision below the one `streams` states."""
     c, bf16 = config, jnp.bfloat16
     n = X.shape[0]
     square = X.astype(jnp.float32)
@@ -113,22 +132,43 @@ def maps_in_bf16(X, p, config):
         jnp.asarray(c.hc_eps, bf16))
 
 
+def pre_mix_in_bf16(X, p, config, mesh=None):
+    """`streams.pre_mix` on `maps_in_bf16`, in the `jnp` path's form."""
+    mapped = maps_in_bf16(X, p, config)
+    h = sum(mapped[0][i][..., None] * X[i].astype(jnp.float32)
+            for i in range(X.shape[0])).astype(X.dtype)
+    return h, (X, mapped)
+
+
 @contextlib.contextmanager
 def controlled(name):
-    """Control `name` in the place of the PROGRAM's `streams.maps`; None: the
+    """Control `name` in the place of the PROGRAM's `streams.pre_mix`, where
+    `streams.connect` and `streams.maps` both get their maps; None: the
     program as it is."""
-    maps = streams.maps
-    streams.maps = {
-        None: maps,
-        "one_sinkhorn_iteration": lambda X, p, c: maps(
-            X, p, dataclasses.replace(c, hc_sinkhorn_iters=1)),
-        "static_maps": lambda X, p, c: maps(
-            X, dict(p, alpha=0 * p["alpha"]), c),
-        "bf16_maps": maps_in_bf16}[name]
+    pre_mix = streams.pre_mix
+    streams.pre_mix = {
+        None: pre_mix,
+        "one_sinkhorn_iteration": lambda X, p, c, *where: pre_mix(
+            X, p, dataclasses.replace(c, hc_sinkhorn_iters=1), *where),
+        "static_maps": lambda X, p, c, *where: pre_mix(
+            X, dict(p, alpha=0 * p["alpha"]), c, *where),
+        "bf16_maps": pre_mix_in_bf16}[name]
     try:
         yield
     finally:
-        streams.maps = maps
+        streams.pre_mix = pre_mix
+
+
+@contextlib.contextmanager
+def jnp_path():
+    """`stream_mix.fused` answered False: the plain `jnp` path on the
+    chip."""
+    fused = stream_mix.fused
+    stream_mix.fused = lambda X, mesh=None: False
+    try:
+        yield
+    finally:
+        stream_mix.fused = fused
 
 
 def frob(got, want):
@@ -146,9 +186,15 @@ def median_row(got, want):
                            / np.linalg.norm(want, axis=-1)))
 
 
+# a Pallas call of the path, by the scope in its event's name
+CALLS = {"hc_calls": {
+    "op": r"^%[\w.\-]*hc\.[\w.\-]* = .* custom-call\(.*tpu_custom_call"}}
+
+
 def busy_ms(fn, args, calls=4):
-    """A call's busy device milliseconds: the union of the `XLA Ops` events
-    of a trace over `calls` calls."""
+    """-> (a call's busy device milliseconds: the union of the `XLA Ops`
+    events of a trace over `calls` calls; {the path's Pallas calls by their
+    output: ms a call})."""
     jax.block_until_ready(fn(*args))
     with tempfile.TemporaryDirectory() as where:
         jax.profiler.start_trace(where)
@@ -158,7 +204,12 @@ def busy_ms(fn, args, calls=4):
         jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(
             where, "plugins", "profile", "*", "*.xplane.pb"))
-        return 1e3 * reduce_trace.reduce_xplane(path)["busy_s"] / calls
+        reduced = reduce_trace.reduce_xplane(path, CALLS)
+    top = reduced["device_ops"][:8]
+    return 1e3 * reduced["busy_s"] / calls, {
+        "hc_calls_ms": 1e3 * (reduced["queries"]["hc_calls"]
+                              or {"total_s": 0.0})["total_s"] / calls,
+        "top_ops_ms": {k: 1e3 * v / calls for k, v in top}}
 
 
 def connection_check(seed, b=4, s=2048):
@@ -186,13 +237,30 @@ def connection_check(seed, b=4, s=2048):
               "limits": {"hc_maps_err": train_hc_cell.MAPS_LIMIT,
                          "hc_value_err": train_hc_cell.VALUE_LIMIT,
                          "hc_grad_err": train_hc_cell.VALUE_LIMIT}}
-    result["fwd_ms"] = busy_ms(jax.jit(program), (X, p, g))
-    result["fwd_bwd_ms"] = busy_ms(jax.jit(
-        lambda X, p, g: jax.vjp(program, X, p, g)[1](cot)), (X, p, g))
     nbytes = opcount_xing.hc_bytes(fields, b * s)
-    result["bound_ms"] = 1e3 * nbytes / peaks.peaks(
-        jax.devices()[0].device_kind)["hbm_bytes_per_s"]
-    result["hc_bytes"] = nbytes
+    peak = peaks.peaks(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+    pass_ms = 1e3 * X[0].nbytes / peak
+
+    def timed():
+        # fresh functions: a jitted one would keep the other form's trace
+        fwd, fwd_ops = busy_ms(jax.jit(lambda X, p, g: program(X, p, g)),
+                               (X, p, g))
+        both, both_ops = busy_ms(jax.jit(
+            lambda X, p, g: jax.vjp(program, X, p, g)[1](cot)), (X, p, g))
+        return {"fwd_ms": fwd, "fwd_bwd_ms": both,
+                "passes": {"fwd": fwd / pass_ms, "fwd_bwd": both / pass_ms},
+                "fwd_ops": fwd_ops, "fwd_bwd_ops": both_ops}
+
+    forms = {"kernels" if stream_mix.fused(X) else "jnp": timed()}
+    if "kernels" in forms:
+        with jnp_path():
+            forms["jnp"] = timed()
+    first = next(iter(forms.values()))
+    result.update(
+        fwd_ms=first["fwd_ms"], fwd_bwd_ms=first["fwd_bwd_ms"], forms=forms,
+        bound_ms=1e3 * nbytes / peak, hc_bytes=nbytes, pass_ms=pass_ms,
+        passes={"hc_bytes": nbytes / X[0].nbytes, "kernels_fwd": 3 * n + 2,
+                "kernels_fwd_bwd": 8 * n + 5})
     return result
 
 
@@ -247,6 +315,42 @@ def model_check(seed):
     return out
 
 
+def step_check(layers, watchdog):
+    """Four train steps of the cell's model at `layers` layers, the path's
+    Pallas calls in it; the watchdog ends a hung process."""
+    import faulthandler
+    import time
+
+    import optax
+
+    faulthandler.dump_traceback_later(watchdog, exit=True)
+    _, fields, _ = cell_config()
+    cfg = mla_moe.MlaMoeConfig(**dict(fields, n_layers=layers))
+    params = jax.jit(partial(mla_moe.init, cfg))(jax.random.PRNGKey(0))
+    opt = optax.adamw(1e-4, weight_decay=0.0)
+    state = {"params": params, "opt_state": jax.jit(opt.init)(params)}
+
+    def step(state, batch):
+        loss, grads = jax.value_and_grad(partial(
+            mla_moe.loss_fn, config=cfg))(state["params"], batch)
+        updates, new = opt.update(grads, state["opt_state"], state["params"])
+        return {"params": optax.apply_updates(state["params"], updates),
+                "opt_state": new}, loss
+
+    toks = jax.random.randint(jax.random.PRNGKey(1), (4, cfg.max_seq_len + 1),
+                              0, cfg.vocab_size)
+    batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(state, batch).compile()
+    print("compiled", flush=True)
+    for i in range(4):
+        start = time.time()
+        state, loss = compiled(state, batch)
+        print(f"step {i} loss {float(loss):.4f} "
+              f"{1e3 * (time.time() - start):.1f} ms", flush=True)
+    faulthandler.cancel_dump_traceback_later()
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=2610000001)
@@ -254,12 +358,16 @@ def main() -> int:
     ap.add_argument("--model", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=2048)
+    ap.add_argument("--step", type=int, default=0, metavar="LAYERS")
+    ap.add_argument("--watchdog", type=int, default=300)
     args = ap.parse_args()
     device = jax.devices()[0]
     if device.platform != "tpu":
         print(f"needs the chip: the backend is {device.platform}",
               file=sys.stderr)
         return 3
+    if args.step:
+        return step_check(args.step, args.watchdog)
     result = {"seed": args.seed, "device": device.device_kind,
               "connection": connection_check(args.seed, args.batch,
                                              args.seq)}
