@@ -768,6 +768,16 @@ def _count_fetches(qs, ks):
     device_profiler.count("flash.head_rows", b * h)
 
 
+def _count_stats(*stats):
+    # Per lowering, as `_count_steps`: the HBM bytes of the row statistics
+    # (lse, delta) as the backward kernels take them. A [.., 1] column's last
+    # dim pads to 128 lanes in the tiled layout; a row is its numbers.
+    device_profiler.count("flash.bwd_stat_column_bytes", sum(
+        x.size * 128 * x.dtype.itemsize for x in stats if x.shape[-1] == 1))
+    device_profiler.count("flash.bwd_stat_row_bytes", sum(
+        x.size * x.dtype.itemsize for x in stats if x.shape[-1] != 1))
+
+
 def _run_row(plan, row, steps_ref, body, carry, finish, diagonal, band,
              part):
     """Run this grid row's steps from `carry`, then `finish(carry)`.
@@ -1060,10 +1070,11 @@ def _in_vmem(block_shape):
     64-wide head's K takes a 128-wide one's room (the v5e compiler on the
     first call with a 64-wide head as its only part, [1, 32768, 8, 64] K and
     V: "Scoped allocation with size 33.00M and limit 32.25M", where the
-    unpadded blocks were reckoned at 16.25M). A [rows, 1] column (lse,
-    delta) is counted as it is: its padding, under 1 MiB a kernel, is in
-    the working set's room, and the calls at 128-wide heads state the
-    limits they always did."""
+    unpadded blocks were reckoned at 16.25M). A [rows, 1] column (the
+    forward's lse block) is counted as it is: its padding, under 1 MiB a
+    kernel, is in the working set's room, and the calls at 128-wide heads
+    state the limits they always did. The backward's lse and delta are
+    lane-dense blocks (`_stat_forms`), counted as any other."""
     *lead, lanes = block_shape
     if lanes > 1:
         lanes = -(-lanes // 128) * 128
@@ -1279,18 +1290,54 @@ def _flash_fwd_pallas(qs, ks, v, mask, scale, block_q, block_k, interpret):
 # Pallas backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, mask, block_q, plan, seq_q, seq_k,
-                   steps_ref=None):
-    """dq_ref [block_q, D (+ R)]: the parts' gradients side by side."""
+def _stat_forms(lse, delta, block_q, width):
+    """The backward pass's row statistics, [B, H, S] float32 each (one number
+    a query), S padded up to whole blocks of queries, as its two kernels take
+    them -> (dq's: ONE array [B, H, blocks, 2 x block_q / lanes, lanes], a
+    block's own numbers a grid step, lse's runs of up to `_SUB` lanes and
+    then delta's; dk/dv's: lse and delta [B, H, steps, width] each, a row a
+    loop step, all of a head's a block (`width` is `block_q` or divides it:
+    `_step_width`)). All are reshapes of the lane-dense arrays: 4 bytes a
+    number in HBM and a lane of VMEM each, where a [B, H, S, 1] column's
+    last dim pads to 128 lanes in the tiled layout, 512 bytes a number
+    written and read (PERF.md section 6, PR 63)."""
+    b, h, s = lse.shape
+    lanes = min(block_q, _SUB)
+    stats = [jnp.pad(x, ((0, 0), (0, 0), (0, -s % block_q)))
+             for x in (lse, delta)]
+    own = jnp.concatenate([x.reshape(b, h, -1, block_q // lanes, lanes)
+                           for x in stats], axis=3)
+    return own, tuple(x.reshape(b, h, -1, width) for x in stats)
+
+
+def _owned_columns(ref):
+    """(lse, delta) [block_q, 1] each: the numbers of a grid step's own block
+    of the statistics `ref` holds ([1, 1, 1, 2 x block_q / lanes, lanes],
+    `_stat_forms`), down a score tile's rows, where dq needs them: the block
+    transposed ONCE, a run of lanes a column, and a statistic's columns one
+    under the other. Once a grid step: dq 3.20 -> 3.25 ms a call at [4, 32,
+    4096, 128] on the v5e, for 0.84 ms of column copies the call no longer
+    makes; as two operands, each spread by a diagonal select and 64 lane
+    reduces, 3.30; each transposed, 3.37 (PERF.md section 6, PR 63)."""
+    spread = ref[0, 0, 0].T   # [lanes, 2 x runs]
+    runs = spread.shape[1] // 2
+    return tuple(
+        jnp.concatenate([spread[:, r:r + 1] for r in range(at, at + runs)])
+        for at in (0, runs))
+
+
+def _bwd_dq_kernel(q_refs, k_refs, v_ref, do_ref, stats_ref, dq_ref, *,
+                   scale, mask, block_q, plan, seq_q, seq_k, steps_ref=None):
+    """dq_ref [block_q, D (+ R)]: the parts' gradients side by side. lse and
+    delta come lane-dense in ONE block, the grid step's own
+    (`_owned_columns`)."""
     from jax.experimental import pallas as pl
 
     width = plan.width
     qi = pl.program_id(2)
     q = _loaded(q_refs)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]      # [block_q, 1]
-    delta = delta_ref[0, 0]  # [block_q, 1]
+    lse, delta = _owned_columns(stats_ref)  # [block_q, 1] each
     causal_offset = seq_k - seq_q
 
     def own_pos(cols, rows=slice(0, block_q)):
@@ -1460,12 +1507,15 @@ def _bwd_dkv_kernel(q_refs, k_refs, v_ref, do_ref, lse_ref, delta_ref,
     _run_row(plan, kj, steps_ref, step, zero, finish, diagonal, band, part)
 
 
-def _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q, plan,
+def _bwd_dq_pallas(qs, ks, v, do, stats, mask, scale, block_q, plan,
                    interpret):
+    """stats: `_stat_forms`' first."""
+    from jax.experimental import pallas as pl
+
     b, h, s_q, _ = do.shape
     s_k = v.shape[2]
     # Same padding rationale as the forward (dynamic_slice clamping).
-    qs, do, lse, delta = _pad_seq((qs, do, lse, delta), block_q)
+    qs, do = _pad_seq((qs, do), block_q)
     ks, v = _pad_seq((ks, v), plan.width)
     s_q_pad, s_k_pad = do.shape[2], v.shape[2]
     kernel = functools.partial(
@@ -1478,27 +1528,25 @@ def _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q, plan,
         kernel, plan,
         [_spec(qs, h, block_q, True), _spec(ks, h, s_k_pad, False),
          _spec(v, h, s_k_pad, False), _spec(do, h, block_q, True),
-         _spec(lse, h, block_q, True), _spec(delta, h, block_q, True)],
+         pl.BlockSpec((1, 1, 1) + stats.shape[3:],
+                      lambda b_, h_, i: (b_, h_, i, 0, 0))],
         grid=(b, h, s_q_pad // block_q),
         out_specs=_spec(out_shape, h, block_q, True),
         out_shape=out_shape,
         interpret=interpret,
-    )(qs, ks, v, do, lse, delta)
+    )(qs, ks, v, do, stats)
     return dq[:, :, :s_q]
 
 
 def _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k, plan,
                     interpret):
+    """lse, delta: `_stat_forms`' rows, one a loop step, for the transposed
+    tile (see the kernel)."""
     b, h, s_q, _ = do.shape
     s_k = v.shape[2]
-    width = plan.width
-    qs, do, lse, delta = _pad_seq((qs, do, lse, delta), width)
+    qs, do = _pad_seq((qs, do), plan.width)
     ks, v = _pad_seq((ks, v), block_k)
     s_q_pad, s_k_pad = do.shape[2], v.shape[2]
-    # one row a loop step, for the transposed tile (see the kernel)
-    n_steps = s_q_pad // width
-    lse = lse.reshape(b, h, n_steps, width)
-    delta = delta.reshape(b, h, n_steps, width)
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, mask=mask, block_k=block_k,
         plan=plan, seq_q=s_q, seq_k=s_k,
@@ -1512,7 +1560,8 @@ def _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k, plan,
         kernel, plan,
         [_spec(qs, h, s_q_pad, False), _spec(ks, h, block_k, True),
          _spec(v, h, block_k, True), _spec(do, h, s_q_pad, False),
-         _spec(lse, h, n_steps, False), _spec(delta, h, n_steps, False)],
+         _spec(lse, h, lse.shape[2], False),
+         _spec(delta, h, delta.shape[2], False)],
         grid=(b, h, s_k_pad // block_k),
         out_specs=_spec(out_shape, h, block_k, True),
         out_shape=out_shape,
@@ -1523,14 +1572,20 @@ def _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k, plan,
 
 def _flash_bwd_pallas(qs, ks, v, o, lse, do, mask, scale, block_q, block_k,
                       interpret):
-    """-> dq [B, H, S, D (+ R)], dk likewise and dv, one a QUERY head (every
-    head's share of the gradient of the KV head, or rotary key, it read)."""
+    """lse [B, H, S], as the forward rule saved it -> dq [B, H, S, D (+ R)],
+    dk likewise and dv, one a QUERY head (every head's share of the gradient
+    of the KV head, or rotary key, it read)."""
     plans = block_schedule(do.shape[2], v.shape[2], block_q, block_k, mask)
     _count_steps(plans["dq"], plans["dkv"])
     _count_fetches(qs, ks)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-    dq = _bwd_dq_pallas(qs, ks, v, do, lse, delta, mask, scale, block_q,
+    # one number a row from its producer to both kernels, never [.., 1]:
+    # XLA's own reduce, which it forms in the output of the matmul that
+    # makes `do` (the attention's output projection, backwards) where it can
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    own, (lse, delta) = _stat_forms(lse, delta, block_q,
+                                    plans["dkv"].width)
+    _count_stats(own, lse, delta)
+    dq = _bwd_dq_pallas(qs, ks, v, do, own, mask, scale, block_q,
                         plans["dq"], interpret)
     dk, dv = _bwd_dkv_pallas(qs, ks, v, do, lse, delta, mask, scale, block_k,
                              plans["dkv"], interpret)
@@ -1545,7 +1600,7 @@ def _flash_bwd_pallas(qs, ks, v, o, lse, do, mask, scale, block_q, block_k,
 # a remat policy that saves them runs no second forward kernel in its
 # backward pass; under one that does not they cost nothing. lse is kept as
 # [B, H, S]: as [B, H, S, 1] its last dim pads to 128 lanes in the TPU's tiled
-# layout, 128x the bytes.
+# layout, 128x the bytes; the backward rule hands it on as it is.
 RESIDUAL_NAMES = ("flash.o", "flash.lse")
 
 
@@ -1572,8 +1627,7 @@ def _flash_fwd_rule(qs, ks, v, mask, scale, block_q, block_k, interpret):
 def _flash_bwd_rule(mask, scale, block_q, block_k, interpret, res, do):
     qs, ks, v, o, lse = res
     dq, dk, dv = _flash_bwd_pallas(
-        qs, ks, v, o, lse[..., None], do, mask, scale, block_q, block_k,
-        interpret)
+        qs, ks, v, o, lse, do, mask, scale, block_q, block_k, interpret)
 
     def shared(g, x):
         """g [B, H, S, W], a gradient one a query head, as the gradient of
@@ -1672,9 +1726,9 @@ def flash_attention(
     (forward, dq) or q and dO of one head (dk/dv) in VMEM, twice over (the
     pipeline's two buffers). Under the compiler's default of 16 MiB a kernel
     that holds: forward and backward compile at S 2048, 4096 and 8192 (8 MiB
-    of K and V at D 128; dk/dv takes lse and delta as rows, [steps, width]:
-    as [S, 1] columns the backward pass was refused at S 8192) and state
-    nothing, so they lower as they always did. Past it a call states its
+    of K and V at D 128; the backward kernels take lse and delta lane-dense,
+    dk/dv as rows [steps, width]: as [S, 1] columns the backward pass was
+    refused at S 8192) and state nothing. Past it a call states its
     own limit, reckoned from its blocks (`_vmem_limit`): S 16,384 (16 MiB of
     K and V: refused at "16.75M of 16.00M" until PR 50) compiles and runs at
     33 MiB of the chip's 128, under `CAUSAL` and `SlidingWindow` alike, and
@@ -1684,7 +1738,8 @@ def flash_attention(
     keys it holds: fetching K and V by the blocks a row's steps touch is
     what lifts it, PERF.md section 7), and before it HBM, where the
     forward's lse leaves the kernel as [B, H, S, 1] float32, padded to 128
-    lanes (235 MB a call at 28 heads x 16,384).
+    lanes (235 MB a call at 28 heads x 16,384; the one column left: the
+    backward pass hands no statistic on as one, `_stat_forms`).
     """
     b, s_q, h, d = q.shape
     group = _group(h, k.shape[2])

@@ -29,6 +29,7 @@ import optax
 from jax.experimental import topologies
 
 from ray_tpu import train
+from ray_tpu._private import device_profiler
 from ray_tpu.models import evabyte
 from ray_tpu.ops import eva
 from ray_tpu.ops.flash_attention import flash_attention
@@ -95,6 +96,10 @@ try:
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         state, {"inputs": tokens, "targets": tokens}).compile()
     out["step"] = "compiled"
+    out["flash_counters"] = {
+        name: n for name, n in device_profiler.snapshot()["counters"].items()
+        if name in ("flash.kernels", "flash.kernels_vmem_stated",
+                    "flash.bwd_stat_column_bytes", "flash.bwd_stat_row_bytes")}
     memory = compiled.memory_analysis()
     out["step_argument_bytes"] = memory.argument_size_in_bytes
     out["step_temp_bytes"] = memory.temp_size_in_bytes
@@ -207,3 +212,17 @@ def test_every_query_of_the_cell_over_every_op_of_the_step(compiled):
         ["eva_flash_bwd_roofline"], ["eva_flash_bwd_roofline"],
         ["eva_flash_fwd_roofline"]]
     assert len(pooled) >= 10 and sum(pooled) >= 0.9 * len(pooled), pooled
+
+
+def test_the_steps_flash_calls_state_the_vmem_limits_the_parents_did(compiled):
+    """The flash kernels this process lowered, as their lowerings counted
+    them (`flash.kernels_vmem_stated` of `flash.kernels` is what
+    `flash_vmem_stated_share` reads): the counts of PR 62, the parent of the
+    PR that hands lse and delta to the backward kernels lane-dense (PR 63):
+    every call over 34,816 keys states one, as it did. And no statistic reaches a backward kernel as an `f32[.., 1]`
+    column, 128 lanes a number."""
+    counted = compiled["flash_counters"]
+    assert (counted["flash.kernels"],
+            counted["flash.kernels_vmem_stated"]) == (7, 7)
+    assert counted["flash.bwd_stat_column_bytes"] == 0
+    assert counted["flash.bwd_stat_row_bytes"] > 0

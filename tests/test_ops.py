@@ -1481,24 +1481,26 @@ def test_gqa_call_hands_the_kernels_k_and_v_at_the_kv_heads_count(rule):
 # sub-tile, no plan has a triangle step, and the calls trace to the text of
 # the commit before a KV head was shared (PR 48's), which is PR 50's too;
 # in tiles of 256 each call's diagonal tiles are triangle steps since PR 51
-# (3 of their 4 sub-tiles) and the text is that PR's, pinned anew
+# (3 of their 4 sub-tiles) and the text is that PR's, pinned anew. ALL EIGHT
+# pinned anew at PR 63: the backward pass hands lse and delta to its kernels
+# lane-dense (`_stat_forms`), the forward's equations as they were
 _TRACED = {
     ("mha-causal", 128):
-        "2f81ed3e594cfca114fd5beea3ea75f2b0d3b9baac459527691cc87cccd298e6",
+        "e360dceab57a55eafba1ac642c601b55e2fea4690f6fb405d011f09c1edf7210",
     ("mha-window-band", 128):
-        "678c51e2ec7607ea3a2d3900eec3f30a62275af07b7f1f60aa05aa8865cac2aa",
+        "ecc34b4a86df86d8ba421fc68e001d4d58e43747f809860ab04d3a28b7fd576f",
     ("mha-block-diffusion", 128):
-        "c3e3e19bba2a3cfb52021c6178a62c39f886c2cbc4fdc41833e01248f07274a5",
+        "317b456e428b8f99833d82e9eb4ef46fd93cafc89759c6175a031439cf1742a0",
     ("in-parts", 128):
-        "3200cb59f77ba6f1fe56bda16aae410ea6af478fb892cdbce1be9caa5eaba83c",
+        "921bf9ac10baf5c4be97f024cdd2dcf580144cdc456ddf342c0a64a0ca119afd",
     ("mha-causal", 256):
-        "f08638ea6d6d8edf8bca076ae0535ea4794caa27e8d9f2720b8d02604cc012f7",
+        "4a011bf5806136331aa6d3fc96f0cd7c9f1b557afcdd1b3e8d593dc01f01268f",
     ("mha-window-band", 256):
-        "7d6f0ca553e8a7c3228a88f0343c3ce56d3901e3b4ca0f9aef60e623c9504754",
+        "84d86702905ad8ed451fd475b9b64ca1520f3aedbd5be146eba313c166591882",
     ("mha-block-diffusion", 256):
-        "5db41ee75362228cd5b04ddc60d8f982cd7c953da35788317ea0a7309a3f2b7a",
+        "cd6285ea55d2aefea071bfbf308490a4e979ae4f259adda10814729ed63693c5",
     ("in-parts", 256):
-        "dff156c5b3399d9aab1c2504fefb983807eab28f582d81c8ba8d36b02eec0dd2",
+        "740f813210e915be1e38cde78c12a9464ac90f7a449f96af88f9672709834884",
 }
 
 
@@ -1744,56 +1746,58 @@ def test_the_nemotron_cells_scan_traces_to_what_it_was():
 # sha256 of the flash call, value and gradients as traced, at each of the
 # nine cells' shapes before PR 55: (batch, S, heads, KV heads, head dim, the
 # rule, the rotary part's width of a call in parts); train-4chip's is a tp 2
-# shard's. PR 53's text: PR 55 reckons a VMEM block's lanes in tiles of 128
-# (`_in_vmem`), which moves no limit a call at 128-wide heads states.
+# shard's. PR 63's text, all twelve: the backward pass's row statistics reach
+# dq and dk/dv lane-dense and delta is a reduce with no `keepdims`; the
+# FORWARD's equations in each are PR 62's (`_CELL_FORWARDS` below).
 _CELL_CALLS = {
     "train-1chip": (
         (4, 2048, 32, 8, 128, True, 0),
-        "b772782c7fd6f57623e7d27765407b6b3b97891394ed7695215260872eb7ba07"),
+        "3d03f2ffea8aa4a49f4bf6a970fabc44715e18365327564ee7b25a3311e3c276"),
     "train-4chip": (
         (2, 2048, 16, 4, 128, True, 0),
-        "99a83cf3a27c794978987d35001874d9defc7a2f933b03a54b0acbf0df3c18e8"),
+        "343d805110c5b6582276d1da431f861a2daf3b6f560c31ea50607208221e5682"),
     "train-olmoe-1chip": (
         (4, 2048, 16, 16, 128, True, 0),
-        "3af1f557b548b45aabded6666b0a6dd85e88dee02355adf8eace1f3d501b94b6"),
+        "ad76d56770413ba46a3f2695a9013db5eb980b305d753f9d69a3683cd93d10fe"),
     "train-joyai-1chip": (
         (4, 2048, 32, 32, 128, True, 64),
-        "215c588077323543d19182c8008f836f56704813bb26f8ba3b573700a4ba7bb6"),
+        "bcab5e430ccf8d0d1743f5f57618ad8b1f42e8ee0ead99eefa20f012d89480fe"),
     "train-sdar-1chip": (
         (4, 4096, 32, 4, 128, BlockDiffusion(2048, 4), 0),
-        "8657c189f1ec87658e2fbe91178505526f92671d6cb9b4074592f0a06c3094f6"),
+        "29175e0770f686fabf18e3370c029a7689573e9ed865ec1c4944809863083297"),
     "train-ling-1chip": (
         (4, 2048, 32, 32, 128, True, 64),
-        "215c588077323543d19182c8008f836f56704813bb26f8ba3b573700a4ba7bb6"),
+        "bcab5e430ccf8d0d1743f5f57618ad8b1f42e8ee0ead99eefa20f012d89480fe"),
     "train-nemotron3-1chip": (
         (2, 2048, 32, 2, 128, True, 0),
-        "301461c060948943a88cd17b4a11a9538d36cb30b0a945eac53dd3dcd5c08fd0"),
+        "05d5aededff4b8dd102abc3635193b684ad0b6071ea92dca50ad714b1c9e583a"),
     "train-laguna-1chip.window": (
         (1, 8192, 64, 8, 128, SlidingWindow(512), 0),
-        "85e93091c021de5c2b0afebcf1b3397725138daf6675ce33c754cee1c00aeadf"),
-    # PR 60's text, as the three loop calls below: a loop plan's rows run
-    # their whole tiles in bodies of 4 and of 2 steps with no mask
+        "b14a39736bb7c91ba56c9d41d1a071c6c84ced79b7e11d4c43483ce1968a2945"),
+    # a loop plan's rows run their whole tiles in bodies of 4 and of 2 steps
+    # with no mask (PR 60), as the three loop calls below
     "train-laguna-1chip.full": (
         (1, 8192, 48, 8, 128, True, 0),
-        "a97d9981022efb68a7972dfb3dca6d2d4081431d7746cc796e99cfa4b6be3e84"),
+        "37bab37bd83184053ea900bd6c0cda8b644ba109023b981288887b66f191ec66"),
     # 24 of its 32 grid rows share ONE unrolled branch (PR 58); the 8 edge
     # rows' loop has the bodies
     "train-smallthinker-1chip.window": (
         (1, 16384, 28, 4, 128, SlidingWindow(4096), 0),
-        "df6c2055f1a4e5b0e032276d74336c82524c1eb62e28f3023a783bb514e1894c"),
+        "110f183065c6399fb21708c40586059a82a8610e00d6df10fbc5d1ff0bcb174a"),
     "train-smallthinker-1chip.full": (
         (1, 16384, 28, 4, 128, True, 0),
-        "cd86a4b7c3e2fae3a0089d5b36304e8efa5445b9eac0bbeaf5e685c5703ffed7"),
+        "e0a111cfcfadc3d02e98ce415f521b7cc6820dda94da81e05bd3d2e8f96b8691"),
     # the call at 64-wide heads and a scale of its own
     "train-granite4-1chip": (
         (1, 32768, 32, 8, 64, True, 0, 1 / 64),
-        "7b457fd5873f820913e6dbd630db66a42a8c16096d3dfb0a9305bbb72289e210"),
+        "605d62bfbb01b5fa336f68aad8eaf538d5a90a19ebacc6646a872ad62af451c8"),
 }
 
 
-@pytest.mark.parametrize("cell", list(_CELL_CALLS))
-def test_the_nine_cells_flash_calls_trace_to_what_they_were(cell):
-    (b, s, h, kv, d, rule, rope, *scale), digest = _CELL_CALLS[cell]
+def _cell_call(cell):
+    """-> (the cell's flash call, summed in float32; its operands as bf16
+    shapes)."""
+    (b, s, h, kv, d, rule, rope, *scale), _ = _CELL_CALLS[cell]
     shapes = [(b, s, h, d), (b, s, kv, d), (b, s, kv, d)]
     if rope:
         shapes += [(b, s, h, rope), (b, s, 1, rope)]
@@ -1804,9 +1808,155 @@ def test_the_nine_cells_flash_calls_trace_to_what_they_were(cell):
             **dict(zip(("scale",), scale)),
             **dict(zip(("q_rope", "k_rope"), parts))).astype(jnp.float32).sum()
 
+    return call, [jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes]
+
+
+@pytest.mark.parametrize("cell", list(_CELL_CALLS))
+def test_the_nine_cells_flash_calls_trace_to_what_they_were(cell):
+    call, shapes = _cell_call(cell)
     assert _traced_digest(
         jax.value_and_grad(call, argnums=tuple(range(len(shapes)))),
-        [jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in shapes]) == digest
+        shapes) == _CELL_CALLS[cell][1]
+
+
+# sha256 of the same twelve calls' FORWARD alone, the value and no gradient,
+# as traced at PR 62: the backward's row statistics (PR 63) move every digest
+# above and none of these. The forward kernel, its (o, lse [b, h, s, 1])
+# outputs and its index maps are what six `*_fwd_roofline` queries of the
+# benchmark pin.
+_CELL_FORWARDS = {
+    "train-1chip":
+        "89446e7930763463b43c16899c7d1da7968f4f86cf92eada9d0bedd2c04d16f0",
+    "train-4chip":
+        "90627cb7fe8ceb9857c133d2276e50fd46322db8f76d42cb272123d4ee519915",
+    "train-olmoe-1chip":
+        "7b3e442f319d177fbeb25e8b249b089425e64459d4de69e293f0fa2403c7eba3",
+    "train-joyai-1chip":
+        "e90b79eccb2bcb726b2b8051a5af1265e6ad4723c8cc79da1654587d3e177197",
+    "train-sdar-1chip":
+        "2c1cefd7f1b978046802271cdabd3e3ec3c3b9fe36b26a644c9e73b728e97dc2",
+    "train-ling-1chip":
+        "e90b79eccb2bcb726b2b8051a5af1265e6ad4723c8cc79da1654587d3e177197",
+    "train-nemotron3-1chip":
+        "c24da12436acb76672dd9f2cde287d8525303e60943dc12644fd040637310f27",
+    "train-laguna-1chip.window":
+        "0cfaaa20b9a49b89392eba7c1fe34271e880962ed0750771a78a15882fc00354",
+    "train-laguna-1chip.full":
+        "f8bb8f8dbecdccac6ba66868e30b74e2fadd5ee17ec8ee76785bab0a601ad1e2",
+    "train-smallthinker-1chip.window":
+        "adcd922778933acc881a183586ef8bdf62ed06f2de1f4555505b892972959930",
+    "train-smallthinker-1chip.full":
+        "518f9e6ccdea708c01adf9ff4d72ed104c010da3d36d8b5afcac7c6c6d87f146",
+    "train-granite4-1chip":
+        "9fc1009c81cd6840cad1b7ec16542e00ed980cad4e8fc48c3e8c8fa7ef7c90f8",
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_CALLS))
+def test_the_cells_forward_calls_trace_to_the_parents(cell):
+    call, shapes = _cell_call(cell)
+    assert _traced_digest(call, shapes) == _CELL_FORWARDS[cell]
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr, those inside its
+    sub-jaxprs (a `custom_vjp`'s, a `pjit`'s) too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("cell", [
+    "train-joyai-1chip", "train-sdar-1chip", "train-laguna-1chip.window",
+    "train-granite4-1chip"])
+def test_no_column_reaches_a_kernel_of_the_flash_call(cell):
+    """No operand of any Pallas call of the traced value and gradients is
+    `f32[..., 1]`: lse and delta reach dq and dk/dv lane-dense. (The one
+    such array left is the forward's lse OUTPUT, which the benchmark's
+    forward queries match.)"""
+    call, shapes = _cell_call(cell)
+    (b, s, h, *_), _ = _CELL_CALLS[cell]
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        call, argnums=tuple(range(len(shapes)))))(*shapes)
+    calls = list(_pallas_calls(traced.jaxpr))
+    assert len(calls) >= 3
+    operands = [v.aval for eqn in calls for v in eqn.invars]
+    assert not [a for a in operands if a.shape and a.shape[-1] == 1]
+    # the rows: three float32 operands, one number a query each of dk/dv's
+    # lse and delta and both in dq's one
+    stats = [a for a in operands if a.dtype == jnp.float32]
+    assert sorted(a.size // (b * h * s) for a in stats) == [1, 1, 2]
+    columns = [v.aval for eqn in calls for v in eqn.outvars
+               if v.aval.shape[-1] == 1]
+    assert [a.shape for a in columns] == [(b, h, s, 1)]   # the forward's lse
+
+
+def _row_stat_case(case):
+    """-> (the call's operands, keywords) of a case of the test below."""
+    (s_q, s_k, h, kv, d, rope), how = _ROW_STAT_CALLS[case]
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 6)
+    args = [jax.random.normal(ks[0], (2, s_q, h, d)),
+            jax.random.normal(ks[1], (2, s_k, kv, d)),
+            jax.random.normal(ks[2], (2, s_k, kv, d))]
+    if rope:
+        how = dict(how, q_rope=jax.random.normal(ks[3], (2, s_q, h, rope)),
+                   k_rope=jax.random.normal(ks[4], (2, s_k, 1, rope)))
+    return args, how, jax.random.normal(ks[5], (2, s_q, h, d))
+
+
+# (queries, keys, heads, KV heads, head dim, rotary part), the call's keywords
+_ROW_STAT_CALLS = {
+    # no multiple of block_q (128) nor of dk/dv's step
+    "ragged": ((300, 300, 2, 2, 32, 0), dict(block_q=128, block_k=128)),
+    # dk/dv's step (128) is half a block of queries: two rows a block
+    "two-rows-a-block": ((600, 600, 2, 2, 32, 0),
+                         dict(block_q=256, block_k=128)),
+    # a sequence under one sub-tile: the block is the sequence, 100 lanes
+    "short": ((100, 100, 2, 2, 32, 0), {}),
+    "queries-at-the-keys-end": ((200, 456, 2, 2, 32, 0),
+                                dict(block_q=128, block_k=128)),
+    "gqa-8": ((256, 256, 8, 1, 32, 0), dict(block_q=128, block_k=128)),
+    "in-parts-rope-64": ((256, 256, 4, 4, 32, 64),
+                         dict(block_q=128, block_k=128)),
+    "head-64": ((320, 320, 4, 2, 64, 0),
+                dict(block_q=128, block_k=128, scale=1 / 64)),
+    "block-diffusion": ((512, 512, 2, 1, 32, 0),
+                        dict(mask=BlockDiffusion(256, 4), block_q=256,
+                             block_k=256)),
+    "window": ((640, 640, 2, 2, 32, 0),
+               dict(mask=SlidingWindow(160), block_q=256, block_k=256)),
+    "eva": ((256, 288, 2, 2, 32, 0),
+            dict(mask=EvaWindows(256, 64, 8), block_q=128, block_k=128)),
+    # more than eight rows of statistics a head
+    "ten-rows": ((1280, 1280, 2, 1, 32, 0), dict(block_q=128, block_k=128)),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_STAT_CALLS))
+def test_flash_gradients_with_row_statistics_against_the_oracle(case):
+    """The three gradients (five in parts) of sum(o * w), the kernels
+    (interpreted) against the float32 oracle, at the shapes where a row of
+    lse or delta can go wrong: the paddings to `block_q` and to dk/dv's step,
+    a block of several rows, `seq_q != seq_k`, each rule."""
+    args, how, w = _row_stat_case(case)
+    parts = [how.pop(name) for name in ("q_rope", "k_rope") if name in how]
+
+    def grads(**path):
+        def loss(*a):
+            return jnp.sum(flash_attention(
+                *a[:3], **how, **path,
+                **dict(zip(("q_rope", "k_rope"), a[3:]))) * w)
+
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(loss, argnums=tuple(range(len(args + parts))))(
+                *args, *parts)
+
+    for got, want in zip(grads(interpret=True), grads(use_pallas=False)):
+        assert got.shape == want.shape
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(got / scale, want / scale, atol=2e-5)
 
 
 @functools.lru_cache(maxsize=None)

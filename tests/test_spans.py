@@ -594,6 +594,42 @@ def test_a_router_ahead_of_attention_and_the_flash_kernels_are_counted():
     assert first("moe.route") < first("swa.attend") < first("moe.experts")
 
 
+def test_the_flash_backwards_row_statistics_are_counted_by_their_form():
+    """A traced `value_and_grad` of a Pallas flash call counts, once per
+    lowering of its backward pass, the HBM bytes of lse and delta as its two
+    kernels take them: `flash.bwd_stat_row_bytes`, lane-dense, 4 bytes a
+    number (two statistics, each in dq's one block of runs of lanes and as
+    dk/dv's rows: 4 x b x h x s float32 over three operands), and
+    `flash.bwd_stat_column_bytes`, what reaches a kernel as `[.., 1]`, its
+    last dim padded to 128 lanes: there, and 0 (2 x b x h x s x 512 a call
+    before PR 63). A trace of the forward alone counts neither."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    b, s, h = 2, 384, 4
+    q, k = (jax.ShapeDtypeStruct((b, s, n, 16), jnp.float32) for n in (h, 2))
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, use_pallas=True, block_q=128,
+                               block_k=128).sum()
+
+    def grew(fn):
+        before = dp.snapshot()["counters"]
+        jax.make_jaxpr(fn)(q, k, k)
+        after = dp.snapshot()["counters"]
+        return {name: after.get(name, 0) - before.get(name, 0) for name in (
+            "flash.bwd_stat_row_bytes", "flash.bwd_stat_column_bytes")}
+
+    assert grew(call) == {"flash.bwd_stat_row_bytes": 0,
+                          "flash.bwd_stat_column_bytes": 0}
+    assert grew(jax.value_and_grad(call, argnums=(0, 1, 2))) == {
+        "flash.bwd_stat_row_bytes": 2 * 2 * b * h * s * 4,
+        "flash.bwd_stat_column_bytes": 0}
+    assert "flash.bwd_stat_column_bytes" in dp.snapshot()["counters"]
+
+
 def test_the_heads_chunks_are_counted_where_their_gradient_is_formed():
     """`blocks.chunked_ce` counts every chunk it traces, the remainder's
     too, under `ce.chunks`, and under `ce.chunks_fused` those of the forward

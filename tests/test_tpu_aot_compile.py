@@ -84,8 +84,9 @@ def pair_scatters(hlo, pairs):
 
 def flash_operands(hlo):
     # per Pallas call of a compiled program, the shapes of its 4-d operands
-    # (q, K, V, dO, lse, delta; a loop plan's table of steps is 2-d); an
-    # instruction's line names its operands only, as in `pair_scatters`
+    # (q, K, V, dO, dk/dv's rows of lse and delta; a loop plan's table of
+    # steps is 2-d, dq's lse and delta 5-d); an instruction's line names its
+    # operands only, as in `pair_scatters`
     shapes, calls = {}, []
     for ln in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", ln)
@@ -777,12 +778,13 @@ def test_flash_calls_with_triangle_steps_compile_for_v5e(compiled):
 def test_the_dense_cells_flash_call_lowers_to_the_text_it_had(compiled):
     """train-1chip's call, `[4, 2048, 32, 128]` causal over 8 KV heads, value
     and gradient, as LOWERED for the v5e, every kernel's assembly in it less
-    its locations (`tools/step_lowering_hash.py`): the text of the commit
-    before a loop plan's rows ran steps in bodies (PR 59's). Its plans are
-    unrolled, 10 steps a kernel, and reach none of that."""
+    its locations (`tools/step_lowering_hash.py`): PR 63's text, whose
+    backward kernels take lse and delta lane-dense (`_stat_forms`) and whose
+    delta is a reduce with no `keepdims` (until then PR 59's: its plans are
+    unrolled, 10 steps a kernel, and no change to a loop plan reached it)."""
     assert compiled["flash_s2048_lowered"] == (
-        "ab940e3f54001e440bfbe9d7beed7028"
-        "02dd11282941af318a6e65cd4505fb57")
+        "61fca4b1df28f3d83db4233f1a802ca6"
+        "5a28498423421353c6c4e59c4fa8e15c")
 
 
 @pytest.mark.parametrize("call, steps, in_bodies", [
@@ -1104,15 +1106,16 @@ def test_window_and_full_flash_calls_at_s8192_compile_for_v5e(compiled):
     # call's loops run their rows' whole tiles in bodies of 4 and of 2 steps
     # since PR 60: its text; the window call's first row, its own tile
     # alone, is a triangle step since PR 51 (dk/dv: the last row), the one
-    # branch of its two that is not the band's: PR 51's text
+    # branch of its two that is not the band's. Both are PR 63's texts: the
+    # backward kernels take lse and delta lane-dense (`_stat_forms`)
     assert compiled["laguna_full_jaxpr"] != (     # PR 49's, until PR 60
         "dc1bde509d1bcbdffd1972a302135611f48b337c4a9784192d6e2610f0bb16f6")
     assert compiled["laguna_full_jaxpr"] == (
-        "55ce70dccebd147dea6455459dd25d5b7340b859c3823d68944876e4a3db7d89")
+        "4e4bcd7dbef7b4155315f33450f13c351181a53b24a248ee83cd352b8a4545e1")
     assert compiled["laguna_window_jaxpr"] != (   # PR 49's
         "bb8f601aafbc03b1d2e47ad5becb97d305d31ef91a6140603b735510530a66e6")
     assert compiled["laguna_window_jaxpr"] == (
-        "9a53f067d2f5c29ded6141f7308f6e78097b17c71ef90f7399fce5544f81ef3f")
+        "3e5a242fb1c22c7bb2d45aaa30b475ca53420231789c93681f0c05f64a0cda83")
     assert compiled["laguna_window_triangle_steps"] == [1] * 3
     assert compiled["laguna_full_triangle_steps"] == [0] * 3
     assert compiled["laguna_full_vmem_limits"] == []
@@ -1140,15 +1143,16 @@ def test_window_and_full_flash_calls_at_s16384_compile_for_v5e(compiled):
     to 33.3 MiB of the v5e's 128."""
     for name, steps, longest, digest in (
             ("smallthinker_window", 252, 9,
-             "8cc14de4d1e6c3d572c2299b996f1a9a"
-             "3d12a50dc4e6724790a6ba517caed9e0"),
-            ("smallthinker_full", 528, 32, "976e9b502281bfccc59fbd55bc8be43d"
-             "05ee7201c370fcfd1a041e4b260e3efa")):
+             "bef3892b91a1a233db39081179712960"
+             "950db6c4171fc25de4112552eaf48fea"),
+            ("smallthinker_full", 528, 32, "ae862653013c28790e66f0838c5a8616"
+             "504f03a6c2e69e87db46623a205a1684")):
         assert compiled[name] == "compiled", compiled[name]
         # loops: no triangle step. The window call's 24 rows of ONE shape
         # share an unrolled branch (PR 58); the loops, all of the causal
         # call's rows and the window call's 8 edge rows, run a row's whole
-        # tiles in bodies of 4 and of 2 steps with no mask: PR 60's texts
+        # tiles in bodies of 4 and of 2 steps with no mask (PR 60); PR 63's
+        # texts: lse and delta reach the backward kernels lane-dense
         assert compiled[name + "_plans"] == [[False, steps, longest, 0]] * 3
         assert compiled[name + "_jaxpr"] == digest
         limits = sorted(map(int, compiled[name + "_vmem_limits"]))

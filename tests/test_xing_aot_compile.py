@@ -34,6 +34,7 @@ import optax
 from jax.experimental import topologies
 
 from ray_tpu import train
+from ray_tpu._private import device_profiler
 from ray_tpu.models import blocks, mla_moe
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -91,6 +92,10 @@ try:
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
         state, {"inputs": tokens, "targets": tokens}).compile()
     out["step"] = "compiled"
+    out["flash_counters"] = {
+        name: n for name, n in device_profiler.snapshot()["counters"].items()
+        if name in ("flash.kernels", "flash.kernels_vmem_stated",
+                    "flash.bwd_stat_column_bytes", "flash.bwd_stat_row_bytes")}
     out["step_argument_bytes"] = compiled.memory_analysis() \
         .argument_size_in_bytes
     # the ops that run as events of their own: every instruction outside a
@@ -279,3 +284,19 @@ def test_the_share_query_takes_the_routed_block_and_none_of_the_path(
     both = [op for op, _ in took if queries["hc_time_share"].search(op)
             or queries["hc_kernel_time_share"].search(op)]
     assert not both
+
+
+def test_the_steps_flash_calls_state_the_vmem_limits_the_parents_did(compiled):
+    """The flash kernels this process lowered, as their lowerings counted
+    them (`flash.kernels_vmem_stated` of `flash.kernels` is what
+    `flash_vmem_stated_share` reads): the counts of PR 62, the parent of the
+    PR that hands lse and delta to the backward kernels lane-dense (PR 63):
+    NO call of this step states a limit (beside a share's routed block's
+    backward pass every Pallas call that STATED one hung the v5e, PERF.md
+    section 6, PR 62). And no statistic reaches a backward kernel as an `f32[.., 1]`
+    column, 128 lanes a number."""
+    counted = compiled["flash_counters"]
+    assert (counted["flash.kernels"],
+            counted["flash.kernels_vmem_stated"]) == (12, 0)
+    assert counted["flash.bwd_stat_column_bytes"] == 0
+    assert counted["flash.bwd_stat_row_bytes"] > 0

@@ -43,6 +43,19 @@ the program has.) What `_LOOP_BODY`'s comment was read from
 --scale 0.08838834764831845` (SmallThinker's, and with `--rule window --window
 4096` its window layers' edge rows); EvaByte's above. Appends a line to
 chiprun_out/flash_loop_body_sweep.jsonl.
+
+`--ops`: no oracle either; the call's BACKWARD PASS alone as one program (its
+residuals made by another), traced: the two kernels' ms and, by name and
+shape, the device ms a call of EVERY other op of it, largest first (what
+makes delta, what is left of the relayouts, the sums over a KV head's group,
+the transposes): "the call alone, before | after" for a change to what feeds
+the kernels. It takes `--batch`, `--rule bd --block n` (`BlockDiffusion(s /
+2, n)`) and `--rope r` (a call in parts: r rotary channels and ONE rotary
+key) besides, so it runs at any cell's shape: SDAR's `--ops --rule bd --batch
+4 --seq 4096 --block 4 --heads 32 --kv-heads 4 --head-dim 128 --scale
+0.08838834764831845`, JoyAI's `--ops --batch 4 --seq 2048 --heads 32
+--kv-heads 32 --head-dim 128 --rope 64 --scale 0.07216878364870323`,
+Granite's `--ops`. Appends a line to chiprun_out/flash_backward_ops.jsonl.
 """
 import argparse
 import importlib
@@ -58,8 +71,9 @@ import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.ops import eva  # noqa: E402
 from ray_tpu.ops.flash_attention import (  # noqa: E402
-    EvaWindows, SlidingWindow, block_schedule, flash_attention)
-from tools.kda_chip_check import kernel_ms  # noqa: E402
+    BlockDiffusion, EvaWindows, SlidingWindow, block_schedule,
+    flash_attention)
+from tools.kda_chip_check import device_events, kernel_ms  # noqa: E402
 
 BOUND = 2e-2
 ROWS = 256
@@ -127,6 +141,29 @@ def value_and_grads(kernel, w, n):
         argnums=tuple(range(n)))(*x)))
 
 
+def backward_ops(kernel, args, w, n=3):
+    """-> the ms a call, on the device's clock, of the ops of `kernel`'s
+    backward pass alone: {"dq_ms", "dkv_ms"} and [(ms, op and shape)] of
+    every other op, largest first; and each gradient's Frobenius norm (two
+    commits' runs at one seed give the same inputs: norms to compare)."""
+    o, pull = jax.jit(lambda *x: jax.vjp(kernel, *x))(*args)
+    back = jax.jit(lambda pull, g: pull(g))
+    g = w.astype(o.dtype)
+    norms = [float(jnp.linalg.norm(x.astype(jnp.float32)))
+             for x in jax.block_until_ready(back(pull, g))]
+    kernels, others = {}, {}
+    for name, ns in device_events(back, pull, g, n=n):
+        kernel = kernel_of(name)
+        m = re.match(r"%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(", name)
+        op = kernel or (
+            f"{m[1]} {m[2]}" + (" " + m[3] if m[3] != "fusion" else "")
+            if m else name)[:160]
+        into = kernels if kernel else others
+        into[op] = into.get(op, 0.0) + ns * 1e-6 / n
+    return kernels, sorted(((ms, op) for op, ms in others.items()),
+                           reverse=True), norms
+
+
 FORMS = [(), (2,), (4,), (8,), (4, 2)]
 
 
@@ -163,8 +200,13 @@ def main():
     ap.add_argument("--bodies", default=None, help="with --sweep: the sizes "
                     "of a loop's bodies in place of the default forms, as "
                     "'4,2;2,1'")
+    ap.add_argument("--ops", action="store_true")
+    ap.add_argument("--batch", type=int, default=1, help="with --ops")
+    ap.add_argument("--block", type=int, default=4, help="--rule bd's")
+    ap.add_argument("--rope", type=int, default=0, help="with --ops: the "
+                    "rotary part's width of a call in parts")
     ap.add_argument("--seed", type=int, default=55)
-    ap.add_argument("--rule", choices=("causal", "window", "eva"),
+    ap.add_argument("--rule", choices=("causal", "window", "eva", "bd"),
                     default="causal")
     ap.add_argument("--seq", type=int, default=32768)
     ap.add_argument("--seq-k", type=int, default=None)
@@ -232,6 +274,39 @@ def main():
         def plain(scale, **how):
             return lambda *x: oracle(*x, blocks, scale, keep, **how)
 
+    if a.rule == "bd" and not a.ops:
+        ap.error("--rule bd has no oracle here: with --ops")
+    if a.ops:
+        if is_eva:
+            ap.error("--ops runs flash_attention's own rules")
+        rule = {"causal": True, "window": SlidingWindow(a.window),
+                "bd": BlockDiffusion(s // 2, a.block)}[a.rule]
+        args = tuple(jnp.broadcast_to(x, (a.batch,) + x.shape[1:])
+                     for x in (q, k, v))
+        if a.rope:
+            args += (jax.random.normal(ks[4], (a.batch, s, h, a.rope))
+                     .astype(bf16),
+                     jax.random.normal(ks[5], (a.batch, s_k, 1, a.rope))
+                     .astype(bf16))
+
+        def call(q, k, v, *parts):
+            return flash_attention(
+                q, k, v, causal=rule, scale=a.scale, use_pallas=True,
+                **dict(zip(("q_rope", "k_rope"), parts)))
+
+        kernels, others, norms = backward_ops(
+            call, args, jax.random.normal(ks[3], args[0].shape))
+        out = {"device": jax.devices()[0].device_kind, "seed": a.seed,
+               "rule": a.rule, "shape": [a.batch, s, h, d], "keys": s_k,
+               "kv_heads": g, "rope": a.rope, "grad_norms": norms,
+               "ms": kernels,
+               "other_ops_ms": sum(ms for ms, _ in others),
+               "other_ops": [[round(ms, 4), op] for ms, op in others]}
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/flash_backward_ops.jsonl", "a") as f:
+            f.write(json.dumps(out) + "\n")
+        print(json.dumps(out, indent=1))
+        return 0
     if a.sweep:
         rule = EvaWindows(s, a.window, a.chunk) if is_eva \
             else SlidingWindow(a.window) if a.rule == "window" else True

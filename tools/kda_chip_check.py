@@ -199,11 +199,9 @@ def kernel_of(event_name):
     return KERNEL_OF_OUTPUTS.get(len(re.findall(r"\w+\[", m[1])))
 
 
-def kernel_ms(fn, *args, n=5, kernel_of=kernel_of):
-    """`fn` (compiled already) traced over `n` calls -> the milliseconds
-    of one event of each kernel (`kernel_of(event name)`: this tool's, or
-    `tools/ssd_chip_check.py`'s) on the device's clock; {} where the trace
-    holds no such event."""
+def device_events(fn, *args, n=5):
+    """`fn` (compiled already) traced over `n` calls -> [(name, ns)], every
+    event of the device's "XLA Ops" lines; [] where there is no trace."""
     from jax.profiler import ProfileData
 
     with tempfile.TemporaryDirectory() as d:
@@ -215,11 +213,19 @@ def kernel_ms(fn, *args, n=5, kernel_of=kernel_of):
         paths = glob.glob(os.path.join(
             d, "plugins", "profile", "*", "*.xplane.pb"))
         planes = ProfileData.from_file(paths[0]).planes if paths else []
-        took = {}
-        for e in (e for p in planes if p.name.startswith("/device:TPU:")
-                  for ln in p.lines if ln.name == "XLA Ops"
-                  for e in ln.events):
-            took.setdefault(kernel_of(e.name), []).append(e.duration_ns * 1e-6)
+        return [(e.name, e.duration_ns)
+                for p in planes if p.name.startswith("/device:TPU:")
+                for ln in p.lines if ln.name == "XLA Ops" for e in ln.events]
+
+
+def kernel_ms(fn, *args, n=5, kernel_of=kernel_of):
+    """`fn` (compiled already) traced over `n` calls -> the milliseconds
+    of one event of each kernel (`kernel_of(event name)`: this tool's, or
+    `tools/ssd_chip_check.py`'s) on the device's clock; {} where the trace
+    holds no such event."""
+    took = {}
+    for name, ns in device_events(fn, *args, n=n):
+        took.setdefault(kernel_of(name), []).append(ns * 1e-6)
     return {kernel: statistics.mean(ms) for kernel, ms in took.items()
             if kernel}
 

@@ -21,7 +21,9 @@ a cell (`--cell`), so a cell's counters and caches are its own. `--out`
 keeps each text beside the table, for a diff where a hash moves (`--rehash
 DIR` makes the table again from kept texts); `--tiny` lowers the module's
 `tiny()` configuration at B 2 x S 64 on the CPU instead (seconds, for
-iterating).
+iterating). `--cell NAME --memory` compiles that one step too and prints the
+bytes the compiler places (arguments, outputs, aliased, temporaries): whether
+a change to what the step holds still fits the chip, with no chip.
 """
 
 from __future__ import annotations
@@ -76,7 +78,8 @@ def digest(text: str) -> str:
     return hashlib.sha256(canonical(text).encode()).hexdigest()
 
 
-def lowered_text(workload, config, tiny: bool) -> str:
+def lowered_step(workload, config, tiny: bool):
+    """-> the cell's train step, lowered (`jax.stages.Lowered`)."""
     import importlib
     from functools import partial
 
@@ -146,7 +149,7 @@ def lowered_text(workload, config, tiny: bool) -> str:
         partial(module.loss_fn, config=model, mesh=mesh, rules=rules), opt,
         shardings, batch_sharding={"inputs": bs, "targets": bs})
     tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=bs)
-    return step.lower(state, {"inputs": tokens, "targets": tokens}).as_text()
+    return step.lower(state, {"inputs": tokens, "targets": tokens})
 
 
 def main() -> int:
@@ -156,6 +159,9 @@ def main() -> int:
     ap.add_argument("--against", help="a directory --out wrote: compare")
     ap.add_argument("--rehash", help="a directory of kept texts: its table")
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--memory", action="store_true", help="with --cell: "
+                    "COMPILE the step for the described v5e and print what "
+                    "the compiler places (a refusal is its error)")
     args = ap.parse_args()
     sys.path.insert(0, ROOT)
     if args.rehash:
@@ -170,7 +176,14 @@ def main() -> int:
         return 0
     if args.cell:
         (found,) = [c for c in cells() if c[0]["name"] == args.cell]
-        text = lowered_text(*found, args.tiny)
+        lowered = lowered_step(*found, args.tiny)
+        if args.memory:
+            memory = lowered.compile().memory_analysis()
+            print("MEMORY " + json.dumps({
+                name: getattr(memory, name + "_size_in_bytes")
+                for name in ("argument", "output", "alias", "temp")}))
+            return 0
+        text = lowered.as_text()
         if args.out:
             with open(os.path.join(args.out, args.cell + ".mlir"), "w") as f:
                 f.write(text)
