@@ -298,6 +298,15 @@ def head_gated(attn, h, w_gate):
         preferred_element_type=jnp.float32))[..., None].astype(attn.dtype)
 
 
+def channel_gated(attn, h, w_gate):
+    """`head_gated`'s sibling, a gate a CHANNEL: attn [B, S, H, K] *
+    sigmoid(W_gate h), w_gate [D, H, K] (arXiv:2505.06708's elementwise
+    form); the gate in float32."""
+    return attn * jax.nn.sigmoid(jnp.einsum(
+        "bsd,dhk->bshk", h, w_gate,
+        preferred_element_type=jnp.float32)).astype(attn.dtype)
+
+
 def attn_sublayer(x, params, positions, config, mesh=None,
                    rules: Optional[LogicalAxisRules] = None, mask=None,
                    rotary=None, scale=None, branch=None):
@@ -305,9 +314,10 @@ def attn_sublayer(x, params, positions, config, mesh=None,
     causal, or under the static rule `mask` (`attention`). What may differ
     by the KIND of a layer comes from the caller, not from `config`: the
     rule, the rotary form (`rotary`), the number of query heads (the
-    layer's `wq`) and a gate per head on the output before `wo`,
-    attn_head * sigmoid(w_head . h), where the layer has a `w_attn_gate`
-    [D, H] (`mixers.mla_sublayer`'s form), the scores' `scale` where it
+    layer's `wq`) and a gate on the output before `wo` where the layer has
+    a `w_attn_gate`, by ITS shape: [D, H] a gate per head, attn_head *
+    sigmoid(w_head . h) (`mixers.mla_sublayer`'s form), [D, H, K] a gate
+    per channel (`channel_gated`), the scores' `scale` where it
     is not d_head ** -0.5 and `branch`, a multiplier on what the block adds
     to the residual (`models/granite_hybrid.py`'s published two)."""
     lc = partial(with_logical_constraint, mesh=mesh, rules=rules)
@@ -322,7 +332,9 @@ def attn_sublayer(x, params, positions, config, mesh=None,
         with jax.named_scope("attn.gate"):
             # `qkv`'s normed input: the compiler keeps one
             h = rms_norm(x, params["attn_norm"], config.norm_eps)
-            attn = head_gated(attn, h, params["w_attn_gate"])
+            w_gate = params["w_attn_gate"]
+            attn = (head_gated if w_gate.ndim == 2 else channel_gated)(
+                attn, h, w_gate)
     x = x + scaled(jnp.einsum("bshk,hkd->bsd", attn, params["wo"]), branch)
     return residual(x, mesh, rules)
 

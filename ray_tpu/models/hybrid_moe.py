@@ -7,17 +7,21 @@ Layer i (published index) mixes with MLA when (i + 1) % `period` == 0 and
 with KDA otherwise; its second sublayer is a dense SwiGLU (`d_ff`) when
 i < `n_dense_layers` and routed + shared experts otherwise. All pre-norm,
 over the layer library: `models/blocks.py` (RMSNorm, the SwiGLU sublayer,
-remat, the loss), `models/mixers.py` (MLA), `models/experts.py` (the routed
-block) and `models/layer_pattern.py` (the plan and its walk); KDA is this
-module's own. With h = RMSNorm(x), per head (H heads, d = `kda_head_dim`
-128):
+remat, the loss), `models/mixers.py` (MLA, and KDA in the form this
+config's gate fields give), `models/experts.py` (the routed block) and
+`models/layer_pattern.py` (the plan and its walk). With h = RMSNorm(x), per
+head (H heads, d = `kda_head_dim` 128):
 
 - *KDA* (Kimi Delta Attention, arXiv:2510.26692; `ops/kda.py`): q~, k~, v~ =
   W_q h, W_k h, W_v h; each channel through a causal depthwise conv over
   time of `conv_size` 4 taps, then SiLU; q = l2norm(q) / sqrt(d), k =
   l2norm(k). Decay: a = W_f h + dt_bias (full rank), g = `kda_lower_bound`
-  x sigmoid(exp(A_log_head) a) in (-5, 0) per channel, alpha = exp(g).
-  beta = sigmoid(w_b . h). State S in R^{d x d}, float32, S_0 = 0:
+  x sigmoid(exp(A_log_head) a) in (-5, 0) per channel, alpha = exp(g):
+  `ops/kda.py` is told the bound and takes its bounded plan. The sublayer
+  (`mixers.kda_sublayer`) and the op take other forms as well (no lower
+  bound, low-rank gates, beta in (0, 2): `models/solar_open2.py`); this
+  module's published configurations have none of them and its config
+  refuses them. beta = sigmoid(w_b . h). State S in R^{d x d}, float32, S_0 = 0:
       S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
       o_t = S_t^T q_t
   x = x + W_o [RMSNorm_head(o_t) * sigmoid(W_g h)]. No RoPE.
@@ -55,7 +59,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu._private import device_profiler
 from ray_tpu.models import blocks, experts, layer_pattern, mixers
 from ray_tpu.models.blocks import residual, rms_norm
 from ray_tpu.ops import kda as kda_op
@@ -78,7 +81,9 @@ class HybridMoeConfig(experts.Share):
     n_heads: int = 32
     kda_head_dim: int = 128
     conv_size: int = 4
-    kda_lower_bound: float = -5.0
+    kda_lower_bound: float = -5.0  # in [-5, 0): `ops/kda.py`'s bounded plan
+    kda_gate_rank: int = 0         # `mixers.kda_sublayer` reads both: full
+    kda_beta_scale: float = 1.0    # rank and beta in (0, 1) here, fixed
     q_lora_rank: int = 0
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
@@ -116,8 +121,17 @@ class HybridMoeConfig(experts.Share):
                 object.__setattr__(self, name, tuple(v))
         held = self.held_layers
         self.held  # raises where the share is outside the router's outputs
-        if self.kda_lower_bound < -5.0 or self.kda_lower_bound >= 0:
-            raise ValueError("ops/kda.py takes a log decay in [-5, 0) a step")
+        if (self.kda_lower_bound is None
+                or not -5.0 <= self.kda_lower_bound < 0
+                or self.kda_gate_rank or self.kda_beta_scale != 1.0):
+            raise ValueError(
+                "this module's KDA gate is Ling's published form: "
+                "`kda_lower_bound` in [-5, 0) (`ops/kda.py`'s bounded plan), "
+                "full-rank gates, beta in (0, 1). `mixers.kda_sublayer` and "
+                "`ops/kda.py` take a gate with no bound, low-rank gates and "
+                "beta in (0, 2) (`models/solar_open2.py`), but no published "
+                "configuration of this module has them and "
+                "`benchmarks/reference_ling.py` does not either")
         for i in held:
             if i < self.n_dense_layers and self.is_mla(i):
                 raise NotImplementedError("a dense layer that mixes with MLA")
@@ -158,7 +172,7 @@ class HybridMoeConfig(experts.Share):
     def num_params(self) -> int:
         c = self
         d = c.d_model
-        kda = kda_num_params(c) + 2 * d
+        kda = mixers.kda_num_params(c) + 2 * d
         mla = mixers.mla_num_params(c) + 2 * d
         routed = (d * c.n_experts + c.n_experts + 3 * d * c.d_ff_expert
                   * (c.n_experts_held + c.n_shared_experts))
@@ -169,28 +183,9 @@ class HybridMoeConfig(experts.Share):
         return total
 
 
-def kda_num_params(c) -> int:
-    """The KDA mixer's parameters (no layer norm)."""
-    hd = c.n_heads * c.kda_head_dim
-    return (6 * c.d_model * hd + c.d_model * c.n_heads
-            + 3 * c.conv_size * hd + c.n_heads + hd + c.kda_head_dim)
-
-
 # --------------------------------------------------------------------------
 # parameters
 # --------------------------------------------------------------------------
-
-def _kda_axes(L):
-    proj = L + ("embed", "heads", "kv")
-    return {
-        "attn_norm": L + (None,), "wq": proj, "wk": proj, "wv": proj,
-        "conv_q": L + (None, "heads", "kv"), "conv_k": L + (None, "heads", "kv"),
-        "conv_v": L + (None, "heads", "kv"),
-        "w_f": proj, "dt_bias": L + ("heads", "kv"), "a_log": L + ("heads",),
-        "w_b": L + ("embed", "heads"), "w_g": proj, "o_norm": L + (None,),
-        "wo": L + ("heads", "kv", "embed"), "mlp_norm": L + (None,),
-    }
-
 
 def param_logical_axes(config: HybridMoeConfig) -> Dict[str, Any]:
     c = config
@@ -199,45 +194,18 @@ def param_logical_axes(config: HybridMoeConfig) -> Dict[str, Any]:
     axes = {"embed": ("vocab", "embed"), "final_norm": (None,),
             "lm_head": ("embed", "vocab")}
     if dense:
-        axes["dense"] = {**_kda_axes(L), **blocks.ffn_axes(L)}
+        axes["dense"] = {**mixers.kda_axes(c, L), **blocks.ffn_axes(L)}
     for name, mla in (("kda", False), ("mla", True)):
         if any(c.is_mla(i) == mla for i in loose):
             axes.setdefault("loose", {})[name] = {
-                **(mixers.mla_axes(L, c) if mla else _kda_axes(L)),
+                **(mixers.mla_axes(L, c) if mla else mixers.kda_axes(c, L)),
                 **experts.routed_axes(L)}
     if periods:
         axes["periods"] = {
-            "kda": {**_kda_axes(L + (None,)),
+            "kda": {**mixers.kda_axes(c, L + (None,)),
                     **experts.routed_axes(L + (None,))},
             "mla": {**mixers.mla_axes(L, c), **experts.routed_axes(L)}}
     return axes
-
-
-def _init_kda(config, key):
-    """One layer's KDA mixer and its two layer norms. Fan-in scaled normal
-    projections; conv taps N(0, 1 / conv_size); `a_log` = log U(1, 16) a
-    head (flash-linear-attention's), `dt_bias` -U(1, 5) a channel: with a
-    unit-RMS input W_f h is ~N(0, 1), so a head's decay a token runs from
-    none (exp(A_log) 16) to ~0.8 (exp(A_log) 1), inside (-5, 0) always."""
-    c = config
-    h, d = c.n_heads, c.kda_head_dim
-    ks = jax.random.split(key, 12)
-    proj = lambda k: blocks.dense(c, k, (c.d_model, h, d), c.d_model)  # noqa: E731
-    conv = lambda k: blocks.dense(c, k, (c.conv_size, h, d), c.conv_size)  # noqa: E731
-    ones = partial(jnp.ones, dtype=c.dtype)
-    return {
-        "attn_norm": ones((c.d_model,)),
-        "wq": proj(ks[0]), "wk": proj(ks[1]), "wv": proj(ks[2]),
-        "conv_q": conv(ks[3]), "conv_k": conv(ks[4]), "conv_v": conv(ks[5]),
-        "w_f": proj(ks[6]),
-        "dt_bias": -jax.random.uniform(ks[7], (h, d), minval=1.0, maxval=5.0),
-        "a_log": jnp.log(jax.random.uniform(ks[8], (h,), minval=1.0,
-                                            maxval=16.0)),
-        "w_b": blocks.dense(c, ks[9], (c.d_model, h), c.d_model),
-        "w_g": proj(ks[10]), "o_norm": ones((d,)),
-        "wo": blocks.dense(c, ks[11], (h, d, c.d_model), h * d),
-        "mlp_norm": ones((c.d_model,)),
-    }
 
 
 def init(config: HybridMoeConfig, key) -> Dict[str, Any]:
@@ -247,7 +215,7 @@ def init(config: HybridMoeConfig, key) -> Dict[str, Any]:
     dense, loose, periods, _ = c.plan()
 
     def mixer(key, mla):
-        return mixers.init_mla(c, key) if mla else _init_kda(c, key)
+        return mixers.init_mla(c, key) if mla else mixers.init_kda(c, key)
 
     def dense_layer(key):
         k_mix, *ks = jax.random.split(key, 4)
@@ -289,56 +257,12 @@ def init(config: HybridMoeConfig, key) -> Dict[str, Any]:
 # blocks
 # --------------------------------------------------------------------------
 
-def _short_conv(x, taps):
-    """x [B, S, H, D], taps [K, H, D] -> SiLU of the causal depthwise conv
-    over time: y_t = sum_j taps[j] x_{t - (K - 1) + j}, zeros before 0."""
-    k, s = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0), (0, 0)))
-    y = sum(padded[:, j:j + s].astype(jnp.float32)
-            * taps[j].astype(jnp.float32) for j in range(k))
-    return jax.nn.silu(y)
-
-
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
-def _kda_sublayer(x, p, config: HybridMoeConfig, mesh=None,
-                  rules: Optional[LogicalAxisRules] = None):
-    """x [B, S, D] -> x + KDA(RMSNorm(x)) (the module's docstring)."""
-    c = config
-    d = c.kda_head_dim
-    h = rms_norm(x, p["attn_norm"], c.norm_eps)
-    proj = lambda w: jnp.einsum("bsd,dhk->bshk", h, w)  # noqa: E731
-    heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
-    with jax.named_scope("kda.conv"):
-        q = _l2norm(_short_conv(proj(p["wq"]), p["conv_q"])) * d ** -0.5
-        k = _l2norm(_short_conv(proj(p["wk"]), p["conv_k"]))
-        v = _short_conv(proj(p["wv"]), p["conv_v"])
-    with jax.named_scope("kda.gates"):
-        a = proj(p["w_f"]).astype(jnp.float32) + p["dt_bias"]
-        g = c.kda_lower_bound * jax.nn.sigmoid(
-            jnp.exp(p["a_log"].astype(jnp.float32))[:, None] * a)
-        beta = jax.nn.sigmoid(jnp.einsum(
-            "bsd,dh->bsh", h, p["w_b"], preferred_element_type=jnp.float32))
-        gate = jax.nn.sigmoid(proj(p["w_g"]).astype(jnp.float32))
-    with jax.named_scope("kda.scan"):
-        o = kda_op.kda(
-            *(heads_first(t.astype(c.dtype)) for t in (q, k, v)),
-            heads_first(g), heads_first(beta))
-    o = rms_norm(heads_first(o), p["o_norm"], c.norm_eps)
-    o = (o.astype(jnp.float32) * gate).astype(c.dtype)
-    device_profiler.count("kda.layers", 1)  # per lowering
-    x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    return residual(x, mesh, rules)
-
-
 def layer(x, p, positions, config, mesh, rules, mla: bool, dense: bool):
     """One layer -> (x, the chosen experts [B * S, k] or None)."""
     if mla:
         x = mixers.mla_sublayer(x, p, positions, config, mesh, rules)
     else:
-        x = _kda_sublayer(x, p, config, mesh, rules)
+        x = mixers.kda_sublayer(x, p, config, mesh, rules)
     if dense:
         return blocks.mlp_sublayer(x, p, config, mesh, rules), None
     return experts.expert_sublayer(x, p, config, mesh, rules)

@@ -1,9 +1,13 @@
 """The sequence mixers more than one model module uses, beside `blocks.py`'s
 attention. A mixer moves here when a SECOND model takes it; one model's own
-(KDA in `hybrid_moe.py`, EVA in `evabyte.py`) stays in its module.
+(EVA in `evabyte.py`) stays in its module.
 
 - *MLA*, DeepSeek-V3's latent attention (`mla_moe`, `hybrid_moe`):
   `mla_sublayer`, with `mla_axes`, `init_mla`, `mla_num_params`.
+- *KDA*, Kimi Delta Attention through `ops/kda.py` (`hybrid_moe`,
+  `solar_open2`): `kda_sublayer`, with `kda_axes`, `init_kda`,
+  `kda_num_params`; the decay gate's form, the gates' rank and beta's scale
+  are fields of the caller's config.
 - *Mamba-2* (`nemotron_h`, `granite_hybrid`) through `ops/ssd.py`:
   `mamba_sublayer`. `mixer_axes`, `init_mixer`, `mixer_num_params` give a
   layer of kind `M` (this mixer and its norm) or `*` (`blocks.attn_sublayer`
@@ -23,6 +27,7 @@ import jax.numpy as jnp
 from ray_tpu._private import device_profiler
 from ray_tpu.models import blocks
 from ray_tpu.models.blocks import rms_norm, rope
+from ray_tpu.ops import kda as kda_op
 from ray_tpu.ops import ssd as ssd_op
 from ray_tpu.parallel.sharding import LogicalAxisRules
 
@@ -177,6 +182,148 @@ def mla_sublayer(x, p, positions, config, mesh=None,
     """x [B, S, D] -> x + MLA(RMSNorm(x)) (`mla_mixer`)."""
     h = rms_norm(x, p["attn_norm"], config.norm_eps)
     x = x + mla_mixer(h, p, positions, config, mesh, rotary, scale)
+    return blocks.residual(x, mesh, rules)
+
+
+# --------------------------------------------------------------------------
+# KDA
+# --------------------------------------------------------------------------
+# Kimi Delta Attention (arXiv:2510.26692) through `ops/kda.py`, in the forms
+# its two models publish, each a field of the caller's config:
+# `kda_lower_bound`: the decay gate. A number b < 0 (Ling's `kda_safe_gate`):
+#   g = b x sigmoid(exp(A_log_head) a) in (b, 0); None (Kimi Linear's own,
+#   Solar): g = -exp(A_log_head) x softplus(a), ANY value below 0.
+# `kda_gate_rank`: 0, a = W_f h + dt_bias and the output gate W_g h at full
+#   rank [D, H, d]; r > 0, both through a latent of r (`w_f_down` / `w_g_down`
+#   [D, r], then `w_f` / `w_g` [r, H, d]).
+# `kda_beta_scale`: beta = scale x sigmoid(w_b . h): 1, or 2 where the model
+#   allows I - beta k k^T a negative eigenvalue.
+
+def kda_num_params(c) -> int:
+    """The KDA mixer's parameters (no layer norm)."""
+    hd = c.n_heads * c.kda_head_dim
+    r = c.kda_gate_rank
+    gates = 2 * (c.d_model * r + r * hd) if r else 2 * c.d_model * hd
+    return (4 * c.d_model * hd + gates + c.d_model * c.n_heads
+            + 3 * c.conv_size * hd + c.n_heads + hd + c.kda_head_dim)
+
+
+def kda_axes(c, L):
+    proj = L + ("embed", "heads", "kv")
+    gate = L + (None, "heads", "kv") if c.kda_gate_rank else proj
+    down = {"w_f_down": L + ("embed", None), "w_g_down": L + ("embed", None)} \
+        if c.kda_gate_rank else {}
+    return {
+        "attn_norm": L + (None,), "wq": proj, "wk": proj, "wv": proj,
+        "conv_q": L + (None, "heads", "kv"), "conv_k": L + (None, "heads", "kv"),
+        "conv_v": L + (None, "heads", "kv"),
+        "w_f": gate, "dt_bias": L + ("heads", "kv"), "a_log": L + ("heads",),
+        "w_b": L + ("embed", "heads"), "w_g": gate, **down,
+        "o_norm": L + (None,),
+        "wo": L + ("heads", "kv", "embed"), "mlp_norm": L + (None,),
+    }
+
+
+def init_kda(config, key):
+    """One layer's KDA mixer and its two layer norms. Fan-in scaled normal
+    projections; conv taps N(0, 1 / conv_size); `a_log` = log U(1, 16) a
+    head (flash-linear-attention's). Under the bounded gate `dt_bias` =
+    -U(1, 5) a channel: with a unit-RMS input W_f h is ~N(0, 1), so a head's
+    decay a token runs from none (exp(A_log) 16) to ~0.8 (exp(A_log) 1),
+    inside (-5, 0) always. Under the softplus gate `dt_bias` is the inverse
+    softplus of a step drawn log-uniform in [1e-3, 1e-1]
+    (flash-linear-attention's, Mamba-2's rule): g a token is -1e-3 to -1.6
+    at a = dt_bias, and where W_f h is +3 and exp(A_log) 16 it is -48:
+    no lower bound, and the tail shows at initialisation."""
+    c = config
+    h, d, r = c.n_heads, c.kda_head_dim, c.kda_gate_rank
+    ks = jax.random.split(key, 12)
+    proj = lambda k: blocks.dense(c, k, (c.d_model, h, d), c.d_model)  # noqa: E731
+    conv = lambda k: blocks.dense(c, k, (c.conv_size, h, d), c.conv_size)  # noqa: E731
+    ones = partial(jnp.ones, dtype=c.dtype)
+    gate = (lambda k: blocks.dense(c, k, (r, h, d), r)) if r else proj
+    down = {name: blocks.dense(c, jax.random.fold_in(key, i),
+                               (c.d_model, r), c.d_model)
+            for i, name in enumerate(("w_f_down", "w_g_down"))} if r else {}
+    if c.kda_lower_bound is None:
+        step = jnp.exp(jax.random.uniform(
+            ks[7], (h, d), minval=math.log(1e-3), maxval=math.log(1e-1)))
+        dt_bias = step + jnp.log(-jnp.expm1(-step))
+    else:
+        dt_bias = -jax.random.uniform(ks[7], (h, d), minval=1.0, maxval=5.0)
+    return {
+        "attn_norm": ones((c.d_model,)),
+        "wq": proj(ks[0]), "wk": proj(ks[1]), "wv": proj(ks[2]),
+        "conv_q": conv(ks[3]), "conv_k": conv(ks[4]), "conv_v": conv(ks[5]),
+        "w_f": gate(ks[6]), "dt_bias": dt_bias,
+        "a_log": jnp.log(jax.random.uniform(ks[8], (h,), minval=1.0,
+                                            maxval=16.0)),
+        "w_b": blocks.dense(c, ks[9], (c.d_model, h), c.d_model),
+        "w_g": gate(ks[10]), **down, "o_norm": ones((d,)),
+        "wo": blocks.dense(c, ks[11], (h, d, c.d_model), h * d),
+        "mlp_norm": ones((c.d_model,)),
+    }
+
+
+def _short_conv(x, taps):
+    """x [B, S, H, D], taps [K, H, D] -> SiLU of the causal depthwise conv
+    over time: y_t = sum_j taps[j] x_{t - (K - 1) + j}, zeros before 0."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0), (0, 0)))
+    y = sum(padded[:, j:j + s].astype(jnp.float32)
+            * taps[j].astype(jnp.float32) for j in range(k))
+    return jax.nn.silu(y)
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def kda_sublayer(x, p, config, mesh=None,
+                 rules: Optional[LogicalAxisRules] = None):
+    """x [B, S, D] -> x + W_o [RMSNorm_head(KDA(q, k, v, g, beta)) x
+    sigmoid(gate)] of h = RMSNorm(x): q, k, v = W h, each channel through a
+    causal depthwise conv of `conv_size` taps, then SiLU; q = l2norm(q) /
+    sqrt(d), k = l2norm(k); g, beta and the gate in the config's forms
+    (above), a projection at low rank where the layer has its `_down`
+    matrix. No RoPE. `ops/kda.kda` is told what the gate guarantees of g
+    (`kda_lower_bound`) and picks its plan from it."""
+    c = config
+    d = c.kda_head_dim
+    h = rms_norm(x, p["attn_norm"], c.norm_eps)
+    proj = lambda w: jnp.einsum("bsd,dhk->bshk", h, w)  # noqa: E731
+
+    def gate_proj(name):
+        if name + "_down" not in p:
+            return proj(p[name])
+        with jax.named_scope("kda.gate_lora"):
+            return jnp.einsum("bsr,rhk->bshk", h @ p[name + "_down"], p[name])
+
+    heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
+    with jax.named_scope("kda.conv"):
+        q = _l2norm(_short_conv(proj(p["wq"]), p["conv_q"])) * d ** -0.5
+        k = _l2norm(_short_conv(proj(p["wk"]), p["conv_k"]))
+        v = _short_conv(proj(p["wv"]), p["conv_v"])
+    with jax.named_scope("kda.gates"):
+        a = gate_proj("w_f").astype(jnp.float32) + p["dt_bias"]
+        rate = jnp.exp(p["a_log"].astype(jnp.float32))[:, None]
+        if c.kda_lower_bound is None:
+            g = -rate * jax.nn.softplus(a)
+        else:
+            g = c.kda_lower_bound * jax.nn.sigmoid(rate * a)
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", h, p["w_b"], preferred_element_type=jnp.float32))
+        if c.kda_beta_scale != 1.0:
+            beta = c.kda_beta_scale * beta
+        gate = jax.nn.sigmoid(gate_proj("w_g").astype(jnp.float32))
+    with jax.named_scope("kda.scan"):
+        o = kda_op.kda(
+            *(heads_first(t.astype(c.dtype)) for t in (q, k, v)),
+            heads_first(g), heads_first(beta), g_min=c.kda_lower_bound)
+    o = rms_norm(heads_first(o), p["o_norm"], c.norm_eps)
+    o = (o.astype(jnp.float32) * gate).astype(c.dtype)
+    device_profiler.count("kda.layers", 1)  # per lowering
+    x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return blocks.residual(x, mesh, rules)
 
 

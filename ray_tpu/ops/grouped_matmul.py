@@ -31,9 +31,13 @@ def _gmm_tiling(k: int, n: int):
     matrix where it is at most 4 MiB of bf16 (the cell's are exactly that),
     so the contraction needs no accumulation across grid steps and each
     expert's weights are read once. Fastest of twenty candidates for both
-    of the cell's shapes, transposed or not."""
+    of the cell's shapes, transposed or not. Where K is no power of two
+    (an expert of 1,280: 2 ** 21 // 1280 = 1638) the column tile is
+    rounded down to whole lanes, which Mosaic asks of a block that is not
+    the whole array."""
     tk = min(k, 2048)
-    return 256, tk, min(n, 2 ** 21 // tk)
+    tn = 2 ** 21 // tk
+    return 256, tk, n if n <= tn else tn // 128 * 128
 
 
 # 256 rows of a group a step into a [1024, 1024] tile of its [K, N] output

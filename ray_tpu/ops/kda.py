@@ -1,14 +1,19 @@
 """Kimi Delta Attention: the gated delta rule with a decay per channel.
 
 Per (batch, head), a state S in R^{d_k x d_v}, float32, S_0 = 0, and per
-token t (q, k [d_k], v [d_v], g [d_k] <= 0 the log decay, beta in (0, 1)):
+token t (q, k [d_k], v [d_v], g [d_k] <= 0 the log decay, beta in (0, 2):
+a caller's own range is its model's, (0, 1) where beta = sigmoid (Ling),
+(0, 2) where beta = 2 x sigmoid and I - beta k k^T may have an eigenvalue
+below 0 (Solar); for unit k a step contracts the state either way):
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
 `kda_recurrence` is that definition, token by token (tests, chip checks).
-`kda(q, k, v, g, beta) -> o`, all `[B, H, S, D]` (beta `[B, H, S]`), is its
-chunked form (chunk `CHUNK` = 64), a `custom_vjp`:
+`kda(q, k, v, g, beta, g_min=) -> o`, all `[B, H, S, D]` (beta `[B, H, S]`),
+is its chunked form (chunk `CHUNK` = 64), a `custom_vjp`; `g_min` says what
+the caller's model guarantees of g and picks the plan the scores A and B are
+formed by (THE CONTRACT, below):
 
     G_t = sum_{i <= t} g_i inside the chunk (float32), H the chunk's
     incoming state. With u~_t = beta_t (v_t - (Diag(a_t) S_{t-1})^T k_t) the
@@ -24,11 +29,26 @@ chunked form (chunk `CHUNK` = 64), a `custom_vjp`:
 Nothing is divided by a cumulative decay (with g down to -5 a step a
 chunk's reaches e^-320). exp(G_t - G_i) is not a product of a row's and a
 column's factor that both stay finite over 64 tokens, so A and B are formed
-a SUB-block (16 rows) at a time around a reference r_I, the cumulative decay
-at the block's middle: rows carry exp(G_t - r_own), within e^+-40 for
-g >= -5, columns exp(min(r_I - G_i, 44)): at most 1 for tokens before the
-block, within e^40 inside it, and capped (masked anyway) after it. THE
-CONTRACT: g in [-5, 0] a step, (SUB / 2) x 5 = 40 < `_EXP_CAP`.
+around REFERENCES, by one of two plans. THE CONTRACT: `g_min` is a lower
+bound on every g a step that the caller's MODEL guarantees, or None.
+
+- The BOUNDED plan, for `g_min` >= -5 (`G_MIN_BOUNDED`; Ling's safe gate):
+  a SUB-block (16 rows) at a time around a reference r_I, the cumulative
+  decay at the block's middle: rows carry exp(G_t - r_own), within e^+-40
+  for g >= -5, columns exp(min(r_I - G_i, 44)): at most 1 for tokens before
+  the block, within e^40 inside it, and capped (masked anyway) after it:
+  (SUB / 2) x 5 = 40 < `_EXP_CAP`. Four products a chunk. A g below the
+  bound the caller gave overflows float32 after a few tokens; nothing looks.
+- The ANY-DECAY plan, for any other `g_min` and for None (Kimi Linear's
+  softplus gate, Solar): by HALVING (`_halved_scores`). Level s = 32, 16,
+  .., 1 serves the pairs t > i that lie in the upper and the lower half of
+  one block of 2s, around the lower half's last token: exp(G_t - G_i) =
+  exp(G_t - G_ref) exp(G_ref - G_i), BOTH exponents <= 0 whatever g is, each
+  a sum of g itself over a window (`_windows`), so nothing overflows and an
+  underflow to 0 is the right answer. Six masked products a chunk, no cap,
+  no clamp, forward and in both backward walks; measured on the v5e at
+  [1, 64, 8192, 128]: 1.48x the bounded plan's three kernels (PERF.md
+  section 6, PR 64).
 
 Matmul operands are rounded to the inputs' dtype (bf16 in the model) with
 float32 accumulation, except the solve, which is float32 throughout; the
@@ -62,6 +82,8 @@ SUB = 16
 # over (SUB / 2) tokens x |g| <= 5 = 40, which g = -5 throughout reaches
 # exactly: at a tie `minimum` hands its gradient half to each side
 _EXP_CAP = 44.0
+# the least g a step the bounded plan takes: (SUB / 2) x 5 = 40 < _EXP_CAP
+G_MIN_BOUNDED = -5.0
 _SOLVE_BASE = 8
 # (batch, head) rows a grid step: their chunks are independent chains of
 # small dependent matmuls, and the MXU waits less the more of them a step
@@ -130,9 +152,18 @@ def _solve(a, t_pos, i_pos, mm):
     6, PR 39: that seed's loss went NaN). The inverse itself is tame:
     T Diag(beta)
     maps v to u~, and |u~_t| <= |v_t| + sum_{i < t} |v_i| because every
-    step contracts the state. Here a block's powers stop at d^7, entries at
-    most C(6, 3) = 20, and the pairing multiplies inverses, whose entries
-    stay of order 1."""
+    step contracts the state (|1 - beta (k . k)| <= 1 for beta in (0, 2) and
+    unit k). Here a block's powers stop at d^7. At beta <= 1 their entries
+    are at most C(6, 3) = 20 (d^4 of the all-ones block); at beta < 2 they
+    carry beta^n more, 16 x 20 = 320 in d^4 and 2^7 in d^7's one entry,
+    while (I + beta L)^-1 = (I - N)(I - (1 - beta) N)^-1 (N the shift, L the
+    all-ones strictly lower block) has entries of at most 2: the product
+    cancels 320 down to 2, which costs under 8 of float32's 24 bits. The
+    pairing multiplies inverses, whose entries stay of order 1 (2 at beta
+    2): sums of 8 to 32 terms of at most 8. Held on the chip at beta
+    1.9-1.999 on aligned keys (`tools/kda_chip_check.py`,
+    `negative_eigenvalues`), where the product of the chunk's powers is
+    NaN."""
     c = a.shape[-2]
     issued = math.prod(a.shape[:-2])
 
@@ -171,10 +202,12 @@ def _chunked(x, n):
     return x.reshape(x.shape[:2] + (n, CHUNK) + x.shape[3:])
 
 
-def _kda_chunked(q, k, v, g, beta):
+def _kda_chunked(q, k, v, g, beta, bounded=True):
     """The chunked form in `jnp` -> (o [B, H, S, d_v] in v.dtype, the final
     state float32). S is padded to a multiple of the chunk with tokens that
-    leave the state as it is (g = 0, beta = 0)."""
+    leave the state as it is (g = 0, beta = 0). The scores under the
+    bounded plan's sub-block references, or (`bounded` False) the kernels'
+    own `_halved_scores` on the chunks as rows."""
     dtype = q.dtype
     b, h, s, d_k = q.shape
     pad = -s % CHUNK
@@ -186,20 +219,33 @@ def _kda_chunked(q, k, v, g, beta):
     f32 = jnp.float32
     qc, kc, vc = (_chunked(x, n).astype(f32) for x in (q, k, v))
     beta = _chunked(beta.astype(f32), n)
-    cum = jnp.cumsum(_chunked(g.astype(f32), n), axis=-2)
-    own, refs = _references(cum)
-    row = jnp.exp(cum - own)
-    # [B, H, N, blocks, C, D]: the columns as each sub-block of rows sees them
-    col = kc[..., None, :, :] * jnp.exp(jnp.minimum(
-        refs - cum[..., None, :, :], _EXP_CAP))
-    blocks = lambda x: x.reshape(x.shape[:3] + (CHUNK // SUB, SUB, d_k))  # noqa: E731
-    scores = lambda rows: _mm(  # noqa: E731
-        "bhnitd,bhnijd->bhnitj", blocks(rows * row), col, dtype).reshape(
-            rows.shape[:3] + (CHUNK, CHUNK))
-    t_pos = jnp.arange(CHUNK)[:, None]
-    i_pos = jnp.arange(CHUNK)[None, :]
-    a = jnp.where(t_pos > i_pos, scores(kc), 0.0) * beta[..., None]
-    qk = jnp.where(t_pos >= i_pos, scores(qc), 0.0)
+    if bounded:
+        cum = jnp.cumsum(_chunked(g.astype(f32), n), axis=-2)
+        own, refs = _references(cum)
+        row = jnp.exp(cum - own)
+        # [B, H, N, blocks, C, D]: the columns as each sub-block of rows
+        # sees them
+        col = kc[..., None, :, :] * jnp.exp(jnp.minimum(
+            refs - cum[..., None, :, :], _EXP_CAP))
+        blocks = lambda x: x.reshape(  # noqa: E731
+            x.shape[:3] + (CHUNK // SUB, SUB, d_k))
+        scores = lambda rows: _mm(  # noqa: E731
+            "bhnitd,bhnijd->bhnitj", blocks(rows * row), col, dtype).reshape(
+                rows.shape[:3] + (CHUNK, CHUNK))
+        t_pos = jnp.arange(CHUNK)[:, None]
+        i_pos = jnp.arange(CHUNK)[None, :]
+        a = jnp.where(t_pos > i_pos, scores(kc), 0.0) * beta[..., None]
+        qk = jnp.where(t_pos >= i_pos, scores(qc), 0.0)
+    else:
+        rows = lambda x: x.reshape((-1,) + x.shape[3:])  # noqa: E731
+        t = _halved_scores(
+            rows(qc), rows(kc), rows(_chunked(g.astype(f32), n)),
+            rows(beta)[:, None, :], _mm_in(dtype), _window_sums_linear)
+        back = lambda x: x.reshape(kc.shape[:3] + x.shape[1:])  # noqa: E731
+        cum, qk = back(t["cum"]), back(t["qk"])
+        a = back(t["araw"]) * beta[..., None]
+        t_pos = jnp.arange(CHUNK)[:, None]
+        i_pos = jnp.arange(CHUNK)[None, :]
     t = _solve(a, t_pos, i_pos, partial(jnp.matmul, precision=_HIGHEST)) \
         * beta[..., None, :]
     u = _mm("bhnti,bhniv->bhntv", t, vc, dtype)
@@ -258,13 +304,17 @@ def _as_row(column, eye):
     return jnp.sum(jnp.where(eye, column, 0.0), axis=1, keepdims=True)
 
 
-def _chunk_terms(q, k, g, beta_row, mm):
+def _chunk_terms(q, k, g, beta_row, mm, bounded=True):
     """What each kernel forms first of a chunk of R rows: q (or None), k,
     g [R, C, D] float32, beta_row [R, 1, C] -> a dict of the cumulative log
-    decay `cum`, the sub-block factors (`row` [R, C, D], `cols`: k under
-    each sub-block's column factor, `colfac`: the factors), the masked
-    score matrices `araw` (k k^T under the decay, strictly lower) and `qk`
-    (q k^T, lower) and `beta_col` [R, C, 1]."""
+    decay `cum`, the masked score matrices `araw` (k k^T under the decay,
+    strictly lower) and `qk` (q k^T, lower), `beta_col` [R, C, 1] and what
+    the backward walk takes the scores' gradient back through: under the
+    BOUNDED plan the sub-block factors (`row` [R, C, D], `cols`: k under
+    each sub-block's column factor, `colfac`: the factors), under the
+    any-decay plan `_halved_scores`' levels."""
+    if not bounded:
+        return _halved_scores(q, k, g, beta_row, mm)
     f32 = jnp.float32
     r, c, d = k.shape
     t_pos = jax.lax.broadcasted_iota(jnp.int32, (r, c, c), 1)
@@ -301,6 +351,137 @@ def _chunk_terms(q, k, g, beta_row, mm):
         else jnp.where(t_pos >= i_pos, scores[:, c:], 0.0))
 
 
+def _level_sizes():
+    """The halving plan's levels: the half-sizes 32, 16, .., 1 of a chunk."""
+    return [CHUNK >> n for n in range(1, CHUNK.bit_length())]
+
+
+def _windows(t_pos, j_pos):
+    """The 0/1 matrices [.., (1 + levels) C, C], stacked over rows, whose
+    product with a chunk's g [C, D] is the cumulative log decay and then
+    every level's exponent (`_halved_scores`): a token t in the upper half
+    of its block of 2s reads sum_{ref < j <= t} g_j = G_t - G_ref, ref the
+    lower half's last token; a token i of the lower half sum_{i < j <= ref}
+    g_j = G_ref - G_i. Sums of g itself: no difference of two cumulative
+    sums, no sign to get wrong."""
+    parts = [t_pos >= j_pos]
+    for s in _level_sizes():
+        ref = t_pos // (2 * s) * (2 * s) + s - 1
+        parts.append(((j_pos > ref) & (j_pos <= t_pos))
+                     | ((j_pos > t_pos) & (j_pos <= ref)))
+    return jnp.concatenate(parts, axis=-2).astype(jnp.bfloat16)
+
+
+def _window_sums(windows, x, dim):
+    """`windows` (0 / 1) contracted over its axis 1 + `dim` with x [R, ., D]
+    float32, to float32's accuracy in THREE bf16 passes of the MXU where
+    `precision=HIGHEST` takes six: x is split into three bf16 parts that
+    add up to it (8 + 8 + 8 bits of mantissa), a 0 / 1 weight is exact in
+    bf16 and the accumulation is float32."""
+    bf16, out = jnp.bfloat16, None
+    for _ in range(3):
+        part = x.astype(bf16)
+        term = jax.lax.dot_general(
+            windows, part, (((1 + dim,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        x = x - part.astype(jnp.float32)
+        out = term if out is None else out + term
+    return out
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _window_sums_linear(windows, x, dim):
+    """`_window_sums` for the `jnp` form, which XLA transposes: left to
+    autodiff, the split's casts would hand the cotangent through the first
+    bf16 part alone. The transpose of a window sum is a window sum."""
+    return _window_sums(windows, x, dim)
+
+
+_window_sums_linear.defvjp(
+    lambda windows, x, dim: (_window_sums(windows, x, dim), windows),
+    lambda dim, windows, ct: (jnp.zeros_like(windows),
+                              _window_sums(windows, ct, 1 - dim)))
+
+
+def _level_mask(s, t_pos, i_pos):
+    """Level s's pairs: t in the upper half and i in the lower half of ONE
+    block of 2s. Over the levels every t > i is in exactly one mask: the
+    level of the highest bit in which t and i differ."""
+    return (t_pos // s - i_pos // s == 1) & (t_pos // s % 2 == 1)
+
+
+def _halved_scores(q, k, g, beta_row, mm, window_sums=_window_sums):
+    """`_chunk_terms` for ANY g <= 0: A[t, i] and B[t, i] from factors
+    whose exponents are all <= 0, so that nothing overflows and an
+    underflow to 0 is the right answer. exp(G_t - G_i) = exp(G_t - G_ref)
+    exp(G_ref - G_i) for any ref between i and t; by HALVING, level s (32,
+    16, .., 1) serves the pairs whose t is in the upper and i in the lower
+    half of one block of 2s, with ref the lower half's last token: ONE
+    factor a token and level, e = exp(`_windows` . g) in (0, 1] (a row's
+    factor where the token is in an upper half, a column's where in a
+    lower), one product (x * e)(k * e)^T a level over the whole chunk,
+    selected by the level's mask (what it forms elsewhere is finite and
+    dropped), log2(C) = 6 products where the bounded plan has C / SUB = 4.
+    The diagonal of B is q_t . k_t, no decay. Also kept: `sums`' exponents'
+    factors `es` and the stacked rows `x` (k's, then q's), for the backward
+    walk (`_halved_scores_back`)."""
+    f32 = jnp.float32
+    r, c, d = k.shape
+    t_pos = jax.lax.broadcasted_iota(jnp.int32, (r, c, c), 1)
+    i_pos = jax.lax.broadcasted_iota(jnp.int32, (r, c, c), 2)
+    eye = t_pos == i_pos
+    # the masks are the same for every row of the grid step: formed once,
+    # [1, ., .], and broadcast
+    t_one = jax.lax.broadcasted_iota(jnp.int32, (1, c, c), 1)
+    i_one = jax.lax.broadcasted_iota(jnp.int32, (1, c, c), 2)
+    windows = jnp.broadcast_to(_windows(t_one, i_one),
+                               (r, (1 + len(_level_sizes())) * c, c))
+    sums = window_sums(windows, g, 1)                            # [R, 7C, D]
+    x = k if q is None else jnp.concatenate([k, q], axis=1)
+    both = (lambda a: a) if q is None else \
+        (lambda a: jnp.concatenate([a, a], axis=1))
+    es = []
+    scores = jnp.zeros(x.shape[:2] + (c,), f32)
+    for n, s in enumerate(_level_sizes()):
+        e = jnp.exp(sums[:, (n + 1) * c:(n + 2) * c])
+        mask = both(_level_mask(s, t_one, i_one))
+        scores = jnp.where(mask, mm(x * both(e), k * e, 1, 1), scores)
+        # per lowering: the C x C score products a chunk issues
+        device_profiler.count("kda.halving_products", r * (x.shape[1] // c))
+        es.append(e)
+    qk = None
+    if q is not None:
+        qk = jnp.where(eye, jnp.sum(q * k, axis=2, keepdims=True),
+                       scores[:, c:])
+    return dict(
+        cum=sums[:, :c], t_pos=t_pos, i_pos=i_pos, eye=eye, windows=windows,
+        t_one=t_one, i_one=i_one,
+        es=es, x=x, beta_col=_column(beta_row, eye),
+        araw=scores[:, :c], qk=qk)
+
+
+def _halved_scores_back(t, d_scores, q, k, mm):
+    """The gradient d_scores [R, 2C, C] (A's rows, then B's) back through
+    `_halved_scores` -> (dq, dk [R, C, D], d_sums [R, 6C, D]: the gradient
+    of every level's exponent, in `_windows`' order after `cum`)."""
+    c = k.shape[1]
+    d_diag = jnp.sum(jnp.where(t["eye"], d_scores[:, c:], 0.0), axis=2,
+                     keepdims=True)
+    d_q, d_k, d_sums = d_diag * k, d_diag * q, []
+    for s, e in zip(_level_sizes(), t["es"]):
+        mask = _level_mask(s, t["t_one"], t["i_one"])
+        block = jnp.where(jnp.concatenate([mask, mask], axis=1), d_scores,
+                          0.0)                                   # [R, 2C, C]
+        rows, cols = t["x"] * jnp.concatenate([e, e], axis=1), k * e
+        d_rows = mm(block, cols, 1, 0)                           # [R, 2C, D]
+        d_cols = mm(block, rows, 0, 0)                           # [R, C, D]
+        d_k = d_k + (d_rows[:, :c] + d_cols) * e
+        d_q = d_q + d_rows[:, c:] * e
+        d_rows = d_rows * rows
+        d_sums.append(d_rows[:, :c] + d_rows[:, c:] + d_cols * cols)
+    return d_q, d_k, jnp.concatenate(d_sums, axis=1)
+
+
 def _pair_products(x, y, mm):
     """x = [X1 | X2], y = [Y1 | Y2], [P, C, 2C] each -> [X1 Y1 | X2 Y2] in
     ONE product `mm` a pair: y's halves go onto the diagonal of a [2C, 2C]
@@ -332,10 +513,10 @@ def _solve_rows(a, t_pos, i_pos, mm):
     return jnp.concatenate([m[:, :, :c], m[:, :, c:]], axis=0)
 
 
-def _chunk_forward(q, k, v, g, beta_row, state, mm):
+def _chunk_forward(q, k, v, g, beta_row, state, mm, bounded=True):
     """One chunk -> (o or None where q is, U~, (I + A)^-1, the next
     state), all float32."""
-    t = _chunk_terms(q, k, g, beta_row, mm)
+    t = _chunk_terms(q, k, g, beta_row, mm, bounded)
     c, d = k.shape[1:]
     cum = t["cum"]
     m = _solve_rows(t["araw"] * t["beta_col"], t["t_pos"], t["i_pos"], mm)
@@ -357,10 +538,12 @@ def _decay_column(last):
     return _column(jnp.exp(last), _eye(r, d))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref, *,
+                bounded=True):
     """One chunk of R (batch, head) rows (`_specs`): refs [R, C, D],
     beta [R, N, C] (the row's chunks, this step's picked by the grid's
-    index), the state [R, d_k, d_v] float32 resident over the chunk axis."""
+    index), the state [R, d_k, d_v] float32 resident over the chunk axis.
+    `bounded`, here and in the two kernels below: the scores' plan."""
     from jax.experimental import pallas as pl
 
     n = pl.program_id(1)
@@ -373,13 +556,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state_ref):
     q, k, v = (r[...].astype(f32) for r in (q_ref, k_ref, v_ref))
     beta_row = beta_ref[:, pl.ds(n, 1), :].astype(f32)           # [R, 1, C]
     o, _, _, state = _chunk_forward(
-        q, k, v, g_ref[...], beta_row, state_ref[...], _mm_in(q_ref.dtype))
+        q, k, v, g_ref[...], beta_row, state_ref[...], _mm_in(q_ref.dtype),
+        bounded)
     o_ref[...] = o.astype(o_ref.dtype)
     state_ref[...] = state
 
 
 def _states_kernel(k_ref, v_ref, g_ref, beta_ref, new_ref, m_ref, h_ref,
-                   state_ref):
+                   state_ref, *, bounded=True):
     """The backward pass's first walk, forwards: what the second needs of
     every chunk and cannot form alone: U~ (`new`, in v's dtype: it only
     ever enters a matmul), (I + A)^-1 (float32 [R, C, C]: the ten exact
@@ -399,7 +583,7 @@ def _states_kernel(k_ref, v_ref, g_ref, beta_ref, new_ref, m_ref, h_ref,
     state = state_ref[...]
     h_ref[...] = state
     _, new, m, state = _chunk_forward(
-        None, k, v, g_ref[...], beta_row, state, _mm_in(k_ref.dtype))
+        None, k, v, g_ref[...], beta_row, state, _mm_in(k_ref.dtype), bounded)
     new_ref[...] = new.astype(new_ref.dtype)
     m_ref[...] = m
     state_ref[...] = state
@@ -407,7 +591,7 @@ def _states_kernel(k_ref, v_ref, g_ref, beta_ref, new_ref, m_ref, h_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, new_ref, m_ref,
                 h_ref, dstate_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                dh_ref):
+                dh_ref, *, bounded=True):
     """The second walk, BACKWARDS over the chunks (the grid's step j is
     chunk N - 1 - j): the cotangent of the state, `dh_ref` (scratch,
     float32 [R, d_k, d_v]), is carried from a chunk to the one before it.
@@ -424,7 +608,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, new_ref, m_ref,
     and the score matrices' gradients go back to q, k and G a sub-block of
     rows at a time through the same row and column factors that formed
     them (the references cancel: no term of theirs). dg is the reversed
-    cumulative sum of dG, exact."""
+    cumulative sum of dG, exact. Under the any-decay plan the scores'
+    gradients go back a LEVEL at a time (`_halved_scores_back`), and dg is
+    `_windows`' transpose on the gradients of G and of every level's
+    exponent, in one product (`_window_sums`)."""
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
@@ -441,9 +628,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, new_ref, m_ref,
     new = new_ref[...].astype(f32)
     m, h, dh = m_ref[...], h_ref[...], dh_ref[...]
     beta_row = beta_ref[:, pl.ds(n, 1), :].astype(f32)           # [R, 1, C]
-    t = _chunk_terms(q, k, g_ref[...], beta_row, mm)
+    t = _chunk_terms(q, k, g_ref[...], beta_row, mm, bounded)
     r, c, d = k.shape
-    cum, row, rows, eye = t["cum"], t["row"], t["rows"], t["eye"]
+    cum, eye = t["cum"], t["eye"]
     t_pos, i_pos = t["t_pos"], t["i_pos"]
     decayed = jnp.exp(cum)
     last = cum[:, c - 1:c, :]
@@ -471,6 +658,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, new_ref, m_ref,
     dbeta_ref[:, pl.ds(n, 1), :] = d_beta
 
     d_scores = jnp.concatenate([d_a * t["beta_col"], d_qk], axis=1)
+    if not bounded:
+        # (`d_last` and `token` as the bounded plan forms them further down:
+        # its trace keeps its order, so they are not shared)
+        d_last = jnp.sum(d_k_out * k_out, axis=1, keepdims=True) \
+            + _as_row(d_decay, _eye(r, d))
+        token = jax.lax.broadcasted_iota(jnp.int32, (r, c, d), 1)
+        d_q, d_k, d_sums = _halved_scores_back(t, d_scores, q, k, mm)
+        dq_ref[...] = (d_q + d_q_in * decayed).astype(dq_ref.dtype)
+        dk_ref[...] = (d_k + d_k_in * decayed
+                       + d_k_out * left).astype(dk_ref.dtype)
+        d_cum = d_q_in * q_in + d_k_in * k_in - d_k_out * k_out \
+            + jnp.where(token == c - 1, d_last, 0.0)
+        dg_ref[...] = _window_sums(
+            t["windows"], jnp.concatenate([d_cum, d_sums], axis=1), 0)
+        return
+    row, rows = t["row"], t["rows"]
     d_rows = jnp.zeros((r, 2 * c, d), f32)
     d_k = jnp.zeros((r, c, d), f32)
     d_cum = jnp.zeros((r, c, d), f32)
@@ -521,15 +724,28 @@ def _specs(rows, n, reverse=False):
     return per, walked, held
 
 
-def _params():
+def _kernel(kernel, bounded):
+    """`kernel` under its plan, counted per lowering: `kda.kernels`, and
+    `kda.kernels_any_decay` those of the any-decay plan. The bounded plan's
+    is the function itself: its name, and so its lowering, as before."""
+    device_profiler.count("kda.kernels", 1)
+    device_profiler.count("kda.kernels_any_decay", 0 if bounded else 1)
+    return kernel if bounded else partial(kernel, bounded=False)
+
+
+def _params(bounded=True):
     from jax.experimental.pallas import tpu as pltpu
 
+    # the any-decay backward walk keeps six levels' factors beside what the
+    # bounded one keeps: 16.9 MiB of stack at 8 rows a step, over the
+    # compiler's default of 16 (a v5e's VMEM is 128 MiB)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
+        dimension_semantics=("parallel", "arbitrary"),
+        **({} if bounded else {"vmem_limit_bytes": 32 * 2 ** 20}))
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _kda_fwd_pallas(q, k, v, g, beta, interpret=False):
+@partial(jax.jit, static_argnames=("interpret", "bounded"))
+def _kda_fwd_pallas(q, k, v, g, beta, interpret=False, bounded=True):
     """-> (o [B, H, S, d_v] in v.dtype, the final state [B, H, d_k, d_v]
     float32): the grid walks (groups of batch x head rows, chunks), the
     chunk axis in sequence."""
@@ -541,22 +757,23 @@ def _kda_fwd_pallas(q, k, v, g, beta, interpret=False):
     rows = b * h
     per, walked, held = _specs(rows, n)
     o, state = pl.pallas_call(
-        _fwd_kernel,
+        _kernel(_fwd_kernel, bounded),
         grid=(rows // per, n),
         in_specs=[walked(d_k), walked(d_k), walked(d_v), walked(d_k),
                   held(n, CHUNK)],
         out_specs=[walked(d_v), held(d_k, d_v)],
         out_shape=[jax.ShapeDtypeStruct((rows, n * CHUNK, d_v), v.dtype),
                    jax.ShapeDtypeStruct((rows, d_k, d_v), jnp.float32)],
-        compiler_params=_params(),
+        compiler_params=_params(bounded),
         interpret=interpret,
     )(*args)
     return (o.reshape(b, h, n * CHUNK, d_v)[:, :, :s],
             state.reshape(b, h, d_k, d_v))
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _kda_bwd_pallas(q, k, v, g, beta, do, dstate, interpret=False):
+@partial(jax.jit, static_argnames=("interpret", "bounded"))
+def _kda_bwd_pallas(q, k, v, g, beta, do, dstate, interpret=False,
+                    bounded=True):
     """The five gradients from TWO calls: `_states_kernel` forwards, then
     `_bwd_kernel` backwards. Their outputs are three-dim arrays, (bf16,
     f32, f32) and (bf16, bf16, bf16, f32, f32): no flash kernel's and not
@@ -572,7 +789,7 @@ def _kda_bwd_pallas(q, k, v, g, beta, do, dstate, interpret=False):
     shaped = jax.ShapeDtypeStruct
     per, walked, held = _specs(rows, n)
     new, m, states = pl.pallas_call(
-        _states_kernel,
+        _kernel(_states_kernel, bounded),
         grid=(rows // per, n),
         in_specs=[walked(d_k), walked(d_v), walked(d_k), held(n, CHUNK)],
         out_specs=[walked(d_v), walked(CHUNK), walked(d_v, d_k)],
@@ -580,12 +797,12 @@ def _kda_bwd_pallas(q, k, v, g, beta, do, dstate, interpret=False):
                    shaped((rows, n * CHUNK, CHUNK), f32),
                    shaped((rows, n * d_k, d_v), f32)],
         scratch_shapes=[pltpu.VMEM((per, d_k, d_v), f32)],
-        compiler_params=_params(),
+        compiler_params=_params(bounded),
         interpret=interpret,
     )(k_, v_, g_, beta_)
     per, walked, held = _specs(rows, n, reverse=True)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        _bwd_kernel,
+        _kernel(_bwd_kernel, bounded),
         grid=(rows // per, n),
         in_specs=[walked(d_k), walked(d_k), walked(d_v), walked(d_k),
                   held(n, CHUNK), walked(d_v), walked(d_v), walked(CHUNK),
@@ -598,7 +815,7 @@ def _kda_bwd_pallas(q, k, v, g, beta, do, dstate, interpret=False):
                    shaped((rows, n * CHUNK, d_k), f32),
                    shaped((rows, n, CHUNK), f32)],
         scratch_shapes=[pltpu.VMEM((per, d_k, d_v), f32)],
-        compiler_params=_params(),
+        compiler_params=_params(bounded),
         interpret=interpret,
     )(q_, k_, v_, g_, beta_, do_, new, m, states,
       dstate.astype(f32).reshape(rows, d_k, d_v))
@@ -611,42 +828,56 @@ def _kda_bwd_pallas(q, k, v, g, beta, do, dstate, interpret=False):
 # the call
 # --------------------------------------------------------------------------
 
-def _forward(q, k, v, g, beta, use_pallas, interpret):
+def _forward(q, k, v, g, beta, use_pallas, interpret, bounded):
     n_chunks = -(-q.shape[2] // CHUNK)
     device_profiler.count("kda.chunks", n_chunks)  # per lowering
     if use_pallas or interpret:
-        return _kda_fwd_pallas(q, k, v, g, beta, interpret=interpret)
-    return _kda_chunked(q, k, v, g, beta)
+        return _kda_fwd_pallas(q, k, v, g, beta, interpret=interpret,
+                               bounded=bounded)
+    return _kda_chunked(q, k, v, g, beta, bounded)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _kda(q, k, v, g, beta, use_pallas, interpret):
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda(q, k, v, g, beta, use_pallas, interpret, bounded=True):
     """-> (o, the final state [B, H, d_k, d_v] float32): `kda` less its
     defaults (the tests read the state and hand it a cotangent)."""
-    return _forward(q, k, v, g, beta, use_pallas, interpret)
+    return _forward(q, k, v, g, beta, use_pallas, interpret, bounded)
 
 
-def _kda_fwd_rule(q, k, v, g, beta, use_pallas, interpret):
-    o, state = _forward(q, k, v, g, beta, use_pallas, interpret)
+def _kda_fwd_rule(q, k, v, g, beta, use_pallas, interpret, bounded):
+    o, state = _forward(q, k, v, g, beta, use_pallas, interpret, bounded)
     return (checkpoint_name(o, RESIDUAL_NAMES[0]), state), (q, k, v, g, beta)
 
 
-def _kda_bwd_rule(use_pallas, interpret, res, cotangents):
+def _kda_bwd_rule(use_pallas, interpret, bounded, res, cotangents):
     """On a TPU the two backward kernels; elsewhere XLA's transpose of the
     chunked arithmetic."""
     if use_pallas or interpret:
-        return _kda_bwd_pallas(*res, *cotangents, interpret=interpret)
-    return jax.vjp(_kda_chunked, *res)[1](cotangents)
+        return _kda_bwd_pallas(*res, *cotangents, interpret=interpret,
+                               bounded=bounded)
+    return jax.vjp(partial(_kda_chunked, bounded=bounded), *res)[1](
+        cotangents)
 
 
 _kda.defvjp(_kda_fwd_rule, _kda_bwd_rule)
 
 
-def kda(q, k, v, g, beta, *, use_pallas=None, interpret=False):
-    """q, k [B, H, S, d_k], v [B, H, S, d_v], g [B, H, S, d_k] (log decay,
-    in [-5, 0]), beta [B, H, S] -> o [B, H, S, d_v] in v.dtype.
+def plan_is_bounded(g_min) -> bool:
+    """Which plan a caller's guarantee `g_min` picks (`kda`)."""
+    return g_min is not None and g_min >= G_MIN_BOUNDED
+
+
+def kda(q, k, v, g, beta, *, g_min=None, use_pallas=None, interpret=False):
+    """q, k [B, H, S, d_k], v [B, H, S, d_v], g [B, H, S, d_k] (log decay a
+    step, <= 0), beta [B, H, S] (in (0, 2)) -> o [B, H, S, d_v] in v.dtype.
+    `g_min` (static) is what the caller's MODEL guarantees of g: a lower
+    bound on every g, or None where it has none. A bound of -5 or above
+    takes the bounded plan, anything else the any-decay plan (the module's
+    docstring, THE CONTRACT); a g below a bound the caller gave is the
+    caller's breach, and nothing here looks.
     `use_pallas=None`: the Pallas kernels on a TPU, `jnp` elsewhere
     (`interpret=True` runs the kernels in the Pallas interpreter)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu" and not interpret
-    return _kda(q, k, v, g, beta, bool(use_pallas), bool(interpret))[0]
+    return _kda(q, k, v, g, beta, bool(use_pallas), bool(interpret),
+                plan_is_bounded(g_min))[0]

@@ -47,6 +47,19 @@ KDA_TENSORS = ("o", "state", "dq", "dk", "dv", "dg", "dbeta")
 # section 6, PR 39)
 REGIMES = {"g_near_0": (-0.05, 0.0, False), "g_minus_5": (-5.0, 30.0, False),
            "aligned_keys": (-0.05, 0.0, True)}
+# what only the ANY-DECAY plan takes (`kda`'s `g_min` None). g_minus_60: g a
+# channel its own, from ~-0.01 to -60 a step (the fastest channels' decay
+# over a chunk is e^-3840: any division by it or any positive exponent is
+# inf or NaN; the bounded plan reads NaN here). beta_1999: the aligned keys
+# with beta 1.999 throughout, I - beta k k^T with an eigenvalue of -0.999
+ANY_REGIMES = {**REGIMES, "g_minus_60": (-60.0, None, False),
+               "beta_1999": (-0.05, 0.0, True)}
+# sha256 of the bounded `jnp` form's primitives in order, taken on the commit
+# before the any-decay plan (PR 63's tree)
+BOUNDED_JAXPR_DIGEST = "dc3960939f371186"
+PLANS = {"bounded": REGIMES, "any_decay": ANY_REGIMES}
+CASES = [(plan, regime) for plan, regimes in PLANS.items()
+         for regime in regimes]
 
 
 def _everything(fn, args, w):
@@ -60,13 +73,13 @@ def _everything(fn, args, w):
     return dict(zip(KDA_TENSORS, (o, state) + grads))
 
 
-def _interpreted(*a):
-    return kda_op._kda(*a, False, True)
+def _interpreted(*a, plan="bounded"):
+    return kda_op._kda(*a, False, True, plan == "bounded")
 
 
 @functools.lru_cache(maxsize=None)
-def _kda_case(regime, s):
-    low, shift, aligned = REGIMES[regime]
+def _kda_case(regime, s, plan="bounded"):
+    low, shift, aligned = ANY_REGIMES[regime]
     ks = jax.random.split(jax.random.PRNGKey(7), 7)
     b, h, d = 2, 2, 32
     l2 = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
@@ -77,56 +90,67 @@ def _kda_case(regime, s):
         l2(jax.random.normal(ks[0], (b, h, s, d))) * d ** -0.5,
         l2(k),
         jax.random.normal(ks[2], (b, h, s, d)),
-        low * jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, d)) + shift),
-        jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, s))
-                       + (4.0 if aligned else 0.0)))
+        low * jax.nn.sigmoid(jax.random.normal(ks[3], (b, h, s, d)) + (
+            jnp.linspace(-9.0, 4.0, d) if shift is None else shift)),
+        jnp.full((b, h, s), 1.999) if regime == "beta_1999"
+        else jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, s))
+                            + (4.0 if aligned else 0.0)))
     w = jax.random.normal(ks[5], (b, h, s, d))
     with jax.default_matmul_precision("highest"):
         return (_everything(kda_op.kda_recurrence, args, w),
-                _everything(lambda *a: kda_op._kda(*a, False, False), args, w),
+                _everything(lambda *a: kda_op._kda(
+                    *a, False, False, plan == "bounded"), args, w),
                 args)
 
 
 @pytest.mark.parametrize("tensor", KDA_TENSORS)
 @pytest.mark.parametrize("s", [128, 100], ids=["chunks_whole", "s_100"])
-@pytest.mark.parametrize("regime", list(REGIMES))
-def test_kda_chunked_matches_the_recurrence(regime, s, tensor):
-    want, got, args = _kda_case(regime, s)
+@pytest.mark.parametrize("plan,regime", CASES)
+def test_kda_chunked_matches_the_recurrence(plan, regime, s, tensor):
+    want, got, args = _kda_case(regime, s, plan)
     if regime == "g_minus_5":
         assert float(args[3].max()) < -4.99
+    if regime == "g_minus_60":
+        assert float(args[3].min()) < -55 and float(args[3].max()) > -0.1
     if regime == "aligned_keys":
         assert float(jnp.einsum("bhtd,bhid->bhti", args[1], args[1]).min()) \
             > 0.5
     assert bool(jnp.all(jnp.isfinite(got[tensor])))
     scale = float(jnp.abs(want[tensor]).max()) + 1e-30
+    # beta 1.999 on aligned keys: the state's component along k changes
+    # sign every token and barely decays, so rounding carries further
+    # (2.2e-5 of the largest entry measured): the model's gradient bound
     np.testing.assert_allclose(got[tensor] / scale, want[tensor] / scale,
-                               atol=ATOL)
+                               atol=GRAD_ATOL if regime == "beta_1999"
+                               else ATOL)
 
 
 @functools.lru_cache(maxsize=None)
-def _kda_kernel_case(regime):
-    want, _, args = _kda_case(regime, 100)
+def _kda_kernel_case(regime, plan="bounded"):
+    want, _, args = _kda_case(regime, 100, plan)
     w = jax.random.normal(jax.random.split(jax.random.PRNGKey(7), 7)[5],
                           args[0].shape)
     with jax.default_matmul_precision("highest"):
-        return want, _everything(_interpreted, args, w)
+        return want, _everything(
+            functools.partial(_interpreted, plan=plan), args, w)
 
 
 @pytest.mark.parametrize("tensor", KDA_TENSORS)
-@pytest.mark.parametrize("regime", list(REGIMES))
-def test_kda_kernel_in_the_interpreter_matches_the_recurrence(regime, tensor):
+@pytest.mark.parametrize("plan,regime", CASES)
+def test_kda_kernel_in_the_interpreter_matches_the_recurrence(plan, regime,
+                                                              tensor):
     """The three Pallas kernels (what the TPU runs), interpreted: o and the
     final state from the forward, the five gradients from the backward
     pass's two walks (the state's cotangent enters the last chunk), S no
     multiple of the chunk. dg is a reversed cumulative sum of terms that
     cancel pair by pair: with g = -5 throughout it keeps 2e-5 of its
     largest entry, so it gets the model's gradient tolerance."""
-    want, got = _kda_kernel_case(regime)
+    want, got = _kda_kernel_case(regime, plan)
     assert bool(jnp.all(jnp.isfinite(got[tensor])))
     scale = float(jnp.abs(want[tensor]).max()) + 1e-30
     np.testing.assert_allclose(
         got[tensor] / scale, want[tensor] / scale,
-        atol=GRAD_ATOL if tensor == "dg" else ATOL)
+        atol=GRAD_ATOL if tensor == "dg" or regime == "beta_1999" else ATOL)
 
 
 def _kda_counters_of(lower):
@@ -143,9 +167,15 @@ def test_kda_counts_its_chunks():
     it issues: half where the rows go in lane-packed pairs (b x h 4: 4
     rows a step), all of them at 3 (one row a step) and in the `jnp` form."""
     args = _kda_case("g_near_0", 100)[2]
-    assert _kda_counters_of(lambda: jax.jit(kda_op.kda).lower(*args)) == {
+    bounded = functools.partial(kda_op.kda, g_min=kda_op.G_MIN_BOUNDED)
+    assert _kda_counters_of(lambda: jax.jit(bounded).lower(*args)) == {
         "kda.chunks": 2, "kda.solve_products": 80,
         "kda.solve_passes_packed": 80}
+    # the any-decay plan in the `jnp` form: six score products a chunk for
+    # A's rows and six for B's, 2 x 2 x 2 chunks as rows
+    assert _kda_counters_of(lambda: jax.jit(kda_op.kda).lower(*args)) == {
+        "kda.chunks": 2, "kda.solve_products": 80,
+        "kda.solve_passes_packed": 80, "kda.halving_products": 96}
     for heads, products, issued in ((4, 40, 20), (3, 10, 10)):
         args = kda_chip_check.inputs(
             "mixed", jax.random.PRNGKey(0), b=1, h=heads, s=100, d=32)[0]
@@ -153,9 +183,57 @@ def test_kda_counts_its_chunks():
         # of this one would leave nothing to count
         kda_op._kda_fwd_pallas.clear_cache()
         assert _kda_counters_of(lambda: jax.jit(functools.partial(
+            bounded, interpret=True)).lower(*args)) == {
+                "kda.chunks": 2, "kda.solve_products": products,
+                "kda.solve_passes_packed": issued, "kda.kernels": 1}, heads
+        # the same kernel under the any-decay plan: counted as such, and
+        # 6 levels x (A's rows + B's) products a row of the grid step
+        kda_op._kda_fwd_pallas.clear_cache()
+        assert _kda_counters_of(lambda: jax.jit(functools.partial(
             kda_op.kda, interpret=True)).lower(*args)) == {
                 "kda.chunks": 2, "kda.solve_products": products,
-                "kda.solve_passes_packed": issued}, heads
+                "kda.solve_passes_packed": issued, "kda.kernels": 1,
+                "kda.kernels_any_decay": 1,
+                "kda.halving_products": 12 * (4 if heads == 4 else 1)}, heads
+
+
+def test_the_bounded_plan_fails_where_only_the_any_decay_plan_holds():
+    """The control: `g_minus_60` through the bounded plan (a caller's
+    breach of the bound it gave: nothing looks, as the docstring says)
+    reads inf or NaN; `kda` picks the any-decay plan for any bound below
+    -5 and for none."""
+    _, _, args = _kda_case("g_minus_60", 128, "any_decay")
+    o = kda_op.kda(*args, g_min=kda_op.G_MIN_BOUNDED)
+    assert not bool(jnp.all(jnp.isfinite(o)))
+    assert bool(jnp.all(jnp.isfinite(kda_op.kda(*args, g_min=-60.0))))
+    assert [kda_op.plan_is_bounded(g) for g in (None, -60.0, -5.01, -5.0,
+                                                 -1.0)] \
+        == [False, False, False, True, True]
+
+
+def test_the_bounded_plans_jaxpr_is_pinned():
+    """The bounded plan's `jnp` form and its custom_vjp, as a digest of
+    the jaxpr's equations (primitive names in order): what Ling's model
+    ran before the any-decay plan came. A change to the bounded plan moves
+    it; `tools/step_lowering_hash.py` is the same proof for the kernels."""
+    import hashlib
+
+    args = _kda_case("g_near_0", 128)[2]
+    jaxpr = jax.make_jaxpr(lambda *a: kda_op._kda_chunked(*a, True))(*args)
+
+    def names(j):
+        out = []
+        for e in j.eqns:
+            out.append(e.primitive.name)
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        out += names(inner)
+        return out
+
+    digest = hashlib.sha256(" ".join(names(jaxpr.jaxpr)).encode())
+    assert digest.hexdigest()[:16] == BOUNDED_JAXPR_DIGEST
 
 
 # --------------------------------------------------------------------------
@@ -378,6 +456,23 @@ def test_param_axes_match_the_parameters():
             assert a.ndim == len(spec)
 
 
+@pytest.mark.parametrize("over", [
+    dict(kda_lower_bound=-8.0), dict(kda_lower_bound=None),
+    dict(kda_lower_bound=0.0), dict(kda_gate_rank=8),
+    dict(kda_beta_scale=2.0)], ids=lambda over: next(iter(over)))
+def test_a_kda_gate_outside_lings_published_form_is_refused(over):
+    """The KDA sublayer is `mixers.py`'s and takes a gate without a bound,
+    low-rank gates and beta in (0, 2) (`solar_open2.py` runs them, against
+    `reference_solar2`); this module's published configurations have the
+    bounded full-rank form alone, `reference_ling` likewise, so the config
+    refuses the others and says where they live. -5 itself stands, and is
+    `ops/kda.py`'s bounded plan."""
+    with pytest.raises(ValueError, match="solar_open2"):
+        hybrid_moe.HybridMoeConfig.tiny(**over)
+    assert kda_op.plan_is_bounded(
+        hybrid_moe.HybridMoeConfig.tiny(kda_lower_bound=-5.0).kda_lower_bound)
+
+
 def test_a_nonzero_swiglu_limit_in_a_held_layer_raises():
     limits = (0,) * 8 + (4,) * 4
     hybrid_moe.HybridMoeConfig.tiny(layers=(1, 3, 4, 5),
@@ -403,7 +498,7 @@ def test_the_published_count_of_parameters():
     kda = 63_049_888 + 2 * 2560
     mla = 31_966_080 + 2 * 2560
     routed = 2560 * 512 + 512 + 17 * 5_898_240
-    assert hybrid_moe.kda_num_params(cfg) == 63_049_888
+    assert mixers.kda_num_params(cfg) == 63_049_888
     assert mixers.mla_num_params(cfg) == 31_966_080
     assert cfg.num_params() == (
         2 * 19_648 * 2560 + 2560 + kda + 3 * 2560 * 6144

@@ -802,3 +802,61 @@ def test_the_residual_paths_connections_are_counted_and_scoped():
     assert first("hc.expand") < first("hc.pre") < first("mla.attend") \
         < first("hc.post") < first("hc.reduce")
     assert not [s for s in entered if "hc.maps" in s]
+
+
+def test_the_delta_rules_plans_are_counted_and_its_gates_scoped():
+    """`mixers.kda_sublayer` under both its models: a lowering with the
+    kernels leaves `kda.kernels` (the forward here) and, under Solar's
+    softplus gate (`kda_lower_bound` None: the any-decay plan),
+    `kda.kernels_any_decay` beside it and `kda.halving_products` (6 levels
+    x A's and B's rows x the grid step's rows) beside `kda.solve_products`;
+    under Ling's bounded gate `kda.kernels_any_decay` stays 0 but is THERE.
+    The low-rank gate projections sit under `kda.gate_lora` inside
+    `kda.gates`, the channel gate under `attn.gate`: op names tell them
+    from the big projections."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import hybrid_moe, solar_open2
+    from ray_tpu.ops import kda as kda_op
+
+    names = ("kda.kernels", "kda.kernels_any_decay", "kda.halving_products",
+             "kda.solve_products", "kda.layers")
+
+    def lowered(module, cfg):
+        params = jax.eval_shape(lambda: module.init(cfg, jax.random.PRNGKey(0)))
+        kda_op._kda_fwd_pallas.clear_cache()
+        before = dp.snapshot()["counters"]
+        kept = kda_op.kda
+        # the kernels' branch, interpreted: what a TPU's lowering counts
+        kda_op.kda = lambda *a, **kw: kept(*a, **dict(kw, interpret=True))
+        try:
+            traced = jax.make_jaxpr(
+                lambda p, t: module.forward_hidden(p, t, cfg)[0])(
+                    params, jax.ShapeDtypeStruct((1, 64), jnp.int32))
+        finally:
+            kda_op.kda = kept
+        after = dp.snapshot()["counters"]
+        return traced, {n: after.get(n, 0) - before.get(n, 0) for n in names
+                        if n in after}
+
+    def scopes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scopes(sub)
+
+    traced, grew = lowered(solar_open2, solar_open2.SolarOpen2Config.tiny(
+        layers=(0, 1, 2, 3), remat=False, n_experts_held=4))
+    # one scanned body of the period's three KDA layers: 4 heads a step
+    assert grew == {"kda.kernels": 1, "kda.kernels_any_decay": 1,
+                    "kda.halving_products": 6 * 2 * 4,
+                    "kda.solve_products": 10 * 4, "kda.layers": 1}
+    entered = set(scopes(traced.jaxpr))
+    assert any("kda.gates/kda.gate_lora" in s for s in entered)
+    assert any("attn.gate" in s for s in entered)
+    traced, grew = lowered(hybrid_moe, hybrid_moe.HybridMoeConfig.tiny(
+        layers=(3, 4, 5), remat=False, n_experts_held=4))
+    assert grew["kda.kernels"] == 1 and grew["kda.kernels_any_decay"] == 0
+    assert grew.get("kda.halving_products", 0) == 0
+    assert not [s for s in scopes(traced.jaxpr) if "kda.gate_lora" in s]

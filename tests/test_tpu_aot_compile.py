@@ -717,7 +717,8 @@ from ray_tpu.ops import kda as kda_op
 kda_args = (spec((4, 32, 2048, 128), bf16),) * 3 + (
     spec((4, 32, 2048, 128), jnp.float32), spec((4, 32, 2048), jnp.float32))
 hlo = jax.jit(jax.value_and_grad(  # the value: or the forward is dead code
-    lambda *a: kda_op.kda(*a, use_pallas=True).astype(jnp.float32).sum(),
+    lambda *a: kda_op.kda(*a, g_min=kda_op.G_MIN_BOUNDED,
+                          use_pallas=True).astype(jnp.float32).sum(),
     argnums=(0, 1, 2, 3, 4))).lower(*kda_args).compile().as_text()
 out["kda_calls"] = [
     re.sub(r"custom-call\(.*", 'custom-call(%a), custom_call_target='

@@ -1,6 +1,10 @@
 #!/usr/bin/env python3
-"""python3 tools/kda_chip_check.py [--seed n]: `ops/kda.py`'s three Pallas
-kernels alone at the Ling cell's shape, `[4, 32, 2048, 128]`, ON THE CHIP:
+"""python3 tools/kda_chip_check.py [--seed n] [--shapes ling solar]
+[--kinds KIND ...] | --cell [--seed n ...]:
+`ops/kda.py`'s three Pallas kernels alone at the Ling cell's shape, `[4, 32,
+2048, 128]`, under the BOUNDED plan (`g_min` -5, the top level of the output)
+and, under `any_decay`, the ANY-DECAY plan (`g_min` None), and the same two
+at the Solar cell's, `[1, 64, 8192, 128]` (under `solar`), ON THE CHIP:
 any other backend exits 3 before anything is computed, and every call here
 passes `use_pallas=True`, so no number of this tool ever comes from the
 `jnp` form. o and the five gradients against the token-by-token float32
@@ -26,8 +30,15 @@ vanish: (I - A)(I + A^2) ... (I + A^32) keeps no digit there or overflows
 their powers, then blocks in pairs). The same keys make the problem itself
 touchier (a bf16 score of ~1 is off by 2^-9 and the solve carries that
 through 63 rows), so this input has a bound of its own, `ALIGNED_BOUND`.
+The any-decay plan takes two more. `fast_decay`: `mixed` with g down to -60
+a step in the fastest channels (a chunk's cumulative decay reaches e^-3840:
+any positive exponent or division by it is inf or NaN); the control is the
+BOUNDED plan on the same input, which has to fail. `negative_eigenvalues`:
+`aligned_keys`' keys with beta 1.9 to 1.999 (I - beta k k^T has an
+eigenvalue near -1 along k): the control is the product of powers again.
 
-THE BOUND, 7e-3 a tensor (PERF.md section 6, PR 39, has the readings). The
+THE BOUND, 7e-3 a tensor (PERF.md section 6, PR 39, has the readings; 2e-2
+for `long_memory` at S 8,192, `LONG_MEMORY_BOUND_8K`). The
 chunked form rounds each matmul's operands to bf16 (2^-9 a value) with
 float32 accumulation, through a chain of four matmuls a chunk (the scores,
 T, U / W, U~, o), and the backward kernels round the cotangents the same
@@ -49,6 +60,18 @@ three kernels APART on the device's, from a short trace of value and
 gradient: `fwd_kernel_ms`, `states_ms` (the backward pass's walk forwards:
 the forward's solve again) and `bwd_ms` (its walk backwards), told apart by
 how many outputs a Pallas event has. Writes chiprun_out/kda_chip_check.json.
+`--kinds` keeps those inputs only (a second seed of one input in minutes).
+
+`--cell`: and nothing else: what `train-solar2-1chip`'s `correct` compares,
+through the cell's own functions on the cell's own weights, for each
+`--seed`: `benchmarks/train_kda_cell.path_errors` (one KDA call at [1, 64,
+8192, 128] against `reference_solar2.recurrence`, on the layer's own input
+and on `long_memory`) and `train_cell`'s loss comparison (the whole model's
+loss on one row against `reference_solar2.loss`), each with the PROGRAM and
+with the two controls in its place: `bf16_state` and `bounded_plan`. Exit 1
+unless the program is within the cell's limits and each control outside
+them (by `path_errors`; what the loss says of a control is printed, and is
+why the cell has a kind of its own). Writes chiprun_out/kda_cell_check.json.
 """
 import argparse
 import contextlib
@@ -74,6 +97,26 @@ ALIGNED_BOUND = 3e-2
 # kind -> (its bound, its control)
 KINDS = {"mixed": (BOUND, "bf16_state"), "long_memory": (BOUND, "bf16_state"),
          "aligned_keys": (ALIGNED_BOUND, "product_solve")}
+# `long_memory` past S 2,048: the float32 state's own reading grows with the
+# chunks a state is carried over (o 4.7e-3 at 32 chunks, 8.7e-3 at 128), under
+# BOTH plans alike to three digits, and so does the bf16 control's (o 2.9e-2
+# and 0.117). At [1, 64, 8192, 128] over the seeds 39, 40 and 41 (my chip runs,
+# PR 64; the first seed's run came before the bound, the others after): the
+# kernels read at most o 8.8e-3, dq 8.5e-3, dk 8.8e-3, dv 9.5e-3, dg 1.05e-2,
+# dbeta 7.8e-3; the bf16 state at least o 0.117, dq 0.116, dg 8.6e-2, dbeta
+# 4.0e-2, and in dk and dv what the kernels read (the control rounds the
+# FORWARD state alone; the writing chunk's dk and dv come from the backward
+# walk's state, which it leaves in float32): the bound lies between the two
+# in the four tensors the control moves, a factor of two from either
+LONG_MEMORY_BOUND_8K = 2e-2
+# the inputs only the any-decay plan takes
+ANY_KINDS = {**KINDS, "fast_decay": (BOUND, "bounded_plan"),
+             "negative_eigenvalues": (ALIGNED_BOUND, "product_solve")}
+SHAPES = {"ling": (4, 32, 2048, 128), "solar": (1, 64, 8192, 128)}
+# the controls that have to fail, by input
+MUST_FAIL = {"long_memory": "bf16_state", "aligned_keys": "product_solve",
+             "fast_decay": "bounded_plan",
+             "negative_eigenvalues": "product_solve"}
 
 
 def inputs(kind, key, b=4, h=32, s=2048, d=128, dtype=jnp.bfloat16):
@@ -90,11 +133,15 @@ def inputs(kind, key, b=4, h=32, s=2048, d=128, dtype=jnp.bfloat16):
     if kind == "long_memory":
         g = -2e-5 * jax.random.uniform(ks[6], g.shape, minval=1.0, maxval=1.25)
         beta = jnp.where(jnp.arange(s) < K.CHUNK, beta, 2e-5)
-    if kind == "aligned_keys":
+    if kind == "fast_decay":
+        g = -60.0 * jax.nn.sigmoid(logit)
+    if kind in ("aligned_keys", "negative_eigenvalues"):
         k = l2(jax.random.normal(ks[6], (b, h, 1, d))
                + 0.4 * jax.random.normal(ks[1], (b, h, s, d)))
         g = -5e-2 * jax.random.uniform(ks[3], g.shape, minval=0.01)
         beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, s)) + 4.0)
+    if kind == "negative_eigenvalues":
+        beta = jax.random.uniform(ks[4], (b, h, s), minval=1.9, maxval=1.999)
     w = jax.random.normal(ks[5], (b, h, s, d))
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta), w
 
@@ -142,6 +189,12 @@ def product_solve():
     return _kernels_with("_solve", product)
 
 
+def bounded_plan():
+    """Inside, every call takes the bounded plan whatever its `g_min`: the
+    control on `fast_decay`, whose g breaks that plan's contract."""
+    return _kernels_with("plan_is_bounded", lambda g_min: True)
+
+
 def unpacked_solve():
     """Inside, a grid step's rows go through `ops/kda._solve` one C x C
     product a row, as before PR 40, and not in lane-packed pairs: the same
@@ -159,17 +212,32 @@ def with_grads(fn, w):
 
 def errors(got, want):
     def rel(a, b):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        a, b = jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)
         return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
     return {n: rel(a, b) for n, a, b in zip(NAMES, got, want)}
 
 
-def compare(args, w, control="bf16_state", **how):
+_WANT = {}
+
+
+def _want(args, w, key):
+    """The recurrence's o and gradients for these inputs, kept on the HOST
+    under `key` (None: not kept): both plans are held to the same, and
+    8,192 tokens one after another take the chip over a minute."""
+    if key in _WANT:
+        return _WANT[key]
+    want = with_grads(lambda *a: K.kda_recurrence(*a)[0], w)(
+        *(x.astype(jnp.float32) for x in args))
+    if key is not None:
+        _WANT[key] = want = jax.device_get(want)
+    return want
+
+
+def compare(args, w, control="bf16_state", key=None, **how):
     """The kernels (`how`: `kda`'s `use_pallas` / `interpret`) against the
     recurrence -> {"kernel": errors, `control`: errors}."""
     kernel = lambda *a: K.kda(*a, **how)  # noqa: E731
-    want = with_grads(lambda *a: K.kda_recurrence(*a)[0], w)(
-        *(x.astype(jnp.float32) for x in args))
+    want = _want(args, w, key)
     out = {"kernel": errors(with_grads(kernel, w)(*args), want)}
     with globals()[control]():
         out[control] = errors(with_grads(kernel, w)(*args), want)
@@ -230,28 +298,51 @@ def kernel_ms(fn, *args, n=5, kernel_of=kernel_of):
             if kernel}
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--seed", type=int, default=39)
-    a = ap.parse_args()
-    if jax.default_backend() != "tpu":
-        print(f"kda_chip_check: backend {jax.default_backend()!r}, not a TPU: "
-              "run it through the chip tool", file=sys.stderr)
-        return 3
-    out = {"device": jax.devices()[0].device_kind, "seed": a.seed,
-           "bound": BOUND, "aligned_bound": ALIGNED_BOUND}
-    for i, (kind, (_, control)) in enumerate(KINDS.items()):
+def check(shape, g_min, seed, kinds, only=None):
+    """One shape under one plan -> the output's dict for it. `only`: the
+    inputs to run, each as the whole run of this seed makes it, and no
+    times."""
+    if shape[2] > 2048 and "long_memory" in kinds:
+        kinds = {**kinds, "long_memory": (LONG_MEMORY_BOUND_8K,
+                                          kinds["long_memory"][1])}
+    out = {"shape": list(shape), "g_min": g_min}
+    how = dict(use_pallas=True, g_min=g_min)
+    for i, (kind, (_, control)) in enumerate(kinds.items()):
+        if only and kind not in only:
+            continue
         args, w = inputs(kind, jax.random.fold_in(
-            jax.random.PRNGKey(a.seed), i))
-        out[kind] = compare(args, w, control, use_pallas=True)
-    args, w = inputs("mixed", jax.random.PRNGKey(a.seed))
-    out["shape"] = list(args[0].shape)
-    kernel = lambda *x: K.kda(*x, use_pallas=True)  # noqa: E731
+            jax.random.PRNGKey(seed), i), *shape)
+        out[kind] = compare(args, w, control, (tuple(shape), kind, seed),
+                            **how)
+        # what there is so far, should the call be cut
+        print(json.dumps({"shape": out["shape"], "g_min": g_min,
+                          kind: out[kind]}), flush=True)
+    if only:
+        kinds = {k: v for k, v in kinds.items() if k in only}
+    else:
+        out.update(times(shape, g_min, seed))
+    out["ok"] = all(v <= bound for kind, (bound, _) in kinds.items()
+                    for v in out[kind]["kernel"].values())
+    # a NaN fails its bound too
+    out["control_fails"] = all(
+        any(not v <= kinds[kind][0] for v in out[kind][control].values())
+        for kind, control in MUST_FAIL.items() if kind in kinds)
+    return out
+
+
+def times(shape, g_min, seed):
+    """-> {"ms": the calls' and the kernels' times on `mixed`,
+    "equals_unpacked_solve"} of one shape under one plan."""
+    out = {}
+    how = dict(use_pallas=True, g_min=g_min)
+    args, w = inputs("mixed", jax.random.PRNGKey(seed), *shape)
+    kernel = lambda *x: K.kda(*x, **how)  # noqa: E731
     grad = jax.grad(lambda *x: jnp.sum(kernel(*x).astype(jnp.float32) * w),
                     argnums=(0, 1, 2, 3, 4))
     out["ms"] = {
         "fwd": timed(jax.jit(kernel), *args),
-        "fwd_xla": timed(jax.jit(lambda *x: K._kda_chunked(*x)[0]), *args),
+        "fwd_xla": timed(jax.jit(lambda *x: K._kda_chunked(
+            *x, K.plan_is_bounded(g_min))[0]), *args),
         "fwd_and_bwd": timed(jax.jit(grad), *args)}
     both = jax.jit(lambda *x: (kernel(*x), grad(*x)))
     got = jax.block_until_ready(both(*args))
@@ -260,19 +351,118 @@ def main():
         out["equals_unpacked_solve"] = all(
             bool(jnp.all(a == b)) for a, b in zip(
                 jax.tree.leaves(got), jax.tree.leaves(both(*args))))
-    out["ok"] = all(v <= bound for kind, (bound, _) in KINDS.items()
-                    for v in out[kind]["kernel"].values())
-    # a NaN fails its bound too
-    out["control_fails"] = all(
-        any(not v <= KINDS[kind][0] for v in out[kind][control].values())
-        for kind, control in (("long_memory", "bf16_state"),
-                              ("aligned_keys", "product_solve")))
+    return out
+
+
+CELL_CONFIG = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmarks", "configs", "solar-open2-250b-train-1chip.json")
+CELL_CONTROLS = ("bf16_state", "bounded_plan")
+
+
+def cell_check(seed):
+    """-> {"path": {who: `path_errors` + `correct`}, "loss": {who: the
+    loss's relative error}} for who in the program and the two controls."""
+    from functools import partial
+
+    from benchmarks import reference_solar2, train_kda_cell
+    from benchmarks.train_cell import LOSS_TOLERANCE
+    from ray_tpu.models import solar_open2
+
+    with open(CELL_CONFIG) as f:
+        config = json.load(f)
+    with open(os.path.join(os.path.dirname(os.path.dirname(CELL_CONFIG)),
+                           "traffic", "pretrain-8k-b1.json")) as f:
+        traffic = json.load(f)
+    program = config["program"]
+    fields = {k: config[v] for k, v in program["fields_from"].items()}
+    fields.update(program["fields"])
+    cfg = {"model": fields, "model_module": program["module"],
+           "config_class": program["config_class"],
+           "reference_module": "benchmarks." + config["reference"],
+           "trainer": {**config["trainer"], **traffic}, "seed": seed}
+    whos = (None,) + CELL_CONTROLS
+    entered = lambda who: globals()[who]() if who \
+        else contextlib.nullcontext()  # noqa: E731
+    out = {"seed": seed, "path": {}, "loss": {"tolerance": LOSS_TOLERANCE}}
+    for who in whos:
+        t = time.perf_counter()
+        with entered(who):
+            errors = train_kda_cell.path_errors(cfg)
+        out["path"][who or "program"] = dict(
+            errors, correct=train_kda_cell.within_limits(errors),
+            seconds=time.perf_counter() - t)
+        print(json.dumps({who or "program": out["path"][who or "program"]}),
+              flush=True)
+    # `train_cell`'s comparison: the first row of the timed batch
+    model = solar_open2.SolarOpen2Config(**fields)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed % 2**31), seed // 2**31)
+    params = jax.jit(partial(solar_open2.init, model))(key)
+    t = cfg["trainer"]
+    toks = jax.random.randint(
+        jax.random.fold_in(key, 1),
+        (t["batches_in_cycle"], t["per_chip_batch"], t["seq"] + 1), 0,
+        model.vocab_size)[0]
+    batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
+    t0 = time.perf_counter()
+    want = reference_solar2.loss(params, batch["inputs"], batch["targets"],
+                                 fields)
+    out["loss"].update(reference=want,
+                       reference_seconds=time.perf_counter() - t0)
+    for who in whos:
+        with entered(who):
+            got = float(jax.jit(partial(solar_open2.loss_fn, config=model))(
+                params, batch))
+        out["loss"][who or "program"] = {
+            "loss": got, "rel_err": abs(got - want) / abs(want)}
+    print(json.dumps({"loss": out["loss"]}), flush=True)
+    out["ok"] = out["path"]["program"]["correct"] and not any(
+        out["path"][who]["correct"] for who in CELL_CONTROLS)
+    return out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--seed", type=int, nargs="+", default=[39])
+    ap.add_argument("--kinds", nargs="+", choices=list(ANY_KINDS),
+                    help="these inputs only")
+    ap.add_argument("--cell", action="store_true", help="what the Solar "
+                    "cell's `correct` compares, program and controls, a "
+                    "seed at a time, and nothing else")
+    ap.add_argument("--shapes", nargs="+", default=list(SHAPES),
+                    choices=list(SHAPES))
+    ap.add_argument("--times-only", action="store_true", help="the `ms` of "
+                    "each shape and plan and nothing compared (exit 0): a "
+                    "minute, for tuning a kernel")
+    a = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        print(f"kda_chip_check: backend {jax.default_backend()!r}, not a TPU: "
+              "run it through the chip tool", file=sys.stderr)
+        return 3
     os.makedirs("chiprun_out", exist_ok=True)
+    if a.cell:
+        cells = [cell_check(seed) for seed in a.seed]
+        with open("chiprun_out/kda_cell_check.json", "w") as f:
+            json.dump(cells, f, indent=1)
+        return 0 if all(c["ok"] for c in cells) else 1
+    (seed,) = a.seed
+    kinds = lambda all_: {} if a.times_only else all_  # noqa: E731
+    out = {"device": jax.devices()[0].device_kind, "seed": seed,
+           "bound": BOUND, "aligned_bound": ALIGNED_BOUND}
+    checks = []
+    for name in a.shapes:
+        at = out if name == "ling" else out.setdefault(name, {})
+        at.update(check(SHAPES[name], K.G_MIN_BOUNDED, seed, kinds(KINDS),
+                        a.kinds))
+        at["any_decay"] = check(SHAPES[name], None, seed, kinds(ANY_KINDS),
+                                a.kinds)
+        checks += [at, at["any_decay"]]
     with open("chiprun_out/kda_chip_check.json", "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
-    return 0 if out["ok"] and out["control_fails"] \
-        and out["equals_unpacked_solve"] else 1
+    return 0 if all(c["ok"] and c["control_fails"]
+                    and c.get("equals_unpacked_solve", True)
+                    for c in checks) else 1
 
 
 if __name__ == "__main__":

@@ -6,8 +6,9 @@ checkout, and compare the two tables:
     JAX_PLATFORMS=cpu python3 tools/step_lowering_hash.py --out DIR
     JAX_PLATFORMS=cpu python3 tools/step_lowering_hash.py --against DIR
 
-Each cell of `BENCHMARK.json` whose configuration is of the `train` kind is
-built as `benchmarks/train_cell.py` builds it (the `program` group at the
+Each cell of `BENCHMARK.json` whose configuration is of a `train` kind
+(`train`, or a kind that runs `train_cell`'s step after a comparison of its
+own: `train_hc`, `train_kda`) is built as `benchmarks/train_cell.py` builds it (the `program` group at the
 published widths, the traffic's B x S, AdamW, `train.make_train_step` with
 the state donated), on a DESCRIBED v5e (`jax.experimental.topologies`, as
 `tests/test_tpu_aot_compile.py`: no device is touched, no array is made)
@@ -23,7 +24,12 @@ DIR` makes the table again from kept texts); `--tiny` lowers the module's
 `tiny()` configuration at B 2 x S 64 on the CPU instead (seconds, for
 iterating). `--cell NAME --memory` compiles that one step too and prints the
 bytes the compiler places (arguments, outputs, aliased, temporaries): whether
-a change to what the step holds still fits the chip, with no chip.
+a change to what the step holds still fits the chip, with no chip;
+`--over '{"field": value}'` sets fields of the program's config over the
+file's first: whether the next larger share or depth would fit too; with
+`--out DIR` it also keeps `<cell>.ops.json`, the compiled step's ops as the
+device trace will name them (`event_ops`) and the counters the lowering
+left: what a trace query would take, with no chip.
 """
 
 from __future__ import annotations
@@ -48,7 +54,7 @@ def cells():
         with open(os.path.join(ROOT, "benchmarks", "configs",
                                w["config"] + ".json")) as f:
             config = json.load(f)
-        if config["kind"] == "train":
+        if config["kind"].startswith("train"):
             out.append((w, config))
     return out
 
@@ -74,11 +80,39 @@ def canonical(text: str) -> str:
                   r'(?:[^"\\]|\\.)*)"', kernel, text)
 
 
+def event_ops(compiled_text: str) -> list:
+    """A compiled step's text -> [[op, scope]]: the ops that run as events
+    of their own (every instruction outside a fused computation's body), as
+    the device trace names them (the text up to the operands; a Pallas
+    call's operands dropped), with the scope the op's metadata keeps: what
+    a metric's trace query is run over, with no chip."""
+    ops, fused = [], False
+    for line in compiled_text.splitlines():
+        if re.match(r"^%?fused_computation|^%?\S*fused\S* \(", line):
+            fused = True
+        elif line.startswith("}"):
+            fused = False
+        elif not fused:
+            m = re.match(r"^\s*(?:ROOT )?(%[\w.\-]+ = \(?\w+\[[\d,]*\]\S* "
+                         r"(?:\S+ )*?[\w\-]+\()", line)
+            if m and not any(f" {kind}(" in line for kind in (
+                    "parameter", "constant", "get-tuple-element", "bitcast",
+                    "tuple")):
+                scope = re.search(r'op_name="([^"]*)"', line)
+                text = line.strip()
+                ops.append([
+                    text[:400] if "tpu_custom_call" not in text
+                    else re.sub(r"custom-call\(.*", "custom-call(%a), "
+                                'custom_call_target="tpu_custom_call"', text),
+                    scope.group(1) if scope else ""])
+    return ops
+
+
 def digest(text: str) -> str:
     return hashlib.sha256(canonical(text).encode()).hexdigest()
 
 
-def lowered_step(workload, config, tiny: bool):
+def lowered_step(workload, config, tiny: bool, over=None):
     """-> the cell's train step, lowered (`jax.stages.Lowered`)."""
     import importlib
     from functools import partial
@@ -109,6 +143,7 @@ def lowered_step(workload, config, tiny: bool):
         jax.default_backend = lambda: "tpu"
         fields = {k: config[v] for k, v in program["fields_from"].items()}
         fields.update(program["fields"])
+        fields.update(over or {})
         model = config_class(**fields)
         topo = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
@@ -159,6 +194,8 @@ def main() -> int:
     ap.add_argument("--against", help="a directory --out wrote: compare")
     ap.add_argument("--rehash", help="a directory of kept texts: its table")
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--over", type=json.loads, help="with --cell: fields of "
+                    "the program's config, as JSON, over the file's")
     ap.add_argument("--memory", action="store_true", help="with --cell: "
                     "COMPILE the step for the described v5e and print what "
                     "the compiler places (a refusal is its error)")
@@ -176,12 +213,22 @@ def main() -> int:
         return 0
     if args.cell:
         (found,) = [c for c in cells() if c[0]["name"] == args.cell]
-        lowered = lowered_step(*found, args.tiny)
+        lowered = lowered_step(*found, args.tiny, args.over)
         if args.memory:
-            memory = lowered.compile().memory_analysis()
+            compiled = lowered.compile()
+            memory = compiled.memory_analysis()
             print("MEMORY " + json.dumps({
                 name: getattr(memory, name + "_size_in_bytes")
                 for name in ("argument", "output", "alias", "temp")}))
+            if args.out:
+                from ray_tpu._private import device_profiler
+
+                with open(os.path.join(args.out, args.cell + ".ops.json"),
+                          "w") as f:
+                    json.dump({
+                        "ops": event_ops(compiled.as_text()),
+                        "counters": device_profiler.snapshot()["counters"]},
+                        f)
             return 0
         text = lowered.as_text()
         if args.out:
