@@ -28,6 +28,7 @@ from ray_tpu._private import device_profiler
 from ray_tpu.models import blocks
 from ray_tpu.models.blocks import rms_norm, rope
 from ray_tpu.ops import kda as kda_op
+from ray_tpu.ops import kda_prep
 from ray_tpu.ops import ssd as ssd_op
 from ray_tpu.parallel.sharding import LogicalAxisRules
 
@@ -279,6 +280,31 @@ def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
+def kda_operands(xs, taps, dtype):
+    """The q, k and v projections' outputs [B, S, H, D] and their convs' taps
+    [K, H, D] -> q, k, v [B, H, S, D] in `dtype` as `ops/kda.kda` takes them:
+    conv, SiLU, q = l2norm(q) / sqrt(D), k = l2norm(k), rounded, heads
+    first. The definition; `ops/kda_prep.prep` is the same in one Pallas call
+    each way, where `kda_prep.fused` says it runs."""
+    q, k, v = (_short_conv(x, t) for x, t in zip(xs, taps))
+    q = _l2norm(q) * xs[0].shape[-1] ** -0.5
+    return tuple(jnp.swapaxes(t.astype(dtype), 1, 2)
+                 for t in (q, _l2norm(k), v))
+
+
+def kda_decay(a, dt_bias, a_log, bound):
+    """The decay gate's projection a [B, S, H, D] -> g [B, H, S, D] float32,
+    the log of a channel's decay a token: -exp(A_log) softplus(a + dt_bias),
+    or where the config gives a lower `bound`, bound x sigmoid(exp(A_log) (a
+    + dt_bias)). The definition; `ops/kda_prep.gate` is the same in one
+    Pallas call each way."""
+    a = a.astype(jnp.float32) + dt_bias
+    rate = jnp.exp(a_log.astype(jnp.float32))[:, None]
+    g = -rate * jax.nn.softplus(a) if bound is None \
+        else bound * jax.nn.sigmoid(rate * a)
+    return jnp.swapaxes(g, 1, 2)
+
+
 def kda_sublayer(x, p, config, mesh=None,
                  rules: Optional[LogicalAxisRules] = None):
     """x [B, S, D] -> x + W_o [RMSNorm_head(KDA(q, k, v, g, beta)) x
@@ -289,37 +315,43 @@ def kda_sublayer(x, p, config, mesh=None,
     matrix. No RoPE. `ops/kda.kda` is told what the gate guarantees of g
     (`kda_lower_bound`) and picks its plan from it."""
     c = config
-    d = c.kda_head_dim
     h = rms_norm(x, p["attn_norm"], c.norm_eps)
-    proj = lambda w: jnp.einsum("bsd,dhk->bshk", h, w)  # noqa: E731
+    fused = kda_prep.fused(x.shape[:2] + p["wq"].shape[1:], p["conv_q"], mesh)
 
-    def gate_proj(name):
+    def proj(name, rows=False):
+        """W h as [B, S, H, D], or as the matmul's own rows [B, S, H D] for
+        `ops/kda_prep.py`'s calls, which read a head as a block of lanes (no
+        four-dim array whose layout XLA then picks heads first, and copies
+        from); a projection at low rank where the layer has its `_down`."""
+        w, out = p[name], "hk"
+        if rows:
+            w, out = w.reshape(w.shape[0], -1), "e"
         if name + "_down" not in p:
-            return proj(p[name])
+            return jnp.einsum(f"bsd,d{out}->bs{out}", h, w)
         with jax.named_scope("kda.gate_lora"):
-            return jnp.einsum("bsr,rhk->bshk", h @ p[name + "_down"], p[name])
+            return jnp.einsum(f"bsr,r{out}->bs{out}", h @ p[name + "_down"], w)
 
     heads_first = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
     with jax.named_scope("kda.conv"):
-        q = _l2norm(_short_conv(proj(p["wq"]), p["conv_q"])) * d ** -0.5
-        k = _l2norm(_short_conv(proj(p["wk"]), p["conv_k"]))
-        v = _short_conv(proj(p["wv"]), p["conv_v"])
+        xs = [proj(w, rows=fused) for w in ("wq", "wk", "wv")]
+        taps = [p[t] for t in ("conv_q", "conv_k", "conv_v")]
+        q, k, v = (kda_prep.prep if fused else kda_operands)(
+            xs, taps, c.dtype)
+    rows = 3 * math.prod(q.shape[:3])   # a token's head of q, k or v
+    device_profiler.count("kda.prep_rows", rows)  # per lowering
+    device_profiler.count("kda.prep_rows_fused", rows if fused else 0)
     with jax.named_scope("kda.gates"):
-        a = gate_proj("w_f").astype(jnp.float32) + p["dt_bias"]
-        rate = jnp.exp(p["a_log"].astype(jnp.float32))[:, None]
-        if c.kda_lower_bound is None:
-            g = -rate * jax.nn.softplus(a)
-        else:
-            g = c.kda_lower_bound * jax.nn.sigmoid(rate * a)
+        g = (kda_prep.gate if fused else kda_decay)(
+            proj("w_f", rows=fused), p["dt_bias"], p["a_log"],
+            c.kda_lower_bound)
         beta = jax.nn.sigmoid(jnp.einsum(
             "bsd,dh->bsh", h, p["w_b"], preferred_element_type=jnp.float32))
         if c.kda_beta_scale != 1.0:
             beta = c.kda_beta_scale * beta
-        gate = jax.nn.sigmoid(gate_proj("w_g").astype(jnp.float32))
+        gate = jax.nn.sigmoid(proj("w_g").astype(jnp.float32))
     with jax.named_scope("kda.scan"):
-        o = kda_op.kda(
-            *(heads_first(t.astype(c.dtype)) for t in (q, k, v)),
-            heads_first(g), heads_first(beta), g_min=c.kda_lower_bound)
+        o = kda_op.kda(q, k, v, g, heads_first(beta),
+                       g_min=c.kda_lower_bound)
     o = rms_norm(heads_first(o), p["o_norm"], c.norm_eps)
     o = (o.astype(jnp.float32) * gate).astype(c.dtype)
     device_profiler.count("kda.layers", 1)  # per lowering

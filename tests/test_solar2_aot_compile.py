@@ -119,3 +119,44 @@ def test_the_steps_kernels_and_what_the_queries_take(compiled):
     scopes = {scope for _, scope in compiled["ops"]}
     assert any("kda.gate_lora" in s for s in scopes)
     assert any("attn.gate" in s for s in scopes)
+
+
+def test_the_steps_operand_calls_state_no_limit_and_are_nobody_elses(compiled):
+    """q, k, v and g of the period's KDA body come from `ops/kda_prep.py`'s
+    calls in the compiled STEP (every row counted as fused): `prep`'s and
+    `gate`'s forward, rerun under remat "residuals" (where the compiler does
+    not share it) and backward, named `%kda.prep...` by
+    their scope, which is how `kda_prep_time_share` takes them; no other
+    metric's query that names `tpu_custom_call`, of any cell, takes one (a
+    roofline read over another kernel's events would pass 100%); and the
+    module states no VMEM limit (beside this step's routed block a call that
+    did hung the v5e: PERF.md section 6, PR 62)."""
+    import glob
+    import inspect
+
+    from ray_tpu.ops import kda_prep
+
+    assert "vmem_limit_bytes" not in inspect.getsource(kda_prep)
+    counters = compiled["counters"]
+    assert counters["kda.prep_rows"] == counters["kda.prep_rows_fused"] \
+        == 3 * 8192 * 64 * counters["kda.layers"]
+    calls = [op for op, _ in compiled["ops"]
+             if "tpu_custom_call" in op and op.startswith("%kda.prep")]
+    outputs = {tuple(re.findall(r"\w+\[[\d,]*\]", c.split(" custom-call")[0]))
+               for c in calls}
+    assert outputs == {
+        ("bf16[1,64,8192,128]",) * 3,
+        ("bf16[1,8192,8192]",) * 3 + ("f32[1,12,8192]",),
+        ("f32[1,64,8192,128]",),
+        ("bf16[1,8192,8192]", "f32[1,64,2,128]")}, calls
+    assert 4 <= len(calls) <= 6
+    for path in sorted(glob.glob(os.path.join(
+            REPO_ROOT, "benchmarks", "metrics", "*.json"))):
+        with open(path) as f:
+            query = json.load(f).get("trace_query", {}).get("op", "")
+        if "tpu_custom_call" not in query:
+            continue
+        name = os.path.basename(path)[:-len(".json")]
+        took = [c for c in calls if re.search(query, c)]
+        assert took == (calls if name == "kda_prep_time_share" else []), name
+    assert "kda_prep_time_share" in _queries_of_the_cell()

@@ -860,3 +860,55 @@ def test_the_delta_rules_plans_are_counted_and_its_gates_scoped():
     assert grew["kda.kernels"] == 1 and grew["kda.kernels_any_decay"] == 0
     assert grew.get("kda.halving_products", 0) == 0
     assert not [s for s in scopes(traced.jaxpr) if "kda.gate_lora" in s]
+
+
+@pytest.mark.parametrize("model", ["hybrid_moe", "solar_open2"])
+def test_the_delta_rules_operands_are_counted_under_both_models(
+        model, monkeypatch):
+    """`mixers.kda_sublayer` counts, per lowering, `kda.prep_rows` (a token's
+    head of q, k or v: 3 x B x S x H a layer body) and `kda.prep_rows_fused`,
+    those that `ops/kda_prep.py`'s calls make: all of them at a 128-wide head
+    and whole token tiles (under the interpreter's seam here, on a TPU
+    otherwise), under the scope `kda.prep`; none at the
+    tiny configuration's 16-wide head, where the counter is THERE and 0
+    (`kda_prep_fused_share` reads 0, not nothing)."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import kda_prep
+
+    module = importlib.import_module("ray_tpu.models." + model)
+    tiny = {"hybrid_moe": lambda **kw: module.HybridMoeConfig.tiny(
+                layers=(3, 4, 5), **kw),
+            "solar_open2": lambda **kw: module.SolarOpen2Config.tiny(
+                layers=(0, 1, 2, 3), **kw)}[model]
+    monkeypatch.setattr(kda_prep, "INTERPRET", True)
+    monkeypatch.setattr(kda_prep, "TOKEN_TILE", 32)
+    names = ("kda.prep_rows", "kda.prep_rows_fused", "kda.layers")
+
+    def lowered(cfg):
+        params = jax.eval_shape(lambda: module.init(cfg, jax.random.PRNGKey(0)))
+        before = dp.snapshot()["counters"]
+        traced = jax.make_jaxpr(
+            lambda p, t: module.forward_hidden(p, t, cfg)[0])(
+                params, jax.ShapeDtypeStruct((2, 64), jnp.int32))
+        after = dp.snapshot()["counters"]
+        return traced, {n: after[n] - before.get(n, 0) for n in names}
+
+    def scopes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scopes(sub)
+
+    wide = tiny(remat=False, n_experts_held=4, kda_head_dim=128)
+    traced, grew = lowered(wide)
+    rows = 3 * 2 * 64 * wide.n_heads * grew["kda.layers"]
+    assert grew["kda.layers"] >= 1
+    assert grew["kda.prep_rows"] == grew["kda.prep_rows_fused"] == rows
+    assert any("kda.prep" in s for s in scopes(traced.jaxpr))
+    traced, grew = lowered(tiny(remat=False, n_experts_held=4))
+    assert grew["kda.prep_rows"] > 0 == grew["kda.prep_rows_fused"]
+    assert not [s for s in scopes(traced.jaxpr) if "kda.prep" in s]

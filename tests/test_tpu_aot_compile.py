@@ -731,6 +731,36 @@ for kernel in ("_fwd_kernel", "_states_kernel", "_bwd_kernel"):
         os.environ["MOSAIC_DUMP"], f"*-{kernel}-post-finalize-llo.txt"))
     with open(path) as f:
         out["kda" + kernel + "_vdwg"] = f.read().count('"llo.vdwg"')
+# q, k and v from their projections (`ops/kda_prep.py`): the forward and the
+# backward call at train-ling-1chip's and train-solar2-1chip's shapes
+from ray_tpu.ops import kda_prep
+for cell, (b, h, s) in (("ling", (4, 32, 2048)), ("solar", (1, 64, 8192))):
+    prep_args = ([spec((b, s, h * 128), bf16)] * 3,
+                 [spec((4, h, 128), bf16)] * 3)
+    prep_grads = jax.value_and_grad(lambda xs, taps: sum(
+        t.astype(jnp.float32).sum() for t in kda_prep.prep(xs, taps, bf16)),
+        argnums=(0, 1))
+    assert kda_prep.fused((b, s, h, 128), prep_args[1][0]) == (
+        jax.default_backend() == "tpu")
+    out["kda_prep_vmem_limits_" + cell] = re.findall(
+        r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(prep_grads)(*prep_args)))
+    hlo = jax.jit(prep_grads).lower(*prep_args).compile().as_text()
+    calls_of = lambda hlo: [  # noqa: E731
+        re.sub(r"custom-call\(.*", 'custom-call(%a), custom_call_target='
+               '"tpu_custom_call"', ln.strip())
+        for ln in hlo.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    out["kda_prep_calls_" + cell] = calls_of(hlo)
+    # the decay gate under the cell's form: Ling's bounded sigmoid, Solar's
+    # softplus; a cotangent that is no constant, or XLA folds the call away
+    bound = {"ling": -5.0, "solar": None}[cell]
+    gate_args = (spec((b, s, h * 128), bf16), spec((h, 128), jnp.float32),
+                 spec((h,), jnp.float32), spec((b, h, s, 128), jnp.float32))
+    gate_grads = jax.value_and_grad(lambda a, bias, a_log, w: (
+        kda_prep.gate(a, bias, a_log, bound) * w).sum(), argnums=(0, 1, 2))
+    out["kda_prep_vmem_limits_" + cell] += re.findall(
+        r"vmem_limit_bytes=(\d+)", str(jax.make_jaxpr(gate_grads)(*gate_args)))
+    out["kda_gate_calls_" + cell] = calls_of(
+        jax.jit(gate_grads).lower(*gate_args).compile().as_text())
 print("RESULT " + json.dumps(out))
 """
 
@@ -850,6 +880,45 @@ def test_delta_rule_kernels_compile_for_v5e_under_their_own_signatures(
     assert compiled["kda_fwd_kernel_vdwg"] / rows < 60
     assert compiled["kda_states_kernel_vdwg"] / rows < 60
     assert compiled["kda_bwd_kernel_vdwg"] / rows < 60
+
+
+@pytest.mark.parametrize("cell, b, h, s", [
+    ("ling", 4, 32, 2048), ("solar", 1, 64, 8192)])
+def test_the_delta_rules_operands_compile_for_v5e_under_no_other_signature(
+        compiled, cell, b, h, s):
+    """`ops/kda_prep.py` at train-ling-1chip's and train-solar2-1chip's
+    shapes: FOUR Pallas calls: q, k and v heads first out of `prep`'s
+    forward, three dx and the taps' float32 gradient out of its backward; g
+    float32 heads first out of `gate`'s forward (Ling's bounded form, Solar's
+    softplus), the projection's cotangent and a head's two sums over the
+    tokens out of its backward; none stating a VMEM limit (the v5e's compiler
+    places them in the 16 MiB a call gets unasked), all named by their
+    scope, which is how `kda_prep_time_share` takes them; and NO other
+    metric's query that names `tpu_custom_call` takes any: a flash backward
+    is one or two bf16 four-dim outputs, a KDA forward `(bf16[3-dim],
+    f32[3-dim])`, the SSD's first walk one `f32[3-dim]`."""
+    import glob
+
+    calls = compiled["kda_prep_calls_" + cell] \
+        + compiled["kda_gate_calls_" + cell]
+    assert compiled["kda_prep_vmem_limits_" + cell] == []
+    assert len(calls) == 4 and all(c.startswith("%kda.prep") for c in calls)
+    outputs = sorted(re.findall(r"\w+\[[\d,]*\]", c.split(" custom-call")[0])
+                     for c in calls)
+    assert outputs == sorted([
+        [f"bf16[{b},{h},{s},128]"] * 3,
+        [f"bf16[{b},{s},{h * 128}]"] * 3 + [f"f32[{b},12,{h * 128}]"],
+        [f"f32[{b},{h},{s},128]"],
+        [f"bf16[{b},{s},{h * 128}]", f"f32[{b},{h},2,128]"]])
+    for path in sorted(glob.glob(os.path.join(
+            REPO_ROOT, "benchmarks", "metrics", "*.json"))):
+        with open(path) as f:
+            query = json.load(f).get("trace_query", {}).get("op", "")
+        if "tpu_custom_call" not in query:
+            continue
+        name = os.path.basename(path)[:-len(".json")]
+        took = [bool(re.search(query, c)) for c in calls]
+        assert took == [name == "kda_prep_time_share"] * 4, name
 
 
 def test_state_space_kernels_compile_for_v5e_under_their_own_signatures(

@@ -421,6 +421,263 @@ def cell_check(seed):
     return out
 
 
+SCOPE_PARTS = ("kda.prep", "kda.conv", "kda.gates", "kda.scan")
+
+
+def _phase(scope):
+    """An op's `op_name` -> forward, the forward's rerun under remat, or
+    backward."""
+    if "transpose(" not in scope:
+        return "forward"
+    return "rerun" if "rematted_computation" in scope else "backward"
+
+
+def _fused_scopes(text):
+    """A compiled step's text -> {fused computation: the KDA scopes of the
+    instructions in its body}: a fusion's own metadata keeps one name."""
+    inside, current = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^%?(\S*fused\S*) \(", line)
+        if m:
+            current = inside.setdefault(m[1], set())
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            current.update(re.findall(r"kda\.\w+", "".join(
+                re.findall(r'op_name="([^"]*)"', line))))
+    return inside
+
+
+def scope_split(cell, seed, steps=4, watchdog=900):
+    """The cell's own train step (`benchmarks/train_cell.py`'s: the
+    `program` group at the published widths, the traffic's B x S, AdamW,
+    `train.make_train_step`), `steps` steps traced after two warm ones, under
+    a watchdog that ends a hung process with its stack printed, and the
+    device's time split by the `jax.named_scope` each op's metadata keeps in
+    the COMPILED text (an event is named `%<instruction> = ...`): ms a step
+    of `kda.conv` (the q, k, v side), `kda.gates`, `kda.scan` (the kernels)
+    and, where a program has them, the `kda.prep` calls, each forward, rerun
+    under remat and backward; a fusion that holds ops of several of these
+    scopes is listed under all of them joined. -> the dict
+    chiprun_out/kda_scope_split.<cell>.json keeps."""
+    import faulthandler
+    import importlib
+    from functools import partial
+
+    import optax
+
+    from benchmarks import reduce_trace
+    from ray_tpu import train
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import LogicalAxisRules
+    from ray_tpu.train.step import _as_dict
+    from tools import step_lowering_hash
+
+    faulthandler.dump_traceback_later(watchdog, exit=True)
+    (found,) = [c for c in step_lowering_hash.cells() if c[0]["name"] == cell]
+    workload, config = found
+    with open(os.path.join(step_lowering_hash.ROOT, "benchmarks", "traffic",
+                           workload["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    program = config["program"]
+    module = importlib.import_module(program["module"])
+    fields = {k: config[v] for k, v in program["fields_from"].items()}
+    fields.update(program["fields"])
+    model = getattr(module, program["config_class"])(**fields)
+    mesh = build_mesh(MeshConfig(**config["mesh"]), devices=jax.devices()[:1])
+    rules = LogicalAxisRules()
+    t = config["trainer"]
+    opt = optax.adamw(t["learning_rate"], weight_decay=t["weight_decay"])
+    key = jax.random.fold_in(jax.random.PRNGKey(seed % 2**31), seed // 2**31)
+    state, shardings = train.init_train_state(
+        partial(module.init, model), opt, module.param_logical_axes(model),
+        mesh, key, rules)
+    bs = train.batch_sharding(mesh, rules)
+    step = train.make_train_step(
+        partial(module.loss_fn, config=model, mesh=mesh, rules=rules), opt,
+        shardings, batch_sharding={"inputs": bs, "targets": bs})
+    toks = jax.random.randint(
+        jax.random.fold_in(key, 1),
+        (traffic["per_chip_batch"], traffic["seq"] + 1), 0, model.vocab_size)
+    batch = jax.device_put({"inputs": toks[:, :-1], "targets": toks[:, 1:]},
+                           bs)
+    compiled = step.lower(state, batch).compile()
+    state = _as_dict(state)
+    text = compiled.as_text()
+    print("compiled", flush=True)
+    fused = _fused_scopes(text)
+    scope_of = {}
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        if not m or " parameter(" in line:
+            continue
+        name = "".join(re.findall(r'op_name="([^"]*)"', line)[:1])
+        parts = set(re.findall(r"kda\.\w+", name))
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if called and called[1] in fused:
+            parts |= fused[called[1]]
+        scope_of[m[1]] = ("+".join(sorted(parts & set(SCOPE_PARTS))),
+                          _phase(name), name)
+    losses = []
+    for i in range(2 + steps):
+        if i == 2:
+            where = tempfile.mkdtemp()
+            jax.profiler.start_trace(where)
+        start = time.perf_counter()
+        state, m = compiled(state, batch)
+        losses.append(float(m["loss"]))
+        print(f"step {i} loss {losses[-1]:.4f} "
+              f"{1e3 * (time.perf_counter() - start):.1f} ms", flush=True)
+    jax.profiler.stop_trace()
+    faulthandler.cancel_dump_traceback_later()
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(where, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    events = [(e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, e.name)
+              for p in ProfileData.from_file(path).planes
+              if p.name.startswith("/device:TPU:")
+              for ln in p.lines if ln.name == "XLA Ops" for e in ln.events]
+    events, selfs = reduce_trace.self_times(events)
+    split, by_op, busy = {}, {}, 0.0
+    for (_, _, name), took in zip(events, selfs):
+        ms = 1e3 * took / steps
+        busy += ms
+        m = re.match(r"%([\w.\-]+) = ", name)
+        part, phase, scope = scope_of.get(m[1] if m else "", ("", "", ""))
+        op = by_op.setdefault(m[1] if m else name[:40], {
+            "ms": 0.0, "op": reduce_trace.short_op(name), "part": part,
+            "phase": phase, "scope": scope[-120:]})
+        op["ms"] += ms
+        if part:
+            at = split.setdefault(part, {})
+            at[phase] = at.get(phase, 0.0) + ms
+    for part in split.values():
+        part["all"] = sum(part.values())
+    top = sorted(by_op.values(), key=lambda o: -o["ms"])
+    return {"cell": cell, "seed": seed, "steps": steps, "losses": losses,
+            "device": jax.devices()[0].device_kind,
+            "busy_ms_a_step": busy, "ms_a_step": split,
+            "top_in_scopes": [o for o in top if o["part"]][:40],
+            "top_ops": top[:40], "all_ops": top}
+
+
+def prep_check(seed, calls=5, tile=None):
+    """`ops/kda_prep.py`'s two calls alone at both cells' shapes against
+    `mixers.kda_operands`, the `jnp` lines, on the chip -> {shape: q, k, v
+    against the lines' (the share of bf16 values that are equal, the largest
+    difference in ulps of bf16), dx and d taps against the lines' own vjp in
+    float32 (relative, Frobenius; `lines_*`: what the lines in bf16 read
+    against the same), and by the DEVICE'S clock, ms a call: the forward and
+    forward + backward programs of each, the calls' own events apart, with
+    the bytes a call has to move (a read and a write a tensor forward; x,
+    the cotangent and dx backward) and their share of the HBM's peak}."""
+    from benchmarks import peaks
+    from ray_tpu.models import mixers
+    from ray_tpu.ops import kda_prep
+
+    peak = peaks.PEAKS[jax.devices()[0].device_kind]["hbm_bytes_per_s"]
+    dtype = jnp.bfloat16
+    if tile:   # for tuning: the module's own is what a model runs
+        kda_prep.TOKEN_TILE = tile
+    out = {"tile": kda_prep.TOKEN_TILE}
+    for name, (b, h, s, d) in SHAPES.items():
+        ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+        xs = [jax.random.normal(ks[i], (b, s, h, d)).astype(dtype)
+              for i in range(3)]
+        taps = [(0.5 * jax.random.normal(ks[3 + i], (4, h, d))).astype(dtype)
+                for i in range(3)]
+        cots = tuple(jax.random.normal(ks[6 + i], (b, h, s, d)).astype(dtype)
+                     for i in range(3))
+        assert kda_prep.fused(xs[0].shape, taps[0])
+        # the calls read a projection's rows [B, S, H D]
+        rows = lambda x: x.reshape(x.shape[:2] + (-1,))  # noqa: E731
+        prep = lambda xs, taps, dt: kda_prep.prep(  # noqa: E731
+            [rows(x) for x in xs], taps, dt)
+
+        def both(fn, dt):
+            def run(xs, taps, cots):
+                o, vjp = jax.vjp(lambda xs, taps: fn(xs, taps, dt), xs, taps)
+                return o, vjp(cots)
+            return jax.jit(run)
+
+        fwd = {"calls": jax.jit(lambda xs, taps: prep(xs, taps, dtype)),
+               "lines": jax.jit(lambda xs, taps: mixers.kda_operands(
+                   xs, taps, dtype))}
+        bwd = {"calls": both(prep, dtype),
+               "lines": both(mixers.kda_operands, dtype)}
+        got, (got_dx, got_dtaps) = bwd["calls"](xs, taps, cots)
+        want, (lines_dx, lines_dtaps) = bwd["lines"](xs, taps, cots)
+        f32 = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: a.astype(jnp.float32), tree)
+        _, (want_dx, want_dtaps) = both(mixers.kda_operands, jnp.float32)(
+            *f32((xs, taps, cots)))
+        rel = lambda a, b: float(  # noqa: E731
+            jnp.linalg.norm(a.astype(jnp.float32) - b.astype(jnp.float32))
+            / jnp.linalg.norm(b.astype(jnp.float32)))
+        bits = lambda a: jax.lax.bitcast_convert_type(  # noqa: E731
+            a, jnp.int16).astype(jnp.int32)
+        at = out[name] = {"shape": [b, h, s, d], "values": {}, "grads": {}}
+        for n, a, w in zip("qkv", got, want):
+            at["values"][n] = {
+                "equal_share": float(jnp.mean(a == w)),
+                "max_ulps": int(jnp.max(jnp.abs(bits(a) - bits(w)))),
+                "rel": rel(a, w)}
+        for n, a, w, ln in zip(("dx_q", "dx_k", "dx_v"), got_dx, want_dx,
+                               lines_dx):
+            at["grads"][n] = {"rel": rel(a, w), "lines_rel": rel(ln, w)}
+        for n, a, w, ln in zip(("dtaps_q", "dtaps_k", "dtaps_v"), got_dtaps,
+                               want_dtaps, lines_dtaps):
+            at["grads"][n] = {"rel": rel(a, w), "lines_rel": rel(ln, w)}
+        tensor = b * s * h * d * 2
+        at["bytes"] = {"fwd": 6 * tensor, "fwd_and_bwd": 15 * tensor}
+        at["ms"] = {}
+        def timed_on_device(kind, fns, args):
+            """ms a call of each program of `fns`, and of the calls' own
+            events (named by their scope) with their share of the peak."""
+            for who, fn in fns.items():
+                jax.block_until_ready(fn(*args))
+                events = device_events(fn, *args, n=calls)
+                at["ms"][f"{kind}_{who}"] = \
+                    sum(ns for _, ns in events) * 1e-6 / calls
+                own = sum(ns for e, ns in events
+                          if "kda.prep" in e.split(" = ")[0]) * 1e-6 / calls
+                if who == "calls" and own:
+                    at["ms"][f"{kind}_calls_own"] = own
+                    at[f"{kind}_hbm_share"] = \
+                        at["bytes"][kind] / (own * 1e-3) / peak
+
+        timed_on_device("fwd", fwd, (xs, taps))
+        timed_on_device("fwd_and_bwd", bwd, (xs, taps, cots))
+        # the decay gate under the cell's form: Ling's bounded sigmoid,
+        # Solar's softplus
+        bound = {"ling": K.G_MIN_BOUNDED, "solar": None}[name]
+        a = (2.0 * jax.random.normal(ks[0], (b, s, h, d))).astype(dtype)
+        bias = -jax.random.uniform(ks[1], (h, d), minval=1.0, maxval=5.0)
+        a_log = jnp.log(jax.random.uniform(ks[2], (h,), minval=1.0,
+                                           maxval=16.0))
+        w = jax.random.normal(ks[3], (b, h, s, d))
+
+        def gate_both(fn):
+            def run(a, bias, a_log, w):
+                g, vjp = jax.vjp(lambda *x: fn(*x, bound), a, bias, a_log)
+                return g, vjp(w)
+            return jax.jit(run)
+
+        gates = {"calls": gate_both(lambda a, *x: kda_prep.gate(rows(a), *x)),
+                 "lines": gate_both(mixers.kda_decay)}
+        got_g, got_grads = gates["calls"](a, bias, a_log, w)
+        want_g, _ = gates["lines"](a, bias, a_log, w)
+        _, want_grads = gates["lines"](a.astype(jnp.float32), bias, a_log, w)
+        at["gate"] = {"g": rel(got_g, want_g), **{
+            n: rel(x, y) for n, x, y in zip(("da", "d_dt_bias", "d_a_log"),
+                                            got_grads, want_grads)}}
+        at["bytes"]["gate_fwd_and_bwd"] = 7 * tensor   # a 1, g 2; dg 2, a, da
+        timed_on_device("gate_fwd_and_bwd", gates, (a, bias, a_log, w))
+        print(json.dumps({name: at}), flush=True)
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, nargs="+", default=[39])
@@ -434,12 +691,50 @@ def main():
     ap.add_argument("--times-only", action="store_true", help="the `ms` of "
                     "each shape and plan and nothing compared (exit 0): a "
                     "minute, for tuning a kernel")
+    ap.add_argument("--scopes", metavar="CELL", nargs="+", help="and nothing "
+                    "else: each cell's own train step traced for four "
+                    "steps under a watchdog, the device's ms a step split "
+                    "by the scopes kda.conv / kda.gates / kda.scan / "
+                    "kda.prep, forward, rerun and backward")
+    ap.add_argument("--tile", type=int, help="with --prep: the calls at "
+                    "this token tile, not the module's (for tuning)")
+    ap.add_argument("--prep", action="store_true", help="and nothing else: "
+                    "`ops/kda_prep.py`'s calls alone at both cells' shapes "
+                    "against the `jnp` lines, values, gradients and the "
+                    "device's ms a call")
     a = ap.parse_args()
     if jax.default_backend() != "tpu":
         print(f"kda_chip_check: backend {jax.default_backend()!r}, not a TPU: "
               "run it through the chip tool", file=sys.stderr)
         return 3
     os.makedirs("chiprun_out", exist_ok=True)
+    if a.prep:
+        out = prep_check(a.seed[0], tile=a.tile)
+        name = f"kda_prep_check.{a.tile}.json" if a.tile \
+            else "kda_prep_check.json"
+        with open("chiprun_out/" + name, "w") as f:
+            json.dump(out, f, indent=1)
+        shapes = [at for at in out.values() if isinstance(at, dict)]
+        # a call's dx is rounded once: no worse than the lines' own
+        return 0 if all(
+            v["max_ulps"] <= 1 for at in shapes
+            for v in at["values"].values()) and all(
+            g["rel"] <= max(2e-3, g["lines_rel"]) for at in shapes
+            for g in at["grads"].values()) and all(
+            at["gate"]["g"] <= 1e-5 and at["gate"]["da"] <= 2e-3
+            for at in shapes) else 1
+    if a.scopes:
+        for cell in a.scopes:
+            out = scope_split(cell, a.seed[0])
+            # every op, for a second reading without a second run
+            with open(f"chiprun_out/kda_scope_split.{cell}.ops.json",
+                      "w") as f:
+                json.dump(out.pop("all_ops"), f)
+            with open(f"chiprun_out/kda_scope_split.{cell}.json", "w") as f:
+                json.dump(out, f, indent=1)
+            print(json.dumps({k: out[k] for k in (
+                "cell", "losses", "busy_ms_a_step", "ms_a_step")}))
+        return 0
     if a.cell:
         cells = [cell_check(seed) for seed in a.seed]
         with open("chiprun_out/kda_cell_check.json", "w") as f:
